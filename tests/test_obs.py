@@ -23,7 +23,7 @@ import jax
 
 from tpu_als import ALS, obs
 from tpu_als.cli import main as cli_main
-from tpu_als.obs import report, schema
+from tpu_als.obs import report
 from tpu_als.obs.metrics import BUCKET_BOUNDS, MetricsRegistry, _Hist
 from tpu_als.parallel.mesh import make_mesh
 from tpu_als.utils import observe
@@ -506,33 +506,6 @@ def test_check_obs_schema_catches_fault_point_drift(tmp_path):
     assert "4 violation(s)" in p.stderr
     assert f"{bad.name}:2" not in p.stderr
     assert f"{bad.name}:4" not in p.stderr
-
-
-# -- bench.py probe events -------------------------------------------------
-
-def test_bench_retry_events_are_schema_valid(monkeypatch):
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-
-    class _Failed:
-        returncode = 1
-        stdout = ""
-        stderr = "RuntimeError: tunnel down\n"
-
-    monkeypatch.setattr(bench.subprocess, "run",
-                        lambda *a, **k: _Failed())
-    ok, err, events = bench.tpu_ready(attempts=2, wait_s=0,
-                                      probe_timeout_s=5)
-    assert not ok and "tunnel down" in err
-    # per-attempt retry records, then the terminal exhaustion verdict
-    assert [e["attempt"] for e in events[:-1]] == [1, 2]
-    assert events[-1]["type"] == "bench_probe_exhausted"
-    for ev in events:
-        schema.check_event(ev["type"], {
-            k: v for k, v in ev.items() if k not in ("ts", "type")})
 
 
 # -- the observe CLI end to end (ISSUE acceptance) -------------------------

@@ -1,7 +1,8 @@
 """Batch-in-lanes Pallas SPD solver vs dense reference (interpret mode on
-the CPU test mesh; the same kernel compiles for real on TPU — measured
-2.2x the blocked kernel at rank 128 on v5e)."""
+the CPU test mesh; tests/test_chip_compile.py compiles the same kernel for
+a described v5e)."""
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -144,12 +145,13 @@ class TestAvailableProbe:
     """Same standard as pallas_solve.available: wrong-but-finite output
     fails, crashes fail, correct output passes."""
 
-    def _probe(self, monkeypatch, fake_kernel):
+    def _probe(self, monkeypatch, fake_kernel, cache=None):
         from tpu_als.ops import pallas_lanes
         from tpu_als.utils import platform
 
         monkeypatch.setattr(platform, "on_tpu", lambda: True)
-        monkeypatch.setattr(pallas_lanes, "_AVAILABLE", {})
+        monkeypatch.setattr(pallas_lanes, "_AVAILABLE",
+                            {} if cache is None else cache)
         monkeypatch.setattr(pallas_lanes, "_PANEL", {})
         monkeypatch.setattr(pallas_lanes, "_MXU", {})
         monkeypatch.setattr(pallas_lanes, "spd_solve_lanes", fake_kernel)
@@ -163,9 +165,10 @@ class TestAvailableProbe:
 
     def test_rejects_crashing_kernel(self, monkeypatch):
         def boom(A, b, panel=None, mxu=False, interpret=False):
-            raise RuntimeError("mosaic compile failure")
+            raise jax.errors.JaxRuntimeError("mosaic compile failure")
 
-        assert self._probe(monkeypatch, boom) is False
+        with pytest.warns(UserWarning, match="refused by the compiler"):
+            assert self._probe(monkeypatch, boom) is False
 
     def test_accepts_correct_kernel(self, monkeypatch):
         from tpu_als.ops import pallas_lanes
@@ -184,10 +187,28 @@ class TestAvailableProbe:
         # ladder degrades to the VPU sweep and records mxu=False
         from tpu_als.ops import pallas_lanes
 
+        from tpu_als.utils.platform import ProbeCache
+
         def picky(A, b, panel=None, mxu=False, interpret=False):
             if mxu:
-                raise RuntimeError("mosaic compile failure")
+                raise jax.errors.JaxRuntimeError(
+                    "RESOURCE_EXHAUSTED: scoped vmem\nsecond line")
             return jnp.linalg.solve(A, b[..., None])[..., 0]
 
-        assert self._probe(monkeypatch, picky) is True
+        cache = ProbeCache("t_lanes_ladder")
+        with pytest.warns(UserWarning, match="refused by the compiler"):
+            assert self._probe(monkeypatch, picky, cache) is True
         assert pallas_lanes.selected_mxu(32) is False
+        # the verdict says which rung won and why the other lost
+        assert cache.meta[32]["reason"] == (
+            "pallas_lanes[r=32,panel=32,mxu=True]: compiler refused: "
+            "JaxRuntimeError: RESOURCE_EXHAUSTED: scoped vmem; "
+            "pallas_lanes[r=32,panel=8,mxu=False]: compiled and validated")
+
+    def test_probe_bug_is_not_a_rung_loss(self, monkeypatch):
+        # anything but the compiler's refusal propagates out of the ladder
+        def buggy(A, b, panel=None, mxu=False, interpret=False):
+            raise AttributeError("no such name")
+
+        with pytest.raises(AttributeError):
+            self._probe(monkeypatch, buggy)

@@ -1,6 +1,7 @@
 """Pallas batched SPD solver vs scipy/XLA reference (interpret mode on the
 CPU test mesh; the same kernel compiles for real on TPU)."""
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -78,9 +79,10 @@ class TestAvailableProbe:
 
     def test_rejects_crashing_kernel(self, monkeypatch):
         def boom(A, b, panel=32, interpret=False):
-            raise RuntimeError("mosaic compile failure")
+            raise jax.errors.JaxRuntimeError("mosaic compile failure")
 
-        assert self._probe(monkeypatch, boom) is False
+        with pytest.warns(UserWarning, match="refused by the compiler"):
+            assert self._probe(monkeypatch, boom) is False
 
     def test_accepts_correct_kernel(self, monkeypatch):
         assert self._probe(

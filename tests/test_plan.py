@@ -409,7 +409,7 @@ def test_probe_registry_enumerable_and_clearable_in_place():
     c = platform.probe_cache("t_reg")
     assert platform.probe_cache("t_reg") is c
     c["k"] = True
-    c.meta["k"] = {"seconds": 0.1, "transient": False}
+    c.meta["k"] = {"seconds": 0.1, "reason": "compiled and validated"}
     assert "t_reg" in platform.probe_caches()
     platform.clear_probe_caches("t_reg")
     assert platform.probe_cache("t_reg") is c    # identity preserved
@@ -443,62 +443,28 @@ def test_probe_kernel_contract_unchanged_for_plain_dicts():
 def test_probe_kernel_notes_provenance_on_registered_caches():
     c = platform.probe_cache("t_pk")
     assert platform.probe_kernel(c, ("r", 8), lambda: True) is False
-    assert c.meta[("r", 8)] == {"seconds": None, "transient": False}
+    assert c.meta[("r", 8)] == {"seconds": None, "reason": "no TPU"}
 
 
-def test_snapshot_excludes_transient_and_seed_in_process_wins():
+def test_snapshot_banks_verdicts_and_seed_in_process_wins():
     c = platform.probe_cache("t_snap")
     c[("a", 1)] = True
-    c.meta[("a", 1)] = {"seconds": 0.5, "transient": False}
-    c["flaky"] = False
-    c.meta["flaky"] = {"seconds": 1.0, "transient": True}
+    c.meta[("a", 1)] = {"seconds": 0.5, "reason": "compiled and validated"}
+    c["refused"] = False
+    c.meta["refused"] = {"seconds": 1.0, "reason": "compiler refused: x"}
     snap = platform.snapshot_probes()
-    assert snap["t_snap"] == {repr(("a", 1)): True}   # flaky excluded
+    assert snap["t_snap"] == {repr(("a", 1)): True, "'refused'": False}
     assert platform.probe_timings()["t_snap"] == {repr(("a", 1)): 0.5,
-                                                  "'flaky'": 1.0}
+                                                  "'refused'": 1.0}
     platform.clear_probe_caches("t_snap")
-    c["flaky"] = True                        # this process's own verdict
+    c["refused"] = True                      # this process's own verdict
     n = platform.seed_probes({"t_snap": {repr(("a", 1)): True,
-                                         "'flaky'": False,
+                                         "'refused'": False,
                                          "<unparseable": True}})
-    assert n == 1                            # flaky kept, junk skipped
-    assert c[("a", 1)] is True and c["flaky"] is True
+    assert n == 1                            # own verdict kept, junk skipped
+    assert c[("a", 1)] is True and c["refused"] is True
     assert c.meta[("a", 1)]["seeded"]
-
-
-# -- probe budget suggestion (bench.py consumes this jax-free) -------------
-
-def test_suggested_probe_budget_ladder(monkeypatch, tmp_path):
-    monkeypatch.setenv(ENV_VAR, "off")
-    assert plan_cache.suggested_probe_budget(600) == (600.0, "planner off")
-    monkeypatch.setenv(ENV_VAR, str(tmp_path / "b"))
-    b, why = plan_cache.suggested_probe_budget(600)
-    assert b == 600.0 and "no warm" in why
-    plan.resolve_topk(rank=4, k=3, walk=lambda: "xla")   # bank one entry
-    b, why = plan_cache.suggested_probe_budget(600)
-    assert b == 120.0 and "warm plan entr" in why
-    assert plan_cache.suggested_probe_budget(100)[0] == 100.0  # capped
-    # an entry banked under another jax version is not warm
-    path = plan_cache.entry_path(plan.plan_key(rank=4, dtype="float32"))
-    with open(path, encoding="utf-8") as f:
-        doc = json.load(f)
-    doc["plan_key"]["jax_version"] = "0.0.0"
-    with open(path, "w", encoding="utf-8") as f:
-        json.dump(doc, f)
-    assert plan_cache.suggested_probe_budget(600)[0] == 600.0
-
-
-def test_bench_resolves_probe_budget_from_the_cache(monkeypatch, tmp_path):
-    sys.path.insert(0, REPO)
-    import bench
-
-    monkeypatch.setenv(ENV_VAR, str(tmp_path / "bb"))
-    b, why = bench.resolve_probe_budget(None)
-    assert b == bench.DEFAULT_PROBE_BUDGET_S and "no warm" in why
-    assert bench.resolve_probe_budget(45) == (45.0, "explicit --probe-budget")
-    plan.resolve_topk(rank=4, k=3, walk=lambda: "xla")
-    b, why = bench.resolve_probe_budget(None)
-    assert b == 120.0
+    assert c.meta[("a", 1)]["reason"] == "banked verdict (plan cache)"
 
 
 # -- whole-plan assembly + CLI verbs ---------------------------------------
@@ -512,7 +478,6 @@ def test_resolve_execution_plan_and_summary():
     assert ep.serving_buckets == tuple(DEFAULT_BUCKETS)
     s = ep.summary()
     assert s["resolved_solve_path"] == ep.solve["resolved_solve_path"]
-    assert s["probe_budget_s"] > 0
     # off: same plan, no planner involvement
     os.environ[ENV_VAR] = "off"
     try:

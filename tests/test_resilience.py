@@ -570,33 +570,6 @@ def test_last_good_cache_bounded_per_mesh(rng):
 
 
 # ---------------------------------------------------------------------------
-# bench.py rides the same retry implementation
-
-
-def test_bench_tpu_ready_failure_events(monkeypatch):
-    import subprocess as sp
-
-    import bench
-
-    def failing_run(cmd, timeout=None, capture_output=None, text=None):
-        raise sp.TimeoutExpired(cmd, timeout)
-
-    monkeypatch.setattr(bench.subprocess, "run", failing_run)
-    ok, err, events = bench.tpu_ready(attempts=2, wait_s=0.01,
-                                      probe_timeout_s=1)
-    assert not ok and "hung" in err
-    assert [e["attempt"] for e in events[:-1]] == [1, 2]
-    for e in events[:-1]:
-        assert e["type"] == "bench_retry" and e["attempts"] == 2
-        assert "hung" in e["reason"] and "ts" in e
-        assert "TimeoutError" not in e["reason"]   # raw reason contract
-    # exhaustion ends the trail with an explicit terminal verdict
-    last = events[-1]
-    assert last["type"] == "bench_probe_exhausted"
-    assert last["attempts"] == 2 and "hung" in last["reason"]
-
-
-# ---------------------------------------------------------------------------
 # preemption primitives (the end-to-end kill-and-resume lives in
 # tests/test_resume.py)
 

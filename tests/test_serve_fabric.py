@@ -19,6 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests.conftest import assert_topk_within_contract
 from tpu_als.ops.topk import chunked_topk_scores
 from tpu_als.parallel.mesh import make_mesh
 from tpu_als.parallel.serve import topk_sharded
@@ -113,7 +114,9 @@ def mesh8():
 
 def test_sharded_index_bitwise_vs_single_device(rng, mesh8):
     # distinct-score corpus: ids must match the single-device index
-    # exactly, not merely point at equal scores
+    # exactly, not merely point at equal scores; the scores agree to
+    # the index contract's SCORE_ULPS (per-shard rescore GEMMs have
+    # other shapes than the single-device one — serving/index.py)
     Ni, r, k = 700, 32, 10
     V = rng.normal(size=(Ni, r)).astype(np.float32)
     U = rng.normal(size=(33, r)).astype(np.float32)
@@ -124,7 +127,9 @@ def test_sharded_index_bitwise_vs_single_device(rng, mesh8):
     assert isinstance(ref, Int8CandidateIndex)
     s0, i0 = ref.topk(jnp.asarray(U), k)
     s1, i1 = sh.topk(jnp.asarray(U), k)
-    assert np.array_equal(np.asarray(s0), np.asarray(s1))
+    n_clear = assert_topk_within_contract(s0, i0, U, V, valid, k)
+    assert assert_topk_within_contract(s1, i1, U, V, valid, k) == n_clear
+    assert n_clear >= 30          # the corpus IS distinct-score
     assert np.array_equal(np.asarray(i0), np.asarray(i1))
 
 

@@ -7,6 +7,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from tests.conftest import assert_topk_within_contract
 from tpu_als.ops.topk import chunked_topk_scores
 from tpu_als.parallel.mesh import make_mesh
 from tpu_als.parallel.serve import topk_sharded
@@ -70,10 +71,12 @@ def test_k_capped_at_catalog(rng):
 
 
 def test_strategies_agree_on_duplicate_scores(rng):
-    """Adversarial ties: the module docstring promises SCORES are always
-    identical across strategies even though tied INDICES may differ
-    (merge order is shard-rotation order).  Pin both halves: scores
-    bitwise equal, and every returned index earns its claimed score."""
+    """Adversarial ties: the module docstring promises SCORES agree
+    across strategies to reduction-order rounding even though tied
+    INDICES may differ (merge order is shard-rotation order).  Pin both
+    halves: scores within the serving contract's SCORE_ULPS of the
+    single-device kernel (hence of each other), and every returned
+    index earns its claimed score."""
     base = rng.normal(size=(7, 6)).astype(np.float32)
     V = base[rng.integers(0, 7, 96)]     # whole catalog = repeated rows
     U = rng.normal(size=(11, 6)).astype(np.float32)
@@ -81,7 +84,9 @@ def test_strategies_agree_on_duplicate_scores(rng):
     s_ag, i_ag = topk_sharded(U, V, k, make_mesh(8),
                               strategy="all_gather")
     s_ring, i_ring = topk_sharded(U, V, k, make_mesh(8), strategy="ring")
-    np.testing.assert_array_equal(s_ag, s_ring)
+    valid = np.ones(len(V), bool)
+    assert_topk_within_contract(s_ag, i_ag, U, V, valid, k)
+    assert_topk_within_contract(s_ring, i_ring, U, V, valid, k)
     full = U.astype(np.float64) @ V.astype(np.float64).T
     for ix, s in ((i_ag, s_ag), (i_ring, s_ring)):
         np.testing.assert_allclose(

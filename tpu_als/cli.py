@@ -2385,6 +2385,12 @@ def main(argv=None):
         return args.fn(args)  # read-only commands must not write a run dir
 
     from tpu_als import obs
+    from tpu_als.utils.platform import enable_persistent_compile_cache
+
+    # every other command compiles: keep the executables across runs
+    # (JAX_COMPILATION_CACHE_DIR, else .bench_cache/xla_cache in the
+    # checkout — utils.platform says why the place is fixed)
+    enable_persistent_compile_cache()
 
     run_dir = args.obs_dir
     if run_dir is None and getattr(args, "output", None):

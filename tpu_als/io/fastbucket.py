@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import ctypes
 import os
-
+import subprocess
 
 import numpy as np
 

@@ -292,9 +292,6 @@ EVENTS = {
     "checkpoint_load": (
         ("path", "seconds", "bytes"),
         "one per load_factors call"),
-    "bench_retry": (
-        ("attempt", "attempts", "elapsed_seconds", "reason"),
-        "one per failed bench.py backend probe attempt"),
     "retry_attempt": (
         ("what", "attempt", "attempts", "elapsed_seconds", "reason"),
         "one per failed attempt inside resilience.retry.retry_call "
@@ -367,11 +364,6 @@ EVENTS = {
         ("scenario", "passed", "seconds"),
         "a scenario run finished (or aborted on a phase failure, with "
         "an extra 'error' field): the verdict and total seconds"),
-    "bench_probe_exhausted": (
-        ("attempts", "elapsed_seconds", "reason"),
-        "bench.py gave up on the backend probe: every attempt in the "
-        "retry/budget policy failed (the terminal record after the "
-        "per-attempt bench_retry trail)"),
     "flight_record": (
         ("seq", "trigger", "status", "spans"),
         "one per-request trace dumped by the serving flight recorder "

@@ -81,6 +81,34 @@ class TileBudgetError(ValueError):
     config as infeasible and widen ``vmem_budget`` or shrink ``panel``."""
 
 
+def _weighted_row_sum(bw, Vg_t):
+    """``Σ_k bw[t, k]·Vg_t[t, k, :]`` -> [TN, r] f32, the b-side
+    accumulation shared by the three kernels below.  ``bw`` rides as
+    [TN, 1, WC]: the chip's compiler refuses a ``dot_general`` whose left
+    operand has only batch and contracting dimensions (no
+    ``lhs_non_contracting_dims`` to parse), so it gets a unit free one."""
+    return jax.lax.dot_general(
+        bw[:, None, :], Vg_t,
+        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )[:, 0, :]
+
+
+def _weighted_ridge(count, reg, wdt):
+    """``reg · count`` as the reference builders compute it: in the weight
+    dtype ``wdt``.  At f32 that is the plain product.  Below f32 the
+    rounding is the explicit ``reduce_precision`` op, which the Pallas TPU
+    lowering does not implement — a bf16 fused solve is refused by the
+    chip's compiler with that message."""
+    reg_w = jnp.asarray(reg, wdt).astype(jnp.float32)
+    if jnp.dtype(wdt) == jnp.float32:
+        return count * reg_w
+    fi = jnp.finfo(wdt)
+    return jax.lax.reduce_precision(
+        jax.lax.reduce_precision(count, fi.nexp, fi.nmant) * reg_w,
+        fi.nexp, fi.nmant)
+
+
 def _gather_gram_kernel(cols_ref, aw_ref, bw_ref, V_hbm, A_ref, b_ref,
                         Vg, S, bacc, sem, *, n_wc, two_sided):
     """One (row-tile, width-chunk) grid step.
@@ -125,11 +153,7 @@ def _gather_gram_kernel(cols_ref, aw_ref, bw_ref, V_hbm, A_ref, b_ref,
         dimension_numbers=(((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )
-    bacc[:] = bacc[:] + jax.lax.dot_general(
-        bw_ref[:], Vg_t,
-        dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
+    bacc[:] = bacc[:] + _weighted_row_sum(bw_ref[:], Vg_t)
 
     @pl.when(j == n_wc - 1)
     def _emit():
@@ -206,7 +230,7 @@ def gather_gram(V, cols, aw, bw, *, two_sided, interpret=False):
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((tn, wc), lambda i, j: (i, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((tn, r_pad, r_pad), lambda i, j: (i, 0, 0),
@@ -323,11 +347,7 @@ def _gather_solve_kernel(cols_ref, aw_ref, bw_ref, cw_ref, YtY_ref, V_hbm,
         dimension_numbers=(((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )
-    bacc[:] = bacc[:] + jax.lax.dot_general(
-        bw_ref[:], Vg_t,
-        dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
+    bacc[:] = bacc[:] + _weighted_row_sum(bw_ref[:], Vg_t)
     cnt[:] = cnt[:] + jnp.sum(
         cw_ref[:], axis=1).astype(jnp.float32)[:, None]  # lane-uniform
 
@@ -339,17 +359,11 @@ def _gather_solve_kernel(cols_ref, aw_ref, bw_ref, cw_ref, YtY_ref, V_hbm,
         kk = jax.lax.broadcasted_iota(jnp.int32, (tn, r, r), 2)
         diag = ii == kk
         c3 = cnt[:][:, None, :]                       # [TN, 1, r] broadcast
-        # the reference builders compute ``reg * count`` in the weight
-        # dtype, so a bf16 run's ridge is bf16-rounded; ``.astype`` pairs
-        # get elided inside a jitted kernel (XLA excess precision), so the
-        # rounding must be the explicit reduce_precision op — identity at
-        # f32 (nmant=23), bf16-RN otherwise.  Without it the fused diagonal
+        # a bf16 run's ridge is bf16-rounded; ``.astype`` pairs get
+        # elided inside a jitted kernel (XLA excess precision), hence the
+        # explicit op in _weighted_ridge.  Without it the fused diagonal
         # sits ~0.4% of λ·n off the unfused path's at bf16.
-        fi = jnp.finfo(cw_ref.dtype)
-        reg_w = jnp.asarray(reg, cw_ref.dtype).astype(jnp.float32)
-        ridge = jax.lax.reduce_precision(
-            jax.lax.reduce_precision(c3, fi.nexp, fi.nmant) * reg_w,
-            fi.nexp, fi.nmant)
+        ridge = _weighted_ridge(c3, reg, cw_ref.dtype)
         A = S[:] + YtY_ref[:][None].astype(jnp.float32)
         A = jnp.where(diag, A + ridge + jitter, A)
         # empty rows (count == 0): A := I so the factorization stays
@@ -457,7 +471,7 @@ def gather_solve(V, cols, aw, bw, cw, YtY=None, *, two_sided, reg,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((r_pad, r_pad), lambda i, j: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((tn, r_pad), lambda i, j: (i, 0),
                                memory_space=pltpu.VMEM),
@@ -653,11 +667,7 @@ def _gather_solve_ring_kernel(cols_ref, aw_ref, bw_ref, cw_ref, YtY_ref,
         dimension_numbers=(((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )
-    bacc[:] = bacc[:] + jax.lax.dot_general(
-        bw_ref[0], Vg_t,
-        dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
+    bacc[:] = bacc[:] + _weighted_row_sum(bw_ref[0], Vg_t)
     cnt[:] = cnt[:] + jnp.sum(
         cw_ref[0], axis=1).astype(jnp.float32)[:, None]  # lane-uniform
 
@@ -689,14 +699,7 @@ def _gather_solve_ring_kernel(cols_ref, aw_ref, bw_ref, cw_ref, YtY_ref,
         kk = jax.lax.broadcasted_iota(jnp.int32, (tn, r, r), 2)
         diag = ii == kk
         c3 = cnt[:][:, None, :]                       # [TN, 1, r] broadcast
-        # same explicit weight-dtype rounding as _gather_solve_kernel —
-        # see the comment there (bitwise ridge parity with the reference
-        # builders at bf16)
-        fi = jnp.finfo(cw_ref.dtype)
-        reg_w = jnp.asarray(reg, cw_ref.dtype).astype(jnp.float32)
-        ridge = jax.lax.reduce_precision(
-            jax.lax.reduce_precision(c3, fi.nexp, fi.nmant) * reg_w,
-            fi.nexp, fi.nmant)
+        ridge = _weighted_ridge(c3, reg, cw_ref.dtype)
         A = S[:] + YtY_ref[:][None].astype(jnp.float32)
         A = jnp.where(diag, A + ridge + jitter, A)
         A = jnp.where(c3 <= 0.0, jnp.where(diag, 1.0 + jitter, 0.0), A)
@@ -794,14 +797,14 @@ def gather_solve_ring(V_shard, cols, aw, bw, cw, YtY=None, *, two_sided,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((r_pad, r_pad), lambda i, t, j: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((tn, r_pad), lambda i, t, j: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_pad, r_pad), jnp.float32),
         scratch_shapes=[
-            pltpu.ANY((per, r_pad), V_shard.dtype),   # buf0 (HBM landing)
-            pltpu.ANY((per, r_pad), V_shard.dtype),   # buf1
+            pl.ANY((per, r_pad), V_shard.dtype),   # buf0 (HBM landing)
+            pl.ANY((per, r_pad), V_shard.dtype),   # buf1
             pltpu.VMEM((tn, wc, r_pad), V_shard.dtype),
             pltpu.VMEM((tn, r_pad, r_pad), jnp.float32),
             pltpu.VMEM((tn, r_pad, r_pad), jnp.float32),
@@ -826,7 +829,7 @@ def gather_solve_ring(V_shard, cols, aw, bw, cw, YtY=None, *, two_sided,
             transcendentals=n_pad * r_pad,
         ),
         compiler_params=(
-            pltpu.TPUCompilerParams(collective_id=_RING_COLLECTIVE_ID)
+            pltpu.CompilerParams(collective_id=_RING_COLLECTIVE_ID)
             if sync else None),
         interpret=interpret,
     )(cols_p, aw_p, bw_p, cw_p, YtY_p, V_p)
@@ -952,7 +955,7 @@ def faster_than_einsum(rank=128, compute_dtype="float32", n=2048, w=256,
         from tpu_als.ops.solve import normal_eq_explicit
 
         if not available(rank, cdt):
-            return False
+            return False, "kernel not available"
         dt = jnp.dtype(cdt)
         rng = np.random.default_rng(0)
         N = 4 * n
@@ -979,7 +982,11 @@ def faster_than_einsum(rank=128, compute_dtype="float32", n=2048, w=256,
                 t.append(time.perf_counter() - t0)
             return min(t)
 
-        return best(fused) < best(einsum)
+        tf, te = best(fused), best(einsum)
+        return tf < te, (
+            f"{'won' if tf < te else 'lost'} the timing probe: fused "
+            f"{tf * 1e3:.3f} ms vs einsum {te * 1e3:.3f} ms "
+            f"(min of {reps}, n={n}, w={w})")
 
     return probe_kernel(_FASTER, ("speed", r_pad, cdt, n, w), probe)
 
@@ -1062,7 +1069,7 @@ def solve_faster_than_unfused(rank=128, compute_dtype="float32", n=2048,
         from tpu_als.ops.solve import normal_eq_explicit, solve_spd
 
         if not solve_available(rank, cdt):
-            return False
+            return False, "kernel not available"
         dt = jnp.dtype(cdt)
         rng = np.random.default_rng(0)
         N = 4 * n
@@ -1095,7 +1102,11 @@ def solve_faster_than_unfused(rank=128, compute_dtype="float32", n=2048,
                 t.append(time.perf_counter() - t0)
             return min(t)
 
-        return best(fused) < best(unfused)
+        tf, tu = best(fused), best(unfused)
+        return tf < tu, (
+            f"{'won' if tf < tu else 'lost'} the timing probe: fused "
+            f"{tf * 1e3:.3f} ms vs unfused {tu * 1e3:.3f} ms "
+            f"(min of {reps}, n={n}, w={w})")
 
     return probe_kernel(_SOLVE_FASTER, ("speed", r_pad, cdt, n, w), probe)
 
@@ -1137,7 +1148,7 @@ def ring_available(rank=128, compute_dtype="float32", n_shards=None):
         from tpu_als.parallel.mesh import shard_map
 
         if jax.device_count() < n_shards:
-            return False
+            return False, f"{jax.device_count()} devices < {n_shards} shards"
         S = n_shards
         ax = "ring_probe"
         mesh = Mesh(np.array(jax.devices()[:S]), (ax,))

@@ -146,7 +146,7 @@ def topk_scores_pallas(U, V, item_valid, k, tile_u=256, tile_i=512,
         in_specs=[
             pl.BlockSpec((tile_u, r_pad), lambda i, j: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, tile_i), lambda i, j: (0, j),
                          memory_space=pltpu.VMEM),
         ],
@@ -403,7 +403,7 @@ def topk_merge_ring(U, V_loc, item_valid_loc, k, *, axis_name=None,
         in_specs=[
             pl.BlockSpec((tile_u, r_pad), lambda i, p: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             # hop/merge phases revisit the last tile's block (clamped
             # index map) — only scoring phases read it
             pl.BlockSpec((1, tile_i),
@@ -442,7 +442,7 @@ def topk_merge_ring(U, V_loc, item_valid_loc, k, *, axis_name=None,
             transcendentals=0,
         ),
         compiler_params=(
-            pltpu.TPUCompilerParams(collective_id=_MERGE_COLLECTIVE_ID)
+            pltpu.CompilerParams(collective_id=_MERGE_COLLECTIVE_ID)
             if sync else None),
         interpret=interpret,
     )(Up, Vp, validp)
@@ -530,7 +530,7 @@ def merge_ring_available(rank=128, k=10, n_shards=None):
         from tpu_als.parallel.mesh import shard_map
 
         if jax.device_count() < n_shards:
-            return False
+            return False, f"{jax.device_count()} devices < {n_shards} shards"
         S = n_shards
         ax = "merge_probe"
         mesh = Mesh(np.array(jax.devices()[:S]), (ax,))

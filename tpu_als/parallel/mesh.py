@@ -27,18 +27,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: F401
 
 AXIS = "d"
 
-# jax moved shard_map from jax.experimental into the top-level namespace
-# (and renamed check_rep -> check_vma on the way); resolve whichever this
-# jax has so trainer/serve import on both sides of the move (one
-# definition — both consumers alias this)
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+# one definition — trainer, serve and the kernel probes alias this
+shard_map = jax.shard_map
 
 
 def _default_slice_of(device):

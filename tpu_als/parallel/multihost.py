@@ -69,7 +69,7 @@ def init_distributed(coordinator_address=None, num_processes=None,
 
     coordinator_address = (coordinator_address
                            or os.environ.get("JAX_COORDINATOR_ADDRESS"))
-    if coordinator_address and _already_initialized():
+    if coordinator_address and jax.distributed.is_initialized():
         # idempotent: a launcher (or test worker) may have rendezvoused
         # before handing control to code that also calls this — a second
         # jax.distributed.initialize would raise (the backend is up)
@@ -79,7 +79,7 @@ def init_distributed(coordinator_address=None, num_processes=None,
         # the fault point lives INSIDE the retried closure so chaos
         # tests exercise the retry loop even on the single-process path
         faults.check("multihost.init")
-        if coordinator_address and not _already_initialized():
+        if coordinator_address and not jax.distributed.is_initialized():
             kw = {"coordinator_address": coordinator_address}
             np_ = num_processes or os.environ.get("JAX_NUM_PROCESSES")
             pid = process_id if process_id is not None else \
@@ -168,19 +168,6 @@ def _ragged_allgather(arr, fill=0):
     g = np.asarray(mhu.process_allgather(buf))
     keep = np.arange(pad)[None, :] < lens[:, None]
     return g[keep]
-
-
-def _already_initialized():
-    """True when this process has an active jax.distributed client."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:  # fallback for jax versions without the public probe
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None
-    except Exception:
-        return False
 
 
 def train_multihost(u, i, r, num_users, num_items, cfg, mesh=None,

@@ -36,7 +36,9 @@ two scale-out strategies the trainer has (``parallel/trainer.py``):
 
 Tie-breaking note: with equal scores the selected index can differ
 between ``all_gather`` and ``ring`` (merge order is shard-rotation
-order, which differs per device); scores are always identical.
+order, which differs per device); scores agree to f32
+reduction-order rounding (the per-strategy GEMM shapes differ — the
+``SCORE_ULPS`` contract of tpu_als/serving/index.py).
 ``merge_ring`` is stronger: its stable in-kernel merge reproduces the
 single-device ``chunked_topk_scores`` tie-break bitwise (ids included)
 whenever the score values themselves agree across contraction shapes.

@@ -152,7 +152,8 @@ def model_seconds(config, rank, n, w):
     n_pad = -(-int(n) // tn) * tn
     db = 2 if "bfloat16" in str(config["dtype"]) else 4
     by = rl.fused_solve_kernel_bytes(n_pad * w_pad, n_pad, r_pad, db)
-    return by / (rl.V5E_HBM_GBPS * 1e9)
+    hbm_gbps = rl.device_peaks(rl.MODELED_DEVICE_KIND)["hbm_gbps"]
+    return by / (hbm_gbps * 1e9)
 
 
 def make_timer(rank, compute_dtype, *, n=256, w=64, k=3, seed=0,

@@ -48,16 +48,39 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-# v5e public per-chip specs: 819 GB/s HBM BW, 197 bf16 TFLOP/s
-# (f32 ~half).  ICI: 1600 Gbps aggregate per chip ≈ 200 GB/s.
-V5E_HBM_GBPS = 819.0
-V5E_ICI_GBPS = 200.0
-V5E_BF16_PEAK_FLOPS = 197e12
-V5E_F32_PEAK_FLOPS = 98.5e12
+# Published per-chip peaks, keyed by the ``device_kind`` JAX reports.
+# THE one table: the model below, bench.py's advisory MFU and the
+# autotuner's floor all read it.  A device that is not here is an error
+# (:func:`device_peaks`), not a v5e.  f32 is taken as half the bf16 MXU
+# rate; ICI 1,600 Gbit/s = 200 GB/s.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "name": "v5e", "bf16_flops": 197e12, "f32_flops": 98.5e12,
+        "hbm_gbps": 819.0, "ici_gbps": 200.0,
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip",
+    },
+}
+# the device the closed-form model describes when none is named
+MODELED_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_peaks(device_kind):
+    """The peaks row of ``device_kind``; raises for a device with no
+    published row instead of rating it against another chip's peak."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); add a row with its source to "
+            "tpu_als.perf.roofline.DEVICE_PEAKS") from None
+
 
 # THE headline config (BASELINE.md row 2): ML-25M, rank 128, implicit
 # alpha=40, f32, single v5e core; padding_waste and the measured
-# s/iter from sweep_logs/headline_f32.out (2026-07-31).
+# s/iter are the 2026-07-31 chip run's (an earlier installation, JAX
+# 0.4.37; PERF.md holds what has been measured since).
 HEADLINE = dict(n_users=162_541, n_items=59_047, nnz=25_000_095,
                 rank=128, dtype="float32", implicit=True,
                 padding_waste=1.514, devices=1)
@@ -247,7 +270,7 @@ def roofline(n_users, n_items, nnz, rank, *, dtype="float32",
              user_counts=None, item_counts=None,
              min_width=8, chunk_elems=1 << 19, width_growth=2.0,
              ne_path="einsum",
-             hbm_gbps=V5E_HBM_GBPS, ici_gbps=V5E_ICI_GBPS,
+             device_kind=MODELED_DEVICE_KIND, hbm_gbps=None, ici_gbps=None,
              measured_s_per_iter=None):
     """Analytical per-stage roofline for one full ALS iteration.
 
@@ -284,7 +307,10 @@ def roofline(n_users, n_items, nnz, rank, *, dtype="float32",
     D = max(1, int(devices))
     r = int(rank)
     db = _dtype_bytes(dtype)
-    peak = V5E_F32_PEAK_FLOPS if db == 4 else V5E_BF16_PEAK_FLOPS
+    peaks = device_peaks(device_kind)
+    peak = peaks["f32_flops"] if db == 4 else peaks["bf16_flops"]
+    hbm_gbps = peaks["hbm_gbps"] if hbm_gbps is None else hbm_gbps
+    ici_gbps = peaks["ici_gbps"] if ici_gbps is None else ici_gbps
     hbm = hbm_gbps * 1e9
     ici = ici_gbps * 1e9
     if ne_path not in ("einsum", "gather_fused", "gather_fused_solve"):
@@ -420,6 +446,7 @@ def roofline(n_users, n_items, nnz, rank, *, dtype="float32",
             "strategy": strategy,
             "tiles_user": int(tiles_user), "tiles_item": int(tiles_item),
             "hbm_gbps": float(hbm_gbps), "ici_gbps": float(ici_gbps),
+            "device_kind": device_kind, "device_name": peaks["name"],
         },
         "stages": [
             {"name": s.name, "bytes": int(s.bytes), "flops": int(s.flops),
@@ -465,7 +492,8 @@ def render(report):
          f" ({c.get('padding_waste_source', 'explicit')})"
          f" ne={c.get('ne_path', 'einsum')} D={c['devices']}"
          + (f" strategy={c['strategy']}" if c["strategy"] else "")),
-        f"(HBM {c['hbm_gbps']} GB/s, ICI {c['ici_gbps']} GB/s, v5e)",
+        (f"(HBM {c['hbm_gbps']} GB/s, ICI {c['ici_gbps']} GB/s, "
+         f"{c.get('device_name', 'v5e')})"),
         "",
         f"{'stage':<16}{'MB moved':>12}{'GFLOP':>10}"
         f"{'bytes ms':>10}{'flops ms':>10}{'bound':>7}",

@@ -19,7 +19,6 @@ from tpu_als.plan.planner import (  # noqa: F401
     invalidate_kernel_config,
     mode,
     plan_key,
-    probe_budget_s,
     resolve_execution_plan,
     resolve_gather_strategy,
     resolve_kernel_config,

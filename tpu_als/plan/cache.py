@@ -16,10 +16,8 @@ is moved into a ``.corrupt/`` sibling (typed :class:`PlanCacheCorrupt`)
 rather than crashed on or silently trusted — the planner treats a
 quarantined entry as a cache miss and reprobes.
 
-Deliberately jax-free: ``bench.py`` consults
-:func:`suggested_probe_budget` via a standalone importlib load before
-it is allowed to import jax (its subprocess backend probe must run
-first), and ``scripts/plan_smoke.sh`` inspects entries the same way.
+Deliberately jax-free: ``scripts/plan_smoke.sh`` inspects entries through
+a standalone importlib load.
 """
 
 from __future__ import annotations
@@ -207,31 +205,10 @@ def clear(root=None):
 
 
 def _jax_version():
-    """jax's installed version without importing jax (bench.py calls this
-    before its subprocess backend probe is allowed to touch jax)."""
+    """jax's installed version without importing jax (this module stays
+    jax-free)."""
     try:
         from importlib import metadata
         return metadata.version("jax")
     except Exception:
         return "unknown"
-
-
-def suggested_probe_budget(default_s, root=None):
-    """Bench probe-budget suggestion: when the cache holds at least one
-    valid entry banked under the currently installed jax version, the
-    winning paths are known and compile immediately, so the TPU-ready
-    probe envelope shrinks (to ``max(default/5, 120)`` seconds, capped by
-    the default).  Disarmed, empty, or version-mismatched caches return
-    the default unchanged.  jax-free by construction."""
-    root = root if root is not None else cache_dir()
-    if root is None:
-        return float(default_s), "planner off"
-    ver = _jax_version()
-    warm = [p for p, doc in list_entries(root)
-            if isinstance(doc, dict)
-            and doc.get("plan_key", {}).get("jax_version") == ver]
-    if not warm:
-        return float(default_s), "no warm plan entries"
-    budget = min(float(default_s), max(float(default_s) / 5.0, 120.0))
-    return budget, (f"{len(warm)} warm plan entr"
-                    f"{'y' if len(warm) == 1 else 'ies'} for jax {ver}")

@@ -2,8 +2,8 @@
 
 One resolve discipline for every dispatch decision in the stack — solve
 backend, NE build path, top-k backend, gather strategy, serving bucket
-plan, bench probe budget: **the roofline model proposes, a probe
-confirms, and the verdict persists.**
+plan: **the roofline model proposes, a probe confirms, and the verdict
+persists.**
 
 Mechanics per component:
 
@@ -583,13 +583,6 @@ def resolve_tenant_plan(*, rank, n_users=None, n_items=None,
     }
 
 
-def probe_budget_s(default_s):
-    """Bench probe-budget suggestion; see
-    ``plan.cache.suggested_probe_budget`` (bench.py loads that module
-    standalone to stay jax-free)."""
-    return plan_cache.suggested_probe_budget(default_s)
-
-
 def clear():
     """Drop the on-disk entries AND the in-process probe registry (the
     ``plan clear`` CLI verb).  Returns the number of files removed."""
@@ -612,8 +605,6 @@ class ExecutionPlan:
     topk_backend: str | None
     gather_strategy: str | None
     serving_buckets: tuple
-    probe_budget_s: float
-    probe_budget_reason: str
     notes: dict = field(default_factory=dict)
     kernel_config: dict | None = None  # tuned knobs (None = hand-picked)
 
@@ -625,8 +616,6 @@ class ExecutionPlan:
             "topk_backend": self.topk_backend,
             "gather_strategy": self.gather_strategy,
             "serving_buckets": list(self.serving_buckets),
-            "probe_budget_s": self.probe_budget_s,
-            "probe_budget_reason": self.probe_budget_reason,
             "kernel_config": self.kernel_config,
         }
 
@@ -634,8 +623,7 @@ class ExecutionPlan:
 def resolve_execution_plan(*, rank=128, compute_dtype="float32",
                            solve_backend="auto", cg_iters=0,
                            cg_mode="dense", nonnegative=False, k=10,
-                           n_users=None, n_items=None, n_devices=1,
-                           default_probe_budget_s=600.0):
+                           n_users=None, n_items=None, n_devices=1):
     """Resolve the full plan for one configuration — the ``plan warm``
     entry point.  Every component goes through its real dispatch-site
     walk (``resolve_solve_path`` consults the planner itself), so
@@ -665,11 +653,9 @@ def resolve_execution_plan(*, rank=128, compute_dtype="float32",
     kcfg = (resolve_kernel_config(rank=int(rank),
                                   compute_dtype=compute_dtype)
             if armed() else None)
-    budget, why = plan_cache.suggested_probe_budget(default_probe_budget_s)
     return ExecutionPlan(
         key=plan_key(rank=int(rank), dtype=compute_dtype),
         solve=solve, topk_backend=topk, gather_strategy=gather,
-        serving_buckets=buckets, probe_budget_s=budget,
-        probe_budget_reason=why,
+        serving_buckets=buckets,
         notes={"mode": mode()},
         kernel_config=kcfg)

@@ -6,16 +6,13 @@ SURVEY.md §5.3); our JAX port has no task scheduler, so transient
 failures — a flaky DCN rendezvous, a blip on the checkpoint filesystem,
 a slow NFS read — must be retried at the call site.  This module is the
 ONE implementation every site uses (multihost init, checkpoint save/load,
-stream chunk reads, bench.py's backend probe), so retry semantics and
-observability are identical everywhere.
+stream chunk reads), so retry semantics and observability are identical
+everywhere.
 
-Deliberately stdlib-only and jax-free: bench.py loads this file
-standalone (``importlib`` on the file path) BEFORE anything imports jax,
-because its backend probe must run in a subprocess with the parent
-process still jax-clean.  Obs events are emitted only when
-``tpu_als.obs`` is already in ``sys.modules`` — true for every in-library
-call site, false for the standalone bench load (which passes its own
-``on_attempt`` hook instead).
+Deliberately stdlib-only and jax-free, loadable standalone.  Obs events
+are emitted only when ``tpu_als.obs`` is already in ``sys.modules`` —
+true for every in-library call site; a standalone load passes its own
+``on_attempt`` hook instead.
 """
 
 from __future__ import annotations
@@ -52,8 +49,7 @@ class RetryPolicy:
     ``max_attempts``: total tries (1 = no retry).
     ``base_delay`` / ``factor`` / ``max_delay``: attempt k (0-based)
     sleeps ``min(max_delay, base_delay * factor**k)`` before attempt
-    k+1, scaled by the jitter draw.  ``factor=1`` gives the constant
-    wait bench.py's probe historically used.
+    k+1, scaled by the jitter draw.  ``factor=1`` gives a constant wait.
     ``jitter``: fraction of the delay drawn uniformly in
     ``[1-jitter, 1+jitter]`` from a dedicated ``random.Random(seed)`` —
     deterministic per policy instance, never global RNG state.
@@ -116,8 +112,8 @@ class RetryPolicy:
 
 
 def _call_with_timeout(fn, args, kwargs, seconds, what):
-    """Run ``fn`` on a daemon thread, bounding THIS caller's wait — the
-    bench.py hang-isolation idiom, shared by every timed retry."""
+    """Run ``fn`` on a daemon thread, bounding THIS caller's wait —
+    shared by every timed retry."""
     box = {}
 
     def run():
@@ -139,7 +135,7 @@ def _call_with_timeout(fn, args, kwargs, seconds, what):
 
 def _obs():
     """tpu_als.obs, but ONLY if it is already imported (keeps this
-    module loadable from jax-free contexts like bench.py)."""
+    module loadable from jax-free contexts)."""
     return sys.modules.get("tpu_als.obs")
 
 
@@ -148,8 +144,7 @@ def retry_call(fn, *args, policy=None, what=None, on_attempt=None,
     """Call ``fn(*args, **kwargs)`` under ``policy``.
 
     On each FAILED attempt emits a ``retry_attempt`` obs event and calls
-    ``on_attempt(info_dict)`` if given (bench.py builds its provenance
-    ``bench_retry`` JSONL rows from this hook).  When the budget is
+    ``on_attempt(info_dict)`` if given.  When the budget is
     exhausted emits ``retry_exhausted`` and raises
     :class:`RetryExhausted` from the last error.
     """

@@ -149,9 +149,16 @@ def _submit_open_loop(engine, U, qps, duration_s, rng, counts):
 def _cli_subprocess(args, env_extra=None):
     """Run the tpu_als CLI in a child process (the preempt scenarios
     need a real exit status).  The repo root rides PYTHONPATH so the
-    child resolves the same checkout the parent runs from."""
+    child resolves the same checkout the parent runs from.
+
+    The child is pinned to the CPU on purpose: a chip belongs to one
+    process at a time, and the parent holds a serving engine on the
+    device while the child trains — on a TPU host an unpinned child
+    would fail or hang waiting for it.  Its problem is
+    ``synthetic:48x24x600``-sized; nothing about it needs the chip."""
     env = dict(os.environ)
     env.pop("TPU_ALS_PREEMPT_AT", None)   # only explicit knobs apply
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     if env_extra:
         env.update(env_extra)

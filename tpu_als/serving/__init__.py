@@ -9,8 +9,8 @@ SLO instrumentation:
   bucketed fixed-shape batches, per-request deadlines, typed
   :class:`Overloaded` load shedding.
 - :mod:`tpu_als.serving.index` — int8 symmetric-quantized candidate
-  index with exact f32 rescore (bitwise-identical top-k to the exact
-  kernel; property-tested).
+  index with exact f32 rescore (top-k within ``SCORE_ULPS`` of the
+  exact kernel; property-tested).
 - :mod:`tpu_als.serving.engine` — the steady-state loop wiring batcher
   -> scorer -> response, with atomic model publishes, stale-index
   fallback, and the ``serving.score`` / ``serving.publish`` fault

@@ -61,10 +61,10 @@ tickets.  The pieces the rest of the stack plugs into:
   exact fallback re-uploads per batch — rare by construction), so the
   single-device-copy the fabric exists to avoid never reappears here.
 - **Host throughput.**  The request path stages each micro-batch into
-  one reusable per-bucket ``[B, rank+2]`` array (query rows | bitcast
-  ids | row-mask) and uploads it as ONE transfer — no per-batch
+  one reusable per-bucket ``[B, rank+2]`` int32 array (query rows' f32
+  bits | ids | row-mask) and uploads it as ONE transfer — no per-batch
   id/row/mask re-uploads (the payload is the only host→device traffic).
-  Responses come back packed ``[B, 2k]`` (scores | bitcast indices) in
+  Responses come back packed ``[B, 2k]`` (scores' f32 bits | indices) in
   one bulk transfer, and tickets complete with numpy VIEWS sliced from
   that buffer — zero per-ticket copies; the buffer snapshots an
   immutable device array, so the views stay valid indefinitely.
@@ -143,26 +143,29 @@ def _select_rows(U, ids, rows, rowmask):
 
 @jax.jit
 def _select_packed(U, packed):
-    """:func:`_select_rows` over the single-upload staging layout:
-    ``packed[:, :rank]`` fold-in rows, ``packed[:, rank]`` bitcast int32
-    user ids, ``packed[:, rank+1]`` the row-mask — one host→device
-    transfer carries all three."""
+    """:func:`_select_rows` over the single-upload staging layout, an
+    INT32 array: ``packed[:, :rank]`` the fold-in rows' f32 bits,
+    ``packed[:, rank]`` user ids, ``packed[:, rank+1]`` the row-mask —
+    one host→device transfer carries all three.  Floats ride as integer
+    bits, never ids as float bits: a small int viewed as f32 is a
+    subnormal, and the TPU flushes subnormals to zero on any float op —
+    every id would arrive as 0."""
     rank = U.shape[1]
-    ids = jax.lax.bitcast_convert_type(packed[:, rank], jnp.int32)
-    ids = jnp.clip(ids, 0, U.shape[0] - 1)
-    rowmask = packed[:, rank + 1] != 0.0
-    return jnp.where(rowmask[:, None], packed[:, :rank],
-                     jnp.take(U, ids, axis=0))
+    rows = jax.lax.bitcast_convert_type(packed[:, :rank], jnp.float32)
+    ids = jnp.clip(packed[:, rank], 0, U.shape[0] - 1)
+    rowmask = packed[:, rank + 1] != 0
+    return jnp.where(rowmask[:, None], rows, jnp.take(U, ids, axis=0))
 
 
 @jax.jit
 def _pack_response(s, ix):
-    """Pack ``(scores, indices)`` as ``[B, 2k]`` f32 (indices bitcast)
-    so the response comes back in ONE bulk device→host transfer;
+    """Pack ``(scores, indices)`` as ``[B, 2k]`` int32 (the scores' f32
+    bits, see :func:`_select_packed` for why not the other way round) so
+    the response comes back in ONE bulk device→host transfer;
     ``serve_batch`` slices numpy views back out per ticket."""
     return jnp.concatenate(
-        [s, jax.lax.bitcast_convert_type(ix.astype(jnp.int32),
-                                         jnp.float32)], axis=1)
+        [jax.lax.bitcast_convert_type(s.astype(jnp.float32), jnp.int32),
+         ix.astype(jnp.int32)], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "item_chunk"))
@@ -585,7 +588,7 @@ class ServingEngine:
         self._pinned.clear()
         backend = self._backend or "local"
         for B in self.batcher.buckets:
-            proto = jnp.zeros((B, m.rank + 2), jnp.float32)
+            proto = jnp.zeros((B, m.rank + 2), jnp.int32)
             idx = m.index
             if backend == "merge_ring" and m.Vs is not None:
                 s, ix = self._merge_fn(B, m)(
@@ -642,7 +645,7 @@ class ServingEngine:
             dummy = idx.with_updates(
                 rows, np.ascontiguousarray(Vh[rows]), seq=idx.seq)
             for B in self.batcher.buckets:
-                proto = jnp.zeros((B, m.rank + 2), jnp.float32)
+                proto = jnp.zeros((B, m.rank + 2), jnp.int32)
                 s, ix = dummy.topk(_select_packed(m.U, proto), self.k)
                 _pack_response(s, ix).block_until_ready()
             d <<= 1
@@ -802,26 +805,25 @@ class ServingEngine:
         m = self._model
         n = len(live)
         B = bucket_for(n, self.batcher.buckets)
-        # single-upload staging: one reusable [B, rank+2] array per
-        # bucket carries rows, bitcast ids and the row-mask — the
-        # payload is the only host→device transfer this batch makes
+        # single-upload staging: one reusable int32 [B, rank+2] array
+        # per bucket carries the rows' f32 bits, ids and the row-mask —
+        # the payload is the only host→device transfer this batch makes
         st = self._stage.get(B)
         if st is None or st.shape[1] != m.rank + 2:
-            st = np.zeros((B, m.rank + 2), dtype=np.float32)
+            st = np.zeros((B, m.rank + 2), dtype=np.int32)
             self._stage[B] = st
-        idcol = st[:, m.rank].view(np.int32)   # same-itemsize view
+        rows = st[:, :m.rank].view(np.float32)  # same-itemsize view
         for j, t in enumerate(live):
             if isinstance(t.payload, (int, np.integer)):
-                idcol[j] = t.payload
-                st[j, m.rank + 1] = 0.0
+                st[j, m.rank] = t.payload
+                st[j, m.rank + 1] = 0
             else:
-                st[j, :m.rank] = t.payload
-                st[j, m.rank + 1] = 1.0
+                rows[j] = t.payload
+                st[j, m.rank + 1] = 1
         # pad slots: stale ids/masks from the previous batch are enough
         # to change which (unread) pad rows get scored — zero them; the
         # stale row payloads themselves are unread either way
-        idcol[n:] = 0
-        st[n:, m.rank + 1] = 0.0
+        st[n:, m.rank:] = 0
         obs.histogram("serving.batch_rows", n, **self._labels)
 
         backend = self._backend or "local"
@@ -877,8 +879,8 @@ class ServingEngine:
         # device array — the views stay valid after slot reuse)
         resp = np.asarray(resp_dev)
         kw = resp.shape[1] // 2
-        scores = resp[:, :kw]
-        indices = resp[:, kw:].view(np.int32)  # same-itemsize view
+        scores = resp[:, :kw].view(np.float32)  # same-itemsize view
+        indices = resp[:, kw:]
         score_s = time.perf_counter() - t0
         obs.histogram("serving.score_seconds", score_s, path=path,
                       **self._labels)

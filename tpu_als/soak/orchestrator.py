@@ -70,9 +70,10 @@ _CHILD_DATA = "synthetic:48x24x600"
 def _cli_subprocess(args, env_extra=None):
     """A real tpu_als CLI child (preempt/device-loss need real exit
     statuses and their own fault env) — same contract as the scenario
-    library's helper."""
+    library's helper, the CPU pin included."""
     env = dict(os.environ)
     env.pop("TPU_ALS_PREEMPT_AT", None)
+    env["JAX_PLATFORMS"] = "cpu"    # see scenario.library._cli_subprocess
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     if env_extra:
         env.update(env_extra)

@@ -258,9 +258,13 @@ def test_flight_recorder_scenario_passes(_fresh):
     assert result["facts"]["complete_breach_records"] >= 8
     assert result["facts"]["hard_failures"] == 0
     records = [e for e in reg._events if e["type"] == "flight_record"]
-    assert len(records) >= 8
-    for r in records:
-        assert r["trigger"] == "slo_breach"
+    assert all(r["trigger"] == "slo_breach" for r in records)
+    # the breach dumps the engine's per-batch records too
+    requests = [r for r in records if "admission" in r["spans"]]
+    assert len(requests) >= 8
+    assert {r["batch"] for r in requests} <= {
+        r["batch"] for r in records if r not in requests}
+    for r in requests:
         assert all(r["spans"][k] is not None for k in
                    ("admission", "queue_wait", "score", "respond"))
 

@@ -1,0 +1,295 @@
+"""The serving engine's batch cycle as spans on the profiler's clock, the
+per-batch flight record that keeps the same durations without a profiler,
+``Ticket.t_done``, and what the engine thread's instrumentation writes
+into the registry per batch (ISSUE 25)."""
+
+from __future__ import annotations
+
+import glob
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from tpu_als import obs
+from tpu_als.obs import tracing
+from tpu_als.obs.schema import SERVE_BATCH_SPAN_KEYS, SERVE_SPAN_KEYS
+from tpu_als.serving import MicroBatcher, ServingEngine
+from tpu_als.serving.batcher import Ticket
+
+PHASES = ("serve.batch.stage", "serve.batch.dispatch",
+          "serve.batch.readback", "serve.batch.complete")
+BATCHES = ((5, 8), (20, 32), (32, 32))      # (rows, the bucket they ride)
+
+
+def _engine(**kw):
+    rng = np.random.default_rng(0)
+    eng = ServingEngine(k=5, buckets=(8, 32), shortlist_k=32,
+                        max_wait_s=0.0, **kw)
+    # a catalog large enough that a batch takes milliseconds: the ring's
+    # clock reads are compared with the spans' to within one
+    eng.publish(rng.normal(size=(40, 16)).astype(np.float32),
+                rng.normal(size=(100_000, 16)).astype(np.float32))
+    return eng
+
+
+def _drain(eng, rows):
+    tickets = [eng.submit(j % 40) for j in range(rows)]
+    eng.serve_batch(eng.batcher.next_batch(timeout=1.0))
+    return tickets
+
+
+def _serve_spans(trace_dir):
+    """[(name, start_ns, dur_ns, stats)] of the ``serve.`` spans of every
+    host line of the trace, by start."""
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    spans = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    spans.append((ev.name, ev.start_ns, ev.duration_ns,
+                                  dict(ev.stats)))
+    return sorted(spans, key=lambda s: s[1])
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """Three batches driven through ``next_batch`` + ``serve_batch`` under
+    the profiler: (the trace's serve.* spans, the engine, the tickets of
+    each batch)."""
+    obs.reset()
+    eng = _engine()
+    for rows, _ in BATCHES:          # each bucket's program, compiled
+        _drain(eng, rows)
+    eng.flight.dump("warm")          # what follows is the traced batches'
+    eng.batch_flight.dump("warm")
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        tickets = [_drain(eng, rows) for rows, _ in BATCHES]
+    finally:
+        jax.profiler.stop_trace()
+    return _serve_spans(trace_dir), eng, tickets
+
+
+def test_every_span_of_the_cycle_once_per_batch(traced):
+    spans, _, _ = traced
+    names = [s[0] for s in spans]
+    for name in SERVE_BATCH_SPAN_KEYS:
+        if name != "serve.idle":     # the queue was never empty
+            assert names.count(name) == len(BATCHES), name
+    assert set(names) <= set(SERVE_BATCH_SPAN_KEYS)
+    whole = [s for s in spans if s[0] == "serve.batch"]
+    seqs = [s[3]["seq"] for s in whole]
+    assert seqs == list(range(seqs[0], seqs[0] + len(BATCHES)))
+    assert [(s[3]["rows"], s[3]["bucket"]) for s in whole] == list(BATCHES)
+    assert all(s[3]["path"] == "int8" for s in whole)
+    coalesce = [s for s in spans if s[0] == "serve.batch.coalesce"]
+    assert [s[3]["waiting"] for s in coalesce] == [r for r, _ in BATCHES]
+
+
+def test_phases_lie_inside_their_batch_disjoint_and_cover_it(traced):
+    spans, _, _ = traced
+    for _, b0, bdur, _ in (s for s in spans if s[0] == "serve.batch"):
+        inside = [s for s in spans
+                  if s[0] in PHASES and b0 <= s[1] < b0 + bdur]
+        assert [s[0] for s in inside] == list(PHASES)    # in this order
+        for (_, s0, d0, _), (_, s1, _, _) in zip(inside, inside[1:]):
+            assert s0 + d0 <= s1                         # disjoint
+        assert inside[-1][1] + inside[-1][2] <= b0 + bdur
+        assert sum(s[2] for s in inside) >= 0.95 * bdur
+    # the coalescing wait ends before its batch starts
+    for c, b in zip((s for s in spans if s[0] == "serve.batch.coalesce"),
+                    (s for s in spans if s[0] == "serve.batch")):
+        assert c[1] + c[2] <= b[1]
+
+
+def test_batch_ring_keeps_the_spans_durations(traced):
+    spans, eng, _ = traced
+    reg = obs.reset()
+    assert eng.batch_flight.dump("test") == len(BATCHES)
+    records = [e for e in reg._events if e["type"] == "flight_record"]
+    by_seq = {}
+    for name, _, dur, stats in spans:
+        if name == "serve.batch":
+            by_seq[stats["seq"]] = stats
+    for rec in records:
+        assert set(rec["spans"]) == set(SERVE_BATCH_SPAN_KEYS)
+        stats = by_seq[rec["batch"]]
+        assert (rec["rows"], rec["bucket"], rec["path"]) == (
+            stats["rows"], stats["bucket"], stats["path"])
+        assert rec["waiting"] == rec["rows"] and rec["t0"] > 0
+    # the ring's clock reads bracket the annotations: each duration is
+    # its span's plus the few microseconds the brackets cost
+    whole = [s for s in spans if s[0] == "serve.batch"]
+    for rec, (_, b0, bdur, _) in zip(records, whole):
+        for name in ("serve.batch",) + PHASES:
+            span_ns = next(s[2] for s in spans if s[0] == name
+                           and b0 <= s[1] < b0 + bdur)
+            assert rec["spans"][name] * 1e9 == pytest.approx(
+                span_ns, abs=1e6), name
+        assert sum(rec["spans"][p] for p in PHASES) == pytest.approx(
+            rec["spans"]["serve.batch"], abs=1e-6)
+    coalesce = [s for s in spans if s[0] == "serve.batch.coalesce"]
+    for rec, (_, _, dur, _) in zip(records, coalesce):
+        assert rec["spans"]["serve.batch.coalesce"] * 1e9 == pytest.approx(
+            dur, abs=1e6)
+
+
+def test_request_records_name_the_batch_they_rode(traced):
+    _, eng, tickets = traced
+    reg = obs.reset()
+    # the request ring holds the last 64 requests: all of the last batch
+    # (32) and of the one before (20)
+    eng.flight.dump("test")
+    records = [e for e in reg._events if e["type"] == "flight_record"]
+    assert all(set(r["spans"]) == set(SERVE_SPAN_KEYS) for r in records)
+    last = eng._batch_seq
+    assert [r["batch"] for r in records[-52:]] == [last - 1] * 20 + [last] * 32
+
+
+def test_ticket_t_done_orders_the_flight_records_latencies(traced):
+    _, eng, tickets = traced
+    for batch in tickets:
+        assert all(t.t_submit <= t.t_dequeue <= t.t_done for t in batch)
+        # completed in order: each answer is stamped as it is given
+        done = [t.t_done for t in batch]
+        assert done == sorted(done)
+    reg = obs.reset()
+    last = _drain(eng, 20)
+    eng.flight.dump("test")
+    records = [e for e in reg._events if e["type"] == "flight_record"][-20:]
+    for t, rec in zip(last, records):
+        assert rec["e2e_seconds"] == pytest.approx(t.t_done - t.t_submit)
+    # the last ticket of a batch was submitted later and answered later;
+    # its latency counts the completion loop before it, its ``respond``
+    # is the part of the latency after the scores were in hand
+    assert records[-1]["spans"]["respond"] >= records[0]["spans"]["respond"]
+    gap = (last[-1].t_done - last[0].t_done) - (last[-1].t_submit
+                                                - last[0].t_submit)
+    assert records[-1]["e2e_seconds"] - records[0]["e2e_seconds"] == \
+        pytest.approx(gap)
+    # what follows the submit stamp adds up to the latency, but for the
+    # batch's staging between the dequeue and the dispatch
+    for rec in records:
+        after = sum(rec["spans"][k] for k in ("queue_wait", "score",
+                                              "respond"))
+        assert after <= rec["e2e_seconds"] + 1e-9
+
+
+def test_a_failed_ticket_is_stamped_too():
+    t = Ticket(0, None, None)
+    assert t.t_done is None
+    t.fail(RuntimeError("x"))
+    assert t.t_done >= t.t_submit and t.done()
+
+
+@pytest.mark.parametrize("rows", [1, 20])
+def test_one_registry_event_per_batch_whatever_its_size(rows):
+    """The engine thread writes its phases as annotations and one ring
+    append: the only event a batch leaves in the registry's bounded list
+    is the ``queue_depth`` gauge's, as before, and the per-ticket
+    latencies reach their histograms whole."""
+    eng = _engine()
+    _drain(eng, rows)                # compiled
+    reg = obs.reset()
+    _drain(eng, rows)
+    assert [(e["type"], e.get("name")) for e in reg._events] == [
+        ("metric", "serving.queue_depth")]
+    hists = reg.snapshot()["histograms"]
+    assert hists["serving.enqueue_seconds"]["count"] == rows
+    assert hists["serving.e2e_seconds"]["count"] == rows
+    assert hists["serving.batch_rows"]["count"] == 1
+    assert hists['serving.score_seconds{path="int8"}']["count"] == 1
+    assert len(eng.batch_flight) == 2 and len(eng.flight) == 2 * rows
+
+
+def test_an_idle_engine_thread_says_so(traced):
+    """``serve.idle`` is the wait on an EMPTY queue (its seconds reach the
+    next batch's record); a timeout leaves no batch."""
+    del traced                       # after the module's traced run
+    b = MicroBatcher(buckets=(8,), max_wait_s=0.0)
+    assert b.next_batch(timeout=0.02) is None
+    b.submit(0)
+    assert len(b.next_batch(timeout=1.0)) == 1
+    idle_s, waiting, coalesce_s = b.last_wait
+    assert idle_s >= 0.02 and waiting == 1 and 0 <= coalesce_s < 0.01
+    b.submit(1)
+    b.next_batch(timeout=1.0)
+    assert b.last_wait[0] == 0.0     # counted once
+
+
+def test_histogram_many_is_histogram_in_one_call():
+    values = [1e-5, 3e-4, 3e-4, 0.02, 7.0]
+    one, many = obs.MetricsRegistry(), obs.MetricsRegistry()
+    for v in values:
+        one.histogram("serving.e2e_seconds", v, tenant="a")
+    many.histogram_many("serving.e2e_seconds", values, tenant="a")
+    assert many.prometheus_text() == one.prometheus_text()
+    assert many.snapshot() == one.snapshot()
+    with pytest.raises(KeyError):
+        many.histogram_many("serving.nonsense", values)
+    with pytest.raises(ValueError):
+        many.histogram_many("serving.e2e_seconds", values, shard=1)
+
+
+def test_obs_span_writes_t0_and_an_annotation_of_its_name(tmp_path):
+    reg = obs.reset()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    before = time.perf_counter()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.span("train.fit"):
+            with obs.span("train.iteration", iteration=3):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    inner, outer = [e for e in reg._events if e["type"] == "span"]
+    assert (inner["name"], inner["path"]) == ("train.iteration",
+                                              "train.fit/train.iteration")
+    assert before <= outer["t0"] <= inner["t0"] <= time.perf_counter()
+    assert inner["t0"] + inner["seconds"] <= outer["t0"] + outer[
+        "seconds"] + 1e-5
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    found = {ev.name: ev.duration_ns
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name in ("train.fit", "train.iteration")}
+    assert set(found) == {"train.fit", "train.iteration"}
+    assert found["train.iteration"] == pytest.approx(
+        inner["seconds"] * 1e9, abs=200e3)
+
+
+def test_serve_score_trace_span_names_its_batch():
+    obs.reset()
+    tracing.reset_trace_ids(seed=0)
+    eng = _engine()
+    with tracing.traced():
+        _drain(eng, 3)
+    reg = obs.default_registry()
+    scored = [e for e in reg._events if e["type"] == "trace_span"
+              and e["name"] == "serve.score"]
+    assert [e["batch"] for e in scored] == [eng._batch_seq] * 3
+
+
+def test_vocab_pins_the_profiler_span_names(tmp_path):
+    from tpu_als.analysis import vocab
+
+    assert vocab.check_trace_vocabulary() == []
+    bad = tmp_path / "bad.py"
+    bad.write_text(
+        "from jax.profiler import TraceAnnotation\n"
+        'with TraceAnnotation("serve.batch.stage"):\n'
+        '    with TraceAnnotation("serve.bogus", rows=3):\n'
+        "        pass\n")
+    msgs = [m for _, m in vocab.check_file(str(bad))]
+    assert len(msgs) == 1 and "SERVE_BATCH_SPAN_KEYS" in msgs[0]
+    assert "'serve.bogus'" in msgs[0]

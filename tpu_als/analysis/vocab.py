@@ -47,8 +47,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 # q/name) or anything else (group expr); longest alternatives first so
 # 'histogram_quantile' never half-matches as 'histogram'
 CALL_RE = re.compile(
-    r"\.(?P<method>histogram_quantile|histogram_count|histogram"
-    r"|counter_value|counter|gauge|emit)\(\s*"
+    r"\.(?P<method>histogram_quantile|histogram_count|histogram_many"
+    r"|histogram|counter_value|counter|gauge|emit)\(\s*"
     r"(?:(?P<q>['\"])(?P<name>[^'\"]+)(?P=q)|(?P<expr>[^)\s][^),]*))")
 
 # accessor method -> the metric kind its name must be declared as; a
@@ -56,6 +56,8 @@ CALL_RE = re.compile(
 ACCESSOR_KIND = {"histogram_quantile": "histogram",
                  "histogram_count": "histogram",
                  "counter_value": "counter"}
+# write methods not named for the kind they write
+WRITE_KIND = {"histogram_many": "histogram"}
 
 # scenario-spec literals: Assertion(metric=/event=/num=/den=) bind to
 # the registry only at evaluation time — validate them where declared.
@@ -94,6 +96,13 @@ TRACE_START_RE = re.compile(
 TRACE_RECORD_RE = re.compile(
     r"\btracing\.record_span\(\s*[^,]+,\s*"
     r"(?P<q>['\"])(?P<name>[^'\"]+)(?P=q)")
+
+# profiler-span literals: a ``TraceAnnotation("...")`` name is what a
+# trace reader keys on (benchmark/program_spans.py reads the engine
+# thread's ``serve.`` spans by name), so it is vocabulary like the rest:
+# declared in schema.SERVE_BATCH_SPAN_KEYS
+ANNOTATION_RE = re.compile(
+    r"\bTraceAnnotation\(\s*(?P<q>['\"])(?P<name>[^'\"]+)(?P=q)")
 
 # inline event dicts: a line carrying both a "ts" key and a literal
 # "type" value (the hand-built shape allowed where importing tpu_als is
@@ -327,7 +336,8 @@ def check_tenant_vocabulary(repo=REPO):
     # would silently overwrite the attribution
     reserved = set(getattr(schema, "FLIGHT_RESERVED", ())) \
         | {"tenant", "trace_id", "trace_ids"}
-    for attr in ("SERVE_SPAN_KEYS", "LIVE_SPAN_KEYS"):
+    for attr in ("SERVE_SPAN_KEYS", "SERVE_BATCH_SPAN_KEYS",
+                 "LIVE_SPAN_KEYS"):
         overlap = sorted(set(getattr(schema, attr, ())) & reserved)
         if overlap:
             errors.append(
@@ -390,6 +400,18 @@ def check_trace_vocabulary(repo=REPO):
                 f"tpu_als/obs/schema.py: TRACE_SPANS declares {name!r} "
                 "but no call site under tpu_als/ records it — dead "
                 "vocabulary (remove it or record the hop)")
+    annotated = set()
+    for path in py_files([os.path.join(repo, "tpu_als", "serving")]):
+        with open(path, encoding="utf-8") as f:
+            annotated |= {m.group("name")
+                          for m in ANNOTATION_RE.finditer(f.read())}
+    for name in getattr(schema, "SERVE_BATCH_SPAN_KEYS", ()):
+        if name not in annotated:
+            errors.append(
+                "tpu_als/obs/schema.py: SERVE_BATCH_SPAN_KEYS declares "
+                f"{name!r} but no TraceAnnotation under tpu_als/serving/ "
+                "opens it — the batch record and the trace readers "
+                "would carry a phase nothing times")
     return errors
 
 
@@ -496,7 +518,8 @@ def check_file(path, repo=REPO):
                     f"{where}: emit of undeclared event type {name!r} "
                     "(declare it in tpu_als.obs.schema.EVENTS)")
         else:
-            want_kind = ACCESSOR_KIND.get(method, method)
+            want_kind = (ACCESSOR_KIND.get(method)
+                         or WRITE_KIND.get(method, method))
             decl = schema.METRICS.get(name)
             if decl is None:
                 add(lineno,
@@ -543,6 +566,16 @@ def check_file(path, repo=REPO):
                         "tpu_als.obs.schema.METRICS)")
 
     if not in_obs:
+        batch_spans = getattr(schema, "SERVE_BATCH_SPAN_KEYS", ())
+        for m in ANNOTATION_RE.finditer(text):
+            name = m.group("name")
+            if name not in batch_spans:
+                lineno = line_of(m.start())
+                add(lineno,
+                    f"{rel}:{lineno}: profiler span {name!r} is not "
+                    "declared in tpu_als.obs.schema."
+                    "SERVE_BATCH_SPAN_KEYS — trace readers key on "
+                    "declared span names only")
         trace_spans = getattr(schema, "TRACE_SPANS", ())
         for regex in (TRACE_START_RE, TRACE_RECORD_RE):
             for m in regex.finditer(text):

@@ -54,6 +54,10 @@ def histogram(name, value, **labels):
     _default.histogram(name, value, **labels)
 
 
+def histogram_many(name, values, **labels):
+    _default.histogram_many(name, values, **labels)
+
+
 def histogram_quantile(name, q, **labels):
     return _default.histogram_quantile(name, q, **labels)
 

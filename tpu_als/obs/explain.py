@@ -115,7 +115,7 @@ def _fmt_span(ev):
     if secs is not None:
         parts.append(f"{secs:.6f}s")
     for k in ("tenant", "path", "mode", "seq", "round", "batch_rows",
-              "error"):
+              "batch", "error"):
         if ev.get(k) is not None:
             parts.append(f"{k}={ev[k]}")
     return "  ".join(str(p) for p in parts)

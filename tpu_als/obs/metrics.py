@@ -190,6 +190,12 @@ class MetricsRegistry:
                   labels=dict(labels))
 
     def histogram(self, name, value, **labels):
+        self.histogram_many(name, (value,), **labels)
+
+    def histogram_many(self, name, values, **labels):
+        """Every value of ``values`` into one series: one schema check
+        and one lock for a batch's worth of observations (the serving
+        engine's per-ticket latencies)."""
         schema.check_metric(name, "histogram")
         schema.check_labels(name, labels)
         key = (name, _labels_key(labels))
@@ -197,7 +203,8 @@ class MetricsRegistry:
             h = self._hists.get(key)
             if h is None:
                 h = self._hists[key] = _Hist()
-            h.observe(float(value))
+            for v in values:
+                h.observe(float(v))
 
     def histogram_quantile(self, name, q, **labels):
         """Bucketed quantile estimate of a recorded histogram series
@@ -234,30 +241,34 @@ class MetricsRegistry:
     @contextlib.contextmanager
     def span(self, name, **labels):
         """Record a wall-clock span; nest for tree structure (the event's
-        ``path`` is the '/'-joined stack).  Applies ``jax.named_scope``
-        when jax is already imported so the device trace shares the name
-        — but never imports jax itself (obs must stay importable in
-        processes that keep jax out, e.g. bench.py's probe)."""
+        ``path`` is the '/'-joined stack, ``t0`` its start in
+        ``perf_counter`` seconds).  When jax is already imported the
+        span is also a ``jax.named_scope`` (operations traced inside
+        carry the name) and a ``TraceAnnotation`` (the span sits on the
+        profiler's timeline, on the device trace's clock) — but obs
+        never imports jax itself (it must stay importable in processes
+        that keep jax out, e.g. bench.py's probe)."""
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
         stack.append(name)
         path = "/".join(stack)
-        scope = contextlib.nullcontext()
+        scope = annotation = contextlib.nullcontext()
         jax = sys.modules.get("jax")
         if jax is not None:
             try:
                 scope = jax.named_scope(name)
+                annotation = jax.profiler.TraceAnnotation(name)
             except Exception:
                 pass
         t0 = time.perf_counter()
         try:
-            with scope:
+            with scope, annotation:
                 yield
         finally:
             dt = time.perf_counter() - t0
             stack.pop()
-            self.emit("span", name=name, path=path,
+            self.emit("span", name=name, path=path, t0=round(t0, 6),
                       seconds=round(dt, 6), **labels)
 
     # -- run lifecycle -------------------------------------------------

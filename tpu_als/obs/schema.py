@@ -255,8 +255,22 @@ TRACE_STATUSES = ("ok", "shed", "expired", "failed", "quarantined")
 # the flight recorder's per-record span-key breakdowns (source of truth
 # here, stdlib-only, so analysis/vocab.py can assert — jax-free — that
 # they never collide with the record's structural fields or labels)
-SERVE_SPAN_KEYS = ("admission", "queue_wait", "score", "rescore",
-                   "respond")
+SERVE_SPAN_KEYS = ("admission", "queue_wait", "score", "respond")
+# the engine thread's batch cycle: the names of its profiler spans
+# (``jax.profiler.TraceAnnotation`` in serving/batcher.py and
+# serving/engine.py, one set per batch, on the device trace's clock) and
+# the keys of the engine's per-batch flight record, which keeps the same
+# durations for whoever runs no profiler.  Trace readers key on these
+# names (benchmark/program_spans.py), never on thread names
+SERVE_BATCH_SPAN_KEYS = (
+    "serve.idle",             # blocked on an empty queue
+    "serve.batch.coalesce",   # first request seen -> batch popped
+    "serve.batch",            # all of serve_batch (seq, bucket, rows, path)
+    "serve.batch.stage",      # expiry check + staging into the upload array
+    "serve.batch.dispatch",   # upload + the scoring call, until it returns
+    "serve.batch.readback",   # the one bulk device->host transfer
+    "serve.batch.complete",   # completing the tickets + their bookkeeping
+)
 LIVE_SPAN_KEYS = ("queue_wait", "quarantine", "foldin", "publish")
 
 # field names every flight record (and its flight_record event) claims
@@ -272,9 +286,11 @@ EVENTS = {
         ("cmd", "argv"),
         "one per CLI invocation: the subcommand and its argv"),
     "span": (
-        ("name", "path", "seconds"),
-        "one per closed span(): wall-clock duration; path is the "
-        "'/'-joined stack of enclosing span names (the tree structure)"),
+        ("name", "path", "t0", "seconds"),
+        "one per closed span(): its start (t0, perf_counter seconds) and "
+        "wall-clock duration; path is the '/'-joined stack of enclosing "
+        "span names (the tree structure).  The same name is a "
+        "TraceAnnotation on the profiler's timeline while one records"),
     "metric": (
         ("kind", "name", "value"),
         "a gauge set (gauges are point-in-time, so each set is an "
@@ -368,8 +384,11 @@ EVENTS = {
         ("seq", "trigger", "status", "spans"),
         "one per-request trace dumped by the serving flight recorder "
         "on an SLO breach, shed, or degraded-mode answer: spans is the "
-        "admission/queue_wait/score/rescore/respond breakdown in "
-        "seconds (serving.engine.FlightRecorder)"),
+        "admission/queue_wait/score/respond breakdown in seconds and "
+        "batch the engine's batch counter; the same triggers dump the "
+        "engine's per-batch records (spans keyed by "
+        "SERVE_BATCH_SPAN_KEYS, with batch, t0, bucket, rows, waiting) "
+        "(obs.trace.FlightRecorder)"),
     "attribution": (
         ("stages", "wall_s_per_iter", "coverage"),
         "one per `observe attribution` run: measured per-stage seconds "

@@ -113,8 +113,7 @@ def stage(name, sink=None):
         sink[name] = sink.get(name, 0.0) + dt
 
 
-# Per-request span breakdown every flight record carries.  rescore is
-# None on the exact path (no int8 shortlist to refine).  The tuple's
+# Per-request span breakdown every flight record carries.  The tuple's
 # source of truth lives in the stdlib-only schema module so the jax-free
 # static check (analysis/vocab.py) can pin it against FLIGHT_RESERVED.
 SPAN_KEYS = obs.schema.SERVE_SPAN_KEYS
@@ -131,7 +130,9 @@ class FlightRecorder:
 
     ``span_keys`` names the breakdown each record carries — the serving
     request spans by default; the live updater records its own
-    (queue_wait/quarantine/foldin/publish) through the same ring.
+    (queue_wait/quarantine/foldin/publish) through the same ring, and
+    the serving engine keeps a second one of per-BATCH records keyed by
+    ``SERVE_BATCH_SPAN_KEYS``.
 
     ``labels`` is the recorder's STRUCTURAL attribution (e.g.
     ``tenant=<name>`` on a tenant-built engine's ring): stamped into

@@ -678,8 +678,8 @@ def _fr_collect(ctx):
     records = [e for e in reg._events
                if e.get("type") == "flight_record"]
     # the acceptance shape: an slo_breach dump whose record carries the
-    # FULL per-request span breakdown (rescore stays None — it is fused
-    # into the int8 top-k kernel and not separately fenceable)
+    # FULL per-request span breakdown (the per-batch records the same
+    # breach dumps carry other span keys and are not counted here)
     complete = [
         r for r in records
         if r.get("trigger") == "slo_breach" and r.get("status") == "ok"

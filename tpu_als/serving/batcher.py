@@ -20,8 +20,11 @@ into the other:
   requests whose deadline passed while queued (``serving.expired``)
   instead of spending device time on answers nobody is waiting for.
 
-Pure stdlib + obs — no jax imports, so the admission path stays cheap
-and testable without a device.
+The consumer side writes the two spans of the engine thread's cycle that
+belong to the queue (``serve.idle``, ``serve.batch.coalesce``:
+``obs.schema.SERVE_BATCH_SPAN_KEYS``) onto the profiler's timeline; with
+no profiler recording, an annotation costs about a microsecond.  Nothing
+here touches a device, so the queue stays testable without one.
 """
 
 from __future__ import annotations
@@ -29,6 +32,8 @@ from __future__ import annotations
 import collections
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from tpu_als import obs
 from tpu_als.obs import tracing
@@ -70,7 +75,8 @@ class Ticket:
     """
 
     __slots__ = ("payload", "k", "deadline", "trace", "t_submit",
-                 "t_dequeue", "t_admit", "_event", "_result", "_error")
+                 "t_dequeue", "t_done", "t_admit", "_event", "_result",
+                 "_error")
 
     def __init__(self, payload, k, deadline, trace=None):
         self.payload = payload
@@ -79,6 +85,7 @@ class Ticket:
         self.trace = trace              # TraceContext of the last hop, or None
         self.t_submit = time.perf_counter()
         self.t_dequeue = None
+        self.t_done = None     # answered or failed (perf_counter, as above)
         self.t_admit = None    # admission DURATION (engine submit -> queued)
         self._event = threading.Event()
         self._result = None
@@ -86,10 +93,12 @@ class Ticket:
 
     def complete(self, result):
         self._result = result
+        self.t_done = time.perf_counter()
         self._event.set()
 
     def fail(self, error):
         self._error = error
+        self.t_done = time.perf_counter()
         self._event.set()
 
     def done(self):
@@ -133,6 +142,12 @@ class MicroBatcher:
         self._q = collections.deque()
         self._cond = threading.Condition()
         self._closed = False
+        # the consumer's own account of its last dequeue, for the
+        # engine's per-batch record: seconds blocked on an empty queue
+        # since the batch before, requests waiting as the coalescing
+        # wait began, seconds from then to the batch popped
+        self.last_wait = (0.0, 0, 0.0)
+        self._idle_s = 0.0
 
     def depth(self):
         with self._cond:
@@ -171,35 +186,45 @@ class MicroBatcher:
         arrivals for ``max_wait_s`` or until the largest bucket fills.
         Returns a list of tickets (``t_dequeue`` stamped), or ``None``
         on timeout with an empty queue.  Also sets the
-        ``serving.queue_depth`` gauge to the post-dequeue backlog.
+        ``serving.queue_depth`` gauge to the post-dequeue backlog, and
+        ``last_wait`` to what the dequeue waited for.
         """
         cap = self.buckets[-1]
         with self._cond:
-            if not self._q and not self._cond.wait_for(
-                    lambda: self._q or self._closed, timeout):
-                return None
-            if not self._q:            # closed and drained
-                return None
+            if not self._q:
+                t_idle = time.perf_counter()
+                with TraceAnnotation("serve.idle"):
+                    self._cond.wait_for(
+                        lambda: self._q or self._closed, timeout)
+                self._idle_s += time.perf_counter() - t_idle
+                if not self._q:        # timed out, or closed and drained
+                    return None
             # coalesce: wait out the batching window unless full
             t_first = time.perf_counter()
-            while len(self._q) < cap:
-                remaining = self.max_wait_s - (time.perf_counter() - t_first)
-                if remaining <= 0 or self._closed:
-                    break
-                self._cond.wait(remaining)
-            batch = [self._q.popleft()
-                     for _ in range(min(len(self._q), cap))]
-            depth_after = len(self._q)
+            waiting = len(self._q)
+            with TraceAnnotation("serve.batch.coalesce", waiting=waiting):
+                while len(self._q) < cap:
+                    remaining = self.max_wait_s - (time.perf_counter()
+                                                   - t_first)
+                    if remaining <= 0 or self._closed:
+                        break
+                    self._cond.wait(remaining)
+                batch = [self._q.popleft()
+                         for _ in range(min(len(self._q), cap))]
+                depth_after = len(self._q)
         now = time.perf_counter()
+        self.last_wait = (self._idle_s, waiting, now - t_first)
+        self._idle_s = 0.0
+        waits = []
         for t in batch:
             t.t_dequeue = now
-            obs.histogram("serving.enqueue_seconds", now - t.t_submit,
-                          **self.labels)
+            waits.append(now - t.t_submit)
             # the queue owns the queue-wait hop: chain it here so the
             # span's seconds are the histogram's sample, not a re-read
             if t.trace is not None:
                 t.trace = tracing.record_span(
-                    t.trace, "serve.queue", seconds=now - t.t_submit)
+                    t.trace, "serve.queue", seconds=waits[-1])
+        obs.histogram_many("serving.enqueue_seconds", waits, **self.labels)
         obs.gauge("serving.queue_depth", depth_after, **self.labels)
         return batch
 

@@ -40,11 +40,19 @@ tickets.  The pieces the rest of the stack plugs into:
   (see docs/serving.md for the vocabulary).
 - **Flight recorder.**  Every request outcome is recorded into a
   bounded ring (:class:`~tpu_als.obs.trace.FlightRecorder`) with its
-  admission / queue-wait / score / rescore / respond span breakdown; on
-  an SLO breach (``slo_s``), a shed, or a degraded-mode (exact-fallback)
-  answer, the ring's not-yet-dumped tail is emitted as ``flight_record``
-  events — so a p99 outlier leaves the last N request traces in the obs
-  trail instead of vanishing into a histogram bucket.
+  admission / queue-wait / score / respond span breakdown and the
+  ``batch`` it rode; on an SLO breach (``slo_s``), a shed, or a
+  degraded-mode (exact-fallback) answer, the ring's not-yet-dumped tail
+  is emitted as ``flight_record`` events — so a p99 outlier leaves the
+  last N request traces in the obs trail instead of vanishing into a
+  histogram bucket.
+- **The batch cycle on the profiler's clock.**  The engine thread's
+  phases — idle, coalesce, stage, dispatch, readback, complete — are
+  ``TraceAnnotation`` spans (``obs.schema.SERVE_BATCH_SPAN_KEYS``),
+  always on: under ``jax.profiler.trace`` they sit beside the device's
+  row, so every idle gap of the device falls under the phase that held
+  the engine thread.  The same durations go into one record per batch
+  in a second ring, ``batch_flight``, dumped on the same triggers.
 - **Sharded serving fabric.**  With a ``mesh``, the catalog lives
   device-resident per shard and never commits whole to one device:
   ``serve_backend="sharded"`` publishes a
@@ -84,10 +92,12 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from tpu_als import obs
 from tpu_als.core.ratings import _next_pow2
 from tpu_als.obs import tracing
+from tpu_als.obs.schema import SERVE_BATCH_SPAN_KEYS
 from tpu_als.obs.trace import FlightRecorder
 from tpu_als.ops.topk import chunked_topk_scores
 from tpu_als.resilience import faults
@@ -248,6 +258,14 @@ class ServingEngine:
         # carries it, so no record site can strand a dump unattributed
         self.flight = FlightRecorder(flight_capacity,
                                      labels=self._labels)
+        # one record per BATCH beside the per-request ones: the engine
+        # thread's cycle (the durations of its profiler spans, under
+        # their names) for whoever runs no profiler; a request record's
+        # ``batch`` names the batch record it rode
+        self.batch_flight = FlightRecorder(
+            flight_capacity, span_keys=SERVE_BATCH_SPAN_KEYS,
+            labels=self._labels)
+        self._batch_seq = 0
         self.batcher = MicroBatcher(
             buckets=buckets, max_queue=max_queue, max_wait_s=max_wait_s,
             default_deadline_s=default_deadline_s, labels=self._labels)
@@ -708,6 +726,7 @@ class ServingEngine:
                 "shed", {"admission": time.perf_counter() - t_enter},
                 trace_id=(ctx.trace_id if ctx is not None else None))
             self.flight.dump("shed")
+            self.batch_flight.dump("shed")
             raise
         t.t_admit = time.perf_counter() - t_enter
         obs.counter("serving.requests", **self._labels)
@@ -775,62 +794,145 @@ class ServingEngine:
         """Score one dequeued micro-batch and complete its tickets.
 
         Public so tests and synchronous callers can drive the engine
-        without the background thread.
+        without the background thread.  The phases are disjoint spans
+        on the profiler's timeline (``obs.schema.SERVE_BATCH_SPAN_KEYS``;
+        ``serve.batch`` carries ``seq``, ``bucket``, ``rows``, ``path``),
+        and their durations go into one ``batch_flight`` record.
         """
-        now = time.perf_counter()
+        seq = self._batch_seq = self._batch_seq + 1
+        t_stage = time.perf_counter()
+        with TraceAnnotation("serve.batch", seq=seq) as whole:
+            with TraceAnnotation("serve.batch.stage"):
+                live = self._expire(batch, t_stage)
+                if not live:
+                    return
+                # raise-mode -> _run fails all
+                mode = faults.check("serving.score")
+                m = self._model
+                n = len(live)
+                B = bucket_for(n, self.batcher.buckets)
+                st = self._staged(live, B, m.rank)
+                obs.histogram("serving.batch_rows", n, **self._labels)
+            t_dispatch = time.perf_counter()
+            with TraceAnnotation("serve.batch.dispatch"):
+                resp_dev, path, fell_back = self._dispatch(m, st, B, mode)
+                if fell_back:
+                    obs.counter("serving.fallback_exact", n,
+                                **self._labels)
+                whole.set_metadata(bucket=B, rows=n, path=path)
+            t_readback = time.perf_counter()
+            with TraceAnnotation("serve.batch.readback"):
+                # ONE bulk device→host transfer; tickets complete with
+                # numpy views sliced from this buffer (which snapshots an
+                # immutable device array — the views stay valid after
+                # slot reuse)
+                resp = np.asarray(resp_dev)
+                kw = resp.shape[1] // 2
+                scores = resp[:, :kw].view(np.float32)  # same-itemsize view
+                indices = resp[:, kw:]
+            t_complete = time.perf_counter()
+            score_s = t_complete - t_dispatch
+            with TraceAnnotation("serve.batch.complete"):
+                obs.histogram("serving.score_seconds", score_s, path=path,
+                              **self._labels)
+                e2es = []
+                for j, t in enumerate(live):
+                    kk = min(t.k or self.k, kw)
+                    t.complete((scores[j, :kk], indices[j, :kk]))
+                    e2es.append(t.t_done - t.t_submit)
+                    if t.trace is not None:
+                        t.trace = tracing.record_span(
+                            t.trace, "serve.score", seconds=score_s,
+                            path=path, batch=seq)
+                    self.flight.record(
+                        "ok",
+                        {"admission": t.t_admit,
+                         "queue_wait": (t.t_dequeue - t.t_submit
+                                        if t.t_dequeue else None),
+                         "score": score_s,
+                         "respond": t.t_done - t_complete},
+                        e2e_seconds=e2es[-1], path=path, batch=seq,
+                        trace_id=(t.trace.trace_id
+                                  if t.trace is not None else None))
+                obs.histogram_many("serving.e2e_seconds", e2es,
+                                   **self._labels)
+                trigger = None
+                if self.slo_s is not None and max(e2es) > self.slo_s:
+                    trigger = "slo_breach"
+                elif fell_back:
+                    trigger = "degraded"
+                if trigger:
+                    self.flight.dump(trigger)
+            t_end = time.perf_counter()
+        idle_s, waiting, coalesce_s = self.batcher.last_wait
+        self.batch_flight.record(
+            "ok",
+            dict(zip(SERVE_BATCH_SPAN_KEYS,
+                     (idle_s, coalesce_s, t_end - t_stage,
+                      t_dispatch - t_stage, t_readback - t_dispatch,
+                      t_complete - t_readback, t_end - t_complete))),
+            path=path, batch=seq, t0=t_stage, bucket=B, rows=n,
+            waiting=waiting)
+        if trigger:
+            self.batch_flight.dump(trigger)
+
+    def _expire(self, batch, now):
+        """The tickets of ``batch`` still worth scoring; the others are
+        failed with ``DeadlineExceeded``, counted and recorded."""
         live = []
         for t in batch:
-            if t.deadline is not None and now > t.deadline:
-                obs.counter("serving.expired", **self._labels)
-                if t.trace is not None:
-                    t.trace = tracing.record_span(
-                        t.trace, "serve.expired", status="expired",
-                        seconds=now - t.t_submit)
-                self.flight.record(
-                    "expired",
-                    {"admission": t.t_admit,
-                     "queue_wait": (t.t_dequeue - t.t_submit
-                                    if t.t_dequeue else None)},
-                    e2e_seconds=now - t.t_submit,
-                    trace_id=(t.trace.trace_id
-                              if t.trace is not None else None))
-                t.fail(DeadlineExceeded(
-                    "deadline passed while queued "
-                    f"({now - t.t_submit:.4f}s since submit)"))
-            else:
+            if t.deadline is None or now <= t.deadline:
                 live.append(t)
-        if not live:
-            return
-        mode = faults.check("serving.score")   # raise-mode -> _run fails all
-        m = self._model
-        n = len(live)
-        B = bucket_for(n, self.batcher.buckets)
-        # single-upload staging: one reusable int32 [B, rank+2] array
-        # per bucket carries the rows' f32 bits, ids and the row-mask —
-        # the payload is the only host→device transfer this batch makes
+                continue
+            obs.counter("serving.expired", **self._labels)
+            if t.trace is not None:
+                t.trace = tracing.record_span(
+                    t.trace, "serve.expired", status="expired",
+                    seconds=now - t.t_submit)
+            self.flight.record(
+                "expired",
+                {"admission": t.t_admit,
+                 "queue_wait": (t.t_dequeue - t.t_submit
+                                if t.t_dequeue else None)},
+                e2e_seconds=now - t.t_submit,
+                trace_id=(t.trace.trace_id
+                          if t.trace is not None else None))
+            t.fail(DeadlineExceeded(
+                "deadline passed while queued "
+                f"({now - t.t_submit:.4f}s since submit)"))
+        return live
+
+    def _staged(self, live, B, rank):
+        """Single-upload staging: one reusable int32 ``[B, rank+2]``
+        array per bucket carries the rows' f32 bits, ids and the
+        row-mask — the payload is the only host→device transfer a batch
+        makes."""
         st = self._stage.get(B)
-        if st is None or st.shape[1] != m.rank + 2:
-            st = np.zeros((B, m.rank + 2), dtype=np.int32)
+        if st is None or st.shape[1] != rank + 2:
+            st = np.zeros((B, rank + 2), dtype=np.int32)
             self._stage[B] = st
-        rows = st[:, :m.rank].view(np.float32)  # same-itemsize view
+        rows = st[:, :rank].view(np.float32)    # same-itemsize view
         for j, t in enumerate(live):
             if isinstance(t.payload, (int, np.integer)):
-                st[j, m.rank] = t.payload
-                st[j, m.rank + 1] = 0
+                st[j, rank] = t.payload
+                st[j, rank + 1] = 0
             else:
                 rows[j] = t.payload
-                st[j, m.rank + 1] = 1
+                st[j, rank + 1] = 1
         # pad slots: stale ids/masks from the previous batch are enough
         # to change which (unread) pad rows get scored — zero them; the
         # stale row payloads themselves are unread either way
-        st[n:, m.rank:] = 0
-        obs.histogram("serving.batch_rows", n, **self._labels)
+        st[len(live):, rank:] = 0
+        return st
 
+    def _dispatch(self, m, st, B, mode):
+        """Upload the staged batch and call the scorer the live model
+        and backend select; returns ``(packed response on the device,
+        path, fell back to exact)`` as soon as the call returns."""
         backend = self._backend or "local"
         index = m.index
-        t0 = time.perf_counter()
         packed = jnp.asarray(st)
-        fell_back = False
+        resp_dev, fell_back = None, False
         if backend == "merge_ring":
             if m.Vs is not None and mode != "corrupt":
                 path = "merge_ring"
@@ -846,25 +948,20 @@ class ServingEngine:
             if use_index:
                 if isinstance(index, ShardedInt8Index):
                     path = "int8_sharded"
+                else:
+                    path = "int8"
+                    if not index.delta_count:
+                        resp_dev = self._run_pinned(
+                            (B, "int8"), _serve_int8_packed,
+                            (m.U, index.Vq, index.sv, index.V,
+                             index.valid, packed),
+                            dict(k=self.k, shortlist_k=index.shortlist_k))
+                if resp_dev is None:
                     s, ix = index.topk(_select_packed(m.U, packed),
                                        self.k)
                     resp_dev = _pack_response(s, ix)
-                else:
-                    path = "int8"
-                    resp_dev = self._run_pinned(
-                        (B, "int8"), _serve_int8_packed,
-                        (m.U, index.Vq, index.sv, index.V, index.valid,
-                         packed),
-                        dict(k=self.k, shortlist_k=index.shortlist_k)
-                        ) if not index.delta_count else None
-                    if resp_dev is None:
-                        s, ix = index.topk(_select_packed(m.U, packed),
-                                           self.k)
-                        resp_dev = _pack_response(s, ix)
             else:
                 path = "exact"
-        if fell_back:
-            obs.counter("serving.fallback_exact", n, **self._labels)
         if path == "exact":
             # mesh backends keep V on the host (module docstring):
             # the fallback re-uploads per batch, by design rare
@@ -874,42 +971,4 @@ class ServingEngine:
                 (B, "exact"), _serve_exact_packed,
                 (m.U, Vd, validd, packed),
                 dict(k=self.k, item_chunk=ic))
-        # ONE bulk device→host transfer; tickets complete with numpy
-        # views sliced from this buffer (which snapshots an immutable
-        # device array — the views stay valid after slot reuse)
-        resp = np.asarray(resp_dev)
-        kw = resp.shape[1] // 2
-        scores = resp[:, :kw].view(np.float32)  # same-itemsize view
-        indices = resp[:, kw:]
-        score_s = time.perf_counter() - t0
-        obs.histogram("serving.score_seconds", score_s, path=path,
-                      **self._labels)
-        done = time.perf_counter()
-        breached = False
-        for j, t in enumerate(live):
-            kk = min(t.k or self.k, kw)
-            t.complete((scores[j, :kk], indices[j, :kk]))
-            e2e = done - t.t_submit
-            obs.histogram("serving.e2e_seconds", e2e, **self._labels)
-            if t.trace is not None:
-                t.trace = tracing.record_span(
-                    t.trace, "serve.score", seconds=score_s, path=path)
-            # rescore is fused into the int8 top-k executable (one
-            # jitted call — serving/index.py), so it is not separable
-            # from score without un-fusing the kernel; None records that
-            self.flight.record(
-                "ok",
-                {"admission": t.t_admit,
-                 "queue_wait": (t.t_dequeue - t.t_submit
-                                if t.t_dequeue else None),
-                 "score": score_s,
-                 "respond": time.perf_counter() - done},
-                e2e_seconds=e2e, path=path,
-                trace_id=(t.trace.trace_id
-                          if t.trace is not None else None))
-            if self.slo_s is not None and e2e > self.slo_s:
-                breached = True
-        if breached:
-            self.flight.dump("slo_breach")
-        elif fell_back:
-            self.flight.dump("degraded")
+        return resp_dev, path, fell_back

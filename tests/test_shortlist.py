@@ -1,0 +1,260 @@
+"""The int8 shortlist's selection (``ops.topk.shortlist_topk``): what
+``jax.lax.top_k`` returns, element for element, on both sides of the
+one-stage / two-stage decision; and the three kernels that call it (base,
+delta, per-shard) at a catalog large enough to engage two stages, under the
+contracts they already had."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tests.conftest import assert_topk_within_contract
+from tpu_als import obs
+from tpu_als.ops.topk import (
+    NEG_INF,
+    ShortlistPlan,
+    shortlist_columns,
+    shortlist_plan,
+    shortlist_topk,
+    topk_validity,
+)
+from tpu_als.parallel.mesh import make_mesh
+from tpu_als.serving import ServingEngine, build_index
+from tpu_als.serving.index import build_sharded_index
+
+# large enough for two stages at shortlist 16 (the decision's threshold
+# there is 8,460 columns), small enough for tier-1
+BIG_ITEMS, RANK, SHORTLIST = 40_000, 16, 16
+
+
+@pytest.fixture(autouse=True)
+def _fresh():
+    reg = obs.reset()
+    yield reg
+
+
+def _scores(rng, kind, n, N, k):
+    if kind == "random":
+        return rng.standard_normal((n, N)).astype(np.float32)
+    if kind == "ties":          # seven distinct values: ties everywhere
+        return rng.integers(-3, 4, (n, N)).astype(np.float32)
+    if kind == "sparse":        # fewer than k finite entries a row
+        x = np.full((n, N), NEG_INF, np.float32)
+        for row in x:
+            cols = rng.choice(N, size=int(rng.integers(0, k)), replace=False)
+            row[cols] = rng.standard_normal(len(cols))
+        return x
+    assert kind == "tail"       # the winners sit in the ragged last block
+    x = rng.integers(0, 2, (n, N)).astype(np.float32)
+    x[:, -(N % 128 or 128):] += 5.0
+    return x
+
+
+# (n, N, k, stages expected): both sides of the decision, ragged and whole
+# last blocks, row counts that are and are not whole sublane tiles
+SHAPES = [
+    (4, 1_000, 8, 1),
+    (8, 4_231, 8, 1),            # one block short of the threshold
+    (8, 4_232, 8, 2),            # the threshold itself: 4 * (1024 + 34)
+    (3, 20_259, 16, 2),
+    (16, 34_000, 64, 2),
+    (5, 33_791, 64, 1),
+    (1, 40_064, 16, 2),          # whole blocks: no pad
+    (8, 70_001, 64, 2),
+    (2, 300_000, 8, 2),          # block length 256
+]
+
+
+@pytest.mark.parametrize("kind", ["random", "ties", "sparse", "tail"])
+@pytest.mark.parametrize("n,N,k,stages", SHAPES)
+def test_shortlist_topk_is_lax_top_k(kind, n, N, k, stages):
+    rng = np.random.default_rng(N + k)
+    plan = shortlist_plan(N, k)
+    assert plan.stages == stages and plan.columns == N
+    x = jnp.asarray(_scores(rng, kind, n, N, k))
+    want_s, want_i = jax.lax.top_k(x, k)
+    got_s, got_i = jax.jit(shortlist_topk, static_argnums=1)(x, k)
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+
+
+@pytest.mark.parametrize("N,k", [(1_505_938, 64), (40_000, 16),
+                                 (10_000_000, 64), (300_000, 8)])
+def test_plan_is_whole_blocks_near_the_square_root(N, k):
+    plan = shortlist_plan(N, k)
+    assert plan.stages == 2 and plan.block_len % 128 == 0
+    assert abs(plan.block_len - max((N / k) ** 0.5, 128)) <= 64
+    assert (plan.blocks - 1) * plan.block_len < N <= \
+        plan.blocks * plan.block_len
+    padded = shortlist_columns(N, k)
+    again = shortlist_plan(padded, k)
+    assert N <= padded < N + again.block_len
+    assert padded == again.blocks * again.block_len
+    assert shortlist_columns(padded, k) == padded
+
+
+def test_the_benchmark_cells_plan():
+    assert shortlist_plan(1_505_938, 64) == ShortlistPlan(
+        stages=2, blocks=11_766, block_len=128, columns=1_505_938)
+    assert shortlist_columns(1_505_938, 64) == 1_506_048
+
+
+@pytest.mark.parametrize("N,k", [(1, 1), (63, 64), (2_000, 64),
+                                 (5_000, 16), (8_459, 16), (33_791, 64)])
+def test_small_catalogs_keep_the_single_top_k(N, k):
+    """One stage is the call it replaces: the same jaxpr, so the same
+    program."""
+    assert shortlist_plan(N, k) == ShortlistPlan(1, 1, N, N)
+    assert shortlist_columns(N, k) == N
+    kk = min(k, N)
+    x = jax.ShapeDtypeStruct((4, N), jnp.float32)
+    jax.clear_caches()
+    ours = jax.make_jaxpr(lambda s: shortlist_topk(s, kk))(x)
+    jax.clear_caches()
+    theirs = jax.make_jaxpr(lambda s: jax.lax.top_k(s, kk))(x)
+    assert str(ours) == str(theirs)
+
+
+@pytest.fixture(scope="module")
+def big():
+    rng = np.random.default_rng(26)
+    V = rng.normal(size=(BIG_ITEMS, RANK)).astype(np.float32)
+    U = rng.normal(size=(24, RANK)).astype(np.float32)
+    valid = rng.random(BIG_ITEMS) < 0.9
+    return U, V, valid
+
+
+def test_big_index_engages_two_stages_and_pads_once(big):
+    _, V, valid = big
+    idx = build_index(V, item_valid=valid, shortlist_k=SHORTLIST)
+    plan = idx.shortlist_plan()
+    assert plan.stages == 2
+    assert plan.columns == plan.blocks * plan.block_len == 40_064
+    assert idx.Vq.shape[0] == idx.sv.shape[0] == idx.valid.shape[0] == 40_064
+    assert idx.V.shape[0] == idx.n_base == idx.n_items == BIG_ITEMS
+    assert not np.asarray(idx.valid)[BIG_ITEMS:].any()
+
+
+def test_big_index_topk_within_contract(big):
+    U, V, valid = big
+    idx = build_index(V, item_valid=valid, shortlist_k=SHORTLIST)
+    s, ix = idx.topk(jnp.asarray(U), 5)
+    assert np.asarray(ix).max() < BIG_ITEMS
+    assert assert_topk_within_contract(s, ix, U, V, valid, 5) >= 20
+
+
+def test_big_index_sparse_validity_keeps_sentinels(big):
+    """Fewer valid items than the shortlist: the sentinels and the ids
+    under them are the single top_k's, and none points at a padding
+    column."""
+    U, V, _ = big
+    valid = np.zeros(BIG_ITEMS, bool)
+    valid[[7, 20_000, 39_999]] = True
+    idx = build_index(V, item_valid=valid, shortlist_k=SHORTLIST)
+    s, ix = (np.asarray(a) for a in idx.topk(jnp.asarray(U), 5))
+    assert_topk_within_contract(s, ix, U, V, valid, 5)
+    assert (topk_validity(s).sum(axis=1) == 3).all()
+    assert ix.max() < BIG_ITEMS
+
+
+def _assert_bitwise(idx, ref, U, k):
+    s, ix = (np.asarray(a) for a in idx.topk(U, k))
+    rs, rix = (np.asarray(a) for a in ref.topk(U, k))
+    np.testing.assert_array_equal(s, rs)
+    np.testing.assert_array_equal(ix, rix)
+
+
+def test_big_delta_and_compact_bitwise_against_rebuild(big):
+    """The ``live_delta_index`` contract where two stages select: touched
+    and appended rows through the delta segment, then compacted, against
+    ``build_index`` of the updated catalog."""
+    U, V, valid = big
+    rng = np.random.default_rng(27)
+    idx = build_index(V, item_valid=valid, shortlist_k=SHORTLIST, seq=1)
+    touched = np.sort(rng.choice(BIG_ITEMS, size=37, replace=False))
+    appended = np.arange(BIG_ITEMS, BIG_ITEMS + 100)    # past the padding
+    rows = np.concatenate([touched, appended]).astype(np.int64)
+    V2 = np.concatenate(
+        [V, np.zeros((len(appended), RANK), np.float32)])
+    V2[rows] = 3.0 * rng.normal(size=(len(rows), RANK)).astype(np.float32)
+    valid2 = np.concatenate([valid, np.ones(len(appended), bool)])
+    valid2[touched] = True
+    upd = idx.with_updates(rows, V2[rows], seq=2)
+    assert upd.shortlist_plan().stages == 2
+    assert upd.shortlist_plan().columns == 40_064 + 256
+    ref = build_index(V2, item_valid=valid2, shortlist_k=SHORTLIST, seq=2)
+    Uq = jnp.asarray(U)
+    _assert_bitwise(upd, ref, Uq, 5)
+    assert np.isin(np.asarray(upd.topk(Uq, 5)[1]), rows).any()
+    comp = upd.compact(seq=3)
+    assert comp.delta_count == 0 and comp.n_base == BIG_ITEMS + 100
+    for name in ("Vq", "sv", "valid", "V"):
+        np.testing.assert_array_equal(np.asarray(getattr(comp, name)),
+                                      np.asarray(getattr(ref, name)))
+    _assert_bitwise(comp, ref, Uq, 5)
+
+
+def test_big_sharded_index_two_stages_per_shard(big):
+    """The sharded ``body`` on the 8-device CPU mesh of the fabric tests:
+    each shard's 9,000 columns select in two stages, and the answer is the
+    single-device index's."""
+    U, V, valid = big
+    rng = np.random.default_rng(28)
+    V = np.concatenate(
+        [V, rng.normal(size=(32_000, RANK)).astype(np.float32)])
+    valid = np.concatenate([valid, rng.random(32_000) < 0.9])
+    sh = build_sharded_index(V, make_mesh(8), item_valid=valid,
+                             shortlist_k=SHORTLIST)
+    assert sh.shortlist_plan() == shortlist_plan(9_000, SHORTLIST)
+    assert sh.shortlist_plan().stages == 2
+    s, ix = sh.topk(jnp.asarray(U), 5)
+    assert assert_topk_within_contract(s, ix, U, V, valid, 5) >= 20
+    one = build_index(V, item_valid=valid, shortlist_k=SHORTLIST)
+    np.testing.assert_array_equal(np.asarray(ix),
+                                  np.asarray(one.topk(jnp.asarray(U), 5)[1]))
+    rows = np.array([5, 9_001, 71_999], dtype=np.int64)
+    upd = sh.with_updates(rows, 4.0 * V[rows], seq=2)
+    assert upd.shortlist_plan() == shortlist_plan(9_004, SHORTLIST)
+    V2 = V.copy()
+    V2[rows] *= 4.0
+    s2, ix2 = upd.topk(jnp.asarray(U), 5)
+    assert_topk_within_contract(s2, ix2, U, V2, valid, 5)
+
+
+def _shortlist_events(reg):
+    return [e for e in reg._events if e["type"] == "serving_shortlist"]
+
+
+def test_warmup_reports_the_plan_the_program_was_traced_with(_fresh, big):
+    U, V, _ = big
+    eng = ServingEngine(k=5, buckets=(8, 32), shortlist_k=SHORTLIST,
+                        max_wait_s=0.0)
+    eng.publish(U, V)
+    eng.warmup()
+    events = _shortlist_events(_fresh)
+    assert [(e["bucket"], e["path"]) for e in events] == [(8, "int8"),
+                                                          (32, "int8")]
+    want = shortlist_plan(shortlist_columns(BIG_ITEMS, SHORTLIST), SHORTLIST)
+    assert want == eng.published_index.shortlist_plan()
+    for e in events:
+        assert (e["stages"], e["blocks"], e["block_len"], e["columns"]) \
+            == (2, 313, 128, 40_064) == tuple(want)
+    eng.warmup_live(max_delta_rows=2)
+    live = _shortlist_events(_fresh)[2:]
+    assert [(e["bucket"], e["delta_rows"], e["columns"]) for e in live] == [
+        (8, 1, 40_065), (32, 1, 40_065), (8, 2, 40_066), (32, 2, 40_066)]
+    assert all(tuple(shortlist_plan(e["columns"], SHORTLIST))
+               == (e["stages"], e["blocks"], e["block_len"], e["columns"])
+               for e in live)
+
+
+def test_warmup_reports_one_stage_on_a_small_catalog(_fresh):
+    rng = np.random.default_rng(3)
+    eng = ServingEngine(k=5, buckets=(8,), shortlist_k=32, max_wait_s=0.0)
+    eng.publish(rng.normal(size=(10, 6)).astype(np.float32),
+                rng.normal(size=(300, 6)).astype(np.float32))
+    eng.warmup()
+    (e,) = _shortlist_events(_fresh)
+    assert (e["bucket"], e["path"], e["stages"], e["blocks"],
+            e["block_len"], e["columns"]) == (8, "int8", 1, 1, 300, 300)

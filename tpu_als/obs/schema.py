@@ -328,6 +328,13 @@ EVENTS = {
         "one per ServingEngine, at first publish: the scoring backend "
         "the engine resolved (local / sharded / merge_ring), after the "
         "live-mesh probe for the in-kernel merge"),
+    "serving_shortlist": (
+        ("bucket", "path", "stages", "blocks", "block_len", "columns"),
+        "one per int8 scoring program ServingEngine.warmup / warmup_live "
+        "compiles (per bucket and path; warmup_live adds delta_rows): how "
+        "its shortlist selects, from ops.topk.shortlist_plan — stages 1 is "
+        "one lax.top_k over all columns, 2 is block maxima then top_k "
+        "over the winning blocks of block_len columns"),
     "serve_degraded": (
         ("strategy", "reason"),
         "a sharded top-k request fell back to last-good gathered "

@@ -11,6 +11,8 @@ resident user block and fold each tile's scores into a running
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +35,105 @@ def topk_validity(scores):
     sentinel constant.
     """
     return scores > NEG_INF
+
+
+class ShortlistPlan(NamedTuple):
+    """How :func:`shortlist_topk` selects ``k`` of ``columns`` scores a
+    row: in one ``lax.top_k`` (``stages`` 1, one block of all columns)
+    or over ``blocks`` contiguous blocks of ``block_len`` columns."""
+
+    stages: int
+    blocks: int
+    block_len: int
+    columns: int
+
+
+def shortlist_plan(columns, k):
+    """The selection :func:`shortlist_topk` compiles for a ``[n,
+    columns]`` score matrix and ``k`` — a function of those two static
+    numbers and nothing else (no argument, environment variable, planner
+    entry or probe), so an event that reports it cannot disagree with
+    the program.
+
+    Two stages read ``columns`` scores once for the block maxima and
+    then run ``TopK`` over ``blocks + k * block_len`` of them, which is
+    least near ``block_len = sqrt(columns / k)``; the block length is
+    the multiple of 128 (the TPU's lane width) nearest to that.  Where
+    that is not at least four times fewer than ``columns`` (or there are
+    fewer than ``k`` blocks to choose from) the single ``lax.top_k``
+    stays: every small catalog.
+    """
+    columns, k = int(columns), int(k)
+    if k >= 1:
+        block_len = 128 * max(1, int(math.sqrt(columns / k) / 128 + 0.5))
+        blocks = -(-columns // block_len)
+        if blocks >= k and 4 * (k * block_len + blocks) <= columns:
+            return ShortlistPlan(2, blocks, block_len, columns)
+    return ShortlistPlan(1, 1, columns, columns)
+
+
+def shortlist_columns(columns, k):
+    """``columns`` rounded up to whole blocks of its own
+    :func:`shortlist_plan` (unchanged where one stage selects): the
+    width an index pads its catalog to once, at build time, so that no
+    batch pays for a ragged last block."""
+    while True:
+        plan = shortlist_plan(columns, k)
+        if plan.stages == 1 or columns % plan.block_len == 0:
+            return columns
+        columns = plan.blocks * plan.block_len
+
+
+def shortlist_topk(scores, k):
+    """``jax.lax.top_k(scores, k)`` for an f32 ``[n, N]`` matrix, element
+    for element (values, indices, ties to the lower index, sentinels
+    included), in two exact stages where :func:`shortlist_plan` says
+    they pay: the maxima of contiguous blocks of ``L`` columns, ``top_k``
+    of those for ``k`` blocks, then ``top_k`` over the ``k * L`` scores
+    of the winning blocks.
+
+    Exact because every member of a row's top ``k`` lies in one of the
+    ``k`` blocks with the largest maximum: were ``x`` in the top ``k``
+    and its block not chosen, the chosen blocks' maxima would be ``k``
+    other elements that precede ``x`` (larger, or equal in a lower
+    block, hence at a lower index).  The chosen block ids are sorted
+    ascending before the gather, so position order in the gathered
+    ``[n, k * L]`` is column order and the second ``top_k`` breaks ties
+    as the single one would.
+
+    A ragged last block is padded with ``-inf`` — below every score
+    and, being last, behind every real column in a tie.  An index that
+    knows its shape ahead pads its catalog to
+    :func:`shortlist_columns` instead and skips that copy.  The matrix
+    is viewed as ``[n / 8, 8, blocks, L]``: on the TPU, whose f32 tile
+    is 8 rows by 128 lanes, that view is the row-major matrix's own
+    bytes, and both the block maximum and the gather read it in place
+    (the flat ``[n, blocks, L]`` view cost two relayout copies of the
+    whole matrix at ``n`` = 128: compiled HLO for the v5e, PR 26).
+    """
+    n, N = scores.shape
+    plan = shortlist_plan(N, k)
+    if plan.stages == 1:
+        return jax.lax.top_k(scores, k)
+    L, blocks = plan.block_len, plan.blocks
+    if blocks * L != N:
+        scores = jnp.pad(scores, ((0, 0), (0, blocks * L - N)),
+                         constant_values=-jnp.inf)
+    sub = 8 if n % 8 == 0 else n
+    tiled = scores.reshape(n // sub, sub, blocks, L)
+    with jax.named_scope("serve.shortlist.blockmax"):
+        block_max = jnp.max(tiled, axis=-1).reshape(n, blocks)
+    with jax.named_scope("serve.shortlist.blocks"):
+        _, block_ids = jax.lax.top_k(block_max, k)
+        block_ids = jnp.sort(block_ids, axis=1)
+    with jax.named_scope("serve.shortlist.gather"):
+        won = jnp.take_along_axis(
+            tiled, block_ids.reshape(n // sub, sub, k, 1), axis=2)
+    with jax.named_scope("serve.shortlist.select"):
+        values, pos = jax.lax.top_k(won.reshape(n, k * L), k)
+        cols = (jnp.take_along_axis(block_ids, pos // L, axis=1) * L
+                + pos % L)
+    return values, cols
 
 
 @functools.partial(jax.jit, static_argnames=("k", "item_chunk"))

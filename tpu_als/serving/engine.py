@@ -613,6 +613,7 @@ class ServingEngine:
                     _select_packed(m.U, proto), m.Vs, m.valids)
                 _pack_response(s, ix).block_until_ready()
             elif idx is not None and idx.seq == m.seq:
+                self._emit_shortlist(B, idx)
                 if backend == "local" and not idx.delta_count:
                     self._pinned[(B, "int8")] = _serve_int8_packed.lower(
                         m.U, idx.Vq, idx.sv, idx.V, idx.valid, proto,
@@ -666,7 +667,18 @@ class ServingEngine:
                 proto = jnp.zeros((B, m.rank + 2), jnp.int32)
                 s, ix = dummy.topk(_select_packed(m.U, proto), self.k)
                 _pack_response(s, ix).block_until_ready()
+                self._emit_shortlist(B, dummy, delta_rows=d)
             d <<= 1
+
+    @staticmethod
+    def _emit_shortlist(bucket, index, **extra):
+        """One ``serving_shortlist`` event for a scoring program that
+        was just compiled: the selection its shortlist runs, from the
+        same ``ops.topk.shortlist_plan`` the program was traced with."""
+        obs.emit("serving_shortlist", bucket=bucket,
+                 path=("int8_sharded" if isinstance(index, ShardedInt8Index)
+                       else "int8"),
+                 **index.shortlist_plan()._asdict(), **extra)
 
     def _run_pinned(self, key, fn, args, statics):
         """Dispatch through the AOT-pinned executable when one is live

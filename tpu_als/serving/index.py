@@ -31,6 +31,18 @@ random shapes).  What does hold exactly:
   kernel uses, in the same places, so all-invalid rows and short
   catalogs degrade identically.
 
+The shortlist itself is ``ops.topk.shortlist_topk``: what
+``jax.lax.top_k`` over the approximate scores returns, element for
+element, found in two exact stages (block maxima, then ``top_k`` over
+the winning blocks) wherever the static shapes ``(columns,
+shortlist_k)`` say that pays, and by the single ``top_k`` on every
+small catalog.  All three kernels here (base, delta, per-shard) call
+that one function.  A base index whose shape engages two stages pads
+``Vq`` / ``sv`` / ``valid`` — never ``V`` — with invalid columns to
+whole blocks once, at build time (``ops.topk.shortlist_columns``):
+they sort behind every real column, so no answer changes, and no batch
+pays for a ragged last block.
+
 The column-gather rescore prices at ``n * (n*shortlist_k) * r`` MACs —
 an ``n``-fold overshoot versus the minimal per-row rescore — and still
 beats the exact pass whenever ``n * shortlist_k < n_items``, i.e. for
@@ -80,20 +92,31 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_als.core.ratings import _next_pow2
-from tpu_als.ops.topk import NEG_INF
+from tpu_als.ops.topk import (
+    NEG_INF,
+    shortlist_columns,
+    shortlist_plan,
+    shortlist_topk,
+)
 
 # how far a rescored score may sit from the chunked kernel's, in units in
 # the last place of the row's largest score (module docstring)
 SCORE_ULPS = 16
 
 
-@jax.jit
-def _quantize_rows(X):
+@functools.partial(jax.jit, static_argnames=("pad",))
+def _quantize_rows(X, pad=0):
     """Symmetric per-row int8: scale = max|row| / 127 (zero rows get
-    scale 1 so the division is safe and the row quantizes to zeros)."""
+    scale 1 so the division is safe and the row quantizes to zeros).
+    ``pad`` appends that many zero rows, as quantized zero rows come out
+    (zeros, scale 1), written with the rest: no second copy of a large
+    catalog."""
     s = jnp.max(jnp.abs(X), axis=1) / 127.0
     s = jnp.where(s == 0.0, 1.0, s).astype(jnp.float32)
     q = jnp.clip(jnp.round(X / s[:, None]), -127, 127).astype(jnp.int8)
+    if pad:
+        q = jnp.pad(q, ((0, pad), (0, 0)))
+        s = jnp.pad(s, (0, pad), constant_values=1.0)
     return q, s
 
 
@@ -106,7 +129,7 @@ def _int8_topk(U, Vq, sv, V, valid, k, shortlist_k):
                      preferred_element_type=jnp.int32)
     approx = acc.astype(jnp.float32) * su[:, None] * sv[None, :]
     approx = jnp.where(valid[None, :], approx, NEG_INF)
-    _, cand = jax.lax.top_k(approx, shortlist_k)       # [n, sk]
+    _, cand = shortlist_topk(approx, shortlist_k)      # [n, sk]
     # exact f32 rescore with the chunked kernel's own contraction shape:
     # full U batch x gathered catalog columns (see module docstring)
     Vc = jnp.take(V, cand.reshape(-1), axis=0)         # [n*sk, r]
@@ -129,7 +152,9 @@ def _int8_topk_delta(U, Vq, sv, V, valid, drows, dVq, dsv, dV, dvalid,
     the base path (see module docstring for why this stays bitwise).
 
     ``drows`` maps segment slots to logical catalog ids; padding slots
-    carry ``n_base`` (out of base scatter range, ``dvalid`` False).
+    carry ``Vq.shape[0]`` (out of base scatter range, ``dvalid`` False).
+    An appended id may fall on a block-padding column of ``Vq``: invalid
+    already, so marking it overridden changes nothing.
     ``last_id`` clamps returned ids into the logical catalog.
     """
     n = U.shape[0]
@@ -148,7 +173,7 @@ def _int8_topk_delta(U, Vq, sv, V, valid, drows, dVq, dsv, dV, dvalid,
     approx_d = acc_d.astype(jnp.float32) * su[:, None] * dsv[None, :]
     approx_d = jnp.where(dvalid[None, :], approx_d, NEG_INF)
     approx = jnp.concatenate([approx_b, approx_d], axis=1)
-    _, cand = jax.lax.top_k(approx, shortlist_k)    # positions in nb+d
+    _, cand = shortlist_topk(approx, shortlist_k)   # positions in nb+d
     flat = cand.reshape(-1)
     in_base = flat < nb
     base_ix = jnp.minimum(flat, nb - 1)
@@ -185,13 +210,23 @@ class Int8CandidateIndex:
         if Ni == 0:
             raise ValueError("cannot index an empty catalog")
         self.V = V
-        self.valid = (jnp.ones(Ni, dtype=jnp.bool_) if item_valid is None
-                      else jnp.asarray(item_valid, dtype=jnp.bool_))
-        self.Vq, self.sv = _quantize_rows(V)
         self.n_items = Ni
         self.shortlist_k = min(int(shortlist_k), Ni)
+        # whole shortlist blocks (module docstring); 0 on a small catalog
+        pad = shortlist_columns(Ni, self.shortlist_k) - Ni
+        valid = (jnp.ones(Ni, dtype=jnp.bool_) if item_valid is None
+                 else jnp.asarray(item_valid, dtype=jnp.bool_))
+        self.valid = jnp.pad(valid, (0, pad)) if pad else valid
+        self.Vq, self.sv = _quantize_rows(V, pad=pad)
         self.seq = seq
         self._clear_delta()
+
+    def shortlist_plan(self):
+        """The selection :meth:`topk` compiles for this index as it
+        stands (delta segment included): the
+        ``ops.topk.shortlist_plan`` of its score matrix."""
+        return shortlist_plan(int(self.Vq.shape[0]) + self.delta_slots,
+                              self.shortlist_k)
 
     # -- delta segment (incremental re-quantization) -------------------
 
@@ -207,13 +242,20 @@ class Int8CandidateIndex:
 
     @property
     def n_base(self):
-        """Rows held by the base (pre-delta) arrays."""
-        return int(self.Vq.shape[0])
+        """Rows held by the base (pre-delta) arrays, block padding of
+        the quantized ones not counted."""
+        return int(self.V.shape[0])
 
     @property
     def delta_count(self):
         """Rows currently carried by the delta segment."""
         return int(self.d_rows.size)
+
+    @property
+    def delta_slots(self):
+        """Columns the delta segment adds to the score matrix: its rows
+        padded to a power of two (0 without a segment)."""
+        return _next_pow2(self.delta_count) if self.delta_count else 0
 
     def _copy_shell(self, seq):
         new = object.__new__(type(self))
@@ -310,13 +352,19 @@ class Int8CandidateIndex:
         if not self.d_rows.size:
             return self._copy_shell(seq)
         r = int(self.V.shape[1])
-        grow = self.n_items - self.n_base
+        nb = self.n_base
+        grow = self.n_items - nb
         V, Vq, sv, valid = self.V, self.Vq, self.sv, self.valid
         if grow:
             V = jnp.concatenate([V, jnp.zeros((grow, r), jnp.float32)])
-            Vq = jnp.concatenate([Vq, jnp.zeros((grow, r), jnp.int8)])
-            sv = jnp.concatenate([sv, jnp.ones(grow, jnp.float32)])
-            valid = jnp.concatenate([valid, jnp.zeros(grow, jnp.bool_)])
+        # appended rows and the grown catalog's block padding in one step
+        cols = shortlist_columns(self.n_items, self.shortlist_k)
+        if cols != int(Vq.shape[0]):
+            more = cols - nb
+            Vq = jnp.concatenate([Vq[:nb], jnp.zeros((more, r), jnp.int8)])
+            sv = jnp.concatenate([sv[:nb], jnp.ones(more, jnp.float32)])
+            valid = jnp.concatenate([valid[:nb],
+                                     jnp.zeros(more, jnp.bool_)])
         ix = jnp.asarray(self.d_rows, dtype=jnp.int32)
         new = self._copy_shell(seq)
         new.V = V.at[ix].set(jnp.asarray(self._dV))
@@ -328,13 +376,12 @@ class Int8CandidateIndex:
 
     def _device_delta(self):
         """Padded device mirrors of the segment (built once per delta
-        generation; padding slots carry id ``n_base`` — dropped by the
-        kernel's scatter — and ``valid=False``)."""
+        generation; padding slots carry the id one past the quantized
+        base — dropped by the kernel's scatter — and ``valid=False``)."""
         if self._dev_delta is None:
-            d = self.delta_count
-            d_pad = _next_pow2(d)
+            d, d_pad = self.delta_count, self.delta_slots
             r = int(self.V.shape[1])
-            rows = np.full(d_pad, self.n_base, dtype=np.int32)
+            rows = np.full(d_pad, int(self.Vq.shape[0]), dtype=np.int32)
             rows[:d] = self.d_rows
             dV = np.zeros((d_pad, r), dtype=np.float32)
             dV[:d] = self._dV
@@ -359,7 +406,7 @@ class Int8CandidateIndex:
 
     def nbytes_quantized(self):
         """HBM the shortlist pass reads per batch (vs 4x for f32)."""
-        base = int(np.prod(self.Vq.shape)) + 4 * self.n_base
+        base = int(np.prod(self.Vq.shape)) + 4 * int(self.sv.shape[0])
         r = int(self.V.shape[1])
         return base + self.delta_count * (r + 4)
 
@@ -370,8 +417,9 @@ class Int8CandidateIndex:
         ``chunked_topk_scores`` to ``SCORE_ULPS`` (see module docstring
         for the contract and its conditions).  ``k`` is capped by the shortlist, the shortlist by
         the catalog.  With a delta segment live the shortlist runs over
-        base + segment; without one this is byte-for-byte the original
-        single-kernel path.
+        base + segment, without one over the base alone; either way the
+        selection is :meth:`shortlist_plan`'s (one ``top_k`` on a small
+        catalog, two exact stages on a large one: same candidates).
         """
         sk = self.shortlist_k if shortlist_k is None else \
             min(int(shortlist_k), self.n_items)
@@ -449,7 +497,7 @@ def _build_sharded_int8(mesh, k, k_loc, sk_loc, ni_loc, has_delta):
         else:
             base_ok = valid
             approx = jnp.where(base_ok[None, :], approx, NEG_INF)
-        _, cand = jax.lax.top_k(approx, sk_loc)
+        _, cand = shortlist_topk(approx, sk_loc)
         flat = cand.reshape(-1)
         if has_delta:
             in_base = flat < ni_loc
@@ -556,6 +604,10 @@ class ShardedInt8Index(Int8CandidateIndex):
         self.seq = seq
         self._clear_delta()
 
+    def shortlist_plan(self):
+        cols = self.ni_loc + self.delta_slots     # what one shard scores
+        return shortlist_plan(cols, min(self.shortlist_k, cols))
+
     def _copy_extra(self, new):
         new.mesh = self.mesh
         new.n_shards = self.n_shards
@@ -638,8 +690,7 @@ class ShardedInt8Index(Int8CandidateIndex):
                 "contain at least k candidates")
         U = jnp.asarray(U, dtype=jnp.float32)
         has_delta = bool(self.delta_count)
-        d_pad = _next_pow2(self.delta_count) if has_delta else 0
-        sk_loc = min(sk, self.ni_loc + d_pad)
+        sk_loc = min(sk, self.ni_loc + self.delta_slots)
         k_loc = min(int(k), sk_loc)
         fn = _build_sharded_int8(self.mesh, int(k), k_loc, sk_loc,
                                  self.ni_loc, has_delta)

@@ -1143,14 +1143,10 @@ def run_foldin(args):
 
     srv = FoldInServer(model)
     t0 = time.time()
-    # startup prewarm: compile the pow2 shape grid the batch size implies
-    # (touched-user rows pad to at most next_pow2(batch), capped by the
+    # startup prewarm: compile and run the padded shapes the batch size
+    # implies (touched-user rows up to the batch, capped by the
     # 1000-hot-user pool), so latency quantiles measure serving, not jits
-    from tpu_als.core.ratings import _next_pow2
-
-    cap = _next_pow2(min(args.foldin_batch, 1000))
-    rows = tuple(sorted({max(64, cap // 4), max(64, cap // 2), cap}))
-    srv.prewarm(rows=rows, widths=(2, 4, 8, 16, 32, 64, 128))
+    srv.prewarm(rows=(min(args.foldin_batch, 1000),), widths=(128,))
     prewarm_s = time.time() - t0
     log(f"prewarm: {prewarm_s:.1f}s")
     rng = np.random.default_rng(1)

@@ -68,7 +68,7 @@ def test_foldin_server_prewarm_matches_serving_shapes(rng):
     # no new cache entry (its latency is serve-only)
     model, frame = _fitted(rng)
     srv = FoldInServer(model)
-    srv.prewarm(rows=(4,), widths=(8,))
+    srv.prewarm(rows=(8,), widths=(8,))
     from tpu_als.core import foldin as foldin_mod
 
     sizes0 = foldin_mod._fold_in_jit._cache_size()
@@ -78,8 +78,8 @@ def test_foldin_server_prewarm_matches_serving_shapes(rng):
             np.array([0, 1, 2, 3, 4, 5, 6])),
         "rating": np.full(7, 4.0, np.float32),
     })
-    srv.update(batch)  # 3 touched users -> rows pad to 4; max count 5 ->
-    # width pads to 8: exactly the prewarmed (4, 8) entry
+    srv.update(batch)  # 3 touched users -> rows pad to 8; max count 5 ->
+    # width pads to 8: exactly the prewarmed (8, 8) entry
     assert foldin_mod._fold_in_jit._cache_size() == sizes0
 
 

@@ -19,6 +19,7 @@ Package map (SURVEY.md §7):
   api/       Param system, ALS Estimator / ALSModel, evaluators, tuning
   io/        MovieLens loaders, checkpoint/persistence
   stream/    micro-batch fold-in driver
+  live/      rating events -> fold-in -> incremental publish, under serving
   models/    two-tower retrieval model warm-started from ALS factors
 """
 
@@ -45,5 +46,7 @@ from tpu_als.api.tuning import (  # noqa: F401
     TrainValidationSplit,
     TrainValidationSplitModel,
 )
+from tpu_als.core.ratings import IdMap  # noqa: F401
+from tpu_als.live import LiveUpdater  # noqa: F401
 from tpu_als.stream.microbatch import FoldInServer  # noqa: F401
 from tpu_als.utils.frame import ColumnarFrame  # noqa: F401

@@ -100,7 +100,8 @@ TRACE_RECORD_RE = re.compile(
 # profiler-span literals: a ``TraceAnnotation("...")`` name is what a
 # trace reader keys on (benchmark/program_spans.py reads the engine
 # thread's ``serve.`` spans by name), so it is vocabulary like the rest:
-# declared in schema.SERVE_BATCH_SPAN_KEYS
+# declared in schema.SERVE_BATCH_SPAN_KEYS (the updater thread's ``live.``
+# spans: schema.LIVE_BATCH_SPAN_KEYS)
 ANNOTATION_RE = re.compile(
     r"\bTraceAnnotation\(\s*(?P<q>['\"])(?P<name>[^'\"]+)(?P=q)")
 
@@ -337,7 +338,7 @@ def check_tenant_vocabulary(repo=REPO):
     reserved = set(getattr(schema, "FLIGHT_RESERVED", ())) \
         | {"tenant", "trace_id", "trace_ids"}
     for attr in ("SERVE_SPAN_KEYS", "SERVE_BATCH_SPAN_KEYS",
-                 "LIVE_SPAN_KEYS"):
+                 "LIVE_SPAN_KEYS", "LIVE_BATCH_SPAN_KEYS"):
         overlap = sorted(set(getattr(schema, attr, ())) & reserved)
         if overlap:
             errors.append(
@@ -400,18 +401,20 @@ def check_trace_vocabulary(repo=REPO):
                 f"tpu_als/obs/schema.py: TRACE_SPANS declares {name!r} "
                 "but no call site under tpu_als/ records it — dead "
                 "vocabulary (remove it or record the hop)")
-    annotated = set()
-    for path in py_files([os.path.join(repo, "tpu_als", "serving")]):
-        with open(path, encoding="utf-8") as f:
-            annotated |= {m.group("name")
-                          for m in ANNOTATION_RE.finditer(f.read())}
-    for name in getattr(schema, "SERVE_BATCH_SPAN_KEYS", ()):
-        if name not in annotated:
-            errors.append(
-                "tpu_als/obs/schema.py: SERVE_BATCH_SPAN_KEYS declares "
-                f"{name!r} but no TraceAnnotation under tpu_als/serving/ "
-                "opens it — the batch record and the trace readers "
-                "would carry a phase nothing times")
+    for attr, package in (("SERVE_BATCH_SPAN_KEYS", "serving"),
+                          ("LIVE_BATCH_SPAN_KEYS", "live")):
+        annotated = set()
+        for path in py_files([os.path.join(repo, "tpu_als", package)]):
+            with open(path, encoding="utf-8") as f:
+                annotated |= {m.group("name")
+                              for m in ANNOTATION_RE.finditer(f.read())}
+        for name in getattr(schema, attr, ()):
+            if name not in annotated:
+                errors.append(
+                    f"tpu_als/obs/schema.py: {attr} declares "
+                    f"{name!r} but no TraceAnnotation under "
+                    f"tpu_als/{package}/ opens it — the batch record and "
+                    "the trace readers would carry a phase nothing times")
     return errors
 
 
@@ -566,7 +569,8 @@ def check_file(path, repo=REPO):
                         "tpu_als.obs.schema.METRICS)")
 
     if not in_obs:
-        batch_spans = getattr(schema, "SERVE_BATCH_SPAN_KEYS", ())
+        batch_spans = (getattr(schema, "SERVE_BATCH_SPAN_KEYS", ())
+                       + getattr(schema, "LIVE_BATCH_SPAN_KEYS", ()))
         for m in ANNOTATION_RE.finditer(text):
             name = m.group("name")
             if name not in batch_spans:
@@ -574,8 +578,8 @@ def check_file(path, repo=REPO):
                 add(lineno,
                     f"{rel}:{lineno}: profiler span {name!r} is not "
                     "declared in tpu_als.obs.schema."
-                    "SERVE_BATCH_SPAN_KEYS — trace readers key on "
-                    "declared span names only")
+                    "SERVE_BATCH_SPAN_KEYS or LIVE_BATCH_SPAN_KEYS — "
+                    "trace readers key on declared span names only")
         trace_spans = getattr(schema, "TRACE_SPANS", ())
         for regex in (TRACE_START_RE, TRACE_RECORD_RE):
             for m in regex.finditer(text):

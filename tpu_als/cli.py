@@ -856,7 +856,7 @@ def _serve_bench_tenants(args):
     updaters = {}
     if args.update_qps > 0:
         from tpu_als.api.estimator import ALSModel
-        from tpu_als.core.ratings import IdMap, _next_pow2
+        from tpu_als.core.ratings import IdMap
         from tpu_als.stream.microbatch import FoldInServer
 
         with obs.span("serve_bench.live_prewarm"):
@@ -876,10 +876,7 @@ def _serve_bench_tenants(args):
                     max_wait_ms=args.update_max_wait_ms,
                     slo_s=args.freshness_slo_ms / 1e3)
                 if name == names[0]:
-                    ladder = tuple(sorted(
-                        {_next_pow2(max(1, upd.max_batch >> s))
-                         for s in range(upd.max_batch.bit_length())}))
-                    srv.prewarm(rows=ladder, widths=(1, 2),
+                    srv.prewarm(rows=(upd.max_batch,), widths=(2,),
                                 sides=("user",))
                 updaters[name] = upd
 
@@ -1102,7 +1099,7 @@ def cmd_serve_bench(args):
     updater, model, upd_stats = None, None, {"shed": 0}
     if args.update_qps > 0:
         from tpu_als.api.estimator import ALSModel
-        from tpu_als.core.ratings import IdMap, _next_pow2
+        from tpu_als.core.ratings import IdMap
         from tpu_als.live import LiveUpdater
         from tpu_als.stream.microbatch import FoldInServer
 
@@ -1123,11 +1120,9 @@ def cmd_serve_bench(args):
             max_wait_ms=args.update_max_wait_ms,
             slo_s=args.freshness_slo_ms / 1e3,
             fold_items=args.update_items)
-        ladder = tuple(sorted({_next_pow2(max(1, updater.max_batch >> s))
-                               for s in range(updater.max_batch.bit_length())}))
         with obs.span("serve_bench.live_prewarm"):
             srv.prewarm(
-                rows=ladder, widths=(1, 2),
+                rows=(updater.max_batch,), widths=(2,),
                 sides=(("user", "item") if args.update_items
                        else ("user",)))
             if args.update_items and not args.exact:

@@ -179,7 +179,7 @@ def _resolve_solve_path_walk(cfg: AlsConfig, rank, matfree_capable=True):
     label, because that is what executes.
     """
     from tpu_als.ops import pallas_lanes, pallas_solve
-    from tpu_als.ops.solve import auto_solve_backend
+    from tpu_als.ops.solve import SOLVE_PATH_NAMES, auto_solve_backend
     from tpu_als.utils.platform import on_tpu
 
     tpu = on_tpu()
@@ -206,12 +206,7 @@ def _resolve_solve_path_walk(cfg: AlsConfig, rank, matfree_capable=True):
         # forced DMA-gather NE build; the solve still walks the probe
         # order (the kernel writes A/b, the solve stays on lanes/xla).
         # Off-TPU the kernel runs in interpret mode, so no gate here.
-        base = {
-            "lanes": "einsum+pallas_lanes",
-            "lanes_blocked": "einsum+pallas_lanes_blocked",
-            "pallas": "einsum+pallas_cholesky",
-            "xla": "einsum+xla_cholesky",
-        }[auto_solve_backend(rank)]
+        base = SOLVE_PATH_NAMES[auto_solve_backend(rank)]
         path = "gatherfused" + base[len("einsum"):]
     elif cfg.cg_iters > 0:
         # inexact ALS: no factorization, no Pallas kernel, no probe —
@@ -223,12 +218,7 @@ def _resolve_solve_path_walk(cfg: AlsConfig, rank, matfree_capable=True):
     else:
         # the same probe walk solve_spd's dispatch runs — prewarming here
         # IS the prewarm contract; the re-reads below are cache hits
-        path = {
-            "lanes": "einsum+pallas_lanes",
-            "lanes_blocked": "einsum+pallas_lanes_blocked",
-            "pallas": "einsum+pallas_cholesky",
-            "xla": "einsum+xla_cholesky",
-        }[auto_solve_backend(rank)]
+        path = SOLVE_PATH_NAMES[auto_solve_backend(rank)]
         from tpu_als.ops import pallas_lanes_blocked
 
         lanes_ok = bool(tpu and pallas_lanes.available(rank))

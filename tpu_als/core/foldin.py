@@ -14,18 +14,53 @@ the stream driver so repeated micro-batches hit the jit cache.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from tpu_als.ops.solve import (
     DEFAULT_JITTER,
+    SOLVE_PATH_NAMES,
+    auto_solve_backend,
     compute_yty,
     normal_eq_explicit,
     normal_eq_implicit,
     solve_nnls,
     solve_spd,
 )
+
+
+# The lane kernels solve 128 systems side by side, one per lane, and are
+# admitted by a probe that compiles them (minutes at rank 256): below four
+# lane groups there is nothing to fill them with and nothing to win back
+# the probe, so a micro-batch's handful of systems takes XLA's Cholesky
+FEW_SYSTEMS = 512
+
+
+def solve_path(rank, rows, nonnegative=False):
+    """``(backend, path, why)`` of the solve for ``rows`` systems of this
+    rank, from what the caller can see before it traces: up to
+    ``FEW_SYSTEMS`` rows the XLA Cholesky, above them the probe walk the
+    training step takes (:func:`tpu_als.ops.solve.auto_solve_backend`,
+    eager: a probe cannot run inside a trace).  ``path`` is the name
+    ``core.als.resolve_solve_path`` gives the same choice."""
+    if nonnegative:
+        return "xla", "einsum+nnls", "nonnegative"
+    if rows <= FEW_SYSTEMS:
+        backend, why = "xla", f"{rows} systems, at most {FEW_SYSTEMS}"
+    else:
+        backend, why = auto_solve_backend(rank), "probe walk"
+    return backend, SOLVE_PATH_NAMES[backend], why
+
+
+@functools.partial(jax.jit, static_argnames=("capacity",))
+def pad_rows(F, *, capacity):
+    """``F`` with zero rows up to ``capacity``, on the device: the table a
+    live path appends to without a change of shape (a gather or a lookup
+    never addresses the spare rows; ``F^T F`` is unchanged by them)."""
+    return jnp.pad(F, ((0, capacity - F.shape[0]), (0, 0)))
 
 
 def fold_in(
@@ -46,23 +81,22 @@ def fold_in(
     cols/vals/mask: [n, w] padded CSR rows (same convention as
     tpu_als.core.ratings).  Returns new factors [n, rank].
 
-    Eager wrapper: probes the solve kernels before tracing (a probe inside
-    the jit trace cannot run and would pin the fallback path into the jit
-    cache — ops.solve.prewarm_solve), then dispatches to the jitted body.
+    Eager wrapper: settles the solve's backend before tracing
+    (:func:`solve_path`; a probe inside the jit trace cannot run and
+    would pin the fallback path into the jit cache), then dispatches to the
+    jitted body, one program per (rows, width, backend).
     """
-    from tpu_als.ops.solve import prewarm_solve
-
-    if not nonnegative:
-        prewarm_solve(V.shape[-1])
+    backend = solve_path(V.shape[-1], cols.shape[0], nonnegative)[0]
     return _fold_in_jit(V, cols, vals, mask, reg_param,
                         implicit_prefs=implicit_prefs, alpha=alpha,
                         nonnegative=nonnegative, nnls_sweeps=nnls_sweeps,
-                        YtY=YtY, jitter=jitter)
+                        YtY=YtY, jitter=jitter, backend=backend)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("implicit_prefs", "nonnegative", "nnls_sweeps", "jitter"),
+    static_argnames=("implicit_prefs", "nonnegative", "nnls_sweeps", "jitter",
+                     "backend"),
 )
 def _fold_in_jit(
     V,
@@ -76,14 +110,29 @@ def _fold_in_jit(
     nnls_sweeps=32,
     YtY=None,
     jitter=DEFAULT_JITTER,
+    backend="auto",
 ):
-    Vg = V[cols]
-    if implicit_prefs:
-        if YtY is None:
-            YtY = compute_yty(V)
-        A, b, count = normal_eq_implicit(Vg, vals, mask, reg_param, alpha, YtY)
-    else:
-        A, b, count = normal_eq_explicit(Vg, vals, mask, reg_param)
-    if nonnegative:
-        return solve_nnls(A, b, count, sweeps=nnls_sweeps, jitter=jitter)
-    return solve_spd(A, b, count, jitter=jitter)
+    # A handful of systems builds its normal equations in true float32:
+    # six bf16 passes of matrices this small cost nothing beside the
+    # program's launch, and one pass costs the published rows 6e-3 to
+    # 8e-3 of their length on a v5e (median; XLA's Cholesky multiplies in
+    # float32 whatever the default) — more than serving's rescore adds
+    few = cols.shape[0] <= FEW_SYSTEMS
+    # the scopes name the program's two halves in every operation's
+    # op_name, for whoever reads the compiled program
+    with jax.named_scope("live.foldin.gram"), (
+            jax.default_matmul_precision("highest") if few
+            else contextlib.nullcontext()):
+        Vg = V[cols]
+        if implicit_prefs:
+            if YtY is None:
+                YtY = compute_yty(V)
+            A, b, count = normal_eq_implicit(Vg, vals, mask, reg_param,
+                                             alpha, YtY)
+        else:
+            A, b, count = normal_eq_explicit(Vg, vals, mask, reg_param)
+    with jax.named_scope("live.foldin.solve"):
+        if nonnegative:
+            return solve_nnls(A, b, count, sweeps=nnls_sweeps,
+                              jitter=jitter)
+        return solve_spd(A, b, count, jitter=jitter, backend=backend)

@@ -102,7 +102,9 @@ class IdMap:
     ids: np.ndarray  # [n] original ids, position = dense index
 
     def __post_init__(self):
-        self._lookup = None
+        self._lookup = None   # (sorted ids, their dense rows) of ids[:k]
+        self._tail = None     # the same of ids[k:], the ids appended since
+        self._buf = None      # ``ids`` is a view of it once ids are appended
 
     def __len__(self):
         return len(self.ids)
@@ -113,14 +115,52 @@ class IdMap:
         if self._lookup is None:
             order = np.argsort(self.ids, kind="stable")
             self._lookup = (self.ids[order], order)
-        sorted_ids, order = self._lookup
-        pos = np.searchsorted(sorted_ids, original)
-        pos = np.clip(pos, 0, len(sorted_ids) - 1)
-        hit = sorted_ids[pos] == original
-        return np.where(hit, order[pos], missing).astype(np.int64)
+            self._tail = None
+        out = np.full(original.shape, missing, dtype=np.int64)
+        for tier in (self._lookup, self._tail):
+            if tier is None or not len(tier[0]):
+                continue
+            sorted_ids, order = tier
+            pos = np.searchsorted(sorted_ids, original)
+            pos = np.clip(pos, 0, len(sorted_ids) - 1)
+            hit = sorted_ids[pos] == original
+            out = np.where(hit, order[pos], out)
+        return out
 
     def to_original(self, dense):
         return self.ids[np.asarray(dense)]
+
+    def reserve(self, capacity):
+        """Room for ``capacity`` ids, so that :meth:`append` copies none of
+        those already there."""
+        if self._buf is None or len(self._buf) < capacity:
+            buf = np.empty(int(capacity), dtype=self.ids.dtype)
+            buf[:len(self.ids)] = self.ids
+            self._buf, self.ids = buf, buf[:len(self.ids)]
+
+    def append(self, new_ids):
+        """Take ids the map does not hold yet (each once) at the next dense
+        indices, which are returned.  Costs O(new + appended so far), not
+        O(all): the lookup of the ids sorted at first use stays, the
+        appended ones are kept sorted beside it and folded into it only
+        once they are a sixteenth of it (then the next lookup sorts all)."""
+        new_ids = np.asarray(new_ids, dtype=self.ids.dtype)
+        n, k = len(self.ids), len(new_ids)
+        if n + k > (len(self._buf) if self._buf is not None else 0):
+            self.reserve(row_capacity(n + k))
+        self._buf[n:n + k] = new_ids
+        self.ids = self._buf[:n + k]
+        dense = np.arange(n, n + k, dtype=np.int64)
+        if self._lookup is not None:
+            t_ids, t_dense = self._tail or (new_ids[:0], dense[:0])
+            if len(t_ids) + k > max(4096, len(self._lookup[0]) >> 4):
+                self._lookup = None
+            else:
+                by_id = np.argsort(new_ids, kind="stable")
+                at = np.searchsorted(t_ids, new_ids[by_id])
+                self._tail = (np.insert(t_ids, at, new_ids[by_id]),
+                              np.insert(t_dense, at, dense[by_id]))
+        return dense
 
 
 def remap_ids(raw):
@@ -132,6 +172,34 @@ def remap_ids(raw):
 
 def _next_pow2(x):
     return 1 << int(max(0, int(np.ceil(np.log2(max(1, x))))))
+
+
+def row_capacity(n):
+    """Rows to allocate for a table of ``n`` live rows that entities are
+    appended to between refits: a 64th more (1,024 at least), in whole
+    512s.  An array of this many rows keeps its shape, and every program
+    compiled for it, until the spare rows are used up."""
+    return -(-(int(n) + max(1024, int(n) >> 6)) // 512) * 512
+
+
+def pads_up_to(n):
+    """Every padded size a count of up to ``n`` can take in the live
+    path's programs: 8, 64, 512, 4096, ... — few enough to compile and run
+    them all before the stream starts."""
+    pads = [8]
+    while pads[-1] < n:
+        pads.append(pads[-1] * 8)
+    return tuple(pads)
+
+
+def pad_for(n):
+    """The least of those sizes that holds ``n``."""
+    return pads_up_to(n)[-1]
+
+
+# what the live path warms by default: up to 512 touched entities a
+# micro-batch (the live updater's max_batch is 256), of up to 512 ratings
+LIVE_PADS = pads_up_to(512)
 
 
 def entity_widths(counts, min_width, growth=2.0):

@@ -25,6 +25,14 @@ single measurable quantity:
    O(touched) delta re-quantization for item batches — never a full
    O(catalog) rebuild while the live index is healthy.
 
+The thread's cycle is on the profiler's timeline as ``TraceAnnotation``
+spans, always on, the write path's counterpart of the engine thread's
+(``obs.schema.LIVE_BATCH_SPAN_KEYS``): ``live.idle``,
+``live.batch.coalesce``, and ``live.batch`` (stats ``seq``, ``events``,
+``users``, ``new_users``, ``width``, ``mode``) around
+``live.batch.foldin`` and ``live.batch.publish``.  A trace reader keys on
+those names.
+
 Freshness (``live.freshness_seconds``) is per EVENT, arrival →
 publish-visible, so the histogram's p99 is exactly the SLO quantity:
 how stale can a rating be before it influences recommendations.  A
@@ -40,6 +48,7 @@ import threading
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from tpu_als import obs
 from tpu_als.core.ratings import invalid_rating_mask
@@ -94,6 +103,7 @@ class LiveUpdater:
                                      span_keys=LIVE_SPAN_KEYS,
                                      labels=self._labels)
         self._queue = []
+        self._batch_seq = 0
         self._cond = threading.Condition()
         self._closed = False
         self._thread = None
@@ -129,8 +139,12 @@ class LiveUpdater:
 
     # -- lifecycle ----------------------------------------------------
     def start(self):
+        """Have the engine run the row writes this updater's publishes
+        will make (up to ``max_batch`` users a publish), so that none
+        compiles or loads under traffic, then start the loop."""
         if self._thread is not None:
             raise RuntimeError("updater already started")
+        self.engine.warmup_publish(self.max_batch)
         self._thread = threading.Thread(
             target=self._run, name="tpu-als-live", daemon=True)
         self._thread.start()
@@ -161,18 +175,22 @@ class LiveUpdater:
             if not self._queue:
                 if self._closed:
                     return None
-                self._cond.wait(0.05)
+                with TraceAnnotation("live.idle"):
+                    self._cond.wait(0.05)
                 if not self._queue:
                     return None
             t_oldest = self._queue[0][3]
-            while (len(self._queue) < self.max_batch
-                   and not self._closed):
-                left = self.max_wait_s - (time.perf_counter() - t_oldest)
-                if left <= 0:
-                    break
-                self._cond.wait(left)
-            batch = self._queue[:self.max_batch]
-            del self._queue[:self.max_batch]
+            with TraceAnnotation("live.batch.coalesce",
+                                 waiting=len(self._queue)):
+                while (len(self._queue) < self.max_batch
+                       and not self._closed):
+                    left = self.max_wait_s - (time.perf_counter()
+                                              - t_oldest)
+                    if left <= 0:
+                        break
+                    self._cond.wait(left)
+                batch = self._queue[:self.max_batch]
+                del self._queue[:self.max_batch]
             obs.gauge("live.queue_depth", len(self._queue),
                       **self._labels)
             return batch
@@ -185,24 +203,29 @@ class LiveUpdater:
                     if self._closed and not self._queue:
                         return
                 continue
+            self._batch_seq += 1
             try:
-                self._process(batch)
+                with TraceAnnotation("live.batch",
+                                     seq=self._batch_seq) as whole:
+                    self._process(batch, whole)
             except BaseException as e:  # noqa: BLE001 — loop must survive
                 if not isinstance(e, faults.InjectedFault):
                     obs.emit("warning", what="live.update",
                              reason=f"{type(e).__name__}: {e}")
 
-    def _process(self, batch):
+    def _process(self, batch, whole):
+        """Fold one popped batch in and publish it; ``whole`` is the
+        ``live.batch`` span around the call, which takes the batch's
+        sizes as its stats."""
         t0 = time.perf_counter()
-        users = np.asarray([e[0] for e in batch])
-        items = np.asarray([e[1] for e in batch])
-        ratings = np.asarray([e[2] for e in batch], dtype=np.float32)
-        arrivals = np.asarray([e[3] for e in batch])
+        users, items, ratings, arrivals, ctxs = map(list, zip(*batch))
+        users, items = np.asarray(users), np.asarray(items)
+        ratings = np.asarray(ratings, dtype=np.float32)
+        arrivals = np.asarray(arrivals)
         # chain the queue hop per event (its own wait, not the batch's)
-        ctxs = [tracing.record_span(e[4], "live.queue",
-                                    seconds=t0 - e[3])
-                if e[4] is not None else None
-                for e in batch]
+        ctxs = [tracing.record_span(c, "live.queue", seconds=t0 - a)
+                if c is not None else None
+                for c, a in zip(ctxs, arrivals)]
         queue_wait = t0 - float(arrivals.min())
 
         # quarantine BEFORE the factors can see a poisoned value — the
@@ -238,38 +261,48 @@ class LiveUpdater:
         frame = {p["userCol"]: users, p["itemCol"]: items,
                  p["ratingCol"]: ratings}
         tf = time.perf_counter()
-        touched_users = self.foldin.update(frame)
+        m = self.foldin.model
+        users_before = len(m._user_map)
         touched_item_rows = None
-        if self.fold_items:
-            t_items = self.foldin.update_items(frame)
-            touched_item_rows = self.foldin.model._item_map.to_dense(
-                np.asarray(t_items))
+        with TraceAnnotation("live.batch.foldin"):
+            touched_users = self.foldin.update(frame)
+            if self.fold_items:
+                t_items = self.foldin.update_items(frame)
+                touched_item_rows = m._item_map.to_dense(
+                    np.asarray(t_items))
         foldin_s = time.perf_counter() - tf
         ctxs = [tracing.record_span(c, "live.foldin", seconds=foldin_s)
                 if c is not None else None for c in ctxs]
 
         tp = time.perf_counter()
-        m = self.foldin.model
-        seq, mode = self.engine.publish_update(
-            m._U, m._V, touched_items=touched_item_rows, trace=ctxs)
+        with TraceAnnotation("live.batch.publish"):
+            # the rows the fold moved, and nothing else of either table
+            seq, mode = self.engine.publish_update(
+                m._U, m._V, touched_items=touched_item_rows,
+                touched_users=m._user_map.to_dense(touched_users),
+                trace=ctxs)
         publish_s = time.perf_counter() - tp
+        whole.set_metadata(
+            events=len(ratings), users=len(touched_users),
+            new_users=len(m._user_map) - users_before,
+            width=(self.foldin.stats[-1][3] if len(touched_users) else 0),
+            mode=mode)
         ctxs = [tracing.record_span(c, "live.publish",
                                     seconds=publish_s, seq=seq,
                                     mode=mode)
                 if c is not None else None for c in ctxs]
 
         done = time.perf_counter()
-        worst, worst_ctx = 0.0, None
-        for a, c in zip(arrivals, ctxs):
-            fr = done - float(a)
-            obs.histogram("live.freshness_seconds", fr, **self._labels)
+        fresh = done - arrivals
+        obs.histogram_many("live.freshness_seconds", fresh.tolist(),
+                           **self._labels)
+        for fr, c in zip(fresh, ctxs):
             # the terminal hop: this event's publish seq is now visible
             # to the score path; its seconds ARE the freshness sample
             if c is not None:
-                tracing.record_span(c, "live.visible", seconds=fr,
+                tracing.record_span(c, "live.visible", seconds=float(fr),
                                     seq=seq)
-            if fr > worst:
-                worst, worst_ctx = fr, c
+        worst, worst_ctx = float(fresh.max()), ctxs[int(fresh.argmax())]
         touched = len(touched_users) + (
             len(touched_item_rows) if touched_item_rows is not None
             else 0)
@@ -280,6 +313,10 @@ class LiveUpdater:
             {"queue_wait": queue_wait, "quarantine": quarantine_s,
              "foldin": foldin_s, "publish": publish_s},
             e2e_seconds=worst, seq=seq, mode=mode,
+            # for whoever runs no profiler: how many events became
+            # visible, and when on perf_counter's clock (the engine's
+            # batch records carry their ``t0`` the same way)
+            events=len(ratings), t_done=done,
             trace_ids=sorted({c.trace_id for c in ctxs
                               if c is not None}) or None)
         if self.slo_s is not None and worst > self.slo_s:

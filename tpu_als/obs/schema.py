@@ -138,6 +138,11 @@ METRICS = {
         "gauge", "events",
         "live-updater admission backlog sampled after each micro-batch "
         "dequeue"),
+    "live.publish_h2d_bytes": (
+        "counter", "bytes",
+        "bytes ServingEngine.publish_update sent host -> device: the "
+        "touched user rows and their indices on the incremental path, "
+        "whole tables where it had to re-place one"),
     "foldin.batch_rows": (
         "histogram", "rows",
         "entities solved per FoldInServer micro-batch (the padded "
@@ -211,6 +216,7 @@ LABELS = {
     "live.batch_rows": ("tenant",),
     "live.shed": ("tenant",),
     "live.queue_depth": ("tenant",),
+    "live.publish_h2d_bytes": ("tenant",),
     "tenancy.served_rows": ("tenant",),
     "tenancy.batch_errors": ("tenant",),
 }
@@ -272,6 +278,20 @@ SERVE_BATCH_SPAN_KEYS = (
     "serve.batch.complete",   # completing the tickets + their bookkeeping
 )
 LIVE_SPAN_KEYS = ("queue_wait", "quarantine", "foldin", "publish")
+# the updater thread's batch cycle as profiler spans, the write path's
+# counterpart of SERVE_BATCH_SPAN_KEYS (``TraceAnnotation`` in
+# live/updater.py, always on, disjoint but for ``live.batch`` around its
+# two phases).  Inside the device programs the same vocabulary goes on as
+# ``jax.named_scope``: ``live.foldin.gram`` / ``live.foldin.solve``
+# (core/foldin.py) and ``live.publish.scatter`` (serving/engine.py)
+LIVE_BATCH_SPAN_KEYS = (
+    "live.idle",              # blocked on an empty admission queue
+    "live.batch.coalesce",    # first event seen -> batch popped
+    "live.batch",             # all of _process (seq, events, users,
+    #                           new_users, width, mode)
+    "live.batch.foldin",      # FoldInServer.update (+ update_items)
+    "live.batch.publish",     # ServingEngine.publish_update
+)
 
 # field names every flight record (and its flight_record event) claims
 # structurally — span keys and label keys must stay disjoint from these
@@ -335,6 +355,12 @@ EVENTS = {
         "its shortlist selects, from ops.topk.shortlist_plan — stages 1 is "
         "one lax.top_k over all columns, 2 is block maxima then top_k "
         "over the winning blocks of block_len columns"),
+    "foldin_solve_path": (
+        ("side", "rank", "rows", "width", "path", "reason"),
+        "one per fold-in program FoldInServer.prewarm compiled and ran "
+        "(side user|item, padded rows x width): the solve it takes, under "
+        "core.als.resolve_solve_path's names, and why — from "
+        "core.foldin.solve_path, the function fold_in dispatches on"),
     "serve_degraded": (
         ("strategy", "reason"),
         "a sharded top-k request fell back to last-good gathered "

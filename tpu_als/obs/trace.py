@@ -176,6 +176,12 @@ class FlightRecorder:
             obs.emit("flight_record", trigger=trigger, **r)
         return len(recs)
 
+    def records(self):
+        """The ring's records, oldest first (copies): for a reader in the
+        process that wants them without a trigger."""
+        with self._lock:
+            return [dict(r) for r in self._ring]
+
     def __len__(self):
         with self._lock:
             return len(self._ring)

@@ -153,6 +153,14 @@ def compute_yty(V):
     return jnp.einsum("nr,ns->rs", V, V, preferred_element_type=jnp.float32)
 
 
+# what each backend of the SPD solve is called in a resolved solve path
+# (``core.als.resolve_solve_path``, the ``foldin_solve_path`` event)
+SOLVE_PATH_NAMES = {"lanes": "einsum+pallas_lanes",
+                    "lanes_blocked": "einsum+pallas_lanes_blocked",
+                    "pallas": "einsum+pallas_cholesky",
+                    "xla": "einsum+xla_cholesky"}
+
+
 def auto_solve_backend(rank):
     """THE preference-ordered probe walk for the SPD solve — the single
     source of truth shared by ``solve_spd``'s 'auto' branch,

@@ -494,7 +494,6 @@ def _cold_fit(ctx):
 def _cold_serve_start(ctx):
     from tpu_als.serving import ServingEngine
     from tpu_als.stream.microbatch import FoldInServer
-    from tpu_als.core.ratings import _next_pow2
 
     c = ctx.config
     model = ctx.state["model"]
@@ -508,8 +507,7 @@ def _cold_serve_start(ctx):
     # production startup discipline: the fold-in kernel shapes the new-
     # user batch will need are compiled BEFORE traffic arrives, so the
     # measured freshness window is fold-in + republish + serve, not jit
-    srv.prewarm(rows=(_next_pow2(c["new_users"]),),
-                widths=(_next_pow2(c["ratings_per"]),))
+    srv.prewarm(rows=(c["new_users"],), widths=(c["ratings_per"],))
     ctx.state.update(engine=engine, srv=srv)
 
 
@@ -919,7 +917,6 @@ def _poisoned_stream():
 
 def _cf_start(ctx):
     import tpu_als
-    from tpu_als.core.ratings import _next_pow2
     from tpu_als.io.movielens import synthetic_movielens
     from tpu_als.live import LiveUpdater
     from tpu_als.serving import ServingEngine
@@ -943,11 +940,7 @@ def _cf_start(ctx):
     # (history merge accretes ratings per entity across batches), and
     # one table doubling of headroom (appended users push the fixed-U
     # pad past its pow2 mid-stream otherwise).
-    rows, m = [], c["max_batch"]
-    while m >= 1:
-        rows.append(_next_pow2(m))
-        m //= 2
-    srv.prewarm(rows=tuple(sorted(set(rows))), widths=(1, 2, 4),
+    srv.prewarm(rows=(c["max_batch"],), widths=(4,),
                 sides=("user", "item"), growth=1)
     updater = LiveUpdater(
         engine, srv, max_batch=c["max_batch"],

@@ -21,7 +21,11 @@ tickets.  The pieces the rest of the stack plugs into:
   corrupt-mode fault) is never scored against — the batch takes the
   exact path and ``serving.fallback_exact`` counts it.
 - **Incremental publishes.**  :meth:`ServingEngine.publish_update` is
-  the live fold-in → publish path: a user-only fold-in re-tags the
+  the live fold-in → publish path: the user table lives on the device
+  with spare rows (``core.ratings.row_capacity``), so touched and
+  appended user rows are uploaded alone and written into a copy of it
+  — no shape changes, the pinned executables stay valid, and nothing of
+  the catalog crosses host→device; a user-only fold-in re-tags the
   current index (zero quantization), an item fold-in re-quantizes ONLY
   the touched/appended rows into the index's delta segment
   (``serving/index.py``), and the segment is folded back into the base
@@ -95,7 +99,14 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from tpu_als import obs
-from tpu_als.core.ratings import _next_pow2
+from tpu_als.core.foldin import pad_rows
+from tpu_als.core.ratings import (
+    LIVE_PADS,
+    _next_pow2,
+    pad_for,
+    pads_up_to,
+    row_capacity,
+)
 from tpu_als.obs import tracing
 from tpu_als.obs.schema import SERVE_BATCH_SPAN_KEYS
 from tpu_als.obs.trace import FlightRecorder
@@ -119,8 +130,11 @@ class NoModelPublished(RuntimeError):
 class _Published:
     """One immutable model generation; the engine swaps whole instances.
 
-    ``V``/``valid`` are device arrays on the local backend and HOST
-    numpy on mesh backends (see the module docstring); ``Vs``/``valids``
+    ``U`` holds ``n_users`` live rows and spare zero rows after them,
+    which no request addresses (``submit`` checks ids against
+    ``n_users``).  ``V``/``valid`` are device arrays on the local backend
+    and HOST numpy on mesh backends (see the module docstring);
+    ``Vs``/``valids``
     are the merge-ring backend's shard-resident padded catalog
     (``None`` elsewhere, or after a torn merge-ring publish — the
     score path then falls back exact against the fresh host catalog).
@@ -129,7 +143,7 @@ class _Published:
     __slots__ = ("seq", "U", "V", "valid", "index", "n_users", "rank",
                  "Vs", "valids", "ni_loc")
 
-    def __init__(self, seq, U, V, valid, index,
+    def __init__(self, seq, U, n_users, V, valid, index,
                  Vs=None, valids=None, ni_loc=0):
         self.seq = seq
         self.U = U
@@ -139,7 +153,7 @@ class _Published:
         self.Vs = Vs
         self.valids = valids
         self.ni_loc = int(ni_loc)
-        self.n_users = int(U.shape[0])
+        self.n_users = int(n_users)
         self.rank = int(U.shape[1])
 
 
@@ -205,6 +219,17 @@ def _scatter_catalog(Vs, valids, rows, vals, vmask):
     cache and only the touched payload crosses host→device."""
     return (Vs.at[rows].set(vals, mode="drop"),
             valids.at[rows].set(vmask, mode="drop"))
+
+
+@jax.jit
+def _scatter_users(U, rows, vals):
+    """The user table with ``vals`` written at ``rows``, as a new array:
+    the generation a batch in flight was dequeued with stays whole.
+    ``rows`` are padded up ``pad_for``'s ladder with an out-of-range
+    sentinel (``mode='drop'``), so the programs are few and only the
+    touched payload crosses host→device."""
+    with jax.named_scope("live.publish.scatter"):
+        return U.at[rows].set(vals, mode="drop")
 
 
 class ServingEngine:
@@ -395,6 +420,58 @@ class ServingEngine:
         return Vs, valids, ni_loc, "full"
 
     # -- model lifecycle ----------------------------------------------
+    @staticmethod
+    def _place_users(prev, U):
+        """``(U on the device with spare rows, live rows, bytes sent)``:
+        the whole table uploaded and padded there.  The capacity is the
+        live generation's while the table fits it (same shapes, same
+        programs), ``row_capacity`` of the table otherwise."""
+        n, rank = int(U.shape[0]), int(U.shape[1])
+        cap = row_capacity(n)
+        if prev is not None and prev.rank == rank \
+                and n <= int(prev.U.shape[0]):
+            cap = int(prev.U.shape[0])
+        # wait for it: the upload's buffer is freed as the padded copy is
+        # done, and what a publish allocates next (the catalog, its index)
+        # would otherwise be allocated beside it (+1.7 GB of peak)
+        return (pad_rows(jnp.asarray(U, dtype=jnp.float32),
+                         capacity=cap).block_until_ready(),
+                n, 4 * n * rank)
+
+    def _update_users(self, prev, U, touched_users):
+        """The next generation's user table from the live one: the
+        ``touched_users`` rows of ``U`` (and the rows appended since)
+        uploaded alone and written into a copy of it on the device —
+        O(touched) host work and traffic, no shape change.  Falls back to
+        :meth:`_place_users` where that cannot be (no row list, no live
+        generation, another rank, a shrunken table, spare rows used up,
+        a row outside the table)."""
+        n, rank = int(U.shape[0]), int(U.shape[1])
+        if (touched_users is not None and prev is not None
+                and prev.rank == rank
+                and prev.n_users <= n <= int(prev.U.shape[0])):
+            rows = np.union1d(
+                np.asarray(touched_users, dtype=np.int64).ravel(),
+                np.arange(prev.n_users, n))
+            if not rows.size:
+                return prev.U, n, 0
+            if 0 <= int(rows[0]) and int(rows[-1]) < n:
+                pad = pad_for(len(rows))
+                # the sentinel lies outside the table: dropped
+                rp = np.full(pad, prev.U.shape[0], dtype=np.int32)
+                rp[:len(rows)] = rows
+                vals = np.zeros((pad, rank), dtype=np.float32)
+                vals[:len(rows)] = U[rows]
+                return (_scatter_users(prev.U, jnp.asarray(rp),
+                                       jnp.asarray(vals)),
+                        n, rp.nbytes + vals.nbytes)
+        if touched_users is not None and prev is not None:
+            obs.emit("warning", what="serving.publish_update",
+                     reason=f"user rows rejected ({n} users against "
+                            f"{prev.n_users} live of {prev.U.shape[0]}), "
+                            "user table re-placed whole")
+        return self._place_users(prev, U)
+
     def publish(self, U, V, item_valid=None, quantize=True):
         """Swap in a new model generation atomically.
 
@@ -407,7 +484,7 @@ class ServingEngine:
         """
         t0 = time.perf_counter()
         mode = faults.check("serving.publish")
-        U = jnp.asarray(U, dtype=jnp.float32)
+        U, n_users, _ = self._place_users(self._model, U)
         Vh = np.asarray(V, dtype=np.float32)
         Ni = int(Vh.shape[0])
         validh = (np.ones(Ni, dtype=bool) if item_valid is None
@@ -443,7 +520,7 @@ class ServingEngine:
                              if self._model is not None else None)
             elif index is None and self._model is not None:
                 index = self._model.index      # carried, now stale
-            self._model = _Published(seq, U, V, valid, index,
+            self._model = _Published(seq, U, n_users, V, valid, index,
                                      Vs=Vs, valids=valids, ni_loc=ni_loc)
             self._seq = seq
         fresh = bool((index is not None and index.seq == seq)
@@ -458,9 +535,17 @@ class ServingEngine:
         return seq
 
     def publish_update(self, U, V, *, touched_items=None,
-                       item_valid=None, trace=None):
+                       touched_users=None, item_valid=None, trace=None):
         """Incremental publish after a fold-in: O(touched rows), not
         O(catalog).  Returns ``(seq, mode)``.
+
+        ``touched_users``: rows of ``U`` that changed since the live
+        publish (user fold-in); rows beyond the live user count are
+        treated as appended.  The caller guarantees every OTHER row of
+        ``U`` is unchanged: only the named rows are read from ``U``
+        (which may be a view of a larger host buffer) and uploaded
+        (``live.publish_h2d_bytes`` counts what every publish sends).
+        Without it the whole of ``U`` is uploaded.
 
         ``trace``: the causal-trace contexts (``obs.tracing``) of the
         rating events this publish makes visible; their trace ids are
@@ -476,7 +561,8 @@ class ServingEngine:
         (``Int8CandidateIndex.with_updates``).  Modes:
 
         - ``retag``  — nothing in the catalog changed (user-only
-          fold-in): the live index is carried fresh, zero quantization;
+          fold-in): the live index and the device's catalog are carried,
+          zero quantization, nothing of ``V`` uploaded;
         - ``delta``  — touched/appended rows quantized into the delta
           segment;
         - ``compact``— the segment crossed the planner-resolved
@@ -487,7 +573,6 @@ class ServingEngine:
         - ``none``   — catalog too small to index; serving stays exact.
         """
         t0 = time.perf_counter()
-        U = jnp.asarray(U, dtype=jnp.float32)
         # keep a host handle: the delta path gathers only the touched
         # rows, and doing that in numpy costs O(touched) with no
         # shape-varying device executable (a jnp gather would compile
@@ -498,10 +583,6 @@ class ServingEngine:
         valid_h = (np.ones(Ni, dtype=bool) if item_valid is None
                    else np.asarray(item_valid, dtype=bool))
         backend = self._resolve_backend(int(U.shape[1]))
-        if backend == "local":
-            V, valid = jnp.asarray(Vh), jnp.asarray(valid_h)
-        else:
-            V, valid = Vh, valid_h
         touched = (np.empty(0, dtype=np.int64) if touched_items is None
                    else np.unique(np.asarray(touched_items,
                                              dtype=np.int64).ravel()))
@@ -509,6 +590,17 @@ class ServingEngine:
         with self._publish_lock:
             seq = self._seq + 1
             prev = self._model
+            U, n_users, h2d = self._update_users(prev, U, touched_users)
+            if backend != "local":
+                V, valid = Vh, valid_h
+            elif (prev is not None and not touched.size
+                    and item_valid is None
+                    and int(prev.V.shape[0]) == Ni):
+                # nothing of the catalog changed: the device's copy stays
+                V, valid = prev.V, prev.valid
+            else:
+                V, valid = jnp.asarray(Vh), jnp.asarray(valid_h)
+                h2d += Vh.nbytes + valid_h.nbytes
             cur = prev.index if prev is not None else None
             index, mode = None, "full"
             Vs, valids, ni_loc = None, None, 0
@@ -548,10 +640,11 @@ class ServingEngine:
                                               else V, valid, sk, seq)
                 else:
                     mode = "none"
-            self._model = _Published(seq, U, V, valid, index,
+            self._model = _Published(seq, U, n_users, V, valid, index,
                                      Vs=Vs, valids=valids, ni_loc=ni_loc)
             self._seq = seq
         obs.counter("serving.publishes", **self._labels)
+        obs.counter("live.publish_h2d_bytes", h2d, **self._labels)
         obs.histogram("serving.publish_seconds",
                       time.perf_counter() - t0, mode=mode,
                       **self._labels)
@@ -632,6 +725,21 @@ class ServingEngine:
             else:
                 _serve_exact_packed(m.U, Vd, validd, proto, k=self.k,
                                     item_chunk=ic).block_until_ready()
+
+    def warmup_publish(self, max_rows=LIVE_PADS[-1]):
+        """Compile AND run the user-row writes ``publish_update(
+        touched_users=...)`` makes, for up to ``max_rows`` rows a publish
+        (padded 8 / 64 / 512 ...), against the published table: each run
+        writes nothing (every row the out-of-range sentinel) and holds a
+        second copy of the user table while it runs, as every such
+        publish will.  ``LiveUpdater.start`` calls it."""
+        m = self._model
+        if m is None:
+            raise NoModelPublished("publish(U, V) before warmup")
+        for pad in pads_up_to(max_rows):
+            _scatter_users(
+                m.U, jnp.full(pad, m.U.shape[0], jnp.int32),
+                jnp.zeros((pad, m.rank), jnp.float32)).block_until_ready()
 
     def warmup_live(self, max_delta_rows=None):
         """Compile the DELTA-path scoring executables incremental

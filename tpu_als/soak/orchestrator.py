@@ -97,7 +97,6 @@ def _build_fleet(cfg, *, rank, fit_iters, judge_cfg):
     sustained load."""
     import tpu_als
     from tpu_als import plan as _plan
-    from tpu_als.core.ratings import _next_pow2
     from tpu_als.io.movielens import synthetic_movielens
     from tpu_als.stream.microbatch import FoldInServer
     from tpu_als.tenancy import MultiTenantEngine, TenantSpec
@@ -128,11 +127,7 @@ def _build_fleet(cfg, *, rank, fit_iters, judge_cfg):
         # continuous-freshness startup discipline: every (rows, width)
         # shape the stream can produce compiles BEFORE traffic, both
         # fold directions, one table doubling of catalog headroom
-        rows, m = [], max_batch
-        while m >= 1:
-            rows.append(_next_pow2(m))
-            m //= 2
-        srv.prewarm(rows=tuple(sorted(set(rows))), widths=(1, 2, 4),
+        srv.prewarm(rows=(max_batch,), widths=(4,),
                     sides=("user", "item"), growth=1)
         eng.attach_live(name, srv, max_batch=max_batch,
                         max_wait_ms=max_wait_ms, fold_items=True,

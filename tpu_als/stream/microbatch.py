@@ -5,11 +5,19 @@ for new ratings — SURVEY.md §3.5), promised by the north-star (BASELINE.json
 configs[3]: "hourly micro-batches of new ratings → incremental user-factor
 jit update").  The server wraps a fitted ALSModel; each ``update`` call:
 
-1. merges the batch with the per-user rating history it keeps (optional),
-2. pads touched-user rows/widths to powers of two so repeated batches hit
-   the jit cache (bounded compile count),
+1. groups the batch by touched user and merges each with the rating history
+   the server keeps of that user (optional),
+2. pads touched-user rows and widths up a short ladder (8, 64, 512, ...:
+   ``core.ratings.pad_for``) so that the set of compiled programs is small
+   and ``prewarm`` can run all of them before the stream starts,
 3. runs the jitted fold-in kernel against the fixed item factors,
-4. writes the new rows into the model (appending brand-new users).
+4. writes the new rows into the model (brand-new users into spare rows).
+
+Every step costs what the batch touches, never the table: the model's
+factor arrays are views of buffers with spare rows
+(``core.ratings.row_capacity``), its id maps take appended ids without a
+re-sort, and the fixed side lives on the device once, padded to the same
+capacity, so an append changes no array's shape.
 
 Item factors stay fixed during USER fold-ins (the standard fold-in
 contract); the symmetric ``update_items`` folds new/updated ITEMS against
@@ -27,28 +35,15 @@ import numpy as np
 import jax.numpy as jnp
 
 from tpu_als import obs
-from tpu_als.core.foldin import fold_in
-from tpu_als.core.ratings import IdMap, _next_pow2
+from tpu_als.core.foldin import fold_in, pad_rows, solve_path
+from tpu_als.core.ratings import (
+    LIVE_PADS,
+    pad_for,
+    pads_up_to,
+    row_capacity,
+)
 from tpu_als.ops.solve import compute_yty
 from tpu_als.utils.frame import as_frame
-
-
-def _pad_rows_pow2(F):
-    """Pad a factor table to a power-of-two row count with zero rows.
-
-    The fold-in kernel only ever GATHERS rows of the fixed side (by
-    dense ids < the real row count) and, on the implicit path, reads
-    ``F^T F`` — zero rows change neither.  Without this, every
-    appended entity changes the table's leading dim and the jitted
-    solve recompiles per micro-batch (a compile treadmill the live
-    pipeline's freshness SLO cannot absorb); with it, compiles happen
-    only at doublings."""
-    n = int(F.shape[0])
-    n_pad = _next_pow2(n)
-    if n_pad == n:
-        return F
-    return jnp.concatenate(
-        [F, jnp.zeros((n_pad - n, F.shape[1]), F.dtype)])
 
 
 class FoldInServer:
@@ -57,51 +52,92 @@ class FoldInServer:
     def __init__(self, model, keep_history=True, stats_window=512):
         self.model = model
         self.keep_history = keep_history
-        self._history = {}  # original user id -> (item_dense[], rating[])
-        self._item_history = {}  # original item id -> (user_dense[], rating[])
+        # original id -> (fixed-side dense ids, ratings), in arrival order
+        self._history = {}
+        self._item_history = {}
         p = model._params
         self._reg = float(p.get("regParam", 0.1))
         self._implicit = bool(p.get("implicitPrefs", False))
         self._alpha = float(p.get("alpha", 1.0))
         self._nonnegative = bool(p.get("nonnegative", False))
-        self._V = _pad_rows_pow2(jnp.asarray(model._V))
+        self._bufs = {}     # "_U" / "_V" -> the buffer the model's is a view of
+        self._reserve(items_side=False)
+        self._V = self._place("_V")
         self._YtY = compute_yty(self._V) if self._implicit else None
-        # (batch_size, touched_users, latency_seconds) — bounded: a
+        # (batch_size, touched_users, latency_seconds, padded width) —
+        # bounded: a
         # long-lived live pipeline folds in forever, and the durable
         # record is the registered obs histograms, not this ring
         self.stats = collections.deque(maxlen=int(stats_window))
 
-    def prewarm(self, rows=(256, 512, 1024), widths=(2, 4, 8, 16, 32),
+    def _capacity(self, fac_attr, rows=0):
+        """Rows the buffers of this table have, on the host and on the
+        device alike: what was reserved, or ``row_capacity`` of the table
+        (of ``rows`` more, where that no longer holds them)."""
+        fac, buf = getattr(self.model, fac_attr), self._bufs.get(fac_attr)
+        if buf is not None and fac.base is buf and len(fac) + rows <= len(buf):
+            return len(buf)
+        cap = row_capacity(len(fac))
+        return cap if len(fac) + rows <= cap else row_capacity(len(fac) + rows)
+
+    def _place(self, fac_attr, growth=0):
+        """The fixed side of a fold on the device, with spare zero rows
+        (``growth``: that many doublings more).  The kernel only GATHERS
+        its rows (by dense ids below the live count) and, on the implicit
+        path, reads ``F^T F`` — zero rows change neither — so entities
+        appended to it later change no shape and compile nothing."""
+        return pad_rows(
+            jnp.asarray(getattr(self.model, fac_attr), dtype=jnp.float32),
+            capacity=self._capacity(fac_attr) << growth).block_until_ready()
+
+    def _reserve(self, items_side, rows=0):
+        """Make the model's factor table of this side a writable view of a
+        buffer that holds ``rows`` more: one copy of the table here (and
+        again whenever the spare rows run out), none per batch."""
+        m = self.model
+        fac_attr = "_V" if items_side else "_U"
+        fac, cap = getattr(m, fac_attr), self._capacity(fac_attr, rows)
+        buf = self._bufs.get(fac_attr)
+        if buf is not None and fac.base is buf and cap == len(buf):
+            return
+        buf = np.zeros((cap, fac.shape[1]), dtype=fac.dtype)
+        buf[:len(fac)] = fac
+        self._bufs[fac_attr] = buf
+        setattr(m, fac_attr, buf[:len(fac)])
+        (m._item_map if items_side else m._user_map).reserve(cap)
+
+    def prewarm(self, rows=LIVE_PADS, widths=LIVE_PADS,
                 sides=("user",), growth=0):
-        """Pre-compile the fold-in kernel for a (rows, width) shape grid.
+        """Compile AND run the fold-in program of every padded shape up to
+        ``max(rows)`` entities of ``max(widths)`` ratings a batch.
 
-        ``update`` pads batches to power-of-two shapes, so the jit cache
-        is bounded — but each NEW shape still pays its compile at serving
-        time, which is what dominates a latency benchmark's p95 early in
-        a run (observed: p95 11x p50 on the first 30 batches).  Serving
-        deployments call this once at startup with the shapes their
-        batch size implies; entries are cached per process.
+        ``update`` pads a batch up the ladder 8, 64, 512, ..., so the
+        programs are few — but a shape's first call still pays its compile
+        and its first execution, which a latency SLO cannot absorb (p95
+        11x p50 on the first 30 batches).  Serving deployments call this
+        once at startup; entries are cached per process.  One
+        ``foldin_solve_path`` event per program says how it solves.
 
-        ``sides`` picks the fold directions to compile ("user" solves
-        against the item table, "item" against the user table — a live
-        pipeline with ``fold_items`` needs both).  ``growth`` also
-        compiles against the fixed table padded up that many extra
-        doublings: a stream that appends entities eventually pushes the
-        fixed side past its current pow2 pad, and that recompile should
-        be paid here, not mid-stream against a freshness SLO.  Shapes
-        shared between sides (equal table pads) hit the same jit-cache
-        entry, so requesting both costs no duplicate compiles.
+        ``sides`` picks the fold directions ("user" solves against the
+        item table, "item" against the user table — a live pipeline with
+        ``fold_items`` needs both).  ``growth`` also runs against the
+        fixed table with that many doublings of its capacity: a stream
+        that appends entities past the spare rows re-places it, and that
+        program should be paid for here, not mid-stream.  Shapes shared
+        between sides (equal capacities) hit the same jit-cache entry.
         """
+        m = self.model
+        rows, widths = pads_up_to(max(rows)), pads_up_to(max(widths))
         for side in sides:
-            F0 = (self._V if side == "user"
-                  else _pad_rows_pow2(jnp.asarray(self.model._U)))
+            # the id maps sort their ids at first use: now, not mid-stream
+            (m._user_map if side == "user" else m._item_map).to_dense([0])
             for g in range(int(growth) + 1):
-                n_pad = int(F0.shape[0]) << g
-                F = (F0 if g == 0 else jnp.concatenate(
-                    [F0, jnp.zeros((n_pad - int(F0.shape[0]),
-                                    F0.shape[1]), F0.dtype)]))
+                F = (self._V if side == "user" and g == 0
+                     else self._place("_V" if side == "user" else "_U", g))
                 YtY = compute_yty(F) if self._implicit else None
                 for n in rows:
+                    _, path, why = solve_path(F.shape[1], n,
+                                              self._nonnegative)
                     for w in widths:
                         fold_in(
                             F,
@@ -113,6 +149,9 @@ class FoldInServer:
                             nonnegative=self._nonnegative,
                             YtY=YtY,
                         ).block_until_ready()
+                        obs.emit("foldin_solve_path", side=side,
+                                 rank=int(F.shape[1]), rows=n, width=w,
+                                 path=path, reason=why)
 
     def update(self, batch):
         """Process one micro-batch frame (userCol/itemCol/ratingCol of the
@@ -136,7 +175,7 @@ class FoldInServer:
 
     def _fold_batch(self, batch, items_side):
         """ONE shared mechanics path for both directions — known-side
-        filter, per-entity grouping, history merge, pow2 padding, solve,
+        filter, per-entity grouping, history merge, ladder padding, solve,
         write-back — parameterized by which side is being solved, so a
         fix to any of it cannot apply to one direction only."""
         t0 = time.perf_counter()
@@ -163,37 +202,39 @@ class FoldInServer:
         if len(solved_raw) == 0:
             return np.array([], dtype=np.int64)
 
-        touched = np.unique(solved_raw)
-        per = {e: ([], []) for e in touched}
-        for e, f, v in zip(solved_raw, fixed_dense, r):
-            per[e][0].append(f)
-            per[e][1].append(v)
+        # group the events by entity, each entity's in arrival order
+        touched, entity = np.unique(solved_raw, return_inverse=True)
+        by_entity = np.argsort(entity, kind="stable")
+        bounds = np.cumsum(np.bincount(entity, minlength=len(touched)))[:-1]
+        per = list(zip(np.split(fixed_dense[by_entity], bounds),
+                       np.split(r[by_entity], bounds)))
         if self.keep_history:
-            for e in touched:
+            for j, e in enumerate(touched.tolist()):
                 hist = history.get(e)
                 if hist is not None:
-                    per[e] = (hist[0] + per[e][0], hist[1] + per[e][1])
-                history[e] = per[e]
+                    per[j] = (np.concatenate([hist[0], per[j][0]]),
+                              np.concatenate([hist[1], per[j][1]]))
+                history[e] = per[j]
 
-        # pad rows and width to powers of two -> bounded jit-cache entries
+        # pad rows and width up the ladder -> the programs prewarm ran
         n = len(touched)
-        n_pad = _next_pow2(n)
-        w = _next_pow2(max(len(v[0]) for v in per.values()))
+        lens = np.array([len(f) for f, _ in per])
+        n_pad, w = pad_for(n), pad_for(int(lens.max()))
+        row = np.repeat(np.arange(n), lens)
+        slot = np.arange(lens.sum()) - np.repeat(np.cumsum(lens) - lens, lens)
         cols = np.zeros((n_pad, w), dtype=np.int32)
         vals = np.zeros((n_pad, w), dtype=np.float32)
         mask = np.zeros((n_pad, w), dtype=np.float32)
-        for row, e in enumerate(touched):
-            ff, vv = per[e]
-            cols[row, :len(ff)] = ff
-            vals[row, :len(ff)] = vv
-            mask[row, :len(ff)] = 1.0
+        cols[row, slot] = np.concatenate([f for f, _ in per])
+        vals[row, slot] = np.concatenate([v for _, v in per])
+        mask[row, slot] = 1.0
 
         if items_side:
             # the fixed side here is U, which user fold-ins may have
-            # grown — read it live (one transfer per item batch; item
+            # changed — read it live (one transfer per item batch; item
             # batches are the rare direction, so this stays off the
             # user hot path)
-            F = _pad_rows_pow2(jnp.asarray(m._U))
+            F = self._place("_U")
             YtY = compute_yty(F) if self._implicit else None
         else:
             F, YtY = self._V, self._YtY
@@ -206,11 +247,11 @@ class FoldInServer:
         self._write_back(touched, x, items_side)
         if items_side:
             # refresh the serving-side cache the USER fold-in path reads
-            self._V = _pad_rows_pow2(jnp.asarray(m._V))
+            self._V = self._place("_V")
             if self._implicit:
                 self._YtY = compute_yty(self._V)
         dt = time.perf_counter() - t0
-        self.stats.append((len(solved_raw), n, dt))
+        self.stats.append((len(solved_raw), n, dt, w))
         obs.histogram("foldin.update_seconds", dt,
                       side="item" if items_side else "user")
         obs.histogram("foldin.batch_rows", n,
@@ -219,26 +260,18 @@ class FoldInServer:
         return touched
 
     def _write_back(self, touched_raw_ids, new_rows, items_side=False):
+        """New factor rows into the model's table; entities the id map
+        does not know take the next spare rows, in the order given."""
         m = self.model
-        map_attr = "_item_map" if items_side else "_user_map"
         fac_attr = "_V" if items_side else "_U"
-        fac = getattr(m, fac_attr)
-        if not fac.flags.writeable:  # np view of a jax array is read-only
-            fac = fac.copy()
-            setattr(m, fac_attr, fac)
-        emap = getattr(m, map_attr)
+        emap = m._item_map if items_side else m._user_map
         dense = emap.to_dense(touched_raw_ids)
-        new_mask = dense < 0
-        if new_mask.any():  # brand-new entities: extend map and factors
-            new_ids = touched_raw_ids[new_mask]
-            emap = IdMap(ids=np.concatenate([emap.ids, new_ids]))
-            setattr(m, map_attr, emap)
-            fac = np.concatenate(
-                [fac, np.zeros((len(new_ids), fac.shape[1]),
-                               dtype=fac.dtype)])
-            setattr(m, fac_attr, fac)
-            dense = emap.to_dense(touched_raw_ids)
-        fac[dense] = new_rows
+        new = dense < 0
+        self._reserve(items_side, rows=int(new.sum()))
+        if new.any():
+            dense[new] = emap.append(touched_raw_ids[new])
+            setattr(m, fac_attr, self._bufs[fac_attr][:len(emap)])
+        getattr(m, fac_attr)[dense] = new_rows
 
     def latency(self, q=0.5, skip_warmup=False):
         """Latency quantile over processed batches.  ``skip_warmup`` drops

@@ -1,0 +1,80 @@
+#!/usr/bin/env python3
+"""What closed the serving micro-batches of one benchmark run.
+
+Runs ``benchmark/tests/batch_ring.py`` as it is (one run of
+``benchmark/run.py``, then the batch ring's phases by bucket) and adds,
+for each stream of the run, the shares of ``closed_by`` over the last
+``--seconds`` of its per-batch records — ``age``: the head had already
+waited ``max_wait_s`` when the engine thread came back; ``wait``: the
+rest of its window ran out; ``full``; ``closed`` — with the head's age
+on arrival, and the process's ``serving.batch_closed`` counters against
+the batches the engine served.  No CPU mode (``run.py`` has none):
+
+    chiprun -- python3 scripts/serve_batch_closed.py --workload \\
+        amazon23-r256-share32.serve-steady --seed <n> --seconds 30 --trace 0
+"""
+
+from __future__ import annotations
+
+import collections
+import importlib.util
+import json
+import os
+import statistics as st
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+WAYS = ("age", "wait", "full", "closed")
+
+
+def shares(records, seconds):
+    """``closed_by`` over the last ``seconds`` of one stream's records."""
+    window = [r for r in records if r["t0"] > records[-1]["t0"] - seconds]
+    by = collections.Counter(r["closed_by"] for r in window)
+    row = {"batches": len(window),
+           "rows_mean": st.mean(r["rows"] for r in window),
+           "head_wait_ms_median": 1e3 * st.median(
+               r["head_wait"] for r in window)}
+    for way in WAYS:
+        row[way] = by[way]
+        row[way + "_pct"] = 100.0 * by[way] / len(window)
+    return row
+
+
+def main(argv):
+    sys.path.insert(0, ROOT)
+    spec = importlib.util.spec_from_file_location(
+        "batch_ring", os.path.join(ROOT, "benchmark", "tests",
+                                   "batch_ring.py"))
+    batch_ring = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(batch_ring)
+    import tpu_als.serving.engine as engine_module
+    from tpu_als import obs
+
+    engines = []
+    init = engine_module.ServingEngine.__init__
+
+    def init_and_keep(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        engines.append(self)
+
+    engine_module.ServingEngine.__init__ = init_and_keep
+    code = batch_ring.main(argv)
+    if code:
+        return code
+    seconds = float(argv[argv.index("--seconds") + 1])
+    records = engines[0].batch_flight.records()
+    for k, stream in enumerate(s for s in batch_ring.streams(records)
+                               if len(s) > 8):
+        print(json.dumps({"batch_closed": k, **shares(stream, seconds)}),
+              flush=True)
+    counted = {way: obs.counter_value("serving.batch_closed", by=way)
+               for way in WAYS}
+    print(json.dumps({"serving.batch_closed": counted,
+                      "sum": sum(counted.values()),
+                      "batches_served": engines[0]._batch_seq}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -10,6 +10,7 @@ CLI).
 """
 
 import json
+import threading
 import time
 
 import numpy as np
@@ -224,6 +225,124 @@ def test_batcher_close_drains_then_stops():
 def test_batcher_rejects_bad_buckets():
     with pytest.raises(ValueError, match="sorted and unique"):
         MicroBatcher(buckets=(32, 8))
+
+
+# -- what closes a batch: the head's own clock (ISSUE 29) -------------------
+# A ticket's age is set by backdating ``t_submit``: no sleep stands in for
+# the time the engine thread was away.
+
+
+def _aged(b, payload, age_s):
+    t = b.submit(payload)
+    t.t_submit -= age_s
+    return t
+
+
+def test_batcher_old_head_pops_at_once_with_everything_queued(
+        _fresh, monkeypatch):
+    b = MicroBatcher(buckets=(4, 8), max_wait_s=0.5)
+    _aged(b, 0, 0.6)                 # queued while a batch was scored
+    b.submit(1)
+    b.submit(2)                      # young ones ride along
+    monkeypatch.setattr(b._cond, "wait", lambda *a: pytest.fail(
+        "a head older than max_wait_s entered a wait"))
+    batch = b.next_batch(timeout=1.0)
+    assert [t.payload for t in batch] == [0, 1, 2]
+    idle_s, waiting, coalesce_s = b.last_wait
+    assert (idle_s, waiting) == (0.0, 3) and coalesce_s < 0.25
+    assert b.closed_by == "age" and 0.6 <= b.head_wait < 0.85
+    assert _fresh.counter_value("serving.batch_closed", by="age") == 1
+
+
+def test_batcher_young_head_waits_only_the_rest_of_its_window():
+    b = MicroBatcher(buckets=(4, 8), max_wait_s=1.0)
+    _aged(b, 0, 0.8)
+    assert len(b.next_batch(timeout=1.0)) == 1
+    # ~0.2 s, the remainder; the old rule waited all of max_wait_s again
+    assert 0.15 <= b.last_wait[2] < 0.7
+    assert b.closed_by == "wait" and 0.8 <= b.head_wait < 0.9
+
+
+def test_batcher_first_arrival_into_an_empty_queue_waits_for_company():
+    b = MicroBatcher(buckets=(4, 8), max_wait_s=0.5)
+
+    def arrivals():
+        time.sleep(0.05)
+        b.submit(0)
+        time.sleep(0.05)
+        b.submit(1)                  # inside the first one's window
+
+    th = threading.Thread(target=arrivals)
+    th.start()
+    batch = b.next_batch(timeout=5.0)
+    th.join()
+    assert [t.payload for t in batch] == [0, 1]
+    idle_s, waiting, coalesce_s = b.last_wait
+    # the lone request is the head, its age ~0: all of max_wait_s, as ever
+    assert idle_s >= 0.04 and waiting == 1 and coalesce_s >= 0.4
+    assert b.closed_by == "wait" and b.head_wait < 0.25
+
+
+@pytest.mark.parametrize("how", ["full_on_arrival", "fills_in_the_wait",
+                                 "closed"])
+def test_batcher_full_and_closed_close_as_before(how):
+    b = MicroBatcher(buckets=(2, 4), max_wait_s=30.0)
+    b.submit(0)
+    if how == "full_on_arrival":
+        for i in range(1, 5):
+            b.submit(i)
+        later = None
+    elif how == "fills_in_the_wait":
+        later = threading.Timer(
+            0.05, lambda: [b.submit(i) for i in range(1, 5)])
+    else:
+        later = threading.Timer(0.05, b.close)
+    if later:
+        later.start()
+    batch = b.next_batch(timeout=5.0)
+    if later:
+        later.join()
+    assert b.last_wait[2] < 10.0     # nowhere near max_wait_s
+    if how == "closed":
+        assert b.closed_by == "closed" and len(batch) == 1
+        assert b.next_batch(timeout=0.1) is None
+    else:
+        assert b.closed_by == "full" and len(batch) == 4
+        assert b.depth() == 1
+
+
+def test_batch_closed_counts_every_batch_and_the_record_says_why(
+        rng, _fresh):
+    eng = ServingEngine(k=5, buckets=(2, 4), shortlist_k=32,
+                        max_wait_s=0.2, tenant="t")
+    eng.publish(rng.normal(size=(8, 8)).astype(np.float32),
+                rng.normal(size=(300, 8)).astype(np.float32))
+    b = eng.batcher
+    _aged(b, 0, 0.3)                               # age
+    _drain_one(eng)
+    b.submit(1)                                    # wait (all 0.2 s of it)
+    _drain_one(eng)
+    for i in range(5):                             # full, then age
+        _aged(b, i, 0.3)
+    _drain_one(eng)
+    _drain_one(eng)
+    b.submit(6)
+    b.close()                                      # closed
+    _drain_one(eng)
+    by = {w: _fresh.counter_value("serving.batch_closed", by=w, tenant="t")
+          for w in ("age", "wait", "full", "closed")}
+    assert by == {"age": 2, "wait": 1, "full": 1, "closed": 1}
+    assert sum(by.values()) == eng._batch_seq == 5
+    assert _fresh.histogram_count("serving.batch_rows", tenant="t") == 5
+    recs = eng.batch_flight.records()
+    assert [r["closed_by"] for r in recs] == [
+        "age", "wait", "full", "age", "closed"]
+    assert [r["waiting"] for r in recs] == [1, 1, 5, 1, 1]
+    assert all(r["head_wait"] >= 0.3 for r in (recs[0], recs[2], recs[3]))
+    assert 0 <= recs[1]["head_wait"] < 0.1
+    assert recs[1]["spans"]["serve.batch.coalesce"] >= 0.15
+    assert all(r["spans"]["serve.batch.coalesce"] < 0.1
+               for r in recs if r["closed_by"] != "wait")
 
 
 # ---------------------------------------------------------------------------

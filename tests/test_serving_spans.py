@@ -142,6 +142,22 @@ def test_batch_ring_keeps_the_spans_durations(traced):
             dur, abs=1e6)
 
 
+def test_coalesce_span_and_record_say_what_closed_the_batch(traced):
+    """``closed_by`` / ``head_wait`` ride the coalesce span and the
+    batch's record alike (ISSUE 29); with ``max_wait_s`` 0 every head
+    has had its wait on arrival, and 32 rows fill the largest bucket."""
+    spans, eng, tickets = traced
+    coalesce = [s[3] for s in spans if s[0] == "serve.batch.coalesce"]
+    records = eng.batch_flight.records()[-len(BATCHES):]
+    for stats, rec, batch in zip(coalesce, records, tickets):
+        assert stats["closed_by"] == rec["closed_by"] == (
+            "full" if len(batch) == 32 else "age")
+        assert stats["head_wait"] == pytest.approx(rec["head_wait"])
+        # the head's age on arrival is the start of its queue wait
+        assert 0 <= rec["head_wait"] <= (batch[0].t_dequeue
+                                         - batch[0].t_submit)
+
+
 def test_request_records_name_the_batch_they_rode(traced):
     _, eng, tickets = traced
     reg = obs.reset()

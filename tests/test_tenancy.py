@@ -20,6 +20,7 @@ Five layers:
 
 import importlib.util
 import os
+import time
 
 import numpy as np
 import pytest
@@ -293,6 +294,29 @@ def test_weighted_fair_share_under_contention():
     assert h.served_rows == li.served_rows == 60
     # equal rows at 3x weight -> one third the virtual time charged
     assert h.vtime == pytest.approx(li.vtime / 3.0)
+
+
+def test_old_head_does_not_hold_the_shared_scheduler_thread(_fresh):
+    """One thread serves every tenant: a backlogged tenant whose oldest
+    request has had its ``max_wait_s`` pops at once (ISSUE 29) instead
+    of stalling its neighbors' queues for another ``max_wait_s``."""
+    rng = np.random.default_rng(5)
+    eng = MultiTenantEngine()
+    eng.add_tenant(TenantSpec(name="patient", k=5, max_wait_s=20.0),
+                   *_factors(rng))
+    eng.add_tenant(TenantSpec(name="other", k=5, max_wait_s=0.0),
+                   *_factors(rng))
+    eng.warmup()
+    old = eng.submit("patient", 0)
+    old.t_submit -= 21.0             # queued while the thread was away
+    neighbor = eng.submit("other", 0)
+    t0 = time.perf_counter()
+    assert eng._drain_round()        # the scheduler thread's own round
+    assert time.perf_counter() - t0 < 10.0
+    assert old.done() and neighbor.done()
+    assert eng.tenant("patient").engine.batcher.closed_by == "age"
+    assert _fresh.counter_value("serving.batch_closed", by="age",
+                                tenant="patient") == 1
 
 
 def test_tenant_overloaded_is_typed_and_isolated():

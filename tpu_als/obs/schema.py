@@ -91,6 +91,13 @@ METRICS = {
         "counter", "requests",
         "requests refused at admission (queue at capacity; the typed "
         "Overloaded the caller sees)"),
+    "serving.batch_closed": (
+        "counter", "batches",
+        "micro-batches dequeued, labeled by what closed them: "
+        "by=age (the head had already waited max_wait_s when the "
+        "consumer arrived: popped without a wait) | wait (the rest of "
+        "the head's max_wait_s ran out) | full (the largest bucket "
+        "filled) | closed (the batcher was closing)"),
     "serving.expired": (
         "counter", "requests",
         "requests whose deadline passed while queued (failed with "
@@ -208,6 +215,7 @@ LABELS = {
     "serving.queue_depth": ("tenant",),
     "serving.requests": ("tenant",),
     "serving.shed": ("tenant",),
+    "serving.batch_closed": ("by", "tenant"),
     "serving.expired": ("tenant",),
     "serving.fallback_exact": ("tenant",),
     "serving.publishes": ("tenant",),
@@ -271,6 +279,7 @@ SERVE_SPAN_KEYS = ("admission", "queue_wait", "score", "respond")
 SERVE_BATCH_SPAN_KEYS = (
     "serve.idle",             # blocked on an empty queue
     "serve.batch.coalesce",   # first request seen -> batch popped
+    #                           (waiting, closed_by, head_wait)
     "serve.batch",            # all of serve_batch (seq, bucket, rows, path)
     "serve.batch.stage",      # expiry check + staging into the upload array
     "serve.batch.dispatch",   # upload + the scoring call, until it returns
@@ -420,7 +429,10 @@ EVENTS = {
         "admission/queue_wait/score/respond breakdown in seconds and "
         "batch the engine's batch counter; the same triggers dump the "
         "engine's per-batch records (spans keyed by "
-        "SERVE_BATCH_SPAN_KEYS, with batch, t0, bucket, rows, waiting) "
+        "SERVE_BATCH_SPAN_KEYS, with batch, t0, bucket, rows, waiting, "
+        "closed_by = age|wait|full|closed as serving.batch_closed's "
+        "by, head_wait = seconds the batch's oldest request had waited "
+        "as the consumer arrived) "
         "(obs.trace.FlightRecorder)"),
     "attribution": (
         ("stages", "wall_s_per_iter", "coverage"),

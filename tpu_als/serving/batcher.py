@@ -6,9 +6,14 @@ Online traffic wants the opposite — single-user requests arriving at
 arbitrary times with per-request deadlines.  This queue converts one
 into the other:
 
-- requests are coalesced for at most ``max_wait_s`` (or until the
-  largest bucket fills, whichever is first), so light traffic pays a
-  bounded latency tax and heavy traffic gets full batches;
+- no request is held back for coalescing longer than ``max_wait_s``
+  after it ARRIVED: a batch closes when its oldest request has waited
+  that long (or the largest bucket fills, whichever is first).  The
+  wait counts from the head ticket's ``t_submit``, not from the engine
+  thread's return, so requests that queued while the batch before was
+  being scored have had their coalescing time and pop at once; light
+  traffic pays a bounded latency tax and heavy traffic gets full
+  batches;
 - the engine pads each dequeued batch up to the smallest bucket that
   fits (``bucket_for``), so the scoring executable compiles once per
   bucket instead of once per observed batch size;
@@ -147,6 +152,10 @@ class MicroBatcher:
         # since the batch before, requests waiting as the coalescing
         # wait began, seconds from then to the batch popped
         self.last_wait = (0.0, 0, 0.0)
+        # what closed that batch (serving.batch_closed's ``by``) and
+        # how long its head had already waited as the consumer arrived
+        self.closed_by = None
+        self.head_wait = 0.0
         self._idle_s = 0.0
 
     def depth(self):
@@ -183,11 +192,19 @@ class MicroBatcher:
         """Dequeue the next micro-batch (engine loop only).
 
         Blocks up to ``timeout`` for the first request, then coalesces
-        arrivals for ``max_wait_s`` or until the largest bucket fills.
+        arrivals until the HEAD of the queue (its oldest ticket) has
+        waited ``max_wait_s`` since its ``t_submit``, the largest
+        bucket fills or the batcher closes.  A head that is already
+        that old when the consumer arrives — it queued while the batch
+        before was scored — pops at once with everything queued; a
+        younger one waits only the remainder; the first arrival into
+        an empty queue waits all of ``max_wait_s`` for company.
         Returns a list of tickets (``t_dequeue`` stamped), or ``None``
         on timeout with an empty queue.  Also sets the
-        ``serving.queue_depth`` gauge to the post-dequeue backlog, and
-        ``last_wait`` to what the dequeue waited for.
+        ``serving.queue_depth`` gauge to the post-dequeue backlog,
+        counts ``serving.batch_closed{by=...}``, and leaves
+        ``last_wait``, ``closed_by`` and ``head_wait`` saying what the
+        dequeue waited for.
         """
         cap = self.buckets[-1]
         with self._cond:
@@ -199,21 +216,35 @@ class MicroBatcher:
                 self._idle_s += time.perf_counter() - t_idle
                 if not self._q:        # timed out, or closed and drained
                     return None
-            # coalesce: wait out the batching window unless full
+            # coalesce: the head's own clock runs the batching window
             t_first = time.perf_counter()
             waiting = len(self._q)
-            with TraceAnnotation("serve.batch.coalesce", waiting=waiting):
-                while len(self._q) < cap:
-                    remaining = self.max_wait_s - (time.perf_counter()
-                                                   - t_first)
-                    if remaining <= 0 or self._closed:
+            t_head = self._q[0].t_submit
+            t_close = t_head + self.max_wait_s
+            head_wait = t_first - t_head
+            closed_by = "age"       # the head had its wait: no wait here
+            with TraceAnnotation("serve.batch.coalesce",
+                                 waiting=waiting) as span:
+                while True:
+                    if len(self._q) >= cap:
+                        closed_by = "full"
                         break
+                    if self._closed:
+                        closed_by = "closed"
+                        break
+                    remaining = t_close - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    closed_by = "wait"
                     self._cond.wait(remaining)
                 batch = [self._q.popleft()
                          for _ in range(min(len(self._q), cap))]
                 depth_after = len(self._q)
+                span.set_metadata(closed_by=closed_by,
+                                  head_wait=head_wait)
         now = time.perf_counter()
         self.last_wait = (self._idle_s, waiting, now - t_first)
+        self.closed_by, self.head_wait = closed_by, head_wait
         self._idle_s = 0.0
         waits = []
         for t in batch:
@@ -226,6 +257,7 @@ class MicroBatcher:
                     t.trace, "serve.queue", seconds=waits[-1])
         obs.histogram_many("serving.enqueue_seconds", waits, **self.labels)
         obs.gauge("serving.queue_depth", depth_after, **self.labels)
+        obs.counter("serving.batch_closed", by=closed_by, **self.labels)
         return batch
 
     def close(self):

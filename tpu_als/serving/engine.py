@@ -992,7 +992,8 @@ class ServingEngine:
                       t_dispatch - t_stage, t_readback - t_dispatch,
                       t_complete - t_readback, t_end - t_complete))),
             path=path, batch=seq, t0=t_stage, bucket=B, rows=n,
-            waiting=waiting)
+            waiting=waiting, closed_by=self.batcher.closed_by,
+            head_wait=self.batcher.head_wait)
         if trigger:
             self.batch_flight.dump(trigger)
 

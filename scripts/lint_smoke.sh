@@ -11,7 +11,7 @@
 #   2. the jaxpr contract registry — the named byte pins (ne_audit,
 #      fused_solve_audit, guardrails_disarmed, tracing_disarmed,
 #      plan_cache_off, comm_audit, ring_substrate, live_delta_index,
-#      serve_comm_audit, elastic_disarmed, floor_audit) re-verified
+#      elastic_disarmed, floor_audit) re-verified
 #      through the real CLI on an 8-device CPU backend.  floor_audit is
 #      a bank pin, not a jaxpr pin: the committed BENCH_autotune_cpu.json
 #      must keep tuned <= default and measured-vs-modeled inside its

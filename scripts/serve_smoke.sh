@@ -80,7 +80,7 @@ XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8" \
 python -m tpu_als.cli serve-bench \
     --users 2000 --items 4096 --rank 32 --k 10 --shortlist-k 64 \
     --qps 200 --duration 3 --slo-ms 2000 --max-wait-ms 2 \
-    --mesh-devices 8 --serve-backend sharded --buckets 16,64 \
+    --mesh-devices 8 --buckets 16,64 \
     --bench-json "$work/BENCH_serve_sharded_smoke.json" \
     >"$work/serve_sharded.out" 2>"$work/serve_sharded.log"
 rc=$?

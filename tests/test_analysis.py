@@ -217,8 +217,8 @@ def test_contracts_resolvable_by_name():
     assert set(contracts.names()) == {
         "ne_audit", "fused_solve_audit", "guardrails_disarmed",
         "tracing_disarmed", "plan_cache_off", "comm_audit",
-        "ring_substrate", "live_delta_index", "serve_comm_audit",
-        "elastic_disarmed", "floor_audit"}
+        "ring_substrate", "live_delta_index", "elastic_disarmed",
+        "floor_audit"}
     for name in contracts.names():
         c = contracts.get(name)
         assert c.name == name

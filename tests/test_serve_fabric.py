@@ -1,17 +1,18 @@
 """Sharded serving fabric (PR 17): the mesh-resident int8 index, the
-in-kernel merge-ring serve path, the engine's backend dispatch, and the
-traffic-derived bucket ladder.
+engine's choice of index from its mesh, and the traffic-derived bucket
+ladder.
 
 Equality discipline: corpora are built from INTEGER-valued factors drawn
 from a tiny row pool, so every f32 dot product is exact regardless of
 contraction order and rows collide constantly — score ties are the
 common case, not the measure-zero one.  Bitwise equality (scores AND
 ids) against the single-device ``chunked_topk_scores`` is then a real
-statement about tie ORDER across shard counts, backends, and delta
-publishes.  All on the 8-device forced-host CPU backend; the merge-ring
-kernel runs in interpret mode (identical kernel logic to the TPU
-compile — see tests/test_pallas_topk.py).
+statement about tie ORDER across shard counts and delta publishes.  All
+on the 8-device forced-host CPU backend.
 """
+
+import ast
+import pathlib
 
 import numpy as np
 import pytest
@@ -22,7 +23,6 @@ import jax.numpy as jnp
 from tests.conftest import assert_topk_within_contract
 from tpu_als.ops.topk import chunked_topk_scores
 from tpu_als.parallel.mesh import make_mesh
-from tpu_als.parallel.serve import topk_sharded
 from tpu_als.resilience import faults
 from tpu_als.serving.engine import ServingEngine
 from tpu_als.serving.index import (
@@ -49,62 +49,7 @@ def _reference(U, V, valid, k):
 
 
 # ---------------------------------------------------------------------------
-# 1. in-kernel merge ring through topk_sharded
-
-
-# Tier-1 keeps one non-pow2 count (3) and the full mesh width (8); the
-# interior odd counts ride the slow tier (interpret-mode pallas is
-# seconds per shard count on the 1-core CI box).
-@pytest.mark.parametrize("n_shards", [
-    3, pytest.param(5, marks=pytest.mark.slow),
-    pytest.param(7, marks=pytest.mark.slow), 8])
-def test_merge_ring_bitwise_on_ties_any_shard_count(rng, n_shards):
-    # non-pow2 ring sizes included: the rotation schedule must not
-    # assume a power-of-two neighborhood
-    U, V = _tie_corpus(rng, 23, 90, 16)
-    valid = rng.random(90) < 0.85
-    ref_s, ref_i = _reference(U, V, valid, 6)
-    s, ix = topk_sharded(U, V, 6, make_mesh(n_shards),
-                         strategy="merge_ring", item_valid=valid)
-    assert np.array_equal(np.asarray(s), ref_s)
-    assert np.array_equal(np.asarray(ix), ref_i)
-
-
-def test_merge_ring_all_invalid_shard(rng):
-    # one shard contributes nothing: its candidate set is all sentinel
-    # and must never displace a real candidate during the rotation
-    U, V = _tie_corpus(rng, 11, 64, 8)
-    valid = np.ones(64, bool)
-    valid[16:24] = False           # shard 2 of 8 entirely masked
-    ref_s, ref_i = _reference(U, V, valid, 5)
-    s, ix = topk_sharded(U, V, 5, make_mesh(8), strategy="merge_ring",
-                         item_valid=valid)
-    assert np.array_equal(np.asarray(s), ref_s)
-    assert np.array_equal(np.asarray(ix), ref_i)
-    assert not np.isin(np.asarray(ix), np.arange(16, 24)).any()
-
-
-def test_merge_ring_k_exceeds_shard(rng):
-    # 8 shards x 2 rows: every shard's local k is smaller than the
-    # requested k, so the answer only exists after the full rotation
-    U, V = _tie_corpus(rng, 9, 16, 8)
-    ref_s, ref_i = _reference(U, V, np.ones(16, bool), 5)
-    s, ix = topk_sharded(U, V, 5, make_mesh(8), strategy="merge_ring")
-    assert np.array_equal(np.asarray(s), ref_s)
-    assert np.array_equal(np.asarray(ix), ref_i)
-
-
-def test_serve_comm_audit_contract_is_registered():
-    from tpu_als.analysis import contracts
-
-    assert "serve_comm_audit" in contracts.names()
-    res = contracts.verify("serve_comm_audit")
-    assert res.ok, res
-    assert "no XLA collectives" in res.detail
-
-
-# ---------------------------------------------------------------------------
-# 2. mesh-sharded int8 index
+# 1. mesh-sharded int8 index
 
 
 @pytest.fixture(scope="module")
@@ -222,7 +167,7 @@ def test_sharded_index_residency(rng, mesh8):
 
 
 # ---------------------------------------------------------------------------
-# 3. engine backend dispatch
+# 2. the engine picks its index from the mesh
 
 
 def _drain(eng, payloads, **kw):
@@ -235,20 +180,18 @@ def _drain(eng, payloads, **kw):
     return [t.result(timeout=10) for t in tickets]
 
 
-@pytest.mark.parametrize("backend_kw", [
-    {},
-    dict(serve_backend="sharded"),
-    dict(serve_backend="merge_ring"),
-    dict(serve_backend="auto"),
-], ids=["local", "sharded", "merge_ring", "auto"])
-def test_engine_backends_bitwise(rng, mesh8, backend_kw):
+# 0 = no mesh; 3 pads the 700-row catalog to D·ceil(Ni/D) = 702
+@pytest.mark.parametrize("n_shards", [0, 2, 3, 8])
+def test_engine_backends_bitwise(rng, n_shards):
     Nu, Ni, r, k = 40, 700, 32, 10
     U, V = _tie_corpus(rng, Nu, Ni, r, pool=11)
     valid = rng.random(Ni) < 0.9
     ref_s, ref_i = _reference(U, V, valid, k)
-    kw = dict(mesh=mesh8, **backend_kw) if backend_kw else {}
-    eng = ServingEngine(k=k, shortlist_k=Ni, buckets=(8, 32), **kw)
+    eng = ServingEngine(k=k, shortlist_k=Ni, buckets=(8, 32),
+                        mesh=make_mesh(n_shards) if n_shards else None)
     eng.publish(U, V, item_valid=valid)
+    assert isinstance(eng.published_index,
+                      ShardedInt8Index if n_shards else Int8CandidateIndex)
     eng.warmup()
     for u, (s, ix) in zip(range(20), _drain(eng, list(range(20)))):
         assert np.array_equal(ix, ref_i[u])
@@ -261,17 +204,28 @@ def test_engine_backends_bitwise(rng, mesh8, backend_kw):
     assert s.shape == (4,) and np.array_equal(ix, ref_i[3, :4])
 
 
-def test_engine_backend_validation(mesh8):
-    with pytest.raises(ValueError, match="serve_backend"):
-        ServingEngine(serve_backend="bogus")
-    with pytest.raises(ValueError, match="mesh"):
-        ServingEngine(serve_backend="sharded")   # mesh-less
+def test_engine_imports_no_mesh_kernel_or_batch_topk():
+    """The arrow serving -> parallel must not come back unnoticed: the
+    engine reaches a mesh through ``ShardedInt8Index`` alone, never
+    through the batch ``topk_sharded`` or a Pallas top-k."""
+    import tpu_als.serving.engine as engine
+
+    banned = ("tpu_als.parallel.serve", "tpu_als.ops.pallas_topk")
+    tree = ast.parse(pathlib.Path(engine.__file__).read_text())
+    seen = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            seen.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            seen.add(node.module)
+            seen.update(f"{node.module}.{a.name}" for a in node.names)
+    assert not [m for m in seen if m.startswith(banned)], seen
 
 
 def test_engine_backend_event_mesh_only(rng, mesh8):
-    """``serving_backend`` fires once per MESH-backed engine with the
-    resolved backend and shard count; mesh-less engines are local by
-    construction and emit nothing (docs/observability.md)."""
+    """``serving_backend`` fires once per MESH-backed engine with
+    ``sharded`` and the shard count; mesh-less engines emit nothing
+    (docs/observability.md)."""
     from tpu_als import obs
 
     U, V = _tie_corpus(rng, 8, 96, 16)
@@ -279,8 +233,9 @@ def test_engine_backend_event_mesh_only(rng, mesh8):
     try:
         for eng in (ServingEngine(k=5, shortlist_k=96, buckets=(8,)),
                     ServingEngine(k=5, shortlist_k=96, buckets=(8,),
-                                  mesh=mesh8, serve_backend="sharded")):
+                                  mesh=mesh8)):
             eng.publish(U, V)
+            eng.publish(U, V)       # once per engine, not per publish
         ev = [e for e in reg._events if e["type"] == "serving_backend"]
         assert [(e["backend"], e["n_shards"]) for e in ev] == \
             [("sharded", 8)]
@@ -288,13 +243,13 @@ def test_engine_backend_event_mesh_only(rng, mesh8):
         obs.reset()
 
 
-@pytest.mark.parametrize("backend", ["sharded", "merge_ring"])
-def test_engine_publish_update_modes_on_mesh(rng, mesh8, backend):
+@pytest.mark.parametrize("n_shards", [3, 8])
+def test_engine_publish_update_modes_on_mesh(rng, n_shards):
     Nu, Ni, r, k = 30, 700, 32, 10
     U, V = _tie_corpus(rng, Nu, Ni, r, pool=11)
     valid = rng.random(Ni) < 0.9
     eng = ServingEngine(k=k, shortlist_k=Ni, buckets=(8,),
-                        mesh=mesh8, serve_backend=backend)
+                        mesh=make_mesh(n_shards))
     eng.publish(U, V, item_valid=valid)
     _, mode = eng.publish_update(U, V, item_valid=valid)
     assert mode == "retag"
@@ -308,10 +263,10 @@ def test_engine_publish_update_modes_on_mesh(rng, mesh8, backend):
     assert np.array_equal(ix, ref_i[11]) and np.array_equal(s, ref_s[11])
 
 
-@pytest.mark.parametrize("backend", ["sharded", "merge_ring"])
-def test_engine_torn_publish_serves_fresh_catalog(rng, mesh8, backend):
+@pytest.mark.parametrize("n_shards", [3, 8])
+def test_engine_torn_publish_serves_fresh_catalog(rng, n_shards):
     # a corrupt publish must never leave a stale shard answering: the
-    # fabric handle is dropped and the exact path answers against the
+    # fresh index is dropped and the exact path answers against the
     # FRESH host catalog
     Nu, Ni, r, k = 30, 700, 32, 10
     U, V = _tie_corpus(rng, Nu, Ni, r, pool=11)
@@ -319,7 +274,7 @@ def test_engine_torn_publish_serves_fresh_catalog(rng, mesh8, backend):
     V2 = V.copy()
     V2[[5, 600]] = _tie_corpus(rng, 1, 2, r, pool=11)[1]
     eng = ServingEngine(k=k, shortlist_k=Ni, buckets=(8,),
-                        mesh=mesh8, serve_backend=backend)
+                        mesh=make_mesh(n_shards))
     eng.publish(U, V, item_valid=valid)
     faults.install("serving.publish=corrupt")
     try:
@@ -336,8 +291,7 @@ def test_engine_score_fault_falls_back_exact(rng, mesh8):
     U, V = _tie_corpus(rng, Nu, Ni, r, pool=11)
     valid = rng.random(Ni) < 0.9
     ref_s, ref_i = _reference(U, V, valid, k)
-    eng = ServingEngine(k=k, shortlist_k=Ni, buckets=(8,),
-                        mesh=mesh8, serve_backend="merge_ring")
+    eng = ServingEngine(k=k, shortlist_k=Ni, buckets=(8,), mesh=mesh8)
     eng.publish(U, V, item_valid=valid)
     faults.install("serving.score=corrupt@every=1")
     try:
@@ -345,6 +299,8 @@ def test_engine_score_fault_falls_back_exact(rng, mesh8):
     finally:
         faults.clear()
     assert np.array_equal(ix, ref_i[2]) and np.array_equal(s, ref_s[2])
+    rec, = eng.batch_flight.records()
+    assert rec["path"] == "exact"
 
 
 def test_engine_pin_dropped_on_shape_changing_publish(rng):
@@ -367,7 +323,7 @@ def test_engine_pin_dropped_on_shape_changing_publish(rng):
 
 
 # ---------------------------------------------------------------------------
-# 4. traffic-derived bucket ladder
+# 3. traffic-derived bucket ladder
 
 
 def test_observed_ladder_is_pow2_quantiles():

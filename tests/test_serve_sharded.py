@@ -26,13 +26,16 @@ def _reference(U, V, valid, k):
                                jnp.asarray(valid), k=k)
 
 
+# 3 shards beside the full mesh width: the ring's rotation schedule
+# must not assume a power-of-two neighborhood
+@pytest.mark.parametrize("n_shards", [3, 8])
 @pytest.mark.parametrize("strategy", ["all_gather", "ring"])
-def test_matches_single_device(rng, strategy):
-    U, V = _factors(rng, 41, 97, 8)  # neither divisible by 8 devices
+def test_matches_single_device(rng, strategy, n_shards):
+    U, V = _factors(rng, 41, 97, 8)  # divisible by neither shard count
     valid = np.ones(97, bool)
     k = 10
     ref_s, ref_i = _reference(U, V, valid, k)
-    s, ix = topk_sharded(U, V, k, make_mesh(8), strategy=strategy)
+    s, ix = topk_sharded(U, V, k, make_mesh(n_shards), strategy=strategy)
     np.testing.assert_allclose(s, np.asarray(ref_s), rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(ix, np.asarray(ref_i))
 
@@ -48,17 +51,42 @@ def test_k_larger_than_shard(rng, strategy):
     np.testing.assert_array_equal(ix, np.asarray(ref_i))
 
 
+@pytest.mark.parametrize("n_shards", [3, 8])
 @pytest.mark.parametrize("strategy", ["all_gather", "ring"])
-def test_item_valid_mask(rng, strategy):
+def test_item_valid_mask(rng, strategy, n_shards):
     U, V = _factors(rng, 9, 40, 4)
     valid = rng.random(40) < 0.5
     k = 3
     ref_s, ref_i = _reference(U, V, valid, k)
-    s, ix = topk_sharded(U, V, k, make_mesh(8), strategy=strategy,
+    s, ix = topk_sharded(U, V, k, make_mesh(n_shards), strategy=strategy,
                          item_valid=valid)
     np.testing.assert_allclose(s, np.asarray(ref_s), rtol=1e-5, atol=1e-6)
     # every selected index must be a valid item
     assert valid[ix].all()
+
+
+@pytest.mark.parametrize("strategy", ["all_gather", "ring"])
+def test_all_invalid_shard_never_answers(rng, strategy):
+    # shard 2 of 8 contributes nothing: its local top-k is all sentinel
+    # and must never displace a real candidate in the merge.  Integer
+    # factors from a 7-row palette: every dot product is exact and rows
+    # collide constantly, so ties are the common case and the scores
+    # compare bitwise
+    base = rng.integers(-3, 4, size=(7, 8)).astype(np.float32)
+    V = base[rng.integers(0, 7, 64)]
+    U = rng.integers(-3, 4, size=(11, 8)).astype(np.float32)
+    valid = np.ones(64, bool)
+    valid[16:24] = False
+    k = 5
+    ref_s, _ = _reference(U, V, valid, k)
+    s, ix = topk_sharded(U, V, k, make_mesh(8), strategy=strategy,
+                         item_valid=valid)
+    np.testing.assert_array_equal(s, np.asarray(ref_s))
+    assert not np.isin(ix, np.arange(16, 24)).any()
+    # tied ids may differ from the single-device order: each must earn
+    # its score
+    np.testing.assert_array_equal(
+        np.take_along_axis(U @ V.T, ix.astype(np.int64), axis=1), s)
 
 
 def test_k_capped_at_catalog(rng):

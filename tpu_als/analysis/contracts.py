@@ -31,13 +31,6 @@ against the claimant's own inputs:
   of the updated catalog, and compaction's arrays are byte-equal to
   the rebuild's (serving/index.py; not a jaxpr pin but the same
   discipline — an exactness claim re-verified by name).
-- ``serve_comm_audit``    — the sharded serving fabric's in-kernel
-  cross-shard merge moves exactly the remote-DMA bytes
-  ``perf.roofline.serve_merge_remote_bytes`` prices, traces NO XLA
-  gather/all_gather collectives and exactly one ``pallas_call``
-  (per-shard candidate lists live only in kernel scratch), and its
-  merged top-k is BITWISE equal to single-device
-  ``chunked_topk_scores`` on an adversarial tie catalog.
 - ``floor_audit``         — the committed autotune bank
   (``BENCH_autotune_cpu.json``): the tuned config is never slower than
   the hand-picked defaults, the banked ``model_seconds`` equals the
@@ -60,8 +53,8 @@ and tpu_als subsystems load lazily inside each ``build``.  Contracts
 assume a fresh process (the CLI / smoke-script invocation): process
 state they must control (guardrails mode, the plan-cache env var, probe
 caches) is saved and restored, but a caller that already armed a
-subsystem mid-process may see spurious verdicts.  ``comm_audit`` and
-``serve_comm_audit`` need a multi-device backend — start Python with
+subsystem mid-process may see spurious verdicts.  ``comm_audit`` needs
+a multi-device backend — start Python with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` on CPU.
 """
 
@@ -665,115 +658,6 @@ def _pin_live_delta(a):
             "bitwise == full rebuild; compacted arrays byte-equal")
 
 
-# -- serve_comm_audit -------------------------------------------------------
-
-def _build_serve_comm_audit():
-    import numpy as np
-
-    import jax
-    import jax.numpy as jnp
-
-    from tpu_als.ops.topk import chunked_topk_scores
-    from tpu_als.parallel.comm_audit import (
-        collective_bytes,
-        remote_dma_bytes,
-    )
-    from tpu_als.parallel.mesh import make_mesh, replicated, shard_leading
-    from tpu_als.parallel.serve import _build
-    from tpu_als.perf.roofline import serve_merge_remote_bytes
-
-    D = len(jax.devices())
-    if D < 2:
-        raise ContractViolation(
-            "serve_comm_audit needs a multi-device backend; start Python "
-            "with XLA_FLAGS=--xla_force_host_platform_device_count=8 on "
-            "CPU")
-    # integer-valued factors drawn from a tiny pool: duplicate rows
-    # everywhere, so the catalog is ADVERSARIALLY tied and every f32
-    # dot product is exact — bitwise equality is meaningful, not lucky
-    rng = np.random.default_rng(23)
-    n, Ni, r, k = 40, 87 * D, 32, 10
-    pool = rng.integers(-3, 4, size=(7, r)).astype(np.float32)
-    V = pool[rng.integers(0, 7, Ni)]
-    U = rng.integers(-3, 4, size=(n, r)).astype(np.float32)
-    valid = rng.random(Ni) < 0.9
-    ni_loc = -(-Ni // D)
-    dead = min(2, D - 1)
-    valid[dead * ni_loc:(dead + 1) * ni_loc] = False  # all-invalid shard
-    mesh = make_mesh(D)
-    k_eff = min(k, Ni)
-    tile_u = min(256, -(-n // 8) * 8)
-    tile_i = min(512, -(-ni_loc // 128) * 128)
-    f = _build(mesh, ni_loc, k_eff, min(k_eff, ni_loc), "merge_ring",
-               8192, tile_u=tile_u, tile_i=tile_i, interpret=True)
-    cap = D * ni_loc
-    Vp = np.pad(V, ((0, cap - Ni), (0, 0)))
-    validp = np.pad(valid, (0, cap - Ni))
-    args = (jax.device_put(U, replicated(mesh)),
-            jax.device_put(Vp, shard_leading(mesh)),
-            jax.device_put(validp, shard_leading(mesh)))
-    # the merge ring's schedule: one hop per (user tile, step), S-1
-    # steps — the ``fires`` contract pinned in remote_dma_bytes' docs
-    traced, _ = remote_dma_bytes(f, *args,
-                                 fires=lambda g: g[0] * (D - 1))
-    n_ut = -(-n // tile_u)
-    model = serve_merge_remote_bytes(n_ut, D, tile_u)
-    _, breakdown = collective_bytes(f, *args, axis_size=D)
-
-    # per-shard candidate lists must exist ONLY in kernel scratch: the
-    # traced program holds exactly one pallas_call and no HBM-level
-    # gather/concat of per-shard top-k outputs feeding a host merge
-    def count_pallas(jaxpr, acc=None):
-        acc = [] if acc is None else acc
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                acc.append(eqn)
-            for p in ("jaxpr", "call_jaxpr"):
-                inner = eqn.params.get(p) if eqn.params else None
-                if inner is not None:
-                    count_pallas(getattr(inner, "jaxpr", inner), acc)
-            for br in (eqn.params.get("branches", ())
-                       if eqn.params else ()):
-                count_pallas(getattr(br, "jaxpr", br), acc)
-        return acc
-
-    n_pallas = len(count_pallas(jax.make_jaxpr(f)(*args).jaxpr))
-    s, ix = f(*args)
-    ref_s, ref_i = chunked_topk_scores(jnp.asarray(U), jnp.asarray(V),
-                                       jnp.asarray(valid), k_eff)
-    return {"traced": traced, "model": model, "breakdown": breakdown,
-            "devices": D, "n_pallas": n_pallas,
-            "s": np.asarray(s), "ix": np.asarray(ix),
-            "ref_s": np.asarray(ref_s), "ref_i": np.asarray(ref_i),
-            "queries": n}
-
-
-def _pin_serve_comm_audit(a):
-    import numpy as np
-
-    _require(a["traced"] == a["model"],
-             f"traced in-kernel remote-DMA bytes {a['traced']} != "
-             f"perf.roofline serve_merge_remote_bytes {a['model']}")
-    _require(not a["breakdown"],
-             "the fused serving path still traces XLA collectives "
-             f"({sorted(a['breakdown'])}) — the cross-shard merge did "
-             "not move in-kernel")
-    _require(a["n_pallas"] == 1,
-             f"expected exactly one pallas_call (merge in VMEM "
-             f"scratch), traced {a['n_pallas']} — per-shard candidate "
-             "lists are materializing outside the kernel")
-    _require(np.array_equal(a["s"], a["ref_s"]),
-             "merged top-k SCORES differ from single-device "
-             "chunked_topk_scores on the tie catalog")
-    _require(np.array_equal(a["ix"], a["ref_i"]),
-             "merged top-k INDICES differ from single-device "
-             "chunked_topk_scores — tie ORDER is not reproduced")
-    return (f"in-kernel remote-DMA {a['traced']} B == closed form "
-            f"across {a['devices']} shards; no XLA collectives; one "
-            f"pallas_call; {a['queries']}-query top-k bitwise == "
-            "single-device exact on an adversarial tie catalog")
-
-
 # -- registry ---------------------------------------------------------------
 
 # -- elastic_disarmed -------------------------------------------------------
@@ -954,9 +838,6 @@ _REGISTRY = {
                  "tests/test_ring_substrate.py, PR 15"),
         Contract("live_delta_index", _build_live_delta, _pin_live_delta,
                  "tests/test_live.py, PR 11"),
-        Contract("serve_comm_audit", _build_serve_comm_audit,
-                 _pin_serve_comm_audit,
-                 "tests/test_serve_fabric.py, PR 17"),
         Contract("elastic_disarmed", _build_elastic_disarmed,
                  _pin_elastic_disarmed,
                  "tests/test_resilience.py, PR 18"),

@@ -1084,7 +1084,7 @@ def cmd_serve_bench(args):
         mesh = make_mesh(args.mesh_devices)
     engine = ServingEngine(
         k=args.k, buckets=buckets, shortlist_k=args.shortlist_k,
-        mesh=mesh, serve_backend=args.serve_backend,
+        mesh=mesh,
         max_queue=args.max_queue, max_wait_s=args.max_wait_ms / 1e3,
         default_deadline_s=(args.deadline_ms / 1e3
                             if args.deadline_ms else None),
@@ -1245,9 +1245,8 @@ def cmd_serve_bench(args):
         },
     }
     if mesh is not None:
-        result["backend"] = engine._backend
+        result["backend"] = "sharded"
         result["config"]["mesh_devices"] = int(args.mesh_devices)
-        result["config"]["serve_backend"] = args.serve_backend
     # feed the OBSERVED request-size mix back into the planner: the
     # batch_rows histogram's {p50,p90,p99,max}, weight-reconstructed
     # into a sample so the planner's own quantiles land on the same
@@ -2002,11 +2001,6 @@ def main(argv=None):
                          "(never committed whole to one device) and "
                          "scoring runs the sharded fabric "
                          "(docs/serving.md)")
-    sb.add_argument("--serve-backend", default="auto",
-                    choices=("auto", "local", "sharded", "merge_ring"),
-                    help="scoring backend on the mesh: sharded int8 "
-                         "fan-out, the in-kernel merge-ring top-k, or "
-                         "auto (probe-gated); local ignores the mesh")
     sb.add_argument("--update-qps", type=float, default=0.0,
                     help="concurrent rating-event rate through the "
                          "live fold-in → publish pipeline; >0 makes "

@@ -354,9 +354,9 @@ EVENTS = {
         "catalog size, and whether an int8 index was built for it"),
     "serving_backend": (
         ("backend", "n_shards"),
-        "one per ServingEngine, at first publish: the scoring backend "
-        "the engine resolved (local / sharded / merge_ring), after the "
-        "live-mesh probe for the in-kernel merge"),
+        "one per ServingEngine that was given a mesh, at first publish: "
+        "backend is sharded (the int8 index sharded over n_shards "
+        "devices); an engine without a mesh emits none"),
     "serving_shortlist": (
         ("bucket", "path", "stages", "blocks", "block_len", "columns"),
         "one per int8 scoring program ServingEngine.warmup / warmup_live "

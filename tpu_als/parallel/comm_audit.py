@@ -149,7 +149,7 @@ def collective_bytes(fn, *args, axis_size):
     return int(sum(breakdown.values())), breakdown
 
 
-def remote_dma_bytes(fn, *args, fires=None):
+def remote_dma_bytes(fn, *args):
     """Per-device IN-KERNEL inter-chip bytes of one call of ``fn(*args)``:
     the remote-DMA payloads a Pallas kernel moves with
     ``make_async_remote_copy`` (ops.ring_buffer.remote_copy), which
@@ -159,21 +159,15 @@ def remote_dma_bytes(fn, *args, fires=None):
     A ``dma_start`` is REMOTE iff it carries a send/recv semaphore PAIR
     (local copies have exactly one DMA semaphore); its payload is the
     source ref's aval.  Multiplicity is a SCHEDULE, not derivable from
-    the jaxpr alone; the default is the fused-comm ring's contract
+    the jaxpr alone; the audit knows the fused-comm ring's contract
     (ops.pallas_gather_ne._gather_solve_ring_kernel): grid ``(row_tiles,
     ring_steps, width_chunks)``, ONE transfer per (row tile, step ``t <=
     S-2``) — the parity-variant ``dma_start``s are mutually exclusive
     ``cond`` arms of that one transfer, so the audit requires them to
     move identical payloads and counts ``grid[0] * (grid[1] - 1)`` fires
-    per kernel call, refusing any other grid arity.  A kernel with a
-    different schedule passes ``fires``, a callable mapping the kernel's
-    grid tuple to its fire count (the serving merge ring
-    — ops.pallas_topk._topk_merge_ring_kernel, grid ``(user_tiles,
-    score_phases + S)``, one transfer per (user tile, hop) — passes
-    ``lambda g: g[0] * (S - 1)`` from the ``serve_comm_audit`` contract).
-    The identical-payload rule applies either way: a kernel whose remote
-    arms disagree on payload is data-dependent traffic → raise, same
-    policy as :func:`collective_bytes`'s ``cond`` rule.
+    per kernel call, refusing any other grid arity.  A kernel whose
+    remote arms disagree on payload is data-dependent traffic → raise,
+    same policy as :func:`collective_bytes`'s ``cond`` rule.
 
     Returns ``(total_bytes, per_call)`` where ``per_call`` lists each
     ``pallas_call``'s contribution (scan-scaled).
@@ -181,34 +175,13 @@ def remote_dma_bytes(fn, *args, fires=None):
     closed = jax.make_jaxpr(fn)(*args)
     per_call = []
 
-    def payload_bytes(eqn):
-        # the transferred extent, not the full source ref: a send from a
-        # dynamically-indexed slot (``ref.at[slot]`` — the serving merge
-        # ring's collect buffer) carries the ref WHOLE in invars[0] with
-        # the indexer in params['tree']; reconstruct it and price the
-        # indexer shape.  Refs sent whole have no transform and fall
-        # through to the full aval (the fused-comm ring's landing
-        # buffers — byte-identical to the pre-extension audit).
-        aval = eqn.invars[0].aval
-        try:
-            unflat = jax.tree_util.tree_unflatten(
-                eqn.params["tree"], list(eqn.invars))
-            transforms = unflat[1]
-            if transforms:
-                shape = tuple(transforms[-1].get_indexer_shape())
-                return (int(np.prod(shape))
-                        * np.dtype(aval.dtype).itemsize)
-        except Exception:
-            pass
-        return _aval_bytes(aval)
-
     def kernel_remote_payloads(jaxpr, out):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "dma_start":
                 sems = [v for v in eqn.invars
                         if "semaphore" in str(getattr(v, "aval", ""))]
                 if len(sems) >= 2:
-                    out.append(payload_bytes(eqn))
+                    out.append(_aval_bytes(eqn.invars[0].aval))
             for p in ("jaxpr", "call_jaxpr", "body_jaxpr", "cond_jaxpr"):
                 inner = eqn.params.get(p) if eqn.params else None
                 if inner is not None:
@@ -232,17 +205,12 @@ def remote_dma_bytes(fn, *args, fires=None):
                         f"{sorted(set(payloads))} — data-dependent "
                         "traffic is unauditable")
                 grid = tuple(eqn.params["grid_mapping"].grid)
-                if fires is not None:
-                    n_fires = int(fires(grid))
-                else:
-                    if len(grid) != 3:
-                        raise ValueError(
-                            f"remote-DMA kernel with grid {grid}: the "
-                            "default audit only knows the fused-comm "
-                            "ring schedule (row_tiles, ring_steps, "
-                            "width_chunks) — pass ``fires`` for other "
-                            "schedules")
-                    n_fires = grid[0] * max(0, grid[1] - 1)
+                if len(grid) != 3:
+                    raise ValueError(
+                        f"remote-DMA kernel with grid {grid}: the audit "
+                        "only knows the fused-comm ring schedule "
+                        "(row_tiles, ring_steps, width_chunks)")
+                n_fires = grid[0] * max(0, grid[1] - 1)
                 per_call.append(mult * payloads[0] * n_fires)
             elif name == "scan":
                 walk(eqn.params["jaxpr"].jaxpr,

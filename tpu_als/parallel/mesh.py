@@ -107,7 +107,3 @@ def slice_boundaries(devices, slice_of=None):
 def shard_leading(mesh, axis=AXIS):
     """NamedSharding that splits the leading array axis over the mesh."""
     return NamedSharding(mesh, P(axis))
-
-
-def replicated(mesh):
-    return NamedSharding(mesh, P())

@@ -19,29 +19,12 @@ two scale-out strategies the trainer has (``parallel/trainer.py``):
   + the [n, k] running state, and the cross-device traffic is the item
   table once around the ring plus nothing else (the [n, 2k] merge is
   local).
-- ``merge_ring``: the in-kernel fused path (ops.pallas_topk.
-  topk_merge_ring): queries replicate, each device scores its OWN
-  resident shard inside one Pallas kernel, and the per-shard candidate
-  sets rotate as ``make_async_remote_copy`` hops on the ring substrate,
-  merged in VMEM — no XLA gather collective traces, no per-shard
-  candidate list ever lands in HBM, and the wire bytes per query are
-  independent of catalog size (perf.roofline.serve_merge_remote_bytes;
-  pinned by the ``serve_comm_audit`` contract).  On TPU it is adopted
-  only after the live-mesh probe ``pallas_topk.merge_ring_available``
-  passes for THIS shard count — banked verdicts never steer collectives
-  — and degrades to ``ring`` when the probe fails or ``k > 128``;
-  off-TPU the interpret-mode kernel is dispatched unconditionally
-  (tests/contracts; CPU serving engines prefer the compiled XLA
-  strategies for throughput).
 
 Tie-breaking note: with equal scores the selected index can differ
 between ``all_gather`` and ``ring`` (merge order is shard-rotation
 order, which differs per device); scores agree to f32
 reduction-order rounding (the per-strategy GEMM shapes differ — the
 ``SCORE_ULPS`` contract of tpu_als/serving/index.py).
-``merge_ring`` is stronger: its stable in-kernel merge reproduces the
-single-device ``chunked_topk_scores`` tie-break bitwise (ids included)
-whenever the score values themselves agree across contraction shapes.
 """
 
 from __future__ import annotations
@@ -60,7 +43,7 @@ from tpu_als.ops.topk import NEG_INF, chunked_topk_scores
 from tpu_als.parallel.mesh import AXIS, shard_map
 from tpu_als.resilience import faults
 
-STRATEGIES = ("all_gather", "ring", "merge_ring")
+STRATEGIES = ("all_gather", "ring")
 
 
 class ServeShardLost(RuntimeError):
@@ -128,15 +111,12 @@ def _merge_topk(s1, i1, s2, i2, k):
 
 
 @functools.lru_cache(maxsize=32)
-def _build(mesh, ni_loc, k, k_loc, strategy, item_chunk,
-           tile_u=256, tile_i=512, interpret=False):
+def _build(mesh, ni_loc, k, k_loc, strategy, item_chunk):
     """Compiled sharded top-k for one (mesh, shapes, k, strategy) tuple.
 
     ``jax.sharding.Mesh`` is hashable, so the cache key is exact; without
     the cache every serving call would rebuild the shard_map closure and
-    recompile.  ``tile_u``/``tile_i``/``interpret`` only shape the
-    ``merge_ring`` kernel instantiation (the XLA strategies ignore them;
-    the defaults keep their cache keys unchanged).
+    recompile.
     """
     D = mesh.devices.size
 
@@ -172,24 +152,6 @@ def _build(mesh, ni_loc, k, k_loc, strategy, item_chunk,
             0, D, step, (V_loc, valid_loc, s0, i0))
         return s, ix
 
-    def body_merge_ring(U_full, V_loc, valid_loc):
-        from tpu_als.ops.pallas_topk import topk_merge_ring
-
-        return topk_merge_ring(
-            U_full, V_loc, valid_loc, k, axis_name=AXIS, n_shards=D,
-            ni_loc=ni_loc, tile_u=tile_u, tile_i=tile_i,
-            interpret=interpret)
-
-    if strategy == "merge_ring":
-        # queries replicate (serving batches are tiny next to the
-        # catalog); the merged result is identical on every device, so
-        # the replicated out_specs are sound under check_vma=False
-        return jax.jit(shard_map(
-            body_merge_ring, mesh=mesh,
-            in_specs=(P(), P(AXIS), P(AXIS)),
-            out_specs=(P(), P()),
-            check_vma=False,
-        ))
     body = body_all_gather if strategy == "all_gather" else body_ring
     return jax.jit(shard_map(
         body, mesh=mesh,
@@ -257,43 +219,19 @@ def topk_sharded(U, V, k, mesh, strategy="all_gather", item_valid=None,
     k_eff = min(k, Ni)
     nu_loc = -(-Nu // D)
     ni_loc = -(-Ni // D)
-    if strategy == "merge_ring":
-        from tpu_als.utils.platform import on_tpu
-
-        interpret = not on_tpu()
-        if k_eff > 128:
-            # one lane tile carries the in-kernel candidate set
-            strategy = "ring"
-        elif not interpret:
-            # live-mesh probe, THIS shard count — a banked verdict for a
-            # different mesh is a cache miss, never a steer (the
-            # gather_fused_ring rule); a failed probe degrades to the
-            # XLA ring instead of crashing serving
-            from tpu_als.ops.pallas_topk import merge_ring_available
-
-            if not merge_ring_available(r, k_eff, D):
-                strategy = "ring"
     Vp = np.pad(V, ((0, D * ni_loc - Ni), (0, 0)))
     validp = np.pad(valid, (0, D * ni_loc - Ni))  # pad rows never win
     k_loc = min(k_eff, ni_loc)
-    if strategy == "merge_ring":
-        f = _build(mesh, ni_loc, k_eff, k_loc, strategy, item_chunk,
-                   tile_u=min(256, -(-Nu // 8) * 8),
-                   tile_i=min(512, -(-ni_loc // 128) * 128),
-                   interpret=interpret)
-        Up = U  # replicated queries; the kernel wrapper pads internally
-    else:
-        f = _build(mesh, ni_loc, k_eff, k_loc, strategy,
-                   min(item_chunk, ni_loc if strategy == "ring"
-                       else D * ni_loc))
-        Up = np.pad(U, ((0, D * nu_loc - Nu), (0, 0)))
+    f = _build(mesh, ni_loc, k_eff, k_loc, strategy,
+               min(item_chunk, ni_loc if strategy == "ring"
+                   else D * ni_loc))
+    Up = np.pad(U, ((0, D * nu_loc - Nu), (0, 0)))
     # place shard-wise (NOT jnp.asarray, which would commit the FULL
     # padded catalog to one device before resharding — the exact OOM the
     # ring strategy exists to avoid at 48M-item scale)
-    from tpu_als.parallel.mesh import replicated, shard_leading
+    from tpu_als.parallel.mesh import shard_leading
 
     spec = shard_leading(mesh)
-    u_spec = replicated(mesh) if strategy == "merge_ring" else spec
     multiproc = jax.process_count() > 1
     try:
         with obs.span("serve.topk", strategy=strategy):
@@ -301,7 +239,7 @@ def topk_sharded(U, V, k, mesh, strategy="all_gather", item_valid=None,
             # a shard is stale/lost (nothing sane to execute against)
             if faults.check("serve.gather") == "corrupt":
                 raise ServeShardLost("stale/lost factor shard")
-            s, ix = f(jax.device_put(Up, u_spec),
+            s, ix = f(jax.device_put(Up, spec),
                       jax.device_put(Vp, spec),
                       jax.device_put(validp, spec))
             if multiproc:

@@ -145,50 +145,6 @@ def fused_ring_kernel_bytes(P, n, r, db, ring_bytes):
     return fused_solve_kernel_bytes(P, n, r, db) + int(ring_bytes)
 
 
-def serve_merge_remote_bytes(n_user_tiles, n_shards, tile_u, lanes=128):
-    """In-kernel remote-DMA payload of ONE ``topk_merge_ring`` call
-    (tpu_als.ops.pallas_topk): every user tile runs its own ring pass,
-    and each pass forwards one packed ``[tile_u, 2·lanes]`` f32 candidate
-    set (scores ++ bitcast ids) ``S - 1`` times — the set received each
-    hop is what gets forwarded next, so after ``S - 1`` hops every device
-    holds all ``S`` per-shard sets in VMEM and merges them locally.
-    Note what is ABSENT from the form: the catalog (it never rotates; a
-    query costs ``O(S · tile_u · lanes)`` wire bytes however large the
-    sharded table is) and any per-shard ``[n, k]`` HBM list (the sets
-    live only in the kernel's VMEM collect buffer).
-
-    THE single source of truth shared by the kernel's
-    ``pl.CostEstimate`` ring term, the per-query serving model
-    (:func:`serve_query_bytes`, docs/roofline.md), and the
-    ``serve_comm_audit`` contract (analysis/contracts.py) that pins the
-    traced remote-DMA payload × fire count to this formula.
-    """
-    return int(n_user_tiles * max(0, n_shards - 1)
-               * tile_u * 2 * lanes * 4)
-
-
-def serve_query_bytes(n_queries, n_shards, ni, r, *, tile_u=256,
-                      lanes=128, db=4):
-    """Per-batch byte model of one fused sharded serving call, split by
-    channel: ``hbm`` = each device streams its OWN catalog shard once
-    (``ceil(Ni/S)·r·db``) plus the replicated query rows and the [n,
-    LANES] result pair; ``ici`` = :func:`serve_merge_remote_bytes` over
-    ``ceil(n/tile_u)`` user tiles.  Divide by ``n_queries`` for the
-    per-query closed form docs/roofline.md quotes: the wire cost per
-    query is independent of catalog size — the scaling property the
-    sharded fabric exists for.
-    """
-    S = max(1, int(n_shards))
-    ni_loc = -(-int(ni) // S)
-    n_ut = -(-int(n_queries) // int(tile_u))
-    hbm = int(n_queries * r * db + ni_loc * r * db
-              + 2 * n_queries * lanes * 4)
-    ici = serve_merge_remote_bytes(n_ut, S, tile_u, lanes)
-    return {"hbm_bytes": hbm, "ici_bytes": ici,
-            "hbm_per_query": hbm / max(1, n_queries),
-            "ici_per_query": ici / max(1, n_queries)}
-
-
 def einsum_ne_build_bytes(P, n, r, db, restream=1.0):
     """Modeled NE-build bytes of the UNFUSED path (gather_stream +
     normal_eq stages below, summed): the gather reads one factor row per

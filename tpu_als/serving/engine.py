@@ -57,21 +57,17 @@ tickets.  The pieces the rest of the stack plugs into:
   row, so every idle gap of the device falls under the phase that held
   the engine thread.  The same durations go into one record per batch
   in a second ring, ``batch_flight``, dumped on the same triggers.
-- **Sharded serving fabric.**  With a ``mesh``, the catalog lives
-  device-resident per shard and never commits whole to one device:
-  ``serve_backend="sharded"`` publishes a
-  :class:`~tpu_als.serving.index.ShardedInt8Index` (mesh-sharded int8
-  shortlist + exact rescore, one XLA merge per query);
-  ``serve_backend="merge_ring"`` serves EXACT f32 through the in-kernel
-  cross-shard merge (``ops.pallas_topk.topk_merge_ring`` — per-shard
-  Pallas top-k, candidate sets rotated neighbor-to-neighbor as remote
-  DMAs and merged in VMEM, no per-shard candidate list in HBM).
-  ``"auto"`` resolves per process behind a LIVE mesh probe
-  (``merge_ring_available`` — banked verdicts never steer collectives):
-  merge_ring on a probed TPU mesh, the sharded XLA path otherwise.
-  Mesh backends keep the engine's own catalog handle on the HOST (the
-  exact fallback re-uploads per batch — rare by construction), so the
-  single-device-copy the fabric exists to avoid never reappears here.
+- **One algorithm on any number of chips.**  The engine serves the
+  int8 shortlist + exact f32 rescore from a candidate index: an
+  :class:`~tpu_als.serving.index.Int8CandidateIndex` when ``mesh`` is
+  None, a :class:`~tpu_als.serving.index.ShardedInt8Index` (the same
+  shortlist and rescore per shard, one XLA merge per query) when a mesh
+  is given; a stale or absent index falls back to the exact chunked
+  scan.  ``_build_index`` is the one place that reads the mesh to
+  choose.  With a mesh the catalog lives device-resident per shard and
+  never commits whole to one device: the engine's own catalog handle
+  stays on the HOST (the exact fallback re-uploads per batch — rare by
+  construction).
 - **Host throughput.**  The request path stages each micro-batch into
   one reusable per-bucket ``[B, rank+2]`` int32 array (query rows' f32
   bits | ids | row-mask) and uploads it as ONE transfer — no per-batch
@@ -81,7 +77,7 @@ tickets.  The pieces the rest of the stack plugs into:
   that buffer — zero per-ticket copies; the buffer snapshots an
   immutable device array, so the views stay valid indefinitely.
   :meth:`ServingEngine.warmup` additionally PINS the steady-state
-  local scoring executables ahead of time (``jit(...).lower().
+  mesh-less scoring executables ahead of time (``jit(...).lower().
   compile()`` per bucket), taking jit-cache dispatch off the hot path;
   a shape-changing publish invalidates a pin and falls back to the
   ordinary jit call until the next warmup.
@@ -102,7 +98,6 @@ from tpu_als import obs
 from tpu_als.core.foldin import pad_rows
 from tpu_als.core.ratings import (
     LIVE_PADS,
-    _next_pow2,
     pad_for,
     pads_up_to,
     row_capacity,
@@ -132,44 +127,29 @@ class _Published:
 
     ``U`` holds ``n_users`` live rows and spare zero rows after them,
     which no request addresses (``submit`` checks ids against
-    ``n_users``).  ``V``/``valid`` are device arrays on the local backend
-    and HOST numpy on mesh backends (see the module docstring);
-    ``Vs``/``valids``
-    are the merge-ring backend's shard-resident padded catalog
-    (``None`` elsewhere, or after a torn merge-ring publish — the
-    score path then falls back exact against the fresh host catalog).
+    ``n_users``).  ``V``/``valid`` are device arrays on a mesh-less
+    engine and HOST numpy on a mesh engine (see the module docstring).
     """
 
-    __slots__ = ("seq", "U", "V", "valid", "index", "n_users", "rank",
-                 "Vs", "valids", "ni_loc")
+    __slots__ = ("seq", "U", "V", "valid", "index", "n_users", "rank")
 
-    def __init__(self, seq, U, n_users, V, valid, index,
-                 Vs=None, valids=None, ni_loc=0):
+    def __init__(self, seq, U, n_users, V, valid, index):
         self.seq = seq
         self.U = U
         self.V = V
         self.valid = valid
         self.index = index
-        self.Vs = Vs
-        self.valids = valids
-        self.ni_loc = int(ni_loc)
         self.n_users = int(n_users)
         self.rank = int(U.shape[1])
 
 
-@functools.partial(jax.jit, static_argnames=())
-def _select_rows(U, ids, rows, rowmask):
-    """Per-slot query vectors: the published row for id-requests, the
-    carried fold-in vector for row-requests (``rowmask``)."""
-    ids = jnp.clip(ids, 0, U.shape[0] - 1)   # pad slots point anywhere safe
-    return jnp.where(rowmask[:, None], rows, jnp.take(U, ids, axis=0))
-
-
 @jax.jit
 def _select_packed(U, packed):
-    """:func:`_select_rows` over the single-upload staging layout, an
-    INT32 array: ``packed[:, :rank]`` the fold-in rows' f32 bits,
-    ``packed[:, rank]`` user ids, ``packed[:, rank+1]`` the row-mask —
+    """Per-slot query vectors — the published row for id-requests, the
+    carried fold-in vector for row-requests — from the single-upload
+    staging layout, an INT32 array: ``packed[:, :rank]`` the fold-in
+    rows' f32 bits, ``packed[:, rank]`` user ids, ``packed[:, rank+1]``
+    the row-mask —
     one host→device transfer carries all three.  Floats ride as integer
     bits, never ids as float bits: a small int viewed as f32 is a
     subnormal, and the TPU flushes subnormals to zero on any float op —
@@ -212,16 +192,6 @@ def _serve_int8_packed(U, Vq, sv, V, valid, packed, *, k, shortlist_k):
 
 
 @jax.jit
-def _scatter_catalog(Vs, valids, rows, vals, vmask):
-    """Touched-rows-only refresh of the merge-ring backend's sharded
-    catalog: ``rows`` are padded to pow2 with an out-of-range sentinel
-    (``mode='drop'``), so repeated delta publishes hit a bounded jit
-    cache and only the touched payload crosses host→device."""
-    return (Vs.at[rows].set(vals, mode="drop"),
-            valids.at[rows].set(vmask, mode="drop"))
-
-
-@jax.jit
 def _scatter_users(U, rows, vals):
     """The user table with ``vals`` written at ``rows``, as a new array:
     the generation a batch in flight was dequeued with stays whole.
@@ -257,15 +227,7 @@ class ServingEngine:
                  max_queue=1024, max_wait_s=0.002,
                  default_deadline_s=None, item_chunk=8192,
                  slo_s=None, flight_capacity=64, tenant=None,
-                 mesh=None, serve_backend="auto"):
-        if serve_backend not in ("auto", "local", "sharded",
-                                 "merge_ring"):
-            raise ValueError(
-                f"unknown serve_backend {serve_backend!r} (expected "
-                "'auto', 'local', 'sharded' or 'merge_ring')")
-        if mesh is None and serve_backend in ("sharded", "merge_ring"):
-            raise ValueError(
-                f"serve_backend={serve_backend!r} requires a mesh")
+                 mesh=None):
         if buckets is None:
             # bucket plan from the execution planner: a banked ladder
             # for this device/jax key wins, else DEFAULT_BUCKETS — and
@@ -301,123 +263,23 @@ class ServingEngine:
         self._thread = None
         self._stopping = threading.Event()
         self.mesh = mesh
-        self._backend_req = serve_backend
-        # resolved lazily at the first publish (the live-mesh probe
-        # needs the published rank); mesh-less engines are local by
-        # construction
-        self._backend = "local" if mesh is None else None
         self._stage = {}                # bucket -> reusable [B, rank+2]
         self._pinned = {}               # (bucket, path) -> AOT executable
 
-    # -- backend resolution -------------------------------------------
-    def _resolve_backend(self, rank):
-        """Pick the scoring backend once per engine, at first publish.
-
-        ``auto`` on a mesh probes the LIVE hardware for the in-kernel
-        merge (``merge_ring_available`` — a banked verdict is never
-        consulted: verdicts steer no collectives) and falls back to the
-        sharded XLA path; a FORCED ``merge_ring`` on a mesh the probe
-        rejects degrades to ``sharded`` with a warning rather than
-        letting an unprobed collective near live traffic.
-        """
-        if self._backend is not None:
-            return self._backend
-        from tpu_als.utils.platform import on_tpu
-
-        req = self._backend_req
-        backend = req if req != "auto" else "sharded"
-        if req in ("auto", "merge_ring") and on_tpu():
-            from tpu_als.ops.pallas_topk import merge_ring_available
-
-            ok = (self.k <= 128 and merge_ring_available(
-                rank, self.k, int(self.mesh.devices.size)))
-            if req == "auto":
-                backend = "merge_ring" if ok else "sharded"
-            elif not ok:
-                obs.emit("warning", what="serving.backend",
-                         reason="merge_ring probe failed on this mesh; "
-                                "degrading to the sharded XLA backend")
-                backend = "sharded"
-        self._backend = backend
-        obs.emit("serving_backend", backend=backend,
-                 n_shards=int(self.mesh.devices.size), **self._labels)
-        return backend
-
     def _build_index(self, V, valid, sk, seq):
-        if self._backend == "sharded":
-            return ShardedInt8Index(V, self.mesh, item_valid=valid,
-                                    shortlist_k=sk, seq=seq)
-        return Int8CandidateIndex(V, valid, shortlist_k=sk, seq=seq)
+        """The candidate index of one generation, sharded over the mesh
+        when the engine has one: the one place that chooses."""
+        if self.mesh is None:
+            return Int8CandidateIndex(V, valid, shortlist_k=sk, seq=seq)
+        return ShardedInt8Index(V, self.mesh, item_valid=valid,
+                                shortlist_k=sk, seq=seq)
 
-    def _place_sharded(self, Vh, validh):
-        """Shard-wise placement of the merge-ring catalog: each host
-        slice transfers to its own device; the full table is never
-        committed to one device."""
-        from tpu_als.parallel.mesh import shard_leading
-
-        D = int(self.mesh.devices.size)
-        Ni = int(Vh.shape[0])
-        ni_loc = -(-Ni // D)
-        cap = D * ni_loc
-        spec = shard_leading(self.mesh)
-        Vs = jax.device_put(np.pad(Vh, ((0, cap - Ni), (0, 0))), spec)
-        valids = jax.device_put(np.pad(validh, (0, cap - Ni)), spec)
-        return Vs, valids, ni_loc
-
-    def _merge_fn(self, B, m):
-        """The merge-ring scoring executable for bucket ``B`` against
-        generation ``m`` (lru-cached in ``parallel.serve._build``)."""
-        from tpu_als.parallel.serve import _build
-        from tpu_als.utils.platform import on_tpu
-
-        Ni = int(m.V.shape[0])
-        k_eff = min(self.k, Ni)
-        return _build(self.mesh, m.ni_loc, k_eff,
-                      min(k_eff, m.ni_loc), "merge_ring",
-                      self.item_chunk,
-                      tile_u=min(256, -(-B // 8) * 8),
-                      tile_i=min(512, -(-m.ni_loc // 128) * 128),
-                      interpret=not on_tpu())
-
-    def _update_sharded(self, prev, Vh, valid_h, touched, Ni):
-        """Incremental refresh of the merge-ring backend's sharded
-        catalog: O(touched) host→device traffic per publish.  Returns
-        ``(Vs, valids, ni_loc, mode)`` — ``retag`` shares the previous
-        placement untouched, ``delta`` scatters only the
-        touched/appended rows into it (pow2-padded, bounded jit cache),
-        and anything the incremental path cannot express (first
-        publish, torn predecessor, shrink, growth past the padded
-        capacity, out-of-range rows) re-places the catalog whole
-        (``full``)."""
-        prev_ok = (prev is not None and prev.Vs is not None
-                   and prev.ni_loc > 0)
-        if prev_ok:
-            cap = int(prev.Vs.shape[0])
-            prev_ni = int(prev.V.shape[0])
-            rows = np.union1d(touched, np.arange(prev_ni, Ni))
-            if prev_ni <= Ni <= cap and (not rows.size
-                                         or int(rows[-1]) < Ni):
-                if not rows.size and Ni == prev_ni:
-                    return prev.Vs, prev.valids, prev.ni_loc, "retag"
-                r = int(Vh.shape[1])
-                n_pad = _next_pow2(len(rows))
-                rp = np.full(n_pad, cap, dtype=np.int32)  # OOB: dropped
-                rp[:len(rows)] = rows
-                vals = np.zeros((n_pad, r), dtype=np.float32)
-                vals[:len(rows)] = Vh[rows]
-                vmask = np.zeros(n_pad, dtype=bool)
-                vmask[:len(rows)] = valid_h[rows]
-                Vs, valids = _scatter_catalog(
-                    prev.Vs, prev.valids, jnp.asarray(rp),
-                    jnp.asarray(vals), jnp.asarray(vmask))
-                return Vs, valids, prev.ni_loc, "delta"
-            obs.emit("warning", what="serving.publish_update",
-                     reason="sharded delta rejected (shrink, capacity "
-                            "or out-of-range rows), full re-place")
-        if Ni == 0:
-            return None, None, 0, "none"
-        Vs, valids, ni_loc = self._place_sharded(Vh, valid_h)
-        return Vs, valids, ni_loc, "full"
+    def _announce_mesh(self):
+        """One ``serving_backend`` event per mesh engine, at its first
+        publish; a mesh-less engine emits nothing."""
+        if self.mesh is not None and self._seq == 0:
+            obs.emit("serving_backend", backend="sharded",
+                     n_shards=int(self.mesh.devices.size), **self._labels)
 
     # -- model lifecycle ----------------------------------------------
     @staticmethod
@@ -489,25 +351,18 @@ class ServingEngine:
         Ni = int(Vh.shape[0])
         validh = (np.ones(Ni, dtype=bool) if item_valid is None
                   else np.asarray(item_valid, dtype=bool).ravel())
-        backend = self._resolve_backend(int(U.shape[1]))
-        # mesh backends keep the engine's catalog handle on the HOST —
-        # the shard-resident copies are the only device-committed ones
-        if backend == "local":
+        self._announce_mesh()
+        # a mesh engine keeps its catalog handle on the HOST — the
+        # shard-resident copies are the only device-committed ones
+        if self.mesh is None:
             V, valid = jnp.asarray(Vh), jnp.asarray(validh)
         else:
             V, valid = Vh, validh
         with self._publish_lock:
             seq = self._seq + 1
             sk = min(max(self.shortlist_k, self.k), Ni)
-            index, Vs, valids, ni_loc = None, None, None, 0
-            if backend == "merge_ring":
-                if mode != "corrupt" and Ni > 0:
-                    Vs, valids, ni_loc = self._place_sharded(Vh, validh)
-                # torn merge-ring publish: the fresh placement is
-                # dropped, Vs stays None and the score path answers
-                # exact against the fresh host catalog (counted as
-                # serving.fallback_exact) — never against a stale shard
-            elif quantize and sk >= self.k and Ni > 0:
+            index = None
+            if quantize and sk >= self.k and Ni > 0:
                 index = self._build_index(Vh, validh, sk, seq)
                 if mode == "corrupt":
                     # injected torn publish: quantization died mid-swap,
@@ -518,13 +373,11 @@ class ServingEngine:
                     # either way, no in-place seq mutation.
                     index = (self._model.index
                              if self._model is not None else None)
-            elif index is None and self._model is not None:
+            elif self._model is not None:
                 index = self._model.index      # carried, now stale
-            self._model = _Published(seq, U, n_users, V, valid, index,
-                                     Vs=Vs, valids=valids, ni_loc=ni_loc)
+            self._model = _Published(seq, U, n_users, V, valid, index)
             self._seq = seq
-        fresh = bool((index is not None and index.seq == seq)
-                     or Vs is not None)
+        fresh = index is not None and index.seq == seq
         obs.counter("serving.publishes", **self._labels)
         obs.histogram("serving.publish_seconds",
                       time.perf_counter() - t0,
@@ -582,7 +435,7 @@ class ServingEngine:
         Ni = int(Vh.shape[0])
         valid_h = (np.ones(Ni, dtype=bool) if item_valid is None
                    else np.asarray(item_valid, dtype=bool))
-        backend = self._resolve_backend(int(U.shape[1]))
+        self._announce_mesh()
         touched = (np.empty(0, dtype=np.int64) if touched_items is None
                    else np.unique(np.asarray(touched_items,
                                              dtype=np.int64).ravel()))
@@ -591,7 +444,7 @@ class ServingEngine:
             seq = self._seq + 1
             prev = self._model
             U, n_users, h2d = self._update_users(prev, U, touched_users)
-            if backend != "local":
+            if self.mesh is not None:
                 V, valid = Vh, valid_h
             elif (prev is not None and not touched.size
                     and item_valid is None
@@ -603,11 +456,7 @@ class ServingEngine:
                 h2d += Vh.nbytes + valid_h.nbytes
             cur = prev.index if prev is not None else None
             index, mode = None, "full"
-            Vs, valids, ni_loc = None, None, 0
-            if backend == "merge_ring":
-                Vs, valids, ni_loc, mode = self._update_sharded(
-                    prev, Vh, valid_h, touched, Ni)
-            elif (cur is not None and cur.seq == prev.seq
+            if (cur is not None and cur.seq == prev.seq
                     and cur.n_items <= Ni):
                 try:
                     if touched.size == 0 and Ni == cur.n_items:
@@ -633,15 +482,13 @@ class ServingEngine:
                     obs.emit("warning", what="serving.publish_update",
                              reason=f"delta rejected, full rebuild: {e}")
                     index, mode = None, "full"
-            if index is None and backend != "merge_ring":
+            if index is None:
                 sk = min(max(self.shortlist_k, self.k), Ni)
                 if sk >= self.k and Ni > 0:
-                    index = self._build_index(Vh if backend != "local"
-                                              else V, valid, sk, seq)
+                    index = self._build_index(V, valid, sk, seq)
                 else:
                     mode = "none"
-            self._model = _Published(seq, U, n_users, V, valid, index,
-                                     Vs=Vs, valids=valids, ni_loc=ni_loc)
+            self._model = _Published(seq, U, n_users, V, valid, index)
             self._seq = seq
         obs.counter("serving.publishes", **self._labels)
         obs.counter("live.publish_h2d_bytes", h2d, **self._labels)
@@ -683,13 +530,13 @@ class ServingEngine:
         compile.  Records no metrics (a warmup sample in the latency
         histograms would poison the SLO tail serve-bench reports).
 
-        On the local backend this also PINS the steady-state packed
+        Without a mesh this also PINS the steady-state packed
         executables per bucket (AOT ``lower().compile()``), so the hot
         path calls a compiled program directly instead of going through
         jit-cache dispatch; a publish that changes array shapes
         invalidates a pin (the serve path falls back to the jit call
-        and drops it) — re-run warmup to restore.  Mesh backends warm
-        their jit caches (the sharded executables are keyed on mesh
+        and drops it) — re-run warmup to restore.  A mesh engine warms
+        its jit caches (the sharded executables are keyed on mesh
         placement, which AOT calls are strict about) plus the exact
         fallback.
         """
@@ -697,17 +544,13 @@ class ServingEngine:
         if m is None:
             raise NoModelPublished("publish(U, V) before warmup")
         self._pinned.clear()
-        backend = self._backend or "local"
+        pin = self.mesh is None
         for B in self.batcher.buckets:
             proto = jnp.zeros((B, m.rank + 2), jnp.int32)
             idx = m.index
-            if backend == "merge_ring" and m.Vs is not None:
-                s, ix = self._merge_fn(B, m)(
-                    _select_packed(m.U, proto), m.Vs, m.valids)
-                _pack_response(s, ix).block_until_ready()
-            elif idx is not None and idx.seq == m.seq:
+            if idx is not None and idx.seq == m.seq:
                 self._emit_shortlist(B, idx)
-                if backend == "local" and not idx.delta_count:
+                if pin and not idx.delta_count:
                     self._pinned[(B, "int8")] = _serve_int8_packed.lower(
                         m.U, idx.Vq, idx.sv, idx.V, idx.valid, proto,
                         k=self.k,
@@ -715,10 +558,10 @@ class ServingEngine:
                 else:
                     s, ix = idx.topk(_select_packed(m.U, proto), self.k)
                     _pack_response(s, ix).block_until_ready()
-            # the exact path backs every backend's fallback: always warm
+            # the exact path backs every fallback: always warm
             Vd, validd = jnp.asarray(m.V), jnp.asarray(m.valid)
             ic = min(self.item_chunk, max(int(Vd.shape[0]), 1))
-            if backend == "local":
+            if pin:
                 self._pinned[(B, "exact")] = _serve_exact_packed.lower(
                     m.U, Vd, validd, proto, k=self.k,
                     item_chunk=ic).compile()
@@ -1048,43 +891,33 @@ class ServingEngine:
 
     def _dispatch(self, m, st, B, mode):
         """Upload the staged batch and call the scorer the live model
-        and backend select; returns ``(packed response on the device,
-        path, fell back to exact)`` as soon as the call returns."""
-        backend = self._backend or "local"
+        selects; returns ``(packed response on the device, path, fell
+        back to exact)`` as soon as the call returns."""
         index = m.index
         packed = jnp.asarray(st)
-        resp_dev, fell_back = None, False
-        if backend == "merge_ring":
-            if m.Vs is not None and mode != "corrupt":
-                path = "merge_ring"
-                s, ix = self._merge_fn(B, m)(
-                    _select_packed(m.U, packed), m.Vs, m.valids)
+        resp_dev = None
+        use_index = (index is not None and index.seq == m.seq
+                     and mode != "corrupt")
+        fell_back = index is not None and not use_index
+        if use_index:
+            if isinstance(index, ShardedInt8Index):
+                path = "int8_sharded"
+            else:
+                path = "int8"
+                if not index.delta_count:
+                    resp_dev = self._run_pinned(
+                        (B, "int8"), _serve_int8_packed,
+                        (m.U, index.Vq, index.sv, index.V,
+                         index.valid, packed),
+                        dict(k=self.k, shortlist_k=index.shortlist_k))
+            if resp_dev is None:
+                s, ix = index.topk(_select_packed(m.U, packed),
+                                   self.k)
                 resp_dev = _pack_response(s, ix)
-            else:
-                path, fell_back = "exact", True
         else:
-            use_index = (index is not None and index.seq == m.seq
-                         and mode != "corrupt")
-            fell_back = index is not None and not use_index
-            if use_index:
-                if isinstance(index, ShardedInt8Index):
-                    path = "int8_sharded"
-                else:
-                    path = "int8"
-                    if not index.delta_count:
-                        resp_dev = self._run_pinned(
-                            (B, "int8"), _serve_int8_packed,
-                            (m.U, index.Vq, index.sv, index.V,
-                             index.valid, packed),
-                            dict(k=self.k, shortlist_k=index.shortlist_k))
-                if resp_dev is None:
-                    s, ix = index.topk(_select_packed(m.U, packed),
-                                       self.k)
-                    resp_dev = _pack_response(s, ix)
-            else:
-                path = "exact"
+            path = "exact"
         if path == "exact":
-            # mesh backends keep V on the host (module docstring):
+            # a mesh engine keeps V on the host (module docstring):
             # the fallback re-uploads per batch, by design rare
             Vd, validd = jnp.asarray(m.V), jnp.asarray(m.valid)
             ic = min(self.item_chunk, max(int(Vd.shape[0]), 1))

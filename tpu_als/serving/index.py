@@ -575,9 +575,8 @@ class ShardedInt8Index(Int8CandidateIndex):
     shortlist, which is a strictly WEAKER condition: each shard
     shortlists ``min(sk, ni_loc + d_pad)`` of its own slice, so the
     mesh-wide candidate pool is a superset of the single-device one.
-    The bitwise TIE-ORDER contract lives on the f32 merge-ring kernel
-    (``ops.pallas_topk.topk_merge_ring``), not on this int8 path — same
-    caveat as the single-device int8 index.
+    No TIE-ORDER promise between equal scores — same caveat as the
+    single-device int8 index.
     """
 
     def __init__(self, V, mesh, item_valid=None, shortlist_k=64, seq=0):

@@ -7,8 +7,13 @@ for each stream of the run, the shares of ``closed_by`` over the last
 ``--seconds`` of its per-batch records — ``age``: the head had already
 waited ``max_wait_s`` when the engine thread came back; ``wait``: the
 rest of its window ran out; ``full``; ``closed`` — with the head's age
-on arrival, and the process's ``serving.batch_closed`` counters against
-the batches the engine served.  No CPU mode (``run.py`` has none):
+on arrival and the engine thread's wait for the user table's lock
+(``lock_wait``: median, mean, longest, in ms — a window that stood still
+with none of it did not stand behind a row write), the process's
+``serving.batch_closed`` counters against
+the batches the engine served, and what its ``publish_update``s did to
+the device's user table (``serving.user_table_writes``: a live cell
+counts ``inplace`` alone).  No CPU mode (``run.py`` has none):
 
     chiprun -- python3 scripts/serve_batch_closed.py --workload \\
         amazon23-r256-share32.serve-steady --seed <n> --seconds 30 --trace 0
@@ -35,6 +40,8 @@ def shares(records, seconds):
            "rows_mean": st.mean(r["rows"] for r in window),
            "head_wait_ms_median": 1e3 * st.median(
                r["head_wait"] for r in window)}
+    lock = [1e3 * r["lock_wait"] for r in window]
+    row["lock_wait_ms"] = [st.median(lock), st.mean(lock), max(lock)]
     for way in WAYS:
         row[way] = by[way]
         row[way + "_pct"] = 100.0 * by[way] / len(window)
@@ -73,6 +80,11 @@ def main(argv):
     print(json.dumps({"serving.batch_closed": counted,
                       "sum": sum(counted.values()),
                       "batches_served": engines[0]._batch_seq}), flush=True)
+    writes = {how: obs.counter_value("serving.user_table_writes", how=how)
+              for how in ("inplace", "replaced", "carried")}
+    print(json.dumps({"serving.user_table_writes": writes,
+                      "publishes": obs.counter_value("serving.publishes")}),
+          flush=True)
     return 0
 
 

@@ -2,12 +2,15 @@
 through ``LiveUpdater`` into a started ``ServingEngine``, against a plain
 float64 fold-in and exact top-k — what is folded, in which order, what a
 request sees after a publish, and that every per-batch cost is
-O(touched rows): no compile, no table upload, no table copy, no re-sort."""
+O(touched rows): no compile, no table upload, no table copy (the device's
+user table is donated to the row write), no re-sort."""
 
 from __future__ import annotations
 
 import glob
 import os
+import sys
+import threading
 import time
 
 import jax
@@ -25,10 +28,11 @@ from tpu_als.core.ratings import (
 )
 from tpu_als.obs.schema import LIVE_BATCH_SPAN_KEYS
 from tpu_als.serving import ServingEngine
+from tpu_als.serving.engine import _scatter_users
 
 N_USERS, N_ITEMS, RANK, K = 2000, 3000, 32, 10
 REG = 0.1
-N_EVENTS, NEW_SHARE, HEAVY = 300, 0.1, 7     # HEAVY: one client's id
+N_EVENTS, NEW_SHARE, HEAVY = 480, 0.1, 7     # HEAVY: one client's id
 
 
 def reference_fold(V, items, ratings, reg=REG):
@@ -103,7 +107,7 @@ def make_stack(seed=0, max_batch=8, max_wait_ms=2.0):
 
 @pytest.fixture(scope="module")
 def drained():
-    """300 events through a running updater beside a running engine, one
+    """480 events through a running updater beside a running engine, one
     request by id after every tenth event; then ``stop()`` drains."""
     reg = obs.reset()
     rng, U, V, model, eng, srv, upd = make_stack()
@@ -197,9 +201,19 @@ def test_appended_users_are_servable_by_id(drained):
 
 def test_nothing_compiles_after_the_first_publish(drained):
     """>= 50 publishes, users appended in many of them, requests between
-    them: every program was compiled and run before the stream."""
-    publishes = drained["reg"].counter_value("serving.publishes") - 1
+    them: every program was compiled and run before the stream.  (At
+    most 8 events a batch, so 480 events make 60 publishes or more
+    however full the batches run: on the CPU a row write waits for the
+    scoring program that reads the donated table, the next fold behind
+    it, and the batches fill up.)"""
+    reg = drained["reg"]
+    publishes = reg.counter_value("serving.publishes") - 1
     assert publishes >= 50
+    # every one of them wrote its rows into the live table
+    assert reg.counter_value("serving.user_table_writes",
+                             how="inplace") == publishes
+    assert reg.counter_value("serving.user_table_writes",
+                             how="replaced") == 0
     assert drained["compiles_after_first"] == 0
     # the pinned scoring executables outlive every publish: no shape moved
     assert drained["eng"]._pinned.keys() == drained["pins_before"].keys()
@@ -232,9 +246,39 @@ def test_a_request_dequeued_after_a_publish_sees_it():
     assert not np.allclose(before[0], s)
 
 
-def test_a_batch_in_flight_keeps_the_generation_it_was_dequeued_with():
+def _values(table):
+    """A device table's values WITHOUT a view of its buffer: on the CPU
+    ``np.asarray(table)`` shares the buffer and stays cached on the
+    array, and a buffer with such a reference cannot be donated (the row
+    write would quietly copy)."""
+    return np.asarray(table + 0)
+
+
+@pytest.mark.parametrize("pad", LIVE_PADS)
+def test_the_row_write_is_in_place(pad):
+    """The table is donated: aliased to the result in the compiled
+    program, which holds no copy of it; the result IS the operand's
+    buffer, and the operand's handle is deleted."""
+    U = jnp.ones((N_USERS, RANK), jnp.float32)
+    rows = jnp.full(pad, N_USERS, jnp.int32).at[0].set(3)
+    vals = jnp.full((pad, RANK), 2.0, jnp.float32)
+    text = _scatter_users.lower(U, rows, vals).compile().as_text()
+    assert "input_output_alias" in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f"f32[{N_USERS},{RANK}]" in ln]
+    where = U.unsafe_buffer_pointer()
+    out = _scatter_users(U, rows, vals)
+    assert out.unsafe_buffer_pointer() == where and U.is_deleted()
+    want = np.ones((N_USERS, RANK), np.float32)
+    want[3] = 2.0
+    np.testing.assert_array_equal(np.asarray(out), want)
+
+
+def test_a_row_write_publish_changes_the_named_rows_and_nothing_else():
+    reg = obs.reset()
     rng, U, V, model, eng, srv, upd = make_stack(seed=2)
     old = eng._model
+    before = _values(old.U)
     rows = np.array([5, N_USERS], dtype=np.int64)      # one touched, one new
     U2 = np.concatenate([U, np.ones((1, RANK), np.float32)])
     U2[5] = 2.0
@@ -242,19 +286,219 @@ def test_a_batch_in_flight_keeps_the_generation_it_was_dequeued_with():
     new = eng._model
     assert (seq, mode) == (old.seq + 1, "retag")
     assert new.U.shape == old.U.shape and new.n_users == N_USERS + 1
-    np.testing.assert_array_equal(np.asarray(old.U[5]), U[5])
-    np.testing.assert_array_equal(np.asarray(old.U[N_USERS]), 0.0)
-    np.testing.assert_array_equal(np.asarray(new.U[rows]), U2[rows])
+    after = np.asarray(new.U)
+    np.testing.assert_array_equal(after[rows], U2[rows])
+    others = np.setdiff1d(np.arange(len(before)), rows)
+    np.testing.assert_array_equal(after[others], before[others])
+    np.testing.assert_array_equal(before[N_USERS], 0.0)
+    # the old generation's table went into the new one: its handle is
+    # deleted, its shape still reads
+    assert old.U.is_deleted() and old.U.shape == new.U.shape
+    assert reg.counter_value("serving.user_table_writes",
+                             how="inplace") == 1
+    pub = [e for e in reg._events if e["type"] == "serving_publish"][-1]
+    assert pub["users"] == "inplace" and pub["seq"] == seq
     # nothing of the catalog was sent again, nor quantized
     assert new.V is old.V and new.valid is old.valid
     assert new.index.Vq is old.index.Vq
+
+
+def _dispatch_by_id(eng, user):
+    """One bucket-8 batch asking for ``user``, dispatched as the engine
+    thread would and NOT read back: the packed response on the device."""
+    st = np.zeros((8, RANK + 2), np.int32)
+    st[0, RANK] = user
+    with eng._table_lock:
+        return eng._dispatch(eng._model, st, 8, None)[0]
+
+
+def test_a_batch_dispatched_before_a_publish_answers_from_the_old_rows():
+    rng, U, V, model, eng, srv, upd = make_stack(seed=2)
+    in_flight = _dispatch_by_id(eng, 5)
+    U2 = U.copy()
+    U2[5] = 2.0
+    eng.publish_update(U2, V, touched_users=np.array([5]))
+    behind = _dispatch_by_id(eng, 5)
+    for resp, row in ((in_flight, U[5]), (behind, U2[5])):
+        resp = np.asarray(resp)
+        want_s, want_i = exact_topk(row, V)
+        np.testing.assert_allclose(resp[0, :K].view(np.float32), want_s,
+                                   rtol=1e-3, atol=1e-4)
+        assert resp[0, K:].tolist() == want_i.tolist()
+
+
+def test_a_publish_with_no_row_to_write_carries_the_table():
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed=2)
+    old = eng._model
+    seq, mode = eng.publish_update(U, V, touched_users=np.array([], int))
+    assert (seq, mode) == (old.seq + 1, "retag")
+    assert eng._model.U is old.U and not old.U.is_deleted()
+    assert reg.counter_value("serving.user_table_writes",
+                             how="carried") == 1
+    assert reg.counter_value("live.publish_h2d_bytes") == 0
+
+
+def test_warmup_publish_leaves_the_table_and_the_engine_servable():
+    rng, U, V, model, eng, srv, upd = make_stack(seed=2)
+    old = eng._model
+    before = _values(old.U)
+    eng.warmup_publish(LIVE_PADS[-1])
+    m = eng._model
+    # the same generation over the same values, in the same buffer
+    assert (m.seq, m.n_users, m.index) == (old.seq, old.n_users, old.index)
+    np.testing.assert_array_equal(np.asarray(m.U), before)
+    assert old.U.is_deleted()
+    with eng:
+        s, ix = eng.recommend(5, timeout=10.0)
+    want_s, want_i = exact_topk(U[5], V)
+    np.testing.assert_allclose(s, want_s, rtol=1e-3, atol=1e-4)
+    assert ix.tolist() == want_i.tolist()
+
+
+def test_serving_by_id_under_200_row_writes_never_meets_a_deleted_table():
+    """One thread asks by id without pause for rows that every publish
+    rewrites to ``g * w``, another publishes g = 1..200: no request
+    fails, every answer's scores are ONE generation's (k scores, one g),
+    no older than the generation that was live when the request was
+    submitted, and never older than the answer before."""
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed=8)
+    rows = np.arange(4)
+    w = rng.normal(size=RANK).astype(np.float32)
+    base = eng.published_seq
+    answers, errors, done = [], [], threading.Event()
+
+    def ask():
+        j = 0
+        while not done.is_set():
+            live = eng.published_seq - base
+            try:
+                s, ix = eng.recommend(int(rows[j % 4]), timeout=10.0)
+            except Exception as e:      # noqa: BLE001 — the test's subject
+                errors.append(e)
+                return
+            if live:    # generation 0 holds the seed's rows, not g * w
+                answers.append((live, s, ix))
+            j += 1
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)     # threads change hands mid-publish
+    try:
+        with eng:
+            reader = threading.Thread(target=ask)
+            reader.start()
+            for g in range(1, 201):
+                U[rows] = g * w
+                eng.publish_update(U, V, touched_users=rows)
+                time.sleep(0.002)
+            done.set()
+            reader.join(30.0)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not reader.is_alive()
+    assert not [e for e in reg._events if e["type"] == "warning"]
+    assert reg.counter_value("serving.user_table_writes",
+                             how="inplace") == 200
+    assert len(answers) >= 10
+    last = 0
+    for live, s, ix in answers:
+        g = s / (np.asarray(V, np.float64)[ix] @ w.astype(np.float64))
+        assert np.abs(g - np.round(g[0])).max() < 1e-3 * g[0], g
+        assert live <= round(g[0]) <= 200 and last <= round(g[0])
+        last = round(g[0])
+    assert len({round(float(s[0] / (V[ix[0]].astype(np.float64) @ w)))
+                for _, s, ix in answers}) >= 2
+
+
+def test_a_row_write_that_fails_after_the_donation_replaces_the_table(
+        monkeypatch):
+    """The donated table is gone and the write raised: the publish still
+    lands, on a table placed anew from the host's under the same lock,
+    says so, and counts ``replaced``; without a host table to place
+    from (``warmup_publish``) the warning names the state and the error
+    is raised."""
+    import tpu_als.serving.engine as engine_module
+
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed=9)
+    old = eng._model
+
+    def donate_then_raise(table, rows, vals):
+        table.delete()
+        raise RuntimeError("injected after the donation")
+
+    monkeypatch.setattr(engine_module, "_scatter_users", donate_then_raise)
+    U2 = U.copy()
+    U2[5] = 2.0
+    seq, mode = eng.publish_update(U2, V, touched_users=np.array([5]))
+    m = eng._model
+    assert (seq, mode) == (old.seq + 1, "retag") and old.U.is_deleted()
+    assert m.U.shape == old.U.shape and not m.U.is_deleted()
+    np.testing.assert_array_equal(np.asarray(m.U[:N_USERS]), U2)
+    warn = [e for e in reg._events if e["type"] == "warning"]
+    assert len(warn) == 1 and "after donating" in warn[0]["reason"] \
+        and "re-placed whole" in warn[0]["reason"]
+    assert reg.counter_value("serving.user_table_writes",
+                             how="replaced") == 1
+    assert reg.counter_value("serving.user_table_writes",
+                             how="inplace") == 0
+    pub = [e for e in reg._events if e["type"] == "serving_publish"][-1]
+    assert pub["users"] == "replaced"
+    with eng:
+        s, ix = eng.recommend(5, timeout=10.0)
+    want_s, want_i = exact_topk(U2[5], V)
+    np.testing.assert_allclose(s, want_s, rtol=1e-3, atol=1e-4)
+    assert ix.tolist() == want_i.tolist()
+    with pytest.raises(RuntimeError, match="injected"):
+        eng.warmup_publish(8)
+    assert "NO user table" in [e for e in reg._events
+                               if e["type"] == "warning"][-1]["reason"]
+
+
+def test_a_row_write_that_fails_before_the_donation_changes_nothing(
+        monkeypatch):
+    import tpu_als.serving.engine as engine_module
+
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed=9)
+    old = eng._model
+
+    def refuse(table, rows, vals):
+        raise RuntimeError("injected before the donation")
+
+    monkeypatch.setattr(engine_module, "_scatter_users", refuse)
+    with pytest.raises(RuntimeError, match="injected"):
+        eng.publish_update(U * 2, V, touched_users=np.array([5]))
+    assert eng._model is old and not old.U.is_deleted()
+    assert eng.published_seq == old.seq
+    assert not [e for e in reg._events if e["type"] == "warning"]
+
+
+def test_the_wait_for_the_table_lock_is_in_the_batchs_record():
+    """A batch that finds the lock taken waits in ``serve.batch.stage``,
+    and its record says how long (``lock_wait``); a batch that finds it
+    free waits microseconds."""
+    rng, U, V, model, eng, srv, upd = make_stack(seed=9)
+    with eng:
+        eng.recommend(5, timeout=10.0)
+        free = eng.batch_flight.records()[-1]
+        with eng._table_lock:
+            t = eng.submit(5)
+            time.sleep(0.2)
+        t.result(10.0)
+        held = eng.batch_flight.records()[-1]
+    assert 0.0 <= free["lock_wait"] < 0.01
+    assert 0.05 < held["lock_wait"] <= held["spans"]["serve.batch.stage"]
+    assert held["batch"] == free["batch"] + 1
 
 
 @pytest.mark.parametrize("case", ["row_outside", "past_capacity", "shrunk"])
 def test_user_rows_the_engine_cannot_write_replace_the_table(case):
     reg = obs.reset()
     rng, U, V, model, eng, srv, upd = make_stack(seed=3)
-    cap = int(eng._model.U.shape[0])
+    old = eng._model
+    cap = int(old.U.shape[0])
     if case == "row_outside":
         U2, rows = U, np.array([N_USERS + 4])
     elif case == "past_capacity":
@@ -270,15 +514,22 @@ def test_user_rows_the_engine_cannot_write_replace_the_table(case):
     assert m.n_users == len(U2) and m.U.shape[0] >= len(U2)
     np.testing.assert_array_equal(np.asarray(m.U[:len(U2)]), U2)
     assert reg.counter_value("live.publish_h2d_bytes") >= U2.nbytes
+    # a new table beside the old one, which is whole for whoever holds it
+    assert reg.counter_value("serving.user_table_writes",
+                             how="replaced") == 1
+    np.testing.assert_array_equal(np.asarray(old.U[:N_USERS]), U)
 
 
 def test_publish_without_row_list_keeps_shape_and_counts_the_table():
     reg = obs.reset()
     rng, U, V, model, eng, srv, upd = make_stack(seed=4)
-    cap = eng._model.U.shape
+    old = eng._model
     eng.publish_update(U * 2, V)
-    assert eng._model.U.shape == cap
+    assert eng._model.U.shape == old.U.shape
     assert reg.counter_value("live.publish_h2d_bytes") == U.nbytes
+    assert reg.counter_value("serving.user_table_writes",
+                             how="replaced") == 1
+    assert not old.U.is_deleted()
 
 
 def test_appends_land_in_spare_rows_of_one_buffer():
@@ -336,6 +587,24 @@ def test_pads_and_capacities():
     for n in (1, 24, 2000, 10 ** 6):
         cap = row_capacity(n)
         assert cap % 512 == 0 and cap - n >= max(1024, n >> 6)
+
+
+@pytest.mark.parametrize("n", [0, 5, 64, 100, 128, 200])
+def test_a_table_placed_in_chunks_is_the_padded_table(n, monkeypatch):
+    """Whole chunks, a last chunk that overlaps the one before, a table
+    smaller than a chunk, an empty one: the table padded with zero rows,
+    from one program, the buffer donated to every write."""
+    monkeypatch.setattr(foldin, "PLACE_CHUNK", 64)
+    F = np.random.default_rng(n).normal(size=(n, RANK)).astype(np.float32)
+    before = foldin._write_rows._cache_size()
+    got = foldin.place_rows(F, capacity=256)
+    want = jnp.pad(jnp.asarray(F), ((0, 256 - n), (0, 0)))
+    assert got.shape == (256, RANK) and got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert foldin._write_rows._cache_size() - before <= 1
+    chunk = jnp.zeros((min(64, n), RANK), jnp.float32)
+    text = foldin._write_rows.lower(got, chunk, 0).compile().as_text()
+    assert "input_output_alias" in text
 
 
 def test_prewarm_runs_the_ladder_and_names_each_solve():

@@ -23,7 +23,10 @@ single measurable quantity:
 5. **Publish.**  ``ServingEngine.publish_update`` swaps the new
    generation in atomically — retag for user-only batches, an
    O(touched) delta re-quantization for item batches — never a full
-   O(catalog) rebuild while the live index is healthy.
+   O(catalog) rebuild while the live index is healthy.  The touched
+   user rows are written into the device's table in place (the table
+   is donated to the write: no copy of it, on the host or on the
+   device); the generation before loses its user table to the new one.
 
 The thread's cycle is on the profiler's timeline as ``TraceAnnotation``
 spans, always on, the write path's counterpart of the engine thread's

@@ -109,6 +109,14 @@ METRICS = {
     "serving.publishes": (
         "counter", "publishes",
         "model generations atomically swapped into the serving engine"),
+    "serving.user_table_writes": (
+        "counter", "publishes",
+        "one per ServingEngine.publish_update, labeled by what it did to "
+        "the device's user table: how=inplace (the touched and appended "
+        "rows written into the live table, which was donated to the "
+        "write: O(touched) on the device) | replaced (a new table "
+        "uploaded whole: no row list, or rows the live table cannot "
+        "take) | carried (no row to write: the live table as it is)"),
     "scenario.freshness_seconds": (
         "histogram", "seconds",
         "cold-start scenario: rating-arrival -> servable latency (fold-"
@@ -219,6 +227,7 @@ LABELS = {
     "serving.expired": ("tenant",),
     "serving.fallback_exact": ("tenant",),
     "serving.publishes": ("tenant",),
+    "serving.user_table_writes": ("how", "tenant"),
     "serving.publish_seconds": ("mode", "tenant"),
     "live.freshness_seconds": ("tenant",),
     "live.batch_rows": ("tenant",),
@@ -281,7 +290,8 @@ SERVE_BATCH_SPAN_KEYS = (
     "serve.batch.coalesce",   # first request seen -> batch popped
     #                           (waiting, closed_by, head_wait)
     "serve.batch",            # all of serve_batch (seq, bucket, rows, path)
-    "serve.batch.stage",      # expiry check + staging into the upload array
+    "serve.batch.stage",      # the wait for the user table's lock, then
+    #                           expiry check + staging into the upload array
     "serve.batch.dispatch",   # upload + the scoring call, until it returns
     "serve.batch.readback",   # the one bulk device->host transfer
     "serve.batch.complete",   # completing the tickets + their bookkeeping
@@ -351,7 +361,9 @@ EVENTS = {
     "serving_publish": (
         ("seq", "items", "quantized"),
         "one per ServingEngine.publish: the generation sequence number, "
-        "catalog size, and whether an int8 index was built for it"),
+        "catalog size, and whether an int8 index was built for it; a "
+        "publish_update adds users = inplace|replaced|carried, what it "
+        "did to the device's user table (serving.user_table_writes' how)"),
     "serving_backend": (
         ("backend", "n_shards"),
         "one per ServingEngine that was given a mesh, at first publish: "
@@ -432,7 +444,8 @@ EVENTS = {
         "SERVE_BATCH_SPAN_KEYS, with batch, t0, bucket, rows, waiting, "
         "closed_by = age|wait|full|closed as serving.batch_closed's "
         "by, head_wait = seconds the batch's oldest request had waited "
-        "as the consumer arrived) "
+        "as the consumer arrived, lock_wait = seconds the engine thread "
+        "waited for the user table's lock, inside serve.batch.stage) "
         "(obs.trace.FlightRecorder)"),
     "attribution": (
         ("stages", "wall_s_per_iter", "coverage"),

@@ -23,9 +23,18 @@ tickets.  The pieces the rest of the stack plugs into:
 - **Incremental publishes.**  :meth:`ServingEngine.publish_update` is
   the live fold-in → publish path: the user table lives on the device
   with spare rows (``core.ratings.row_capacity``), so touched and
-  appended user rows are uploaded alone and written into a copy of it
-  — no shape changes, the pinned executables stay valid, and nothing of
-  the catalog crosses host→device; a user-only fold-in re-tags the
+  appended user rows are uploaded alone and written into it IN PLACE:
+  the table is donated to the row write (:func:`_scatter_users`), so a
+  publish costs the device O(touched rows), never a copy of the table,
+  and a generation's user table lives until the next row write, not for
+  ever.  Programs run in dispatch order, so a batch dispatched before
+  the write reads the old rows whole and a batch dispatched after it
+  the new ones; what the donation deletes is only the HOST's handle, so
+  one short lock (``_table_lock``) orders the two host-side uses of it:
+  the engine thread holds it from reading the live generation to the
+  scoring call's return, a publisher around the donating call and the
+  swap.  No shape changes, the pinned executables stay valid, and
+  nothing of the catalog crosses host→device; a user-only fold-in re-tags the
   current index (zero quantization), an item fold-in re-quantizes ONLY
   the touched/appended rows into the index's delta segment
   (``serving/index.py``), and the segment is folded back into the base
@@ -95,7 +104,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from tpu_als import obs
-from tpu_als.core.foldin import pad_rows
+from tpu_als.core.foldin import place_rows
 from tpu_als.core.ratings import (
     LIVE_PADS,
     pad_for,
@@ -123,11 +132,19 @@ class NoModelPublished(RuntimeError):
 
 
 class _Published:
-    """One immutable model generation; the engine swaps whole instances.
+    """One model generation; the engine swaps whole instances and never
+    assigns to one.
 
     ``U`` holds ``n_users`` live rows and spare zero rows after them,
     which no request addresses (``submit`` checks ids against
-    ``n_users``).  ``V``/``valid`` are device arrays on a mesh-less
+    ``n_users``).  It is the one array that does not outlive its
+    generation: the next row-write publish donates it to
+    :func:`_scatter_users`, after which ``U`` of this instance is a
+    deleted array (its ``shape`` still reads; its values raise).  So
+    ``U`` goes to the device only under ``ServingEngine._table_lock``,
+    read from the LIVE generation.  ``seq``, ``n_users``, ``rank``,
+    ``V``/``valid`` and ``index`` stay readable for as long as the
+    instance is held.  ``V``/``valid`` are device arrays on a mesh-less
     engine and HOST numpy on a mesh engine (see the module docstring).
     """
 
@@ -191,10 +208,14 @@ def _serve_int8_packed(U, Vq, sv, V, valid, packed, *, k, shortlist_k):
     return _pack_response(s, ix)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_users(U, rows, vals):
-    """The user table with ``vals`` written at ``rows``, as a new array:
-    the generation a batch in flight was dequeued with stays whole.
+    """``vals`` written at ``rows`` of the user table, IN PLACE: ``U``
+    is donated, the result is the same buffer and the caller's ``U`` is
+    deleted.  The device runs programs in dispatch order, so a batch
+    dispatched before this call reads the old rows whole, one dispatched
+    after it the new rows whole; the caller keeps the deleted handle
+    away from the engine thread (``ServingEngine._table_lock``).
     ``rows`` are padded up ``pad_for``'s ladder with an out-of-range
     sentinel (``mode='drop'``), so the programs are few and only the
     touched payload crosses host→device."""
@@ -258,6 +279,12 @@ class ServingEngine:
             default_deadline_s=default_deadline_s, labels=self._labels)
         self._model = None              # _Published; swapped atomically
         self._publish_lock = threading.Lock()
+        # orders the host's two uses of the live user table: scoring
+        # against it (held from reading self._model to the scoring
+        # call's return) and donating it to a row write (held around
+        # the donating call and the swap).  Taken after _publish_lock,
+        # never before it
+        self._table_lock = threading.Lock()
         self._cadence = None            # plan-resolved, on first use
         self._seq = 0
         self._thread = None
@@ -285,29 +312,33 @@ class ServingEngine:
     @staticmethod
     def _place_users(prev, U):
         """``(U on the device with spare rows, live rows, bytes sent)``:
-        the whole table uploaded and padded there.  The capacity is the
-        live generation's while the table fits it (same shapes, same
-        programs), ``row_capacity`` of the table otherwise."""
+        the whole table uploaded into a new one, a chunk at a time
+        (``core.foldin.place_rows``: never twice on the device).  The
+        capacity is the live generation's while the table fits it (same
+        shapes, same programs), ``row_capacity`` of the table
+        otherwise."""
         n, rank = int(U.shape[0]), int(U.shape[1])
         cap = row_capacity(n)
         if prev is not None and prev.rank == rank \
                 and n <= int(prev.U.shape[0]):
             cap = int(prev.U.shape[0])
-        # wait for it: the upload's buffer is freed as the padded copy is
-        # done, and what a publish allocates next (the catalog, its index)
-        # would otherwise be allocated beside it (+1.7 GB of peak)
-        return (pad_rows(jnp.asarray(U, dtype=jnp.float32),
-                         capacity=cap).block_until_ready(),
+        # wait for it: what a publish allocates next (the catalog, its
+        # index) is then allocated after the last chunk's buffer is freed
+        return (place_rows(U, capacity=cap).block_until_ready(),
                 n, 4 * n * rank)
 
     def _update_users(self, prev, U, touched_users):
-        """The next generation's user table from the live one: the
+        """What a ``publish_update`` does to the user table: ``(how,
+        users, live rows, bytes sent)``.  ``inplace``: the
         ``touched_users`` rows of ``U`` (and the rows appended since)
-        uploaded alone and written into a copy of it on the device —
-        O(touched) host work and traffic, no shape change.  Falls back to
-        :meth:`_place_users` where that cannot be (no row list, no live
-        generation, another rank, a shrunken table, spare rows used up,
-        a row outside the table)."""
+        are uploaded alone — O(touched) host work and traffic, no shape
+        change — and ``users`` is the ``(rows, vals)`` that
+        :func:`_scatter_users` writes into the live table, which the
+        caller does under ``_table_lock``.  ``carried``: no row to
+        write, ``users`` is the live table.  ``replaced``: ``users`` is a
+        new table from :meth:`_place_users`, where a row write cannot be
+        (no row list, no live generation, another rank, a shrunken
+        table, spare rows used up, a row outside the table)."""
         n, rank = int(U.shape[0]), int(U.shape[1])
         if (touched_users is not None and prev is not None
                 and prev.rank == rank
@@ -316,7 +347,7 @@ class ServingEngine:
                 np.asarray(touched_users, dtype=np.int64).ravel(),
                 np.arange(prev.n_users, n))
             if not rows.size:
-                return prev.U, n, 0
+                return "carried", prev.U, n, 0
             if 0 <= int(rows[0]) and int(rows[-1]) < n:
                 pad = pad_for(len(rows))
                 # the sentinel lies outside the table: dropped
@@ -324,15 +355,53 @@ class ServingEngine:
                 rp[:len(rows)] = rows
                 vals = np.zeros((pad, rank), dtype=np.float32)
                 vals[:len(rows)] = U[rows]
-                return (_scatter_users(prev.U, jnp.asarray(rp),
-                                       jnp.asarray(vals)),
+                return ("inplace", (jnp.asarray(rp), jnp.asarray(vals)),
                         n, rp.nbytes + vals.nbytes)
         if touched_users is not None and prev is not None:
             obs.emit("warning", what="serving.publish_update",
                      reason=f"user rows rejected ({n} users against "
                             f"{prev.n_users} live of {prev.U.shape[0]}), "
                             "user table re-placed whole")
-        return self._place_users(prev, U)
+        return ("replaced",) + self._place_users(prev, U)
+
+    def _swap(self, how, users, seq, n_users, V, valid, index, host=None):
+        """Install the next generation, the one place that assigns
+        ``_model``; returns ``how`` it got its user table.  ``users`` is
+        that table, or with ``how == "inplace"`` the ``(rows, vals)`` to
+        write into the live one first.  The donating call and the swap
+        are one step under ``_table_lock``: the engine thread reads the
+        live generation and scores against its table under the same
+        lock, so it never holds a deleted one.  The call is asynchronous
+        and its uploads were made before: the lock is held for the
+        dispatch alone.
+
+        A row write that raises AFTER the donation took effect leaves no
+        table at all.  With ``host``, the whole table the rows came
+        from, the next generation is then placed anew from it, still
+        under the lock (``"replaced"``, with a warning); without it the
+        warning names the state — every batch fails until a ``publish``
+        — and the error is raised."""
+        with self._table_lock:
+            if how == "inplace":
+                table = self._model.U
+                try:
+                    users = _scatter_users(table, *users)
+                except Exception as e:
+                    if not self._model.U.is_deleted():
+                        raise           # nothing was donated: all whole
+                    fate = ("re-placed whole" if host is not None else
+                            "NO user table until the next publish")
+                    obs.emit("warning", what="serving.publish_update",
+                             reason="row write failed after donating the "
+                                    f"user table ({type(e).__name__}: {e}): "
+                                    f"{fate}")
+                    if host is None:
+                        raise
+                    # a deleted table still reads its shape: same capacity
+                    how, users = "replaced", self._place_users(
+                        self._model, host)[0]
+            self._model = _Published(seq, users, n_users, V, valid, index)
+        return how
 
     def publish(self, U, V, item_valid=None, quantize=True):
         """Swap in a new model generation atomically.
@@ -369,13 +438,13 @@ class ServingEngine:
                     # so the fresh index is never published.  The
                     # previous generation's index is carried (stale by
                     # seq, detected on the score path) or the publish
-                    # goes out index-less — _Published stays immutable
-                    # either way, no in-place seq mutation.
+                    # goes out index-less — no in-place seq mutation
+                    # either way.
                     index = (self._model.index
                              if self._model is not None else None)
             elif self._model is not None:
                 index = self._model.index      # carried, now stale
-            self._model = _Published(seq, U, n_users, V, valid, index)
+            self._swap("replaced", U, seq, n_users, V, valid, index)
             self._seq = seq
         fresh = index is not None and index.seq == seq
         obs.counter("serving.publishes", **self._labels)
@@ -397,8 +466,13 @@ class ServingEngine:
         treated as appended.  The caller guarantees every OTHER row of
         ``U`` is unchanged: only the named rows are read from ``U``
         (which may be a view of a larger host buffer) and uploaded
-        (``live.publish_h2d_bytes`` counts what every publish sends).
-        Without it the whole of ``U`` is uploaded.
+        (``live.publish_h2d_bytes`` counts what every publish sends)
+        and written into the device's table in place: the live
+        generation's table is donated to the write, so whoever kept an
+        earlier generation's ``U`` finds it deleted.  Without the list
+        the whole of ``U`` is uploaded into a new table.
+        ``serving.user_table_writes{how=inplace|replaced|carried}`` and
+        ``users=`` on the ``serving_publish`` event say which it was.
 
         ``trace``: the causal-trace contexts (``obs.tracing``) of the
         rating events this publish makes visible; their trace ids are
@@ -443,7 +517,8 @@ class ServingEngine:
         with self._publish_lock:
             seq = self._seq + 1
             prev = self._model
-            U, n_users, h2d = self._update_users(prev, U, touched_users)
+            how, users, n_users, h2d = self._update_users(
+                prev, U, touched_users)
             if self.mesh is not None:
                 V, valid = Vh, valid_h
             elif (prev is not None and not touched.size
@@ -488,9 +563,13 @@ class ServingEngine:
                     index = self._build_index(V, valid, sk, seq)
                 else:
                     mode = "none"
-            self._model = _Published(seq, U, n_users, V, valid, index)
+            # last: every step above may raise or take long (an index
+            # build), and from the row write on the old table is gone
+            how = self._swap(how, users, seq, n_users, V, valid, index,
+                             host=U)
             self._seq = seq
         obs.counter("serving.publishes", **self._labels)
+        obs.counter("serving.user_table_writes", how=how, **self._labels)
         obs.counter("live.publish_h2d_bytes", h2d, **self._labels)
         obs.histogram("serving.publish_seconds",
                       time.perf_counter() - t0, mode=mode,
@@ -502,7 +581,7 @@ class ServingEngine:
                  quantized=bool(index is not None), mode=mode,
                  delta_rows=(index.delta_count
                              if index is not None else 0),
-                 **linked, **self._labels)
+                 users=how, **linked, **self._labels)
         return seq, mode
 
     def _live_cadence(self):
@@ -539,50 +618,64 @@ class ServingEngine:
         its jit caches (the sharded executables are keyed on mesh
         placement, which AOT calls are strict about) plus the exact
         fallback.
+
+        Holds ``_table_lock`` throughout, as whoever hands the live user
+        table to the device must: no row write donates the table
+        meanwhile, and the engine thread dispatches nothing — warm up
+        before the traffic.  (The lock is taken HERE and not by a
+        wrapper around the method: one more Python frame between the
+        caller and ``lower()`` cost the six lowerings 0.33 s on the
+        chip's host; PERF.md section 6, PR 31.)
         """
-        m = self._model
-        if m is None:
-            raise NoModelPublished("publish(U, V) before warmup")
-        self._pinned.clear()
-        pin = self.mesh is None
-        for B in self.batcher.buckets:
-            proto = jnp.zeros((B, m.rank + 2), jnp.int32)
-            idx = m.index
-            if idx is not None and idx.seq == m.seq:
-                self._emit_shortlist(B, idx)
-                if pin and not idx.delta_count:
-                    self._pinned[(B, "int8")] = _serve_int8_packed.lower(
-                        m.U, idx.Vq, idx.sv, idx.V, idx.valid, proto,
-                        k=self.k,
-                        shortlist_k=idx.shortlist_k).compile()
+        with self._table_lock:
+            m = self._model
+            if m is None:
+                raise NoModelPublished("publish(U, V) before warmup")
+            self._pinned.clear()
+            pin = self.mesh is None
+            for B in self.batcher.buckets:
+                proto = jnp.zeros((B, m.rank + 2), jnp.int32)
+                idx = m.index
+                if idx is not None and idx.seq == m.seq:
+                    self._emit_shortlist(B, idx)
+                    if pin and not idx.delta_count:
+                        self._pinned[(B, "int8")] = _serve_int8_packed.lower(
+                            m.U, idx.Vq, idx.sv, idx.V, idx.valid, proto,
+                            k=self.k,
+                            shortlist_k=idx.shortlist_k).compile()
+                    else:
+                        s, ix = idx.topk(_select_packed(m.U, proto), self.k)
+                        _pack_response(s, ix).block_until_ready()
+                # the exact path backs every fallback: always warm
+                Vd, validd = jnp.asarray(m.V), jnp.asarray(m.valid)
+                ic = min(self.item_chunk, max(int(Vd.shape[0]), 1))
+                if pin:
+                    self._pinned[(B, "exact")] = _serve_exact_packed.lower(
+                        m.U, Vd, validd, proto, k=self.k,
+                        item_chunk=ic).compile()
                 else:
-                    s, ix = idx.topk(_select_packed(m.U, proto), self.k)
-                    _pack_response(s, ix).block_until_ready()
-            # the exact path backs every fallback: always warm
-            Vd, validd = jnp.asarray(m.V), jnp.asarray(m.valid)
-            ic = min(self.item_chunk, max(int(Vd.shape[0]), 1))
-            if pin:
-                self._pinned[(B, "exact")] = _serve_exact_packed.lower(
-                    m.U, Vd, validd, proto, k=self.k,
-                    item_chunk=ic).compile()
-            else:
-                _serve_exact_packed(m.U, Vd, validd, proto, k=self.k,
-                                    item_chunk=ic).block_until_ready()
+                    _serve_exact_packed(m.U, Vd, validd, proto, k=self.k,
+                                        item_chunk=ic).block_until_ready()
 
     def warmup_publish(self, max_rows=LIVE_PADS[-1]):
         """Compile AND run the user-row writes ``publish_update(
         touched_users=...)`` makes, for up to ``max_rows`` rows a publish
-        (padded 8 / 64 / 512 ...), against the published table: each run
-        writes nothing (every row the out-of-range sentinel) and holds a
-        second copy of the user table while it runs, as every such
-        publish will.  ``LiveUpdater.start`` calls it."""
-        m = self._model
-        if m is None:
-            raise NoModelPublished("publish(U, V) before warmup")
-        for pad in pads_up_to(max_rows):
-            _scatter_users(
-                m.U, jnp.full(pad, m.U.shape[0], jnp.int32),
-                jnp.zeros((pad, m.rank), jnp.float32)).block_until_ready()
+        (padded 8 / 64 / 512 ...), on the published table itself: each
+        run writes nothing (every row the out-of-range sentinel), and its
+        result, the same buffer with the same values, is installed as
+        the live generation's table, since the write deleted the handle
+        it was given.  ``LiveUpdater.start`` calls it."""
+        with self._publish_lock:
+            m = self._model
+            if m is None:
+                raise NoModelPublished("publish(U, V) before warmup")
+            for pad in pads_up_to(max_rows):
+                self._swap(
+                    "inplace",
+                    (jnp.full(pad, m.U.shape[0], jnp.int32),
+                     jnp.zeros((pad, m.rank), jnp.float32)),
+                    m.seq, m.n_users, m.V, m.valid, m.index)
+            self._model.U.block_until_ready()
 
     def warmup_live(self, max_delta_rows=None):
         """Compile the DELTA-path scoring executables incremental
@@ -594,32 +687,34 @@ class ServingEngine:
         ``max_delta_rows`` (default: the planner cadence's compaction
         threshold plus one max_batch — the largest segment a publish
         can carry before ``publish_update`` folds it back into the
-        base).  Cheap no-op when the model serves exact.
+        base).  Cheap no-op when the model serves exact.  Holds
+        ``_table_lock`` throughout, like :meth:`warmup`.
         """
-        m = self._model
-        if m is None:
-            raise NoModelPublished("publish(U, V) before warmup")
-        idx = m.index
-        if idx is None or idx.seq != m.seq:
-            return
-        if max_delta_rows is None:
-            cad = self._live_cadence()
-            max_delta_rows = int(
-                max(cad["compact_min_rows"],
-                    cad["compact_delta_frac"] * idx.n_base)
-                + cad["max_batch"])
-        Vh = np.asarray(m.V, dtype=np.float32)
-        d = 1
-        while d <= min(max_delta_rows * 2 - 1, idx.n_items):
-            rows = np.arange(d, dtype=np.int64)
-            dummy = idx.with_updates(
-                rows, np.ascontiguousarray(Vh[rows]), seq=idx.seq)
-            for B in self.batcher.buckets:
-                proto = jnp.zeros((B, m.rank + 2), jnp.int32)
-                s, ix = dummy.topk(_select_packed(m.U, proto), self.k)
-                _pack_response(s, ix).block_until_ready()
-                self._emit_shortlist(B, dummy, delta_rows=d)
-            d <<= 1
+        with self._table_lock:
+            m = self._model
+            if m is None:
+                raise NoModelPublished("publish(U, V) before warmup")
+            idx = m.index
+            if idx is None or idx.seq != m.seq:
+                return
+            if max_delta_rows is None:
+                cad = self._live_cadence()
+                max_delta_rows = int(
+                    max(cad["compact_min_rows"],
+                        cad["compact_delta_frac"] * idx.n_base)
+                    + cad["max_batch"])
+            Vh = np.asarray(m.V, dtype=np.float32)
+            d = 1
+            while d <= min(max_delta_rows * 2 - 1, idx.n_items):
+                rows = np.arange(d, dtype=np.int64)
+                dummy = idx.with_updates(
+                    rows, np.ascontiguousarray(Vh[rows]), seq=idx.seq)
+                for B in self.batcher.buckets:
+                    proto = jnp.zeros((B, m.rank + 2), jnp.int32)
+                    s, ix = dummy.topk(_select_packed(m.U, proto), self.k)
+                    _pack_response(s, ix).block_until_ready()
+                    self._emit_shortlist(B, dummy, delta_rows=d)
+                d <<= 1
 
     @staticmethod
     def _emit_shortlist(bucket, index, **extra):
@@ -765,24 +860,41 @@ class ServingEngine:
         seq = self._batch_seq = self._batch_seq + 1
         t_stage = time.perf_counter()
         with TraceAnnotation("serve.batch", seq=seq) as whole:
-            with TraceAnnotation("serve.batch.stage"):
-                live = self._expire(batch, t_stage)
-                if not live:
-                    return
-                # raise-mode -> _run fails all
-                mode = faults.check("serving.score")
-                m = self._model
-                n = len(live)
-                B = bucket_for(n, self.batcher.buckets)
-                st = self._staged(live, B, m.rank)
-                obs.histogram("serving.batch_rows", n, **self._labels)
-            t_dispatch = time.perf_counter()
-            with TraceAnnotation("serve.batch.dispatch"):
-                resp_dev, path, fell_back = self._dispatch(m, st, B, mode)
-                if fell_back:
-                    obs.counter("serving.fallback_exact", n,
-                                **self._labels)
-                whole.set_metadata(bucket=B, rows=n, path=path)
+            # the live generation is read AFTER the dequeue and its user
+            # table goes to the scoring call under one hold of the lock:
+            # a row write donates that table, and may only between two
+            # batches' dispatches (what is dispatched reads it whole).
+            # The wait for the lock is inside the stage span, as it is
+            # inside the record's ``stage``, and alone in ``lock_wait``
+            # (a ``with`` cannot span the two phases from inside the
+            # first; an ExitStack did, for 25 us a batch on the chip's
+            # host: PERF.md section 6, PR 31)
+            held = False
+            try:
+                with TraceAnnotation("serve.batch.stage"):
+                    held = self._table_lock.acquire()
+                    t_locked = time.perf_counter()
+                    live = self._expire(batch, t_locked)
+                    if not live:
+                        return
+                    # raise-mode -> _run fails all
+                    mode = faults.check("serving.score")
+                    m = self._model
+                    n = len(live)
+                    B = bucket_for(n, self.batcher.buckets)
+                    st = self._staged(live, B, m.rank)
+                    obs.histogram("serving.batch_rows", n, **self._labels)
+                t_dispatch = time.perf_counter()
+                with TraceAnnotation("serve.batch.dispatch"):
+                    resp_dev, path, fell_back = self._dispatch(
+                        m, st, B, mode)
+                    if fell_back:
+                        obs.counter("serving.fallback_exact", n,
+                                    **self._labels)
+                    whole.set_metadata(bucket=B, rows=n, path=path)
+            finally:
+                if held:
+                    self._table_lock.release()
             t_readback = time.perf_counter()
             with TraceAnnotation("serve.batch.readback"):
                 # ONE bulk device→host transfer; tickets complete with
@@ -836,7 +948,8 @@ class ServingEngine:
                       t_complete - t_readback, t_end - t_complete))),
             path=path, batch=seq, t0=t_stage, bucket=B, rows=n,
             waiting=waiting, closed_by=self.batcher.closed_by,
-            head_wait=self.batcher.head_wait)
+            head_wait=self.batcher.head_wait,
+            lock_wait=t_locked - t_stage)
         if trigger:
             self.batch_flight.dump(trigger)
 

@@ -35,7 +35,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from tpu_als import obs
-from tpu_als.core.foldin import fold_in, pad_rows, solve_path
+from tpu_als.core.foldin import fold_in, place_rows, solve_path
 from tpu_als.core.ratings import (
     LIVE_PADS,
     pad_for,
@@ -86,8 +86,8 @@ class FoldInServer:
         its rows (by dense ids below the live count) and, on the implicit
         path, reads ``F^T F`` — zero rows change neither — so entities
         appended to it later change no shape and compile nothing."""
-        return pad_rows(
-            jnp.asarray(getattr(self.model, fac_attr), dtype=jnp.float32),
+        return place_rows(
+            getattr(self.model, fac_attr),
             capacity=self._capacity(fac_attr) << growth).block_until_ready()
 
     def _reserve(self, items_side, rows=0):

@@ -13,7 +13,10 @@ with none of it did not stand behind a row write), the process's
 ``serving.batch_closed`` counters against
 the batches the engine served, and what its ``publish_update``s did to
 the device's user table (``serving.user_table_writes``: a live cell
-counts ``inplace`` alone).  No CPU mode (``run.py`` has none):
+counts ``inplace`` alone), and for an engine given a mesh its
+``serving_mesh_plan`` events (one a bucket ``warmup()`` pinned) with
+the process's ``serving.mesh_exchange_bytes``: the mesh path read
+without a profiler.  No CPU mode (``run.py`` has none):
 
     chiprun -- python3 scripts/serve_batch_closed.py --workload \\
         amazon23-r256-share32.serve-steady --seed <n> --seconds 30 --trace 0
@@ -85,6 +88,15 @@ def main(argv):
     print(json.dumps({"serving.user_table_writes": writes,
                       "publishes": obs.counter_value("serving.publishes")}),
           flush=True)
+    plans = [{k: v for k, v in e.items() if k not in ("ts", "type")}
+             for e in obs.default_registry()._events
+             if e["type"] == "serving_mesh_plan"]
+    if plans:
+        print(json.dumps({"serving_mesh_plan": plans,
+                          "serving.mesh_exchange_bytes": obs.counter_value(
+                              "serving.mesh_exchange_bytes"),
+                          "batches_served": engines[0]._batch_seq}),
+              flush=True)
     return 0
 
 

@@ -1,0 +1,320 @@
+"""The mesh engine as one program a bucket (PR 32): the user table
+sharded by rows with the by-id lookup inside the scoring program, the
+merge on every shard, the row write in place in the owning shard, the
+exact fallback per shard — on the CPU's virtual devices, four of them,
+with a catalog and a user count that 4 does not divide."""
+
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from tpu_als import make_mesh, obs
+from tpu_als.core.foldin import place_rows
+from tpu_als.obs.schema import SERVE_MESH_SCOPES
+from tpu_als.parallel.comm_audit import collective_bytes
+from tpu_als.parallel.mesh import AXIS, shard_map
+from tpu_als.serving import engine as engine_module
+from tpu_als.serving.engine import ServingEngine, _mesh_lookup
+from tpu_als.serving.index import SCORE_ULPS, mesh_exchange_bytes
+
+S, N_USERS, N_ITEMS, RANK, K = 4, 5003, 2003, 32, 10
+BUCKETS = (8, 32)
+P = jax.sharding.PartitionSpec
+
+
+def factors(seed=0):
+    rng = np.random.default_rng(seed)
+    U = rng.standard_normal((N_USERS, RANK)).astype(np.float32)
+    V = (rng.standard_normal((N_ITEMS, RANK))
+         / np.sqrt(RANK)).astype(np.float32)
+    return rng, U, V
+
+
+def engine(U, V, mesh=True, **kw):
+    eng = ServingEngine(k=K, buckets=BUCKETS, shortlist_k=128,
+                        mesh=make_mesh(S) if mesh else None, **kw)
+    eng.publish(U, V)
+    eng.warmup()
+    return eng
+
+
+def drain(eng, payloads):
+    tickets = [eng.submit(p) for p in payloads]
+    while True:
+        batch = eng.batcher.next_batch(timeout=0.01)
+        if batch is None:
+            break
+        eng.serve_batch(batch)
+    return [t.result(timeout=10) for t in tickets]
+
+
+def values(table):
+    """A device table's values without a view of its buffer (on the CPU
+    ``np.asarray(table)`` keeps one, and a buffer with such a reference
+    cannot be donated)."""
+    return np.asarray(table + 0)
+
+
+def shard_edges(table):
+    n_loc = table.shape[0] // S
+    return n_loc, sorted({s * n_loc + d for s in range(S)
+                          for d in (0, n_loc - 1)})
+
+
+# -- (a) the same answers as without a mesh ----------------------------------
+
+@pytest.mark.parametrize("by", ["id", "vector"])
+def test_mesh_engine_answers_as_the_meshless_engine_does(by):
+    rng, U, V = factors()
+    assert N_USERS % S and N_ITEMS % S
+    ids = rng.choice(N_USERS, 40, replace=False)
+    payloads = ([int(i) for i in ids] if by == "id" else
+                [U[i] + 0.01 * rng.standard_normal(RANK).astype(np.float32)
+                 for i in ids])
+    want = drain(engine(U, V, mesh=False), payloads)
+    got = drain(engine(U, V), payloads)
+    for (ws, wi), (gs, gi) in zip(want, got):
+        assert gi.tolist() == wi.tolist()
+        tol = SCORE_ULPS * np.spacing(np.abs(ws).max())
+        assert np.abs(gs - ws).max() <= tol
+
+
+# -- (b) the lookup -----------------------------------------------------------
+
+def lookup_program(mesh):
+    return jax.jit(shard_map(
+        lambda U, packed: _mesh_lookup(
+            U, packed, me=jax.lax.axis_index(AXIS), axis=AXIS),
+        mesh=mesh, in_specs=(P(AXIS), P()), out_specs=P(),
+        check_vma=False))
+
+
+def packed_ids(ids, rank):
+    packed = np.zeros((len(ids), rank + 2), np.int32)
+    packed[:, rank] = ids
+    return packed
+
+
+def test_sharded_lookup_returns_the_rows_bit_for_bit():
+    rng, U, V = factors(1)
+    eng = engine(U, V)
+    table = eng._model.U
+    n_loc, edges = shard_edges(table)
+    assert table.shape[0] == S * n_loc >= N_USERS
+    assert [int(s.data.shape[0]) for s in table.addressable_shards] \
+        == [n_loc] * S
+    # appended users: the first spare rows, on the last shards
+    U2 = np.concatenate([U, rng.standard_normal((5, RANK)).astype(
+        np.float32)])
+    eng.publish_update(U2, V, touched_users=[0])
+    table = eng._model.U
+    live = [i for i in edges if i < len(U2)]
+    ids = np.array(live + list(range(N_USERS, len(U2)))
+                   + rng.choice(N_USERS, 16).tolist())
+    assert {int(i) // n_loc for i in ids} == set(range(S))
+    got = lookup_program(eng.mesh)(table, packed_ids(ids, RANK))
+    assert np.asarray(got).tobytes() == U2[ids].tobytes()
+    # a request by vector rides through untouched
+    packed = packed_ids(ids[:8], RANK)
+    rows = rng.standard_normal((8, RANK)).astype(np.float32)
+    packed[:4, :RANK] = rows[:4].view(np.int32)
+    packed[:4, RANK + 1] = 1
+    got = np.asarray(lookup_program(eng.mesh)(table, packed))
+    assert got[:4].tobytes() == rows[:4].tobytes()
+    assert got[4:].tobytes() == U2[ids[4:8]].tobytes()
+
+
+def test_place_rows_shards_a_table_by_rows_without_a_second_copy():
+    rng, U, _ = factors(2)
+    mesh = make_mesh(S)
+    table = place_rows(U, capacity=N_USERS + 30, mesh=mesh)
+    n_loc = -(-(N_USERS + 30) // S)
+    assert table.shape == (S * n_loc, RANK)
+    want = np.zeros(table.shape, np.float32)
+    want[:N_USERS] = U
+    for s, shard in enumerate(table.addressable_shards):
+        assert shard.device == mesh.devices.flat[s]
+        np.testing.assert_array_equal(np.asarray(shard.data),
+                                      want[s * n_loc:(s + 1) * n_loc])
+    # a shard with no live row at all is zeros
+    small = place_rows(U[:5], capacity=64, mesh=mesh)
+    np.testing.assert_array_equal(np.asarray(small)[5:], 0.0)
+    np.testing.assert_array_equal(np.asarray(small)[:5], U[:5])
+
+
+# -- (c) the row write --------------------------------------------------------
+
+def test_publish_update_writes_in_place_into_the_owning_shard():
+    reg = obs.reset()
+    try:
+        rng, U, V = factors(3)
+        eng = engine(U, V)
+        eng.warmup_publish()
+        old = eng._model
+        n_loc = old.U.shape[0] // S
+        before = values(old.U)
+        where = [s.data.unsafe_buffer_pointer()
+                 for s in old.U.addressable_shards]
+        row = n_loc + 3                              # shard 1's
+        queued = eng.submit(int(row))                # admitted BEFORE
+        U2 = U.copy()
+        U2[row] = rng.standard_normal(RANK).astype(np.float32)
+        eng.publish_update(U2, V, touched_users=[row])
+        new = eng._model
+        assert old.U.is_deleted() and new.U.shape == old.U.shape
+        assert [s.data.unsafe_buffer_pointer()
+                for s in new.U.addressable_shards] == where
+        after = values(new.U)
+        changed = np.flatnonzero((after != before).any(axis=1))
+        assert changed.tolist() == [row]
+        np.testing.assert_array_equal(after[row], U2[row])
+        assert reg.counter_value("serving.user_table_writes",
+                                 how="inplace") == 1
+        # dequeued after the write: the new row answers
+        eng.serve_batch(eng.batcher.next_batch(timeout=1.0))
+        got_s, got_i = queued.result(timeout=10)
+        want_s, want_i = drain(engine(U2, V, mesh=False), [int(row)])[0]
+        assert got_i.tolist() == want_i.tolist()
+        assert np.abs(got_s - want_s).max() <= SCORE_ULPS * np.spacing(
+            np.abs(want_s).max())
+    finally:
+        obs.reset()
+
+
+# -- (d) one pinned program a bucket ------------------------------------------
+
+class CountingCalls:
+    def __init__(self, compiled):
+        self.compiled, self.calls = compiled, 0
+
+    def __call__(self, *args):
+        self.calls += 1
+        return self.compiled(*args)
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_after_warmup_a_batch_runs_one_pinned_program(bucket):
+    import jax.monitoring
+
+    reg = obs.reset()
+    try:
+        _, U, V = factors(4)
+        eng = engine(U, V)
+        plans = [e for e in reg._events if e["type"] == "serving_mesh_plan"]
+        assert [e["bucket"] for e in plans] == list(BUCKETS)
+        assert {(e["shards"], e["items_per_shard"], e["users_per_shard"],
+                 e["k_loc"]) for e in plans} == {
+            (S, -(-N_ITEMS // S), eng._model.U.shape[0] // S, K)}
+        assert set(eng._pinned) == {(B, p) for B in BUCKETS
+                                    for p in ("int8", "exact")}
+        pin = eng._pinned[(bucket, "int8")] = CountingCalls(
+            eng._pinned[(bucket, "int8")])
+        compiled = []
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda event, _, **kw: compiled.append(event)
+            if event.endswith("backend_compile_duration") else None)
+        rows = bucket - 3
+        answers = drain(eng, list(range(rows)))
+        assert len(answers) == rows and pin.calls == 1
+        assert not compiled
+        assert (bucket, "int8") in eng._pinned       # it ran, and stays
+        rec = eng.batch_flight.records()[-1]
+        assert (rec["bucket"], rec["path"]) == (bucket, "int8_sharded")
+        plan = next(e for e in plans if e["bucket"] == bucket)
+        assert reg.counter_value("serving.mesh_exchange_bytes") \
+            == plan["exchange_bytes"]
+    finally:
+        obs.reset()
+
+
+# -- (e) what the compiled programs hold --------------------------------------
+
+COLLECTIVE = re.compile(
+    r"\b(all-gather|all-reduce|all-to-all|collective-permute|"
+    r"reduce-scatter)(-start)?\(")
+
+
+def table_shaped(text, rows_loc, rank, pattern):
+    """Lines of the compiled text that apply ``pattern`` to an array with
+    a whole shard's rows."""
+    return [ln for ln in text.splitlines() if re.search(pattern, ln)
+            and re.search(rf"\[{rows_loc},{rank}\]", ln)]
+
+
+def test_compiled_row_write_has_no_table_shaped_copy_or_collective():
+    _, U, V = factors(5)
+    eng = engine(U, V)
+    m = eng._model
+    n_loc = m.U.shape[0] // S
+    rows, vals = jax.device_put(
+        (np.full(8, m.U.shape[0], np.int32), np.zeros((8, RANK), np.float32)),
+        eng._replicated)
+    text = engine_module._build_mesh_scatter(eng.mesh).lower(
+        m.U, rows, vals).compile().as_text()
+    assert "input_output_alias" in text
+    assert not COLLECTIVE.search(text)
+    assert not table_shaped(text, n_loc, RANK, r" copy\(")
+
+
+def test_compiled_scoring_program_moves_queries_and_answers_only():
+    _, U, V = factors(6)
+    eng = engine(U, V)
+    m = eng._model
+    n_loc, ni_loc = m.U.shape[0] // S, m.index.ni_loc
+    text = eng._pinned[(8, "int8")].as_text()
+    moved = [ln for ln in text.splitlines() if COLLECTIVE.search(ln)]
+    assert moved
+    for ln in moved:                # [8, rank] queries, [.., 8, k] answers
+        assert not re.search(rf"\[({n_loc}|{ni_loc}),", ln), ln
+    assert not table_shaped(text, n_loc, RANK, r" copy\(")
+    assert not table_shaped(text, ni_loc, RANK, r" copy\(")
+    for scope in SERVE_MESH_SCOPES:
+        assert scope in text
+
+
+# -- the closed form of the exchange -------------------------------------------
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_exchange_bytes_match_the_traced_program(bucket):
+    _, U, V = factors(7)
+    eng = engine(U, V)
+    m = eng._model
+    packed = jax.device_put(np.zeros((bucket, RANK + 2), np.int32),
+                            eng._replicated)
+    for call, idx in ((eng._int8_call(m, m.index, packed), m.index),
+                      (eng._exact_call(m, packed), None)):
+        fn, args, _ = call
+        traced, breakdown = collective_bytes(fn, *args, axis_size=S)
+        plan = eng._mesh_plan(m, idx, bucket)
+        assert set(breakdown) == {"psum", "all_gather"}
+        assert traced == plan["exchange_bytes"] == mesh_exchange_bytes(
+            S, bucket, RANK, K)
+    assert mesh_exchange_bytes(4, 8, 256, 10) == 12288 + 1920
+
+
+# -- the exact fallback ----------------------------------------------------------
+
+def test_exact_fallback_scores_per_shard_and_uploads_nothing(monkeypatch):
+    _, U, V = factors(8)
+    eng = ServingEngine(k=K, buckets=(8,), mesh=make_mesh(S))
+    eng.publish(U, V, quantize=False)          # no index: exact serves
+    eng.warmup()
+    m = eng._model
+    assert m.index is None and len(m.V.sharding.device_set) == S
+    assert [int(s.data.shape[0]) for s in m.V.addressable_shards] \
+        == [-(-N_ITEMS // S)] * S
+    uploads = []
+    real = jax.device_put
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: (
+        uploads.append(np.asarray(x).nbytes if not isinstance(x, tuple)
+                       else 0), real(x, *a, **k))[1])
+    got = drain(eng, [3, 2700, N_USERS - 1])
+    assert max(uploads) <= 8 * (RANK + 2) * 4
+    want = drain(engine(U, V, mesh=False), [3, 2700, N_USERS - 1])
+    for (ws, wi), (gs, gi) in zip(want, got):
+        assert gi.tolist() == wi.tolist()
+        assert np.abs(gs - ws).max() <= SCORE_ULPS * np.spacing(
+            np.abs(ws).max())
+    assert eng.batch_flight.records()[-1]["path"] == "exact"

@@ -197,8 +197,9 @@ def test_big_delta_and_compact_bitwise_against_rebuild(big):
 
 def test_big_sharded_index_two_stages_per_shard(big):
     """The sharded ``body`` on the 8-device CPU mesh of the fabric tests:
-    each shard's 9,000 columns select in two stages, and the answer is the
-    single-device index's."""
+    each shard's 9,000 columns, padded once to whole blocks (9,088) as the
+    single-device index pads its own, select in two stages, and the answer
+    is the single-device index's."""
     U, V, valid = big
     rng = np.random.default_rng(28)
     V = np.concatenate(
@@ -206,7 +207,10 @@ def test_big_sharded_index_two_stages_per_shard(big):
     valid = np.concatenate([valid, rng.random(32_000) < 0.9])
     sh = build_sharded_index(V, make_mesh(8), item_valid=valid,
                              shortlist_k=SHORTLIST)
-    assert sh.shortlist_plan() == shortlist_plan(9_000, SHORTLIST)
+    cols = shortlist_columns(9_000, SHORTLIST)
+    assert cols == sh.ni_loc == 9_088 and cols % shortlist_plan(
+        cols, SHORTLIST).block_len == 0
+    assert sh.shortlist_plan() == shortlist_plan(cols, SHORTLIST)
     assert sh.shortlist_plan().stages == 2
     s, ix = sh.topk(jnp.asarray(U), 5)
     assert assert_topk_within_contract(s, ix, U, V, valid, 5) >= 20
@@ -215,7 +219,7 @@ def test_big_sharded_index_two_stages_per_shard(big):
                                   np.asarray(one.topk(jnp.asarray(U), 5)[1]))
     rows = np.array([5, 9_001, 71_999], dtype=np.int64)
     upd = sh.with_updates(rows, 4.0 * V[rows], seq=2)
-    assert upd.shortlist_plan() == shortlist_plan(9_004, SHORTLIST)
+    assert upd.shortlist_plan() == shortlist_plan(cols + 4, SHORTLIST)
     V2 = V.copy()
     V2[rows] *= 4.0
     s2, ix2 = upd.topk(jnp.asarray(U), 5)

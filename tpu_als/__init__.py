@@ -48,5 +48,6 @@ from tpu_als.api.tuning import (  # noqa: F401
 )
 from tpu_als.core.ratings import IdMap  # noqa: F401
 from tpu_als.live import LiveUpdater  # noqa: F401
+from tpu_als.parallel.mesh import make_mesh  # noqa: F401
 from tpu_als.stream.microbatch import FoldInServer  # noqa: F401
 from tpu_als.utils.frame import ColumnarFrame  # noqa: F401

@@ -67,7 +67,7 @@ def _write_rows(table, rows, at):
     return jax.lax.dynamic_update_slice(table, rows, (at, 0))
 
 
-def place_rows(F, *, capacity):
+def place_rows(F, *, capacity, mesh=None):
     """``F`` on the device with zero rows up to ``capacity``: the table a
     live path appends to without a change of shape (a gather or a lookup
     never addresses the spare rows; ``F^T F`` is unchanged by them).
@@ -76,23 +76,56 @@ def place_rows(F, *, capacity):
     the table never lies on the device twice (uploaded whole and then
     padded it does at its peak: 1.5 GB more at 1.5 M × 256).  The last
     chunk starts early enough to be whole — rows written twice, with the
-    same values — so every chunk runs one program."""
+    same values — so every chunk runs one program.
+
+    With a ``mesh`` the table is sharded by rows over its devices: shard
+    ``s`` of ``D`` holds rows ``[s * n_loc, (s + 1) * n_loc)``, ``n_loc =
+    ceil(capacity / D)``, and the result has ``D * n_loc`` rows.  Each
+    shard is placed as above on its own device, the shards' chunks in
+    turn so that the devices' uploads overlap, and the shards are joined
+    without a copy: no device ever holds another's rows, or its own
+    twice."""
     F = np.asarray(F, dtype=np.float32)
-    table = jnp.zeros((capacity, F.shape[1]), jnp.float32)
-    n = len(F)
-    step = min(PLACE_CHUNK, n)
-    sent = None
-    for lo in range(0, n, step or 1):
-        lo = min(lo, n - step)
-        chunk = jax.device_put(F[lo:lo + step])
-        table = _write_rows(table, chunk, lo)
-        # one upload behind, no more: an upload takes its device buffer
-        # when it is enqueued, and a loop that ran ahead would hold the
-        # whole table a second time
-        if sent is not None:
-            sent.block_until_ready()
-        sent = chunk
-    return table
+    if mesh is None:
+        return _place_shards(F, [(None, 0, len(F))], capacity)[0]
+    devices = list(mesh.devices.flat)
+    n_loc = -(-int(capacity) // len(devices))
+    shards = _place_shards(
+        F, [(d, min(s * n_loc, len(F)), min((s + 1) * n_loc, len(F)))
+            for s, d in enumerate(devices)], n_loc)
+    return jax.make_array_from_single_device_arrays(
+        (len(devices) * n_loc, F.shape[1]),
+        jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec(mesh.axis_names[0])),
+        shards)
+
+
+def _place_shards(F, parts, rows):
+    """One zero table of ``rows`` rows per ``(device, lo, hi)`` of
+    ``parts`` (``None``: the default device), ``F[lo:hi]`` written into
+    its head a chunk at a time; see :func:`place_rows`."""
+    width = F.shape[1]
+    tables, sent = [], [None] * len(parts)
+    for device, _, _ in parts:
+        with jax.default_device(device):
+            tables.append(jnp.zeros((rows, width), jnp.float32))
+    steps = [min(PLACE_CHUNK, hi - lo) for _, lo, hi in parts]
+    for i in range(max(-(-(hi - lo) // (step or 1))
+                       for (_, lo, hi), step in zip(parts, steps))):
+        for j, ((device, lo, hi), step) in enumerate(zip(parts, steps)):
+            at = i * step
+            if not step or at >= hi - lo:
+                continue
+            at = min(at, hi - lo - step)
+            chunk = jax.device_put(F[lo + at:lo + at + step], device)
+            tables[j] = _write_rows(tables[j], chunk, at)
+            # one upload behind, no more: an upload takes its device
+            # buffer when it is enqueued, and a loop that ran ahead would
+            # hold the whole table a second time
+            if sent[j] is not None:
+                sent[j].block_until_ready()
+            sent[j] = chunk
+    return tables
 
 
 def fold_in(

@@ -117,6 +117,14 @@ METRICS = {
         "write: O(touched) on the device) | replaced (a new table "
         "uploaded whole: no row list, or rows the live table cannot "
         "take) | carried (no row to write: the live table as it is)"),
+    "serving.mesh_exchange_bytes": (
+        "counter", "bytes",
+        "a mesh engine only: what one device moves between the chips "
+        "for the batches it scored, one add a batch — the by-id "
+        "lookup's all-reduce of the [bucket, rank] queries and the "
+        "merge's two all-gathers of the shards' local top-k lists, by "
+        "the closed form serving.index.mesh_exchange_bytes (a test pins "
+        "it to the traced program's collectives)"),
     "scenario.freshness_seconds": (
         "histogram", "seconds",
         "cold-start scenario: rating-arrival -> servable latency (fold-"
@@ -228,6 +236,7 @@ LABELS = {
     "serving.fallback_exact": ("tenant",),
     "serving.publishes": ("tenant",),
     "serving.user_table_writes": ("how", "tenant"),
+    "serving.mesh_exchange_bytes": ("tenant",),
     "serving.publish_seconds": ("mode", "tenant"),
     "live.freshness_seconds": ("tenant",),
     "live.batch_rows": ("tenant",),
@@ -295,6 +304,19 @@ SERVE_BATCH_SPAN_KEYS = (
     "serve.batch.dispatch",   # upload + the scoring call, until it returns
     "serve.batch.readback",   # the one bulk device->host transfer
     "serve.batch.complete",   # completing the tickets + their bookkeeping
+)
+# inside a mesh engine's ONE scoring program a bucket (serving/engine.py
+# ``_build_mesh_serve`` / ``_build_mesh_exact``) the three steps that
+# exist only across chips are ``jax.named_scope``s, in every operation's
+# ``op_name`` beside ``serve.shortlist.*`` (ops/topk.py); the engine
+# thread's spans above lie around that program unchanged,
+# ``path="int8_sharded"``
+SERVE_MESH_SCOPES = (
+    "serve.mesh.lookup",      # each shard takes the user rows it owns for
+    #                           the batch's ids; one all-reduce sums them
+    "serve.mesh.score",       # the shard's int8 shortlist + f32 rescore
+    "serve.mesh.merge",       # two all-gathers of the local top-k lists,
+    #                           one top_k, the packed response
 )
 LIVE_SPAN_KEYS = ("queue_wait", "quarantine", "foldin", "publish")
 # the updater thread's batch cycle as profiler spans, the write path's
@@ -376,6 +398,16 @@ EVENTS = {
         "its shortlist selects, from ops.topk.shortlist_plan — stages 1 is "
         "one lax.top_k over all columns, 2 is block maxima then top_k "
         "over the winning blocks of block_len columns"),
+    "serving_mesh_plan": (
+        ("bucket", "shards", "items_per_shard", "users_per_shard", "k_loc",
+         "exchange_bytes"),
+        "one per sharded int8 scoring program a mesh engine's "
+        "ServingEngine.warmup compiles and pins (per bucket): the mesh "
+        "size, the catalog and user-table rows one shard holds, the "
+        "answers one shard gives a query, and the bytes one device moves "
+        "between the chips for one batch (the lookup's all-reduce, the "
+        "merge's all-gathers: serving.index.mesh_exchange_bytes), which "
+        "is what serving.mesh_exchange_bytes adds per batch"),
     "foldin_solve_path": (
         ("side", "rank", "rows", "width", "path", "reason"),
         "one per fold-in program FoldInServer.prewarm compiled and ran "

@@ -70,13 +70,22 @@ tickets.  The pieces the rest of the stack plugs into:
   int8 shortlist + exact f32 rescore from a candidate index: an
   :class:`~tpu_als.serving.index.Int8CandidateIndex` when ``mesh`` is
   None, a :class:`~tpu_als.serving.index.ShardedInt8Index` (the same
-  shortlist and rescore per shard, one XLA merge per query) when a mesh
-  is given; a stale or absent index falls back to the exact chunked
-  scan.  ``_build_index`` is the one place that reads the mesh to
-  choose.  With a mesh the catalog lives device-resident per shard and
-  never commits whole to one device: the engine's own catalog handle
-  stays on the HOST (the exact fallback re-uploads per batch — rare by
-  construction).
+  shortlist and rescore per shard) when a mesh is given; a stale or
+  absent index falls back to the exact chunked scan.  ``_build_index``
+  is the one place that reads the mesh to choose.  With a mesh ALL the
+  tables live sharded by rows over it, shard ``s`` holding a contiguous
+  block: the catalog and its int8 rows (no device ever holds the whole
+  of them, and the engine and its index share the one sharded copy) and
+  the user table (spare rows on the last shards).  A batch is ONE
+  program (:func:`_build_mesh_serve`): every shard takes the user rows
+  it owns for the batch's ids and one all-reduce sums them
+  (:func:`_mesh_lookup`), every shard scores its slice, two all-gathers
+  and one ``top_k`` merge the local lists on every shard, and the
+  packed response comes back in one transfer.  The row write of a
+  publish goes into the owning shard in place
+  (:func:`_build_mesh_scatter`), and the exact fallback scores per
+  shard too (:func:`_build_mesh_exact`): nothing but the staged batch
+  and a publish's touched rows is ever uploaded.
 - **Host throughput.**  The request path stages each micro-batch into
   one reusable per-bucket ``[B, rank+2]`` int32 array (query rows' f32
   bits | ids | row-mask) and uploads it as ONE transfer — no per-batch
@@ -86,8 +95,9 @@ tickets.  The pieces the rest of the stack plugs into:
   that buffer — zero per-ticket copies; the buffer snapshots an
   immutable device array, so the views stay valid indefinitely.
   :meth:`ServingEngine.warmup` additionally PINS the steady-state
-  mesh-less scoring executables ahead of time (``jit(...).lower().
-  compile()`` per bucket), taking jit-cache dispatch off the hot path;
+  scoring executables ahead of time (``jit(...).lower().compile()`` per
+  bucket, with a mesh or without), taking jit-cache dispatch off the
+  hot path;
   a shape-changing publish invalidates a pin and falls back to the
   ordinary jit call until the next warmup.
 """
@@ -112,7 +122,7 @@ from tpu_als.core.ratings import (
     row_capacity,
 )
 from tpu_als.obs import tracing
-from tpu_als.obs.schema import SERVE_BATCH_SPAN_KEYS
+from tpu_als.obs.schema import SERVE_BATCH_SPAN_KEYS, SERVE_MESH_SCOPES
 from tpu_als.obs.trace import FlightRecorder
 from tpu_als.ops.topk import chunked_topk_scores
 from tpu_als.resilience import faults
@@ -123,8 +133,15 @@ from tpu_als.serving.batcher import (
     Overloaded,
     bucket_for,
 )
-from tpu_als.serving.index import Int8CandidateIndex, ShardedInt8Index
-from tpu_als.serving.index import _int8_topk
+from tpu_als.serving.index import (
+    Int8CandidateIndex,
+    ShardedInt8Index,
+    _int8_topk,
+    _shard_merge,
+    _shard_score,
+    mesh_exchange_bytes,
+    place_catalog,
+)
 
 
 class NoModelPublished(RuntimeError):
@@ -144,19 +161,23 @@ class _Published:
     ``U`` goes to the device only under ``ServingEngine._table_lock``,
     read from the LIVE generation.  ``seq``, ``n_users``, ``rank``,
     ``V``/``valid`` and ``index`` stay readable for as long as the
-    instance is held.  ``V``/``valid`` are device arrays on a mesh-less
-    engine and HOST numpy on a mesh engine (see the module docstring).
+    instance is held.  ``V``/``valid`` hold the ``n_items`` rows of the
+    catalog on the device; on a mesh engine they and ``U`` are sharded
+    by rows over the mesh and padded to whole shards (see the module
+    docstring), and a fresh index shares ``V``/``valid`` with the engine.
     """
 
-    __slots__ = ("seq", "U", "V", "valid", "index", "n_users", "rank")
+    __slots__ = ("seq", "U", "V", "valid", "index", "n_users", "rank",
+                 "n_items")
 
-    def __init__(self, seq, U, n_users, V, valid, index):
+    def __init__(self, seq, U, n_users, V, valid, index, n_items):
         self.seq = seq
         self.U = U
         self.V = V
         self.valid = valid
         self.index = index
         self.n_users = int(n_users)
+        self.n_items = int(n_items)
         self.rank = int(U.shape[1])
 
 
@@ -221,6 +242,108 @@ def _scatter_users(U, rows, vals):
     touched payload crosses host→device."""
     with jax.named_scope("live.publish.scatter"):
         return U.at[rows].set(vals, mode="drop")
+
+
+def _mesh_lookup(U, packed, *, me, axis):
+    """:func:`_select_packed` against a user table sharded by rows,
+    inside ``shard_map``: this shard holds table rows ``[me * n_loc,
+    (me + 1) * n_loc)``, takes the rows it owns for the batch's ids
+    (zeros for the others) and the shards' contributions are summed —
+    one row and ``S - 1`` zeros a slot, so the sum is the row — after
+    which every shard holds the ``[B, rank]`` queries.  A request by
+    vector rides in ``packed`` as it does without a mesh."""
+    n_loc, rank = U.shape
+    rows = jax.lax.bitcast_convert_type(packed[:, :rank], jnp.float32)
+    loc = packed[:, rank] - me * n_loc
+    owned = (loc >= 0) & (loc < n_loc)
+    mine = jnp.where(owned[:, None],
+                     jnp.take(U, jnp.clip(loc, 0, n_loc - 1), axis=0), 0.0)
+    rowmask = packed[:, rank + 1] != 0
+    return jnp.where(rowmask[:, None], rows, jax.lax.psum(mine, axis))
+
+
+@functools.lru_cache(maxsize=32)
+def _build_mesh_serve(mesh, k, k_loc, sk_loc, ni_loc, has_delta):
+    """A mesh engine's whole int8 request path as ONE program: the
+    by-id lookup in the sharded user table, each shard's int8 shortlist
+    and f32 rescore over its slice of the catalog
+    (``serving.index._shard_score``, the body ``ShardedInt8Index.topk``
+    runs), the merge of the shards' local top-k lists on every shard,
+    the packed ``[B, 2k]`` response, replicated: one device→host
+    transfer answers the batch.  :meth:`ServingEngine.warmup` lowers,
+    compiles and pins it per bucket, as it does
+    :func:`_serve_int8_packed` without a mesh; a device trace names it
+    ``jit_serve_mesh_int8`` on its ``XLA Modules`` line, one a batch."""
+    from tpu_als.parallel.mesh import AXIS, shard_map
+
+    P = jax.sharding.PartitionSpec
+
+    def serve_mesh_int8(U, packed, Vq, sv, V, valid, last_id, *delta):
+        me = jax.lax.axis_index(AXIS)
+        with jax.named_scope(SERVE_MESH_SCOPES[0]):
+            Ub = _mesh_lookup(U, packed, me=me, axis=AXIS)
+        with jax.named_scope(SERVE_MESH_SCOPES[1]):
+            s, gids = _shard_score(Ub, Vq, sv, V, valid, delta, me=me,
+                                   k_loc=k_loc, sk_loc=sk_loc,
+                                   ni_loc=ni_loc)
+        with jax.named_scope(SERVE_MESH_SCOPES[2]):
+            return _pack_response(
+                *_shard_merge(s, gids, last_id, axis=AXIS, k=k))
+
+    return jax.jit(shard_map(
+        serve_mesh_int8, mesh=mesh,
+        in_specs=(P(AXIS), P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P())
+        + (P(),) * (5 if has_delta else 0),
+        out_specs=P(), check_vma=False))
+
+
+@functools.lru_cache(maxsize=32)
+def _build_mesh_exact(mesh, k, k_loc, ni_loc, item_chunk):
+    """A mesh engine's exact fallback, per shard: the lookup, the exact
+    chunked scan of this shard's slice of the engine's own sharded
+    catalog, the same merge.  Nothing of the catalog moves."""
+    from tpu_als.parallel.mesh import AXIS, shard_map
+
+    P = jax.sharding.PartitionSpec
+
+    def serve_mesh_exact(U, packed, V, valid, last_id):
+        me = jax.lax.axis_index(AXIS)
+        with jax.named_scope(SERVE_MESH_SCOPES[0]):
+            Ub = _mesh_lookup(U, packed, me=me, axis=AXIS)
+        with jax.named_scope(SERVE_MESH_SCOPES[1]):
+            s, ix = chunked_topk_scores(Ub, V, valid, k_loc,
+                                        item_chunk=item_chunk)
+        with jax.named_scope(SERVE_MESH_SCOPES[2]):
+            return _pack_response(*_shard_merge(
+                s, ix.astype(jnp.int32) + me * ni_loc, last_id,
+                axis=AXIS, k=k))
+
+    return jax.jit(shard_map(
+        serve_mesh_exact, mesh=mesh, in_specs=(P(AXIS), P(), P(AXIS), P(AXIS), P()),
+        out_specs=P(), check_vma=False))
+
+
+@functools.lru_cache(maxsize=8)
+def _build_mesh_scatter(mesh):
+    """:func:`_scatter_users` for a table sharded by rows: every shard
+    is given the same ``(rows, vals)`` and writes the rows it owns into
+    its own part, IN PLACE (the table is donated; the others fall on the
+    out-of-range sentinel and are dropped).  No collective, no copy."""
+    from tpu_als.parallel.mesh import AXIS, shard_map
+
+    P = jax.sharding.PartitionSpec
+
+    def scatter_users_mesh(U, rows, vals):
+        n_loc = U.shape[0]
+        loc = rows - jax.lax.axis_index(AXIS) * n_loc
+        owned = (loc >= 0) & (loc < n_loc)
+        with jax.named_scope("live.publish.scatter"):
+            return U.at[jnp.where(owned, loc, n_loc)].set(vals, mode="drop")
+
+    return jax.jit(shard_map(scatter_users_mesh, mesh=mesh,
+                             in_specs=(P(AXIS), P(), P()),
+                             out_specs=P(AXIS), check_vma=False),
+                   donate_argnums=(0,))
 
 
 class ServingEngine:
@@ -290,16 +413,32 @@ class ServingEngine:
         self._thread = None
         self._stopping = threading.Event()
         self.mesh = mesh
+        # what every shard is given whole: the staged batch, a publish's
+        # touched rows
+        self._replicated = (None if mesh is None else
+                            jax.sharding.NamedSharding(
+                                mesh, jax.sharding.PartitionSpec()))
         self._stage = {}                # bucket -> reusable [B, rank+2]
         self._pinned = {}               # (bucket, path) -> AOT executable
+        self._plans = {}                # _mesh_plan's memo
 
-    def _build_index(self, V, valid, sk, seq):
-        """The candidate index of one generation, sharded over the mesh
-        when the engine has one: the one place that chooses."""
+    def _place_catalog(self, Vh, validh):
+        """The host's catalog on the device: whole, or sharded by rows
+        over the mesh (``serving.index.place_catalog``: a chunk at a
+        time into each shard, never whole on one device)."""
+        if self.mesh is None:
+            return jnp.asarray(Vh), jnp.asarray(validh)
+        return place_catalog(Vh, validh, self.mesh,
+                             max(self.shortlist_k, self.k))[:2]
+
+    def _build_index(self, V, valid, n_items, sk, seq):
+        """The candidate index of one generation over the catalog as
+        :meth:`_place_catalog` placed it, sharded over the mesh when the
+        engine has one: the one place that chooses."""
         if self.mesh is None:
             return Int8CandidateIndex(V, valid, shortlist_k=sk, seq=seq)
         return ShardedInt8Index(V, self.mesh, item_valid=valid,
-                                shortlist_k=sk, seq=seq)
+                                shortlist_k=sk, seq=seq, n_items=n_items)
 
     def _announce_mesh(self):
         """One ``serving_backend`` event per mesh engine, at its first
@@ -309,14 +448,15 @@ class ServingEngine:
                      n_shards=int(self.mesh.devices.size), **self._labels)
 
     # -- model lifecycle ----------------------------------------------
-    @staticmethod
-    def _place_users(prev, U):
+    def _place_users(self, prev, U):
         """``(U on the device with spare rows, live rows, bytes sent)``:
         the whole table uploaded into a new one, a chunk at a time
-        (``core.foldin.place_rows``: never twice on the device).  The
-        capacity is the live generation's while the table fits it (same
-        shapes, same programs), ``row_capacity`` of the table
-        otherwise."""
+        (``core.foldin.place_rows``: never twice on the device; with a
+        mesh sharded by rows, shard ``s`` holding rows ``[s * n_loc, (s
+        + 1) * n_loc)``, so the spare rows, which follow the live ones,
+        lie on the last shards).  The capacity is the live generation's
+        while the table fits it (same shapes, same programs),
+        ``row_capacity`` of the table otherwise."""
         n, rank = int(U.shape[0]), int(U.shape[1])
         cap = row_capacity(n)
         if prev is not None and prev.rank == rank \
@@ -324,7 +464,8 @@ class ServingEngine:
             cap = int(prev.U.shape[0])
         # wait for it: what a publish allocates next (the catalog, its
         # index) is then allocated after the last chunk's buffer is freed
-        return (place_rows(U, capacity=cap).block_until_ready(),
+        return (place_rows(U, capacity=cap,
+                           mesh=self.mesh).block_until_ready(),
                 n, 4 * n * rank)
 
     def _update_users(self, prev, U, touched_users):
@@ -355,7 +496,8 @@ class ServingEngine:
                 rp[:len(rows)] = rows
                 vals = np.zeros((pad, rank), dtype=np.float32)
                 vals[:len(rows)] = U[rows]
-                return ("inplace", (jnp.asarray(rp), jnp.asarray(vals)),
+                return ("inplace",
+                        jax.device_put((rp, vals), self._replicated),
                         n, rp.nbytes + vals.nbytes)
         if touched_users is not None and prev is not None:
             obs.emit("warning", what="serving.publish_update",
@@ -364,7 +506,8 @@ class ServingEngine:
                             "user table re-placed whole")
         return ("replaced",) + self._place_users(prev, U)
 
-    def _swap(self, how, users, seq, n_users, V, valid, index, host=None):
+    def _swap(self, how, users, seq, n_users, V, valid, index, n_items,
+              host=None):
         """Install the next generation, the one place that assigns
         ``_model``; returns ``how`` it got its user table.  ``users`` is
         that table, or with ``how == "inplace"`` the ``(rows, vals)`` to
@@ -385,7 +528,8 @@ class ServingEngine:
             if how == "inplace":
                 table = self._model.U
                 try:
-                    users = _scatter_users(table, *users)
+                    users = (_scatter_users if self.mesh is None else
+                             _build_mesh_scatter(self.mesh))(table, *users)
                 except Exception as e:
                     if not self._model.U.is_deleted():
                         raise           # nothing was donated: all whole
@@ -400,7 +544,8 @@ class ServingEngine:
                     # a deleted table still reads its shape: same capacity
                     how, users = "replaced", self._place_users(
                         self._model, host)[0]
-            self._model = _Published(seq, users, n_users, V, valid, index)
+            self._model = _Published(seq, users, n_users, V, valid, index,
+                                     n_items)
         return how
 
     def publish(self, U, V, item_valid=None, quantize=True):
@@ -421,18 +566,19 @@ class ServingEngine:
         validh = (np.ones(Ni, dtype=bool) if item_valid is None
                   else np.asarray(item_valid, dtype=bool).ravel())
         self._announce_mesh()
-        # a mesh engine keeps its catalog handle on the HOST — the
-        # shard-resident copies are the only device-committed ones
-        if self.mesh is None:
-            V, valid = jnp.asarray(Vh), jnp.asarray(validh)
-        else:
-            V, valid = Vh, validh
+        V, valid = self._place_catalog(Vh, validh)
         with self._publish_lock:
             seq = self._seq + 1
             sk = min(max(self.shortlist_k, self.k), Ni)
             index = None
             if quantize and sk >= self.k and Ni > 0:
-                index = self._build_index(Vh, validh, sk, seq)
+                # without a mesh the index uploads a copy of its own from
+                # the host's catalog, as it always has (the device then
+                # holds V twice: PERF.md section 7); with one it shares
+                # the engine's sharded table
+                index = self._build_index(
+                    *((Vh, validh) if self.mesh is None else (V, valid)),
+                    Ni, sk, seq)
                 if mode == "corrupt":
                     # injected torn publish: quantization died mid-swap,
                     # so the fresh index is never published.  The
@@ -444,7 +590,7 @@ class ServingEngine:
                              if self._model is not None else None)
             elif self._model is not None:
                 index = self._model.index      # carried, now stale
-            self._swap("replaced", U, seq, n_users, V, valid, index)
+            self._swap("replaced", U, seq, n_users, V, valid, index, Ni)
             self._seq = seq
         fresh = index is not None and index.seq == seq
         obs.counter("serving.publishes", **self._labels)
@@ -519,15 +665,12 @@ class ServingEngine:
             prev = self._model
             how, users, n_users, h2d = self._update_users(
                 prev, U, touched_users)
-            if self.mesh is not None:
-                V, valid = Vh, valid_h
-            elif (prev is not None and not touched.size
-                    and item_valid is None
-                    and int(prev.V.shape[0]) == Ni):
+            if (prev is not None and not touched.size
+                    and item_valid is None and prev.n_items == Ni):
                 # nothing of the catalog changed: the device's copy stays
                 V, valid = prev.V, prev.valid
             else:
-                V, valid = jnp.asarray(Vh), jnp.asarray(valid_h)
+                V, valid = self._place_catalog(Vh, valid_h)
                 h2d += Vh.nbytes + valid_h.nbytes
             cur = prev.index if prev is not None else None
             index, mode = None, "full"
@@ -560,12 +703,12 @@ class ServingEngine:
             if index is None:
                 sk = min(max(self.shortlist_k, self.k), Ni)
                 if sk >= self.k and Ni > 0:
-                    index = self._build_index(V, valid, sk, seq)
+                    index = self._build_index(V, valid, Ni, sk, seq)
                 else:
                     mode = "none"
             # last: every step above may raise or take long (an index
             # build), and from the row write on the old table is gone
-            how = self._swap(how, users, seq, n_users, V, valid, index,
+            how = self._swap(how, users, seq, n_users, V, valid, index, Ni,
                              host=U)
             self._seq = seq
         obs.counter("serving.publishes", **self._labels)
@@ -609,15 +752,15 @@ class ServingEngine:
         compile.  Records no metrics (a warmup sample in the latency
         histograms would poison the SLO tail serve-bench reports).
 
-        Without a mesh this also PINS the steady-state packed
-        executables per bucket (AOT ``lower().compile()``), so the hot
-        path calls a compiled program directly instead of going through
-        jit-cache dispatch; a publish that changes array shapes
-        invalidates a pin (the serve path falls back to the jit call
-        and drops it) — re-run warmup to restore.  A mesh engine warms
-        its jit caches (the sharded executables are keyed on mesh
-        placement, which AOT calls are strict about) plus the exact
-        fallback.
+        This PINS the steady-state packed executables per bucket (AOT
+        ``lower().compile()``), so the hot path calls a compiled program
+        directly instead of going through jit-cache dispatch; a publish
+        that changes array shapes invalidates a pin (the serve path
+        falls back to the jit call and drops it) — re-run warmup to
+        restore.  With a mesh the pinned programs are the sharded ones
+        (:func:`_build_mesh_serve`, :func:`_build_mesh_exact`), one a
+        bucket and path like the others, and each int8 one is announced
+        by a ``serving_mesh_plan`` event.
 
         Holds ``_table_lock`` throughout, as whoever hands the live user
         table to the device must: no row write donates the table
@@ -632,30 +775,92 @@ class ServingEngine:
             if m is None:
                 raise NoModelPublished("publish(U, V) before warmup")
             self._pinned.clear()
-            pin = self.mesh is None
             for B in self.batcher.buckets:
-                proto = jnp.zeros((B, m.rank + 2), jnp.int32)
+                proto = self._proto(B, m.rank)
                 idx = m.index
                 if idx is not None and idx.seq == m.seq:
                     self._emit_shortlist(B, idx)
-                    if pin and not idx.delta_count:
-                        self._pinned[(B, "int8")] = _serve_int8_packed.lower(
-                            m.U, idx.Vq, idx.sv, idx.V, idx.valid, proto,
-                            k=self.k,
-                            shortlist_k=idx.shortlist_k).compile()
+                    if not idx.delta_count:
+                        fn, args, statics = self._int8_call(m, idx, proto)
+                        self._pinned[(B, "int8")] = fn.lower(
+                            *args, **statics).compile()
+                        if self.mesh is not None:
+                            obs.emit("serving_mesh_plan", bucket=B,
+                                     **self._mesh_plan(m, idx, B),
+                                     **self._labels)
                     else:
-                        s, ix = idx.topk(_select_packed(m.U, proto), self.k)
-                        _pack_response(s, ix).block_until_ready()
+                        self._score_delta(m, idx, proto).block_until_ready()
                 # the exact path backs every fallback: always warm
-                Vd, validd = jnp.asarray(m.V), jnp.asarray(m.valid)
-                ic = min(self.item_chunk, max(int(Vd.shape[0]), 1))
-                if pin:
-                    self._pinned[(B, "exact")] = _serve_exact_packed.lower(
-                        m.U, Vd, validd, proto, k=self.k,
-                        item_chunk=ic).compile()
-                else:
-                    _serve_exact_packed(m.U, Vd, validd, proto, k=self.k,
-                                        item_chunk=ic).block_until_ready()
+                fn, args, statics = self._exact_call(m, proto)
+                self._pinned[(B, "exact")] = fn.lower(
+                    *args, **statics).compile()
+
+    def _proto(self, B, rank):
+        """An empty staged batch of bucket ``B``, placed as
+        :meth:`_dispatch` places a real one."""
+        return jax.device_put(np.zeros((B, rank + 2), np.int32),
+                              self._replicated)
+
+    def _int8_call(self, m, idx, packed):
+        """``(jitted function, arguments, static arguments)`` of the
+        delta-free int8 request path for one staged batch: the one
+        program :meth:`warmup` pins and :meth:`_dispatch` runs."""
+        if self.mesh is None:
+            return (_serve_int8_packed,
+                    (m.U, idx.Vq, idx.sv, idx.V, idx.valid, packed),
+                    dict(k=self.k, shortlist_k=idx.shortlist_k))
+        return (*self._mesh_serve_call(m, idx, packed), {})
+
+    def _mesh_serve_call(self, m, idx, packed):
+        """``(jitted function, arguments)`` of a mesh engine's one int8
+        program for this index as it stands, delta segment or none."""
+        k_loc, sk_loc = idx.shard_widths(self.k)
+        return (_build_mesh_serve(self.mesh, self.k, k_loc, sk_loc,
+                                  idx.ni_loc, bool(idx.delta_count)),
+                (m.U, packed, *idx.score_args()))
+
+    def _exact_call(self, m, packed):
+        """The same of the exact fallback, against the engine's own
+        catalog handle: per shard with a mesh, nothing uploaded."""
+        if self.mesh is None:
+            return (_serve_exact_packed, (m.U, m.V, m.valid, packed),
+                    dict(k=self.k, item_chunk=min(
+                        self.item_chunk, max(int(m.V.shape[0]), 1))))
+        ni_loc = int(m.V.shape[0]) // int(self.mesh.devices.size)
+        return (_build_mesh_exact(self.mesh, self.k, min(self.k, ni_loc),
+                                  ni_loc, min(self.item_chunk, ni_loc)),
+                (m.U, packed, m.V, m.valid,
+                 jax.device_put(np.int32(m.n_items - 1), self._replicated)),
+                {})
+
+    def _score_delta(self, m, idx, packed):
+        """The packed response of an index with a live delta segment,
+        through the jit cache (:meth:`warmup_live` compiles its
+        programs): three calls without a mesh, one with."""
+        if self.mesh is None:
+            s, ix = idx.topk(_select_packed(m.U, packed), self.k)
+            return _pack_response(s, ix)
+        fn, args = self._mesh_serve_call(m, idx, packed)
+        return fn(*args)
+
+    def _mesh_plan(self, m, idx, bucket):
+        """What one batch of ``bucket`` rows costs the mesh, scored by
+        ``idx`` (``None``: by the exact fallback): the fields of a
+        ``serving_mesh_plan`` event, kept per (bucket, shapes) — the
+        engine thread looks ``exchange_bytes`` up for every batch."""
+        S = int(self.mesh.devices.size)
+        ni_loc = int(m.V.shape[0]) // S if idx is None else idx.ni_loc
+        k_loc = (min(self.k, ni_loc) if idx is None
+                 else idx.shard_widths(self.k)[0])
+        key = (bucket, ni_loc, k_loc, m.U.shape)
+        plan = self._plans.get(key)
+        if plan is None:
+            plan = self._plans[key] = dict(
+                shards=S, items_per_shard=ni_loc,
+                users_per_shard=int(m.U.shape[0]) // S, k_loc=k_loc,
+                exchange_bytes=mesh_exchange_bytes(S, bucket, m.rank,
+                                                   k_loc))
+        return plan
 
     def warmup_publish(self, max_rows=LIVE_PADS[-1]):
         """Compile AND run the user-row writes ``publish_update(
@@ -672,9 +877,11 @@ class ServingEngine:
             for pad in pads_up_to(max_rows):
                 self._swap(
                     "inplace",
-                    (jnp.full(pad, m.U.shape[0], jnp.int32),
-                     jnp.zeros((pad, m.rank), jnp.float32)),
-                    m.seq, m.n_users, m.V, m.valid, m.index)
+                    jax.device_put(
+                        (np.full(pad, m.U.shape[0], np.int32),
+                         np.zeros((pad, m.rank), np.float32)),
+                        self._replicated),
+                    m.seq, m.n_users, m.V, m.valid, m.index, m.n_items)
             self._model.U.block_until_ready()
 
     def warmup_live(self, max_delta_rows=None):
@@ -703,16 +910,16 @@ class ServingEngine:
                     max(cad["compact_min_rows"],
                         cad["compact_delta_frac"] * idx.n_base)
                     + cad["max_batch"])
-            Vh = np.asarray(m.V, dtype=np.float32)
+            top = min(max_delta_rows * 2 - 1, idx.n_items)
+            Vh = np.asarray(m.V[:top], dtype=np.float32)
             d = 1
-            while d <= min(max_delta_rows * 2 - 1, idx.n_items):
+            while d <= top:
                 rows = np.arange(d, dtype=np.int64)
                 dummy = idx.with_updates(
                     rows, np.ascontiguousarray(Vh[rows]), seq=idx.seq)
                 for B in self.batcher.buckets:
-                    proto = jnp.zeros((B, m.rank + 2), jnp.int32)
-                    s, ix = dummy.topk(_select_packed(m.U, proto), self.k)
-                    _pack_response(s, ix).block_until_ready()
+                    proto = self._proto(B, m.rank)
+                    self._score_delta(m, dummy, proto).block_until_ready()
                     self._emit_shortlist(B, dummy, delta_rows=d)
                 d <<= 1
 
@@ -1003,39 +1210,25 @@ class ServingEngine:
         return st
 
     def _dispatch(self, m, st, B, mode):
-        """Upload the staged batch and call the scorer the live model
-        selects; returns ``(packed response on the device, path, fell
-        back to exact)`` as soon as the call returns."""
+        """Upload the staged batch (to every shard, with a mesh) and
+        call the scorer the live model selects; returns ``(packed
+        response on the device, path, fell back to exact)`` as soon as
+        the call returns."""
         index = m.index
-        packed = jnp.asarray(st)
-        resp_dev = None
+        packed = jax.device_put(st, self._replicated)
         use_index = (index is not None and index.seq == m.seq
                      and mode != "corrupt")
         fell_back = index is not None and not use_index
-        if use_index:
-            if isinstance(index, ShardedInt8Index):
-                path = "int8_sharded"
-            else:
-                path = "int8"
-                if not index.delta_count:
-                    resp_dev = self._run_pinned(
-                        (B, "int8"), _serve_int8_packed,
-                        (m.U, index.Vq, index.sv, index.V,
-                         index.valid, packed),
-                        dict(k=self.k, shortlist_k=index.shortlist_k))
-            if resp_dev is None:
-                s, ix = index.topk(_select_packed(m.U, packed),
-                                   self.k)
-                resp_dev = _pack_response(s, ix)
+        if self.mesh is not None:
+            obs.counter("serving.mesh_exchange_bytes",
+                        self._mesh_plan(m, index if use_index else None,
+                                        B)["exchange_bytes"],
+                        **self._labels)
+        if not use_index:
+            path, pin, call = "exact", "exact", self._exact_call(m, packed)
         else:
-            path = "exact"
-        if path == "exact":
-            # a mesh engine keeps V on the host (module docstring):
-            # the fallback re-uploads per batch, by design rare
-            Vd, validd = jnp.asarray(m.V), jnp.asarray(m.valid)
-            ic = min(self.item_chunk, max(int(Vd.shape[0]), 1))
-            resp_dev = self._run_pinned(
-                (B, "exact"), _serve_exact_packed,
-                (m.U, Vd, validd, packed),
-                dict(k=self.k, item_chunk=ic))
-        return resp_dev, path, fell_back
+            path = "int8" if self.mesh is None else "int8_sharded"
+            if index.delta_count:
+                return self._score_delta(m, index, packed), path, fell_back
+            pin, call = "int8", self._int8_call(m, index, packed)
+        return self._run_pinned((B, pin), *call), path, fell_back

@@ -449,117 +449,167 @@ def build_index(V, item_valid=None, shortlist_k=64, seq=0):
                               shortlist_k=shortlist_k, seq=seq)
 
 
+def _shard_score(U, Vq, sv, V, valid, delta, *, me, k_loc, sk_loc, ni_loc):
+    """One shard's part of a sharded query, inside ``shard_map``: the
+    SAME shortlist→rescore pipeline as :func:`_int8_topk` /
+    :func:`_int8_topk_delta` over this shard's catalog slice only — no
+    shard ever sees another's rows.  The (tiny, replicated) ``delta``
+    segment — ``()`` without one — is scored by every shard but masked
+    to the rows it OWNS (``row // ni_loc == me``), so each delta row is
+    scored exactly once mesh-wide.  Returns the local top-``k_loc``
+    ``(scores [n, k_loc], catalog ids [n, k_loc])``."""
+    n = U.shape[0]
+    Uq, su = _quantize_rows(U)
+    acc = jnp.einsum("nr,cr->nc", Uq, Vq,
+                     preferred_element_type=jnp.int32)
+    approx = acc.astype(jnp.float32) * su[:, None] * sv[None, :]
+    if delta:
+        drows, dVq, dsv, dV, dvalid = delta
+        d = dVq.shape[0]
+        idx = drows - me * ni_loc          # local slot, if owned
+        owned = (idx >= 0) & (idx < ni_loc)
+        # overridden base rows mask regardless of dvalid (a delta
+        # row may mark an item invalid); ni_loc is the OOB sentinel
+        over = jnp.zeros((ni_loc,), jnp.bool_).at[
+            jnp.where(owned, idx, ni_loc)].set(True, mode="drop")
+        base_ok = valid & ~over
+        approx = jnp.where(base_ok[None, :], approx, NEG_INF)
+        dmask = dvalid & owned
+        acc_d = jnp.einsum("nr,cr->nc", Uq, dVq,
+                           preferred_element_type=jnp.int32)
+        approx_d = (acc_d.astype(jnp.float32)
+                    * su[:, None] * dsv[None, :])
+        approx_d = jnp.where(dmask[None, :], approx_d, NEG_INF)
+        approx = jnp.concatenate([approx, approx_d], axis=1)
+    else:
+        base_ok = valid
+        approx = jnp.where(base_ok[None, :], approx, NEG_INF)
+    _, cand = shortlist_topk(approx, sk_loc)
+    flat = cand.reshape(-1)
+    if delta:
+        in_base = flat < ni_loc
+        base_ix = jnp.minimum(flat, ni_loc - 1)
+        delta_ix = jnp.clip(flat - ni_loc, 0, d - 1)
+        Vc = jnp.where(in_base[:, None],
+                       jnp.take(V, base_ix, axis=0),
+                       jnp.take(dV, delta_ix, axis=0))
+    else:
+        Vc = jnp.take(V, flat, axis=0)
+    exact_all = jnp.einsum("nr,cr->nc", U, Vc,
+                           preferred_element_type=jnp.float32)
+    pos = (jnp.arange(n, dtype=jnp.int32)[:, None] * sk_loc
+           + jnp.arange(sk_loc, dtype=jnp.int32)[None, :])
+    exact = jnp.take_along_axis(exact_all, pos, axis=1)
+    if delta:
+        cand_ok = jnp.where(in_base, jnp.take(base_ok, base_ix),
+                            jnp.take(dmask, delta_ix))
+        gid = jnp.where(in_base, flat + me * ni_loc,
+                        jnp.take(drows, delta_ix))
+    else:
+        cand_ok = jnp.take(base_ok, flat)
+        gid = flat + me * ni_loc
+    exact = jnp.where(cand_ok.reshape(n, sk_loc), exact, NEG_INF)
+    s, sel = jax.lax.top_k(exact, k_loc)
+    gids = jnp.take_along_axis(gid.reshape(n, sk_loc), sel, axis=1)
+    return s, gids.astype(jnp.int32)
+
+
+def _shard_merge(s, gids, last_id, *, axis, k):
+    """The shards' local top-``k_loc`` lists gathered onto every shard
+    (two ``all_gather``s of ``[n, k_loc]``: ``S * k_loc`` values a
+    query, never a per-shard candidate LIST in host memory),
+    concatenated in shard order and reduced with one stable
+    ``lax.top_k``: every shard ends with the same ``[n, k]`` answer."""
+    n, k_loc = s.shape
+    all_s = jax.lax.all_gather(s, axis)                # [S, n, k_loc]
+    all_i = jax.lax.all_gather(gids, axis)
+    cat_s = jnp.transpose(all_s, (1, 0, 2)).reshape(n, -1)
+    cat_i = jnp.transpose(all_i, (1, 0, 2)).reshape(n, -1)
+    if cat_s.shape[1] < k:     # tiny shards: pad so top_k(k) is legal
+        pad = k - cat_s.shape[1]
+        cat_s = jnp.pad(cat_s, ((0, 0), (0, pad)),
+                        constant_values=NEG_INF)
+        cat_i = jnp.pad(cat_i, ((0, 0), (0, pad)))
+    bs, sel = jax.lax.top_k(cat_s, k)
+    bi = jnp.take_along_axis(cat_i, sel, axis=1)
+    return bs, jnp.minimum(bi, last_id)
+
+
+def mesh_exchange_bytes(n_shards, rows, rank, k_loc):
+    """Bytes one device moves for one batch of the mesh engine's scoring
+    program, by ``parallel.comm_audit``'s conventions (a test pins this
+    to the traced program's): the by-id lookup's ``psum`` of the
+    ``[rows, rank]`` f32 queries, a bidirectional-ring all-reduce,
+    ``2 (S-1)/S`` of them, and the merge's two ``all_gather``s of the
+    local ``[rows, k_loc]`` f32 scores and int32 ids, ``(S-1)/S`` of the
+    gathered ``[S, rows, k_loc]`` each."""
+    S = int(n_shards)
+    return (2 * (S - 1) * rows * rank * 4 // S
+            + 2 * (S - 1) * rows * k_loc * 4)
+
+
 @functools.lru_cache(maxsize=32)
 def _build_sharded_int8(mesh, k, k_loc, sk_loc, ni_loc, has_delta):
-    """shard_map'd int8 shortlist + exact rescore, one program per shard.
-
-    Each shard runs the SAME shortlist→rescore pipeline as
-    :func:`_int8_topk` / :func:`_int8_topk_delta` over its catalog slice
-    only — no shard ever sees another's rows, so nothing here reads the
-    full table.  The (tiny, replicated) delta segment is scored by every
-    shard but masked to the rows it OWNS (``row // ni_loc == me``), so
-    each delta row is scored exactly once mesh-wide.  Per-shard local
-    top-``k_loc`` lands as a stacked ``[S, n, k_loc]`` output the final
-    (out-of-shard-map, same jit) merge concatenates in shard order and
-    reduces with one stable ``lax.top_k`` — ``S*k_loc`` values per
-    query, never a per-shard candidate LIST in host memory.
-    """
+    """shard_map'd int8 shortlist + exact rescore for a batch of query
+    VECTORS: :func:`_shard_score` per shard, :func:`_shard_merge` on
+    every shard, one program.  (The engine's whole request path, from
+    the staged batch to the packed response, is the same two functions
+    behind a by-id lookup: ``serving.engine._build_mesh_serve``.)"""
     from tpu_als.parallel.mesh import AXIS, shard_map
 
     P = jax.sharding.PartitionSpec
-    D = int(mesh.devices.size)
 
-    def body(U, Vq, sv, V, valid, *delta):
-        me = jax.lax.axis_index(AXIS)
-        n = U.shape[0]
-        Uq, su = _quantize_rows(U)
-        acc = jnp.einsum("nr,cr->nc", Uq, Vq,
-                         preferred_element_type=jnp.int32)
-        approx = acc.astype(jnp.float32) * su[:, None] * sv[None, :]
-        if has_delta:
-            drows, dVq, dsv, dV, dvalid = delta
-            d = dVq.shape[0]
-            idx = drows - me * ni_loc          # local slot, if owned
-            owned = (idx >= 0) & (idx < ni_loc)
-            # overridden base rows mask regardless of dvalid (a delta
-            # row may mark an item invalid); ni_loc is the OOB sentinel
-            over = jnp.zeros((ni_loc,), jnp.bool_).at[
-                jnp.where(owned, idx, ni_loc)].set(True, mode="drop")
-            base_ok = valid & ~over
-            approx = jnp.where(base_ok[None, :], approx, NEG_INF)
-            dmask = dvalid & owned
-            acc_d = jnp.einsum("nr,cr->nc", Uq, dVq,
-                               preferred_element_type=jnp.int32)
-            approx_d = (acc_d.astype(jnp.float32)
-                        * su[:, None] * dsv[None, :])
-            approx_d = jnp.where(dmask[None, :], approx_d, NEG_INF)
-            approx = jnp.concatenate([approx, approx_d], axis=1)
-        else:
-            base_ok = valid
-            approx = jnp.where(base_ok[None, :], approx, NEG_INF)
-        _, cand = shortlist_topk(approx, sk_loc)
-        flat = cand.reshape(-1)
-        if has_delta:
-            in_base = flat < ni_loc
-            base_ix = jnp.minimum(flat, ni_loc - 1)
-            delta_ix = jnp.clip(flat - ni_loc, 0, d - 1)
-            Vc = jnp.where(in_base[:, None],
-                           jnp.take(V, base_ix, axis=0),
-                           jnp.take(dV, delta_ix, axis=0))
-        else:
-            Vc = jnp.take(V, flat, axis=0)
-        exact_all = jnp.einsum("nr,cr->nc", U, Vc,
-                               preferred_element_type=jnp.float32)
-        pos = (jnp.arange(n, dtype=jnp.int32)[:, None] * sk_loc
-               + jnp.arange(sk_loc, dtype=jnp.int32)[None, :])
-        exact = jnp.take_along_axis(exact_all, pos, axis=1)
-        if has_delta:
-            cand_ok = jnp.where(in_base, jnp.take(base_ok, base_ix),
-                                jnp.take(dmask, delta_ix))
-            gid = jnp.where(in_base, flat + me * ni_loc,
-                            jnp.take(drows, delta_ix))
-        else:
-            cand_ok = jnp.take(base_ok, flat)
-            gid = flat + me * ni_loc
-        exact = jnp.where(cand_ok.reshape(n, sk_loc), exact, NEG_INF)
-        s, sel = jax.lax.top_k(exact, k_loc)
-        gids = jnp.take_along_axis(gid.reshape(n, sk_loc), sel, axis=1)
-        return s[None], gids.astype(jnp.int32)[None]
+    def sharded_int8_topk(U, Vq, sv, V, valid, last_id, *delta):
+        s, gids = _shard_score(
+            U, Vq, sv, V, valid, delta, me=jax.lax.axis_index(AXIS),
+            k_loc=k_loc, sk_loc=sk_loc, ni_loc=ni_loc)
+        return _shard_merge(s, gids, last_id, axis=AXIS, k=k)
 
     delta_specs = (P(),) * 5 if has_delta else ()
-    sharded = shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS)) + delta_specs,
-        out_specs=(P(AXIS), P(AXIS)), check_vma=False)
+    return jax.jit(shard_map(
+        sharded_int8_topk, mesh=mesh,
+        in_specs=(P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P())
+        + delta_specs,
+        out_specs=(P(), P()), check_vma=False))
 
-    def merged(U, Vq, sv, V, valid, last_id, *delta):
-        s, ix = sharded(U, Vq, sv, V, valid, *delta)
-        n = U.shape[0]
-        cat_s = jnp.transpose(s, (1, 0, 2)).reshape(n, D * k_loc)
-        cat_i = jnp.transpose(ix, (1, 0, 2)).reshape(n, D * k_loc)
-        if D * k_loc < k:      # tiny shards: pad so top_k(k) is legal
-            pad = k - D * k_loc
-            cat_s = jnp.pad(cat_s, ((0, 0), (0, pad)),
-                            constant_values=NEG_INF)
-            cat_i = jnp.pad(cat_i, ((0, 0), (0, pad)))
-        bs, sel = jax.lax.top_k(cat_s, k)
-        bi = jnp.take_along_axis(cat_i, sel, axis=1)
-        return bs, jnp.minimum(bi, last_id)
 
-    return jax.jit(merged)
+def place_catalog(V, item_valid, mesh, shortlist_k=64):
+    """``(V, item_valid, n_items)`` with the host's catalog sharded by
+    rows over ``mesh``: ``ceil(n_items / D)`` rows a shard, rounded up to
+    whole shortlist blocks (``ops.topk.shortlist_columns``, as the
+    one-device index pads its quantized rows: no batch pays for a ragged
+    last block), the rows past the catalog zero and invalid.  Each
+    shard's rows go up a chunk at a time into a zero table on its own
+    device (``core.foldin.place_rows``), so the host never pads a copy
+    of the catalog and no device holds more than its shard and one
+    chunk."""
+    from tpu_als.core.foldin import place_rows
+    from tpu_als.parallel.mesh import shard_leading
+
+    V = np.asarray(V, dtype=np.float32)
+    Ni, D = int(V.shape[0]), int(mesh.devices.size)
+    if Ni == 0:
+        raise ValueError("cannot index an empty catalog")
+    ni_loc = -(-Ni // D)
+    cap = D * shortlist_columns(ni_loc, min(int(shortlist_k), ni_loc))
+    valid = (np.ones(Ni, dtype=bool) if item_valid is None
+             else np.asarray(item_valid, dtype=bool).ravel())
+    return (place_rows(V, capacity=cap, mesh=mesh),
+            jax.device_put(np.pad(valid, (0, cap - Ni)),
+                           shard_leading(mesh)), Ni)
 
 
 class ShardedInt8Index(Int8CandidateIndex):
     """:class:`Int8CandidateIndex` with the catalog SHARDED over a mesh.
 
-    Build/publish places each shard's quantized slice device-resident —
-    the base arrays are padded to ``n_shards * ni_loc`` and placed with
-    ``jax.device_put(..., shard_leading(mesh))``, which transfers each
-    host slice to its own device; the full table is never committed to
-    any single device (same placement discipline as
-    ``parallel.serve.topk_sharded``).  Quantization runs jitted on the
-    already-sharded array — per-row, so it stays sharded and each device
-    quantizes only its slice.
+    Build/publish places each shard's slice device-resident
+    (:func:`place_catalog`: ``n_shards * ni_loc`` rows, shard ``s``
+    holding catalog ids ``[s * ni_loc, (s + 1) * ni_loc)``, ``ni_loc``
+    in whole shortlist blocks, uploaded a chunk at a time); the full
+    table is never committed to any single
+    device and never copied on the host.  Quantization runs jitted on
+    the already-sharded array — per-row, so it stays sharded and each
+    device quantizes only its slice.
 
     The PR 11 live pipeline composes unchanged: :meth:`with_updates`
     inherits the base's host-side delta merge (O(touched) per publish,
@@ -579,28 +629,24 @@ class ShardedInt8Index(Int8CandidateIndex):
     single-device int8 index.
     """
 
-    def __init__(self, V, mesh, item_valid=None, shortlist_k=64, seq=0):
-        from tpu_als.parallel.mesh import shard_leading
-
-        V = np.asarray(V, dtype=np.float32)
-        Ni = int(V.shape[0])
-        if Ni == 0:
-            raise ValueError("cannot index an empty catalog")
-        D = int(mesh.devices.size)
-        ni_loc = -(-Ni // D)
-        cap = D * ni_loc
-        valid = (np.ones(Ni, dtype=bool) if item_valid is None
-                 else np.asarray(item_valid, dtype=bool).ravel())
-        spec = shard_leading(mesh)
+    def __init__(self, V, mesh, item_valid=None, shortlist_k=64, seq=0,
+                 n_items=None):
+        """``V``: the host's catalog, placed by :func:`place_catalog` —
+        or, with ``n_items``, that function's result (``item_valid``
+        with it), which the index then shares with whoever placed it
+        (the engine: its exact fallback scores the same buffers)."""
+        if n_items is None:
+            V, item_valid, n_items = place_catalog(V, item_valid, mesh,
+                                                   shortlist_k)
         self.mesh = mesh
-        self.n_shards = D
-        self.ni_loc = ni_loc
-        self.V = jax.device_put(np.pad(V, ((0, cap - Ni), (0, 0))), spec)
-        self.valid = jax.device_put(np.pad(valid, (0, cap - Ni)), spec)
-        self.Vq, self.sv = _quantize_rows(self.V)
-        self.n_items = Ni
-        self.shortlist_k = min(int(shortlist_k), Ni)
+        self.n_shards = int(mesh.devices.size)
+        self.ni_loc = int(V.shape[0]) // self.n_shards
+        self.V, self.valid = V, item_valid
+        self.Vq, self.sv = _quantize_rows(V)
+        self.n_items = int(n_items)
+        self.shortlist_k = min(int(shortlist_k), self.n_items)
         self.seq = seq
+        self._last = None
         self._clear_delta()
 
     def shortlist_plan(self):
@@ -611,6 +657,7 @@ class ShardedInt8Index(Int8CandidateIndex):
         new.mesh = self.mesh
         new.n_shards = self.n_shards
         new.ni_loc = self.ni_loc
+        new._last = None
 
     @property
     def capacity(self):
@@ -688,16 +735,30 @@ class ShardedInt8Index(Int8CandidateIndex):
                 f"k={k} exceeds shortlist_k={sk}; the shortlist must "
                 "contain at least k candidates")
         U = jnp.asarray(U, dtype=jnp.float32)
-        has_delta = bool(self.delta_count)
-        sk_loc = min(sk, self.ni_loc + self.delta_slots)
-        k_loc = min(int(k), sk_loc)
+        k_loc, sk_loc = self.shard_widths(k, sk)
         fn = _build_sharded_int8(self.mesh, int(k), k_loc, sk_loc,
-                                 self.ni_loc, has_delta)
-        last = jnp.int32(self.n_items - 1)
-        if has_delta:
-            return fn(U, self.Vq, self.sv, self.V, self.valid, last,
-                      *self._device_delta())
-        return fn(U, self.Vq, self.sv, self.V, self.valid, last)
+                                 self.ni_loc, bool(self.delta_count))
+        return fn(U, *self.score_args())
+
+    def shard_widths(self, k, shortlist_k=None):
+        """``(k_loc, sk_loc)``: how many answers, of how long a
+        shortlist, one shard gives for a query."""
+        sk = self.shortlist_k if shortlist_k is None else shortlist_k
+        sk_loc = min(sk, self.ni_loc + self.delta_slots)
+        return min(int(k), sk_loc), sk_loc
+
+    def score_args(self):
+        """What a sharded scoring program takes after its queries: the
+        four sharded base arrays, the last catalog id (answers are
+        clamped to it), and the replicated delta segment if there is
+        one."""
+        if self._last is None or self._last[0] != self.n_items:
+            self._last = (self.n_items, jax.device_put(
+                np.int32(self.n_items - 1), jax.sharding.NamedSharding(
+                    self.mesh, jax.sharding.PartitionSpec())))
+        delta = self._device_delta() if self.delta_count else ()
+        return (self.Vq, self.sv, self.V, self.valid, self._last[1],
+                *delta)
 
 
 def build_sharded_index(V, mesh, item_valid=None, shortlist_k=64, seq=0):

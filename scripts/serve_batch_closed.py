@@ -9,9 +9,16 @@ waited ``max_wait_s`` when the engine thread came back; ``wait``: the
 rest of its window ran out; ``full``; ``closed`` — with the head's age
 on arrival and the engine thread's wait for the user table's lock
 (``lock_wait``: median, mean, longest, in ms — a window that stood still
-with none of it did not stand behind a row write), the process's
-``serving.batch_closed`` counters against
-the batches the engine served, and what its ``publish_update``s did to
+with none of it did not stand behind a row write), how many of the
+window's batches were dispatched while the batch before was still being
+read back (``in_flight``: the count and share of each value, as
+``serving.batch_overlap`` labels them), the engine thread's wait for one
+of two batches in flight to complete (``handoff_wait``: batches with
+any, then median, mean, longest in ms), the batch's own wait for the
+completion thread (``completion_wait``: its whole life less its four
+phases) and that thread's wait for a batch (``completion_idle``), the
+process's ``serving.batch_closed`` and ``serving.batch_overlap``
+counters against the batches the engine served, and what its ``publish_update``s did to
 the device's user table (``serving.user_table_writes``: a live cell
 counts ``inplace`` alone), and for an engine given a mesh its
 ``serving_mesh_plan`` events (one a bucket ``warmup()`` pinned) with
@@ -33,6 +40,14 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WAYS = ("age", "wait", "full", "closed")
+PHASES = ("serve.batch.stage", "serve.batch.dispatch",
+          "serve.batch.readback", "serve.batch.complete")
+
+
+def three(values):
+    """Median, mean and longest."""
+    values = list(values)
+    return [st.median(values), st.mean(values), max(values)]
 
 
 def shares(records, seconds):
@@ -43,8 +58,16 @@ def shares(records, seconds):
            "rows_mean": st.mean(r["rows"] for r in window),
            "head_wait_ms_median": 1e3 * st.median(
                r["head_wait"] for r in window)}
-    lock = [1e3 * r["lock_wait"] for r in window]
-    row["lock_wait_ms"] = [st.median(lock), st.mean(lock), max(lock)]
+    for name in ("lock_wait", "handoff_wait", "completion_idle"):
+        row[name + "_ms"] = three(1e3 * r[name] for r in window)
+    row["handoff_waits"] = sum(r["handoff_wait"] > 0 for r in window)
+    row["completion_wait_ms"] = three(
+        1e3 * (r["spans"]["serve.batch"] - sum(r["spans"][p] for p in PHASES))
+        for r in window)
+    flying = collections.Counter(r["in_flight"] for r in window)
+    for n in sorted(flying):
+        row[f"in_flight_{n}"] = flying[n]
+        row[f"in_flight_{n}_pct"] = 100.0 * flying[n] / len(window)
     for way in WAYS:
         row[way] = by[way]
         row[way + "_pct"] = 100.0 * by[way] / len(window)
@@ -83,6 +106,9 @@ def main(argv):
     print(json.dumps({"serving.batch_closed": counted,
                       "sum": sum(counted.values()),
                       "batches_served": engines[0]._batch_seq}), flush=True)
+    print(json.dumps({"serving.batch_overlap": {
+        str(n): obs.counter_value("serving.batch_overlap", in_flight=n)
+        for n in (0, 1)}}), flush=True)
     writes = {how: obs.counter_value("serving.user_table_writes", how=how)
               for how in ("inplace", "replaced", "carried")}
     print(json.dumps({"serving.user_table_writes": writes,

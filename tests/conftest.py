@@ -7,6 +7,8 @@ before any ``import jax`` in the test session.
 """
 
 import os
+import threading
+import time
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -117,3 +119,61 @@ def _bound_live_executables_per_module(request):
                 and item.get_closest_marker("slow") is not None):
             jax.clear_caches()
             return
+
+
+class GatedResponses:
+    """Stands between a ``ServingEngine``'s dispatch and its readback:
+    every batch the engine dispatches comes back as a stub whose
+    ``__array__`` (what ``np.asarray`` calls: the engine's one
+    device→host transfer) blocks until the test releases it, so a test
+    decides when each batch's readback ends, and with what.
+
+    ``gates[i]`` belongs to the i-th dispatch; ``gates[i].entered`` is
+    set once its readback has begun; ``release(i)`` lets it return the
+    real response, ``release(i, error)`` makes it raise; ``open()``
+    releases every gate there is and every later one at its birth."""
+
+    class _Gate:
+        def __init__(self, resp):
+            self.resp, self.error = resp, None
+            self.entered, self.released = (threading.Event(),
+                                           threading.Event())
+
+        def __array__(self, dtype=None, copy=None):
+            self.entered.set()
+            if not self.released.wait(30.0):
+                raise TimeoutError("the test never released this readback")
+            if self.error is not None:
+                raise self.error
+            return np.asarray(self.resp)
+
+    def __init__(self, eng):
+        self.gates, self._open = [], False
+        dispatch = eng._dispatch
+
+        def gated(*args):
+            resp, path, fell_back = dispatch(*args)
+            gate = self._Gate(resp)
+            if self._open:
+                gate.released.set()
+            self.gates.append(gate)
+            return gate, path, fell_back
+
+        eng._dispatch = gated
+
+    def release(self, i, error=None):
+        self.gates[i].error = error
+        self.gates[i].released.set()
+
+    def open(self):
+        self._open = True
+        for gate in self.gates:
+            gate.released.set()
+
+    def wait_dispatched(self, n, timeout=10.0):
+        """Until ``n`` batches have been dispatched."""
+        deadline = time.monotonic() + timeout
+        while len(self.gates) < n:
+            assert time.monotonic() < deadline, (
+                f"{len(self.gates)} batches dispatched, not {n}")
+            time.sleep(0.001)

@@ -21,6 +21,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from tests.conftest import GatedResponses
 from tpu_als import obs, plan
 from tpu_als.api.estimator import ALSModel
 from tpu_als.core.ratings import IdMap
@@ -253,6 +254,48 @@ def test_publish_update_retag_delta_compact_modes(rng, _fresh):
         _fresh.histogram_count("serving.publish_seconds", mode=m)
         for m in ("full", "retag", "delta", "compact", "none"))
     assert priced >= 4
+
+
+@pytest.mark.parametrize("publishes", [1, 3])
+def test_publish_between_two_batches_in_flight_gives_each_its_generation(
+        rng, _fresh, publishes):
+    """Batch A is dispatched and its readback held; ``publishes`` row
+    writes rewrite user 3 (each donates the table A was dispatched
+    with); batch B is dispatched behind them while A is still in
+    flight.  A answers from the generation it was dispatched with, B
+    reads the writes, and the untouched user's answers are bit-equal."""
+    eng, U, V = _published_engine(rng)
+    gated = GatedResponses(eng)
+    with eng:
+        with eng._table_lock:           # both requests into ONE batch
+            A = [eng.submit(3), eng.submit(5)]
+            time.sleep(0.05)
+        gated.wait_dispatched(1)
+        assert gated.gates[0].entered.wait(10.0)
+        U2 = U.copy()
+        for g in range(1, publishes + 1):
+            U2[3] = -g * U[3]
+            seq, mode = eng.publish_update(U2, V, touched_users=[3])
+            assert mode == "retag"
+        with eng._table_lock:
+            B = [eng.submit(3), eng.submit(5)]
+            time.sleep(0.05)
+        gated.wait_dispatched(2)        # behind the writes, A not read back
+        assert not A[0].done()
+        gated.open()
+        (sa3, ia3), (sa5, ia5) = (t.result(timeout=10.0) for t in A)
+        (sb3, ib3), (sb5, ib5) = (t.result(timeout=10.0) for t in B)
+    assert _fresh.counter_value("serving.user_table_writes",
+                                how="inplace") == publishes
+    np.testing.assert_array_equal(sa5, sb5)         # untouched: the same
+    np.testing.assert_array_equal(ia5, ib5)         # bits from both
+    full = V.astype(np.float64) @ U[3].astype(np.float64)
+    np.testing.assert_allclose(sa3, np.sort(full)[::-1][:5], rtol=1e-4)
+    np.testing.assert_allclose(                     # read-your-writes
+        sb3, np.sort(-publishes * full)[::-1][:5], rtol=1e-4)
+    assert not np.array_equal(ia3, ib3)
+    recs = eng.batch_flight.records()
+    assert [(r["rows"], r["in_flight"]) for r in recs] == [(2, 0), (2, 1)]
 
 
 def test_publish_update_delta_serves_bitwise_vs_rebuild(rng):

@@ -10,6 +10,8 @@ CLI).
 """
 
 import json
+import os
+import sys
 import threading
 import time
 
@@ -18,7 +20,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from tests.conftest import assert_topk_within_contract
+from tests.conftest import GatedResponses, assert_topk_within_contract
 from tpu_als import obs
 from tpu_als.ops.topk import NEG_INF, chunked_topk_scores, topk_validity
 from tpu_als.resilience import faults
@@ -540,6 +542,326 @@ def test_engine_small_catalog_skips_index(rng):
     _drain_one(eng)
     s, _ = t.result(timeout=1.0)
     assert topk_validity(s).sum() == 6
+
+
+# ---------------------------------------------------------------------------
+# two batches in flight (ISSUE 33): the engine thread dispatches, a
+# completion thread reads back.  GatedResponses holds each batch's readback
+# until the test lets it go; with ``max_wait_s`` 0 a lone request is a batch.
+
+
+def _one_batch_each(eng, gated, payloads):
+    """Submit ``payloads`` one at a time, each only once the one before
+    has been dispatched: a batch apiece, in this order."""
+    tickets = []
+    for j, payload in enumerate(payloads):
+        tickets.append(eng.submit(payload))
+        gated.wait_dispatched(j + 1)
+    return tickets
+
+
+def test_next_batch_is_dispatched_while_the_last_is_read_back(rng, _fresh):
+    eng, U, V = _engine(rng)
+    gated = GatedResponses(eng)
+    with eng:
+        a = eng.submit(3)
+        gated.wait_dispatched(1)
+        assert gated.gates[0].entered.wait(10.0)   # a's readback: blocked
+        b = eng.submit(4)
+        gated.wait_dispatched(2)                   # and b is on the device
+        assert not a.done() and not b.done()
+        assert b.t_dequeue is not None
+        # the completion thread holds neither the table's lock nor a
+        # table: a publisher gets in while it sits in a readback
+        assert eng._table_lock.acquire(timeout=5.0)
+        eng._table_lock.release()
+        gated.open()
+        ref_s, ref_ix = _exact(U[3:5], V, np.ones(V.shape[0], bool), eng.k)
+        for j, t in enumerate((a, b)):
+            s, ix = t.result(timeout=10.0)
+            np.testing.assert_allclose(s, ref_s[j], rtol=1e-5, atol=1e-6)
+            np.testing.assert_array_equal(ix, ref_ix[j])
+    recs = eng.batch_flight.records()
+    assert [r["in_flight"] for r in recs] == [0, 1]
+    assert [r["handoff_wait"] for r in recs] == [0.0, 0.0]
+    assert {n: _fresh.counter_value("serving.batch_overlap", in_flight=n)
+            for n in (0, 1, 2)} == {0: 1, 1: 1, 2: 0}
+    # b waited for the completion thread (it was in a's readback): its
+    # whole life is longer than its four phases; a's is not, but by a hop
+    phases = ("serve.batch.stage", "serve.batch.dispatch",
+              "serve.batch.readback", "serve.batch.complete")
+    for r in recs:
+        assert r["spans"]["serve.batch"] >= sum(r["spans"][p]
+                                                for p in phases)
+        assert r["completion_idle"] >= 0.0
+    assert recs[1]["completion_idle"] == pytest.approx(0.0, abs=0.05)
+
+
+def test_each_batch_in_flight_is_staged_into_an_array_of_its_own(rng):
+    """The upload may read the host's buffer after ``device_put`` has
+    returned (on the CPU the device array IS the buffer): the next batch
+    of the bucket must not be staged over it."""
+    eng, U, V = _engine(rng)
+    staged, inner = [], eng._dispatch
+
+    def keeping(m, st, B, mode):
+        staged.append(st)
+        return inner(m, st, B, mode)
+
+    eng._dispatch = keeping
+    gated = GatedResponses(eng)
+    with eng:
+        a, b = _one_batch_each(eng, gated, (3, 4))
+        rank = U.shape[1]
+        assert not np.shares_memory(staged[0], staged[1])
+        assert (staged[0][0, rank], staged[1][0, rank]) == (3, 4)
+        gated.open()
+        ref_s, _ = _exact(U[3:5], V, np.ones(V.shape[0], bool), eng.k)
+        np.testing.assert_allclose(a.result(timeout=10.0)[0], ref_s[0],
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(b.result(timeout=10.0)[0], ref_s[1],
+                                   rtol=1e-5, atol=1e-6)
+
+
+def test_a_third_batch_waits_for_one_of_two_in_flight(rng, _fresh):
+    eng, _, _ = _engine(rng)
+    gated = GatedResponses(eng)
+    with eng:
+        a, b = _one_batch_each(eng, gated, (0, 1))
+        c = eng.submit(2)
+        time.sleep(0.2)
+        # two in flight: c is neither dispatched nor even dequeued
+        assert len(gated.gates) == 2 and c.t_dequeue is None
+        assert eng._handed - eng._completed == 2
+        gated.release(0)
+        gated.wait_dispatched(3)                   # a completed: c goes
+        assert a.result(timeout=10.0) and not b.done()
+        gated.open()
+        assert b.result(timeout=10.0) and c.result(timeout=10.0)
+    recs = eng.batch_flight.records()
+    assert [r["in_flight"] for r in recs] == [0, 1, 1]
+    assert recs[0]["handoff_wait"] == recs[1]["handoff_wait"] == 0.0
+    assert 0.15 < recs[2]["handoff_wait"] < 5.0
+    # c queued meanwhile: the wait is in its queue wait, as a request
+    # behind a busy engine's always was
+    assert 0.15 < c.t_dequeue - c.t_submit <= recs[2]["handoff_wait"]
+    assert _fresh.counter_value("serving.batch_overlap", in_flight=2) == 0
+
+
+def test_completions_arrive_in_dispatch_order(rng):
+    eng, _, _ = _engine(rng)
+    gated = GatedResponses(eng)
+    with eng:
+        a, b = _one_batch_each(eng, gated, (0, 1))
+        gated.release(1)                # the later batch's response first
+        time.sleep(0.1)
+        assert not b.done() and not gated.gates[1].entered.is_set()
+        gated.release(0)
+        sa, _ = a.result(timeout=10.0)
+        sb, _ = b.result(timeout=10.0)
+        assert a.t_done <= b.t_done
+    assert [r["batch"] for r in eng.batch_flight.records()] == [1, 2]
+    # each ticket holds views of its OWN batch's buffer
+    assert sa.base is not None and sb.base is not None
+    assert not np.shares_memory(sa, sb)
+
+
+@pytest.mark.parametrize("half", ["dispatch", "readback"])
+def test_an_error_in_either_half_fails_that_batch_alone(rng, _fresh, half):
+    eng, U, V = _engine(rng)
+    gated = GatedResponses(eng)
+    boom = RuntimeError("boom in " + half)
+    if half == "dispatch":
+        inner, calls = eng._dispatch, []
+
+        def first_raises(*args):
+            calls.append(1)
+            if len(calls) == 1:
+                raise boom
+            return inner(*args)
+
+        eng._dispatch = first_raises
+    with eng:
+        if half == "dispatch":
+            a = eng.submit(0)
+            with pytest.raises(RuntimeError, match="boom in dispatch"):
+                a.result(timeout=10.0)
+            b = eng.submit(1)
+            gated.open()
+        else:
+            a, b = _one_batch_each(eng, gated, (0, 1))
+            gated.release(1)
+            gated.release(0, error=boom)
+            with pytest.raises(RuntimeError, match="boom in readback"):
+                a.result(timeout=10.0)
+        s, ix = b.result(timeout=10.0)             # the next is answered
+        ref_s, _ = _exact(U[1:2], V, np.ones(V.shape[0], bool), eng.k)
+        np.testing.assert_allclose(s, ref_s[0], rtol=1e-5, atol=1e-6)
+        # and the loop goes on, with both slots free again
+        gated.open()
+        assert eng.recommend(2, timeout=10.0)[0].shape == (5,)
+    assert eng._handed == eng._completed
+    warn = [e for e in _fresh._events if e["type"] == "warning"]
+    assert len(warn) == 1 and warn[0]["what"] == "serving.batch" \
+        and "boom in " + half in warn[0]["reason"]
+    failed = [r for r in eng.flight.records() if r["status"] == "failed"]
+    assert len(failed) == 1 and failed[0]["error"] == "RuntimeError"
+    assert [r["status"] for r in eng.batch_flight.records()] == ["ok", "ok"]
+
+
+@pytest.mark.parametrize("what", ["expired", "score_fault", "readback"])
+def test_batches_that_never_complete_normally_give_their_slot_back(
+        rng, what):
+    """More such batches in a row than there are slots, then a request
+    that must still be answered."""
+    eng, _, _ = _engine(rng)
+    gated = GatedResponses(eng)
+    if what == "score_fault":
+        faults.install("serving.score=raise@every=1")
+    with eng:
+        for j in range(3):
+            if what == "expired":
+                t = eng.submit(j, deadline_s=0.0)
+                with pytest.raises(DeadlineExceeded):
+                    t.result(timeout=10.0)
+            elif what == "score_fault":
+                with pytest.raises(InjectedFault):
+                    eng.recommend(j, timeout=10.0)
+            else:
+                t = eng.submit(j)
+                gated.wait_dispatched(j + 1)
+                gated.release(j, error=ValueError("torn transfer"))
+                with pytest.raises(ValueError, match="torn"):
+                    t.result(timeout=10.0)
+        faults.clear()
+        gated.open()
+        assert eng.recommend(5, timeout=10.0)[0].shape == (5,)
+    assert eng._handed == eng._completed == (4 if what == "readback" else 1)
+
+
+def test_stop_answers_what_is_queued_and_what_is_in_flight(rng):
+    eng, U, V = _engine(rng)
+    gated = GatedResponses(eng)
+    eng.start()
+    a, b = _one_batch_each(eng, gated, (0, 1))     # two in flight
+    queued = [eng.submit(j) for j in (2, 3, 4)]    # and three admitted
+    engine_thread, completer = eng._thread, eng._completer
+    stopper = threading.Thread(target=eng.stop)
+    stopper.start()
+    time.sleep(0.05)
+    assert stopper.is_alive()                      # draining, not dropping
+    with pytest.raises(RuntimeError, match="closed"):
+        eng.submit(5)
+    gated.open()
+    stopper.join(10.0)
+    assert not stopper.is_alive()
+    assert not engine_thread.is_alive() and not completer.is_alive()
+    ref_s, _ = _exact(U[:5], V, np.ones(V.shape[0], bool), eng.k)
+    for j, t in enumerate([a, b] + queued):
+        assert t.done()
+        np.testing.assert_allclose(t.result(timeout=0)[0], ref_s[j],
+                                   rtol=1e-5, atol=1e-6)
+    assert eng._handed == eng._completed
+
+
+def test_stop_gives_up_at_its_timeout_on_both_threads_together(rng):
+    eng, _, _ = _engine(rng)
+    gated = GatedResponses(eng)
+    eng.start()
+    a, = _one_batch_each(eng, gated, (0,))
+    completer = eng._completer
+    t0 = time.monotonic()
+    eng.stop(drain_timeout_s=0.3)                  # a's readback never ends
+    assert 0.25 < time.monotonic() - t0 < 2.0      # one budget, not two
+    assert completer.is_alive() and not a.done()
+    gated.open()
+    completer.join(10.0)
+    assert not completer.is_alive() and a.done()
+
+
+@pytest.mark.parametrize("rows", [1, 5, 20, 32])
+def test_threads_and_serve_batch_answer_bit_equal(rng, rows):
+    """The same batch through the two threads and through a synchronous
+    ``serve_batch``: one implementation, so the same bits."""
+    answers = []
+    for threaded in (False, True):
+        eng = ServingEngine(k=5, buckets=(8, 32), shortlist_k=32,
+                            max_wait_s=0.3)
+        r = np.random.default_rng(7)
+        U = r.normal(size=(40, 8)).astype(np.float32)
+        V = r.normal(size=(300, 8)).astype(np.float32)
+        eng.publish(U, V)
+        payloads = [j if j % 3 else U[j] * 0.5 for j in range(rows)]
+        if threaded:
+            with eng:
+                tickets = [eng.submit(p) for p in payloads]
+                got = [t.result(timeout=10.0) for t in tickets]
+        else:
+            tickets = [eng.submit(p) for p in payloads]
+            eng.serve_batch(eng.batcher.next_batch(timeout=1.0))
+            got = [t.result(timeout=0) for t in tickets]
+        rec, = eng.batch_flight.records()
+        assert rec["rows"] == rows and rec["in_flight"] == 0
+        answers.append(got)
+    for (s0, i0), (s1, i1) in zip(*answers):
+        np.testing.assert_array_equal(s0, s1)
+        np.testing.assert_array_equal(i0, i1)
+
+
+def test_many_submitters_against_the_two_threads(rng):
+    """Stress: more submitting threads than cores, the interpreter
+    changing hands every 100 us.  Every request is answered with its own
+    user's scores, never more than two batches are in flight, and
+    batches complete in the order they were dispatched."""
+    eng = ServingEngine(k=5, buckets=(8, 32), shortlist_k=32,
+                        max_wait_s=0.0005, flight_capacity=1 << 14)
+    U = rng.normal(size=(40, 8)).astype(np.float32)
+    V = rng.normal(size=(300, 8)).astype(np.float32)
+    eng.publish(U, V)
+    eng.warmup()
+    ref_s, ref_ix = _exact(U, V, np.ones(300, bool), 5)
+    peak, errors = [0], []
+    finish = eng._finish
+
+    def watching(flown, *args):
+        peak[0] = max(peak[0], eng._handed - eng._completed)
+        return finish(flown, *args)
+
+    eng._finish = watching
+    per_thread, n_threads = 150, 2 * (os.cpu_count() or 4)
+
+    def ask(seed):
+        r = np.random.default_rng(seed)
+        try:
+            for _ in range(per_thread):
+                u = int(r.integers(40))
+                s, ix = eng.recommend(u, timeout=30.0)
+                np.testing.assert_allclose(s, ref_s[u], rtol=1e-5,
+                                           atol=1e-6)
+                np.testing.assert_array_equal(ix, ref_ix[u])
+        except Exception as e:      # noqa: BLE001 — reported below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        with eng:
+            threads = [threading.Thread(target=ask, args=(j,))
+                       for j in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(120.0)
+            assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors, errors[:3]
+    recs = eng.batch_flight.records()
+    assert sum(r["rows"] for r in recs) == per_thread * n_threads
+    assert [r["batch"] for r in recs] == list(range(1, len(recs) + 1))
+    assert {r["in_flight"] for r in recs} <= {0, 1}
+    assert 1 <= peak[0] <= 2
+    assert eng._handed == eng._completed == len(recs)
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,7 @@ import jax
 import numpy as np
 import pytest
 
+from tests.conftest import GatedResponses
 from tpu_als import obs
 from tpu_als.obs import tracing
 from tpu_als.obs.schema import SERVE_BATCH_SPAN_KEYS, SERVE_SPAN_KEYS
@@ -108,6 +109,87 @@ def test_phases_lie_inside_their_batch_disjoint_and_cover_it(traced):
     for c, b in zip((s for s in spans if s[0] == "serve.batch.coalesce"),
                     (s for s in spans if s[0] == "serve.batch")):
         assert c[1] + c[2] <= b[1]
+
+
+def _spans_by_line(trace_dir):
+    """``_serve_spans`` with the host line each span sits on:
+    [(name, start_ns, dur_ns, stats, line)]."""
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    spans = []
+    for p, plane in enumerate(jax.profiler.ProfileData.from_file(path).planes):
+        for n, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    spans.append((ev.name, ev.start_ns, ev.duration_ns,
+                                  dict(ev.stats), (p, n)))
+    return sorted(spans, key=lambda s: s[1])
+
+
+def test_started_engine_writes_the_same_spans_from_two_threads(tmp_path):
+    """The engine thread writes ``serve.batch`` around stage + dispatch,
+    the completion thread ``readback`` and ``complete`` with the batch's
+    ``seq``, on a line of its own; batch 2's stage and dispatch lie
+    UNDER batch 1's readback, which is the point; no span has a name the
+    trace readers do not know."""
+    obs.reset()
+    eng = _engine()
+    _drain(eng, 5)                                  # compiled
+    first = eng._batch_seq + 1
+    gated = GatedResponses(eng)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with eng:
+            a = eng.submit(0)
+            gated.wait_dispatched(1)
+            assert gated.gates[0].entered.wait(10.0)
+            b = eng.submit(1)
+            gated.wait_dispatched(2)
+            gated.open()
+            a.result(timeout=10.0), b.result(timeout=10.0)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _spans_by_line(str(tmp_path))
+    assert {s[0] for s in spans} <= set(SERVE_BATCH_SPAN_KEYS)
+    by_seq = {}
+    for name, start, dur, stats, line in spans:
+        if name in ("serve.batch", "serve.batch.readback",
+                    "serve.batch.complete"):
+            assert name not in by_seq.setdefault(stats["seq"], {})
+            by_seq[stats["seq"]][name] = (start, start + dur, line)
+    assert sorted(by_seq) == [first, first + 1]
+    whole = [s for s in spans if s[0] == "serve.batch"]
+    assert [(s[3]["rows"], s[3]["bucket"], s[3]["path"])
+            for s in whole] == [(1, 8, "int8")] * 2
+    for seq, own in by_seq.items():
+        b0, b1, engine_line = own["serve.batch"]
+        inside = [s[0] for s in spans if s[0] in PHASES
+                  and b0 <= s[1] < b1 and s[4] == engine_line]
+        assert inside == list(PHASES[:2])           # stage, dispatch
+        r0, r1, line = own["serve.batch.readback"]
+        c0, c1, cline = own["serve.batch.complete"]
+        assert line == cline != engine_line
+        assert b1 <= r0 and r1 <= c0                # handed over, in order
+    # batch 2 was staged and dispatched while batch 1 was read back
+    r0, r1, _ = by_seq[first]["serve.batch.readback"]
+    b0, b1, _ = by_seq[first + 1]["serve.batch"]
+    assert r0 < b0 and b1 < r1
+    # and completed after it
+    assert by_seq[first]["serve.batch.complete"][1] <= \
+        by_seq[first + 1]["serve.batch.readback"][0]
+    # the records keep the spans' durations; ``serve.batch`` is the whole
+    # life there, for batch 2 far longer than its engine-thread span
+    recs = {r["batch"]: r for r in eng.batch_flight.records()}
+    for seq, own in by_seq.items():
+        for name in ("serve.batch.readback", "serve.batch.complete"):
+            assert recs[seq]["spans"][name] * 1e9 == pytest.approx(
+                own[name][1] - own[name][0], abs=1e6), name
+        life = recs[seq]["spans"]["serve.batch"] * 1e9
+        assert life == pytest.approx(
+            own["serve.batch.complete"][1] - own["serve.batch"][0], abs=1e6)
+    assert [recs[first + j]["in_flight"] for j in (0, 1)] == [0, 1]
 
 
 def test_batch_ring_keeps_the_spans_durations(traced):

@@ -98,6 +98,16 @@ METRICS = {
         "consumer arrived: popped without a wait) | wait (the rest of "
         "the head's max_wait_s ran out) | full (the largest bucket "
         "filled) | closed (the batcher was closing)"),
+    "serving.batch_overlap": (
+        "counter", "batches",
+        "one per batch the engine THREAD dispatched (a synchronous "
+        "serve_batch counts in_flight=0), labeled by the batches it had "
+        "already handed to the completion thread that were not yet "
+        "completed as this dispatch began: in_flight=0 (the device was "
+        "given nothing meanwhile: the serial loop) | 1 (this batch was "
+        "dispatched while the one before was being read back); never "
+        "more, the engine thread waits first (the batch record's "
+        "handoff_wait)"),
     "serving.expired": (
         "counter", "requests",
         "requests whose deadline passed while queued (failed with "
@@ -232,6 +242,7 @@ LABELS = {
     "serving.requests": ("tenant",),
     "serving.shed": ("tenant",),
     "serving.batch_closed": ("by", "tenant"),
+    "serving.batch_overlap": ("in_flight", "tenant"),
     "serving.expired": ("tenant",),
     "serving.fallback_exact": ("tenant",),
     "serving.publishes": ("tenant",),
@@ -288,22 +299,32 @@ TRACE_STATUSES = ("ok", "shed", "expired", "failed", "quarantined")
 # here, stdlib-only, so analysis/vocab.py can assert — jax-free — that
 # they never collide with the record's structural fields or labels)
 SERVE_SPAN_KEYS = ("admission", "queue_wait", "score", "respond")
-# the engine thread's batch cycle: the names of its profiler spans
+# a batch's cycle: the names of its profiler spans
 # (``jax.profiler.TraceAnnotation`` in serving/batcher.py and
 # serving/engine.py, one set per batch, on the device trace's clock) and
 # the keys of the engine's per-batch flight record, which keeps the same
 # durations for whoever runs no profiler.  Trace readers key on these
-# names (benchmark/program_spans.py), never on thread names
+# names (benchmark/program_spans.py), never on thread names.  A started
+# engine writes them from TWO threads (E: the engine thread, ``_run``;
+# C: the completion thread, ``_run_completions``), so a batch's readback
+# may lie under the next batch's stage and dispatch; a synchronous
+# ``serve_batch`` writes all of them on its caller's thread, the four
+# phases inside ``serve.batch``
 SERVE_BATCH_SPAN_KEYS = (
-    "serve.idle",             # blocked on an empty queue
-    "serve.batch.coalesce",   # first request seen -> batch popped
+    "serve.idle",             # E: blocked on an empty queue
+    "serve.batch.coalesce",   # E: first request seen -> batch popped
     #                           (waiting, closed_by, head_wait)
-    "serve.batch",            # all of serve_batch (seq, bucket, rows, path)
-    "serve.batch.stage",      # the wait for the user table's lock, then
+    "serve.batch",            # E: stage + dispatch (seq, bucket, rows,
+    #                           path); synchronous: all of serve_batch.
+    #                           The RECORD's is the batch's whole life,
+    #                           stage to its last ticket's bookkeeping
+    "serve.batch.stage",      # E: the wait for the user table's lock, then
     #                           expiry check + staging into the upload array
-    "serve.batch.dispatch",   # upload + the scoring call, until it returns
-    "serve.batch.readback",   # the one bulk device->host transfer
-    "serve.batch.complete",   # completing the tickets + their bookkeeping
+    "serve.batch.dispatch",   # E: upload + the scoring call, until it
+    #                           returns
+    "serve.batch.readback",   # C: the one bulk device->host transfer (seq)
+    "serve.batch.complete",   # C: completing the tickets + their
+    #                           bookkeeping (seq)
 )
 # inside a mesh engine's ONE scoring program a bucket (serving/engine.py
 # ``_build_mesh_serve`` / ``_build_mesh_exact``) the three steps that
@@ -477,7 +498,13 @@ EVENTS = {
         "closed_by = age|wait|full|closed as serving.batch_closed's "
         "by, head_wait = seconds the batch's oldest request had waited "
         "as the consumer arrived, lock_wait = seconds the engine thread "
-        "waited for the user table's lock, inside serve.batch.stage) "
+        "waited for the user table's lock, inside serve.batch.stage, "
+        "in_flight = 0|1 batches handed over and not yet completed as "
+        "this one was dispatched, as serving.batch_overlap's label, "
+        "handoff_wait = seconds the engine thread waited, before it "
+        "dequeued this batch, for one of two batches in flight to "
+        "complete, completion_idle = seconds the completion thread had "
+        "waited for a batch when this one was handed over) "
         "(obs.trace.FlightRecorder)"),
     "attribution": (
         ("stages", "wall_s_per_iter", "coverage"),

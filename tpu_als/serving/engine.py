@@ -1,11 +1,41 @@
 """Steady-state serving loop: batcher -> scorer -> response.
 
-One background thread drains the :class:`~tpu_als.serving.batcher.
-MicroBatcher`, pads each micro-batch to its bucket, scores it against
-the currently-published model (int8 shortlist + exact rescore when an
-index is live, the exact chunked kernel otherwise) and completes the
-tickets.  The pieces the rest of the stack plugs into:
+Two background threads.  The ENGINE thread drains the
+:class:`~tpu_als.serving.batcher.MicroBatcher`, pads each micro-batch to
+its bucket and dispatches it against the currently-published model (int8
+shortlist + exact rescore when an index is live, the exact chunked
+kernel otherwise); the COMPLETION thread reads each response back and
+completes the tickets, in dispatch order.  The pieces the rest of the
+stack plugs into:
 
+- **Two batches in flight.**  A batch is two halves:
+  :meth:`ServingEngine._begin` (expiry, staging, the scoring call, all
+  under one hold of ``_table_lock``) and :meth:`ServingEngine._finish`
+  (the one device→host transfer, the tickets, their records).
+  ``_run`` does the first and hands the batch over
+  (:class:`_Flown`: the tickets, the response on the device, the stamps
+  taken so far) through a FIFO to ``_run_completions``, which does the
+  second: the engine thread is back at the queue after stage + dispatch
+  and no request waits behind another batch's readback.  At most
+  ``MAX_IN_FLIGHT`` (2) batches are handed over and not yet completed;
+  with two the engine thread waits, before it dequeues, for the older to
+  complete — the admission queue fills meanwhile and the next batch is
+  larger, which is the back-pressure saturation wants.  Whether the
+  overlap engages follows from the traffic alone (is the next batch
+  closed before the last is read back?), and
+  ``serving.batch_overlap{in_flight=0|1}`` counts it.  The completion
+  thread touches neither ``_table_lock`` nor a table.
+  :meth:`ServingEngine.serve_batch` runs both halves on its caller's
+  thread, for schedulers and tests that drive the engine themselves.
+- **Which thread writes which span** (``obs.schema.
+  SERVE_BATCH_SPAN_KEYS``): the engine thread ``serve.idle``,
+  ``serve.batch.coalesce`` (both in the batcher), ``serve.batch``
+  around ``serve.batch.stage`` and ``serve.batch.dispatch``; the
+  completion thread ``serve.batch.readback`` and
+  ``serve.batch.complete``, each with the batch's ``seq``.  What the
+  completion thread waits for when it has nothing is no span (a trace
+  reader counts every ``serve.`` span as a phase): it is the batch
+  record's ``completion_idle``.
 - **Atomic publishes, no recompile.**  :meth:`ServingEngine.publish`
   places the new U/V on device once and swaps a single reference under
   a lock; in-flight batches finish against the old tables, the next
@@ -31,9 +61,10 @@ tickets.  The pieces the rest of the stack plugs into:
   the write reads the old rows whole and a batch dispatched after it
   the new ones; what the donation deletes is only the HOST's handle, so
   one short lock (``_table_lock``) orders the two host-side uses of it:
-  the engine thread holds it from reading the live generation to the
-  scoring call's return, a publisher around the donating call and the
-  swap.  No shape changes, the pinned executables stay valid, and
+  the engine thread holds it from reading the live generation (after
+  the batch's dequeue) to the scoring call's return, a publisher around
+  the donating call and the swap; a batch still in flight was
+  dispatched before the write and reads the old rows.  No shape changes, the pinned executables stay valid, and
   nothing of the catalog crosses host→device; a user-only fold-in re-tags the
   current index (zero quantization), an item fold-in re-quantizes ONLY
   the touched/appended rows into the index's delta segment
@@ -59,13 +90,15 @@ tickets.  The pieces the rest of the stack plugs into:
   is emitted as ``flight_record`` events — so a p99 outlier leaves the
   last N request traces in the obs trail instead of vanishing into a
   histogram bucket.
-- **The batch cycle on the profiler's clock.**  The engine thread's
-  phases — idle, coalesce, stage, dispatch, readback, complete — are
+- **The batch cycle on the profiler's clock.**  A batch's phases —
+  idle, coalesce, stage, dispatch, readback, complete — are
   ``TraceAnnotation`` spans (``obs.schema.SERVE_BATCH_SPAN_KEYS``),
   always on: under ``jax.profiler.trace`` they sit beside the device's
-  row, so every idle gap of the device falls under the phase that held
-  the engine thread.  The same durations go into one record per batch
-  in a second ring, ``batch_flight``, dumped on the same triggers.
+  row, so every idle gap of the device falls under the phases that held
+  the two threads.  The same durations go into one record per batch
+  in a second ring, ``batch_flight``, dumped on the same triggers, with
+  ``in_flight`` and ``handoff_wait`` (the engine thread's wait for one
+  of two batches in flight to complete: the saturation signal).
 - **One algorithm on any number of chips.**  The engine serves the
   int8 shortlist + exact f32 rescore from a candidate index: an
   :class:`~tpu_als.serving.index.Int8CandidateIndex` when ``mesh`` is
@@ -87,8 +120,9 @@ tickets.  The pieces the rest of the stack plugs into:
   shard too (:func:`_build_mesh_exact`): nothing but the staged batch
   and a publish's touched rows is ever uploaded.
 - **Host throughput.**  The request path stages each micro-batch into
-  one reusable per-bucket ``[B, rank+2]`` int32 array (query rows' f32
-  bits | ids | row-mask) and uploads it as ONE transfer — no per-batch
+  one ``[B, rank+2]`` int32 array (query rows' f32 bits | ids |
+  row-mask; a new one every batch, since the batch before may still be
+  in flight from its own) and uploads it as ONE transfer — no per-batch
   id/row/mask re-uploads (the payload is the only host→device traffic).
   Responses come back packed ``[B, 2k]`` (scores' f32 bits | indices) in
   one bulk transfer, and tickets complete with numpy VIEWS sliced from
@@ -104,7 +138,9 @@ tickets.  The pieces the rest of the stack plugs into:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+import queue
 import threading
 import time
 
@@ -144,8 +180,40 @@ from tpu_als.serving.index import (
 )
 
 
+# batches handed to the device and not yet completed, at most: one being
+# read back and one dispatched behind it.  The engine thread waits for
+# the older to complete before it dequeues a third
+MAX_IN_FLIGHT = 2
+
+
 class NoModelPublished(RuntimeError):
     """A request arrived before the first :meth:`ServingEngine.publish`."""
+
+
+@dataclasses.dataclass(slots=True)
+class _Flown:
+    """One dispatched batch on its way from :meth:`ServingEngine._begin`
+    to :meth:`ServingEngine._finish`: the tickets still alive, the packed
+    response on the device, and every stamp and count the first half
+    took, so that the second reads nothing of the engine's state (the
+    engine thread is a batch further by then)."""
+
+    seq: int
+    live: list
+    resp_dev: object
+    bucket: int
+    rows: int
+    path: str
+    fell_back: bool
+    t_stage: float
+    t_locked: float
+    t_dispatch: float
+    last_wait: tuple        # the batcher's (idle_s, waiting, coalesce_s)
+    closed_by: str
+    head_wait: float
+    in_flight: int
+    handoff_wait: float
+    t_flown: float          # the scoring call had returned
 
 
 class _Published:
@@ -410,15 +478,25 @@ class ServingEngine:
         self._table_lock = threading.Lock()
         self._cadence = None            # plan-resolved, on first use
         self._seq = 0
-        self._thread = None
+        self._thread = None             # the engine thread (_run)
+        self._completer = None          # the completion thread
         self._stopping = threading.Event()
+        # what the engine thread hands the completion thread, in
+        # dispatch order; a slot is taken before a batch is dequeued and
+        # given back as it completes, so at most MAX_IN_FLIGHT wait here
+        # or are being read back.  ``_handed`` is written by the engine
+        # thread alone, ``_completed`` by the completion thread alone:
+        # their difference is the number in flight
+        self._handoff = queue.SimpleQueue()
+        self._slots = threading.BoundedSemaphore(MAX_IN_FLIGHT)
+        self._handed = 0
+        self._completed = 0
         self.mesh = mesh
         # what every shard is given whole: the staged batch, a publish's
         # touched rows
         self._replicated = (None if mesh is None else
                             jax.sharding.NamedSharding(
                                 mesh, jax.sharding.PartitionSpec()))
-        self._stage = {}                # bucket -> reusable [B, rank+2]
         self._pinned = {}               # (bucket, path) -> AOT executable
         self._plans = {}                # _mesh_plan's memo
 
@@ -1009,16 +1087,23 @@ class ServingEngine:
         self._stopping.clear()
         self._thread = threading.Thread(
             target=self._run, name="tpu-als-serving", daemon=True)
+        self._completer = threading.Thread(
+            target=self._run_completions, name="tpu-als-serving-readback",
+            daemon=True)
+        self._completer.start()
         self._thread.start()
         return self
 
     def stop(self, drain_timeout_s=5.0):
-        """Close admission, drain in-flight batches, join the loop."""
+        """Close admission, answer what is queued and what is in flight,
+        join both threads, all inside ``drain_timeout_s``."""
         self.batcher.close()
         self._stopping.set()
-        if self._thread is not None:
-            self._thread.join(drain_timeout_s)
-            self._thread = None
+        deadline = time.monotonic() + drain_timeout_s
+        for thread in (self._thread, self._completer):
+            if thread is not None:
+                thread.join(max(0.0, deadline - time.monotonic()))
+        self._thread = self._completer = None
 
     def __enter__(self):
         return self.start()
@@ -1027,136 +1112,214 @@ class ServingEngine:
         self.stop()
 
     def _run(self):
+        """The engine thread: ``next_batch`` → stage → ``_dispatch`` →
+        hand over → ``next_batch``.  It never waits for an answer; with
+        ``MAX_IN_FLIGHT`` batches handed over and not yet completed it
+        waits for the older to complete BEFORE it pops the next, so what
+        arrives meanwhile rides that next batch."""
+        try:
+            while True:
+                handoff_wait = 0.0
+                if not self._slots.acquire(blocking=False):
+                    t_full = time.perf_counter()
+                    self._slots.acquire()
+                    handoff_wait = time.perf_counter() - t_full
+                batch = self.batcher.next_batch(timeout=0.1)
+                if batch is None:
+                    self._slots.release()
+                    if self._stopping.is_set():
+                        return
+                    continue
+                seq = self._batch_seq = self._batch_seq + 1
+                try:
+                    with TraceAnnotation("serve.batch", seq=seq) as whole:
+                        flown = self._begin(batch, seq, whole, handoff_wait)
+                except BaseException as e:  # noqa: BLE001 — tickets must resolve
+                    self._fail(batch, e)
+                    flown = None
+                if flown is None:
+                    self._slots.release()   # nothing went to the device
+                else:
+                    self._handed += 1
+                    self._handoff.put(flown)
+        finally:
+            self._handoff.put(None)         # after the last batch handed over
+
+    def _run_completions(self):
+        """The completion thread: takes what the engine thread handed
+        over, in dispatch order, reads each response back and completes
+        its tickets.  It touches neither ``_table_lock`` nor a table."""
         while True:
-            batch = self.batcher.next_batch(timeout=0.1)
-            if batch is None:
-                if self._stopping.is_set():
-                    return
-                continue
+            t_idle = time.perf_counter()
+            flown = self._handoff.get()
+            if flown is None:
+                return
+            t_taken = time.perf_counter()
             try:
-                self.serve_batch(batch)
-            except BaseException as e:   # noqa: BLE001 — tickets must resolve
-                for t in batch:
-                    if not t.done():
-                        t.fail(e)
-                        if t.trace is not None:
-                            t.trace = tracing.record_span(
-                                t.trace, "serve.score", status="failed",
-                                error=type(e).__name__)
-                        self.flight.record(
-                            "failed",
-                            {"admission": t.t_admit,
-                             "queue_wait": (t.t_dequeue - t.t_submit
-                                            if t.t_dequeue else None)},
-                            error=type(e).__name__,
-                            trace_id=(t.trace.trace_id
-                                      if t.trace is not None else None))
-                if not isinstance(e, faults.InjectedFault):
-                    obs.emit("warning", what="serving.batch",
-                             reason=f"{type(e).__name__}: {e}")
+                self._finish(flown, t_taken, t_taken - t_idle)
+            except BaseException as e:  # noqa: BLE001 — tickets must resolve
+                self._fail(flown.live, e)
+            finally:
+                self._completed += 1
+                self._slots.release()
+
+    def _fail(self, batch, e):
+        """Fail the tickets of ``batch`` that no one has answered with
+        ``e``, each with its trace hop and its flight record; the loop
+        that calls this goes on to the next batch."""
+        for t in batch:
+            if not t.done():
+                t.fail(e)
+                if t.trace is not None:
+                    t.trace = tracing.record_span(
+                        t.trace, "serve.score", status="failed",
+                        error=type(e).__name__)
+                self.flight.record(
+                    "failed",
+                    {"admission": t.t_admit,
+                     "queue_wait": (t.t_dequeue - t.t_submit
+                                    if t.t_dequeue else None)},
+                    error=type(e).__name__,
+                    trace_id=(t.trace.trace_id
+                              if t.trace is not None else None))
+        if not isinstance(e, faults.InjectedFault):
+            obs.emit("warning", what="serving.batch",
+                     reason=f"{type(e).__name__}: {e}")
 
     def serve_batch(self, batch):
-        """Score one dequeued micro-batch and complete its tickets.
+        """Score one dequeued micro-batch and complete its tickets, both
+        halves on the caller's thread: :meth:`_begin`, then
+        :meth:`_finish`, the two the engine's threads run.
 
         Public so tests and synchronous callers can drive the engine
-        without the background thread.  The phases are disjoint spans
+        without the background threads.  The phases are disjoint spans
         on the profiler's timeline (``obs.schema.SERVE_BATCH_SPAN_KEYS``;
         ``serve.batch`` carries ``seq``, ``bucket``, ``rows``, ``path``),
-        and their durations go into one ``batch_flight`` record.
+        all four inside ``serve.batch`` here, and their durations go
+        into one ``batch_flight`` record.
         """
         seq = self._batch_seq = self._batch_seq + 1
-        t_stage = time.perf_counter()
         with TraceAnnotation("serve.batch", seq=seq) as whole:
-            # the live generation is read AFTER the dequeue and its user
-            # table goes to the scoring call under one hold of the lock:
-            # a row write donates that table, and may only between two
-            # batches' dispatches (what is dispatched reads it whole).
-            # The wait for the lock is inside the stage span, as it is
-            # inside the record's ``stage``, and alone in ``lock_wait``
-            # (a ``with`` cannot span the two phases from inside the
-            # first; an ExitStack did, for 25 us a batch on the chip's
-            # host: PERF.md section 6, PR 31)
-            held = False
-            try:
-                with TraceAnnotation("serve.batch.stage"):
-                    held = self._table_lock.acquire()
-                    t_locked = time.perf_counter()
-                    live = self._expire(batch, t_locked)
-                    if not live:
-                        return
-                    # raise-mode -> _run fails all
-                    mode = faults.check("serving.score")
-                    m = self._model
-                    n = len(live)
-                    B = bucket_for(n, self.batcher.buckets)
-                    st = self._staged(live, B, m.rank)
-                    obs.histogram("serving.batch_rows", n, **self._labels)
-                t_dispatch = time.perf_counter()
-                with TraceAnnotation("serve.batch.dispatch"):
-                    resp_dev, path, fell_back = self._dispatch(
-                        m, st, B, mode)
-                    if fell_back:
-                        obs.counter("serving.fallback_exact", n,
-                                    **self._labels)
-                    whole.set_metadata(bucket=B, rows=n, path=path)
-            finally:
-                if held:
-                    self._table_lock.release()
-            t_readback = time.perf_counter()
-            with TraceAnnotation("serve.batch.readback"):
-                # ONE bulk device→host transfer; tickets complete with
-                # numpy views sliced from this buffer (which snapshots an
-                # immutable device array — the views stay valid after
-                # slot reuse)
-                resp = np.asarray(resp_dev)
-                kw = resp.shape[1] // 2
-                scores = resp[:, :kw].view(np.float32)  # same-itemsize view
-                indices = resp[:, kw:]
-            t_complete = time.perf_counter()
-            score_s = t_complete - t_dispatch
-            with TraceAnnotation("serve.batch.complete"):
-                obs.histogram("serving.score_seconds", score_s, path=path,
-                              **self._labels)
-                e2es = []
-                for j, t in enumerate(live):
-                    kk = min(t.k or self.k, kw)
-                    t.complete((scores[j, :kk], indices[j, :kk]))
-                    e2es.append(t.t_done - t.t_submit)
-                    if t.trace is not None:
-                        t.trace = tracing.record_span(
-                            t.trace, "serve.score", seconds=score_s,
-                            path=path, batch=seq)
-                    self.flight.record(
-                        "ok",
-                        {"admission": t.t_admit,
-                         "queue_wait": (t.t_dequeue - t.t_submit
-                                        if t.t_dequeue else None),
-                         "score": score_s,
-                         "respond": t.t_done - t_complete},
-                        e2e_seconds=e2es[-1], path=path, batch=seq,
-                        trace_id=(t.trace.trace_id
-                                  if t.trace is not None else None))
-                obs.histogram_many("serving.e2e_seconds", e2es,
-                                   **self._labels)
-                trigger = None
-                if self.slo_s is not None and max(e2es) > self.slo_s:
-                    trigger = "slo_breach"
-                elif fell_back:
-                    trigger = "degraded"
-                if trigger:
-                    self.flight.dump(trigger)
-            t_end = time.perf_counter()
-        idle_s, waiting, coalesce_s = self.batcher.last_wait
+            flown = self._begin(batch, seq, whole)
+            if flown is not None:
+                self._finish(flown, flown.t_flown)
+
+    def _begin(self, batch, seq, whole, handoff_wait=0.0):
+        """A batch's first half, stage + dispatch, inside the caller's
+        ``serve.batch`` span ``whole``: the :class:`_Flown` batch to
+        :meth:`_finish`, or ``None`` where every ticket had expired."""
+        t_stage = time.perf_counter()
+        # the live generation is read AFTER the dequeue and its user
+        # table goes to the scoring call under one hold of the lock:
+        # a row write donates that table, and may only between two
+        # batches' dispatches (what is dispatched reads it whole).
+        # The wait for the lock is inside the stage span, as it is
+        # inside the record's ``stage``, and alone in ``lock_wait``
+        # (a ``with`` cannot span the two phases from inside the
+        # first; an ExitStack did, for 25 us a batch on the chip's
+        # host: PERF.md section 6, PR 31)
+        held = False
+        try:
+            with TraceAnnotation("serve.batch.stage"):
+                held = self._table_lock.acquire()
+                t_locked = time.perf_counter()
+                live = self._expire(batch, t_locked)
+                if not live:
+                    return None
+                # raise-mode -> the caller fails all
+                mode = faults.check("serving.score")
+                m = self._model
+                n = len(live)
+                B = bucket_for(n, self.batcher.buckets)
+                st = self._staged(live, B, m.rank)
+                obs.histogram("serving.batch_rows", n, **self._labels)
+            t_dispatch = time.perf_counter()
+            with TraceAnnotation("serve.batch.dispatch"):
+                # each counter has one writer: the difference needs no lock
+                in_flight = self._handed - self._completed
+                resp_dev, path, fell_back = self._dispatch(m, st, B, mode)
+                if fell_back:
+                    obs.counter("serving.fallback_exact", n,
+                                **self._labels)
+                whole.set_metadata(bucket=B, rows=n, path=path)
+        finally:
+            if held:
+                self._table_lock.release()
+        obs.counter("serving.batch_overlap", in_flight=in_flight,
+                    **self._labels)
+        # the batcher's account of this dequeue is taken now: by the
+        # time the batch completes the engine thread has dequeued again
+        return _Flown(seq, live, resp_dev, B, n, path, fell_back,
+                      t_stage, t_locked, t_dispatch, self.batcher.last_wait,
+                      self.batcher.closed_by, self.batcher.head_wait,
+                      in_flight, handoff_wait, time.perf_counter())
+
+    def _finish(self, flown, t_readback, idle_s=0.0):
+        """A batch's second half, readback + complete, from
+        ``t_readback`` (when the caller took the batch up; ``idle_s``:
+        how long it had waited for one).  Reads nothing but ``flown``."""
+        seq, live, path = flown.seq, flown.live, flown.path
+        with TraceAnnotation("serve.batch.readback", seq=seq):
+            # ONE bulk device→host transfer; tickets complete with
+            # numpy views sliced from this buffer (which snapshots an
+            # immutable device array — the views stay valid after
+            # slot reuse)
+            resp = np.asarray(flown.resp_dev)
+            kw = resp.shape[1] // 2
+            scores = resp[:, :kw].view(np.float32)  # same-itemsize view
+            indices = resp[:, kw:]
+        t_complete = time.perf_counter()
+        score_s = t_complete - flown.t_dispatch
+        with TraceAnnotation("serve.batch.complete", seq=seq):
+            obs.histogram("serving.score_seconds", score_s, path=path,
+                          **self._labels)
+            e2es = []
+            for j, t in enumerate(live):
+                kk = min(t.k or self.k, kw)
+                t.complete((scores[j, :kk], indices[j, :kk]))
+                e2es.append(t.t_done - t.t_submit)
+                if t.trace is not None:
+                    t.trace = tracing.record_span(
+                        t.trace, "serve.score", seconds=score_s,
+                        path=path, batch=seq)
+                self.flight.record(
+                    "ok",
+                    {"admission": t.t_admit,
+                     "queue_wait": (t.t_dequeue - t.t_submit
+                                    if t.t_dequeue else None),
+                     "score": score_s,
+                     "respond": t.t_done - t_complete},
+                    e2e_seconds=e2es[-1], path=path, batch=seq,
+                    trace_id=(t.trace.trace_id
+                              if t.trace is not None else None))
+            obs.histogram_many("serving.e2e_seconds", e2es,
+                               **self._labels)
+            trigger = None
+            if self.slo_s is not None and max(e2es) > self.slo_s:
+                trigger = "slo_breach"
+            elif flown.fell_back:
+                trigger = "degraded"
+            if trigger:
+                self.flight.dump(trigger)
+        t_end = time.perf_counter()
+        idle_queue_s, waiting, coalesce_s = flown.last_wait
+        # ``serve.batch`` is the batch's whole life, stage to the last
+        # ticket's bookkeeping: with the halves on two threads it is
+        # longer than its four phases by the wait for the completion
+        # thread (the hop, or the batch before still being completed)
         self.batch_flight.record(
             "ok",
             dict(zip(SERVE_BATCH_SPAN_KEYS,
-                     (idle_s, coalesce_s, t_end - t_stage,
-                      t_dispatch - t_stage, t_readback - t_dispatch,
+                     (idle_queue_s, coalesce_s, t_end - flown.t_stage,
+                      flown.t_dispatch - flown.t_stage,
+                      flown.t_flown - flown.t_dispatch,
                       t_complete - t_readback, t_end - t_complete))),
-            path=path, batch=seq, t0=t_stage, bucket=B, rows=n,
-            waiting=waiting, closed_by=self.batcher.closed_by,
-            head_wait=self.batcher.head_wait,
-            lock_wait=t_locked - t_stage)
+            path=path, batch=seq, t0=flown.t_stage, bucket=flown.bucket,
+            rows=flown.rows, waiting=waiting, closed_by=flown.closed_by,
+            head_wait=flown.head_wait,
+            lock_wait=flown.t_locked - flown.t_stage,
+            in_flight=flown.in_flight, handoff_wait=flown.handoff_wait,
+            completion_idle=idle_s)
         if trigger:
             self.batch_flight.dump(trigger)
 
@@ -1186,27 +1349,23 @@ class ServingEngine:
                 f"({now - t.t_submit:.4f}s since submit)"))
         return live
 
-    def _staged(self, live, B, rank):
-        """Single-upload staging: one reusable int32 ``[B, rank+2]``
-        array per bucket carries the rows' f32 bits, ids and the
-        row-mask — the payload is the only host→device transfer a batch
-        makes."""
-        st = self._stage.get(B)
-        if st is None or st.shape[1] != rank + 2:
-            st = np.zeros((B, rank + 2), dtype=np.int32)
-            self._stage[B] = st
+    @staticmethod
+    def _staged(live, B, rank):
+        """Single-upload staging: one int32 ``[B, rank+2]`` array
+        carries the rows' f32 bits, ids and the row-mask — the payload
+        is the only host→device transfer a batch makes.  A NEW array
+        every batch, zeroed (pad slots score user 0, unread): the upload
+        may read the host's buffer after ``device_put`` has returned (on
+        the CPU the device array IS that buffer), and the next batch of
+        this bucket is staged while this one is still in flight."""
+        st = np.zeros((B, rank + 2), dtype=np.int32)
         rows = st[:, :rank].view(np.float32)    # same-itemsize view
         for j, t in enumerate(live):
             if isinstance(t.payload, (int, np.integer)):
                 st[j, rank] = t.payload
-                st[j, rank + 1] = 0
             else:
                 rows[j] = t.payload
                 st[j, rank + 1] = 1
-        # pad slots: stale ids/masks from the previous batch are enough
-        # to change which (unread) pad rows get scored — zero them; the
-        # stale row payloads themselves are unread either way
-        st[len(live):, rank:] = 0
         return st
 
     def _dispatch(self, m, st, B, mode):

@@ -20,7 +20,11 @@ phases) and that thread's wait for a batch (``completion_idle``), the
 process's ``serving.batch_closed`` and ``serving.batch_overlap``
 counters against the batches the engine served, and what its ``publish_update``s did to
 the device's user table (``serving.user_table_writes``: a live cell
-counts ``inplace`` alone), and for an engine given a mesh its
+counts ``inplace`` alone) and to the catalog (``serving.catalog_writes``:
+``carried`` alone where no item is folded; where items are, ``delta`` and
+``compact`` with the bytes a publish sent of each table, the compactions'
+rows, the items appended and the ratings still waiting for a side's
+factor), and for an engine given a mesh its
 ``serving_mesh_plan`` events (one a bucket ``warmup()`` pinned) with
 the process's ``serving.mesh_exchange_bytes``: the mesh path read
 without a profiler.  No CPU mode (``run.py`` has none):
@@ -114,6 +118,22 @@ def main(argv):
     print(json.dumps({"serving.user_table_writes": writes,
                       "publishes": obs.counter_value("serving.publishes")}),
           flush=True)
+    catalog = {how: obs.counter_value("serving.catalog_writes", how=how)
+               for how in ("carried", "delta", "compact", "replaced")}
+    events = obs.default_registry()._events
+    print(json.dumps({
+        "serving.catalog_writes": catalog,
+        "live.publish_h2d_bytes": obs.counter_value(
+            "live.publish_h2d_bytes"),
+        "live.catalog_h2d_bytes": obs.counter_value(
+            "live.catalog_h2d_bytes"),
+        "live.items_appended": obs.counter_value("live.items_appended"),
+        "compaction_rows": [e["rows"] for e in events
+                            if e["type"] == "serving_compaction"],
+        "live.events_waiting_last": ([
+            e["value"] for e in events if e["type"] == "metric"
+            and e["name"] == "live.events_waiting"] or [None])[-1]}),
+        flush=True)
     plans = [{k: v for k, v in e.items() if k not in ("ts", "type")}
              for e in obs.default_registry()._events
              if e["type"] == "serving_mesh_plan"]

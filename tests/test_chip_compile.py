@@ -170,3 +170,71 @@ def test_spd_solve_lanes_blocked_rank256(one_chip, mxu):
     compile_for(one_chip,
                 functools.partial(spd_solve_lanes_blocked, mxu=mxu),
                 ((2048, 256, 256), F32), ((2048, 256), F32))
+
+
+# -- the live cell's catalog programs at its size (PR 34) --------------------
+
+LIVE_USERS, LIVE_ITEMS, LIVE_RANK, LIVE_SLOTS = 1_703_438, 1_505_938, 256, 512
+
+
+def _compiled(sharding, jitted, *shapes, **statics):
+    """``jitted`` (donations and all) compiled for the described chip."""
+    return jitted.lower(*[jax.ShapeDtypeStruct(s, d, sharding=sharding)
+                          for s, d in shapes], **statics).compile()
+
+
+def _live_catalog_shapes():
+    from tpu_als.core.ratings import row_capacity
+    from tpu_als.ops.topk import shortlist_columns
+
+    cap = row_capacity(LIVE_ITEMS)
+    cols = shortlist_columns(cap, 64)
+    r, d = LIVE_RANK, LIVE_SLOTS
+    base = [((cols, r), jnp.int8), ((cols,), jnp.float32),
+            ((cap, r), jnp.float32), ((cols,), jnp.bool_)]
+    seg = [((d,), jnp.int32), ((d, r), jnp.int8), ((d,), jnp.float32),
+           ((d, r), jnp.float32), ((d,), jnp.bool_)]
+    return cap, cols, base, seg
+
+
+@pytest.mark.parametrize("bucket", [8, 128])
+def test_serve_with_a_segment_at_the_live_cells_size(one_chip, bucket):
+    """The one scoring program a bucket of an engine whose catalog moves:
+    base + segment are whole shortlist blocks (no ragged last block to
+    pad), and the program fits beside the tables."""
+    from tpu_als.core.ratings import row_capacity
+    from tpu_als.ops.topk import shortlist_plan
+    from tpu_als.serving.engine import _serve_int8_delta_packed
+
+    cap, cols, base, seg = _live_catalog_shapes()
+    plan = shortlist_plan(cols + LIVE_SLOTS, 64)
+    assert plan.stages == 2 and plan.columns % plan.block_len == 0
+    c = _compiled(
+        one_chip, _serve_int8_delta_packed,
+        ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32),
+        *base, *seg, ((), jnp.int32), ((bucket, LIVE_RANK + 2), jnp.int32),
+        k=10, shortlist_k=64)
+    assert c.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_the_catalog_writes_are_in_place_at_the_live_cells_size(one_chip):
+    """The compaction, the engine's row write and the fold-in server's, as
+    the chip's compiler builds them: the tables aliased to the results, no
+    copy of one in the program."""
+    from tpu_als.core.foldin import _scatter_rows
+    from tpu_als.serving.engine import _scatter_items
+    from tpu_als.serving.index import _fold_segment_inplace
+
+    cap, cols, (Vq, sv, V, valid), seg = _live_catalog_shapes()
+    rows, vals, ok = (((8,), jnp.int32), ((8, LIVE_RANK), jnp.float32),
+                      ((8,), jnp.bool_))
+    tables = (f"f32[{cap},{LIVE_RANK}]", f"s8[{cols},{LIVE_RANK}]")
+    for fn, shapes in (
+            (_fold_segment_inplace, (V, Vq, sv, valid, *seg)),
+            (_scatter_items, (V, ((cap,), jnp.bool_), rows, vals, ok)),
+            (_scatter_rows, (V, rows, vals))):
+        text = _compiled(one_chip, fn, *shapes).as_text()
+        assert "input_output_alias" in text
+        assert not [ln for ln in text.splitlines()
+                    if (" copy(" in ln or " copy-start(" in ln)
+                    and any(t in ln for t in tables)], fn

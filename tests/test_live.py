@@ -344,9 +344,19 @@ def test_publish_update_tiny_catalog_is_none(rng):
 
 def test_warmup_live_precompiles_without_touching_the_index(rng):
     eng, U, V = _published_engine(rng, Ni=80)
-    idx = eng.published_index
+    idx, seq = eng.published_index, eng.published_seq
+    Q = _queries(rng, 4, V.shape[1])
+    s0, i0 = (np.asarray(a) for a in idx.topk(Q, eng.k))
     eng.warmup_live(max_delta_rows=4)
-    assert eng.published_index is idx       # warmup publishes nothing
+    # warmup publishes nothing: the same generation, the same catalog,
+    # the same answers — with spare rows and an empty segment under it
+    live = eng.published_index
+    assert (eng.published_seq, live.seq, live.n_items) == (seq, seq, 80)
+    assert live.delta_count == 0 and live.delta_slots >= 4
+    assert live.n_base > 80 and live.V.shape[0] == live.n_base
+    s1, i1 = (np.asarray(a) for a in live.topk(Q, eng.k))
+    np.testing.assert_array_equal(s0, s1)
+    np.testing.assert_array_equal(i0, i1)
     # the delta path it warmed serves correctly afterwards
     V2 = V.copy()
     V2[:3] = rng.normal(size=(3, V.shape[1])).astype(np.float32)

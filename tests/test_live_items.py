@@ -1,0 +1,553 @@
+"""The live deployment with its catalog moving (``fold_items``), at a small
+size: rating events of known and new users on known and new items through
+``LiveUpdater`` into a started ``ServingEngine``, against a plain float64
+REPLAY kept here — batch by batch, users first, each fold over the ratings
+whose other side had a factor when it ran.  What is held for a side without
+a factor, what a request sees, and that every per-batch cost of the item
+side is O(touched rows): spare catalog rows, a segment of fixed size, no
+compile, in-place compaction, no catalog-shaped upload or copy."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tests.test_live_deployment import CompileCount, wait_for
+from tpu_als import ALSModel, FoldInServer, IdMap, LiveUpdater, obs
+from tpu_als.core.foldin import _scatter_rows
+from tpu_als.core.ratings import LIVE_PADS, row_capacity
+from tpu_als.obs.schema import LIVE_BATCH_SPAN_KEYS, LIVE_ITEM_SPAN_KEYS
+from tpu_als.serving import ServingEngine, build_index
+from tpu_als.serving.engine import _scatter_items
+from tpu_als.serving.index import (
+    SCORE_ULPS,
+    _fold_segment_inplace,
+    _write_segment,
+    segment_write_bytes,
+)
+
+N_USERS, N_ITEMS, RANK, K = 600, 900, 16, 10
+REG = 0.1
+MAX_BATCH = 8
+
+
+def fold(F, ids, ratings):
+    """x = (F_e^T F_e + reg * n * I)^-1 F_e^T r, float64."""
+    Fe = np.stack([F[i] for i in ids])
+    A = Fe.T @ Fe + REG * len(ids) * np.eye(Fe.shape[1])
+    return np.linalg.solve(A, Fe.T @ np.asarray(ratings, np.float64))
+
+
+def replay(U0, V0, batches, fold_items=True):
+    """The rule, plainly: ``(U, V)`` as ``{id: float64 row}`` after the
+    batches, and the ratings that entered a fold for the first time.  In a
+    batch the users fold first, against the catalog as the batch before
+    left it, then the items, against the user factors as this batch's
+    user fold left them; a fold of an entity is over ALL its ratings so
+    far whose other side has a factor now; an entity with none gets no
+    factor and its ratings wait."""
+    U = {u: np.asarray(x, np.float64) for u, x in enumerate(U0)}
+    V = {i: np.asarray(x, np.float64) for i, x in enumerate(V0)}
+    hist_u, hist_i, used, entered = {}, {}, {}, 0
+    for batch in batches:
+        sides = [(U, V, hist_u, 0, 1)]
+        if fold_items:
+            sides.append((V, U, hist_i, 1, 0))
+        for solved, fixed, hist, me, other in sides:
+            for ev in batch:
+                hist.setdefault(ev[me], []).append((ev[other], ev[2]))
+            moved = {}
+            for e in sorted({ev[me] for ev in batch}):
+                ok = [(o, r) for o, r in hist[e] if o in fixed]
+                entered += len(ok) - used.get((me, e), 0)
+                used[(me, e)] = len(ok)
+                if ok:
+                    moved[e] = fold(fixed, *zip(*ok))
+            solved.update(moved)
+    return U, V, entered
+
+
+def exact_topk(q, V, n_items, k=K):
+    M = np.stack([V[i] for i in range(n_items)])
+    s = M @ np.asarray(q, np.float64)
+    ids = np.argsort(-s, kind="stable")[:k]
+    return s[ids], ids
+
+
+def make_stack(seed=0, fold_items=True, max_batch=MAX_BATCH, max_wait_ms=2.0):
+    rng = np.random.default_rng(seed)
+    U = rng.normal(size=(N_USERS, RANK)).astype(np.float32)
+    V = (rng.normal(size=(N_ITEMS, RANK)) / np.sqrt(RANK)).astype(np.float32)
+    model = ALSModel(
+        RANK, IdMap(ids=np.arange(N_USERS)), IdMap(ids=np.arange(N_ITEMS)),
+        U.copy(), V.copy(),
+        {"userCol": "u", "itemCol": "i", "ratingCol": "r", "regParam": REG,
+         "implicitPrefs": False, "alpha": 1.0, "nonnegative": False})
+    eng = ServingEngine(k=K, buckets=(8,), shortlist_k=N_ITEMS,
+                        max_wait_s=0.0)
+    eng.publish(U, V)
+    srv = FoldInServer(model)
+    srv.prewarm(rows=(max_batch,),
+                sides=("user", "item") if fold_items else ("user",))
+    eng.warmup()
+    upd = LiveUpdater(eng, srv, max_batch=max_batch, max_wait_ms=max_wait_ms,
+                      fold_items=fold_items, flight_capacity=4096)
+    return rng, U, V, model, eng, srv, upd
+
+
+def seeded_events(rng, n, new_users=0.1, new_items=0.1):
+    """(user, item, stars) in arrival order; new users and new items take
+    the next ids as they arrive, a new item's first rating comes from a
+    user the model held at start."""
+    events, next_user, next_item = [], N_USERS, N_ITEMS
+    for _ in range(n):
+        x = rng.random()
+        user, item = int(rng.integers(0, N_USERS)), int(
+            rng.integers(0, N_ITEMS))
+        if x < new_users:
+            user, next_user = next_user, next_user + 1
+        elif x < new_users + new_items:
+            item, next_item = next_item, next_item + 1
+        elif x < new_users + 2 * new_items and next_item > N_ITEMS:
+            item = int(rng.integers(N_ITEMS, next_item))   # rated again
+        events.append((user, item, float(rng.integers(1, 6))))
+    return events
+
+
+def batches_of(upd, events):
+    """The events as the updater batched them: its per-batch ``events``
+    counts, in admission order."""
+    sizes = [r["events"] for r in upd.flight.records()
+             if r.get("status") == "ok"]
+    assert sum(sizes) == len(events)
+    cuts = np.cumsum(sizes)[:-1]
+    return [events[lo:hi] for lo, hi in zip(np.r_[0, cuts], np.r_[cuts,
+                                                                  len(events)])]
+
+
+def run_stream(seed, fold_items, n=400):
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed, fold_items)
+    events = seeded_events(rng, n)
+    compiles = CompileCount()
+    eng.start()
+    upd.start()             # warmup_publish, and warmup_live for items
+    warm = compiles.n
+    try:
+        for j, (u, i, r) in enumerate(events):
+            upd.submit(u, i, r)
+            if j % 10 == 0:
+                eng.recommend(int(rng.integers(0, N_USERS)), timeout=10.0)
+    finally:
+        upd.stop(drain_timeout_s=30.0)
+    return dict(reg=reg, rng=rng, U0=U, V0=V, model=model, eng=eng, srv=srv,
+                upd=upd, events=events, compiled=compiles.n - warm)
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["fold_items", "users_only"])
+def streamed(request):
+    out = run_stream(seed=0, fold_items=request.param)
+    out["fold_items"] = request.param
+    out["replay"] = replay(out["U0"], out["V0"],
+                           batches_of(out["upd"], out["events"]),
+                           fold_items=request.param)
+    yield out
+    out["eng"].stop()
+
+
+# (a) and (g): the factors, the counts and the answers are the replay's
+
+
+def test_the_models_factors_are_the_replays(streamed):
+    m, (U, V, _) = streamed["model"], streamed["replay"]
+    assert len(m._user_map) == len(U) and len(m._item_map) == len(V)
+    # folds chain (an item's row feeds its users' next folds, and back),
+    # each in float32: looser than one fold's 2e-4
+    users = sorted(U)
+    np.testing.assert_allclose(
+        m._U[m._user_map.to_dense(users)], [U[u] for u in users],
+        rtol=2e-3, atol=5e-4)
+    items = sorted(V)
+    np.testing.assert_allclose(
+        m._V[m._item_map.to_dense(items)], [V[i] for i in items],
+        rtol=2e-3, atol=5e-4)
+    if not streamed["fold_items"]:
+        # today's user-only behaviour: the catalog is not touched
+        assert len(V) == N_ITEMS
+        np.testing.assert_array_equal(m._V, streamed["V0"])
+
+
+def test_every_rating_enters_each_fold_once(streamed):
+    reg, events = streamed["reg"], streamed["events"]
+    assert reg.histogram_count("live.freshness_seconds") == len(events)
+    assert reg.counter_value("live.shed") == 0
+    # one per rating and side, less what still waits for a factor
+    assert reg.counter_value("foldin.ratings") == streamed["replay"][2]
+    sides = 2 if streamed["fold_items"] else 1
+    assert streamed["replay"][2] <= sides * len(events)
+
+
+def test_the_engines_answers_are_the_replays(streamed):
+    """By id for touched and new users, and by the vector of each new
+    item's own factor: the ids are the exact top-k of the replay's
+    catalog, new items among them, the scores within the index's ulps."""
+    eng, m = streamed["eng"], streamed["model"]
+    U, V, _ = streamed["replay"]
+    n_items = len(V)
+    touched = sorted({u for u, _, _ in streamed["events"] if u in U})[:40]
+    queries = [(int(m._user_map.to_dense([u])[0]), U[u]) for u in touched]
+    new_items = [i for i in sorted(V) if i >= N_ITEMS]
+    queries += [(8.0 * V[i].astype(np.float32), 8.0 * V[i])
+                for i in new_items[:20]]
+    returned = set()
+    for payload, q in queries:
+        s, ix = eng.recommend(payload, timeout=10.0)
+        want_s, want_i = exact_topk(q, V, n_items)
+        tol = SCORE_ULPS * np.spacing(np.float32(np.abs(want_s).max()))
+        # the fold's own rounding reaches the scores too
+        np.testing.assert_allclose(s, want_s, rtol=1e-3,
+                                   atol=max(float(tol), 1e-3))
+        # each returned id earns its score (near ties may change places)
+        own = np.stack([V[i] for i in ix.tolist()]) @ np.asarray(
+            q, np.float64)
+        np.testing.assert_allclose(s, own, rtol=1e-3, atol=1e-3)
+        assert len(set(ix.tolist()) & set(want_i.tolist())) >= K - 1
+        returned |= set(ix.tolist())
+    if streamed["fold_items"]:
+        # a new item is returned, by id, for the query that points at it
+        assert len(new_items) >= 20
+        assert len(set(new_items[:20]) & returned) >= 15
+        assert eng.published_index.n_items == n_items
+    else:
+        assert eng.published_index.n_items == N_ITEMS
+        assert max(returned) < N_ITEMS
+
+
+def test_items_only_spans_and_counters(streamed):
+    reg = streamed["reg"]
+    pubs = [e for e in reg._events if e["type"] == "serving_publish"][1:]
+    if streamed["fold_items"]:
+        assert {e["catalog"] for e in pubs} == {"delta", "compact"}
+        appended = len(streamed["replay"][1]) - N_ITEMS
+        assert reg.counter_value("live.items_appended") == appended > 20
+        assert reg.counter_value("live.catalog_h2d_bytes") > 0
+        recs = [r for r in streamed["upd"].flight.records()
+                if r.get("status") == "ok"]
+        assert sum(r["new_items"] for r in recs) == appended
+        assert all(r["items"] >= 1 and r["segment_rows"] >= 0 for r in recs)
+    else:
+        assert {e["catalog"] for e in pubs} == {"carried"}
+        assert {e["mode"] for e in pubs} == {"retag"}
+        assert reg.counter_value("live.catalog_h2d_bytes") == 0
+        assert reg.counter_value("live.items_appended") == 0
+        assert reg.counter_value(
+            "serving.catalog_writes", how="carried") == len(pubs)
+
+
+# (d) nothing compiles, whatever the segment holds
+
+
+def test_nothing_compiles_while_the_segment_fills_and_compacts(streamed):
+    if not streamed["fold_items"]:
+        assert streamed["compiled"] == 0
+        return
+    reg = streamed["reg"]
+    compactions = [e for e in reg._events if e["type"] == "serving_compaction"]
+    assert len(compactions) >= 3 and all(e["rows"] >= 64
+                                         for e in compactions)
+    assert reg.counter_value("serving.catalog_writes",
+                             how="compact") == len(compactions)
+    assert streamed["compiled"] == 0
+    # appended items fell on spare rows: no array changed its shape
+    idx = streamed["eng"].published_index
+    assert idx.n_base == row_capacity(N_ITEMS) > idx.n_items > N_ITEMS
+    assert idx.delta_slots == 512 and idx.delta_count < 64 + MAX_BATCH
+    assert int(streamed["eng"]._model.V.shape[0]) == idx.n_base
+    assert ("int8_delta" in {pin for _, pin in streamed["eng"]._pinned}
+            and "int8" not in {pin for _, pin in streamed["eng"]._pinned})
+    assert not [e for e in reg._events if e["type"] == "warning"]
+
+
+# (b) the hole: a rating of a new item is not lost from its user's history
+
+
+def one_at_a_time(upd, reg, events):
+    """Each event a batch of its own."""
+    for ev in events:
+        n = reg.histogram_count("live.freshness_seconds")
+        upd.submit(*ev)
+        wait_for(lambda: reg.histogram_count(
+            "live.freshness_seconds") == n + 1)
+
+
+def test_a_rating_of_a_new_item_enters_its_users_next_fold():
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed=3)
+    events = [(3, N_ITEMS, 5.0), (4, 11, 2.0), (3, 12, 1.0)]
+    with upd:
+        one_at_a_time(upd, reg, events)
+    Ur, Vr, entered = replay(U, V, [[e] for e in events])
+    # the new item was folded from user 3's rating, against user 3's row
+    # as the same batch's user fold left it: unchanged (nothing to use)
+    np.testing.assert_allclose(model._V[N_ITEMS],
+                               fold(dict(enumerate(U)), [3], [5.0]),
+                               rtol=2e-4, atol=2e-5)
+    # and user 3's next fold is over BOTH ratings, the new item's first
+    # (against item 12 as it was: the batch's item fold comes after)
+    then = dict(Vr)
+    then[12] = V[12].astype(np.float64)
+    want = fold(then, [N_ITEMS, 12], [5.0, 1.0])
+    np.testing.assert_allclose(model._U[3], want, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(model._U[3], Ur[3], rtol=2e-4, atol=2e-5)
+    assert not np.allclose(model._U[3], fold(then, [12], [1.0]), rtol=1e-2)
+    assert srv._history[3][0].tolist() == [N_ITEMS, 12]
+    assert reg.counter_value("foldin.ratings") == entered == 6
+    assert srv.events_waiting == 0
+
+
+# (c) neither side has a factor: kept, counted, folded once one has
+
+
+def test_a_rating_with_both_sides_unknown_waits_and_is_folded():
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed=4)
+    X, Y = N_USERS + 70, N_ITEMS + 70           # any ids nobody holds
+    waiting = []
+    with upd:
+        for ev in [(X, Y, 5.0), (X, 11, 3.0), (5, Y, 4.0), (X, 12, 2.0)]:
+            one_at_a_time(upd, reg, [ev])
+            waiting.append((srv.events_waiting, [
+                e["value"] for e in reg._events if e["type"] == "metric"
+                and e["name"] == "live.events_waiting"][-1]))
+            if ev == (X, Y, 5.0):
+                # nothing could be folded: nobody was appended
+                assert len(model._user_map) == N_USERS
+                assert len(model._item_map) == N_ITEMS
+    # held on both sides; X got a factor; then Y, from both its ratings
+    assert waiting == [(2, 2), (1, 1), (0, 0), (0, 0)]
+    Ur, Vr, entered = replay(U, V, [[(X, Y, 5.0)], [(X, 11, 3.0)],
+                                    [(5, Y, 4.0)], [(X, 12, 2.0)]])
+    x, y = model._user_map.to_dense([X])[0], model._item_map.to_dense([Y])[0]
+    assert (x, y) == (N_USERS, N_ITEMS)
+    np.testing.assert_allclose(model._U[x], Ur[X], rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(model._V[y], Vr[Y], rtol=2e-4, atol=2e-5)
+    # Y's fold was over X's AND user 5's rating; X's last over all three
+    np.testing.assert_allclose(
+        Vr[Y], fold({X: Ur[X], 5: U[5].astype(np.float64)} | {
+            X: fold(dict(enumerate(V.astype(np.float64))), [11], [3.0])},
+            [X, 5], [5.0, 4.0]), rtol=1e-9)
+    assert srv._history[X][0].tolist() == [Y, 11, 12]
+    # 4 ratings x 2 sides, less user 5's of Y (5 has not been folded since)
+    assert reg.counter_value("foldin.ratings") == entered == 7
+    s, ix = eng.recommend(8.0 * model._V[y], timeout=10.0) \
+        if eng.start() else None
+    eng.stop()
+    assert ix[0] == y
+
+
+# (e) compaction in place: bitwise a rebuild, and a batch in flight
+
+
+def _values(a):
+    return np.asarray(a + 0)      # no cached view of the buffer (donation)
+
+
+def test_compaction_in_place_is_bitwise_a_rebuild_of_the_catalog():
+    rng = np.random.default_rng(5)
+    V = rng.normal(size=(N_ITEMS, RANK)).astype(np.float32)
+    cap = row_capacity(N_ITEMS)
+    idx = build_index(V, shortlist_k=64, seq=1).reserve(rows=cap, slots=64)
+    rows = np.r_[rng.choice(N_ITEMS, 30, replace=False),
+                 N_ITEMS:N_ITEMS + 9].astype(np.int64)
+    V2 = np.concatenate([V, np.zeros((9, RANK), np.float32)])
+    V2[rows] = rng.normal(size=(len(rows), RANK)).astype(np.float32)
+    upd = idx.with_updates(rows, V2[rows], seq=2)
+    assert (upd.delta_count, upd.delta_slots, upd.n_base) == (39, 64, cap)
+    Q = jnp.asarray(rng.normal(size=(9, RANK)).astype(np.float32))
+    before = [np.asarray(a) for a in upd.topk(Q, K)]
+    where = {n: getattr(upd, n).unsafe_buffer_pointer()
+             for n in ("V", "Vq", "sv", "valid")}
+    comp = upd.compact(seq=3)
+    # the same buffers, the operands' handles deleted: no copy was made
+    for name, at in where.items():
+        assert getattr(comp, name).unsafe_buffer_pointer() == at, name
+        assert getattr(upd, name).is_deleted(), name
+    assert (comp.delta_count, comp.delta_slots, comp.n_items) == (
+        0, 64, N_ITEMS + 9)
+    ref = build_index(V2, shortlist_k=64, seq=3).reserve(rows=cap)
+    for name in ("V", "Vq", "sv", "valid"):
+        np.testing.assert_array_equal(np.asarray(getattr(comp, name)),
+                                      np.asarray(getattr(ref, name)), name)
+    # and it changes no answer
+    after = [np.asarray(a) for a in comp.topk(Q, K)]
+    full = [np.asarray(a) for a in build_index(
+        V2, shortlist_k=64, seq=3).topk(Q, K)]
+    for got in (before, after):
+        np.testing.assert_array_equal(got[0], full[0])
+        np.testing.assert_array_equal(got[1], full[1])
+
+
+def _dispatch_by_vector(eng, q):
+    """One bucket-8 batch asking by vector, dispatched as the engine
+    thread would and NOT read back: (the packed response on the device,
+    the generation it read)."""
+    st = np.zeros((8, RANK + 2), np.int32)
+    st[0, :RANK] = np.asarray(q, np.float32).view(np.int32)
+    st[0, RANK + 1] = 1
+    with eng._table_lock:
+        return eng._dispatch(eng._model, st, 8, None)[0], eng._model.seq
+
+
+def test_a_batch_dispatched_before_a_compaction_answers_from_its_generation():
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed=6)
+    eng.warmup_live(max_rows=64)
+    V1 = V.copy()
+    V1[:40] *= 3.0
+    eng.publish_update(U, V1, touched_items=np.arange(40))
+    q = 8.0 * V1[7]
+    in_flight, seq1 = _dispatch_by_vector(eng, q)
+    base = eng.published_index
+    V2 = V1.copy()
+    V2[:64] *= -2.0             # 64 rows in the segment: it is folded
+    seq2, mode = eng.publish_update(U, V2, touched_items=np.arange(64))
+    assert (seq2, mode) == (seq1 + 1, "compact")
+    assert base.V.is_deleted() and eng.published_index.delta_count == 0
+    behind, _ = _dispatch_by_vector(eng, q)
+    for resp, cat in ((in_flight, V1), (behind, V2)):
+        resp = np.asarray(resp)
+        want_s, want_i = exact_topk(q, dict(enumerate(cat)), N_ITEMS)
+        np.testing.assert_allclose(resp[0, :K].view(np.float32), want_s,
+                                   rtol=1e-3, atol=1e-4)
+        assert resp[0, K:].tolist() == want_i.tolist()
+    comp, = [e for e in reg._events if e["type"] == "serving_compaction"]
+    assert (comp["seq"], comp["rows"]) == (seq2, 64)
+
+
+def test_a_ticket_says_which_generation_answered_it():
+    rng, U, V, model, eng, srv, upd = make_stack(seed=6)
+    with eng:
+        t1 = eng.submit(3)
+        t1.result(timeout=10.0)
+        seq, _ = eng.publish_update(U, V, touched_users=np.array([3]))
+        t2 = eng.submit(3)
+        t2.result(timeout=10.0)
+    assert (t1.seq, t2.seq) == (seq - 1, seq)
+
+
+# (f) bytes in proportion to the rows; no catalog-shaped copy on the device
+
+
+def test_an_item_publish_uploads_the_touched_rows_not_the_catalog():
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed=7)
+    eng.warmup_live(max_rows=64)
+    old = eng._model
+    sent = []
+    for n in (3, 8, 40):
+        V = V.copy()
+        V[:n] += 1.0
+        eng.publish_update(U, V, touched_items=np.arange(n),
+                           touched_users=np.array([], int))
+        sent.append(reg.counter_value("live.catalog_h2d_bytes")
+                    - sum(sent))
+    # slot, id, row, bit, padded to 8, 8, 64 rows: uploaded once, for the
+    # segment and for the engine's own table alike
+    assert sent == [segment_write_bytes(n, RANK) for n in (3, 8, 40)]
+    assert sent == [pad * (4 + 4 + 4 * RANK + 1) for pad in (8, 8, 64)]
+    assert sent[2] < 4 * N_ITEMS * RANK // 4
+    assert reg.counter_value("live.publish_h2d_bytes") == 0
+    new = eng._model
+    # ONE generation of the catalog: the engine's table is the old one's
+    # buffer, written in place, and the base arrays are shared
+    assert old.V.is_deleted() and new.V.shape == old.V.shape
+    assert new.index.V is old.index.V and new.index.Vq is old.index.Vq
+    np.testing.assert_array_equal(np.asarray(new.V)[:N_ITEMS], V)
+    assert reg.counter_value("serving.catalog_writes", how="delta") == 3
+
+
+@pytest.mark.parametrize("pad", LIVE_PADS)
+def test_the_catalog_writes_hold_no_catalog_shaped_copy(pad):
+    """The engine's row write, the fold-in server's and the compaction
+    donate what they write into: aliased to the result in the compiled
+    program, which holds no copy of a table."""
+    cap, cols, slots = 1536, 1664, 512
+    V = jnp.zeros((cap, RANK), jnp.float32)
+    valid = jnp.zeros(cap, jnp.bool_)
+    rows = jnp.full(pad, cap, jnp.int32)
+    vals = jnp.zeros((pad, RANK), jnp.float32)
+    ok = jnp.zeros(pad, jnp.bool_)
+    seg = (jnp.zeros(slots, jnp.int32), jnp.zeros((slots, RANK), jnp.int8),
+           jnp.ones(slots, jnp.float32), jnp.zeros((slots, RANK),
+                                                   jnp.float32),
+           jnp.zeros(slots, jnp.bool_))
+    base = (V, jnp.zeros((cols, RANK), jnp.int8), jnp.ones(cols, jnp.float32),
+            jnp.zeros(cols, jnp.bool_))
+    shaped = (f"f32[{cap},{RANK}]", f"s8[{cols},{RANK}]", f"f32[{cols}]",
+              f"pred[{cols}]", f"pred[{cap}]")
+    for fn, args in ((_scatter_items, (V, valid, rows, vals, ok)),
+                     (_scatter_rows, (V, rows, vals)),
+                     (_fold_segment_inplace, (*base, *seg))):
+        text = fn.lower(*args).compile().as_text()
+        assert "input_output_alias" in text
+        assert not [ln for ln in text.splitlines() if " copy(" in ln
+                    and any(s in ln for s in shaped)], fn
+    # the segment's own write touches nothing of the catalog's size
+    text = _write_segment.lower(*seg, rows, rows, vals, ok).compile().as_text()
+    assert not any(s in text for s in shaped)
+
+
+def test_the_fold_in_server_uploads_no_table_per_batch(monkeypatch):
+    """Both fixed sides are placed once; a batch writes the rows it moved
+    into them, in place."""
+    rng, U, V, model, eng, srv, upd = make_stack(seed=8)
+    placed = []
+    monkeypatch.setattr(srv, "_place", lambda *a, **k: placed.append(a))
+    tables = (srv._V.unsafe_buffer_pointer(), srv._Ud.unsafe_buffer_pointer())
+    frame = {"u": np.array([3, N_USERS]), "i": np.array([N_ITEMS, 5]),
+             "r": np.array([5.0, 2.0], np.float32)}
+    for _ in range(3):
+        srv.update(frame)
+        srv.update_items(frame)
+    assert not placed
+    assert (srv._V.unsafe_buffer_pointer(),
+            srv._Ud.unsafe_buffer_pointer()) == tables
+    np.testing.assert_array_equal(
+        np.asarray(srv._V)[:N_ITEMS + 1], model._V)
+    np.testing.assert_array_equal(
+        np.asarray(srv._Ud)[:N_USERS + 1], model._U)
+
+
+def test_an_items_updaters_timeline_holds_the_item_spans(tmp_path):
+    import glob
+    import os
+
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed=9)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with upd:
+            one_at_a_time(upd, reg, [(1, j, 3.0) for j in range(70)])
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    spans = [(ev.name, dict(ev.stats))
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("live.")]
+    names = [n for n, _ in spans]
+    assert set(names) == set(LIVE_BATCH_SPAN_KEYS) | set(LIVE_ITEM_SPAN_KEYS)
+    assert names.count("live.batch.foldin.users") == names.count(
+        "live.batch.foldin.items") == names.count("live.batch") == 70
+    assert names.count("live.batch.publish.compact") == 1   # at 64 rows
+    stats = [s for n, s in spans if n == "live.batch"]
+    assert [s["items"] for s in stats] == [1] * 70
+    assert [s["segment_rows"] for s in stats] == [
+        *range(1, 64), 0, *range(1, 7)]
+    assert stats[63]["mode"] == "compact" and stats[0]["mode"] == "delta"

@@ -11,6 +11,7 @@ import pytest
 
 from tests.conftest import assert_topk_within_contract
 from tpu_als import obs
+from tpu_als.core.ratings import row_capacity
 from tpu_als.ops.topk import (
     NEG_INF,
     ShortlistPlan,
@@ -244,10 +245,18 @@ def test_warmup_reports_the_plan_the_program_was_traced_with(_fresh, big):
     for e in events:
         assert (e["stages"], e["blocks"], e["block_len"], e["columns"]) \
             == (2, 313, 128, 40_064) == tuple(want)
-    eng.warmup_live(max_delta_rows=2)
+    eng.warmup_live()
     live = _shortlist_events(_fresh)[2:]
+    idx = eng.published_index
+    # ONE program a bucket for "with a segment": the catalog's spare
+    # rows and the segment's slots fix its shapes, base + segment in
+    # whole blocks (no batch pays for a ragged last one)
+    assert idx.n_base == row_capacity(BIG_ITEMS) and idx.delta_slots == 512
+    columns = int(idx.Vq.shape[0]) + 512
     assert [(e["bucket"], e["delta_rows"], e["columns"]) for e in live] == [
-        (8, 1, 40_065), (32, 1, 40_065), (8, 2, 40_066), (32, 2, 40_066)]
+        (8, 512, columns), (32, 512, columns)]
+    assert all(e["stages"] == 2 and e["columns"] % e["block_len"] == 0
+               for e in live)
     assert all(tuple(shortlist_plan(e["columns"], SHORTLIST))
                == (e["stages"], e["blocks"], e["block_len"], e["columns"])
                for e in live)

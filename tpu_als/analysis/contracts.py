@@ -631,9 +631,14 @@ def _build_live_delta():
     rows = np.concatenate([touched, np.arange(Ni, Ni + 5)])
     delta = base.with_updates(rows, Vn[rows], valid_rows=validn[rows],
                               seq=2)
+    # scored BEFORE the compaction: compact() donates the base arrays,
+    # and an index whose successor was compacted is spent
+    answers = {"delta": tuple(np.asarray(x) for x in delta.topk(U, k))}
     compacted = delta.compact(seq=3)
+    answers["compacted"] = tuple(np.asarray(x)
+                                 for x in compacted.topk(U, k))
     ref = build_index(Vn, item_valid=validn, shortlist_k=sk, seq=2)
-    return {"U": U, "k": k, "delta": delta, "compacted": compacted,
+    return {"U": U, "k": k, "answers": answers, "compacted": compacted,
             "ref": ref, "touched": len(rows)}
 
 
@@ -642,7 +647,7 @@ def _pin_live_delta(a):
 
     s_r, ix_r = (np.asarray(x) for x in a["ref"].topk(a["U"], a["k"]))
     for which in ("delta", "compacted"):
-        s, ix = (np.asarray(x) for x in a[which].topk(a["U"], a["k"]))
+        s, ix = a["answers"][which]
         _require(np.array_equal(s, s_r),
                  f"{which} top-k SCORES differ from the full rebuild "
                  "(the O(touched) incremental publish is not bitwise)")

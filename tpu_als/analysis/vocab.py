@@ -338,7 +338,8 @@ def check_tenant_vocabulary(repo=REPO):
     reserved = set(getattr(schema, "FLIGHT_RESERVED", ())) \
         | {"tenant", "trace_id", "trace_ids"}
     for attr in ("SERVE_SPAN_KEYS", "SERVE_BATCH_SPAN_KEYS",
-                 "LIVE_SPAN_KEYS", "LIVE_BATCH_SPAN_KEYS"):
+                 "LIVE_SPAN_KEYS", "LIVE_BATCH_SPAN_KEYS",
+                 "LIVE_ITEM_SPAN_KEYS"):
         overlap = sorted(set(getattr(schema, attr, ())) & reserved)
         if overlap:
             errors.append(
@@ -401,10 +402,13 @@ def check_trace_vocabulary(repo=REPO):
                 f"tpu_als/obs/schema.py: TRACE_SPANS declares {name!r} "
                 "but no call site under tpu_als/ records it — dead "
                 "vocabulary (remove it or record the hop)")
-    for attr, package in (("SERVE_BATCH_SPAN_KEYS", "serving"),
-                          ("LIVE_BATCH_SPAN_KEYS", "live")):
+    for attr, packages in (("SERVE_BATCH_SPAN_KEYS", ("serving",)),
+                           ("LIVE_BATCH_SPAN_KEYS", ("live",)),
+                           ("LIVE_ITEM_SPAN_KEYS", ("live", "serving"))):
+        package = " or tpu_als/".join(packages)
         annotated = set()
-        for path in py_files([os.path.join(repo, "tpu_als", package)]):
+        for path in py_files([os.path.join(repo, "tpu_als", sub)
+                              for sub in packages]):
             with open(path, encoding="utf-8") as f:
                 annotated |= {m.group("name")
                               for m in ANNOTATION_RE.finditer(f.read())}
@@ -570,7 +574,8 @@ def check_file(path, repo=REPO):
 
     if not in_obs:
         batch_spans = (getattr(schema, "SERVE_BATCH_SPAN_KEYS", ())
-                       + getattr(schema, "LIVE_BATCH_SPAN_KEYS", ()))
+                       + getattr(schema, "LIVE_BATCH_SPAN_KEYS", ())
+                       + getattr(schema, "LIVE_ITEM_SPAN_KEYS", ()))
         for m in ANNOTATION_RE.finditer(text):
             name = m.group("name")
             if name not in batch_spans:
@@ -578,7 +583,8 @@ def check_file(path, repo=REPO):
                 add(lineno,
                     f"{rel}:{lineno}: profiler span {name!r} is not "
                     "declared in tpu_als.obs.schema."
-                    "SERVE_BATCH_SPAN_KEYS or LIVE_BATCH_SPAN_KEYS — "
+                    "SERVE_BATCH_SPAN_KEYS, LIVE_BATCH_SPAN_KEYS or "
+                    "LIVE_ITEM_SPAN_KEYS — "
                     "trace readers key on declared span names only")
         trace_spans = getattr(schema, "TRACE_SPANS", ())
         for regex in (TRACE_START_RE, TRACE_RECORD_RE):

@@ -1125,13 +1125,9 @@ def cmd_serve_bench(args):
                 rows=(updater.max_batch,), widths=(2,),
                 sides=(("user", "item") if args.update_items
                        else ("user",)))
-            if args.update_items and not args.exact:
-                # each event touches one item, so the stream can never
-                # grow the delta segment past its own event count —
-                # compile the (bucket, delta-pad) serve executables up
-                # to that bound now, not on the request path
-                engine.warmup_live(max_delta_rows=max(
-                    1, int(args.update_qps * args.duration)))
+            # (with --update-items ``updater.start()`` runs
+            # ``engine.warmup_live``: spare catalog rows, the segment's
+            # slots, the with-segment programs)
 
     path = "exact" if args.exact else "int8"
     n_req = max(1, int(args.qps * args.duration))

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_als.core.ratings import pad_for
 from tpu_als.ops.solve import (
     DEFAULT_JITTER,
     SOLVE_PATH_NAMES,
@@ -65,6 +66,30 @@ def _write_rows(table, rows, at):
     """``rows`` written into ``table`` from row ``at`` on, in place (the
     table is donated; ``at`` is traced: one program for every offset)."""
     return jax.lax.dynamic_update_slice(table, rows, (at, 0))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_rows(table, rows, vals):
+    """``vals`` written at ``rows`` of ``table``, in place (donated);
+    rows outside it are dropped."""
+    with jax.named_scope("live.foldin.scatter"):
+        return table.at[rows].set(vals, mode="drop")
+
+
+def write_rows(table, rows, vals, pad=None):
+    """``table`` (on the device, as :func:`place_rows` made it) with the
+    host's ``vals`` at ``rows``, written IN PLACE: the table is donated,
+    so the caller's handle is deleted and the result is the same buffer.
+    Only the rows cross host→device, padded up ``pad_for``'s ladder (8,
+    64, 512, ...; ``pad``: to that many, for whoever runs the programs
+    ahead) with a row outside the table, which is dropped: few
+    programs, O(touched rows) on the host, on the link and on the
+    device."""
+    n, pad = len(rows), pad or pad_for(len(rows))
+    rp = np.full(pad, table.shape[0], dtype=np.int32)
+    vp = np.zeros((pad, table.shape[1]), dtype=np.float32)
+    rp[:n], vp[:n] = rows, vals
+    return _scatter_rows(table, *jax.device_put((rp, vp)))
 
 
 def place_rows(F, *, capacity, mesh=None):

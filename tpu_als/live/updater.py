@@ -17,9 +17,15 @@ single measurable quantity:
    they can reach the factors, through the SAME obs contract streaming
    ingest uses: one ``ingest_quarantined`` event + the
    ``ingest.quarantined_rows`` counter.
-4. **Fold.**  ``FoldInServer.update`` solves the touched user rows
-   (and ``update_items`` the touched item rows when ``fold_items`` is
-   on — the path that exercises incremental index re-quantization).
+4. **Fold.**  ``FoldInServer.update`` solves the touched user rows;
+   with ``fold_items`` ``update_items`` then solves the touched item
+   rows, USERS FIRST: an item's fold regresses on the user factors as
+   the same batch's user fold left them (a new user's rating counts at
+   once), a user's on the catalog as the batch before left it.  A
+   rating of an item without a factor is kept in its user's history and
+   enters that user's next fold after the item has one
+   (``stream/microbatch.py``); no admitted rating is dropped from a
+   history.
 5. **Publish.**  ``ServingEngine.publish_update`` swaps the new
    generation in atomically — retag for user-only batches, an
    O(touched) delta re-quantization for item batches — never a full
@@ -27,14 +33,21 @@ single measurable quantity:
    user rows are written into the device's table in place (the table
    is donated to the write: no copy of it, on the host or on the
    device); the generation before loses its user table to the new one.
+   The touched and appended item rows go the same way: uploaded alone,
+   into the index's delta segment and the engine's own catalog, the
+   segment folded into the base arrays in place when it fills.
 
 The thread's cycle is on the profiler's timeline as ``TraceAnnotation``
 spans, always on, the write path's counterpart of the engine thread's
 (``obs.schema.LIVE_BATCH_SPAN_KEYS``): ``live.idle``,
 ``live.batch.coalesce``, and ``live.batch`` (stats ``seq``, ``events``,
 ``users``, ``new_users``, ``width``, ``mode``) around
-``live.batch.foldin`` and ``live.batch.publish``.  A trace reader keys on
-those names.
+``live.batch.foldin`` and ``live.batch.publish``.  With ``fold_items``
+also (``obs.schema.LIVE_ITEM_SPAN_KEYS``) ``live.batch.foldin.users``
+and ``live.batch.foldin.items`` inside the fold,
+``live.batch.publish.compact`` (the engine's) inside a publish that
+compacts, and the stats ``items``, ``new_items``, ``segment_rows``.  A
+trace reader keys on those names.
 
 Freshness (``live.freshness_seconds``) is per EVENT, arrival →
 publish-visible, so the histogram's p99 is exactly the SLO quantity:
@@ -47,6 +60,7 @@ compaction-heavy publish.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 
@@ -143,11 +157,15 @@ class LiveUpdater:
     # -- lifecycle ----------------------------------------------------
     def start(self):
         """Have the engine run the row writes this updater's publishes
-        will make (up to ``max_batch`` users a publish), so that none
-        compiles or loads under traffic, then start the loop."""
+        will make (up to ``max_batch`` users a publish; with
+        ``fold_items`` as many items, into a catalog with spare rows and
+        a segment of fixed size: ``ServingEngine.warmup_live``), so that
+        none compiles or loads under traffic, then start the loop."""
         if self._thread is not None:
             raise RuntimeError("updater already started")
         self.engine.warmup_publish(self.max_batch)
+        if self.fold_items:
+            self.engine.warmup_live(max_rows=self.max_batch)
         self._thread = threading.Thread(
             target=self._run, name="tpu-als-live", daemon=True)
         self._thread.start()
@@ -265,14 +283,18 @@ class LiveUpdater:
                  p["ratingCol"]: ratings}
         tf = time.perf_counter()
         m = self.foldin.model
-        users_before = len(m._user_map)
+        users_before, items_before = len(m._user_map), len(m._item_map)
         touched_item_rows = None
         with TraceAnnotation("live.batch.foldin"):
-            touched_users = self.foldin.update(frame)
+            # users first: the item fold regresses on their rows
+            with (TraceAnnotation("live.batch.foldin.users")
+                  if self.fold_items else contextlib.nullcontext()):
+                touched_users = self.foldin.update(frame)
+            width = self.foldin.stats[-1][3] if len(touched_users) else 0
             if self.fold_items:
-                t_items = self.foldin.update_items(frame)
-                touched_item_rows = m._item_map.to_dense(
-                    np.asarray(t_items))
+                with TraceAnnotation("live.batch.foldin.items"):
+                    touched_item_rows = m._item_map.to_dense(
+                        self.foldin.update_items(frame))
         foldin_s = time.perf_counter() - tf
         ctxs = [tracing.record_span(c, "live.foldin", seconds=foldin_s)
                 if c is not None else None for c in ctxs]
@@ -285,11 +307,21 @@ class LiveUpdater:
                 touched_users=m._user_map.to_dense(touched_users),
                 trace=ctxs)
         publish_s = time.perf_counter() - tp
+        sizes = {}
+        if self.fold_items:
+            index = self.engine.published_index
+            sizes = {"items": len(touched_item_rows),
+                     "new_items": len(m._item_map) - items_before,
+                     "segment_rows": (index.delta_count
+                                      if index is not None else 0)}
+            obs.counter("live.items_appended", sizes["new_items"],
+                        **self._labels)
+            obs.gauge("live.events_waiting", self.foldin.events_waiting,
+                      **self._labels)
         whole.set_metadata(
             events=len(ratings), users=len(touched_users),
             new_users=len(m._user_map) - users_before,
-            width=(self.foldin.stats[-1][3] if len(touched_users) else 0),
-            mode=mode)
+            width=width, mode=mode, **sizes)
         ctxs = [tracing.record_span(c, "live.publish",
                                     seconds=publish_s, seq=seq,
                                     mode=mode)
@@ -319,7 +351,7 @@ class LiveUpdater:
             # for whoever runs no profiler: how many events became
             # visible, and when on perf_counter's clock (the engine's
             # batch records carry their ``t0`` the same way)
-            events=len(ratings), t_done=done,
+            events=len(ratings), t_done=done, **sizes,
             trace_ids=sorted({c.trace_id for c in ctxs
                               if c is not None}) or None)
         if self.slo_s is not None and worst > self.slo_s:

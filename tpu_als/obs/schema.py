@@ -54,7 +54,11 @@ METRICS = {
         "histogram", "seconds",
         "FoldInServer micro-batch latency, labeled side=user|item"),
     "foldin.ratings": (
-        "counter", "rows", "ratings folded in by FoldInServer"),
+        "counter", "rows",
+        "ratings that entered a FoldInServer fold for the first time, one "
+        "per rating and side folded: a rating folded into its user AND "
+        "into its item (update and update_items) counts twice; a rating "
+        "held for a side without a factor counts when it enters"),
     "checkpoint.save_seconds": (
         "histogram", "seconds", "save_factors wall-clock duration"),
     "checkpoint.save_bytes": (
@@ -127,6 +131,15 @@ METRICS = {
         "write: O(touched) on the device) | replaced (a new table "
         "uploaded whole: no row list, or rows the live table cannot "
         "take) | carried (no row to write: the live table as it is)"),
+    "serving.catalog_writes": (
+        "counter", "publishes",
+        "one per ServingEngine.publish_update, labeled by what it did to "
+        "the catalog: how=carried (nothing of it changed: the index "
+        "re-tagged) | delta (the touched and appended rows written into "
+        "the index's delta segment and into the engine's own table, in "
+        "place) | compact (the same, and the segment folded into the base "
+        "arrays, in place, before or after) | replaced (uploaded whole: "
+        "no live index to take the rows)"),
     "serving.mesh_exchange_bytes": (
         "counter", "bytes",
         "a mesh engine only: what one device moves between the chips "
@@ -173,9 +186,27 @@ METRICS = {
         "dequeue"),
     "live.publish_h2d_bytes": (
         "counter", "bytes",
-        "bytes ServingEngine.publish_update sent host -> device: the "
-        "touched user rows and their indices on the incremental path, "
-        "whole tables where it had to re-place one"),
+        "bytes ServingEngine.publish_update sent host -> device of the "
+        "USER table: the touched user rows and their indices on the "
+        "incremental path, the whole table where it had to re-place it "
+        "(the catalog's: live.catalog_h2d_bytes)"),
+    "live.catalog_h2d_bytes": (
+        "counter", "bytes",
+        "bytes ServingEngine.publish_update sent host -> device of the "
+        "CATALOG: the touched and appended item rows with their ids, slots "
+        "and valid bits (padded to 8 / 64 / 512 rows; once for the "
+        "index's segment, once for the engine's own table), the whole "
+        "catalog where it had to re-place it"),
+    "live.items_appended": (
+        "counter", "items",
+        "catalog items the live updater's item fold appended (unknown "
+        "before their first foldable rating, servable by id after its "
+        "publish)"),
+    "live.events_waiting": (
+        "gauge", "events",
+        "ratings the fold-in server holds in a history for a side whose "
+        "other entity has no factor yet (one per rating and side), "
+        "sampled after each micro-batch's folds"),
     "foldin.batch_rows": (
         "histogram", "rows",
         "entities solved per FoldInServer micro-batch (the padded "
@@ -254,6 +285,10 @@ LABELS = {
     "live.shed": ("tenant",),
     "live.queue_depth": ("tenant",),
     "live.publish_h2d_bytes": ("tenant",),
+    "live.catalog_h2d_bytes": ("tenant",),
+    "live.items_appended": ("tenant",),
+    "live.events_waiting": ("tenant",),
+    "serving.catalog_writes": ("how", "tenant"),
     "tenancy.served_rows": ("tenant",),
     "tenancy.batch_errors": ("tenant",),
 }
@@ -350,9 +385,21 @@ LIVE_BATCH_SPAN_KEYS = (
     "live.idle",              # blocked on an empty admission queue
     "live.batch.coalesce",    # first event seen -> batch popped
     "live.batch",             # all of _process (seq, events, users,
-    #                           new_users, width, mode)
+    #                           new_users, width, mode; with fold_items
+    #                           also items, new_items, segment_rows)
     "live.batch.foldin",      # FoldInServer.update (+ update_items)
     "live.batch.publish",     # ServingEngine.publish_update
+)
+# what an updater with ``fold_items`` writes besides, inside the two
+# phases above (none of it without: a user-only updater's timeline is
+# LIVE_BATCH_SPAN_KEYS and nothing else).  In the device programs:
+# ``live.publish.scatter_items`` (the catalog row write: the engine's
+# own table, serving/engine.py, and the index's segment,
+# serving/index.py) and ``live.publish.compact`` (serving/index.py)
+LIVE_ITEM_SPAN_KEYS = (
+    "live.batch.foldin.users",    # FoldInServer.update
+    "live.batch.foldin.items",    # FoldInServer.update_items
+    "live.batch.publish.compact",  # the segment folded into the base
 )
 
 # field names every flight record (and its flight_record event) claims
@@ -406,7 +453,15 @@ EVENTS = {
         "one per ServingEngine.publish: the generation sequence number, "
         "catalog size, and whether an int8 index was built for it; a "
         "publish_update adds users = inplace|replaced|carried, what it "
-        "did to the device's user table (serving.user_table_writes' how)"),
+        "did to the device's user table (serving.user_table_writes' how), "
+        "and catalog = carried|delta|compact|replaced "
+        "(serving.catalog_writes' how)"),
+    "serving_compaction": (
+        ("seq", "rows"),
+        "one per compaction of the live index's delta segment "
+        "(ServingEngine._compact_live): the generation it was folded "
+        "under (compaction changes no answer, so seq stays) and the rows "
+        "the segment held"),
     "serving_backend": (
         ("backend", "n_shards"),
         "one per ServingEngine that was given a mesh, at first publish: "
@@ -415,7 +470,8 @@ EVENTS = {
     "serving_shortlist": (
         ("bucket", "path", "stages", "blocks", "block_len", "columns"),
         "one per int8 scoring program ServingEngine.warmup / warmup_live "
-        "compiles (per bucket and path; warmup_live adds delta_rows): how "
+        "compiles (per bucket and path; warmup_live adds delta_rows, the "
+        "segment's slots): how "
         "its shortlist selects, from ops.topk.shortlist_plan — stages 1 is "
         "one lax.top_k over all columns, 2 is block maxima then top_k "
         "over the winning blocks of block_len columns"),

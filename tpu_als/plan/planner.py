@@ -519,13 +519,19 @@ def invalidate_kernel_config(*, rank, compute_dtype="float32",
 
 
 # live-pipeline cadence: micro-batch accumulation + index compaction.
-# The defaults are the measured sweet spot on CPU (fold-in p50 82 ms
-# amortizes over ~256 events; a quarter-catalog delta segment keeps the
-# two-GEMM shortlist within noise of the base kernel).
+# The batch bounds are the measured sweet spot on CPU (fold-in p50 82 ms
+# amortizes over ~256 events).  The compaction threshold was a quarter
+# of the catalog while a compaction copied the catalog on the device;
+# since it scatters the segment into donated base arrays (PR 34) it
+# costs what the segment holds, and what is left to weigh is what every
+# BATCH pays for the segment's slots (a second int8 GEMM, the override
+# mask, a longer shortlist): an 8,192nd of the catalog — 184 rows at
+# 1.5 M items, 512 slots with one max_batch of room — keeps that within
+# noise of the base kernel on a v5e (PERF.md section 6, PR 34).
 DEFAULT_LIVE_CADENCE = {
     "max_batch": 256,
     "max_wait_ms": 50.0,
-    "compact_delta_frac": 0.25,
+    "compact_delta_frac": 2.0 ** -13,
     "compact_min_rows": 64,
 }
 

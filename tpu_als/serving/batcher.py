@@ -77,11 +77,14 @@ class Ticket:
     (``obs.tracing``, None when disarmed): the ticket carries it into
     the batch, and each hop replaces it with the child context so the
     chain admission -> queue -> round -> score is one linked trail.
+    ``seq`` is the publish sequence number of the generation that
+    answered it, stamped where its batch read the live generation (None
+    until then): every score and id of the answer is that generation's.
     """
 
     __slots__ = ("payload", "k", "deadline", "trace", "t_submit",
-                 "t_dequeue", "t_done", "t_admit", "_event", "_result",
-                 "_error")
+                 "t_dequeue", "t_done", "t_admit", "seq", "_event",
+                 "_result", "_error")
 
     def __init__(self, payload, k, deadline, trace=None):
         self.payload = payload
@@ -92,6 +95,7 @@ class Ticket:
         self.t_dequeue = None
         self.t_done = None     # answered or failed (perf_counter, as above)
         self.t_admit = None    # admission DURATION (engine submit -> queued)
+        self.seq = None        # the generation that answered (engine)
         self._event = threading.Event()
         self._result = None
         self._error = None

@@ -66,10 +66,18 @@ stack plugs into:
   the donating call and the swap; a batch still in flight was
   dispatched before the write and reads the old rows.  No shape changes, the pinned executables stay valid, and
   nothing of the catalog crosses host→device; a user-only fold-in re-tags the
-  current index (zero quantization), an item fold-in re-quantizes ONLY
-  the touched/appended rows into the index's delta segment
-  (``serving/index.py``), and the segment is folded back into the base
-  when it crosses the planner-resolved compaction threshold.  Every
+  current index (zero quantization).  An item fold-in uploads ONLY the
+  touched/appended rows, once: they are quantized on the device into
+  the index's delta segment (``serving/index.py``; a fixed number of
+  slots, so the one scoring program "with a segment" a bucket keeps its
+  shapes) and written, from the same device arrays, into the engine's
+  own catalog in place (:func:`_scatter_items`, donated like the user
+  table and under the same lock); appended items fall on the spare
+  rows :meth:`ServingEngine.warmup_live` gave the catalog.  The segment
+  is folded back into the base arrays, IN PLACE (they are donated:
+  :meth:`ServingEngine._compact_live`), when it crosses the
+  planner-resolved compaction threshold: ONE generation of the catalog
+  on the device, whatever is published.  Every
   mode lands in the ``serving.publish_seconds`` histogram so the
   O(touched)-vs-O(catalog) publish cost claim is measured, not assumed.
 - **Fault points.**  ``serving.publish`` fires inside publish (corrupt
@@ -160,7 +168,7 @@ from tpu_als.core.ratings import (
 from tpu_als.obs import tracing
 from tpu_als.obs.schema import SERVE_BATCH_SPAN_KEYS, SERVE_MESH_SCOPES
 from tpu_als.obs.trace import FlightRecorder
-from tpu_als.ops.topk import chunked_topk_scores
+from tpu_als.ops.topk import chunked_topk_scores, shortlist_plan
 from tpu_als.resilience import faults
 from tpu_als.serving.batcher import (
     DEFAULT_BUCKETS,
@@ -173,10 +181,13 @@ from tpu_als.serving.index import (
     Int8CandidateIndex,
     ShardedInt8Index,
     _int8_topk,
+    _int8_topk_delta,
+    _next_pow2,
     _shard_merge,
     _shard_score,
     mesh_exchange_bytes,
     place_catalog,
+    segment_write_bytes,
 )
 
 
@@ -289,11 +300,22 @@ def _serve_exact_packed(U, V, valid, packed, *, k, item_chunk):
 
 @functools.partial(jax.jit, static_argnames=("k", "shortlist_k"))
 def _serve_int8_packed(U, Vq, sv, V, valid, packed, *, k, shortlist_k):
-    """Whole delta-free int8 request path as one pinnable executable
-    (the delta path stays on ``index.topk`` — its executables are
-    pre-compiled by :meth:`ServingEngine.warmup_live` instead)."""
+    """Whole delta-free int8 request path as one pinnable executable."""
     Ub = _select_packed(U, packed)
     s, ix = _int8_topk(Ub, Vq, sv, V, valid, k=k, shortlist_k=shortlist_k)
+    return _pack_response(s, ix)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "shortlist_k"))
+def _serve_int8_delta_packed(U, Vq, sv, V, valid, drows, dVq, dsv, dV,
+                             dvalid, last_id, packed, *, k, shortlist_k):
+    """The same with a delta segment beside the base arrays
+    (``serving.index._int8_topk_delta``): the one program a bucket an
+    engine whose catalog moves runs, whatever the segment holds — its
+    slots are fixed (:meth:`ServingEngine.warmup_live` pins it)."""
+    Ub = _select_packed(U, packed)
+    s, ix = _int8_topk_delta(Ub, Vq, sv, V, valid, drows, dVq, dsv, dV,
+                             dvalid, last_id, k=k, shortlist_k=shortlist_k)
     return _pack_response(s, ix)
 
 
@@ -310,6 +332,18 @@ def _scatter_users(U, rows, vals):
     touched payload crosses host→device."""
     with jax.named_scope("live.publish.scatter"):
         return U.at[rows].set(vals, mode="drop")
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _scatter_items(V, valid, rows, vals, ok):
+    """:func:`_scatter_users` for the engine's own catalog (what the
+    exact fallback scores): ``vals`` and their ``ok`` bits written at
+    ``rows`` of ``V`` and ``valid``, IN PLACE, both donated; appended
+    items fall on spare rows.  Same order on the device, same lock on
+    the host."""
+    with jax.named_scope("live.publish.scatter_items"):
+        return (V.at[rows].set(vals, mode="drop"),
+                valid.at[rows].set(ok, mode="drop"))
 
 
 def _mesh_lookup(U, packed, *, me, axis):
@@ -585,16 +619,21 @@ class ServingEngine:
         return ("replaced",) + self._place_users(prev, U)
 
     def _swap(self, how, users, seq, n_users, V, valid, index, n_items,
-              host=None):
+              host=None, items=None):
         """Install the next generation, the one place that assigns
-        ``_model``; returns ``how`` it got its user table.  ``users`` is
+        ``_model`` (but for :meth:`_compact_live`, which installs the
+        same generation compacted); returns ``how`` it got its user
+        table.  ``users`` is
         that table, or with ``how == "inplace"`` the ``(rows, vals)`` to
-        write into the live one first.  The donating call and the swap
+        write into the live one first.  ``items``: the ``(rows, vals,
+        ok)`` to write into the live generation's OWN catalog first
+        (:func:`_scatter_items`; ``V``/``valid`` are then ignored).  The
+        donating calls and the swap
         are one step under ``_table_lock``: the engine thread reads the
-        live generation and scores against its table under the same
-        lock, so it never holds a deleted one.  The call is asynchronous
-        and its uploads were made before: the lock is held for the
-        dispatch alone.
+        live generation and scores against its tables under the same
+        lock, so it never holds a deleted one.  The calls are asynchronous
+        and their uploads were made before: the lock is held for the
+        dispatches alone.
 
         A row write that raises AFTER the donation took effect leaves no
         table at all.  With ``host``, the whole table the rows came
@@ -622,9 +661,70 @@ class ServingEngine:
                     # a deleted table still reads its shape: same capacity
                     how, users = "replaced", self._place_users(
                         self._model, host)[0]
+            if items is not None:
+                V, valid = _scatter_items(self._model.V, self._model.valid,
+                                          *items)
             self._model = _Published(seq, users, n_users, V, valid, index,
                                      n_items)
         return how
+
+    def _compact_rows(self, index):
+        """Rows in the delta segment from which a publish folds it back
+        into the base: the planner cadence's ``max(compact_min_rows,
+        compact_delta_frac * catalog)``."""
+        cad = self._live_cadence()
+        return max(cad["compact_min_rows"],
+                   cad["compact_delta_frac"] * index.n_items)
+
+    def _segment_slots(self, index, at_least=None):
+        """Slots of the delta segment of an index this engine serves
+        from: the compaction threshold (:meth:`_compact_rows`) plus one
+        ``max_batch`` — the most a segment holds before a publish folds
+        it back — or ``at_least`` where a caller asks for more, as a
+        power of two, in whole blocks of the shortlist over base +
+        segment so that no batch pays for a ragged last block.  Resolved
+        here, once: a segment has no other size while the engine serves
+        from it."""
+        rows = max(self._compact_rows(index)
+                   + self._live_cadence()["max_batch"], at_least or 0)
+        slots = _next_pow2(int(np.ceil(rows)))
+        cols = index.shortlist_plan().columns - index.delta_slots
+        plan = shortlist_plan(cols + slots, index.shortlist_k)
+        if plan.stages == 2:
+            slots = -(-slots // plan.block_len) * plan.block_len
+        return max(slots, index.delta_slots)
+
+    def _compact_live(self):
+        """Fold the live generation's delta segment into its base
+        arrays, IN PLACE (``Int8CandidateIndex.compact``: they are
+        donated to the scatter), and install the same generation with
+        the compacted index, one step under ``_table_lock`` like a row
+        write: compaction changes no answer, so ``seq`` stays.  A batch
+        dispatched before it reads the old arrays whole.  An index whose
+        catalog has outgrown its spare rows is enlarged first (a copy,
+        new shapes: the pinned programs go stale; warned)."""
+        with TraceAnnotation("live.batch.publish.compact"), \
+                self._table_lock:
+            m = self._model
+            if m.index.n_items > m.index.n_base:
+                obs.emit("warning", what="serving.publish_update",
+                         reason=f"{m.index.n_items} items against "
+                                f"{m.index.n_base} catalog rows: spare "
+                                "rows used up, base arrays copied larger")
+            rows = m.index.delta_count
+            try:
+                index = m.index.compact(m.index.seq)
+            except Exception as e:
+                # the donation may have taken the base arrays: serve
+                # exact until the next publish_update rebuilds the index
+                obs.emit("warning", what="serving.publish_update",
+                         reason="compaction failed, index dropped "
+                                f"({type(e).__name__}: {e})")
+                index = None
+            self._model = _Published(m.seq, m.U, m.n_users, m.V, m.valid,
+                                     index, m.n_items)
+        obs.emit("serving_compaction", seq=m.seq, rows=rows,
+                 **self._labels)
 
     def publish(self, U, V, item_valid=None, quantize=True):
         """Swap in a new model generation atomically.
@@ -680,6 +780,48 @@ class ServingEngine:
                  **self._labels)
         return seq
 
+    def _write_catalog(self, Vh, rows, item_valid, seq):
+        """The item side of a ``publish_update`` with a live index:
+        ``rows`` of the host's catalog ``Vh`` (touched and appended, in
+        order) uploaded alone and written into the index's delta segment
+        — folded into the base first where it has no room for them —
+        and handed on, on the device, for the engine's own table.
+        Returns ``(index, the (rows, vals, ok) that _swap writes in
+        place or None where that table cannot take them, bytes sent,
+        whether a compaction ran)``; a row outside the catalog raises
+        ``ValueError``."""
+        prev, Ni = self._model, int(Vh.shape[0])
+        cur = prev.index
+        if int(rows[-1]) >= Ni:
+            raise ValueError(f"touched row {int(rows[-1])} outside "
+                             f"the catalog [0, {Ni})")
+        vrs = np.ascontiguousarray(Vh[rows], dtype=np.float32)
+        vls = (np.ones(len(rows), dtype=bool)
+               if item_valid is None else item_valid[rows])
+        slots, compacted = self._segment_slots(cur), False
+        if cur.delta_count and (cur.slots_needed(rows)
+                                > slots - cur.delta_count):
+            self._compact_live()
+            prev, compacted = self._model, True
+            cur = prev.index
+            if cur is None:
+                raise ValueError("the index was lost in its compaction")
+        if cur.delta_slots < slots:     # an engine nobody warmed up
+            cur = cur.reserve(slots=slots)
+        index = cur.with_updates(rows, vrs, valid_rows=vls, seq=seq)
+        sent, items = segment_write_bytes(len(rows), prev.rank), None
+        cap = int(prev.V.shape[0])
+        if self.mesh is None and prev.n_items <= Ni <= cap:
+            # the engine's own catalog takes the same rows, as they
+            # already lie on the device (padding ids fall outside it)
+            items = index.written
+        elif self.mesh is None:
+            obs.emit("warning", what="serving.publish_update",
+                     reason=f"{Ni} items against {cap} catalog rows: "
+                            "spare rows used up, the engine's catalog "
+                            "re-placed whole")
+        return index, items, sent, compacted
+
     def publish_update(self, U, V, *, touched_items=None,
                        touched_users=None, item_valid=None, trace=None):
         """Incremental publish after a fold-in: O(touched rows), not
@@ -714,14 +856,21 @@ class ServingEngine:
         - ``retag``  — nothing in the catalog changed (user-only
           fold-in): the live index and the device's catalog are carried,
           zero quantization, nothing of ``V`` uploaded;
-        - ``delta``  — touched/appended rows quantized into the delta
-          segment;
-        - ``compact``— the segment crossed the planner-resolved
-          threshold and was folded back into the base (memcpy-class);
+        - ``delta``  — touched/appended rows uploaded alone, quantized
+          on the device into the delta segment and written in place
+          into the engine's own catalog (``live.catalog_h2d_bytes``
+          counts what went up);
+        - ``compact``— the same, and the segment was folded into the
+          base arrays in place (:meth:`_compact_live`): it had crossed
+          the planner-resolved threshold, or had no room for the rows;
         - ``full``   — no usable live index (first publish, stale or
           exact-mode predecessor, catalog shrank, or a malformed
           update) → ordinary full rebuild;
         - ``none``   — catalog too small to index; serving stays exact.
+
+        ``serving.catalog_writes{how=carried|delta|compact|replaced}``
+        and ``catalog=`` on the ``serving_publish`` event say what
+        became of the catalog.
         """
         t0 = time.perf_counter()
         # keep a host handle: the delta path gathers only the touched
@@ -731,53 +880,55 @@ class ServingEngine:
         Vh = (V if isinstance(V, np.ndarray)
               else np.asarray(V, dtype=np.float32))
         Ni = int(Vh.shape[0])
-        valid_h = (np.ones(Ni, dtype=bool) if item_valid is None
-                   else np.asarray(item_valid, dtype=bool))
+        if item_valid is not None:
+            item_valid = np.asarray(item_valid, dtype=bool)
         self._announce_mesh()
         touched = (np.empty(0, dtype=np.int64) if touched_items is None
                    else np.unique(np.asarray(touched_items,
                                              dtype=np.int64).ravel()))
-        cad = self._live_cadence()
         with self._publish_lock:
             seq = self._seq + 1
             prev = self._model
             how, users, n_users, h2d = self._update_users(
                 prev, U, touched_users)
+            cur = prev.index if prev is not None else None
+            fresh = (cur is not None and cur.seq == prev.seq
+                     and cur.n_items <= Ni)
+            rows = (np.union1d(touched, np.arange(cur.n_items, Ni))
+                    if fresh else touched)
+            # what becomes of the catalog: ``carried`` as it is, or the
+            # touched rows written (``delta``), or uploaded whole
+            # (``replaced``); ``items``: the rows for the engine's own
+            # table, written in place by ``_swap``
+            catalog, items, index, mode, sent = "replaced", None, None, \
+                "full", 0
+            compacted = False
             if (prev is not None and not touched.size
                     and item_valid is None and prev.n_items == Ni):
                 # nothing of the catalog changed: the device's copy stays
-                V, valid = prev.V, prev.valid
-            else:
-                V, valid = self._place_catalog(Vh, valid_h)
-                h2d += Vh.nbytes + valid_h.nbytes
-            cur = prev.index if prev is not None else None
-            index, mode = None, "full"
-            if (cur is not None and cur.seq == prev.seq
-                    and cur.n_items <= Ni):
+                V, valid, catalog = prev.V, prev.valid, "carried"
+                if fresh:
+                    index, mode = cur.retag(seq), "retag"
+            elif fresh and not rows.size:
+                # a validity mask alone, no row named: the index is
+                # carried as it is (the caller's guarantee)
+                index, mode = cur.retag(seq), "retag"
+            elif fresh:
                 try:
-                    if touched.size == 0 and Ni == cur.n_items:
-                        index, mode = cur.retag(seq), "retag"
-                    else:
-                        rows = np.union1d(touched,
-                                          np.arange(cur.n_items, Ni))
-                        if rows.size and int(rows[-1]) >= Ni:
-                            raise ValueError(
-                                f"touched row {int(rows[-1])} outside "
-                                f"the catalog [0, {Ni})")
-                        vrs = np.ascontiguousarray(
-                            Vh[rows], dtype=np.float32)
-                        vls = valid_h[rows]
-                        index = cur.with_updates(rows, vrs,
-                                                 valid_rows=vls, seq=seq)
-                        mode = "delta"
-                        if index.delta_count >= max(
-                                cad["compact_min_rows"],
-                                cad["compact_delta_frac"] * index.n_base):
-                            index, mode = index.compact(seq), "compact"
+                    index, items, sent, compacted = self._write_catalog(
+                        Vh, rows, item_valid, seq)
+                    mode, catalog, prev = "delta", "delta", self._model
+                    V, valid = prev.V, prev.valid
                 except ValueError as e:
                     obs.emit("warning", what="serving.publish_update",
                              reason=f"delta rejected, full rebuild: {e}")
-                    index, mode = None, "full"
+            if catalog != "carried" and items is None:
+                # no row write to be had (no live index to take the
+                # rows, a mesh, spare rows used up): the whole catalog
+                valid_h = (np.ones(Ni, dtype=bool) if item_valid is None
+                           else item_valid)
+                V, valid = self._place_catalog(Vh, valid_h)
+                sent += Vh.nbytes + valid_h.nbytes
             if index is None:
                 sk = min(max(self.shortlist_k, self.k), Ni)
                 if sk >= self.k and Ni > 0:
@@ -787,11 +938,21 @@ class ServingEngine:
             # last: every step above may raise or take long (an index
             # build), and from the row write on the old table is gone
             how = self._swap(how, users, seq, n_users, V, valid, index, Ni,
-                             host=U)
+                             host=U, items=items)
             self._seq = seq
+            if (mode == "delta"
+                    and index.delta_count >= self._compact_rows(index)):
+                # past the cadence's threshold: folded into the base
+                # now, so that the next publish finds an empty segment
+                self._compact_live()
+                index, compacted = self._model.index, True
+            if compacted:
+                mode = catalog = "compact"
         obs.counter("serving.publishes", **self._labels)
         obs.counter("serving.user_table_writes", how=how, **self._labels)
+        obs.counter("serving.catalog_writes", how=catalog, **self._labels)
         obs.counter("live.publish_h2d_bytes", h2d, **self._labels)
+        obs.counter("live.catalog_h2d_bytes", sent, **self._labels)
         obs.histogram("serving.publish_seconds",
                       time.perf_counter() - t0, mode=mode,
                       **self._labels)
@@ -802,7 +963,7 @@ class ServingEngine:
                  quantized=bool(index is not None), mode=mode,
                  delta_rows=(index.delta_count
                              if index is not None else 0),
-                 users=how, **linked, **self._labels)
+                 users=how, catalog=catalog, **linked, **self._labels)
         return seq, mode
 
     def _live_cadence(self):
@@ -858,16 +1019,13 @@ class ServingEngine:
                 idx = m.index
                 if idx is not None and idx.seq == m.seq:
                     self._emit_shortlist(B, idx)
-                    if not idx.delta_count:
-                        fn, args, statics = self._int8_call(m, idx, proto)
-                        self._pinned[(B, "int8")] = fn.lower(
-                            *args, **statics).compile()
-                        if self.mesh is not None:
-                            obs.emit("serving_mesh_plan", bucket=B,
-                                     **self._mesh_plan(m, idx, B),
-                                     **self._labels)
-                    else:
-                        self._score_delta(m, idx, proto).block_until_ready()
+                    fn, args, statics = self._int8_call(m, idx, proto)
+                    self._pinned[(B, self._int8_pin(idx))] = fn.lower(
+                        *args, **statics).compile()
+                    if self.mesh is not None:
+                        obs.emit("serving_mesh_plan", bucket=B,
+                                 **self._mesh_plan(m, idx, B),
+                                 **self._labels)
                 # the exact path backs every fallback: always warm
                 fn, args, statics = self._exact_call(m, proto)
                 self._pinned[(B, "exact")] = fn.lower(
@@ -881,20 +1039,32 @@ class ServingEngine:
 
     def _int8_call(self, m, idx, packed):
         """``(jitted function, arguments, static arguments)`` of the
-        delta-free int8 request path for one staged batch: the one
-        program :meth:`warmup` pins and :meth:`_dispatch` runs."""
-        if self.mesh is None:
-            return (_serve_int8_packed,
-                    (m.U, idx.Vq, idx.sv, idx.V, idx.valid, packed),
-                    dict(k=self.k, shortlist_k=idx.shortlist_k))
-        return (*self._mesh_serve_call(m, idx, packed), {})
+        int8 request path for one staged batch, scored by ``idx`` as it
+        stands — with a delta segment (whatever it holds: its slots fix
+        the shapes) or without one: the one program :meth:`warmup` /
+        :meth:`warmup_live` pins (under :meth:`_int8_pin`) and
+        :meth:`_dispatch` runs."""
+        if self.mesh is not None:
+            return (*self._mesh_serve_call(m, idx, packed), {})
+        statics = dict(k=self.k, shortlist_k=idx.shortlist_k)
+        if idx.delta_slots:
+            return (_serve_int8_delta_packed,
+                    (m.U, idx.Vq, idx.sv, idx.V, idx.valid, *idx._seg,
+                     idx._last_id(), packed), statics)
+        return (_serve_int8_packed,
+                (m.U, idx.Vq, idx.sv, idx.V, idx.valid, packed), statics)
+
+    @staticmethod
+    def _int8_pin(idx):
+        """The name :meth:`_int8_call`'s program is pinned under."""
+        return "int8_delta" if idx.delta_slots else "int8"
 
     def _mesh_serve_call(self, m, idx, packed):
         """``(jitted function, arguments)`` of a mesh engine's one int8
         program for this index as it stands, delta segment or none."""
         k_loc, sk_loc = idx.shard_widths(self.k)
         return (_build_mesh_serve(self.mesh, self.k, k_loc, sk_loc,
-                                  idx.ni_loc, bool(idx.delta_count)),
+                                  idx.ni_loc, bool(idx.delta_slots)),
                 (m.U, packed, *idx.score_args()))
 
     def _exact_call(self, m, packed):
@@ -910,16 +1080,6 @@ class ServingEngine:
                 (m.U, packed, m.V, m.valid,
                  jax.device_put(np.int32(m.n_items - 1), self._replicated)),
                 {})
-
-    def _score_delta(self, m, idx, packed):
-        """The packed response of an index with a live delta segment,
-        through the jit cache (:meth:`warmup_live` compiles its
-        programs): three calls without a mesh, one with."""
-        if self.mesh is None:
-            s, ix = idx.topk(_select_packed(m.U, packed), self.k)
-            return _pack_response(s, ix)
-        fn, args = self._mesh_serve_call(m, idx, packed)
-        return fn(*args)
 
     def _mesh_plan(self, m, idx, bucket):
         """What one batch of ``bucket`` rows costs the mesh, scored by
@@ -962,44 +1122,74 @@ class ServingEngine:
                     m.seq, m.n_users, m.V, m.valid, m.index, m.n_items)
             self._model.U.block_until_ready()
 
-    def warmup_live(self, max_delta_rows=None):
-        """Compile the DELTA-path scoring executables incremental
-        publishes can produce — one per (bucket, delta-pad) pair —
-        before any live traffic, so a growing delta segment never puts
-        a compile on the request path.
+    def warmup_live(self, max_delta_rows=None, max_rows=LIVE_PADS[-1]):
+        """Make the published generation ready for a catalog that moves
+        (``publish_update(touched_items=...)``), before any live
+        traffic, so that no item publish changes a shape, compiles or
+        runs a program for the first time under it:
 
-        Delta pads are the power-of-two ladder up to
-        ``max_delta_rows`` (default: the planner cadence's compaction
-        threshold plus one max_batch — the largest segment a publish
-        can carry before ``publish_update`` folds it back into the
-        base).  Cheap no-op when the model serves exact.  Holds
-        ``_table_lock`` throughout, like :meth:`warmup`.
+        - the catalog gets SPARE ROWS (``core.ratings.row_capacity``, the
+          user table's rule; the quantized rows in whole shortlist
+          blocks of them) in the index's base arrays and in the
+          engine's own table — one copy of each on the device, here —
+          and the index its delta segment, at its one size
+          (:meth:`_segment_slots`; ``max_delta_rows`` asks for at least
+          that many);
+        - the scoring program "with a segment" is pinned for every
+          bucket AND run once, the exact fallback pinned again at the
+          new shapes; the delta-free pins are dropped (an index with a
+          segment never runs them);
+        - the three write programs are run on the live arrays, writing
+          nothing: the engine's catalog row write and the segment's, at
+          every padded size up to ``max_rows`` rows a publish, and the
+          compaction (each donates what it is given; the results, the
+          same buffers with the same values, are installed).
+
+        ``LiveUpdater.start`` calls it when ``fold_items`` is on.  Cheap
+        no-op when the model serves exact.  ``seq`` does not move.
         """
-        with self._table_lock:
+        with self._publish_lock, self._table_lock:
             m = self._model
             if m is None:
                 raise NoModelPublished("publish(U, V) before warmup")
             idx = m.index
             if idx is None or idx.seq != m.seq:
                 return
-            if max_delta_rows is None:
-                cad = self._live_cadence()
-                max_delta_rows = int(
-                    max(cad["compact_min_rows"],
-                        cad["compact_delta_frac"] * idx.n_base)
-                    + cad["max_batch"])
-            top = min(max_delta_rows * 2 - 1, idx.n_items)
-            Vh = np.asarray(m.V[:top], dtype=np.float32)
-            d = 1
-            while d <= top:
-                rows = np.arange(d, dtype=np.int64)
-                dummy = idx.with_updates(
-                    rows, np.ascontiguousarray(Vh[rows]), seq=idx.seq)
-                for B in self.batcher.buckets:
-                    proto = self._proto(B, m.rank)
-                    self._score_delta(m, dummy, proto).block_until_ready()
-                    self._emit_shortlist(B, dummy, delta_rows=d)
-                d <<= 1
+            rows = (idx.n_base if idx.n_base > idx.n_items
+                    else row_capacity(idx.n_items))
+            idx = idx.reserve(rows, self._segment_slots(idx,
+                                                        max_delta_rows))
+            # installed at once: the smaller base arrays go before the
+            # engine's own table is copied larger (one table's worth of
+            # room at a time)
+            m = self._model = _Published(m.seq, m.U, m.n_users, m.V,
+                                         m.valid, idx, m.n_items)
+            V, valid = m.V, m.valid
+            if self.mesh is None and int(V.shape[0]) < idx.n_base:
+                more = idx.n_base - int(V.shape[0])
+                V = jnp.pad(V, ((0, more), (0, 0)))
+                valid = jnp.pad(valid, (0, more))
+            if self.mesh is None:
+                for pad in pads_up_to(max_rows):
+                    # every row the out-of-range sentinel: nothing written
+                    V, valid = _scatter_items(V, valid, *jax.device_put((
+                        np.full(pad, V.shape[0], np.int32),
+                        np.zeros((pad, m.rank), np.float32),
+                        np.zeros(pad, bool))))
+            idx = idx.prewarm(max_rows)
+            m = self._model = _Published(m.seq, m.U, m.n_users, V, valid,
+                                         idx, m.n_items)
+            for B in self.batcher.buckets:
+                proto = self._proto(B, m.rank)
+                self._pinned.pop((B, "int8"), None)
+                fn, args, statics = self._int8_call(m, idx, proto)
+                c = self._pinned[(B, self._int8_pin(idx))] = fn.lower(
+                    *args, **statics).compile()
+                c(*args).block_until_ready()
+                self._emit_shortlist(B, idx, delta_rows=idx.delta_slots)
+                fn, args, statics = self._exact_call(m, proto)
+                self._pinned[(B, "exact")] = fn.lower(
+                    *args, **statics).compile()
 
     @staticmethod
     def _emit_shortlist(bucket, index, **extra):
@@ -1230,6 +1420,8 @@ class ServingEngine:
                 mode = faults.check("serving.score")
                 m = self._model
                 n = len(live)
+                for t in live:      # the generation that answers them
+                    t.seq = m.seq
                 B = bucket_for(n, self.batcher.buckets)
                 st = self._staged(live, B, m.rank)
                 obs.histogram("serving.batch_rows", n, **self._labels)
@@ -1387,7 +1579,6 @@ class ServingEngine:
             path, pin, call = "exact", "exact", self._exact_call(m, packed)
         else:
             path = "int8" if self.mesh is None else "int8_sharded"
-            if index.delta_count:
-                return self._score_delta(m, index, packed), path, fell_back
-            pin, call = "int8", self._int8_call(m, index, packed)
+            pin, call = (self._int8_pin(index),
+                         self._int8_call(m, index, packed))
         return self._run_pinned((B, pin), *call), path, fell_back

@@ -81,6 +81,20 @@ approximate:
 Base rows overridden by the delta are masked to ``NEG_INF`` in the
 base GEMM (their fresh values live in the segment), so a row is never
 scored twice and never scored stale.
+
+The segment lives on the DEVICE, in arrays of a fixed number of slots
+(:attr:`Int8CandidateIndex.delta_slots`; free slots carry
+:data:`SLOT_FREE` and ``valid=False``): ``with_updates`` uploads the
+touched rows alone, quantizes them there and writes them into their
+slots (a row already in the segment keeps its slot), so a row is
+quantized and uploaded once and the scoring program's shapes do not
+move while the segment has room.  The host keeps only which catalog id
+sits in which slot.  :meth:`Int8CandidateIndex.reserve` gives the base
+arrays spare rows and the segment its slots ahead of traffic;
+:meth:`compact` scatters the segment into the base arrays IN PLACE (they
+are donated: the index it is called on, and every index that shares its
+base arrays, is spent afterwards — as a generation's user table after a
+row write, ``serving/engine.py``).
 """
 
 from __future__ import annotations
@@ -91,7 +105,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_als.core.ratings import _next_pow2
+from tpu_als.core.ratings import _next_pow2, pad_for, pads_up_to
 from tpu_als.ops.topk import (
     NEG_INF,
     shortlist_columns,
@@ -102,6 +116,11 @@ from tpu_als.ops.topk import (
 # how far a rescored score may sit from the chunked kernel's, in units in
 # the last place of the row's largest score (module docstring)
 SCORE_ULPS = 16
+
+# the catalog id a free slot of the delta segment carries: outside every
+# catalog, so the scatters that read it (the override mask, the
+# compaction) drop it whatever the base arrays' size
+SLOT_FREE = np.iinfo(np.int32).max
 
 
 @functools.partial(jax.jit, static_argnames=("pad",))
@@ -151,10 +170,10 @@ def _int8_topk_delta(U, Vq, sv, V, valid, drows, dVq, dsv, dV, dvalid,
     concatenated approx scores, and the SAME-shaped exact rescore as
     the base path (see module docstring for why this stays bitwise).
 
-    ``drows`` maps segment slots to logical catalog ids; padding slots
-    carry ``Vq.shape[0]`` (out of base scatter range, ``dvalid`` False).
-    An appended id may fall on a block-padding column of ``Vq``: invalid
-    already, so marking it overridden changes nothing.
+    ``drows`` maps segment slots to logical catalog ids; free slots
+    carry :data:`SLOT_FREE` (out of every scatter's range, ``dvalid``
+    False).  An appended id may fall on a spare or block-padding column
+    of ``Vq``: invalid already, so marking it overridden changes nothing.
     ``last_id`` clamps returned ids into the logical catalog.
     """
     n = U.shape[0]
@@ -194,6 +213,52 @@ def _int8_topk_delta(U, Vq, sv, V, valid, drows, dVq, dsv, dV, dvalid,
     return s, jnp.take_along_axis(logical, sel, axis=1)
 
 
+@jax.jit
+def _write_segment(drows, dVq, dsv, dV, dvalid, slots, ids, rows, valid):
+    """``rows`` (f32, with their catalog ``ids`` and ``valid`` bits)
+    into ``slots`` of the delta segment, quantized here — per row, so
+    bit for bit what a rebuild of the whole catalog would hold.  The
+    update is padded up ``pad_for``'s ladder with slots outside the
+    segment, which are dropped: few programs, and only the touched
+    payload crosses host→device.  Nothing is donated: the generation
+    before scores against its own segment, and a segment is a few MB."""
+    with jax.named_scope("live.publish.scatter_items"):
+        q, s = _quantize_rows(rows)
+        return (drows.at[slots].set(ids, mode="drop"),
+                dVq.at[slots].set(q, mode="drop"),
+                dsv.at[slots].set(s, mode="drop"),
+                dV.at[slots].set(rows, mode="drop"),
+                dvalid.at[slots].set(valid, mode="drop"))
+
+
+def _fold_segment(V, Vq, sv, valid, drows, dVq, dsv, dV, dvalid):
+    """The segment's rows scattered into the base arrays at their
+    catalog ids (free slots fall outside and are dropped), and the
+    emptied slot map: a compaction.  Nothing is quantized again."""
+    with jax.named_scope("live.publish.compact"):
+        return (V.at[drows].set(dV, mode="drop"),
+                Vq.at[drows].set(dVq, mode="drop"),
+                sv.at[drows].set(dsv, mode="drop"),
+                valid.at[drows].set(dvalid, mode="drop"),
+                jnp.full_like(drows, SLOT_FREE), jnp.zeros_like(dvalid))
+
+
+# the base arrays donated: the same buffers come back with the segment's
+# rows written, no copy of the catalog on the device; the caller's
+# handles are deleted (``Int8CandidateIndex.compact``)
+_fold_segment_inplace = jax.jit(_fold_segment, donate_argnums=(0, 1, 2, 3))
+# for base arrays sharded over a mesh, which are re-placed afterwards
+_fold_segment_copied = jax.jit(_fold_segment)
+
+
+def segment_write_bytes(n_rows, rank):
+    """Bytes :meth:`Int8CandidateIndex.with_updates` uploads for
+    ``n_rows`` touched rows: slot, id, f32 row and valid bit of each,
+    padded up the ladder (the engine's own table takes the same arrays
+    on the device: no second upload)."""
+    return pad_for(n_rows) * (4 + 4 + 4 * int(rank) + 1)
+
+
 class Int8CandidateIndex:
     """Quantize-once-per-publish candidate index over the item factors.
 
@@ -231,19 +296,24 @@ class Int8CandidateIndex:
     # -- delta segment (incremental re-quantization) -------------------
 
     def _clear_delta(self):
-        # host-side merged delta state (small: O(delta rows)); the
-        # padded device mirrors the kernel consumes are built lazily
+        # the host's part of the segment: which catalog id sits in which
+        # slot, in slot order (O(delta rows)); the rows themselves are on
+        # the device, once
         self.d_rows = np.empty(0, dtype=np.int64)
-        self._dV = np.empty((0, int(self.V.shape[1])), dtype=np.float32)
-        self._dVq = np.empty((0, int(self.V.shape[1])), dtype=np.int8)
-        self._dsv = np.empty(0, dtype=np.float32)
-        self._dvalid = np.empty(0, dtype=bool)
-        self._dev_delta = None
+        # (drows, dVq, dsv, dV, dvalid), ``delta_slots`` slots each
+        self._seg = None
+        self._last = None
+        self.written = None     # with_updates: what it uploaded
+
+    @staticmethod
+    def _put(arrays):
+        """Host arrays onto the device(s) the segment lives on."""
+        return jax.device_put(arrays)
 
     @property
     def n_base(self):
-        """Rows held by the base (pre-delta) arrays, block padding of
-        the quantized ones not counted."""
+        """Rows held by the base (pre-delta) arrays, spare rows counted,
+        block padding of the quantized ones not."""
         return int(self.V.shape[0])
 
     @property
@@ -253,9 +323,9 @@ class Int8CandidateIndex:
 
     @property
     def delta_slots(self):
-        """Columns the delta segment adds to the score matrix: its rows
-        padded to a power of two (0 without a segment)."""
-        return _next_pow2(self.delta_count) if self.delta_count else 0
+        """Columns the delta segment adds to the score matrix: the slots
+        it has, used or free (0 without a segment)."""
+        return 0 if self._seg is None else int(self._seg[0].shape[0])
 
     def _copy_shell(self, seq):
         new = object.__new__(type(self))
@@ -264,10 +334,8 @@ class Int8CandidateIndex:
         new.n_items = self.n_items
         new.shortlist_k = self.shortlist_k
         new.seq = self.seq if seq is None else int(seq)
-        new.d_rows = self.d_rows
-        new._dV, new._dVq = self._dV, self._dVq
-        new._dsv, new._dvalid = self._dsv, self._dvalid
-        new._dev_delta = self._dev_delta
+        new.d_rows, new._seg, new._last = self.d_rows, self._seg, self._last
+        new.written = None
         self._copy_extra(new)
         return new
 
@@ -285,17 +353,76 @@ class Int8CandidateIndex:
         """
         return self._copy_shell(seq)
 
+    def _with_slots(self, slots):
+        """The segment with ``slots`` slots, what it holds kept: a new
+        set of (small) arrays, and new shapes for the scoring program."""
+        r = int(self.V.shape[1])
+        seg = self._put((np.full(slots, SLOT_FREE, np.int32),
+                         np.zeros((slots, r), np.int8),
+                         np.ones(slots, np.float32),
+                         np.zeros((slots, r), np.float32),
+                         np.zeros(slots, bool)))
+        if self._seg is None:
+            return seg
+        old = self.delta_slots
+        return tuple(a.at[:old].set(b) for a, b in zip(seg, self._seg))
+
+    def reserve(self, rows=0, slots=0):
+        """A new index over the same catalog whose base arrays hold
+        ``rows`` catalog rows (spare ones zero and invalid; the
+        quantized arrays in whole shortlist blocks of that many, as
+        :func:`build_index` of ``rows`` items would pad them) and whose
+        segment has ``slots`` slots: appended items and a filling segment
+        then change no array's shape, so whoever serves from the index
+        compiles its programs once.  One copy of each array it enlarges,
+        here; arrays already that large are shared."""
+        new = self._copy_shell(None)
+        nb, r = self.n_base, int(self.V.shape[1])
+        if rows > nb:
+            cols = shortlist_columns(rows, self.shortlist_k)
+            new.V = jnp.pad(self.V, ((0, rows - nb), (0, 0)))
+            # from the catalog's own rows on: the block padding of the
+            # smaller size may reach further than that of the larger
+            new.Vq = jnp.pad(self.Vq[:nb], ((0, cols - nb), (0, 0)))
+            new.sv = jnp.pad(self.sv[:nb], (0, cols - nb),
+                             constant_values=1.0)
+            new.valid = jnp.pad(self.valid[:nb], (0, cols - nb))
+        if slots > self.delta_slots:
+            new._seg = new._with_slots(int(slots))
+        return new
+
+    def _held(self, ids):
+        """``(held, slot)`` for catalog ``ids``: which of them the
+        segment holds, and in which slot (meaningless where not held)."""
+        if not self.delta_count:
+            return (np.zeros(len(ids), dtype=bool),
+                    np.zeros(len(ids), dtype=np.int64))
+        by_id = np.argsort(self.d_rows, kind="stable")
+        at = np.minimum(np.searchsorted(self.d_rows[by_id], ids),
+                        self.delta_count - 1)
+        return self.d_rows[by_id[at]] == ids, by_id[at]
+
+    def slots_needed(self, rows):
+        """How many free slots ``with_updates(rows, ...)`` would take: a
+        row already in the segment keeps its slot."""
+        rows = np.unique(np.asarray(rows, dtype=np.int64).ravel())
+        return int(rows.size - self._held(rows)[0].sum())
+
     def with_updates(self, rows, V_rows, valid_rows=None, seq=None):
         """A new index with ``rows`` of the catalog re-quantized into
-        the delta segment — O(len(rows)) quantization work, the base
-        arrays shared untouched.
+        the delta segment — O(len(rows)) upload and quantization work,
+        the base arrays shared untouched.
 
         ``rows`` are logical catalog ids; ids ``>= n_items`` APPEND
         (catalog growth from an item fold-in) and must leave no hole
         above the current catalog size.  A row already in the segment
-        is replaced (newest wins).  Quantizing only the touched rows is
-        bitwise-identical to a full rebuild because quantization is
-        strictly per-row (the ``live_delta_index`` contract).
+        is replaced in its slot (newest wins).  Quantizing only the
+        touched rows is bitwise-identical to a full rebuild because
+        quantization is strictly per-row (the ``live_delta_index``
+        contract).  A segment without room for the new rows is enlarged
+        to the next power of two (new shapes: whoever must not compile
+        under traffic calls :meth:`reserve` ahead and :meth:`compact`
+        before the segment overflows, as the engine does).
         """
         rows = np.asarray(rows, dtype=np.int64).ravel()
         r = int(self.V.shape[1])
@@ -318,90 +445,114 @@ class Int8CandidateIndex:
             raise ValueError(
                 f"append gap: ids {gap} missing — appended rows must "
                 "be contiguous above the current catalog")
-        # quantize ONLY the touched rows, padded to pow2 so repeated
-        # delta publishes hit a bounded jit cache
-        n_pad = _next_pow2(len(rows))
-        Vp = np.zeros((n_pad, r), dtype=np.float32)
-        Vp[:len(rows)] = V_rows
-        q, s = _quantize_rows(jnp.asarray(Vp))
-        q = np.asarray(q)[:len(rows)]
-        s = np.asarray(s)[:len(rows)]
+        # a row the segment holds keeps its slot, the others take the
+        # next free ones
+        held, slots = self._held(rows)
+        slots = slots.astype(np.int32)
+        slots[~held] = self.delta_count + np.arange(int((~held).sum()))
         new = self._copy_shell(seq)
         new.n_items = n_new
-        if self.d_rows.size:       # merge: older entries for the same
-            keep = ~np.isin(self.d_rows, rows)   # id are superseded
-            new.d_rows = np.concatenate([self.d_rows[keep], rows])
-            new._dV = np.concatenate([self._dV[keep], V_rows])
-            new._dVq = np.concatenate([self._dVq[keep], q])
-            new._dsv = np.concatenate([self._dsv[keep], s])
-            new._dvalid = np.concatenate([self._dvalid[keep], valid_rows])
-        else:
-            new.d_rows, new._dV, new._dVq = rows, V_rows, q
-            new._dsv, new._dvalid = s, valid_rows
-        new._dev_delta = None
+        new.d_rows = np.concatenate([self.d_rows, rows[~held]])
+        seg = self._seg
+        if len(new.d_rows) > self.delta_slots:
+            seg = self._with_slots(_next_pow2(len(new.d_rows)))
+        pad = pad_for(len(rows))
+        sl = np.full(pad, SLOT_FREE, dtype=np.int32)
+        ids = np.full(pad, SLOT_FREE, dtype=np.int32)
+        vals = np.zeros((pad, r), dtype=np.float32)
+        ok = np.zeros(pad, dtype=bool)
+        sl[:len(rows)], ids[:len(rows)] = slots, rows
+        vals[:len(rows)], ok[:len(rows)] = V_rows, valid_rows
+        sl, *written = self._put((sl, ids, vals, ok))
+        new._seg = _write_segment(*seg, sl, *written)
+        # the rows as they went up — ``(ids, rows, valid bits)``, padded
+        # with ids outside any table — for whoever writes them elsewhere
+        # too (the engine's own catalog): nothing is uploaded twice
+        new.written = tuple(written)
+        new._last_id()      # here, not on the request path
         return new
 
     def compact(self, seq=None):
-        """Fold the delta segment back into the base arrays.
+        """Fold the delta segment back into the base arrays, IN PLACE.
 
         A memcpy-class scatter — the segment's already-quantized rows
         are placed, nothing is re-quantized — yielding arrays bitwise
         equal to a full :func:`build_index` rebuild of the updated
-        catalog, and scoring through the identical base kernel again.
+        catalog.  The base arrays are DONATED to the scatter: the same
+        buffers come back, no copy of the catalog on the device, and
+        the index this is called on, with every index that shares its
+        base arrays, is spent (its arrays read "deleted").  Programs run
+        in dispatch order, so a batch dispatched before the call reads
+        the old rows whole.  Where the catalog has outgrown the base
+        arrays they are first enlarged to exactly ``n_items`` rows
+        (:meth:`reserve`: a copy, new shapes).  The emptied segment
+        keeps its slots.
         """
         if not self.d_rows.size:
             return self._copy_shell(seq)
-        r = int(self.V.shape[1])
-        nb = self.n_base
-        grow = self.n_items - nb
-        V, Vq, sv, valid = self.V, self.Vq, self.sv, self.valid
-        if grow:
-            V = jnp.concatenate([V, jnp.zeros((grow, r), jnp.float32)])
-        # appended rows and the grown catalog's block padding in one step
-        cols = shortlist_columns(self.n_items, self.shortlist_k)
-        if cols != int(Vq.shape[0]):
-            more = cols - nb
-            Vq = jnp.concatenate([Vq[:nb], jnp.zeros((more, r), jnp.int8)])
-            sv = jnp.concatenate([sv[:nb], jnp.ones(more, jnp.float32)])
-            valid = jnp.concatenate([valid[:nb],
-                                     jnp.zeros(more, jnp.bool_)])
-        ix = jnp.asarray(self.d_rows, dtype=jnp.int32)
-        new = self._copy_shell(seq)
-        new.V = V.at[ix].set(jnp.asarray(self._dV))
-        new.Vq = Vq.at[ix].set(jnp.asarray(self._dVq))
-        new.sv = sv.at[ix].set(jnp.asarray(self._dsv))
-        new.valid = valid.at[ix].set(jnp.asarray(self._dvalid))
-        new._clear_delta()
+        src = (self if self.n_items <= self.n_base
+               else self.reserve(rows=self.n_items))
+        new = src._copy_shell(seq)
+        _, dVq, dsv, dV, _ = src._seg
+        (new.V, new.Vq, new.sv, new.valid, drows,
+         dvalid) = src._fold(src._seg[0])
+        new.d_rows = np.empty(0, dtype=np.int64)
+        new._seg = (drows, dVq, dsv, dV, dvalid)
         return new
 
-    def _device_delta(self):
-        """Padded device mirrors of the segment (built once per delta
-        generation; padding slots carry the id one past the quantized
-        base — dropped by the kernel's scatter — and ``valid=False``)."""
-        if self._dev_delta is None:
-            d, d_pad = self.delta_count, self.delta_slots
-            r = int(self.V.shape[1])
-            rows = np.full(d_pad, int(self.Vq.shape[0]), dtype=np.int32)
-            rows[:d] = self.d_rows
-            dV = np.zeros((d_pad, r), dtype=np.float32)
-            dV[:d] = self._dV
-            dVq = np.zeros((d_pad, r), dtype=np.int8)
-            dVq[:d] = self._dVq
-            dsv = np.ones(d_pad, dtype=np.float32)
-            dsv[:d] = self._dsv
-            dvalid = np.zeros(d_pad, dtype=bool)
-            dvalid[:d] = self._dvalid
-            self._dev_delta = (jnp.asarray(rows), jnp.asarray(dVq),
-                               jnp.asarray(dsv), jnp.asarray(dV),
-                               jnp.asarray(dvalid))
-        return self._dev_delta
+    def _fold(self, drows):
+        """The segment's rows at catalog ids ``drows`` into the base
+        arrays, which are donated: ``(V, Vq, sv, valid, the emptied slot
+        map, the emptied valid bits)``."""
+        return _fold_segment_inplace(self.V, self.Vq, self.sv, self.valid,
+                                     drows, *self._seg[1:])
+
+    def prewarm(self, max_rows):
+        """Run the segment's row write at every padded size up to
+        ``max_rows`` rows and the compaction once, on this index's own
+        arrays, writing nothing (every slot free): the index to use
+        afterwards — the compaction donated the base arrays it was
+        given, the same buffers with the same values come back."""
+        new = self._copy_shell(None)
+        r = int(self.V.shape[1])
+        for pad in pads_up_to(max_rows):
+            free = np.full(pad, SLOT_FREE, dtype=np.int32)
+            new._seg = _write_segment(*new._seg, *self._put((
+                free, free, np.zeros((pad, r), np.float32),
+                np.zeros(pad, bool))))
+        new.V, new.Vq, new.sv, new.valid, _, _ = new._fold(
+            jnp.full_like(new._seg[0], SLOT_FREE))
+        return new
+
+    def _last_id(self):
+        """The last catalog id, on the device (answers are clamped to
+        it): uploaded once per catalog size."""
+        if self._last is None or self._last[0] != self.n_items:
+            self._last = (self.n_items,
+                          self._put(np.int32(self.n_items - 1)))
+        return self._last[1]
+
+    def rows(self, ids):
+        """``(f32 rows [n, rank], valid bits [n])`` this index serves for
+        catalog ``ids``, read back to the host: the segment's where it
+        holds the id, else the base arrays' — what a rescore multiplies.
+        For whoever checks a publish against what it meant to publish;
+        O(len(ids)) off the device."""
+        ids = np.asarray(ids, dtype=np.int64).ravel()
+        held, slots = self._held(ids)
+        base = np.minimum(ids, self.n_base - 1)
+        rows = np.array(jnp.take(self.V, base, axis=0))
+        ok = np.array(jnp.take(self.valid, base)) & (ids < self.n_base)
+        if held.any():
+            rows[held] = np.asarray(
+                jnp.take(self._seg[3], slots[held], axis=0))
+            ok[held] = np.asarray(jnp.take(self._seg[4], slots[held]))
+        return rows, ok & (ids < self.n_items)
 
     def block_until_ready(self):
         """Fence every device array this index owns (bench timing)."""
-        arrs = [self.V, self.valid, self.Vq, self.sv]
-        if self.delta_count:
-            arrs.extend(self._device_delta())
-        jax.block_until_ready(arrs)
+        jax.block_until_ready([self.V, self.valid, self.Vq, self.sv,
+                               *(self._seg or ())])
         return self
 
     def nbytes_quantized(self):
@@ -416,8 +567,9 @@ class Int8CandidateIndex:
         Returns ``(scores [n, k], indices [n, k])`` matching
         ``chunked_topk_scores`` to ``SCORE_ULPS`` (see module docstring
         for the contract and its conditions).  ``k`` is capped by the shortlist, the shortlist by
-        the catalog.  With a delta segment live the shortlist runs over
-        base + segment, without one over the base alone; either way the
+        the catalog.  With a delta segment (used or emptied) the
+        shortlist runs over base + segment, without one over the base
+        alone; either way the
         selection is :meth:`shortlist_plan`'s (one ``top_k`` on a small
         catalog, two exact stages on a large one: same candidates).
         """
@@ -428,14 +580,12 @@ class Int8CandidateIndex:
                 f"k={k} exceeds shortlist_k={sk}; the shortlist must "
                 "contain at least k candidates")
         U = jnp.asarray(U, dtype=jnp.float32)
-        if not self.delta_count:
+        if not self.delta_slots:
             return _int8_topk(U, self.Vq, self.sv, self.V, self.valid,
                               k=int(k), shortlist_k=sk)
-        drows, dVq, dsv, dV, dvalid = self._device_delta()
         return _int8_topk_delta(
-            U, self.Vq, self.sv, self.V, self.valid,
-            drows, dVq, dsv, dV, dvalid,
-            jnp.int32(self.n_items - 1), k=int(k), shortlist_k=sk)
+            U, self.Vq, self.sv, self.V, self.valid, *self._seg,
+            self._last_id(), k=int(k), shortlist_k=sk)
 
 
 def build_index(V, item_valid=None, shortlist_k=64, seq=0):
@@ -612,11 +762,13 @@ class ShardedInt8Index(Int8CandidateIndex):
     device quantizes only its slice.
 
     The PR 11 live pipeline composes unchanged: :meth:`with_updates`
-    inherits the base's host-side delta merge (O(touched) per publish,
-    base arrays shared by reference), the replicated delta segment is
+    inherits the base's slot bookkeeping and row write (O(touched) per
+    publish, base arrays shared by reference; the segment's arrays are
+    replicated over the mesh), the segment is
     routed to owning shards at SCORE time by ``row // ni_loc``, and
-    :meth:`compact` scatters the segment into the sharded base in place
-    of the base class's grow-then-scatter (capacity always covers
+    :meth:`compact` scatters the segment into a COPY of the sharded base
+    (:meth:`_fold`; not donated: this class's compaction has never run in a cell;
+    capacity always covers
     ``n_items`` here — growth past the shard stride rebuilds, see
     :meth:`with_updates`).
 
@@ -646,8 +798,11 @@ class ShardedInt8Index(Int8CandidateIndex):
         self.n_items = int(n_items)
         self.shortlist_k = min(int(shortlist_k), self.n_items)
         self.seq = seq
-        self._last = None
         self._clear_delta()
+
+    def _put(self, arrays):
+        return jax.device_put(arrays, jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec()))
 
     def shortlist_plan(self):
         cols = self.ni_loc + self.delta_slots     # what one shard scores
@@ -657,12 +812,16 @@ class ShardedInt8Index(Int8CandidateIndex):
         new.mesh = self.mesh
         new.n_shards = self.n_shards
         new.ni_loc = self.ni_loc
-        new._last = None
 
     @property
     def capacity(self):
         """Catalog ids the sharded base can hold without re-striding."""
         return self.n_base
+
+    def reserve(self, rows=0, slots=0):
+        """The segment's slots only: the shards' stride fixes the base
+        arrays' capacity (growth past it rebuilds, :meth:`_regrown`)."""
+        return super().reserve(slots=slots)
 
     def with_updates(self, rows, V_rows, valid_rows=None, seq=None):
         rows_a = np.asarray(rows, dtype=np.int64).ravel()
@@ -701,28 +860,16 @@ class ShardedInt8Index(Int8CandidateIndex):
                           shortlist_k=self.shortlist_k,
                           seq=self.seq if seq is None else int(seq))
 
-    def compact(self, seq=None):
-        """Fold the delta into the sharded base: same memcpy-class
-        scatter as the base class, minus its grow branch (capacity
-        always covers ``n_items`` — see :meth:`_regrown`); results are
-        re-placed shard-leading so residency survives the scatter."""
-        if not self.d_rows.size:
-            return self._copy_shell(seq)
+    def _fold(self, drows):
+        """The base class's scatter into a COPY of the sharded base
+        arrays (nothing donated: the index stays whole), re-placed
+        shard-leading so residency survives the scatter."""
         from tpu_als.parallel.mesh import shard_leading
 
         spec = shard_leading(self.mesh)
-        ix = jnp.asarray(self.d_rows, dtype=jnp.int32)
-        new = self._copy_shell(seq)
-        new.V = jax.device_put(
-            self.V.at[ix].set(jnp.asarray(self._dV)), spec)
-        new.Vq = jax.device_put(
-            self.Vq.at[ix].set(jnp.asarray(self._dVq)), spec)
-        new.sv = jax.device_put(
-            self.sv.at[ix].set(jnp.asarray(self._dsv)), spec)
-        new.valid = jax.device_put(
-            self.valid.at[ix].set(jnp.asarray(self._dvalid)), spec)
-        new._clear_delta()
-        return new
+        out = _fold_segment_copied(self.V, self.Vq, self.sv, self.valid,
+                                   drows, *self._seg[1:])
+        return (*(jax.device_put(a, spec) for a in out[:4]), *out[4:])
 
     def topk(self, U, k, shortlist_k=None):
         """Top-k of ``U @ V.T`` scored shard-resident (see class
@@ -737,7 +884,7 @@ class ShardedInt8Index(Int8CandidateIndex):
         U = jnp.asarray(U, dtype=jnp.float32)
         k_loc, sk_loc = self.shard_widths(k, sk)
         fn = _build_sharded_int8(self.mesh, int(k), k_loc, sk_loc,
-                                 self.ni_loc, bool(self.delta_count))
+                                 self.ni_loc, bool(self.delta_slots))
         return fn(U, *self.score_args())
 
     def shard_widths(self, k, shortlist_k=None):
@@ -752,13 +899,8 @@ class ShardedInt8Index(Int8CandidateIndex):
         four sharded base arrays, the last catalog id (answers are
         clamped to it), and the replicated delta segment if there is
         one."""
-        if self._last is None or self._last[0] != self.n_items:
-            self._last = (self.n_items, jax.device_put(
-                np.int32(self.n_items - 1), jax.sharding.NamedSharding(
-                    self.mesh, jax.sharding.PartitionSpec())))
-        delta = self._device_delta() if self.delta_count else ()
-        return (self.Vq, self.sv, self.V, self.valid, self._last[1],
-                *delta)
+        return (self.Vq, self.sv, self.V, self.valid, self._last_id(),
+                *(self._seg or ()))
 
 
 def build_sharded_index(V, mesh, item_valid=None, shortlist_k=64, seq=0):

@@ -22,7 +22,21 @@ capacity, so an append changes no array's shape.
 Item factors stay fixed during USER fold-ins (the standard fold-in
 contract); the symmetric ``update_items`` folds new/updated ITEMS against
 the fixed user factors, so both directions of catalog growth are served
-between refits.
+between refits.  Each direction's fixed side is a table of the server's
+own on the device, placed once (the catalog at construction, the user
+table when the first item fold — or ``prewarm`` of the item side — asks
+for it): a fold's write-back also writes the rows it moved into that
+table IN PLACE (``core.foldin.write_rows``), so the other direction's
+next fold reads them, and no batch uploads a table.
+
+**A rating whose other side has no factor yet** (a new item at its
+user's fold, a new user at its item's fold) is kept in the history, not
+dropped: a fold regresses on those of the entity's ratings whose other
+side has a factor WHEN IT RUNS, so a held rating enters the entity's
+first fold after the other side got one.  An entity none of whose
+ratings can be used gets no factor (it is not appended) and its ratings
+wait; ``events_waiting`` counts them.  (Without ``keep_history`` there
+is nowhere to keep one: it is dropped, as before.)
 """
 
 from __future__ import annotations
@@ -35,7 +49,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from tpu_als import obs
-from tpu_als.core.foldin import fold_in, place_rows, solve_path
+from tpu_als.core.foldin import fold_in, place_rows, solve_path, write_rows
 from tpu_als.core.ratings import (
     LIVE_PADS,
     pad_for,
@@ -52,9 +66,15 @@ class FoldInServer:
     def __init__(self, model, keep_history=True, stats_window=512):
         self.model = model
         self.keep_history = keep_history
-        # original id -> (fixed-side dense ids, ratings), in arrival order
+        # original id -> (fixed-side ORIGINAL ids, ratings), in arrival
+        # order
         self._history = {}
         self._item_history = {}
+        # side -> {original id: how many of its ratings its last fold
+        # could use}
+        self._used = {"user": {}, "item": {}}
+        # side -> {original id without a factor: ratings held for it}
+        self._waiting = {"user": {}, "item": {}}
         p = model._params
         self._reg = float(p.get("regParam", 0.1))
         self._implicit = bool(p.get("implicitPrefs", False))
@@ -62,7 +82,11 @@ class FoldInServer:
         self._nonnegative = bool(p.get("nonnegative", False))
         self._bufs = {}     # "_U" / "_V" -> the buffer the model's is a view of
         self._reserve(items_side=False)
+        # each fold direction's fixed side on the device, placed once:
+        # the catalog now, the user table when an item fold first needs
+        # it (a user-only server never holds one)
         self._V = self._place("_V")
+        self._Ud = None
         self._YtY = compute_yty(self._V) if self._implicit else None
         # (batch_size, touched_users, latency_seconds, padded width) —
         # bounded: a
@@ -132,7 +156,7 @@ class FoldInServer:
             # the id maps sort their ids at first use: now, not mid-stream
             (m._user_map if side == "user" else m._item_map).to_dense([0])
             for g in range(int(growth) + 1):
-                F = (self._V if side == "user" and g == 0
+                F = (self._fixed(items_side=side == "item") if g == 0
                      else self._place("_V" if side == "user" else "_U", g))
                 YtY = compute_yty(F) if self._implicit else None
                 for n in rows:
@@ -152,6 +176,13 @@ class FoldInServer:
                         obs.emit("foldin_solve_path", side=side,
                                  rank=int(F.shape[1]), rows=n, width=w,
                                  path=path, reason=why)
+        if self._Ud is not None:
+            # both directions fold: each one's write-back also writes
+            # its rows into the other's fixed table; those programs now
+            none = np.empty((0, m._U.shape[1]), np.float32)
+            for n in rows:
+                self._V = write_rows(self._V, [], none, pad=n)
+                self._Ud = write_rows(self._Ud, [], none, pad=n)
 
     def update(self, batch):
         """Process one micro-batch frame (userCol/itemCol/ratingCol of the
@@ -165,19 +196,38 @@ class FoldInServer:
         ratings from known users becomes recommendable without a refit.
         The reference stack requires a full refit here too (SURVEY §3.5).
 
-        Users unknown to the model are ignored (no factors to regress
-        on — fold them in via ``update`` first).  After the write-back
-        the server's cached serving-side V and YᵀY are refreshed, so
-        subsequent USER fold-ins see the new items.  Returns the
-        original ids of the items whose factors moved.
+        A rating by a user the model does not hold cannot be regressed
+        on yet: it is kept and enters the item's first fold after the
+        user has a factor (module docstring; fold the batch's users
+        first, ``update``, and a new user's rating counts at once).
+        The write-back also writes the moved rows into the catalog the
+        USER fold-ins read on the device, so they see the new items.
+        Returns the original ids of the items whose factors moved.
         """
         return self._fold_batch(batch, items_side=True)
 
+    @property
+    def events_waiting(self):
+        """Ratings held in a history for a side whose other entity has
+        no factor yet, one per rating and side."""
+        return sum(sum(w.values()) for w in self._waiting.values())
+
+    def _fixed(self, items_side):
+        """The fixed side of a fold of this direction, on the device."""
+        if not items_side:
+            return self._V
+        if self._Ud is None:
+            # item folds begin: the catalog gets its spare rows on the
+            # host too (one copy, here and not under the first append)
+            self._reserve(items_side=True)
+            self._Ud = self._place("_U")
+        return self._Ud
+
     def _fold_batch(self, batch, items_side):
-        """ONE shared mechanics path for both directions — known-side
-        filter, per-entity grouping, history merge, ladder padding, solve,
-        write-back — parameterized by which side is being solved, so a
-        fix to any of it cannot apply to one direction only."""
+        """ONE shared mechanics path for both directions — history
+        merge, known-side filter, per-entity grouping, ladder padding,
+        solve, write-back — parameterized by which side is being solved,
+        so a fix to any of it cannot apply to one direction only."""
         t0 = time.perf_counter()
         frame = as_frame(batch)
         m = self.model
@@ -191,24 +241,23 @@ class FoldInServer:
             fixed_raw = np.asarray(frame[p["itemCol"]])
             fixed_map, history = m._item_map, self._history
         r = np.asarray(frame[p["ratingCol"]], dtype=np.float32)
-
-        # fixed-side entities never seen in training cannot contribute
-        # (no factors to regress on); the reference would equally ignore
-        # them until a refit
-        fixed_dense = fixed_map.to_dense(fixed_raw)
-        known = fixed_dense >= 0
-        solved_raw = solved_raw[known]
-        fixed_dense, r = fixed_dense[known], r[known]
         if len(solved_raw) == 0:
             return np.array([], dtype=np.int64)
 
-        # group the events by entity, each entity's in arrival order
+        # group the events by entity, each entity's in arrival order, and
+        # put them behind the entity's history
         touched, entity = np.unique(solved_raw, return_inverse=True)
         by_entity = np.argsort(entity, kind="stable")
         bounds = np.cumsum(np.bincount(entity, minlength=len(touched)))[:-1]
-        per = list(zip(np.split(fixed_dense[by_entity], bounds),
+        per = list(zip(np.split(fixed_raw[by_entity], bounds),
                        np.split(r[by_entity], bounds)))
+        side = "item" if items_side else "user"
+        used = self._used[side] if self.keep_history else {}
         if self.keep_history:
+            # a rating whose other side has no factor yet waits for it
+            held = self._waiting["user" if items_side else "item"]
+            for e in fixed_raw[fixed_map.to_dense(fixed_raw) < 0].tolist():
+                held[e] = held.get(e, 0) + 1
             for j, e in enumerate(touched.tolist()):
                 hist = history.get(e)
                 if hist is not None:
@@ -216,28 +265,45 @@ class FoldInServer:
                               np.concatenate([hist[1], per[j][1]]))
                 history[e] = per[j]
 
+        # a fold regresses on the ratings whose other side has a factor
+        # NOW (fixed-side entities never seen cannot contribute: no
+        # factors to regress on); an entity with none is not folded
+        lens = np.array([len(f) for f, _ in per])
+        dense = fixed_map.to_dense(np.concatenate([f for f, _ in per]))
+        vals_all = np.concatenate([v for _, v in per])
+        known = dense >= 0
+        usable = np.add.reduceat(known.astype(np.int64),
+                                 np.cumsum(lens) - lens)
+        # ratings that enter a fold of this side for the first time
+        entered = 0
+        for e, n_ok in zip(touched.tolist(), usable.tolist()):
+            entered += n_ok - used.get(e, 0)
+            used[e] = n_ok
+        fold = usable > 0
+        if not fold.any():
+            return np.array([], dtype=np.int64)
+        touched = touched[fold]
+        dense, vals_all = dense[known], vals_all[known]
+        lens = usable[fold]
+
         # pad rows and width up the ladder -> the programs prewarm ran
         n = len(touched)
-        lens = np.array([len(f) for f, _ in per])
         n_pad, w = pad_for(n), pad_for(int(lens.max()))
         row = np.repeat(np.arange(n), lens)
         slot = np.arange(lens.sum()) - np.repeat(np.cumsum(lens) - lens, lens)
         cols = np.zeros((n_pad, w), dtype=np.int32)
         vals = np.zeros((n_pad, w), dtype=np.float32)
         mask = np.zeros((n_pad, w), dtype=np.float32)
-        cols[row, slot] = np.concatenate([f for f, _ in per])
-        vals[row, slot] = np.concatenate([v for _, v in per])
+        cols[row, slot] = dense
+        vals[row, slot] = vals_all
         mask[row, slot] = 1.0
 
+        F = self._fixed(items_side)
         if items_side:
-            # the fixed side here is U, which user fold-ins may have
-            # changed — read it live (one transfer per item batch; item
-            # batches are the rare direction, so this stays off the
-            # user hot path)
-            F = self._place("_U")
+            # O(table) a batch on the implicit path: ROADMAP R2
             YtY = compute_yty(F) if self._implicit else None
         else:
-            F, YtY = self._V, self._YtY
+            YtY = self._YtY
         x = np.asarray(fold_in(
             F, jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(mask),
             self._reg, implicit_prefs=self._implicit, alpha=self._alpha,
@@ -245,23 +311,21 @@ class FoldInServer:
         ))[:n]
 
         self._write_back(touched, x, items_side)
-        if items_side:
-            # refresh the serving-side cache the USER fold-in path reads
-            self._V = self._place("_V")
-            if self._implicit:
-                self._YtY = compute_yty(self._V)
+        if items_side and self._implicit:
+            self._YtY = compute_yty(self._V)
         dt = time.perf_counter() - t0
-        self.stats.append((len(solved_raw), n, dt, w))
-        obs.histogram("foldin.update_seconds", dt,
-                      side="item" if items_side else "user")
-        obs.histogram("foldin.batch_rows", n,
-                      side="item" if items_side else "user")
-        obs.counter("foldin.ratings", len(solved_raw))
+        self.stats.append((entered, n, dt, w))
+        obs.histogram("foldin.update_seconds", dt, side=side)
+        obs.histogram("foldin.batch_rows", n, side=side)
+        obs.counter("foldin.ratings", entered)
         return touched
 
     def _write_back(self, touched_raw_ids, new_rows, items_side=False):
-        """New factor rows into the model's table; entities the id map
-        does not know take the next spare rows, in the order given."""
+        """New factor rows into the model's table, and into the server's
+        own table of that side on the device where it holds one (the
+        other direction's fixed side); entities the id map does not know
+        take the next spare rows, in the order given, and what was held
+        for them waits no longer."""
         m = self.model
         fac_attr = "_V" if items_side else "_U"
         emap = m._item_map if items_side else m._user_map
@@ -271,7 +335,19 @@ class FoldInServer:
         if new.any():
             dense[new] = emap.append(touched_raw_ids[new])
             setattr(m, fac_attr, self._bufs[fac_attr][:len(emap)])
+            held = self._waiting["item" if items_side else "user"]
+            for e in touched_raw_ids[new].tolist():
+                held.pop(e, None)
         getattr(m, fac_attr)[dense] = new_rows
+        dev_attr = "_V" if items_side else "_Ud"
+        table = getattr(self, dev_attr)
+        if table is None:
+            return
+        if int(table.shape[0]) != len(self._bufs[fac_attr]):
+            # spare rows used up: the table of the new capacity, whole
+            setattr(self, dev_attr, self._place(fac_attr))
+        else:
+            setattr(self, dev_attr, write_rows(table, dense, new_rows))
 
     def latency(self, q=0.5, skip_warmup=False):
         """Latency quantile over processed batches.  ``skip_warmup`` drops

@@ -4,9 +4,14 @@
 Runs ``benchmark/tests/batch_ring.py`` as it is (one run of
 ``benchmark/run.py``, then the batch ring's phases by bucket) and adds,
 for each stream of the run, the shares of ``closed_by`` over the last
-``--seconds`` of its per-batch records — ``age``: the head had already
-waited ``max_wait_s`` when the engine thread came back; ``wait``: the
-rest of its window ran out; ``full``; ``closed`` — with the head's age
+``--seconds`` of its per-batch records — ``slot``: the engine thread
+held a free slot of its pipeline and popped what was queued at once
+(every batch of a started engine below saturation); ``full``;
+``closed``; ``age`` and ``wait``, the timed rule of callers that drive
+``next_batch`` themselves, which a started engine never counts — with
+the requests' wait in the queue (``queue_wait_ms``: median, mean,
+longest over the request ring, the quantity the benchmark's
+``serve_queue_ms`` takes the median of), the head's age
 on arrival and the engine thread's wait for the user table's lock
 (``lock_wait``: median, mean, longest, in ms — a window that stood still
 with none of it did not stand behind a row write), how many of the
@@ -43,7 +48,7 @@ import statistics as st
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-WAYS = ("age", "wait", "full", "closed")
+WAYS = ("slot", "full", "closed", "age", "wait")
 PHASES = ("serve.batch.stage", "serve.batch.dispatch",
           "serve.batch.readback", "serve.batch.complete")
 
@@ -105,6 +110,11 @@ def main(argv):
                                if len(s) > 8):
         print(json.dumps({"batch_closed": k, **shares(stream, seconds)}),
               flush=True)
+    waits = [1e3 * r["spans"]["queue_wait"]
+             for r in engines[0].flight.records()
+             if r["spans"].get("queue_wait") is not None]
+    print(json.dumps({"queue_wait_ms": three(waits),
+                      "requests": len(waits)}), flush=True)
     counted = {way: obs.counter_value("serving.batch_closed", by=way)
                for way in WAYS}
     print(json.dumps({"serving.batch_closed": counted,

@@ -267,9 +267,11 @@ def test_publish_between_two_batches_in_flight_gives_each_its_generation(
     eng, U, V = _published_engine(rng)
     gated = GatedResponses(eng)
     with eng:
-        with eng._table_lock:           # both requests into ONE batch
+        # both requests into ONE batch: with a slot free the engine
+        # thread pops what is queued at once (ISSUE 35), so the pair is
+        # admitted under the queue's own lock
+        with eng.batcher._cond:
             A = [eng.submit(3), eng.submit(5)]
-            time.sleep(0.05)
         gated.wait_dispatched(1)
         assert gated.gates[0].entered.wait(10.0)
         U2 = U.copy()
@@ -277,9 +279,8 @@ def test_publish_between_two_batches_in_flight_gives_each_its_generation(
             U2[3] = -g * U[3]
             seq, mode = eng.publish_update(U2, V, touched_users=[3])
             assert mode == "retag"
-        with eng._table_lock:
+        with eng.batcher._cond:
             B = [eng.submit(3), eng.submit(5)]
-            time.sleep(0.05)
         gated.wait_dispatched(2)        # behind the writes, A not read back
         assert not A[0].done()
         gated.open()
@@ -294,8 +295,12 @@ def test_publish_between_two_batches_in_flight_gives_each_its_generation(
     np.testing.assert_allclose(                     # read-your-writes
         sb3, np.sort(-publishes * full)[::-1][:5], rtol=1e-4)
     assert not np.array_equal(ia3, ib3)
+    # each ticket names the generation that answered it
+    assert {t.seq for t in A} == {seq - publishes}
+    assert {t.seq for t in B} == {seq}
     recs = eng.batch_flight.records()
-    assert [(r["rows"], r["in_flight"]) for r in recs] == [(2, 0), (2, 1)]
+    assert [(r["rows"], r["in_flight"], r["closed_by"]) for r in recs] == [
+        (2, 0, "slot"), (2, 1, "slot")]
 
 
 def test_publish_update_delta_serves_bitwise_vs_rebuild(rng):

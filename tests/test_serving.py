@@ -265,7 +265,10 @@ def test_batcher_young_head_waits_only_the_rest_of_its_window():
     assert b.closed_by == "wait" and 0.8 <= b.head_wait < 0.9
 
 
-def test_batcher_first_arrival_into_an_empty_queue_waits_for_company():
+@pytest.mark.parametrize("coalesce", [True, False])
+def test_batcher_first_arrival_into_an_empty_queue(coalesce):
+    """The timed rule holds it for company; a consumer that waits for
+    nothing (the engine's loop, a slot in hand) takes it alone."""
     b = MicroBatcher(buckets=(4, 8), max_wait_s=0.5)
 
     def arrivals():
@@ -276,13 +279,18 @@ def test_batcher_first_arrival_into_an_empty_queue_waits_for_company():
 
     th = threading.Thread(target=arrivals)
     th.start()
-    batch = b.next_batch(timeout=5.0)
-    th.join()
-    assert [t.payload for t in batch] == [0, 1]
+    batch = b.next_batch(timeout=5.0, coalesce=coalesce)
     idle_s, waiting, coalesce_s = b.last_wait
-    # the lone request is the head, its age ~0: all of max_wait_s, as ever
-    assert idle_s >= 0.04 and waiting == 1 and coalesce_s >= 0.4
-    assert b.closed_by == "wait" and b.head_wait < 0.25
+    th.join()
+    assert idle_s >= 0.04 and waiting == 1 and b.head_wait < 0.25
+    if coalesce:
+        # the lone request is the head, its age ~0: all of max_wait_s
+        assert [t.payload for t in batch] == [0, 1]
+        assert coalesce_s >= 0.4 and b.closed_by == "wait"
+    else:
+        assert [t.payload for t in batch] == [0]
+        assert coalesce_s < 0.04 and b.closed_by == "slot"
+        assert [t.payload for t in b.next_batch(timeout=1.0)] == [1]
 
 
 @pytest.mark.parametrize("how", ["full_on_arrival", "fills_in_the_wait",
@@ -347,13 +355,63 @@ def test_batch_closed_counts_every_batch_and_the_record_says_why(
                for r in recs if r["closed_by"] != "wait")
 
 
+# -- a consumer with a pipeline to ask waits for nothing (ISSUE 35) ---------
+# ``max_wait_s`` is so large that one timed wait would fail the test.
+
+PATIENT = 30.0
+
+
+def _closed_by(reg):
+    """``serving.batch_closed`` by label, the ways that counted."""
+    counts = {w: reg.counter_value("serving.batch_closed", by=w)
+              for w in ("slot", "full", "closed", "age", "wait")}
+    return {w: n for w, n in counts.items() if n}
+
+
+@pytest.mark.parametrize("queued,closing,by,rows", [
+    (1, False, "slot", 1),           # dispatched alone
+    (3, False, "slot", 3),           # whatever coalesced meanwhile
+    (5, False, "slot", 4),           # just over the second-largest bucket:
+    (8, False, "slot", 4),           # that bucket full, the rest next time
+    (9, False, "slot", 9),           # over half of the largest: all of them
+    (20, False, "full", 16),         # capped at the largest bucket
+    (2, True, "closed", 2),
+])
+def test_batcher_without_coalescing_pops_what_is_queued_at_once(
+        _fresh, monkeypatch, queued, closing, by, rows):
+    b = MicroBatcher(buckets=(2, 4, 16), max_wait_s=PATIENT)
+    for i in range(queued):
+        b.submit(i)
+    if closing:
+        b.close()
+    monkeypatch.setattr(b._cond, "wait", lambda *a: pytest.fail(
+        "a consumer that holds a slot entered a timed wait"))
+    batch = b.next_batch(timeout=1.0, coalesce=False)
+    assert [t.payload for t in batch] == list(range(rows))
+    idle_s, waiting, coalesce_s = b.last_wait
+    assert (idle_s, waiting) == (0.0, queued) and coalesce_s < 0.25
+    assert b.closed_by == by and b.depth() == queued - rows
+    assert _closed_by(_fresh) == {by: 1}
+
+
+def test_a_caller_that_coalesces_pops_the_largest_bucket_mostly_empty():
+    """The cut to the bucket below is for the consumer that is back at
+    once; a scheduler's round takes what is queued, as it always did."""
+    b = MicroBatcher(buckets=(2, 4, 16), max_wait_s=0.5)
+    _aged(b, 0, 0.6)
+    for i in range(1, 5):
+        b.submit(i)
+    assert len(b.next_batch(timeout=1.0)) == 5 and b.closed_by == "age"
+
+
 # ---------------------------------------------------------------------------
 # engine
 
 
-def _engine(rng, n=40, Ni=300, r=8, k=5, quantize=True, **kw):
-    eng = ServingEngine(k=k, buckets=(8, 32), shortlist_k=32,
-                        max_wait_s=0.0, **kw)
+def _engine(rng, n=40, Ni=300, r=8, k=5, quantize=True, max_wait_s=0.0,
+            buckets=(8, 32), **kw):
+    eng = ServingEngine(k=k, buckets=buckets, shortlist_k=32,
+                        max_wait_s=max_wait_s, **kw)
     U = rng.normal(size=(n, r)).astype(np.float32)
     V = rng.normal(size=(Ni, r)).astype(np.float32)
     eng.publish(U, V, quantize=quantize)
@@ -779,6 +837,138 @@ def test_stop_gives_up_at_its_timeout_on_both_threads_together(rng):
     assert not completer.is_alive() and a.done()
 
 
+# -- a batch closes when the pipeline can take it (ISSUE 35) ----------------
+# The engine thread takes a slot, then pops what is queued at once; requests
+# coalesce only while it is away.  ``max_wait_s`` is PATIENT throughout: a
+# single timed wait would run every ``result(timeout=10)`` out.
+
+
+def test_with_a_slot_free_a_lone_request_is_dispatched_without_waiting(
+        rng, _fresh):
+    eng, U, V = _engine(rng, max_wait_s=PATIENT)
+    with eng:
+        for u in (3, 4):            # the second finds a warm program
+            t = eng.submit(u)
+            s, ix = t.result(timeout=10.0)
+        assert t.t_dequeue - t.t_submit < 0.5
+        ref_s, ref_ix = _exact(U[4:5], V, np.ones(V.shape[0], bool), eng.k)
+        np.testing.assert_allclose(s, ref_s[0], rtol=1e-5, atol=1e-6)
+        np.testing.assert_array_equal(ix, ref_ix[0])
+    recs = eng.batch_flight.records()
+    assert [(r["closed_by"], r["rows"], r["waiting"], r["in_flight"],
+             r["handoff_wait"]) for r in recs] == [("slot", 1, 1, 0, 0.0)] * 2
+    for r in recs:
+        assert r["spans"]["serve.batch.coalesce"] < 0.01 * PATIENT
+        assert 0 <= r["head_wait"] < 0.5
+    assert _closed_by(_fresh) == {"slot": 2}
+
+
+def test_arrivals_while_both_slots_are_taken_ride_one_next_batch(
+        rng, _fresh):
+    eng, U, V = _engine(rng, max_wait_s=PATIENT)
+    gated = GatedResponses(eng)
+    with eng:
+        a, b = _one_batch_each(eng, gated, (0, 1))  # a batch each: alone
+        later = [eng.submit(j) for j in range(2, 7)]
+        time.sleep(0.2)
+        # both slots taken: the five wait in the queue, for a slot and
+        # not for each other
+        assert len(gated.gates) == 2
+        assert all(t.t_dequeue is None for t in later)
+        gated.release(0)
+        gated.wait_dispatched(3)                    # a completed: ALL five
+        assert len({t.t_dequeue for t in later}) == 1
+        last = eng.submit(7)                        # b still in flight
+        time.sleep(0.1)
+        assert last.t_dequeue is None
+        gated.open()
+        tickets = [a, b] + later + [last]
+        ref_s, ref_ix = _exact(U[:8], V, np.ones(V.shape[0], bool), eng.k)
+        for j, t in enumerate(tickets):
+            s, ix = t.result(timeout=10.0)
+            np.testing.assert_allclose(s, ref_s[j], rtol=1e-5, atol=1e-6)
+            np.testing.assert_array_equal(ix, ref_ix[j])
+        done = [t.t_done for t in tickets]
+        assert done == sorted(done)                 # answered in order
+    recs = eng.batch_flight.records()
+    assert [(r["rows"], r["waiting"], r["closed_by"]) for r in recs] == [
+        (1, 1, "slot"), (1, 1, "slot"), (5, 5, "slot"), (1, 1, "slot")]
+    assert [r["in_flight"] for r in recs[:3]] == [0, 1, 1]
+    assert recs[0]["handoff_wait"] == recs[1]["handoff_wait"] == 0.0
+    # the queue did the coalescing, and the record says so
+    assert 0.15 < recs[2]["handoff_wait"] < 5.0
+    assert 0.15 < recs[2]["head_wait"] < 5.0
+    assert all(r["spans"]["serve.batch.coalesce"] < 0.01 * PATIENT
+               for r in recs)
+    assert _closed_by(_fresh) == {"slot": 4}
+
+
+@pytest.mark.parametrize("how", ["full", "closed", "rung_below"])
+def test_full_and_closed_close_the_engines_batches_as_before(
+        rng, _fresh, how):
+    """40 requests queue behind two taken slots.  The largest bucket
+    fills (``full``), or ``stop`` drains what is there (``closed``); on
+    the default ladder, where 40 rows would ride bucket 128 a third
+    full, the bucket below goes full and the other 8 ride the next."""
+    eng, _, _ = _engine(rng, max_wait_s=PATIENT, buckets=(
+        (8, 32, 128) if how == "rung_below" else (8, 32)))
+    gated = GatedResponses(eng)
+    eng.start()
+    a, b = _one_batch_each(eng, gated, (0, 1))
+    later = [eng.submit(j % 40) for j in range(3 if how == "closed" else 40)]
+    if how == "closed":
+        stopper = threading.Thread(target=eng.stop)
+        stopper.start()
+        time.sleep(0.05)
+        gated.open()
+        stopper.join(10.0)
+        assert not stopper.is_alive() and all(t.done() for t in later)
+        want = [(1, "slot"), (1, "slot"), (3, "closed")]
+    else:
+        gated.open()
+        for t in later:
+            t.result(timeout=10.0)
+        eng.stop()
+        want = [(1, "slot"), (1, "slot"),
+                (32, "full" if how == "full" else "slot"), (8, "slot")]
+        done = [t.t_done for t in later]
+        assert done == sorted(done)                 # answered in order
+    recs = eng.batch_flight.records()
+    assert [(r["rows"], r["closed_by"]) for r in recs] == want
+    assert sum(_closed_by(_fresh).values()) == len(want)
+    assert not {"age", "wait"} & set(_closed_by(_fresh))
+
+
+@pytest.mark.parametrize("what", ["expired", "shed"])
+def test_expiry_and_shedding_behind_two_taken_slots(rng, _fresh, what):
+    """Neither moved: a request whose deadline passes while it waits for
+    a slot is failed where its batch is staged, its neighbours answered;
+    a full queue refuses at admission."""
+    eng, _, _ = _engine(rng, max_wait_s=PATIENT, max_queue=3)
+    gated = GatedResponses(eng)
+    with eng:
+        _one_batch_each(eng, gated, (0, 1))
+        if what == "expired":
+            dead = eng.submit(2, deadline_s=0.05)
+            alive = eng.submit(3)
+            time.sleep(0.1)
+            gated.open()
+            with pytest.raises(DeadlineExceeded):
+                dead.result(timeout=10.0)
+            assert alive.result(timeout=10.0)[0].shape == (5,)
+            assert dead.t_dequeue == alive.t_dequeue    # one batch
+            assert _fresh.counter_value("serving.expired") == 1
+            assert eng.batch_flight.records()[2]["rows"] == 1
+        else:
+            queued = [eng.submit(j) for j in (2, 3, 4)]
+            with pytest.raises(Overloaded):
+                eng.submit(5)
+            assert _fresh.counter_value("serving.shed") == 1
+            gated.open()
+            assert all(t.result(timeout=10.0) for t in queued)
+            assert eng.batch_flight.records()[2]["rows"] == 3
+
+
 @pytest.mark.parametrize("rows", [1, 5, 20, 32])
 def test_threads_and_serve_batch_answer_bit_equal(rng, rows):
     """The same batch through the two threads and through a synchronous
@@ -793,8 +983,11 @@ def test_threads_and_serve_batch_answer_bit_equal(rng, rows):
         eng.publish(U, V)
         payloads = [j if j % 3 else U[j] * 0.5 for j in range(rows)]
         if threaded:
+            # admitted before the threads start: the engine thread finds
+            # them all queued and pops them as ONE batch (with the loop
+            # running, the first would ride alone: ISSUE 35)
+            tickets = [eng.submit(p) for p in payloads]
             with eng:
-                tickets = [eng.submit(p) for p in payloads]
                 got = [t.result(timeout=10.0) for t in tickets]
         else:
             tickets = [eng.submit(p) for p in payloads]
@@ -802,6 +995,8 @@ def test_threads_and_serve_batch_answer_bit_equal(rng, rows):
             got = [t.result(timeout=0) for t in tickets]
         rec, = eng.batch_flight.records()
         assert rec["rows"] == rows and rec["in_flight"] == 0
+        assert rec["closed_by"] == (
+            "full" if rows == 32 else "slot" if threaded else "wait")
         answers.append(got)
     for (s0, i0), (s1, i1) in zip(*answers):
         np.testing.assert_array_equal(s0, s1)

@@ -25,10 +25,10 @@ PHASES = ("serve.batch.stage", "serve.batch.dispatch",
 BATCHES = ((5, 8), (20, 32), (32, 32))      # (rows, the bucket they ride)
 
 
-def _engine(**kw):
+def _engine(max_wait_s=0.0, **kw):
     rng = np.random.default_rng(0)
     eng = ServingEngine(k=5, buckets=(8, 32), shortlist_k=32,
-                        max_wait_s=0.0, **kw)
+                        max_wait_s=max_wait_s, **kw)
     # a catalog large enough that a batch takes milliseconds: the ring's
     # clock reads are compared with the spans' to within one
     eng.publish(rng.normal(size=(40, 16)).astype(np.float32),
@@ -38,6 +38,16 @@ def _engine(**kw):
 
 def _drain(eng, rows):
     tickets = [eng.submit(j % 40) for j in range(rows)]
+    eng.serve_batch(eng.batcher.next_batch(timeout=1.0))
+    return tickets
+
+
+def _aged_drain(eng, rows):
+    """``_drain`` for an engine whose ``max_wait_s`` is long: the head is
+    backdated, so the timed rule of a caller that drives ``next_batch``
+    itself pops at once (``age``)."""
+    tickets = [eng.submit(j % 40) for j in range(rows)]
+    tickets[0].t_submit -= 2 * eng.batcher.max_wait_s
     eng.serve_batch(eng.batcher.next_batch(timeout=1.0))
     return tickets
 
@@ -131,10 +141,12 @@ def test_started_engine_writes_the_same_spans_from_two_threads(tmp_path):
     the completion thread ``readback`` and ``complete`` with the batch's
     ``seq``, on a line of its own; batch 2's stage and dispatch lie
     UNDER batch 1's readback, which is the point; no span has a name the
-    trace readers do not know."""
+    trace readers do not know.  The coalesce span is still written around
+    each pop, however short: with a slot free the loop waits for nothing
+    (``closed_by`` ``slot``; ISSUE 35), though ``max_wait_s`` is 30 s."""
     obs.reset()
-    eng = _engine()
-    _drain(eng, 5)                                  # compiled
+    eng = _engine(max_wait_s=30.0)
+    _aged_drain(eng, 5)                             # compiled
     first = eng._batch_seq + 1
     gated = GatedResponses(eng)
     opts = jax.profiler.ProfileOptions()
@@ -190,6 +202,16 @@ def test_started_engine_writes_the_same_spans_from_two_threads(tmp_path):
         assert life == pytest.approx(
             own["serve.batch.complete"][1] - own["serve.batch"][0], abs=1e6)
     assert [recs[first + j]["in_flight"] for j in (0, 1)] == [0, 1]
+    coalesce = [s for s in spans if s[0] == "serve.batch.coalesce"]
+    assert len(coalesce) == 2 and all(s[4] == engine_line for s in coalesce)
+    for (_, c0, dur, stats, _), seq in zip(coalesce, sorted(by_seq)):
+        assert (stats["closed_by"], stats["waiting"]) == ("slot", 1)
+        assert stats["closed_by"] == recs[seq]["closed_by"]
+        assert stats["head_wait"] == pytest.approx(recs[seq]["head_wait"])
+        assert dur < 0.3e9                          # 1 % of max_wait_s
+        assert recs[seq]["spans"]["serve.batch.coalesce"] * 1e9 == \
+            pytest.approx(dur, abs=1e6)
+        assert c0 + dur <= by_seq[seq]["serve.batch"][0]
 
 
 def test_batch_ring_keeps_the_spans_durations(traced):

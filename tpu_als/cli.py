@@ -1982,7 +1982,11 @@ def main(argv=None):
                     help="admission-queue depth beyond which requests "
                          "are shed (typed Overloaded)")
     sb.add_argument("--max-wait-ms", type=float, default=2.0,
-                    help="micro-batch coalescing window")
+                    help="upper bound on a request's wait for company "
+                         "in the admission queue (the started engine "
+                         "closes a batch as soon as its pipeline has a "
+                         "free slot, so it binds only for schedulers "
+                         "that dequeue by themselves)")
     sb.add_argument("--buckets", default=None,
                     help="comma-separated padded batch sizes (one "
                          "compiled program each); default: the "

@@ -98,10 +98,13 @@ METRICS = {
     "serving.batch_closed": (
         "counter", "batches",
         "micro-batches dequeued, labeled by what closed them: "
-        "by=age (the head had already waited max_wait_s when the "
-        "consumer arrived: popped without a wait) | wait (the rest of "
-        "the head's max_wait_s ran out) | full (the largest bucket "
-        "filled) | closed (the batcher was closing)"),
+        "by=slot (the engine's own loop held a free slot of its "
+        "pipeline: popped at once with whatever was queued, nothing "
+        "waited for) | full (the largest bucket filled) | closed (the "
+        "batcher was closing) | and, for callers that drive next_batch "
+        "themselves, the timed rule: age (the head had already waited "
+        "max_wait_s when the consumer arrived: popped without a wait) | "
+        "wait (the rest of the head's max_wait_s ran out)"),
     "serving.batch_overlap": (
         "counter", "batches",
         "one per batch the engine THREAD dispatched (a synchronous "
@@ -551,7 +554,7 @@ EVENTS = {
         "batch the engine's batch counter; the same triggers dump the "
         "engine's per-batch records (spans keyed by "
         "SERVE_BATCH_SPAN_KEYS, with batch, t0, bucket, rows, waiting, "
-        "closed_by = age|wait|full|closed as serving.batch_closed's "
+        "closed_by = slot|full|closed|age|wait as serving.batch_closed's "
         "by, head_wait = seconds the batch's oldest request had waited "
         "as the consumer arrived, lock_wait = seconds the engine thread "
         "waited for the user table's lock, inside serve.batch.stage, "

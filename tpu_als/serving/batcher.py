@@ -7,13 +7,27 @@ arbitrary times with per-request deadlines.  This queue converts one
 into the other:
 
 - no request is held back for coalescing longer than ``max_wait_s``
-  after it ARRIVED: a batch closes when its oldest request has waited
-  that long (or the largest bucket fills, whichever is first).  The
-  wait counts from the head ticket's ``t_submit``, not from the engine
-  thread's return, so requests that queued while the batch before was
-  being scored have had their coalescing time and pop at once; light
-  traffic pays a bounded latency tax and heavy traffic gets full
-  batches;
+  after it ARRIVED.  What closes a batch depends on who asks.  A
+  consumer with a pipeline to ask (the engine's own loop, which takes
+  one of its ``MAX_IN_FLIGHT`` slots BEFORE it dequeues) passes
+  ``coalesce=False``: the batch closes as soon as the queue is not
+  empty, with everything queued (``slot``).  Requests then coalesce
+  only while that consumer is away — waiting for a slot, or staging
+  and dispatching the batch before — so a batch's size follows the
+  pipeline's state, not a clock: the first arrival into an empty queue
+  beside an idle device rides alone, and under saturation the queue
+  fills while both slots are taken (a queue that has outgrown the
+  second-largest bucket by less than its size again goes as that
+  bucket, full, and a remainder: the largest program, a third full,
+  takes longer than its rows took to arrive, and a loop that pops on
+  completion would then never leave it).  A consumer with no pipeline to
+  ask (a scheduler's round, a synchronous ``serve_batch`` caller)
+  keeps the timed rule: a batch closes when its oldest request has
+  waited ``max_wait_s`` (or the largest bucket fills, whichever is
+  first), counted from the head ticket's ``t_submit``, so requests
+  that queued while the batch before was being scored have had their
+  coalescing time and pop at once.  Either way ``max_wait_s`` is an
+  upper bound: the slot rule only ever closes earlier;
 - the engine pads each dequeued batch up to the smallest bucket that
   fits (``bucket_for``), so the scoring executable compiles once per
   bucket instead of once per observed batch size;
@@ -192,17 +206,27 @@ class MicroBatcher:
             self._cond.notify()
         return t
 
-    def next_batch(self, timeout=None):
-        """Dequeue the next micro-batch (engine loop only).
+    def next_batch(self, timeout=None, coalesce=True):
+        """Dequeue the next micro-batch (one consumer only).
 
-        Blocks up to ``timeout`` for the first request, then coalesces
-        arrivals until the HEAD of the queue (its oldest ticket) has
-        waited ``max_wait_s`` since its ``t_submit``, the largest
-        bucket fills or the batcher closes.  A head that is already
-        that old when the consumer arrives — it queued while the batch
-        before was scored — pops at once with everything queued; a
-        younger one waits only the remainder; the first arrival into
-        an empty queue waits all of ``max_wait_s`` for company.
+        Blocks up to ``timeout`` for the first request.  Then, with
+        ``coalesce`` (the default: a caller with no pipeline to ask), it
+        coalesces arrivals until the HEAD of the queue (its oldest
+        ticket) has waited ``max_wait_s`` since its ``t_submit``, the
+        largest bucket fills or the batcher closes.  A head that is
+        already that old when the consumer arrives — it queued while
+        the batch before was scored — pops at once with everything
+        queued (``age``); a younger one waits only the remainder
+        (``wait``); the first arrival into an empty queue waits all of
+        ``max_wait_s`` for company.  With ``coalesce=False`` (the
+        engine's own loop, which holds a free slot of its pipeline as it
+        calls) nothing is waited for: whatever is queued is popped at
+        once (``slot``; ``full`` and ``closed`` as above), and what
+        coalesced did so while the caller was away.  Such a caller is
+        back right after its dispatch, so where the queue holds more
+        than the second-largest bucket and at most twice that, it gets
+        that bucket full and the rest next time, not the largest
+        program mostly empty.
         Returns a list of tickets (``t_dequeue`` stamped), or ``None``
         on timeout with an empty queue.  Also sets the
         ``serving.queue_depth`` gauge to the post-dequeue backlog,
@@ -226,7 +250,8 @@ class MicroBatcher:
             t_head = self._q[0].t_submit
             t_close = t_head + self.max_wait_s
             head_wait = t_first - t_head
-            closed_by = "age"       # the head had its wait: no wait here
+            # no wait here: the head had had its own, or none is asked for
+            closed_by = "age" if coalesce else "slot"
             with TraceAnnotation("serve.batch.coalesce",
                                  waiting=waiting) as span:
                 while True:
@@ -236,13 +261,26 @@ class MicroBatcher:
                     if self._closed:
                         closed_by = "closed"
                         break
+                    if not coalesce:
+                        break
                     remaining = t_close - time.perf_counter()
                     if remaining <= 0:
                         break
                     closed_by = "wait"
                     self._cond.wait(remaining)
-                batch = [self._q.popleft()
-                         for _ in range(min(len(self._q), cap))]
+                take = min(len(self._q), cap)
+                if not coalesce and len(self.buckets) > 1:
+                    # a few rows over the rung below would ride the
+                    # largest program mostly empty, for longer than
+                    # they took to arrive: the queue refills meanwhile
+                    # and every later batch does the same.  This
+                    # consumer is back as soon as it has dispatched, so
+                    # a full batch of the rung below goes now and the
+                    # rest next
+                    below = self.buckets[-2]
+                    if below < take <= 2 * below:
+                        take = below
+                batch = [self._q.popleft() for _ in range(take)]
                 depth_after = len(self._q)
                 span.set_metadata(closed_by=closed_by,
                                   head_wait=head_wait)

@@ -17,10 +17,22 @@ stack plugs into:
   taken so far) through a FIFO to ``_run_completions``, which does the
   second: the engine thread is back at the queue after stage + dispatch
   and no request waits behind another batch's readback.  At most
-  ``MAX_IN_FLIGHT`` (2) batches are handed over and not yet completed;
-  with two the engine thread waits, before it dequeues, for the older to
-  complete — the admission queue fills meanwhile and the next batch is
-  larger, which is the back-pressure saturation wants.  Whether the
+  ``MAX_IN_FLIGHT`` (2) batches are handed over and not yet completed,
+  and the slots are what closes a batch: the engine thread takes one
+  BEFORE it dequeues, and with a slot in hand it pops whatever is
+  queued at once (``next_batch(coalesce=False)``, ``closed_by`` /
+  ``serving.batch_closed`` ``slot``) — no request waits out
+  ``max_wait_s`` beside a device that could take it.  Requests coalesce
+  only while the thread is away: staging and dispatching the batch
+  before, or, with two in flight, waiting for the older to complete
+  (``handoff_wait``) — the admission queue fills meanwhile and the next
+  batch is larger, which is the back-pressure saturation wants (a
+  queue just over the second-largest bucket goes as that bucket, full,
+  and a remainder, not as the largest program mostly empty:
+  ``MicroBatcher.next_batch``).  A
+  batch's size so follows the pipeline's own state, at any rate, and
+  ``max_wait_s`` stays the upper bound it was, binding only for callers
+  that drive ``next_batch`` themselves.  Whether the
   overlap engages follows from the traffic alone (is the next batch
   closed before the last is read back?), and
   ``serving.batch_overlap{in_flight=0|1}`` counts it.  The completion
@@ -1302,11 +1314,13 @@ class ServingEngine:
         self.stop()
 
     def _run(self):
-        """The engine thread: ``next_batch`` → stage → ``_dispatch`` →
-        hand over → ``next_batch``.  It never waits for an answer; with
-        ``MAX_IN_FLIGHT`` batches handed over and not yet completed it
-        waits for the older to complete BEFORE it pops the next, so what
-        arrives meanwhile rides that next batch."""
+        """The engine thread: a slot → ``next_batch`` → stage →
+        ``_dispatch`` → hand over → a slot.  It never waits for an
+        answer, and with a slot in hand it never waits for company:
+        what is queued is popped at once.  With ``MAX_IN_FLIGHT``
+        batches handed over and not yet completed it waits for the
+        older to complete BEFORE it pops the next, so what arrives
+        meanwhile rides that next batch."""
         try:
             while True:
                 handoff_wait = 0.0
@@ -1314,7 +1328,10 @@ class ServingEngine:
                     t_full = time.perf_counter()
                     self._slots.acquire()
                     handoff_wait = time.perf_counter() - t_full
-                batch = self.batcher.next_batch(timeout=0.1)
+                # the slot is the signal that the device can take a
+                # batch: nothing is held back for company
+                batch = self.batcher.next_batch(timeout=0.1,
+                                                coalesce=False)
                 if batch is None:
                     self._slots.release()
                     if self._stopping.is_set():

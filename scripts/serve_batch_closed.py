@@ -22,6 +22,14 @@ of two batches in flight to complete (``handoff_wait``: batches with
 any, then median, mean, longest in ms), the batch's own wait for the
 completion thread (``completion_wait``: its whole life less its four
 phases) and that thread's wait for a batch (``completion_idle``), the
+two halves of dispatch (``upload``, ``launch``: median, mean, longest in
+ms) and, for a stream a profiler watched, each phase's CPU share
+(``cpu_pct``: the thread's own CPU time over the phase's wall time, summed
+over the window — what is missing the thread spent without a processor, or
+in the two clock calls the span holds), the latency of the requests that rode
+the stream's batches (``e2e_ms``: median and 90th percentile of submit →
+answer over the request ring: a traced stream's beside the window's is
+what the profiler costs), the
 process's ``serving.batch_closed`` and ``serving.batch_overlap``
 counters against the batches the engine served, and what its ``publish_update``s did to
 the device's user table (``serving.user_table_writes``: a live cell
@@ -59,8 +67,10 @@ def three(values):
     return [st.median(values), st.mean(values), max(values)]
 
 
-def shares(records, seconds):
-    """``closed_by`` over the last ``seconds`` of one stream's records."""
+def shares(records, seconds, requests=()):
+    """``closed_by`` over the last ``seconds`` of one stream's records;
+    ``requests``: the request ring's records, for the latency of those
+    that rode the window's batches."""
     window = [r for r in records if r["t0"] > records[-1]["t0"] - seconds]
     by = collections.Counter(r["closed_by"] for r in window)
     row = {"batches": len(window),
@@ -73,6 +83,15 @@ def shares(records, seconds):
     row["completion_wait_ms"] = three(
         1e3 * (r["spans"]["serve.batch"] - sum(r["spans"][p] for p in PHASES))
         for r in window)
+    for name in ("upload", "launch"):
+        row[name + "_ms"] = three(1e3 * r[name] for r in window)
+    # the CPU clock is read only while a profiler records: a traced stream
+    watched = [r for r in window if r["cpu"]["stage"] is not None]
+    if watched:
+        row["cpu_pct"] = {
+            phase: 100.0 * sum(r["cpu"][phase] for r in watched)
+            / sum(r["spans"]["serve.batch." + phase] for r in watched)
+            for phase in watched[0]["cpu"]}
     flying = collections.Counter(r["in_flight"] for r in window)
     for n in sorted(flying):
         row[f"in_flight_{n}"] = flying[n]
@@ -80,6 +99,12 @@ def shares(records, seconds):
     for way in WAYS:
         row[way] = by[way]
         row[way + "_pct"] = 100.0 * by[way] / len(window)
+    rode = range(window[0]["batch"], window[-1]["batch"] + 1)
+    e2e = sorted(1e3 * r["e2e_seconds"] for r in requests
+                 if r.get("batch") in rode and r["e2e_seconds"] is not None)
+    if e2e:
+        row["e2e_ms"] = {"requests": len(e2e), "p50": e2e[len(e2e) // 2],
+                         "p90": e2e[(9 * len(e2e)) // 10]}
     return row
 
 
@@ -106,12 +131,12 @@ def main(argv):
         return code
     seconds = float(argv[argv.index("--seconds") + 1])
     records = engines[0].batch_flight.records()
+    requests = engines[0].flight.records()
     for k, stream in enumerate(s for s in batch_ring.streams(records)
                                if len(s) > 8):
-        print(json.dumps({"batch_closed": k, **shares(stream, seconds)}),
-              flush=True)
-    waits = [1e3 * r["spans"]["queue_wait"]
-             for r in engines[0].flight.records()
+        print(json.dumps({"batch_closed": k,
+                          **shares(stream, seconds, requests)}), flush=True)
+    waits = [1e3 * r["spans"]["queue_wait"] for r in requests
              if r["spans"].get("queue_wait") is not None]
     print(json.dumps({"queue_wait_ms": three(waits),
                       "requests": len(waits)}), flush=True)

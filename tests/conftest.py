@@ -152,12 +152,12 @@ class GatedResponses:
         dispatch = eng._dispatch
 
         def gated(*args):
-            resp, path, fell_back = dispatch(*args)
+            resp, *rest = dispatch(*args)
             gate = self._Gate(resp)
             if self._open:
                 gate.released.set()
             self.gates.append(gate)
-            return gate, path, fell_back
+            return (gate, *rest)
 
         eng._dispatch = gated
 

@@ -26,7 +26,7 @@ from tpu_als.core.ratings import (
     pads_up_to,
     row_capacity,
 )
-from tpu_als.obs.schema import LIVE_BATCH_SPAN_KEYS
+from tpu_als.obs.schema import LIVE_BATCH_SPAN_KEYS, LIVE_FOLDIN_SPAN_KEYS
 from tpu_als.serving import ServingEngine
 from tpu_als.serving.engine import _scatter_users
 
@@ -309,7 +309,7 @@ def _dispatch_by_id(eng, user):
     st = np.zeros((8, RANK + 2), np.int32)
     st[0, RANK] = user
     with eng._table_lock:
-        return eng._dispatch(eng._model, st, 8, None)[0]
+        return eng._dispatch(eng._model, st, 8, None, 0)[0]
 
 
 def test_a_batch_dispatched_before_a_publish_answers_from_the_old_rows():
@@ -678,10 +678,24 @@ def test_the_updaters_cycle_is_on_the_profilers_timeline(tmp_path):
         jax.profiler.stop_trace()
     spans = _live_spans(str(tmp_path))
     names = [s[0] for s in spans]
-    assert set(names) == set(LIVE_BATCH_SPAN_KEYS)
+    assert set(names) == set(LIVE_BATCH_SPAN_KEYS + LIVE_FOLDIN_SPAN_KEYS)
     for phase in ("live.batch", "live.batch.coalesce", "live.batch.foldin",
-                  "live.batch.publish"):
+                  "live.batch.publish", "live.batch.foldin.readback"):
         assert names.count(phase) == 2
+    # the fold's wait for the device lies inside the fold, and the three
+    # spans of the thread's work say what CPU time it had in them
+    for fold, back in zip(*([s for s in spans if s[0] == name] for name in
+                            ("live.batch.foldin",
+                             "live.batch.foldin.readback"))):
+        assert fold[1] <= back[1] and back[1] + back[2] <= fold[1] + fold[2]
+        assert back[3] == {"side": "users"}
+    for name, _, dur, stats in spans:
+        if name in ("live.batch", "live.batch.foldin", "live.batch.publish"):
+            assert 0 <= stats["cpu_us"] <= stats["wall_us"] + 100, stats
+            assert stats["wall_us"] <= dur / 1e3 + 1, (name, stats)
+    for rec in upd.flight.records():
+        for phase in ("foldin", "publish"):
+            assert 0 <= rec[phase + "_cpu"] <= rec["spans"][phase] + 1e-4
     first, second = [s for s in spans if s[0] == "live.batch"]
     assert (first[3]["events"], first[3]["users"], first[3]["new_users"],
             first[3]["width"], first[3]["mode"]) == (3, 2, 1, 8, "retag")

@@ -18,7 +18,11 @@ from tests.test_live_deployment import CompileCount, wait_for
 from tpu_als import ALSModel, FoldInServer, IdMap, LiveUpdater, obs
 from tpu_als.core.foldin import _scatter_rows
 from tpu_als.core.ratings import LIVE_PADS, row_capacity
-from tpu_als.obs.schema import LIVE_BATCH_SPAN_KEYS, LIVE_ITEM_SPAN_KEYS
+from tpu_als.obs.schema import (
+    LIVE_BATCH_SPAN_KEYS,
+    LIVE_FOLDIN_SPAN_KEYS,
+    LIVE_ITEM_SPAN_KEYS,
+)
 from tpu_als.serving import ServingEngine, build_index
 from tpu_als.serving.engine import _scatter_items
 from tpu_als.serving.index import (
@@ -398,7 +402,7 @@ def _dispatch_by_vector(eng, q):
     st[0, :RANK] = np.asarray(q, np.float32).view(np.int32)
     st[0, RANK + 1] = 1
     with eng._table_lock:
-        return eng._dispatch(eng._model, st, 8, None)[0], eng._model.seq
+        return eng._dispatch(eng._model, st, 8, None, 0)[0], eng._model.seq
 
 
 def test_a_batch_dispatched_before_a_compaction_answers_from_its_generation():
@@ -542,7 +546,8 @@ def test_an_items_updaters_timeline_holds_the_item_spans(tmp_path):
              for line in plane.lines for ev in line.events
              if ev.name.startswith("live.")]
     names = [n for n, _ in spans]
-    assert set(names) == set(LIVE_BATCH_SPAN_KEYS) | set(LIVE_ITEM_SPAN_KEYS)
+    assert set(names) == set(LIVE_BATCH_SPAN_KEYS + LIVE_ITEM_SPAN_KEYS
+                             + LIVE_FOLDIN_SPAN_KEYS)
     assert names.count("live.batch.foldin.users") == names.count(
         "live.batch.foldin.items") == names.count("live.batch") == 70
     assert names.count("live.batch.publish.compact") == 1   # at 64 rows
@@ -551,3 +556,51 @@ def test_an_items_updaters_timeline_holds_the_item_spans(tmp_path):
     assert [s["segment_rows"] for s in stats] == [
         *range(1, 64), 0, *range(1, 7)]
     assert stats[63]["mode"] == "compact" and stats[0]["mode"] == "delta"
+
+
+@pytest.fixture(scope="module")
+def traced_folds(tmp_path_factory):
+    """[(name, start_ns, end_ns, stats)] of the ``live.`` spans of five
+    one-event batches of an updater that folds both sides."""
+    import glob
+    import os
+
+    reg = obs.reset()
+    rng, U, V, model, eng, srv, upd = make_stack(seed=10)
+    trace_dir = str(tmp_path_factory.mktemp("folds"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        with upd:
+            one_at_a_time(upd, reg, [(2, j, 4.0) for j in range(5)])
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return sorted(
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("live."))
+
+
+@pytest.mark.parametrize("side", ["users", "items"])
+def test_a_folds_readback_lies_inside_the_fold_of_its_side(traced_folds,
+                                                           side):
+    """``live.batch.foldin.readback`` (stream/microbatch.py: the fold-in
+    program called and its rows read back) is a child of the fold that
+    made it, and says which (ISSUE 36)."""
+    folds = [s for s in traced_folds
+             if s[0] == "live.batch.foldin." + side]
+    backs = [s for s in traced_folds
+             if s[0] == "live.batch.foldin.readback"
+             and s[3]["side"] == side]
+    assert len(folds) == len(backs) == 5
+    for (_, f0, f1, _), (_, b0, b1, _) in zip(folds, backs):
+        assert f0 <= b0 < b1 <= f1
+    other = [s for s in traced_folds
+             if s[0] == "live.batch.foldin.readback"
+             and s[3]["side"] != side]
+    for _, f0, f1, _ in folds:
+        assert all(b1 <= f0 or f1 <= b0 for _, b0, b1, _ in other)

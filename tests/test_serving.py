@@ -662,9 +662,9 @@ def test_each_batch_in_flight_is_staged_into_an_array_of_its_own(rng):
     eng, U, V = _engine(rng)
     staged, inner = [], eng._dispatch
 
-    def keeping(m, st, B, mode):
+    def keeping(m, st, *rest):
         staged.append(st)
-        return inner(m, st, B, mode)
+        return inner(m, st, *rest)
 
     eng._dispatch = keeping
     gated = GatedResponses(eng)

@@ -16,12 +16,22 @@ import pytest
 from tests.conftest import GatedResponses
 from tpu_als import obs
 from tpu_als.obs import tracing
-from tpu_als.obs.schema import SERVE_BATCH_SPAN_KEYS, SERVE_SPAN_KEYS
+from tpu_als.obs.schema import (
+    EVENTS,
+    PIPE_SPAN_KEYS,
+    SERVE_BATCH_SPAN_KEYS,
+    SERVE_DISPATCH_SPAN_KEYS,
+    SERVE_SPAN_KEYS,
+)
 from tpu_als.serving import MicroBatcher, ServingEngine
 from tpu_als.serving.batcher import Ticket
 
 PHASES = ("serve.batch.stage", "serve.batch.dispatch",
           "serve.batch.readback", "serve.batch.complete")
+UPLOAD, LAUNCH = SERVE_DISPATCH_SPAN_KEYS
+# every span the engine writes for a batch: all carry its ``seq``, and
+# the four phases ``cpu_us`` beside ``wall_us``
+OF_A_BATCH = ("serve.batch",) + PHASES + SERVE_DISPATCH_SPAN_KEYS
 BATCHES = ((5, 8), (20, 32), (32, 32))      # (rows, the bucket they ride)
 
 
@@ -53,18 +63,9 @@ def _aged_drain(eng, rows):
 
 
 def _serve_spans(trace_dir):
-    """[(name, start_ns, dur_ns, stats)] of the ``serve.`` spans of every
-    host line of the trace, by start."""
-    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
-                                   "*.xplane.pb"))
-    spans = []
-    for plane in jax.profiler.ProfileData.from_file(path).planes:
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith("serve."):
-                    spans.append((ev.name, ev.start_ns, ev.duration_ns,
-                                  dict(ev.stats)))
-    return sorted(spans, key=lambda s: s[1])
+    """[(name, start_ns, dur_ns, stats)] of the ``serve.`` and ``pipe.``
+    spans of every host line of the trace, by start."""
+    return [s[:4] for s in _spans_by_line(trace_dir)]
 
 
 @pytest.fixture(scope="module")
@@ -95,7 +96,8 @@ def test_every_span_of_the_cycle_once_per_batch(traced):
     for name in SERVE_BATCH_SPAN_KEYS:
         if name != "serve.idle":     # the queue was never empty
             assert names.count(name) == len(BATCHES), name
-    assert set(names) <= set(SERVE_BATCH_SPAN_KEYS)
+    assert set(names) <= set(SERVE_BATCH_SPAN_KEYS
+                             + SERVE_DISPATCH_SPAN_KEYS)
     whole = [s for s in spans if s[0] == "serve.batch"]
     seqs = [s[3]["seq"] for s in whole]
     assert seqs == list(range(seqs[0], seqs[0] + len(BATCHES)))
@@ -122,15 +124,16 @@ def test_phases_lie_inside_their_batch_disjoint_and_cover_it(traced):
 
 
 def _spans_by_line(trace_dir):
-    """``_serve_spans`` with the host line each span sits on:
-    [(name, start_ns, dur_ns, stats, line)]."""
+    """[(name, start_ns, dur_ns, stats, line)] of the ``serve.`` and
+    ``pipe.`` spans of the trace, by start, each with the host line it
+    sits on."""
     path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     spans = []
     for p, plane in enumerate(jax.profiler.ProfileData.from_file(path).planes):
         for n, line in enumerate(plane.lines):
             for ev in line.events:
-                if ev.name.startswith("serve."):
+                if ev.name.startswith(("serve.", "pipe.")):
                     spans.append((ev.name, ev.start_ns, ev.duration_ns,
                                   dict(ev.stats), (p, n)))
     return sorted(spans, key=lambda s: s[1])
@@ -164,7 +167,8 @@ def test_started_engine_writes_the_same_spans_from_two_threads(tmp_path):
     finally:
         jax.profiler.stop_trace()
     spans = _spans_by_line(str(tmp_path))
-    assert {s[0] for s in spans} <= set(SERVE_BATCH_SPAN_KEYS)
+    assert {s[0] for s in spans} <= set(
+        SERVE_BATCH_SPAN_KEYS + SERVE_DISPATCH_SPAN_KEYS + PIPE_SPAN_KEYS)
     by_seq = {}
     for name, start, dur, stats, line in spans:
         if name in ("serve.batch", "serve.batch.readback",
@@ -212,6 +216,205 @@ def test_started_engine_writes_the_same_spans_from_two_threads(tmp_path):
         assert recs[seq]["spans"]["serve.batch.coalesce"] * 1e9 == \
             pytest.approx(dur, abs=1e6)
         assert c0 + dur <= by_seq[seq]["serve.batch"][0]
+
+
+@pytest.fixture(scope="module")
+def pipelined(tmp_path_factory):
+    """A started engine under the profiler with both slots taken: batch 1
+    is held in its readback, batch 2 dispatched behind it, and the
+    engine thread waits for a slot before it can pop the third request:
+    (the trace's spans with their lines, the engine, its first seq)."""
+    obs.reset()
+    eng = _engine(max_wait_s=30.0)
+    _aged_drain(eng, 5)                             # compiled
+    first = eng._batch_seq + 1
+    gated = GatedResponses(eng)
+    trace_dir = str(tmp_path_factory.mktemp("pipelined"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        with eng:
+            a = eng.submit(0)
+            gated.wait_dispatched(1)
+            assert gated.gates[0].entered.wait(10.0)
+            b = eng.submit(1)
+            gated.wait_dispatched(2)
+            c = eng.submit(2)
+            time.sleep(0.02)            # the thread stands at the slots
+            assert len(gated.gates) == 2
+            gated.open()
+            for t in (a, b, c):
+                t.result(timeout=10.0)
+    finally:
+        jax.profiler.stop_trace()
+    return _spans_by_line(trace_dir), eng, first
+
+
+@pytest.fixture(params=["serve_batch", "pipelined"])
+def either(request):
+    """(spans, engine) of the synchronous ``serve_batch`` run and of the
+    started engine's: the same rules hold for both."""
+    if request.param == "serve_batch":
+        spans, eng, _ = request.getfixturevalue("traced")
+        return spans, eng
+    spans, eng, _ = request.getfixturevalue("pipelined")
+    return [s[:4] for s in spans], eng
+
+
+def _by_seq(spans):
+    """{seq: {name: (start, end)}} of the spans a batch is made of."""
+    out = {}
+    for name, start, dur, stats in spans:
+        if name in OF_A_BATCH:
+            own = out.setdefault(stats["seq"], {})
+            assert name not in own, (name, stats["seq"])
+            own[name] = (start, start + dur)
+    return out
+
+
+def test_every_span_of_a_batch_carries_its_seq(either):
+    spans, _ = either
+    batches = _by_seq(spans)
+    assert len(batches) == 3
+    for seq, own in batches.items():
+        assert set(own) == set(OF_A_BATCH), seq
+    # and a span with no seq belongs to no batch
+    assert all("seq" in s[3] for s in spans if s[0] in OF_A_BATCH)
+
+
+def test_upload_and_launch_lie_inside_dispatch_and_do_not_overlap(either):
+    spans, _ = either
+    for seq, own in _by_seq(spans).items():
+        d0, d1 = own["serve.batch.dispatch"]
+        (u0, u1), (l0, l1) = own[UPLOAD], own[LAUNCH]
+        assert d0 <= u0 <= u1 <= l0 <= l1 <= d1, seq
+    launches = [s[3] for s in spans if s[0] == LAUNCH]
+    assert {(st["program"], st["pinned"]) for st in launches} == {
+        ("jit__serve_int8_packed", 0)}      # nobody called warmup()
+    uploads = [s[3] for s in spans if s[0] == UPLOAD]
+    # the staged array: [bucket, rank + 2] of int32
+    assert {st["bytes"] for st in uploads} <= {8 * 18 * 4, 32 * 18 * 4}
+
+
+@pytest.mark.parametrize("name", PHASES)
+def test_cpu_us_lies_within_wall_us_within_the_spans_duration(
+        traced, pipelined, name):
+    """``cpu_us`` is the thread's own CPU time over an interval inside the
+    span, ``wall_us`` the wall time of that interval: never more CPU than
+    wall (the two clocks are read a call apart), nor more wall than the
+    span lasted."""
+    own = [s for s in traced[0] + [p[:4] for p in pipelined[0]]
+           if s[0] == name]
+    assert len(own) == 6
+    for _, _, dur, stats in own:
+        assert 0 <= stats["cpu_us"] <= stats["wall_us"] + 100, stats
+        assert stats["wall_us"] <= dur / 1e3 + 1, stats
+    if name == "serve.batch.readback":
+        # the gated readback blocked for the test's 20 ms: wall, not CPU
+        held = max(own, key=lambda s: s[2])
+        assert held[2] > 15e6 and held[3]["cpu_us"] < 5e3
+
+
+@pytest.mark.parametrize("run", ["serve_batch", "pipelined"])
+def test_slot_wait_is_a_span_only_while_both_slots_are_taken(
+        traced, pipelined, run):
+    if run == "serve_batch":        # its caller's thread takes no slot
+        assert not [s for s in traced[0] if s[0] in PIPE_SPAN_KEYS]
+        return
+    spans, eng, first = pipelined
+    waits = [s for s in spans if s[0] == "pipe.slot_wait"]
+    assert waits and all(set(PIPE_SPAN_KEYS) >= {s[0]} for s in waits)
+    third, = [w for w in waits if w[3]["seq"] == first + 2]
+    assert third[2] > 15e6              # the 20 ms the test held batch 1
+    rec, = [r for r in eng.batch_flight.records()
+            if r["batch"] == first + 2]
+    assert rec["handoff_wait"] * 1e9 == pytest.approx(third[2], abs=1e6)
+    batches = _by_seq([s[:4] for s in spans])
+    engine_line = third[4]
+    for _, w0, dur, stats, line in waits:
+        assert line == engine_line and stats["seq"] > first + 1
+        # both slots: two batches dispatched and not yet completed
+        flying = [seq for seq, own in batches.items()
+                  if own["serve.batch.dispatch"][1] <= w0
+                  < own["serve.batch.complete"][1]]
+        assert len(flying) == 2, (stats, flying)
+        # it ends when the older of them has completed
+        assert batches[min(flying)]["serve.batch.complete"][1] <= \
+            w0 + dur + 1e6
+        # between two batches: under no span of the engine thread's
+        for name, s0, sdur, _, sline in spans:
+            if sline == line and name.startswith("serve."):
+                assert s0 + sdur <= w0 or w0 + dur <= s0, name
+
+
+def test_the_batch_record_carries_the_split_and_the_cpu_time(either):
+    """``upload`` + ``launch`` within ``dispatch`` on every record; the CPU
+    seconds beside each phase's wall seconds on those a profiler watched,
+    ``None`` on the others (the warm batches here): the CPU clock is a
+    system call and is read only where a trace holds it."""
+    _, eng = either
+    declared = EVENTS["flight_record"][1]
+    warm = eng.batch_flight.records()[0]
+    assert set(warm["cpu"].values()) == {None} and warm["upload"] > 0
+    records = eng.batch_flight.records()[-3:]
+    for rec in records:
+        for field in ("upload", "launch", "cpu"):
+            assert field in rec and f"{field}" in declared
+        spans = rec["spans"]
+        assert 0 < rec["upload"] and 0 < rec["launch"]
+        assert rec["upload"] + rec["launch"] <= spans["serve.batch.dispatch"]
+        assert set(rec["cpu"]) == {p.rsplit(".", 1)[1] for p in PHASES}
+        for phase in PHASES:
+            cpu = rec["cpu"][phase.rsplit(".", 1)[1]]
+            assert 0 <= cpu <= spans[phase] + 1e-4, phase
+    assert "upload / launch" in declared and "cpu = {stage, dispatch, " \
+        "readback, complete}" in " ".join(declared.split())
+
+
+def test_no_profiler_no_cpu_clock(monkeypatch):
+    """The thread-CPU clock is a system call (7-30 us on the chip's
+    sandboxed host, PERF.md section 6, PR 36): a batch that no profiler
+    session watches reads it not once, on either thread."""
+    def read(*_):
+        raise AssertionError("the CPU clock was read with no profiler on")
+
+    eng = _engine()
+    _drain(eng, 5)                   # compiled
+    monkeypatch.setattr(time, "thread_time_ns", read)
+    tickets = _drain(eng, 5)
+    with eng:
+        tickets.append(eng.submit(3))
+        tickets[-1].result(timeout=10.0)
+    assert all(t.done() and t.result() is not None for t in tickets)
+    for rec in eng.batch_flight.records():
+        assert set(rec["cpu"].values()) == {None}
+        assert rec["upload"] + rec["launch"] <= rec["spans"][
+            "serve.batch.dispatch"]
+
+
+def test_serve_batch_writes_the_started_engines_spans_but_the_slot_wait(
+        traced, pipelined):
+    sync = {s[0] for s in traced[0]}
+    # (the started engine's queue ran empty; ``traced``'s never did)
+    started = {s[0] for s in pipelined[0]} - {"serve.idle"}
+    assert started - sync == set(PIPE_SPAN_KEYS)
+    assert sync - started == set()
+
+
+def test_a_warmed_engine_launches_its_pinned_program(tmp_path):
+    eng = _engine()
+    eng.warmup()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _drain(eng, 5)
+    finally:
+        jax.profiler.stop_trace()
+    launch, = [s[3] for s in _serve_spans(str(tmp_path)) if s[0] == LAUNCH]
+    assert (launch["program"], launch["pinned"]) == (
+        "jit__serve_int8_packed", 1)
 
 
 def test_batch_ring_keeps_the_spans_durations(traced):

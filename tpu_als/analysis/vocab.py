@@ -104,6 +104,16 @@ TRACE_RECORD_RE = re.compile(
 # spans: schema.LIVE_BATCH_SPAN_KEYS)
 ANNOTATION_RE = re.compile(
     r"\bTraceAnnotation\(\s*(?P<q>['\"])(?P<name>[^'\"]+)(?P=q)")
+# the schema's tuples of profiler span names, each with the packages
+# under tpu_als/ whose TraceAnnotations open them
+PROFILER_SPAN_TUPLES = (
+    ("SERVE_BATCH_SPAN_KEYS", ("serving",)),
+    ("SERVE_DISPATCH_SPAN_KEYS", ("serving",)),
+    ("PIPE_SPAN_KEYS", ("serving",)),
+    ("LIVE_BATCH_SPAN_KEYS", ("live",)),
+    ("LIVE_ITEM_SPAN_KEYS", ("live", "serving")),
+    ("LIVE_FOLDIN_SPAN_KEYS", ("stream",)),
+)
 
 # inline event dicts: a line carrying both a "ts" key and a literal
 # "type" value (the hand-built shape allowed where importing tpu_als is
@@ -402,9 +412,7 @@ def check_trace_vocabulary(repo=REPO):
                 f"tpu_als/obs/schema.py: TRACE_SPANS declares {name!r} "
                 "but no call site under tpu_als/ records it — dead "
                 "vocabulary (remove it or record the hop)")
-    for attr, packages in (("SERVE_BATCH_SPAN_KEYS", ("serving",)),
-                           ("LIVE_BATCH_SPAN_KEYS", ("live",)),
-                           ("LIVE_ITEM_SPAN_KEYS", ("live", "serving"))):
+    for attr, packages in PROFILER_SPAN_TUPLES:
         package = " or tpu_als/".join(packages)
         annotated = set()
         for path in py_files([os.path.join(repo, "tpu_als", sub)
@@ -573,19 +581,17 @@ def check_file(path, repo=REPO):
                         "tpu_als.obs.schema.METRICS)")
 
     if not in_obs:
-        batch_spans = (getattr(schema, "SERVE_BATCH_SPAN_KEYS", ())
-                       + getattr(schema, "LIVE_BATCH_SPAN_KEYS", ())
-                       + getattr(schema, "LIVE_ITEM_SPAN_KEYS", ()))
+        batch_spans = {name for attr, _ in PROFILER_SPAN_TUPLES
+                       for name in getattr(schema, attr, ())}
         for m in ANNOTATION_RE.finditer(text):
             name = m.group("name")
             if name not in batch_spans:
                 lineno = line_of(m.start())
                 add(lineno,
                     f"{rel}:{lineno}: profiler span {name!r} is not "
-                    "declared in tpu_als.obs.schema."
-                    "SERVE_BATCH_SPAN_KEYS, LIVE_BATCH_SPAN_KEYS or "
-                    "LIVE_ITEM_SPAN_KEYS — "
-                    "trace readers key on declared span names only")
+                    "declared in tpu_als.obs.schema ("
+                    + ", ".join(attr for attr, _ in PROFILER_SPAN_TUPLES)
+                    + ") — trace readers key on declared span names only")
         trace_spans = getattr(schema, "TRACE_SPANS", ())
         for regex in (TRACE_START_RE, TRACE_RECORD_RE):
             for m in regex.finditer(text):

@@ -46,8 +46,15 @@ spans, always on, the write path's counterpart of the engine thread's
 also (``obs.schema.LIVE_ITEM_SPAN_KEYS``) ``live.batch.foldin.users``
 and ``live.batch.foldin.items`` inside the fold,
 ``live.batch.publish.compact`` (the engine's) inside a publish that
-compacts, and the stats ``items``, ``new_items``, ``segment_rows``.  A
-trace reader keys on those names.
+compacts, and the stats ``items``, ``new_items``, ``segment_rows``.
+Every fold writes ``live.batch.foldin.readback`` around its program's
+call and the blocking read of its rows
+(``obs.schema.LIVE_FOLDIN_SPAN_KEYS``, stream/microbatch.py), and
+``live.batch``, ``.foldin`` and ``.publish`` carry ``cpu_us`` beside
+``wall_us`` while a profiler session records: the thread's own CPU time
+inside the span (``serving.engine.cpu_mark`` / ``stamp_cpu``; the batch
+record's ``foldin_cpu``, ``publish_cpu``).  A trace reader keys on those
+names.
 
 Freshness (``live.freshness_seconds``) is per EVENT, arrival →
 publish-visible, so the histogram's p99 is exactly the SLO quantity:
@@ -73,6 +80,7 @@ from tpu_als.obs import tracing
 from tpu_als.obs.trace import FlightRecorder
 from tpu_als.resilience import faults
 from tpu_als.serving.batcher import Overloaded
+from tpu_als.serving.engine import cpu_mark, stamp_cpu
 
 # the per-batch span breakdown the updater's flight ring carries
 # (source of truth in the stdlib-only schema module, where the jax-free
@@ -228,7 +236,9 @@ class LiveUpdater:
             try:
                 with TraceAnnotation("live.batch",
                                      seq=self._batch_seq) as whole:
+                    mark = cpu_mark()
                     self._process(batch, whole)
+                    stamp_cpu(whole, mark)
             except BaseException as e:  # noqa: BLE001 — loop must survive
                 if not isinstance(e, faults.InjectedFault):
                     obs.emit("warning", what="live.update",
@@ -285,7 +295,8 @@ class LiveUpdater:
         m = self.foldin.model
         users_before, items_before = len(m._user_map), len(m._item_map)
         touched_item_rows = None
-        with TraceAnnotation("live.batch.foldin"):
+        with TraceAnnotation("live.batch.foldin") as span:
+            mark = cpu_mark()
             # users first: the item fold regresses on their rows
             with (TraceAnnotation("live.batch.foldin.users")
                   if self.fold_items else contextlib.nullcontext()):
@@ -295,17 +306,20 @@ class LiveUpdater:
                 with TraceAnnotation("live.batch.foldin.items"):
                     touched_item_rows = m._item_map.to_dense(
                         self.foldin.update_items(frame))
+            foldin_cpu = stamp_cpu(span, mark)
         foldin_s = time.perf_counter() - tf
         ctxs = [tracing.record_span(c, "live.foldin", seconds=foldin_s)
                 if c is not None else None for c in ctxs]
 
         tp = time.perf_counter()
-        with TraceAnnotation("live.batch.publish"):
+        with TraceAnnotation("live.batch.publish") as span:
+            mark = cpu_mark()
             # the rows the fold moved, and nothing else of either table
             seq, mode = self.engine.publish_update(
                 m._U, m._V, touched_items=touched_item_rows,
                 touched_users=m._user_map.to_dense(touched_users),
                 trace=ctxs)
+            publish_cpu = stamp_cpu(span, mark)
         publish_s = time.perf_counter() - tp
         sizes = {}
         if self.fold_items:
@@ -352,6 +366,11 @@ class LiveUpdater:
             # visible, and when on perf_counter's clock (the engine's
             # batch records carry their ``t0`` the same way)
             events=len(ratings), t_done=done, **sizes,
+            # the thread's own CPU seconds in the two phases, beside
+            # their wall seconds above (None: no profiler recorded):
+            # what is missing it spent waiting, for the interpreter or
+            # for the device
+            foldin_cpu=foldin_cpu, publish_cpu=publish_cpu,
             trace_ids=sorted({c.trace_id for c in ctxs
                               if c is not None}) or None)
         if self.slo_s is not None and worst > self.slo_s:

@@ -364,6 +364,41 @@ SERVE_BATCH_SPAN_KEYS = (
     "serve.batch.complete",   # C: completing the tickets + their
     #                           bookkeeping (seq)
 )
+# every span of a batch carries its ``seq``, and the four phases
+# (stage, dispatch, readback, complete) ``cpu_us`` beside ``wall_us``:
+# the thread's own CPU time inside the span (``time.thread_time_ns``) and
+# the wall time of the same interval, so wall - CPU is time the thread
+# held no processor — waiting for the interpreter, or blocked in a
+# transfer.  The two are stamped only while a profiler session records
+# (serving/engine.py ``cpu_mark``: the CPU clock is a system call, 7-30 us
+# on the chip's sandboxed host), so a span has both or neither.  Dispatch
+# is split where its work happens, two child spans INSIDE
+# ``serve.batch.dispatch`` (a reader that adds the leaves above covers no
+# more time for them); not keys of the batch record, which carries their
+# seconds as ``upload`` and ``launch``
+SERVE_DISPATCH_SPAN_KEYS = (
+    "serve.batch.dispatch.upload",  # E: device_put of the staged batch
+    #                                 (seq, bytes: the staged array's,
+    #                                 which every shard of a mesh takes
+    #                                 whole)
+    "serve.batch.dispatch.launch",  # E: the scorer chosen and called,
+    #                                 until the call returns (seq,
+    #                                 program: the jitted function as the
+    #                                 trace's XLA Modules line names it,
+    #                                 ``jit__serve_int8_packed``, ...;
+    #                                 pinned 0|1: the AOT executable took
+    #                                 it)
+)
+# the engine thread's blocking wait for one of MAX_IN_FLIGHT slots,
+# written only when it blocks (seq: the batch that will take the slot;
+# the record's ``handoff_wait``).  Named OUTSIDE the ``serve.`` prefix on
+# purpose: benchmark/program_spans.py::read takes every ``serve.`` event
+# for a phase of a batch, and a span BETWEEN two batches would move its
+# unattributed share and break "the gaps by span add up to the idle
+# time"; the ``benchmark`` PR that retires that reader may rename it
+PIPE_SPAN_KEYS = (
+    "pipe.slot_wait",
+)
 # inside a mesh engine's ONE scoring program a bucket (serving/engine.py
 # ``_build_mesh_serve`` / ``_build_mesh_exact``) the three steps that
 # exist only across chips are ``jax.named_scope``s, in every operation's
@@ -403,6 +438,16 @@ LIVE_ITEM_SPAN_KEYS = (
     "live.batch.foldin.users",    # FoldInServer.update
     "live.batch.foldin.items",    # FoldInServer.update_items
     "live.batch.publish.compact",  # the segment folded into the base
+)
+# what every fold writes, items or none, inside ``live.batch.foldin``
+# (with ``fold_items`` inside ``.foldin.users`` / ``.foldin.items``):
+# stream/microbatch.py, which the updater drives.  ``live.batch``,
+# ``.foldin`` and ``.publish`` also carry ``cpu_us`` and ``wall_us`` (as
+# the engine's phases above, under a profiler)
+LIVE_FOLDIN_SPAN_KEYS = (
+    "live.batch.foldin.readback",  # the fold-in program called and its
+    #                                rows read back: blocks on the
+    #                                device (side: users | items)
 )
 
 # field names every flight record (and its flight_record event) claims
@@ -563,7 +608,16 @@ EVENTS = {
         "handoff_wait = seconds the engine thread waited, before it "
         "dequeued this batch, for one of two batches in flight to "
         "complete, completion_idle = seconds the completion thread had "
-        "waited for a batch when this one was handed over) "
+        "waited for a batch when this one was handed over, upload / "
+        "launch = seconds of the two child spans of dispatch "
+        "(SERVE_DISPATCH_SPAN_KEYS; upload + launch <= dispatch), cpu = "
+        "{stage, dispatch, readback, complete}: seconds of the thread's "
+        "own CPU time in each phase, beside the phase's wall seconds in "
+        "spans — wall - CPU is time the thread held no processor; each "
+        "None for a batch that no profiler session watched: the CPU "
+        "clock is read only where a trace holds it); the "
+        "live updater's per-batch records carry foldin_cpu and "
+        "publish_cpu the same way "
         "(obs.trace.FlightRecorder)"),
     "attribution": (
         ("stages", "wall_s_per_iter", "coverage"),

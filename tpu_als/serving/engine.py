@@ -42,12 +42,19 @@ stack plugs into:
 - **Which thread writes which span** (``obs.schema.
   SERVE_BATCH_SPAN_KEYS``): the engine thread ``serve.idle``,
   ``serve.batch.coalesce`` (both in the batcher), ``serve.batch``
-  around ``serve.batch.stage`` and ``serve.batch.dispatch``; the
-  completion thread ``serve.batch.readback`` and
-  ``serve.batch.complete``, each with the batch's ``seq``.  What the
-  completion thread waits for when it has nothing is no span (a trace
-  reader counts every ``serve.`` span as a phase): it is the batch
-  record's ``completion_idle``.
+  around ``serve.batch.stage`` and ``serve.batch.dispatch`` (inside it
+  ``.dispatch.upload`` and ``.dispatch.launch``,
+  ``SERVE_DISPATCH_SPAN_KEYS``); the completion thread
+  ``serve.batch.readback`` and ``serve.batch.complete``.  Every span of
+  a batch carries its ``seq``, and — while a profiler session records —
+  each phase ``cpu_us`` and ``wall_us``, the thread's own CPU time
+  inside it beside the wall time of the same interval
+  (:func:`cpu_mark`, :func:`stamp_cpu`).  The engine thread's wait
+  for a slot is ``pipe.slot_wait`` (``PIPE_SPAN_KEYS``), outside the
+  ``serve.`` prefix: a trace reader counts every ``serve.`` span as a
+  phase of a batch.  What the completion thread waits for when it has
+  nothing is no span for the same reason: it is the batch record's
+  ``completion_idle``.
 - **Atomic publishes, no recompile.**  :meth:`ServingEngine.publish`
   places the new U/V on device once and swaps a single reference under
   a lock; in-flight batches finish against the old tables, the next
@@ -118,7 +125,10 @@ stack plugs into:
   the two threads.  The same durations go into one record per batch
   in a second ring, ``batch_flight``, dumped on the same triggers, with
   ``in_flight`` and ``handoff_wait`` (the engine thread's wait for one
-  of two batches in flight to complete: the saturation signal).
+  of two batches in flight to complete: the saturation signal), the two
+  halves of dispatch (``upload``, ``launch``) and each phase's CPU
+  seconds (``cpu``; ``None`` for a batch no profiler watched: the CPU
+  clock costs too much on the chip's host to be read for nobody).
 - **One algorithm on any number of chips.**  The engine serves the
   int8 shortlist + exact f32 rescore from a candidate index: an
   :class:`~tpu_als.serving.index.Int8CandidateIndex` when ``mesh`` is
@@ -209,6 +219,36 @@ from tpu_als.serving.index import (
 MAX_IN_FLIGHT = 2
 
 
+def cpu_mark():
+    """``(thread CPU ns, wall ns)`` read together as a stamped span opens,
+    or ``None`` while no profiler session records: the CPU clock is a
+    system call, which the chip's sandboxed host answers in 7 us alone and
+    in 30 us beside busy threads (``perf_counter`` in 0.15 us: PERF.md
+    section 6, PR 36), so the stamps are taken only where a trace will
+    hold them.  The wall reading comes second here and first in
+    :func:`stamp_cpu`: the two slow calls lie outside the interval."""
+    if not TraceAnnotation.is_enabled():
+        return None
+    return time.thread_time_ns(), time.perf_counter_ns()
+
+
+def stamp_cpu(span, mark):
+    """Close the CPU account that :func:`cpu_mark` opened on ``span``, a
+    ``TraceAnnotation`` about to close: ``cpu_us``, the calling thread's
+    own CPU time since the mark, beside ``wall_us``, the wall time of the
+    same interval (the span's own duration less the two clock calls).
+    Returns the CPU seconds, ``None`` for no mark.  Wall less CPU is time
+    the thread held no processor: waiting for the interpreter, or blocked
+    in a transfer (a phase that never blocks and reads 20 ms of wall on
+    2 ms of CPU stood still with the machine)."""
+    if mark is None:
+        return None
+    wall_ns = time.perf_counter_ns() - mark[1]
+    cpu_ns = time.thread_time_ns() - mark[0]
+    span.set_metadata(cpu_us=cpu_ns // 1000, wall_us=wall_ns // 1000)
+    return 1e-9 * cpu_ns
+
+
 class NoModelPublished(RuntimeError):
     """A request arrived before the first :meth:`ServingEngine.publish`."""
 
@@ -236,7 +276,11 @@ class _Flown:
     head_wait: float
     in_flight: int
     handoff_wait: float
-    t_flown: float          # the scoring call had returned
+    t_launch: float         # the upload had returned
+    t_launched: float       # the scoring call had returned
+    cpu_stage: float | None     # the engine thread's CPU seconds in it
+    cpu_dispatch: float | None  # (None: no profiler was recording)
+    t_flown: float          # the engine thread is done with the batch
 
 
 class _Published:
@@ -1326,7 +1370,9 @@ class ServingEngine:
                 handoff_wait = 0.0
                 if not self._slots.acquire(blocking=False):
                     t_full = time.perf_counter()
-                    self._slots.acquire()
+                    with TraceAnnotation("pipe.slot_wait",
+                                         seq=self._batch_seq + 1):
+                        self._slots.acquire()
                     handoff_wait = time.perf_counter() - t_full
                 # the slot is the signal that the device can take a
                 # batch: nothing is held back for company
@@ -1402,8 +1448,10 @@ class ServingEngine:
         without the background threads.  The phases are disjoint spans
         on the profiler's timeline (``obs.schema.SERVE_BATCH_SPAN_KEYS``;
         ``serve.batch`` carries ``seq``, ``bucket``, ``rows``, ``path``),
-        all four inside ``serve.batch`` here, and their durations go
-        into one ``batch_flight`` record.
+        all four inside ``serve.batch`` here, with the started engine's
+        stats and dispatch's two child spans (no ``pipe.slot_wait``: the
+        caller's thread takes no slot), and their durations go into one
+        ``batch_flight`` record.
         """
         seq = self._batch_seq = self._batch_seq + 1
         with TraceAnnotation("serve.batch", seq=seq) as whole:
@@ -1427,7 +1475,8 @@ class ServingEngine:
         # host: PERF.md section 6, PR 31)
         held = False
         try:
-            with TraceAnnotation("serve.batch.stage"):
+            with TraceAnnotation("serve.batch.stage", seq=seq) as span:
+                mark = cpu_mark()
                 held = self._table_lock.acquire()
                 t_locked = time.perf_counter()
                 live = self._expire(batch, t_locked)
@@ -1441,19 +1490,25 @@ class ServingEngine:
                     t.seq = m.seq
                 B = bucket_for(n, self.batcher.buckets)
                 st = self._staged(live, B, m.rank)
-                obs.histogram("serving.batch_rows", n, **self._labels)
+                cpu_stage = stamp_cpu(span, mark)
             t_dispatch = time.perf_counter()
-            with TraceAnnotation("serve.batch.dispatch"):
+            with TraceAnnotation("serve.batch.dispatch", seq=seq) as span:
+                mark = cpu_mark()
                 # each counter has one writer: the difference needs no lock
                 in_flight = self._handed - self._completed
-                resp_dev, path, fell_back = self._dispatch(m, st, B, mode)
+                (resp_dev, path, fell_back, t_launch,
+                 t_launched) = self._dispatch(m, st, B, mode, seq)
                 if fell_back:
                     obs.counter("serving.fallback_exact", n,
                                 **self._labels)
                 whole.set_metadata(bucket=B, rows=n, path=path)
+                cpu_dispatch = stamp_cpu(span, mark)
         finally:
             if held:
                 self._table_lock.release()
+        # the registry's lock is taken with the table's given back: a
+        # publisher waiting for the table waits for no histogram
+        obs.histogram("serving.batch_rows", n, **self._labels)
         obs.counter("serving.batch_overlap", in_flight=in_flight,
                     **self._labels)
         # the batcher's account of this dequeue is taken now: by the
@@ -1461,14 +1516,16 @@ class ServingEngine:
         return _Flown(seq, live, resp_dev, B, n, path, fell_back,
                       t_stage, t_locked, t_dispatch, self.batcher.last_wait,
                       self.batcher.closed_by, self.batcher.head_wait,
-                      in_flight, handoff_wait, time.perf_counter())
+                      in_flight, handoff_wait, t_launch, t_launched,
+                      cpu_stage, cpu_dispatch, time.perf_counter())
 
     def _finish(self, flown, t_readback, idle_s=0.0):
         """A batch's second half, readback + complete, from
         ``t_readback`` (when the caller took the batch up; ``idle_s``:
         how long it had waited for one).  Reads nothing but ``flown``."""
         seq, live, path = flown.seq, flown.live, flown.path
-        with TraceAnnotation("serve.batch.readback", seq=seq):
+        with TraceAnnotation("serve.batch.readback", seq=seq) as span:
+            mark = cpu_mark()
             # ONE bulk device→host transfer; tickets complete with
             # numpy views sliced from this buffer (which snapshots an
             # immutable device array — the views stay valid after
@@ -1477,9 +1534,11 @@ class ServingEngine:
             kw = resp.shape[1] // 2
             scores = resp[:, :kw].view(np.float32)  # same-itemsize view
             indices = resp[:, kw:]
+            cpu_readback = stamp_cpu(span, mark)
         t_complete = time.perf_counter()
         score_s = t_complete - flown.t_dispatch
-        with TraceAnnotation("serve.batch.complete", seq=seq):
+        with TraceAnnotation("serve.batch.complete", seq=seq) as span:
+            mark = cpu_mark()
             obs.histogram("serving.score_seconds", score_s, path=path,
                           **self._labels)
             e2es = []
@@ -1510,6 +1569,7 @@ class ServingEngine:
                 trigger = "degraded"
             if trigger:
                 self.flight.dump(trigger)
+            cpu_complete = stamp_cpu(span, mark)
         t_end = time.perf_counter()
         idle_queue_s, waiting, coalesce_s = flown.last_wait
         # ``serve.batch`` is the batch's whole life, stage to the last
@@ -1528,7 +1588,11 @@ class ServingEngine:
             head_wait=flown.head_wait,
             lock_wait=flown.t_locked - flown.t_stage,
             in_flight=flown.in_flight, handoff_wait=flown.handoff_wait,
-            completion_idle=idle_s)
+            completion_idle=idle_s,
+            upload=flown.t_launch - flown.t_dispatch,
+            launch=flown.t_launched - flown.t_launch,
+            cpu={"stage": flown.cpu_stage, "dispatch": flown.cpu_dispatch,
+                 "readback": cpu_readback, "complete": cpu_complete})
         if trigger:
             self.batch_flight.dump(trigger)
 
@@ -1577,25 +1641,37 @@ class ServingEngine:
                 st[j, rank + 1] = 1
         return st
 
-    def _dispatch(self, m, st, B, mode):
+    def _dispatch(self, m, st, B, mode, seq):
         """Upload the staged batch (to every shard, with a mesh) and
-        call the scorer the live model selects; returns ``(packed
-        response on the device, path, fell back to exact)`` as soon as
-        the call returns."""
+        call the scorer the live model selects, each in a child span of
+        the caller's ``serve.batch.dispatch``; returns ``(packed
+        response on the device, path, fell back to exact, when the
+        upload had returned, when the call had)`` as soon as the call
+        returns."""
         index = m.index
-        packed = jax.device_put(st, self._replicated)
-        use_index = (index is not None and index.seq == m.seq
-                     and mode != "corrupt")
-        fell_back = index is not None and not use_index
-        if self.mesh is not None:
-            obs.counter("serving.mesh_exchange_bytes",
-                        self._mesh_plan(m, index if use_index else None,
-                                        B)["exchange_bytes"],
-                        **self._labels)
-        if not use_index:
-            path, pin, call = "exact", "exact", self._exact_call(m, packed)
-        else:
-            path = "int8" if self.mesh is None else "int8_sharded"
-            pin, call = (self._int8_pin(index),
-                         self._int8_call(m, index, packed))
-        return self._run_pinned((B, pin), *call), path, fell_back
+        with TraceAnnotation("serve.batch.dispatch.upload", seq=seq,
+                             bytes=st.nbytes):
+            packed = jax.device_put(st, self._replicated)
+        t_launch = time.perf_counter()
+        with TraceAnnotation("serve.batch.dispatch.launch",
+                             seq=seq) as span:
+            use_index = (index is not None and index.seq == m.seq
+                         and mode != "corrupt")
+            fell_back = index is not None and not use_index
+            if self.mesh is not None:
+                obs.counter("serving.mesh_exchange_bytes",
+                            self._mesh_plan(m, index if use_index else None,
+                                            B)["exchange_bytes"],
+                            **self._labels)
+            if not use_index:
+                path, pin = "exact", "exact"
+                fn, args, statics = self._exact_call(m, packed)
+            else:
+                path = "int8" if self.mesh is None else "int8_sharded"
+                pin = self._int8_pin(index)
+                fn, args, statics = self._int8_call(m, index, packed)
+            resp_dev = self._run_pinned((B, pin), fn, args, statics)
+            # a pin that failed was dropped inside the call
+            span.set_metadata(program="jit_" + fn.__name__,
+                              pinned=int((B, pin) in self._pinned))
+        return resp_dev, path, fell_back, t_launch, time.perf_counter()

@@ -47,6 +47,7 @@ import time
 import numpy as np
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from tpu_als import obs
 from tpu_als.core.foldin import fold_in, place_rows, solve_path, write_rows
@@ -304,11 +305,16 @@ class FoldInServer:
             YtY = compute_yty(F) if self._implicit else None
         else:
             YtY = self._YtY
-        x = np.asarray(fold_in(
-            F, jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(mask),
-            self._reg, implicit_prefs=self._implicit, alpha=self._alpha,
-            nonnegative=self._nonnegative, YtY=YtY,
-        ))[:n]
+        # the fold's one wait for the device, on the profiler's timeline
+        # (obs.schema.LIVE_FOLDIN_SPAN_KEYS): three uploads, the call,
+        # the rows read back
+        with TraceAnnotation("live.batch.foldin.readback",
+                             side=side + "s"):
+            x = np.asarray(fold_in(
+                F, jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(mask),
+                self._reg, implicit_prefs=self._implicit,
+                alpha=self._alpha, nonnegative=self._nonnegative, YtY=YtY,
+            ))[:n]
 
         self._write_back(touched, x, items_side)
         if items_side and self._implicit:

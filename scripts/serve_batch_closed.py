@@ -37,7 +37,10 @@ counts ``inplace`` alone) and to the catalog (``serving.catalog_writes``:
 ``carried`` alone where no item is folded; where items are, ``delta`` and
 ``compact`` with the bytes a publish sent of each table, the compactions'
 rows, the items appended and the ratings still waiting for a side's
-factor), and for an engine given a mesh its
+factor), the ``serving_shortlist`` events (one a scoring program
+``warmup()`` / ``warmup_live()`` compiled: the selection's stages, blocks
+and ``blocks_layout``, what stage two asks of the compiler for that
+bucket), and for an engine given a mesh its
 ``serving_mesh_plan`` events (one a bucket ``warmup()`` pinned) with
 the process's ``serving.mesh_exchange_bytes``: the mesh path read
 without a profiler.  No CPU mode (``run.py`` has none):
@@ -169,9 +172,13 @@ def main(argv):
             e["value"] for e in events if e["type"] == "metric"
             and e["name"] == "live.events_waiting"] or [None])[-1]}),
         flush=True)
-    plans = [{k: v for k, v in e.items() if k not in ("ts", "type")}
-             for e in obs.default_registry()._events
-             if e["type"] == "serving_mesh_plan"]
+    def bare(kind):
+        return [{k: v for k, v in e.items() if k not in ("ts", "type")}
+                for e in events if e["type"] == kind]
+
+    print(json.dumps({"serving_shortlist": bare("serving_shortlist")}),
+          flush=True)
+    plans = bare("serving_mesh_plan")
     if plans:
         print(json.dumps({"serving_mesh_plan": plans,
                           "serving.mesh_exchange_bytes": obs.counter_value(

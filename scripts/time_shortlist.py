@@ -1,33 +1,60 @@
 #!/usr/bin/env python3
-"""The shortlist selection alone, on the chip: ``jax.lax.top_k`` against
-``ops.topk.shortlist_topk`` on ``f32[B, 1505938]`` (the benchmark cell's
-catalog), k = 64, B in {8, 32, 128}.
+"""The shortlist selection on the chip, whole and by stage, k = 64,
+B in {8, 32, 128}.
 
     chiprun -- python scripts/time_shortlist.py
 
-Each call is fenced with ``block_until_ready``; 20 repeats after a warm
-one, median ms, and the plan (stages, blocks, L) as chosen.  One JSON line
-per B, then a table.  The input is a plain row-major matrix of N(0, 1)
-scores, not the int8 score fusion's output: inside the serving program the
-matrix is already whole blocks wide (the index pads its catalog once, at
-build time), here the ragged 1,505,938 pays its pad, so the second pair of
-columns times the function at the padded width too.  Exits 1 without a TPU:
-a CPU's times are not the chip's.
+Three tables, one JSON line a row and then markdown:
+
+1. **The stages alone** (PR 37), blocks in {11,766, 11,956} (the cells'
+   catalogs, without and with the live segment): ``jax.lax.top_k`` on
+   ``f32[B, blocks]`` handed over row-major (``{1,0}``) and column-major
+   (``{0,1}``, which is how stage two's block maxima leave the score
+   fusion: the batch's rows along the 128 lanes), each asked for with a
+   layout constraint on the operand, and on ``f32[B, k * 128]`` (stage
+   three's operand).  The layout ``TopK`` was in fact handed is read back
+   from the compiled program, and the operation's own time stands beside
+   the program's (which holds the relayout copies a constraint may cost).
+2. **The whole function**: ``ops.topk.shortlist_topk`` on ``f32[B,
+   blocks * 128]`` under three rules for stage two's layout constraint:
+   the tree's (``ops.topk.ROW_MAJOR_BELOW`` rows), never (the function
+   as it was before PR 37) and always.
+3. **The scoring program** ``serving.index._int8_topk`` at the benchmark
+   cell's shapes (1,506,048 int8 rows of rank 256) under the same three:
+   what a batch pays on the device.
+
+Then, as since PR 26, ``jax.lax.top_k`` against ``shortlist_topk`` on
+``f32[B, 1505938]``, ragged and at the padded width.
+
+A time is the device's own: the median duration of the program's runs on
+the trace's ``XLA Modules`` line over 20 runs after a warm one (a host
+clock around a 0.05 ms program reads the launch, 0.2-0.5 ms on this
+host), and its longest operation by self time on the ``XLA Ops`` line;
+the host clock's median, each run fenced with ``block_until_ready``,
+stands beside them.  Inputs are N(0, 1) scores.
+Exits 1 without a TPU: a CPU's times are not the chip's.
 """
 
 from __future__ import annotations
 
+import functools
+import glob
 import json
 import os
+import re
 import statistics
 import sys
+import tempfile
 import time
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from benchmark.trace import self_times, short_name  # noqa: E402
+from tpu_als.ops import topk as topk_mod  # noqa: E402
 from tpu_als.ops.topk import (  # noqa: E402
     shortlist_columns,
     shortlist_plan,
@@ -36,8 +63,57 @@ from tpu_als.ops.topk import (  # noqa: E402
 
 COLUMNS = 1_505_938
 K = 64
+BLOCK_LEN = 128
+BLOCKS = (11_766, 11_956)
 BATCHES = (8, 32, 128)
 REPEATS = 20
+RANK = 256
+ROW_MAJOR = Layout(major_to_minor=(0, 1))
+COLUMN_MAJOR = Layout(major_to_minor=(1, 0))
+
+
+def times_ms(fn, *args):
+    """``(device ms, host ms, longest operation, its ms)`` of one call of
+    the jitted ``fn``: medians over ``REPEATS`` runs, the device's from
+    its own record of them; the operation's is its self time a run."""
+    jax.block_until_ready(fn(*args))
+    host = []
+    with tempfile.TemporaryDirectory() as d:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(d, profiler_options=opts)
+        for _ in range(REPEATS):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            host.append((time.perf_counter() - t0) * 1e3)
+        jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                         "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+    lines = {line.name: list(line.events) for plane in data.planes
+             if plane.name == "/device:TPU:0" for line in plane.lines}
+    runs = [ev.duration_ns * 1e-6 for ev in lines["XLA Modules"]]
+    assert len(runs) == REPEATS, (len(runs), REPEATS)
+    ops = self_times([(short_name(ev.name), int(ev.start_ns),
+                       int(ev.duration_ns)) for ev in lines["XLA Ops"]])
+    op, ns = max(ops.items(), key=lambda kv: kv[1])
+    return (statistics.median(runs), statistics.median(host), op,
+            ns * 1e-6 / REPEATS)
+
+
+def topk_operands(fn, *args, **kw):
+    """What each ``TopK`` of the compiled ``fn`` is handed, by the last
+    part of its ``op_name``: ``{"blocks/top_k": "f32[8,11766]{1,0}"}``."""
+    text = fn.lower(*args, **kw).compile().as_text()
+    shapes = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]*\]\{[\d,]*)", text))
+    found = {}
+    for ln in text.splitlines():
+        m = re.search(r"= \(.*\) (?:custom-call|fusion)\((%[\w.\-]+)\).*"
+                      r"(?:custom_call_target=\"TopK\"|kind=kCustom).*"
+                      r"op_name=\"[^\"]*?(\w+/top_k|top_k)\"", ln)
+        if m and m.group(1) in shapes:
+            found[m.group(2)] = shapes[m.group(1)] + "}"
+    return found
 
 
 @jax.jit
@@ -45,19 +121,118 @@ def single(scores):
     return jax.lax.top_k(scores, K)
 
 
+@functools.partial(jax.jit, static_argnames="layout")
+def single_in(scores, layout):
+    return jax.lax.top_k(with_layout_constraint(scores, layout), K)
+
+
 @jax.jit
 def staged(scores):
     return shortlist_topk(scores, K)
 
 
-def median_ms(fn, x):
-    jax.block_until_ready(fn(x))
-    times = []
-    for _ in range(REPEATS):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(x))
-        times.append((time.perf_counter() - t0) * 1e3)
-    return statistics.median(times)
+def timed_by_rule(fn, *args, **kw):
+    """Device / host ms of the jitted ``fn`` under three rules for stage
+    two's layout constraint: ``tree`` (``ROW_MAJOR_BELOW`` as it stands),
+    ``never`` (the program as it was before PR 37) and ``always``.  A
+    script's device, not a switch of the program: the constant is put
+    back, and every trace dropped, around each."""
+    out = {}
+    kept = topk_mod.ROW_MAJOR_BELOW
+    for rule, below in (("tree", kept), ("never", 0), ("always", 1 << 30)):
+        topk_mod.ROW_MAJOR_BELOW = below
+        jax.clear_caches()
+        try:
+            out[rule] = times_ms(lambda *a: fn(*a, **kw), *args)
+            if rule == "tree":
+                out["operands"] = topk_operands(fn, *args, **kw)
+        finally:
+            topk_mod.ROW_MAJOR_BELOW = kept
+    jax.clear_caches()
+    return out
+
+
+def stages_alone(dev):
+    rows = []
+    for b in BATCHES:
+        for blocks in BLOCKS:
+            x = jax.random.normal(jax.random.PRNGKey(b), (b, blocks),
+                                  jnp.float32)
+            won = jax.random.normal(jax.random.PRNGKey(b + 1),
+                                    (b, K * BLOCK_LEN), jnp.float32)
+            want = single(x)
+            row = {"table": "stages", "B": b, "blocks": blocks,
+                   "device": dev.device_kind, "equal": True}
+            for name, layout in (("row_major", ROW_MAJOR),
+                                 ("column_major", COLUMN_MAJOR)):
+                got = single_in(x, layout)
+                row["equal"] &= bool(jnp.array_equal(want[0], got[0])
+                                     and jnp.array_equal(want[1], got[1]))
+                row[name] = times_ms(single_in, x, layout)
+                row[name + "_operand"] = topk_operands(single_in, x, layout)
+            row["select"] = times_ms(single_in, won, ROW_MAJOR)
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+            del x, won
+    return rows
+
+
+def whole_function():
+    rows = []
+    for b in BATCHES:
+        for blocks in BLOCKS:
+            x = jax.random.normal(jax.random.PRNGKey(b),
+                                  (b, blocks * BLOCK_LEN), jnp.float32)
+            row = {"table": "whole", "B": b, "blocks": blocks,
+                   **timed_by_rule(staged, x),
+                   **shortlist_plan(blocks * BLOCK_LEN, K, b)._asdict()}
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+            del x
+    return rows
+
+
+def scoring_program():
+    from tpu_als.serving.index import _int8_topk
+
+    cols = shortlist_columns(COLUMNS, K)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
+    Vq = jax.random.randint(k1, (cols, RANK), -127, 128, jnp.int8)
+    sv = jnp.full((cols,), 1.0 / 127 / 16, jnp.float32)
+    V = jax.random.normal(k2, (COLUMNS, RANK), jnp.float32) / 16
+    valid = jnp.ones((cols,), jnp.bool_).at[COLUMNS:].set(False)
+    rows = []
+    for b in BATCHES:
+        U = jax.random.normal(jax.random.fold_in(k3, b), (b, RANK),
+                              jnp.float32)
+        row = {"table": "program", "B": b, "columns": cols,
+               "blocks": shortlist_plan(cols, K).blocks,
+               **timed_by_rule(_int8_topk, U, Vq, sv, V, valid,
+                               k=10, shortlist_k=K)}
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    return rows
+
+
+def against_single_top_k(dev):
+    padded = shortlist_columns(COLUMNS, K)
+    rows = []
+    for b in BATCHES:
+        row = {"table": "single", "B": b, "k": K, "device": dev.device_kind}
+        for cols in (COLUMNS, padded):
+            x = jax.random.normal(jax.random.PRNGKey(b), (b, cols),
+                                  jnp.float32)
+            want, got = single(x), staged(x)
+            same = bool(jnp.array_equal(want[0], got[0])
+                        and jnp.array_equal(want[1], got[1]))
+            row[str(cols)] = {
+                "top_k": times_ms(single, x),
+                "shortlist_topk": times_ms(staged, x),
+                "equal": same, **shortlist_plan(cols, K, b)._asdict()}
+            del x, want, got
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    return rows, padded
 
 
 def main():
@@ -66,35 +241,60 @@ def main():
         print(f"time_shortlist: needs a TPU, found {dev.platform}",
               file=sys.stderr)
         return 1
-    padded = shortlist_columns(COLUMNS, K)
-    rows = []
-    for b in BATCHES:
-        row = {"B": b, "k": K, "device": dev.device_kind}
-        for cols in (COLUMNS, padded):
-            x = jax.random.normal(jax.random.PRNGKey(b), (b, cols),
-                                  jnp.float32)
-            want, got = single(x), staged(x)
-            same = bool(jnp.array_equal(want[0], got[0])
-                        and jnp.array_equal(want[1], got[1]))
-            plan = shortlist_plan(cols, K)
-            row[str(cols)] = {
-                "top_k_ms": median_ms(single, x),
-                "shortlist_topk_ms": median_ms(staged, x),
-                "equal": same, **plan._asdict()}
-            del x, want, got
-        rows.append(row)
-        print(json.dumps(row), flush=True)
-    print(f"\n| B | lax.top_k ms | shortlist_topk ms | at {padded} columns: "
-          "lax.top_k ms | shortlist_topk ms | stages, blocks, L | equal |")
+    stages = stages_alone(dev)
+    whole = whole_function()
+    program = scoring_program()
+    single_rows, padded = against_single_top_k(dev)
+
+    def ms(t):
+        return f"{t[0]:.4f} ({t[1]:.3f})"
+
+    def op(t):
+        return f"{t[3]:.4f} `{t[2]}`"
+
+    print("\nDevice ms of the program (host clock ms), medians of 20; "
+          "an operation's: self ms a run.\n")
+    print("| B | blocks | top_k asked row-major {1,0} | its longest "
+          "operation | top_k asked column-major {0,1} | its longest "
+          "operation | ratio of the two operations | top_k f32[B, 8192] "
+          "row-major (stage three) | TopK was handed | equal |")
+    print("| --- | --- | --- | --- | --- | --- | --- | --- | --- | --- |")
+    for r in stages:
+        print(f"| {r['B']} | {r['blocks']} | {ms(r['row_major'])} | "
+              f"{op(r['row_major'])} | {ms(r['column_major'])} | "
+              f"{op(r['column_major'])} | "
+              f"{r['column_major'][3] / r['row_major'][3]:.2f} | "
+              f"{ms(r['select'])} | {r['row_major_operand']} / "
+              f"{r['column_major_operand']} | {r['equal']} |")
+    print(f"\nStage two constrained to row-major by the tree's rule "
+          f"(under {topk_mod.ROW_MAJOR_BELOW} rows), never (the program "
+          "before PR 37), always.\n")
+    for title, table in (("shortlist_topk on f32[B, blocks * 128]", whole),
+                         ("_int8_topk at the cell's shapes", program)):
+        print(f"| B | blocks | {title}: tree | its longest operation | "
+              "never | its longest operation | always | its longest "
+              "operation | never - tree | TopK was handed (tree) |")
+        print("| --- | --- | --- | --- | --- | --- | --- | --- | --- | --- |")
+        for r in table:
+            print(f"| {r['B']} | {r['blocks']} | " + " | ".join(
+                f"{ms(r[rule])} | {op(r[rule])}"
+                for rule in ("tree", "never", "always"))
+                + f" | {r['never'][0] - r['tree'][0]:.4f} | "
+                f"{r['operands']} |")
+        print()
+    print(f"\n| B | lax.top_k | shortlist_topk | at {padded} columns: "
+          "lax.top_k | shortlist_topk | stages, blocks, L | equal |")
     print("| --- | --- | --- | --- | --- | --- | --- |")
-    for row in rows:
+    for row in single_rows:
         a, p = row[str(COLUMNS)], row[str(padded)]
-        print(f"| {row['B']} | {a['top_k_ms']:.3f} | "
-              f"{a['shortlist_topk_ms']:.3f} | {p['top_k_ms']:.3f} | "
-              f"{p['shortlist_topk_ms']:.3f} | {a['stages']}, {a['blocks']}, "
+        print(f"| {row['B']} | {ms(a['top_k'])} | "
+              f"{ms(a['shortlist_topk'])} | {ms(p['top_k'])} | "
+              f"{ms(p['shortlist_topk'])} | {a['stages']}, {a['blocks']}, "
               f"{a['block_len']} | {a['equal'] and p['equal']} |")
-    return 0 if all(r[str(c)]["equal"] for r in rows
-                    for c in (COLUMNS, padded)) else 1
+    ok = (all(r["equal"] for r in stages)
+          and all(r[str(c)]["equal"] for r in single_rows
+                  for c in (COLUMNS, padded)))
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

@@ -14,6 +14,7 @@ described chip cannot be read back without one).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -238,3 +239,107 @@ def test_the_catalog_writes_are_in_place_at_the_live_cells_size(one_chip):
         assert not [ln for ln in text.splitlines()
                     if (" copy(" in ln or " copy-start(" in ln)
                     and any(t in ln for t in tables)], fn
+
+
+# -- stage two of the shortlist: the layout its TopK is handed (PR 37) -------
+
+MESH_ITEMS_PER_SHARD, MESH_USERS_PER_SHARD = 3_012_096, 3_460_224
+BUCKETS = (8, 32, 128)
+
+
+def _stage_two_topk(text):
+    """``[(result layout, scoped bytes or None)]`` of the instructions
+    that run ``serve.shortlist.blocks``' ``top_k`` (the ``TopK`` custom
+    call, or the ``kCustom`` fusion around it): ``TopK`` keeps its
+    operand's layout, so ``{1,0`` says the block maxima arrived with the
+    blocks along the lanes, ``{0,1`` with the batch's rows there (8 of
+    128 filled at bucket 8, 12.2 MB of scoped memory instead of 1.0)."""
+    found = []
+    for ln in text.splitlines():
+        if (re.search(r'op_name="[^"]*serve\.shortlist\.blocks/top_k"', ln)
+                and ('custom_call_target="TopK"' in ln
+                     or "kind=kCustom" in ln)):
+            layout = re.search(r"= \(f32\[[\d,]+\](\{[\d,]+)", ln).group(1)
+            scoped = re.search(r'"size":"(\d+)"', ln)
+            found.append((layout, int(scoped.group(1)) if scoped else None))
+    return found
+
+
+def _assert_stage_two_as_planned(text, columns, bucket):
+    """Where the plan asks for row-major the compiler obeys and ``TopK``
+    asks for about a megabyte; where it leaves the layout to the compiler
+    (128 rows fill the lanes) the compiler still picks rows-along-lanes,
+    which is what makes leaving it the cheaper choice there (0.31 against
+    0.68 ms on the v5e, PERF.md section 6, PR 37)."""
+    from tpu_als.ops.topk import shortlist_plan
+
+    plan = shortlist_plan(columns, 64, rows=bucket)
+    assert plan.blocks_layout == ("row_major" if bucket < 128
+                                  else "compiler")
+    found = _stage_two_topk(text)
+    assert found, "no instruction carries serve.shortlist.blocks/top_k"
+    if plan.blocks_layout == "compiler":
+        assert all(layout == "{0,1" for layout, _ in found), found
+        return
+    assert all(layout == "{1,0" for layout, _ in found), found
+    scoped = [s for _, s in found if s is not None]
+    assert scoped and max(scoped) < 2 << 20, found
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_stage_two_topk_layout_in_the_steady_cells_program(
+        one_chip, bucket):
+    from tpu_als.serving.engine import _serve_int8_packed
+
+    _, cols, base, _ = _live_catalog_shapes()
+    Vq, sv, _, valid = base
+    c = _compiled(
+        one_chip, _serve_int8_packed, ((LIVE_USERS, LIVE_RANK), jnp.float32),
+        Vq, sv, ((LIVE_ITEMS, LIVE_RANK), jnp.float32), valid,
+        ((bucket, LIVE_RANK + 2), jnp.int32), k=10, shortlist_k=64)
+    _assert_stage_two_as_planned(c.as_text(), cols, bucket)
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_stage_two_topk_layout_with_a_segment(one_chip, bucket):
+    from tpu_als.core.ratings import row_capacity
+    from tpu_als.serving.engine import _serve_int8_delta_packed
+
+    _, cols, base, seg = _live_catalog_shapes()
+    c = _compiled(
+        one_chip, _serve_int8_delta_packed,
+        ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32),
+        *base, *seg, ((), jnp.int32), ((bucket, LIVE_RANK + 2), jnp.int32),
+        k=10, shortlist_k=64)
+    _assert_stage_two_as_planned(c.as_text(), cols + LIVE_SLOTS, bucket)
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_stage_two_topk_layout_on_every_shard_of_the_mesh_cell(
+        topo, bucket):
+    """``_shard_score`` inside the mesh engine's one program a bucket, at
+    the mesh cell's 3,012,096 catalog rows a shard, for the four
+    described chips."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from tpu_als.parallel.mesh import AXIS, make_mesh
+    from tpu_als.serving.engine import _build_mesh_serve
+
+    mesh = make_mesh(devices=list(topo.devices))
+    shards, ni, r = len(topo.devices), MESH_ITEMS_PER_SHARD, LIVE_RANK
+    rows, whole = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
+
+    def shape(s, d, sharding):
+        return jax.ShapeDtypeStruct(s, d, sharding=sharding)
+
+    fn = _build_mesh_serve(mesh, 10, 10, 64, ni, False)
+    c = fn.lower(
+        shape((shards * MESH_USERS_PER_SHARD, r), jnp.float32, rows),
+        shape((bucket, r + 2), jnp.int32, whole),
+        shape((shards * ni, r), jnp.int8, rows),
+        shape((shards * ni,), jnp.float32, rows),
+        shape((shards * ni, r), jnp.float32, rows),
+        shape((shards * ni,), jnp.bool_, rows),
+        shape((), jnp.int32, whole)).compile()
+    _assert_stage_two_as_planned(c.as_text(), ni, bucket)

@@ -14,6 +14,7 @@ from tpu_als import obs
 from tpu_als.core.ratings import row_capacity
 from tpu_als.ops.topk import (
     NEG_INF,
+    ROW_MAJOR_BELOW,
     ShortlistPlan,
     shortlist_columns,
     shortlist_plan,
@@ -80,6 +81,80 @@ def test_shortlist_topk_is_lax_top_k(kind, n, N, k, stages):
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
 
 
+def _block_scores(rng, kind, n, N, k, L):
+    """Scores built block by block, for stage two: ``tied_blocks`` — a
+    few blocks with a maximum of their own, then the k-th largest block
+    maximum shared by every second block, the winners' position in their
+    block random; ``few_blocks`` — fewer than ``k`` blocks hold a valid
+    score at all, yet more than ``k`` scores (the rest is the index's
+    sentinel)."""
+    blocks = -(-N // L)
+    if kind == "tied_blocks":
+        x = rng.integers(-3, 1, (n, N)).astype(np.float32)
+        for row in x:
+            for b in rng.choice(blocks, size=k // 4, replace=False):
+                row[b * L + rng.integers(0, min(L, N - b * L))] = \
+                    7.0 + rng.random()
+            for b in range(int(rng.integers(0, 2)), blocks, 2):
+                row[b * L + rng.integers(0, min(L, N - b * L))] = 5.0
+        return x
+    assert kind == "few_blocks"
+    x = np.full((n, N), NEG_INF, np.float32)
+    for row in x:
+        for b in rng.choice(blocks, size=k // 2, replace=False):
+            cols = b * L + rng.choice(min(L, N - b * L), size=3,
+                                      replace=False)
+            row[cols] = rng.integers(-2, 3, 3)
+    return x
+
+
+@pytest.mark.parametrize("kind", ["tied_blocks", "few_blocks"])
+@pytest.mark.parametrize("N", [40_064, 40_000])     # whole and ragged
+@pytest.mark.parametrize("n", [8, 32, 128])
+def test_stage_two_is_lax_top_k_at_every_bucket(kind, n, N):
+    """Both sides of the rule that decides stage two's layout (row-major
+    under ``ROW_MAJOR_BELOW`` rows, the compiler's above), on the cases
+    stage two decides: ties among the block maxima at the k-th place, and
+    fewer than ``k`` blocks with anything valid in them."""
+    k = SHORTLIST
+    plan = shortlist_plan(N, k, rows=n)
+    assert plan.stages == 2
+    assert plan.blocks_layout == ("row_major" if n < ROW_MAJOR_BELOW
+                                  else "compiler")
+    rng = np.random.default_rng(n + N)
+    x = jnp.asarray(_block_scores(rng, kind, n, N, k, plan.block_len))
+    want_s, want_i = jax.lax.top_k(x, k)
+    got_s, got_i = jax.jit(shortlist_topk, static_argnums=1)(x, k)
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+
+
+def test_rows_decide_the_layout_and_nothing_else():
+    two = shortlist_plan(1_506_048, 64)
+    assert ROW_MAJOR_BELOW == 56
+    for rows in (1, 8, 32, 55, 56, 128, 1024):
+        plan = shortlist_plan(1_506_048, 64, rows=rows)
+        assert plan[:4] == two[:4]
+        assert plan.blocks_layout == ("row_major" if rows < 56
+                                      else "compiler")
+        # one stage is the compiler's single top_k whatever the rows
+        assert shortlist_plan(2_000, 64, rows=rows) == ShortlistPlan(
+            1, 1, 2_000, 2_000, "compiler")
+    assert two.blocks_layout == "compiler"      # no rows: columns' plan
+
+
+def test_the_constraint_is_in_the_jaxpr_only_where_the_plan_says():
+    def primitives(n):
+        jaxpr = jax.make_jaxpr(lambda s: shortlist_topk(s, SHORTLIST))(
+            jax.ShapeDtypeStruct((n, BIG_ITEMS), jnp.float32))
+        return [str(e.primitive) for e in jaxpr.eqns]
+
+    assert primitives(8).count("layout_constraint") == 1
+    assert primitives(32).count("layout_constraint") == 1
+    assert primitives(128).count("layout_constraint") == 0
+    assert primitives(8).count("top_k") == primitives(128).count("top_k") == 2
+
+
 @pytest.mark.parametrize("N,k", [(1_505_938, 64), (40_000, 16),
                                  (10_000_000, 64), (300_000, 8)])
 def test_plan_is_whole_blocks_near_the_square_root(N, k):
@@ -99,6 +174,15 @@ def test_the_benchmark_cells_plan():
     assert shortlist_plan(1_505_938, 64) == ShortlistPlan(
         stages=2, blocks=11_766, block_len=128, columns=1_505_938)
     assert shortlist_columns(1_505_938, 64) == 1_506_048
+    # the cells' programs, bucket by bucket: steady and fold-in; with the
+    # live segment's 512 slots; one shard of the mesh cell
+    for columns, blocks, block_len in ((1_506_048, 11_766, 128),
+                                       (1_530_368, 11_956, 128),
+                                       (3_012_096, 11_766, 256)):
+        for bucket, layout in ((8, "row_major"), (32, "row_major"),
+                               (128, "compiler")):
+            assert shortlist_plan(columns, 64, rows=bucket) == ShortlistPlan(
+                2, blocks, block_len, columns, layout)
 
 
 @pytest.mark.parametrize("N,k", [(1, 1), (63, 64), (2_000, 64),
@@ -244,7 +328,11 @@ def test_warmup_reports_the_plan_the_program_was_traced_with(_fresh, big):
     assert want == eng.published_index.shortlist_plan()
     for e in events:
         assert (e["stages"], e["blocks"], e["block_len"], e["columns"]) \
-            == (2, 313, 128, 40_064) == tuple(want)
+            == (2, 313, 128, 40_064) == tuple(want)[:4]
+        # both buckets lie under ROW_MAJOR_BELOW rows: the event says what
+        # stage two asked of the compiler for THIS program
+        assert e["blocks_layout"] == "row_major" == \
+            eng.published_index.shortlist_plan(rows=e["bucket"]).blocks_layout
     eng.warmup_live()
     live = _shortlist_events(_fresh)[2:]
     idx = eng.published_index
@@ -257,8 +345,9 @@ def test_warmup_reports_the_plan_the_program_was_traced_with(_fresh, big):
         (8, 512, columns), (32, 512, columns)]
     assert all(e["stages"] == 2 and e["columns"] % e["block_len"] == 0
                for e in live)
-    assert all(tuple(shortlist_plan(e["columns"], SHORTLIST))
-               == (e["stages"], e["blocks"], e["block_len"], e["columns"])
+    assert all(tuple(shortlist_plan(e["columns"], SHORTLIST, e["bucket"]))
+               == (e["stages"], e["blocks"], e["block_len"], e["columns"],
+                   e["blocks_layout"])
                for e in live)
 
 
@@ -270,4 +359,5 @@ def test_warmup_reports_one_stage_on_a_small_catalog(_fresh):
     eng.warmup()
     (e,) = _shortlist_events(_fresh)
     assert (e["bucket"], e["path"], e["stages"], e["blocks"],
-            e["block_len"], e["columns"]) == (8, "int8", 1, 1, 300, 300)
+            e["block_len"], e["columns"], e["blocks_layout"]) == (
+                8, "int8", 1, 1, 300, 300, "compiler")

@@ -516,13 +516,19 @@ EVENTS = {
         "backend is sharded (the int8 index sharded over n_shards "
         "devices); an engine without a mesh emits none"),
     "serving_shortlist": (
-        ("bucket", "path", "stages", "blocks", "block_len", "columns"),
+        ("bucket", "path", "stages", "blocks", "block_len", "columns",
+         "blocks_layout"),
         "one per int8 scoring program ServingEngine.warmup / warmup_live "
         "compiles (per bucket and path; warmup_live adds delta_rows, the "
         "segment's slots): how "
         "its shortlist selects, from ops.topk.shortlist_plan — stages 1 is "
         "one lax.top_k over all columns, 2 is block maxima then top_k "
-        "over the winning blocks of block_len columns"),
+        "over the winning blocks of block_len columns; blocks_layout is "
+        "what stage two asks of the compiler for its operand: row_major "
+        "(the block maxima constrained to blocks-along-lanes, for a "
+        "bucket under ops.topk.ROW_MAJOR_BELOW rows: on the TPU they "
+        "leave the score fusion with the batch's rows along the 128 "
+        "lanes, 8 of 128 filled at bucket 8) or compiler (no constraint)"),
     "serving_mesh_plan": (
         ("bucket", "shards", "items_per_shard", "users_per_shard", "k_loc",
          "exchange_bytes"),

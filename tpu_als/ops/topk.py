@@ -16,6 +16,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 # plain python float: creating a jnp scalar here would initialize the JAX
 # backend as an import side effect
@@ -37,23 +38,41 @@ def topk_validity(scores):
     return scores > NEG_INF
 
 
+# Stage two's ``TopK`` on the v5e (11,766 and 11,956 block maxima a row,
+# k 64; PERF.md section 6, PR 37): handed its operand row-major it costs
+# 5.4 us a row of the batch; handed it with the rows along the 128 lanes,
+# which is how the block maxima leave the score fusion and what the
+# compiler picks by itself, 0.30 ms whatever their number up to 128 (at 8
+# rows 8 of 128 lanes hold data).  Below this many rows row-major is
+# cheaper: 0.044 against 0.303 ms at 8, 0.173 against 0.299 at 32, 0.68
+# against 0.31 at 128.
+ROW_MAJOR_BELOW = 56
+
+
 class ShortlistPlan(NamedTuple):
     """How :func:`shortlist_topk` selects ``k`` of ``columns`` scores a
     row: in one ``lax.top_k`` (``stages`` 1, one block of all columns)
-    or over ``blocks`` contiguous blocks of ``block_len`` columns."""
+    or over ``blocks`` contiguous blocks of ``block_len`` columns, and
+    what stage two asks of the compiler for its operand
+    (``blocks_layout``: ``"row_major"`` = the block maxima constrained
+    to blocks-along-lanes before ``top_k``, ``"compiler"`` = no
+    constraint, the layout is the compiler's)."""
 
     stages: int
     blocks: int
     block_len: int
     columns: int
+    blocks_layout: str = "compiler"
 
 
-def shortlist_plan(columns, k):
-    """The selection :func:`shortlist_topk` compiles for a ``[n,
-    columns]`` score matrix and ``k`` — a function of those two static
+def shortlist_plan(columns, k, rows=None):
+    """The selection :func:`shortlist_topk` compiles for a ``[rows,
+    columns]`` score matrix and ``k`` — a function of those static
     numbers and nothing else (no argument, environment variable, planner
     entry or probe), so an event that reports it cannot disagree with
-    the program.
+    the program.  ``rows`` decides ``blocks_layout`` alone (row-major
+    below :data:`ROW_MAJOR_BELOW` rows); a plan asked for without it is
+    the plan of the columns, its layout left at ``"compiler"``.
 
     Two stages read ``columns`` scores once for the block maxima and
     then run ``TopK`` over ``blocks + k * block_len`` of them, which is
@@ -68,7 +87,10 @@ def shortlist_plan(columns, k):
         block_len = 128 * max(1, int(math.sqrt(columns / k) / 128 + 0.5))
         blocks = -(-columns // block_len)
         if blocks >= k and 4 * (k * block_len + blocks) <= columns:
-            return ShortlistPlan(2, blocks, block_len, columns)
+            return ShortlistPlan(
+                2, blocks, block_len, columns,
+                "row_major" if rows is not None and rows < ROW_MAJOR_BELOW
+                else "compiler")
     return ShortlistPlan(1, 1, columns, columns)
 
 
@@ -110,9 +132,17 @@ def shortlist_topk(scores, k):
     bytes, and both the block maximum and the gather read it in place
     (the flat ``[n, blocks, L]`` view cost two relayout copies of the
     whole matrix at ``n`` = 128: compiled HLO for the v5e, PR 26).
+
+    The block maxima leave that reduce (on the chip: the score fusion)
+    with the blocks along the sublanes and the batch's ``n`` rows along
+    the 128 lanes, and ``TopK`` takes them as they come.  For a small
+    batch (``blocks_layout`` of the plan) stage two therefore asks for
+    them row-major first: a relayout of ``n * blocks`` floats, a few
+    microseconds, for a ``TopK`` whose lanes are full.  A layout changes
+    no value.
     """
     n, N = scores.shape
-    plan = shortlist_plan(N, k)
+    plan = shortlist_plan(N, k, rows=n)
     if plan.stages == 1:
         return jax.lax.top_k(scores, k)
     L, blocks = plan.block_len, plan.blocks
@@ -124,6 +154,9 @@ def shortlist_topk(scores, k):
     with jax.named_scope("serve.shortlist.blockmax"):
         block_max = jnp.max(tiled, axis=-1).reshape(n, blocks)
     with jax.named_scope("serve.shortlist.blocks"):
+        if plan.blocks_layout == "row_major":
+            block_max = with_layout_constraint(
+                block_max, Layout(major_to_minor=(0, 1)))
         _, block_ids = jax.lax.top_k(block_max, k)
         block_ids = jnp.sort(block_ids, axis=1)
     with jax.named_scope("serve.shortlist.gather"):

@@ -1251,11 +1251,12 @@ class ServingEngine:
     def _emit_shortlist(bucket, index, **extra):
         """One ``serving_shortlist`` event for a scoring program that
         was just compiled: the selection its shortlist runs, from the
-        same ``ops.topk.shortlist_plan`` the program was traced with."""
+        same ``ops.topk.shortlist_plan`` the program was traced with
+        (``bucket`` rows: they decide ``blocks_layout``)."""
         obs.emit("serving_shortlist", bucket=bucket,
                  path=("int8_sharded" if isinstance(index, ShardedInt8Index)
                        else "int8"),
-                 **index.shortlist_plan()._asdict(), **extra)
+                 **index.shortlist_plan(rows=bucket)._asdict(), **extra)
 
     def _run_pinned(self, key, fn, args, statics):
         """Dispatch through the AOT-pinned executable when one is live

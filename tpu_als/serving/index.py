@@ -286,12 +286,12 @@ class Int8CandidateIndex:
         self.seq = seq
         self._clear_delta()
 
-    def shortlist_plan(self):
+    def shortlist_plan(self, rows=None):
         """The selection :meth:`topk` compiles for this index as it
-        stands (delta segment included): the
-        ``ops.topk.shortlist_plan`` of its score matrix."""
+        stands (delta segment included) and a batch of ``rows``
+        queries: the ``ops.topk.shortlist_plan`` of its score matrix."""
         return shortlist_plan(int(self.Vq.shape[0]) + self.delta_slots,
-                              self.shortlist_k)
+                              self.shortlist_k, rows)
 
     # -- delta segment (incremental re-quantization) -------------------
 
@@ -804,9 +804,9 @@ class ShardedInt8Index(Int8CandidateIndex):
         return jax.device_put(arrays, jax.sharding.NamedSharding(
             self.mesh, jax.sharding.PartitionSpec()))
 
-    def shortlist_plan(self):
+    def shortlist_plan(self, rows=None):
         cols = self.ni_loc + self.delta_slots     # what one shard scores
-        return shortlist_plan(cols, min(self.shortlist_k, cols))
+        return shortlist_plan(cols, min(self.shortlist_k, cols), rows)
 
     def _copy_extra(self, new):
         new.mesh = self.mesh

@@ -343,3 +343,129 @@ def test_stage_two_topk_layout_on_every_shard_of_the_mesh_cell(
         shape((shards * ni,), jnp.bool_, rows),
         shape((), jnp.int32, whole)).compile()
     _assert_stage_two_as_planned(c.as_text(), ni, bucket)
+
+
+# -- per-request exclusion (PR 39) --------------------------------------------
+
+UNSEEN_RATINGS = 17_860_625
+HISTORY_PADS = (64, 512, 4096)
+# sha256 of the lowered (StableHLO) text of the three programs the cells
+# without histories run, at their cells' shapes, taken on the parent commit
+# (01a67e1): an engine that published no histories and sees no ``exclude``
+# must lower to what it lowered to before the engine knew of either
+PARENT_LOWERED = {
+    ("steady", 8): "35ea3052c100ffc5", ("steady", 32): "abfcefa10f9a23f9",
+    ("steady", 128): "f61c331f33283e9b",
+    ("delta", 8): "2919b222ef9e0eea", ("delta", 32): "f64fc009c44cba65",
+    ("delta", 128): "6b8540347d23241f",
+    ("mesh", 8): "2b4d0ec9ed8e15d5", ("mesh", 32): "e839ec6eb37f9beb",
+    ("mesh", 128): "6a08a28f0749d53b",
+}
+
+
+def _lower(sharding, jitted, *shapes, **statics):
+    return jitted.lower(*[jax.ShapeDtypeStruct(s, d, sharding=sharding)
+                          for s, d in shapes], **statics)
+
+
+def _lowered(name, bucket, topo, one_chip):
+    from tpu_als.core.ratings import row_capacity
+    from tpu_als.serving.engine import (
+        _build_mesh_serve,
+        _serve_int8_delta_packed,
+        _serve_int8_packed,
+    )
+
+    from tpu_als.ops.topk import shortlist_columns
+
+    cap, _, base, seg = _live_catalog_shapes()
+    packed = ((bucket, LIVE_RANK + 2), jnp.int32)
+    if name == "steady":
+        cols = shortlist_columns(LIVE_ITEMS, 64)
+        return _lower(one_chip, _serve_int8_packed,
+                      ((LIVE_USERS, LIVE_RANK), jnp.float32),
+                      ((cols, LIVE_RANK), jnp.int8), ((cols,), jnp.float32),
+                      ((LIVE_ITEMS, LIVE_RANK), jnp.float32),
+                      ((cols,), jnp.bool_), packed, k=10, shortlist_k=64)
+    if name == "delta":
+        return _lower(one_chip, _serve_int8_delta_packed,
+                      ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32),
+                      *base, *seg, ((), jnp.int32), packed,
+                      k=10, shortlist_k=64)
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from tpu_als.parallel.mesh import AXIS, make_mesh
+
+    mesh = make_mesh(devices=list(topo.devices))
+    shards, ni, r = len(topo.devices), MESH_ITEMS_PER_SHARD, LIVE_RANK
+    rows, whole = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
+
+    def shape(s, d, sharding):
+        return jax.ShapeDtypeStruct(s, d, sharding=sharding)
+
+    return _build_mesh_serve(mesh, 10, 10, 64, ni, False).lower(
+        shape((shards * MESH_USERS_PER_SHARD, r), jnp.float32, rows),
+        shape((bucket, r + 2), jnp.int32, whole),
+        shape((shards * ni, r), jnp.int8, rows),
+        shape((shards * ni,), jnp.float32, rows),
+        shape((shards * ni, r), jnp.float32, rows),
+        shape((shards * ni,), jnp.bool_, rows),
+        shape((), jnp.int32, whole))
+
+
+@pytest.mark.parametrize("name,bucket", sorted(PARENT_LOWERED))
+def test_programs_without_histories_lower_to_the_parents_text(
+        topo, one_chip, name, bucket):
+    import hashlib
+
+    text = _lowered(name, bucket, topo, one_chip).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_LOWERED[name, bucket]
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_the_program_that_excludes_at_the_unseen_cells_size(one_chip, bucket):
+    """``_serve_int8_seen_packed`` at every history pad of the cell's
+    ladder: it compiles; beyond the program without histories it holds
+    the one-byte mask and no four-byte matrix of the scores' size (the
+    bit-packed words written out broadcast, or a second ``f32[B,
+    columns]``); the only sort it adds is its own, unstable one (the
+    compiler puts a stable sort, 9-15 s to compile, before a scatter it is
+    not told is sorted), and no mask is copied row by row (the ``while``
+    a scatter into ``bool[B, columns]`` ends in)."""
+    from tpu_als.ops.topk import shortlist_columns
+    from tpu_als.serving.engine import (
+        MAX_EXCLUDE,
+        _serve_int8_packed,
+        _serve_int8_seen_packed,
+    )
+
+    cols = shortlist_columns(LIVE_ITEMS, 64)
+    tables = [((LIVE_USERS, LIVE_RANK), jnp.float32),
+              ((cols, LIVE_RANK), jnp.int8), ((cols,), jnp.float32),
+              ((LIVE_ITEMS, LIVE_RANK), jnp.float32), ((cols,), jnp.bool_)]
+    plain = _compiled(one_chip, _serve_int8_packed, *tables,
+                      ((bucket, LIVE_RANK + 2), jnp.int32),
+                      k=10, shortlist_k=64)
+    base = plain.memory_analysis().temp_size_in_bytes
+    sorts = plain.as_text().count(" sort(")
+    for pad in HISTORY_PADS:
+        c = _compiled(
+            one_chip, _serve_int8_seen_packed, *tables,
+            ((LIVE_USERS + 1,), jnp.int32),
+            ((UNSEEN_RATINGS + HISTORY_PADS[-1],), jnp.int32),
+            ((bucket, LIVE_RANK + 2 + MAX_EXCLUDE), jnp.int32),
+            k=10, shortlist_k=64, pad=pad)
+        text = c.as_text()
+        entry = text[text.index("\nENTRY "):]
+        extra = c.memory_analysis().temp_size_in_bytes - base
+        assert extra < 1.05 * bucket * cols + (8 << 20), (pad, extra)
+        assert text.count(" sort(") == sorts + 1, pad
+        # the one loop is the histories' slices, a row each: none walks
+        # a mask
+        assert not [ln for ln in entry.splitlines()
+                    if " while(" in ln and "pred[" in ln], pad
+        assert not re.search(r"= u32\[368,32,\d+,128\]", entry), pad
+        assert 'op_name="jit(_serve_int8_seen_packed)/jit(_int8_topk)/' \
+            "serve.exclude/" in text

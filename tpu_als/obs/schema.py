@@ -143,6 +143,20 @@ METRICS = {
         "place) | compact (the same, and the segment folded into the base "
         "arrays, in place, before or after) | replaced (uploaded whole: "
         "no live index to take the rows)"),
+    "serving.excluded_ids": (
+        "histogram", "ids",
+        "catalog ids taken out of one request's ranking by a batch that "
+        "excludes (ops.topk.excluded_mask's rule), one sample a request "
+        "and source: source=history (the published history of a by-id "
+        "request's user, publish(user_seen=...); 0 for a request by "
+        "vector; written only by an engine that published histories) | "
+        "request (the request's own list, submit(exclude=...))"),
+    "serving.exclusion_upload_bytes": (
+        "counter", "bytes",
+        "what the requests' own lists of excluded ids add to a batch's "
+        "one host->device upload, one add a batch that excludes: bucket "
+        "x MAX_EXCLUDE int32 columns of the staging layout (the users' "
+        "histories never cross again after their publish)"),
     "serving.mesh_exchange_bytes": (
         "counter", "bytes",
         "a mesh engine only: what one device moves between the chips "
@@ -282,6 +296,8 @@ LABELS = {
     "serving.publishes": ("tenant",),
     "serving.user_table_writes": ("how", "tenant"),
     "serving.mesh_exchange_bytes": ("tenant",),
+    "serving.excluded_ids": ("source", "tenant"),
+    "serving.exclusion_upload_bytes": ("tenant",),
     "serving.publish_seconds": ("mode", "tenant"),
     "live.freshness_seconds": ("tenant",),
     "live.batch_rows": ("tenant",),
@@ -412,6 +428,15 @@ SERVE_MESH_SCOPES = (
     "serve.mesh.merge",       # two all-gathers of the local top-k lists,
     #                           one top_k, the packed response
 )
+# per-request exclusion inside a scoring program: one ``jax.named_scope``
+# around the rule's operations (serving/engine.py ``_select_seen``: the
+# batch's histories gathered from the published CSR; ops/topk.py
+# ``excluded_mask``: the sort, the scatter-add into the bit-packed mask
+# and its unpacking), in
+# every such operation's ``op_name``; and on the host the stat
+# ``excluded`` (the batch's history pad) on ``serve.batch.stage`` of a
+# batch that excludes
+SERVE_EXCLUDE_SCOPE = "serve.exclude"
 LIVE_SPAN_KEYS = ("queue_wait", "quarantine", "foldin", "publish")
 # the updater thread's batch cycle as profiler spans, the write path's
 # counterpart of SERVE_BATCH_SPAN_KEYS (``TraceAnnotation`` in
@@ -529,6 +554,19 @@ EVENTS = {
         "bucket under ops.topk.ROW_MAJOR_BELOW rows: on the TPU they "
         "leave the score fusion with the batch's rows along the 128 "
         "lanes, 8 of 128 filled at bucket 8) or compiler (no constraint)"),
+    "serving_exclusion": (
+        ("bucket", "path", "history_pad", "request_pad", "rows", "ids",
+         "columns", "block", "words", "mask_bytes", "keys"),
+        "one per scoring program that excludes, as ServingEngine.warmup "
+        "compiles and pins it for a generation that holds users' "
+        "histories (per bucket: path int8 at every history pad of the "
+        "ladder 64, 512, ... up to the longest history, path exact at the "
+        "longest alone): the ids a row may exclude (ids = history_pad + "
+        "request_pad, the request's own MAX_EXCLUDE) and what the mask "
+        "costs, from ops.topk.exclusion_plan, the helper the program's "
+        "mask was traced with — the uint32 words of the bit-packed mask "
+        "(32 blocks of block columns to a word), their mask_bytes, and the "
+        "keys it sorts before its one scatter-add (rows x ids)"),
     "serving_mesh_plan": (
         ("bucket", "shards", "items_per_shard", "users_per_shard", "k_loc",
          "exchange_bytes"),

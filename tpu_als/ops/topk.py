@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from tpu_als.obs.schema import SERVE_EXCLUDE_SCOPE
+
 # plain python float: creating a jnp scalar here would initialize the JAX
 # backend as an import side effect
 NEG_INF = -3.4e38
@@ -36,6 +38,119 @@ def topk_validity(scores):
     sentinel constant.
     """
     return scores > NEG_INF
+
+
+# the id that pads a row's list of excluded ids: outside every catalog, so
+# the scatter that reads it drops it (``serving.index.SLOT_FREE``'s value)
+NOT_AN_ID = 2 ** 31 - 1
+
+
+class ExclusionPlan(NamedTuple):
+    """What :func:`excluded_mask` builds for ``rows`` rows of ``ids``
+    excluded ids in all (its lists' widths added up) over ``columns``
+    scores in blocks of ``block``: the words of its bit-packed mask (32
+    blocks a word), the bytes of those words, and the keys it sorts."""
+
+    rows: int
+    ids: int
+    columns: int
+    block: int
+    words: int
+    mask_bytes: int
+    keys: int
+
+
+def exclusion_plan(rows, ids, columns, block):
+    """The mask of ``rows`` rows of ``ids`` ids over ``columns`` scores —
+    a function of those static numbers alone, so an event that reports
+    it (``serving_exclusion``) cannot disagree with the program."""
+    rows, ids, columns, block = int(rows), int(ids), int(columns), int(block)
+    if columns % block:
+        raise ValueError(f"{columns} columns are not whole blocks of "
+                         f"{block}")
+    words = -(-(columns // block) // 32) * rows * block
+    if 32 * words >= 2 ** 31:
+        raise ValueError(f"{rows} rows of {columns} columns: more (row, "
+                         "column) pairs than one mask's int32 keys hold")
+    return ExclusionPlan(rows, ids, columns, block, words, 4 * words,
+                         rows * ids)
+
+
+def excluded_mask(seen, columns, block):
+    """``bool[columns // block, n, block]``: True at ``(id // block, row,
+    id % block)`` for every id of the lists in ``seen`` — a sequence of
+    ``int32[n, h]`` arrays, each row some of that row's excluded catalog
+    ids in any order, padded with :data:`NOT_AN_ID` (an id outside ``[0,
+    columns)`` is dropped; an id may stand twice).  Block-major, because
+    that is how the scores lie on the TPU (8 rows by 128 lanes a tile,
+    tile after tile along the columns): transposed to ``[n, columns //
+    block, block]`` it is the row-major mask's own bytes, and a chunked
+    scan takes its chunks as they come.
+
+    THE RULE of per-request exclusion, which every scoring path applies
+    through this one mask (``serving.index._int8_topk`` before its
+    shortlist and again before the last ``top_k`` of its rescore,
+    :func:`chunked_topk_scores` chunk by chunk): *the answer is what the
+    same program would return if the excluded (row, column) pairs had
+    ``valid = False`` for that row alone* — same sentinels (``NEG_INF``,
+    :func:`topk_validity`), same tie rule, and where fewer than ``k``
+    columns are left the surplus slots carry the sentinel.  Exact for any
+    number of ids a row: nothing is "filtered after the shortlist".
+
+    How (PERF.md section 6, PR 39, has the forms that were timed): a
+    scatter into a ``bool[n, columns]`` matrix walks the whole matrix at
+    60 GB/s on the v5e and is then copied row by row into the tiled
+    layout (0.8 ms at 8 rows, 11 ms at 128).  So the mask is built
+    bit-packed, 32 BLOCKS to a word — ``uint32[ceil(blocks / 32), n,
+    block]``, 1.5 MB at 8 rows of 1.5 M columns — by one scatter-add of
+    ``1 << (block % 32)``, and unpacked by a shift along the major
+    dimension, which costs the tiled layout nothing.  The scatter is told
+    its indices are sorted, and they are: the keys ``(word, bit)`` are
+    sorted here first, UNSTABLE (the TPU compiler's own stable sort, which
+    it puts before any scatter it is not told that of, compiles for 9-15
+    s at 33,000 keys; the unstable one for 1.6 s), and a key that stands
+    twice adds its bit once.  No ``f32[n, columns]`` is made here.
+    """
+    plan = exclusion_plan(seen[0].shape[0], sum(s.shape[1] for s in seen),
+                          columns, block)
+    n, blocks = plan.rows, columns // block
+    with jax.named_scope(SERVE_EXCLUDE_SCOPE):
+        ids = jnp.concatenate(seen, axis=1) if len(seen) > 1 else seen[0]
+        row = jnp.arange(n, dtype=jnp.int32)[:, None]
+        blk, lane = ids // block, ids % block
+        key = (((blk >> 5) * n + row) * block + lane) * 32 + (blk & 31)
+        key = jnp.where((ids >= 0) & (ids < columns), key, 32 * plan.words)
+        key = jax.lax.sort(key.reshape(-1), is_stable=False)
+
+        def scatter(key):
+            once = jnp.concatenate([jnp.ones((1,), jnp.bool_),
+                                    key[1:] != key[:-1]])
+            return jnp.zeros((plan.words,), jnp.uint32).at[key >> 5].add(
+                jnp.where(once,
+                          jnp.uint32(1) << (key & 31).astype(jnp.uint32),
+                          jnp.uint32(0)),
+                mode="drop", indices_are_sorted=True)
+
+        # the scatter pays by the key, padding included (8.7 ns each on
+        # the v5e), and most batches hold far fewer ids than their pad
+        # allows: the real keys sort first, so where they fit a quarter
+        # of the list that quarter is all that is scattered
+        few = plan.keys // 4
+        if few >= 1024:
+            # (the barrier: the compiler otherwise moves the broadcast
+            # below into both branches, and they return four bytes a pair)
+            words = jax.lax.optimization_barrier(jax.lax.cond(
+                jnp.sum(key < 32 * plan.words) <= few,
+                lambda key: scatter(key[:few]), scatter, key))
+        else:
+            words = scatter(key)
+        bits = (words.reshape(-1, 1, n, block)
+                >> jnp.arange(32, dtype=jnp.uint32)[None, :, None, None])
+        # unpacked HERE, one byte a pair: without the barrier the compiler
+        # moves the reshape below in front of the shift and then writes
+        # the broadcast words out, four bytes a pair
+        mask = jax.lax.optimization_barrier((bits & 1) != 0)
+        return mask.reshape(-1, n, block)[:blocks]
 
 
 # Stage two's ``TopK`` on the v5e (11,766 and 11,956 block maxima a row,
@@ -170,11 +285,14 @@ def shortlist_topk(scores, k):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "item_chunk"))
-def chunked_topk_scores(U, V, item_valid, k, item_chunk=8192):
+def chunked_topk_scores(U, V, item_valid, k, item_chunk=8192, seen=None):
     """Top-k items per user row of ``U``.
 
     U [n, r]; V [Ni, r]; item_valid [Ni] bool (False rows never recommended —
     padding rows and cold items).  Returns (scores [n, k], indices [n, k]).
+    ``seen`` (lists of ``int32[n, h]`` ids, as :func:`excluded_mask`
+    takes them): ids each row is not to be answered with, by that
+    function's rule.
 
     When a row has fewer than ``k`` valid items the remaining slots
     hold the ``NEG_INF`` sentinel score with MEANINGLESS indices (the
@@ -190,17 +308,24 @@ def chunked_topk_scores(U, V, item_valid, k, item_chunk=8192):
     Vc = Vp.reshape(nchunks, item_chunk, r)
     validc = validp.reshape(nchunks, item_chunk)
     base = jnp.arange(nchunks, dtype=jnp.int32) * item_chunk
+    xs = (Vc, validc, base)
+    if seen is not None:
+        # the rows' masks ride the scan chunk by chunk, beside ``valid``
+        xs += (excluded_mask(seen, nchunks * item_chunk, item_chunk),)
 
     init_s = jnp.full((n, k), NEG_INF, dtype=jnp.float32)
     init_i = jnp.zeros((n, k), dtype=jnp.int32)
 
     def step(carry, chunk):
         best_s, best_i = carry
-        Vt, valid, off = chunk
+        Vt, valid, off, *excluded = chunk
         scores = jnp.einsum(
             "nr,cr->nc", U, Vt, preferred_element_type=jnp.float32
         )
-        scores = jnp.where(valid[None, :], scores, NEG_INF)
+        ok = valid[None, :]
+        if excluded:
+            ok = ok & ~excluded[0]
+        scores = jnp.where(ok, scores, NEG_INF)
         ids = off + jnp.arange(Vt.shape[0], dtype=jnp.int32)
         cat_s = jnp.concatenate([best_s, scores], axis=1)
         cat_i = jnp.concatenate([best_i, jnp.broadcast_to(ids, (n, Vt.shape[0]))], axis=1)
@@ -208,7 +333,7 @@ def chunked_topk_scores(U, V, item_valid, k, item_chunk=8192):
         new_i = jnp.take_along_axis(cat_i, sel, axis=1)
         return (new_s, new_i), None
 
-    (best_s, best_i), _ = jax.lax.scan(step, (init_s, init_i), (Vc, validc, base))
+    (best_s, best_i), _ = jax.lax.scan(step, (init_s, init_i), xs)
     return best_s, best_i
 
 

@@ -94,15 +94,18 @@ class Ticket:
     ``seq`` is the publish sequence number of the generation that
     answered it, stamped where its batch read the live generation (None
     until then): every score and id of the answer is that generation's.
+    ``exclude`` is the request's own list of catalog ids it is not to be
+    answered with (an int32 array the engine validated, or None).
     """
 
     __slots__ = ("payload", "k", "deadline", "trace", "t_submit",
-                 "t_dequeue", "t_done", "t_admit", "seq", "_event",
-                 "_result", "_error")
+                 "t_dequeue", "t_done", "t_admit", "seq", "exclude",
+                 "_event", "_result", "_error")
 
-    def __init__(self, payload, k, deadline, trace=None):
+    def __init__(self, payload, k, deadline, trace=None, exclude=None):
         self.payload = payload
         self.k = k
+        self.exclude = exclude
         self.deadline = deadline        # absolute perf_counter time, or None
         self.trace = trace              # TraceContext of the last hop, or None
         self.t_submit = time.perf_counter()
@@ -180,7 +183,8 @@ class MicroBatcher:
         with self._cond:
             return len(self._q)
 
-    def submit(self, payload, k=None, deadline_s=None, trace=None):
+    def submit(self, payload, k=None, deadline_s=None, trace=None,
+               exclude=None):
         """Admit one request; returns its :class:`Ticket`.
 
         Raises :class:`Overloaded` (and counts ``serving.shed``) when
@@ -193,7 +197,7 @@ class MicroBatcher:
             deadline_s = self.default_deadline_s
         deadline = (time.perf_counter() + deadline_s
                     if deadline_s is not None else None)
-        t = Ticket(payload, k, deadline, trace=trace)
+        t = Ticket(payload, k, deadline, trace=trace, exclude=exclude)
         with self._cond:
             if self._closed:
                 raise RuntimeError("batcher is closed")

@@ -188,9 +188,18 @@ from tpu_als.core.ratings import (
     row_capacity,
 )
 from tpu_als.obs import tracing
-from tpu_als.obs.schema import SERVE_BATCH_SPAN_KEYS, SERVE_MESH_SCOPES
+from tpu_als.obs.schema import (
+    SERVE_BATCH_SPAN_KEYS,
+    SERVE_EXCLUDE_SCOPE,
+    SERVE_MESH_SCOPES,
+)
 from tpu_als.obs.trace import FlightRecorder
-from tpu_als.ops.topk import chunked_topk_scores, shortlist_plan
+from tpu_als.ops.topk import (
+    NOT_AN_ID,
+    chunked_topk_scores,
+    exclusion_plan,
+    shortlist_plan,
+)
 from tpu_als.resilience import faults
 from tpu_als.serving.batcher import (
     DEFAULT_BUCKETS,
@@ -207,6 +216,7 @@ from tpu_als.serving.index import (
     _next_pow2,
     _shard_merge,
     _shard_score,
+    mask_block,
     mesh_exchange_bytes,
     place_catalog,
     segment_write_bytes,
@@ -217,6 +227,44 @@ from tpu_als.serving.index import (
 # read back and one dispatched behind it.  The engine thread waits for
 # the older to complete before it dequeues a third
 MAX_IN_FLIGHT = 2
+
+
+# catalog ids a request may bring of its own (``submit(exclude=...)``):
+# the columns they ride in, after the ``rank + 2`` of the staging layout
+MAX_EXCLUDE = 64
+
+
+class _Seen:
+    """The users' histories of one generation: CSR over catalog ids on
+    the device (``indptr int32[n_users + 1]``, ``indices int32[nnz]``; a
+    row's ids ascending, none twice), and on the host what staging needs
+    of them: each user's count (``lengths``) and the ladder of history
+    pads the scoring programs are compiled for (``pads``: 64, 512, ...
+    up to the longest history) — a batch rides the least that holds its
+    longest."""
+
+    __slots__ = ("indptr", "indices", "lengths", "pads")
+
+    def __init__(self, indptr, indices, lengths, pads):
+        self.indptr, self.indices = indptr, indices
+        self.lengths, self.pads = lengths, pads
+
+    def pad_for(self, live):
+        """The history pad of a batch of tickets: its by-id requests'
+        longest history, up the ladder."""
+        ids = [t.payload for t in live
+               if isinstance(t.payload, (int, np.integer))]
+        if self.lengths is None or not ids:
+            return self.pads[0]
+        longest = int(self.lengths[ids].max())
+        return next(p for p in self.pads if p >= longest)
+
+
+def history_pads(longest):
+    """The ladder of history pads for histories of up to ``longest``
+    ids: 64, 512, 4096, ... (``core.ratings.pads_up_to`` from 64)."""
+    return tuple(p for p in pads_up_to(max(int(longest), MAX_EXCLUDE))
+                 if p >= MAX_EXCLUDE)
 
 
 def cpu_mark():
@@ -300,17 +348,21 @@ class _Published:
     catalog on the device; on a mesh engine they and ``U`` are sharded
     by rows over the mesh and padded to whole shards (see the module
     docstring), and a fresh index shares ``V``/``valid`` with the engine.
+    ``seen``: the users' histories published with this generation
+    (:class:`_Seen`), or None.
     """
 
     __slots__ = ("seq", "U", "V", "valid", "index", "n_users", "rank",
-                 "n_items")
+                 "n_items", "seen")
 
-    def __init__(self, seq, U, n_users, V, valid, index, n_items):
+    def __init__(self, seq, U, n_users, V, valid, index, n_items,
+                 seen=None):
         self.seq = seq
         self.U = U
         self.V = V
         self.valid = valid
         self.index = index
+        self.seen = seen
         self.n_users = int(n_users)
         self.n_items = int(n_items)
         self.rank = int(U.shape[1])
@@ -372,6 +424,60 @@ def _serve_int8_delta_packed(U, Vq, sv, V, valid, drows, dVq, dsv, dV,
     Ub = _select_packed(U, packed)
     s, ix = _int8_topk_delta(Ub, Vq, sv, V, valid, drows, dVq, dsv, dV,
                              dvalid, last_id, k=k, shortlist_k=shortlist_k)
+    return _pack_response(s, ix)
+
+
+def _select_seen(indptr, indices, packed, rank, pad):
+    """``(int32[B, pad], int32[B, MAX_EXCLUDE])``: what each slot of a
+    staged batch is not to be answered with — the first ``pad`` ids of
+    the published history of a by-id request's user (a batch rides a pad
+    that holds its longest; a request by vector has none), and the
+    request's own list as it rode the staging layout's last
+    ``MAX_EXCLUDE`` columns; each padded with ``NOT_AN_ID``: the two
+    lists ``ops.topk.excluded_mask`` takes.
+    Selected on the device, as :func:`_select_packed` selects the user
+    rows: the histories never cross host→device after their publish."""
+    with jax.named_scope(SERVE_EXCLUDE_SCOPE):
+        ids = jnp.clip(packed[:, rank], 0, indptr.shape[0] - 2)
+        first = jnp.take(indptr, ids)
+        count = jnp.where(packed[:, rank + 1] != 0, 0,
+                          jnp.take(indptr, ids + 1) - first)
+        # a history is a contiguous run of the CSR: one slice of ``pad``
+        # ids a row (the table ends in ``pad`` spare ids, so none is
+        # clamped), not ``pad`` scalar gathers (0.23 ms a batch of 8 rows
+        # of 4,096 on the v5e, and the whole 71 MB table fetched before
+        # them: PERF.md section 6, PR 39)
+        runs = jax.vmap(lambda at: jax.lax.dynamic_slice(
+            indices, (at,), (pad,)))(first)
+        j = jnp.arange(pad, dtype=jnp.int32)[None, :]
+        history = jnp.where(j < count[:, None], runs, NOT_AN_ID)
+        return history, packed[:, rank + 2:]
+
+
+@functools.partial(jax.jit, static_argnames=("k", "shortlist_k", "pad"))
+def _serve_int8_seen_packed(U, Vq, sv, V, valid, indptr, indices, packed,
+                            *, k, shortlist_k, pad):
+    """:func:`_serve_int8_packed` for a batch that excludes: the staging
+    layout is ``MAX_EXCLUDE`` columns wider (the requests' own lists),
+    the users' histories are taken from the published CSR
+    (:func:`_select_seen`), and the scoring takes them out
+    (``ops.topk.excluded_mask``'s rule).  One program a bucket and
+    history pad."""
+    Ub = _select_packed(U, packed)
+    seen = _select_seen(indptr, indices, packed, U.shape[1], pad)
+    s, ix = _int8_topk(Ub, Vq, sv, V, valid, k=k, shortlist_k=shortlist_k,
+                       seen=seen)
+    return _pack_response(s, ix)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "item_chunk", "pad"))
+def _serve_exact_seen_packed(U, V, valid, indptr, indices, packed, *, k,
+                             item_chunk, pad):
+    """The exact fallback of a batch that excludes."""
+    Ub = _select_packed(U, packed)
+    seen = _select_seen(indptr, indices, packed, U.shape[1], pad)
+    s, ix = chunked_topk_scores(Ub, V, valid, k, item_chunk=item_chunk,
+                                seen=seen)
     return _pack_response(s, ix)
 
 
@@ -587,7 +693,10 @@ class ServingEngine:
         self._replicated = (None if mesh is None else
                             jax.sharding.NamedSharding(
                                 mesh, jax.sharding.PartitionSpec()))
-        self._pinned = {}               # (bucket, path) -> AOT executable
+        # (bucket, path) -> AOT executable; (bucket, path, history pad)
+        # for the programs that exclude
+        self._pinned = {}
+        self._no_history = None         # _without_history's memo
         self._plans = {}                # _mesh_plan's memo
 
     def _place_catalog(self, Vh, validh):
@@ -675,7 +784,7 @@ class ServingEngine:
         return ("replaced",) + self._place_users(prev, U)
 
     def _swap(self, how, users, seq, n_users, V, valid, index, n_items,
-              host=None, items=None):
+              host=None, items=None, seen=None):
         """Install the next generation, the one place that assigns
         ``_model`` (but for :meth:`_compact_live`, which installs the
         same generation compacted); returns ``how`` it got its user
@@ -721,7 +830,7 @@ class ServingEngine:
                 V, valid = _scatter_items(self._model.V, self._model.valid,
                                           *items)
             self._model = _Published(seq, users, n_users, V, valid, index,
-                                     n_items)
+                                     n_items, seen)
         return how
 
     def _compact_rows(self, index):
@@ -778,11 +887,58 @@ class ServingEngine:
                                 f"({type(e).__name__}: {e})")
                 index = None
             self._model = _Published(m.seq, m.U, m.n_users, m.V, m.valid,
-                                     index, m.n_items)
+                                     index, m.n_items, m.seen)
         obs.emit("serving_compaction", seq=m.seq, rows=rows,
                  **self._labels)
 
-    def publish(self, U, V, item_valid=None, quantize=True):
+    def _place_seen(self, user_seen, n_users, n_items):
+        """The users' histories of a publish on the device
+        (:class:`_Seen`), checked first: CSR ``(indptr, indices)`` over
+        catalog ids, one row a user, a row's ids ascending and none
+        twice."""
+        indptr, indices = (np.asarray(a) for a in user_seen)
+        if indptr.shape != (n_users + 1,) or indptr[0] != 0 \
+                or indptr[-1] != len(indices) \
+                or len(indices) >= NOT_AN_ID:
+            raise ValueError(
+                f"user_seen: indptr of shape {indptr.shape} ending at "
+                f"{indptr[-1] if len(indptr) else None} for {n_users} "
+                f"users and {len(indices)} ids")
+        lengths = np.diff(indptr)
+        ok = bool((lengths >= 0).all())
+        if ok and len(indices):
+            # a row's ids rise; its first need not exceed the last of the
+            # row before
+            rises = np.diff(indices.astype(np.int64)) > 0
+            starts = indptr[1:-1]
+            rises[starts[(starts > 0) & (starts < len(indices))] - 1] = True
+            ok = bool(indices.min() >= 0 and indices.max() < n_items
+                      and rises.all())
+        if not ok:
+            raise ValueError(
+                "user_seen: every row holds catalog ids in "
+                f"[0, {n_items}), ascending, none twice")
+        pads = history_pads(lengths.max(initial=0))
+        # spare ids at the end: a slice of the longest pad from the last
+        # user's first id stays inside the table (``_select_seen``)
+        dev = jax.device_put((
+            indptr.astype(np.int32),
+            np.concatenate([indices.astype(np.int32),
+                            np.full(pads[-1], NOT_AN_ID, np.int32)])))
+        return _Seen(*dev, lengths.astype(np.int32), pads)
+
+    def _without_history(self):
+        """The empty table of histories: what a batch of an engine that
+        published none rides when a request brings a list of its own."""
+        if self._no_history is None:
+            pads = history_pads(0)
+            self._no_history = _Seen(
+                *jax.device_put((np.zeros(2, np.int32),
+                                 np.full(pads[-1], NOT_AN_ID, np.int32))),
+                None, pads)
+        return self._no_history
+
+    def publish(self, U, V, item_valid=None, quantize=True, user_seen=None):
         """Swap in a new model generation atomically.
 
         ``quantize=True`` builds the int8 candidate index for the new
@@ -791,12 +947,33 @@ class ServingEngine:
         serving exact until the next quantized publish (the old index,
         if any, is carried but detected as stale and never used).
         Returns the publish sequence number.
+
+        ``user_seen``: the users' histories, CSR over catalog ids
+        (``indptr int64[n_users + 1]``, ``indices int32[nnz]``; a row's
+        ids ascending, none twice).  They are placed on the device once,
+        with the generation, and swapped with it; from then on a request
+        by user id is answered WITHOUT the ids of that user's history
+        (and without its own ``exclude`` list: :meth:`submit`), by the
+        rule ``ops.topk.excluded_mask`` states, on the int8 path and on
+        the exact fallback alike.  ``None`` publishes none: the engine
+        then compiles and runs what it did before it knew of histories.
+        Not yet with a mesh (``serving.index._shard_score`` takes no
+        per-row mask: each shard would mask its own ids) — refused here,
+        as :meth:`warmup_live` and :meth:`publish_update` refuse a
+        generation that holds histories.
         """
         t0 = time.perf_counter()
         mode = faults.check("serving.publish")
-        U, n_users, _ = self._place_users(self._model, U)
+        if user_seen is not None and self.mesh is not None:
+            raise NotImplementedError(
+                "publish(user_seen=...) on a mesh engine: the sharded "
+                "scoring program (serving.index._shard_score) takes no "
+                "per-row exclusion; each shard would mask the ids it owns")
         Vh = np.asarray(V, dtype=np.float32)
         Ni = int(Vh.shape[0])
+        seen = (None if user_seen is None
+                else self._place_seen(user_seen, int(U.shape[0]), Ni))
+        U, n_users, _ = self._place_users(self._model, U)
         validh = (np.ones(Ni, dtype=bool) if item_valid is None
                   else np.asarray(item_valid, dtype=bool).ravel())
         self._announce_mesh()
@@ -824,7 +1001,8 @@ class ServingEngine:
                              if self._model is not None else None)
             elif self._model is not None:
                 index = self._model.index      # carried, now stale
-            self._swap("replaced", U, seq, n_users, V, valid, index, Ni)
+            self._swap("replaced", U, seq, n_users, V, valid, index, Ni,
+                       seen=seen)
             self._seq = seq
         fresh = index is not None and index.seq == seq
         obs.counter("serving.publishes", **self._labels)
@@ -928,6 +1106,14 @@ class ServingEngine:
         and ``catalog=`` on the ``serving_publish`` event say what
         became of the catalog.
         """
+        if self._model is not None and self._model.seen is not None:
+            raise NotImplementedError(
+                "publish_update on a generation that holds users' "
+                "histories: a folded rating has to join its user's "
+                "history with the publish that makes it servable, and "
+                "neither the delta segment's program "
+                "(serving.index._int8_topk_delta) nor the row write "
+                "takes histories yet; publish(..., user_seen=...) whole")
         t0 = time.perf_counter()
         # keep a host handle: the delta path gathers only the touched
         # rows, and doing that in numpy costs O(touched) with no
@@ -1055,7 +1241,13 @@ class ServingEngine:
         restore.  With a mesh the pinned programs are the sharded ones
         (:func:`_build_mesh_serve`, :func:`_build_mesh_exact`), one a
         bucket and path like the others, and each int8 one is announced
-        by a ``serving_mesh_plan`` event.
+        by a ``serving_mesh_plan`` event.  For a generation that holds
+        users' histories (``publish(user_seen=...)``) the pinned
+        programs are the ones that exclude (:meth:`_warm_exclusion`: the
+        int8 program at every history pad, the exact one at the
+        longest), each announced by a ``serving_exclusion`` event, and
+        the programs without histories are not compiled: no batch of
+        such a generation runs them.
 
         Holds ``_table_lock`` throughout, as whoever hands the live user
         table to the device must: no row write donates the table
@@ -1071,6 +1263,11 @@ class ServingEngine:
                 raise NoModelPublished("publish(U, V) before warmup")
             self._pinned.clear()
             for B in self.batcher.buckets:
+                if m.seen is not None:
+                    # every batch of this generation excludes: the
+                    # programs without histories would never run
+                    self._warm_exclusion(m, B)
+                    continue
                 proto = self._proto(B, m.rank)
                 idx = m.index
                 if idx is not None and idx.seq == m.seq:
@@ -1087,19 +1284,69 @@ class ServingEngine:
                 self._pinned[(B, "exact")] = fn.lower(
                     *args, **statics).compile()
 
-    def _proto(self, B, rank):
-        """An empty staged batch of bucket ``B``, placed as
-        :meth:`_dispatch` places a real one."""
-        return jax.device_put(np.zeros((B, rank + 2), np.int32),
+    def _warm_exclusion(self, m, B):
+        """Pin what a generation with histories runs for bucket ``B``,
+        and run each once (a program's first execution takes up to
+        seconds, and there are several a bucket: none is left to the
+        traffic): the int8 program at every history pad of its ladder,
+        the exact fallback at the longest alone (a fallback batch rides
+        that one whatever its histories: :meth:`_dispatch`); one
+        ``serving_exclusion`` event each, from the plan the program's
+        mask was traced with."""
+        wide = self._proto(B, m.rank, wide=True)
+        idx = m.index
+        if idx is not None and idx.seq == m.seq:
+            for pad in m.seen.pads:
+                fn, args, statics = self._int8_call(m, idx, wide,
+                                                    m.seen, pad)
+                c = self._pinned[(B, "int8", pad)] = fn.lower(
+                    *args, **statics).compile()
+                c(*args).block_until_ready()
+                self._emit_shortlist(B, idx, history_pad=pad)
+                cols = int(idx.Vq.shape[0])
+                self._emit_exclusion(B, "int8", pad, cols,
+                                     mask_block(cols))
+        pad = m.seen.pads[-1]
+        fn, args, statics = self._exact_call(m, wide, m.seen, pad)
+        c = self._pinned[(B, "exact", pad)] = fn.lower(
+            *args, **statics).compile()
+        c(*args).block_until_ready()
+        chunk = statics["item_chunk"]
+        self._emit_exclusion(B, "exact", pad,
+                             -(-int(m.V.shape[0]) // chunk) * chunk, chunk)
+
+    def _emit_exclusion(self, bucket, path, pad, columns, block):
+        obs.emit("serving_exclusion", bucket=bucket, path=path,
+                 history_pad=pad, request_pad=MAX_EXCLUDE,
+                 **exclusion_plan(bucket, pad + MAX_EXCLUDE, columns,
+                                  block)._asdict(), **self._labels)
+
+    def _proto(self, B, rank, wide=False):
+        """An empty staged batch of bucket ``B`` (``wide``: of a batch
+        that excludes), placed as :meth:`_dispatch` places a real one."""
+        return jax.device_put(self._staged((), B, rank, wide),
                               self._replicated)
 
-    def _int8_call(self, m, idx, packed):
+    def _int8_call(self, m, idx, packed, seen=None, pad=None):
         """``(jitted function, arguments, static arguments)`` of the
         int8 request path for one staged batch, scored by ``idx`` as it
         stands — with a delta segment (whatever it holds: its slots fix
         the shapes) or without one: the one program :meth:`warmup` /
         :meth:`warmup_live` pins (under :meth:`_int8_pin`) and
-        :meth:`_dispatch` runs."""
+        :meth:`_dispatch` runs.  ``seen`` (with its history ``pad``):
+        the batch excludes, and ``packed`` is the wide layout."""
+        if seen is not None:
+            # (a mesh engine never gets here: ``publish`` and ``submit``
+            # refuse it histories and lists; a segment can arrive between
+            # a request's ``submit`` and its batch)
+            if idx.delta_slots:
+                raise NotImplementedError(
+                    "a batch that excludes, scored by the program with a "
+                    "delta segment: it takes no per-row exclusion yet")
+            return (_serve_int8_seen_packed,
+                    (m.U, idx.Vq, idx.sv, idx.V, idx.valid, seen.indptr,
+                     seen.indices, packed),
+                    dict(k=self.k, shortlist_k=idx.shortlist_k, pad=pad))
         if self.mesh is not None:
             return (*self._mesh_serve_call(m, idx, packed), {})
         statics = dict(k=self.k, shortlist_k=idx.shortlist_k)
@@ -1123,13 +1370,18 @@ class ServingEngine:
                                   idx.ni_loc, bool(idx.delta_slots)),
                 (m.U, packed, *idx.score_args()))
 
-    def _exact_call(self, m, packed):
+    def _exact_call(self, m, packed, seen=None, pad=None):
         """The same of the exact fallback, against the engine's own
         catalog handle: per shard with a mesh, nothing uploaded."""
         if self.mesh is None:
+            statics = dict(k=self.k, item_chunk=min(
+                self.item_chunk, max(int(m.V.shape[0]), 1)))
+            if seen is not None:
+                return (_serve_exact_seen_packed,
+                        (m.U, m.V, m.valid, seen.indptr, seen.indices,
+                         packed), dict(statics, pad=pad))
             return (_serve_exact_packed, (m.U, m.V, m.valid, packed),
-                    dict(k=self.k, item_chunk=min(
-                        self.item_chunk, max(int(m.V.shape[0]), 1))))
+                    statics)
         ni_loc = int(m.V.shape[0]) // int(self.mesh.devices.size)
         return (_build_mesh_exact(self.mesh, self.k, min(self.k, ni_loc),
                                   ni_loc, min(self.item_chunk, ni_loc)),
@@ -1211,6 +1463,12 @@ class ServingEngine:
             idx = m.index
             if idx is None or idx.seq != m.seq:
                 return
+            if m.seen is not None:
+                raise NotImplementedError(
+                    "warmup_live on a generation that holds users' "
+                    "histories: the scoring program with a delta segment "
+                    "(serving.index._int8_topk_delta) takes no per-row "
+                    "exclusion yet")
             rows = (idx.n_base if idx.n_base > idx.n_items
                     else row_capacity(idx.n_items))
             idx = idx.reserve(rows, self._segment_slots(idx,
@@ -1272,13 +1530,21 @@ class ServingEngine:
         return fn(*args, **statics)
 
     # -- request path -------------------------------------------------
-    def submit(self, payload, k=None, deadline_s=None):
+    def submit(self, payload, k=None, deadline_s=None, exclude=None):
         """Admit one request; returns its ticket (see ``Ticket.result``).
 
         ``payload``: int user index into the published user table, or a
-        rank-length f32 vector (fold-in row).  Raises ``Overloaded``
-        when shedding, ``NoModelPublished`` before the first publish,
-        ``ValueError`` on a malformed payload.
+        rank-length f32 vector (fold-in row).  ``exclude``: catalog ids
+        the request is not to be answered with, at most ``MAX_EXCLUDE``
+        (64) of them: a request by id is answered without the ids of its
+        user's published history (``publish(user_seen=...)``) AND
+        without these, a request by vector without these
+        (``ops.topk.excluded_mask`` states the rule).  Raises
+        ``Overloaded`` when shedding, ``NoModelPublished`` before the
+        first publish, ``ValueError`` on a malformed payload or list (a
+        longer one is refused, not cut), ``NotImplementedError`` for a
+        list where the scoring program cannot take one yet (a mesh, an
+        index with a delta segment).
         """
         t_enter = time.perf_counter()
         m = self._model
@@ -1297,6 +1563,8 @@ class ServingEngine:
                 raise ValueError(
                     f"fold-in payload shape {payload.shape} != "
                     f"({m.rank},) (the published rank)")
+        if exclude is not None:
+            exclude = self._checked_exclude(m, exclude)
         # root span BEFORE enqueue: the consumer thread may dequeue the
         # ticket the instant submit releases the lock, so the context
         # must already ride it (None when tracing is disarmed — the
@@ -1306,7 +1574,7 @@ class ServingEngine:
             seconds=time.perf_counter() - t_enter)
         try:
             t = self.batcher.submit(payload, k=k, deadline_s=deadline_s,
-                                    trace=ctx)
+                                    trace=ctx, exclude=exclude)
         except Overloaded:
             # a shed never queues: its trace is the admission span plus
             # a queue hop with status="shed" (refusals are traced)
@@ -1322,10 +1590,33 @@ class ServingEngine:
         obs.counter("serving.requests", **self._labels)
         return t
 
-    def recommend(self, payload, k=None, deadline_s=None, timeout=None):
+    def _checked_exclude(self, m, exclude):
+        """A request's own list as it will ride the staging layout: the
+        distinct ids, int32, or the error ``submit`` raises."""
+        ids = np.unique(np.asarray(exclude).ravel())
+        if ids.size and (ids.dtype.kind not in "iu" or ids[0] < 0
+                         or ids[-1] >= m.n_items):
+            raise ValueError(f"exclude holds ids outside the published "
+                             f"catalog [0, {m.n_items})")
+        if ids.size > MAX_EXCLUDE:
+            raise ValueError(
+                f"exclude holds {ids.size} ids, a request may bring "
+                f"{MAX_EXCLUDE}: a longer list belongs to the user's "
+                "history (publish(user_seen=...))")
+        if self.mesh is not None or (m.index is not None
+                                     and m.index.delta_slots):
+            raise NotImplementedError(
+                "exclude on "
+                + ("a mesh engine" if self.mesh is not None
+                   else "an index with a delta segment")
+                + ": its scoring program takes no per-row exclusion yet")
+        return ids.astype(np.int32)
+
+    def recommend(self, payload, k=None, deadline_s=None, timeout=None,
+                  exclude=None):
         """Submit + block: returns ``(scores, indices)`` for one request."""
-        return self.submit(payload, k=k,
-                           deadline_s=deadline_s).result(timeout)
+        return self.submit(payload, k=k, deadline_s=deadline_s,
+                           exclude=exclude).result(timeout)
 
     # -- engine loop --------------------------------------------------
     def start(self):
@@ -1490,7 +1781,17 @@ class ServingEngine:
                 for t in live:      # the generation that answers them
                     t.seq = m.seq
                 B = bucket_for(n, self.batcher.buckets)
-                st = self._staged(live, B, m.rank)
+                # a batch excludes where its generation holds histories
+                # or one of its requests brings a list: the wide layout,
+                # and the history pad that holds its longest
+                seen, pad = m.seen, None
+                if seen is None and any(t.exclude is not None
+                                        for t in live):
+                    seen = self._without_history()
+                if seen is not None:
+                    pad = seen.pad_for(live)
+                    span.set_metadata(excluded=pad)
+                st = self._staged(live, B, m.rank, wide=seen is not None)
                 cpu_stage = stamp_cpu(span, mark)
             t_dispatch = time.perf_counter()
             with TraceAnnotation("serve.batch.dispatch", seq=seq) as span:
@@ -1498,7 +1799,8 @@ class ServingEngine:
                 # each counter has one writer: the difference needs no lock
                 in_flight = self._handed - self._completed
                 (resp_dev, path, fell_back, t_launch,
-                 t_launched) = self._dispatch(m, st, B, mode, seq)
+                 t_launched) = self._dispatch(m, st, B, mode, seq, seen,
+                                              pad)
                 if fell_back:
                     obs.counter("serving.fallback_exact", n,
                                 **self._labels)
@@ -1512,6 +1814,8 @@ class ServingEngine:
         obs.histogram("serving.batch_rows", n, **self._labels)
         obs.counter("serving.batch_overlap", in_flight=in_flight,
                     **self._labels)
+        if seen is not None:
+            self._count_excluded(live, seen, B)
         # the batcher's account of this dequeue is taken now: by the
         # time the batch completes the engine thread has dequeued again
         return _Flown(seq, live, resp_dev, B, n, path, fell_back,
@@ -1623,32 +1927,61 @@ class ServingEngine:
                 f"({now - t.t_submit:.4f}s since submit)"))
         return live
 
+    def _count_excluded(self, live, seen, B):
+        """The counters of one batch that excludes: ids taken out a
+        request, by where they came from, and what the requests' own
+        lists added to the batch's one upload."""
+        if seen.lengths is not None:
+            obs.histogram_many(
+                "serving.excluded_ids",
+                [int(seen.lengths[t.payload])
+                 if isinstance(t.payload, (int, np.integer)) else 0
+                 for t in live], source="history", **self._labels)
+        obs.histogram_many(
+            "serving.excluded_ids",
+            [0 if t.exclude is None else len(t.exclude) for t in live],
+            source="request", **self._labels)
+        obs.counter("serving.exclusion_upload_bytes", 4 * B * MAX_EXCLUDE,
+                    **self._labels)
+
     @staticmethod
-    def _staged(live, B, rank):
+    def _staged(live, B, rank, wide=False):
         """Single-upload staging: one int32 ``[B, rank+2]`` array
         carries the rows' f32 bits, ids and the row-mask — the payload
         is the only host→device transfer a batch makes.  A NEW array
         every batch, zeroed (pad slots score user 0, unread): the upload
         may read the host's buffer after ``device_put`` has returned (on
         the CPU the device array IS that buffer), and the next batch of
-        this bucket is staged while this one is still in flight."""
-        st = np.zeros((B, rank + 2), dtype=np.int32)
+        this bucket is staged while this one is still in flight.
+        ``wide``: a batch that excludes carries ``MAX_EXCLUDE`` more
+        columns, each request's own list of ids padded with
+        ``NOT_AN_ID`` (a pad slot is marked a request by vector: it
+        takes no history)."""
+        st = np.zeros((B, rank + 2 + (MAX_EXCLUDE if wide else 0)),
+                      dtype=np.int32)
         rows = st[:, :rank].view(np.float32)    # same-itemsize view
+        if wide:
+            st[:, rank + 2:] = NOT_AN_ID
+            st[len(live):, rank + 1] = 1
         for j, t in enumerate(live):
             if isinstance(t.payload, (int, np.integer)):
                 st[j, rank] = t.payload
             else:
                 rows[j] = t.payload
                 st[j, rank + 1] = 1
+            if wide and t.exclude is not None:
+                st[j, rank + 2:rank + 2 + len(t.exclude)] = t.exclude
         return st
 
-    def _dispatch(self, m, st, B, mode, seq):
+    def _dispatch(self, m, st, B, mode, seq, seen=None, pad=None):
         """Upload the staged batch (to every shard, with a mesh) and
         call the scorer the live model selects, each in a child span of
         the caller's ``serve.batch.dispatch``; returns ``(packed
         response on the device, path, fell back to exact, when the
         upload had returned, when the call had)`` as soon as the call
-        returns."""
+        returns.  ``seen``, ``pad``: the batch excludes — the program of
+        its history pad; on the exact fallback the longest pad's, the
+        one :meth:`warmup` pins."""
         index = m.index
         with TraceAnnotation("serve.batch.dispatch.upload", seq=seq,
                              bytes=st.nbytes):
@@ -1666,13 +1999,17 @@ class ServingEngine:
                             **self._labels)
             if not use_index:
                 path, pin = "exact", "exact"
-                fn, args, statics = self._exact_call(m, packed)
+                if seen is not None:
+                    pad = seen.pads[-1]
+                fn, args, statics = self._exact_call(m, packed, seen, pad)
             else:
                 path = "int8" if self.mesh is None else "int8_sharded"
                 pin = self._int8_pin(index)
-                fn, args, statics = self._int8_call(m, index, packed)
-            resp_dev = self._run_pinned((B, pin), fn, args, statics)
+                fn, args, statics = self._int8_call(m, index, packed,
+                                                    seen, pad)
+            key = (B, pin) if seen is None else (B, pin, pad)
+            resp_dev = self._run_pinned(key, fn, args, statics)
             # a pin that failed was dropped inside the call
             span.set_metadata(program="jit_" + fn.__name__,
-                              pinned=int((B, pin) in self._pinned))
+                              pinned=int(key in self._pinned))
         return resp_dev, path, fell_back, t_launch, time.perf_counter()

@@ -108,6 +108,8 @@ import numpy as np
 from tpu_als.core.ratings import _next_pow2, pad_for, pads_up_to
 from tpu_als.ops.topk import (
     NEG_INF,
+    NOT_AN_ID,
+    excluded_mask,
     shortlist_columns,
     shortlist_plan,
     shortlist_topk,
@@ -120,7 +122,7 @@ SCORE_ULPS = 16
 # the catalog id a free slot of the delta segment carries: outside every
 # catalog, so the scatters that read it (the override mask, the
 # compaction) drop it whatever the base arrays' size
-SLOT_FREE = np.iinfo(np.int32).max
+SLOT_FREE = NOT_AN_ID
 
 
 @functools.partial(jax.jit, static_argnames=("pad",))
@@ -139,15 +141,39 @@ def _quantize_rows(X, pad=0):
     return q, s
 
 
+def mask_block(columns):
+    """The block :func:`_int8_topk` asks ``ops.topk.excluded_mask`` for
+    over ``columns`` scores: the TPU's 128 lanes where the columns are
+    whole blocks of them (every catalog the shortlist takes in two
+    stages, which an index pads to whole blocks), else one block of all
+    columns (a small catalog)."""
+    return 128 if columns % 128 == 0 else columns
+
+
 @functools.partial(jax.jit, static_argnames=("k", "shortlist_k"))
-def _int8_topk(U, Vq, sv, V, valid, k, shortlist_k):
+def _int8_topk(U, Vq, sv, V, valid, k, shortlist_k, seen=None):
+    """``seen`` (lists of ``int32[n, h]`` ids, padded with
+    ``NOT_AN_ID``): the ids each row is not to be answered with
+    (``ops.topk.excluded_mask`` takes them and states the rule):
+    out of the approximate scores before the shortlist, and out of
+    the rescored candidates before the last ``top_k`` (a candidate list
+    of a row with fewer than ``shortlist_k`` columns left holds some).
+    ``None`` traces what the function traced before it took the
+    argument."""
     n = U.shape[0]
     Uq, su = _quantize_rows(U)
     # int8 x int8 -> int32 on the MXU; rescale to approximate f32 scores
     acc = jnp.einsum("nr,cr->nc", Uq, Vq,
                      preferred_element_type=jnp.int32)
     approx = acc.astype(jnp.float32) * su[:, None] * sv[None, :]
-    approx = jnp.where(valid[None, :], approx, NEG_INF)
+    ok = valid[None, :]
+    if seen is not None:
+        # block-major: transposed it is the row-major mask's own bytes
+        cols = Vq.shape[0]
+        excluded = excluded_mask(seen, cols, mask_block(cols)).transpose(
+            1, 0, 2).reshape(n, cols)
+        ok = ok & ~excluded
+    approx = jnp.where(ok, approx, NEG_INF)
     _, cand = shortlist_topk(approx, shortlist_k)      # [n, sk]
     # exact f32 rescore with the chunked kernel's own contraction shape:
     # full U batch x gathered catalog columns (see module docstring)
@@ -157,7 +183,10 @@ def _int8_topk(U, Vq, sv, V, valid, k, shortlist_k):
     rows = (jnp.arange(n, dtype=jnp.int32)[:, None] * shortlist_k
             + jnp.arange(shortlist_k, dtype=jnp.int32)[None, :])
     exact = jnp.take_along_axis(exact_all, rows, axis=1)
-    exact = jnp.where(jnp.take(valid, cand), exact, NEG_INF)
+    cand_ok = jnp.take(valid, cand)
+    if seen is not None:
+        cand_ok &= ~jnp.take_along_axis(excluded, cand, axis=1)
+    exact = jnp.where(cand_ok, exact, NEG_INF)
     s, sel = jax.lax.top_k(exact, k)
     return s, jnp.take_along_axis(cand, sel, axis=1)
 

@@ -23,7 +23,11 @@ any, then median, mean, longest in ms), the batch's own wait for the
 completion thread (``completion_wait``: its whole life less its four
 phases) and that thread's wait for a batch (``completion_idle``), the
 two halves of dispatch (``upload``, ``launch``: median, mean, longest in
-ms) and, for a stream a profiler watched, each phase's CPU share
+ms) with how the staged batch reached the device (``upload_how_pct``: the
+share of ``call`` — it rode the scoring call as its host argument, the
+transfer is inside ``launch`` — and of ``put``, a separate ``device_put``,
+which no path makes since PR 41) and, for a stream a profiler watched, each
+phase's CPU share
 (``cpu_pct``: the thread's own CPU time over the phase's wall time, summed
 over the window — what is missing the thread spent without a processor, or
 in the two clock calls the span holds), the latency of the requests that rode
@@ -88,6 +92,9 @@ def shares(records, seconds, requests=()):
         for r in window)
     for name in ("upload", "launch"):
         row[name + "_ms"] = three(1e3 * r[name] for r in window)
+    hows = collections.Counter(r["upload_how"] for r in window)
+    row["upload_how_pct"] = {how: 100.0 * n / len(window)
+                             for how, n in sorted(hows.items())}
     # the CPU clock is read only while a profiler records: a traced stream
     watched = [r for r in window if r["cpu"]["stage"] is not None]
     if watched:

@@ -121,6 +121,17 @@ def _bound_live_executables_per_module(request):
             return
 
 
+class CompileCount:
+    """Backend compilations, from JAX's own monitoring events."""
+
+    def __init__(self):
+        self.n = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, duration, **_):
+        self.n += event == "/jax/core/compile/backend_compile_duration"
+
+
 class GatedResponses:
     """Stands between a ``ServingEngine``'s dispatch and its readback:
     every batch the engine dispatches comes back as a stub whose

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.conftest import CompileCount
 from tpu_als import ALSModel, FoldInServer, IdMap, LiveUpdater, obs
 from tpu_als.core import foldin
 from tpu_als.core.ratings import (
@@ -65,17 +66,6 @@ def seeded_events(rng, n=N_EVENTS):
         events.append((user, int(rng.integers(0, N_ITEMS)),
                        float(rng.integers(1, 6))))
     return events
-
-
-class CompileCount:
-    """Backend compilations, from JAX's own monitoring events."""
-
-    def __init__(self):
-        self.n = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        self.n += event == "/jax/core/compile/backend_compile_duration"
 
 
 def wait_for(pred, timeout=20.0):

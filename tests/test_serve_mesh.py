@@ -311,7 +311,9 @@ def test_exact_fallback_scores_per_shard_and_uploads_nothing(monkeypatch):
         uploads.append(np.asarray(x).nbytes if not isinstance(x, tuple)
                        else 0), real(x, *a, **k))[1])
     got = drain(eng, [3, 2700, N_USERS - 1])
-    assert max(uploads) <= 8 * (RANK + 2) * 4
+    # the staged batch rides the program's call; the last catalog id
+    # went up once, in ``warmup()``: no ``device_put`` on a batch's path
+    assert uploads == []
     want = drain(engine(U, V, mesh=False), [3, 2700, N_USERS - 1])
     for (ws, wi), (gs, gi) in zip(want, got):
         assert gi.tolist() == wi.tolist()

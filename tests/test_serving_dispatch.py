@@ -1,0 +1,233 @@
+"""One runtime call a batch on the engine thread (PR 41): the staged
+batch rides the pinned program's call as the host array it is, and no
+``jax.device_put`` is left on a batch's path — for every program
+``ServingEngine._dispatch`` can choose: the plain int8 program, the one
+with a delta segment, the one that excludes histories, the exact
+fallback, and on four CPU devices the mesh's int8 and exact programs.
+
+One engine a program runs one scenario (``flown``) and four tests read
+it: (i) the same answers, bit for bit, as the same pinned executable
+called on a ``device_put`` copy of the same staged array; (ii) not one
+``device_put`` while a started engine serves 50 batches; (iii) no
+compilation across them, nor on the jit fall-back after a dropped pin
+(the mesh's compiles once: a host argument carries no sharding); (iv) two
+batches of one bucket in flight at once are staged in two arrays and
+both answered right."""
+
+from __future__ import annotations
+
+import threading
+
+import jax
+import numpy as np
+import pytest
+
+from tests.conftest import CompileCount, GatedResponses
+from tpu_als import make_mesh
+from tpu_als.serving.engine import ServingEngine
+
+S, N_USERS, N_ITEMS, RANK, K, BUCKET = 4, 203, 2003, 16, 5, 8
+BATCHES = 50
+# program: (the jitted function's name, the key warmup() pins it under)
+PROGRAMS = {
+    "int8": ("_serve_int8_packed", (BUCKET, "int8")),
+    "int8_delta": ("_serve_int8_delta_packed", (BUCKET, "int8_delta")),
+    "int8_seen": ("_serve_int8_seen_packed", (BUCKET, "int8", 64)),
+    "exact": ("_serve_exact_packed", (BUCKET, "exact")),
+    "mesh_int8": ("serve_mesh_int8", (BUCKET, "int8")),
+    "mesh_exact": ("serve_mesh_exact", (BUCKET, "exact")),
+}
+
+
+class Calls:
+    """In a pinned executable's place: counts its calls, keeps their
+    arguments, and raises in its stead while ``broken``."""
+
+    def __init__(self, compiled):
+        self.compiled, self.args, self.broken = compiled, [], False
+
+    def __call__(self, *args):
+        if self.broken:
+            raise TypeError("the pin no longer fits (says the test)")
+        self.args.append(args)
+        return self.compiled(*args)
+
+
+def build(program):
+    rng = np.random.default_rng(41)
+    U = rng.standard_normal((N_USERS, RANK)).astype(np.float32)
+    V = (rng.standard_normal((N_ITEMS, RANK)) / 4).astype(np.float32)
+    eng = ServingEngine(k=K, buckets=(BUCKET,), shortlist_k=32,
+                        mesh=(make_mesh(S) if program.startswith("mesh")
+                              else None))
+    kw = {}
+    if program.endswith("exact"):
+        kw["quantize"] = False          # no index: the fallback serves
+    if program == "int8_seen":
+        lengths = rng.integers(0, 40, N_USERS)
+        kw["user_seen"] = (
+            np.concatenate([[0], np.cumsum(lengths)]).astype(np.int64),
+            np.concatenate([np.sort(rng.choice(N_ITEMS, n, replace=False))
+                            for n in lengths]).astype(np.int32))
+    eng.publish(U, V, **kw)
+    eng.warmup()
+    if program == "int8_delta":
+        eng.warmup_live(max_rows=8)
+    return eng, rng
+
+
+def requests(rng, n):
+    """By id, and every third by vector."""
+    return [rng.standard_normal(RANK).astype(np.float32) if j % 3 == 2
+            else int(rng.integers(0, N_USERS)) for j in range(n)]
+
+
+def serve_now(eng, payloads):
+    """``payloads`` as ONE batch on the caller's thread."""
+    tickets = [eng.submit(p) for p in payloads]
+    batch = eng.batcher.next_batch(timeout=0, coalesce=False)
+    assert len(batch) == len(payloads)
+    eng.serve_batch(batch)
+    return [t.result(timeout=0) for t in tickets]
+
+
+def on_a_placed_copy(eng, pin, args):
+    """The pinned executable on the same arguments but the staged array,
+    which goes up first by a ``device_put`` of a copy, as every batch's
+    did until PR 41: the packed response."""
+    (at,) = [i for i, a in enumerate(args) if isinstance(a, np.ndarray)]
+    placed = jax.device_put(args[at].copy(), eng._replicated)
+    return np.asarray(pin.compiled(*args[:at], placed, *args[at + 1:]))
+
+
+def packed(answers):
+    """``[(scores, ids)]`` as the rows of the packed response."""
+    return np.stack([np.concatenate([s.view(np.int32), i])
+                     for s, i in answers])
+
+
+@pytest.fixture(scope="module", params=list(PROGRAMS))
+def flown(request):
+    """What one engine of ``program`` did, step by step (the keys of the
+    returned dict), with ``jax.device_put`` and the compiler watched."""
+    program = request.param
+    name, key = PROGRAMS[program]
+    eng, rng = build(program)
+    compiles = CompileCount()
+    pin = eng._pinned[key] = Calls(eng._pinned[key])
+    staged, stage = [], eng._staged
+
+    def keep_staged(*args, **kw):
+        staged.append(stage(*args, **kw))
+        return staged[-1]
+
+    eng._staged = keep_staged
+    out = {"program": program, "name": name, "eng": eng}
+    # (i) one batch on the caller's thread
+    out["answers"] = packed(serve_now(eng, requests(rng, 5)))
+    out["placed"] = on_a_placed_copy(eng, pin, pin.args[-1])[:5]
+    # (iii) the pin dropped: the ordinary jit call takes the host array
+    n0, pin.broken = compiles.n, True
+    fell = serve_now(eng, requests(rng, 5))
+    out["pin_dropped"] = key not in eng._pinned
+    out["fallback_compiles"] = compiles.n - n0
+    again = requests(rng, 5)
+    fell_again = serve_now(eng, again)
+    out["fallback_compiles_again"] = compiles.n - n0 - out[
+        "fallback_compiles"]
+    pin.broken, eng._pinned[key] = False, pin
+    out["fallback"] = packed(fell_again), packed(serve_now(eng, again))
+    assert len(fell) == 5
+    # (ii), (iii) a started engine, BATCHES requests one after another
+    puts, real_put = [], jax.device_put
+    n0, calls0, seq0 = compiles.n, len(pin.args), eng._batch_seq
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "device_put", lambda *a, **kw: (
+            puts.append(threading.current_thread().name),
+            real_put(*a, **kw))[1])
+        gated = GatedResponses(eng)
+        gated.open()
+        with eng:
+            for p in requests(rng, BATCHES):
+                eng.submit(p).result(timeout=20.0)
+            out["batches"] = eng._batch_seq - seq0
+            out["pinned_calls"] = len(pin.args) - calls0
+            out["compiles"] = compiles.n - n0
+            out["device_puts"] = list(puts)
+            # (iv) two of one bucket in flight: the second staged and
+            # dispatched while the first's readback is held
+            gated._open = False
+            first = len(gated.gates)
+            a = eng.submit(7)
+            gated.wait_dispatched(first + 1)
+            assert gated.gates[first].entered.wait(10.0)
+            b = eng.submit(11)
+            gated.wait_dispatched(first + 2)
+            out["both_in_flight"] = not (a.done() or b.done())
+            out["staged"] = staged[-2:]
+            gated.open()
+            out["pair"] = packed([a.result(timeout=20.0)]), packed(
+                [b.result(timeout=20.0)])
+        out["device_puts_in_all"] = list(puts)
+    out["pair_placed"] = [on_a_placed_copy(eng, pin, args)[:1]
+                          for args in pin.args[-2:]]
+    return out
+
+
+def test_the_batch_rides_the_call_bit_for_bit(flown):
+    """(i) the program and its operands are the parent's: the answers of
+    a batch served through the engine are those of the same pinned
+    executable on a ``device_put`` copy of the same staged array."""
+    eng, (_, key) = flown["eng"], PROGRAMS[flown["program"]]
+    assert eng._pinned[key].compiled is not None
+    assert flown["answers"].shape == (5, 2 * K)
+    assert np.array_equal(flown["answers"], flown["placed"])
+    rec = eng.batch_flight.records()[-1]
+    assert rec["upload_how"] == "call" and rec["upload"] > 0
+
+
+def test_no_device_put_while_a_started_engine_serves(flown):
+    """(ii) not from the engine thread, nor from any other."""
+    assert flown["batches"] == BATCHES == flown["pinned_calls"]
+    assert "tpu-als-serving" not in flown["device_puts_in_all"]
+    assert flown["device_puts"] == []
+
+
+def test_nothing_compiles_on_the_pin_nor_on_the_jit_fall_back(flown):
+    """(iii) the host argument hits the pinned executable; after a
+    dropped pin the ordinary jit call takes it too — without a mesh from
+    the cache entry ``warmup()`` left, with one after ONE compilation
+    (a host argument carries no sharding where the prototype carried
+    the replicated one), and the answers are the pinned program's."""
+    assert flown["compiles"] == 0
+    assert flown["pin_dropped"]
+    mesh = flown["program"].startswith("mesh")
+    assert flown["fallback_compiles"] == (1 if mesh else 0)
+    assert flown["fallback_compiles_again"] == 0
+    by_jit, by_pin = flown["fallback"]
+    assert np.array_equal(by_jit, by_pin)
+
+
+def test_two_batches_in_flight_are_staged_apart_and_both_right(flown):
+    """(iv) ``_staged``'s contract: a NEW array every batch — the runtime
+    may read the host's buffer after the call has returned."""
+    assert flown["both_in_flight"]
+    first, second = flown["staged"]
+    assert first is not second and not np.shares_memory(first, second)
+    assert first.shape == second.shape and first.base is None
+    # the first's ids were not overwritten by the second's staging
+    assert (first[0, RANK], second[0, RANK]) == (7, 11)
+    for got, want in zip(flown["pair"], flown["pair_placed"]):
+        assert np.array_equal(got, want)
+    assert not np.array_equal(*flown["pair"])
+
+
+def test_the_fallback_on_a_mesh_uploads_the_last_id_once():
+    """The exact fallback's clamp id goes to the mesh once a catalog
+    size, not once a batch (the int8 path's ``_last_id`` pattern)."""
+    eng, _ = build("mesh_exact")
+    handle = eng._last_item(eng._model.n_items)
+    assert handle is eng._last_item(eng._model.n_items)
+    assert int(handle) == N_ITEMS - 1
+    assert handle is not eng._last_item(N_ITEMS + 1)
+    assert int(eng._last_item(N_ITEMS + 1)) == N_ITEMS

@@ -297,6 +297,31 @@ def test_upload_and_launch_lie_inside_dispatch_and_do_not_overlap(either):
     assert {st["bytes"] for st in uploads} <= {8 * 18 * 4, 32 * 18 * 4}
 
 
+def test_upload_says_how_the_staged_batch_reached_the_device(either):
+    """Since PR 41 the staged array rides the scoring call as its host
+    argument: every upload span says ``how`` = ``call`` beside ``seq``
+    and ``bytes``, the batch's record mirrors it as ``upload_how``, and
+    ``obs/schema.py`` declares both."""
+    import inspect
+
+    from tpu_als.obs import schema
+
+    spans, eng = either
+    uploads = [s[3] for s in spans if s[0] == UPLOAD]
+    assert len(uploads) == 3
+    for stats in uploads:
+        assert {"seq", "bytes", "how"} <= set(stats), stats
+        assert stats["how"] == "call"
+    by_batch = {r["batch"]: r for r in eng.batch_flight.records()}
+    for stats in uploads:
+        assert by_batch[stats["seq"]]["upload_how"] == stats["how"]
+    assert "upload_how = call|put" in " ".join(
+        EVENTS["flight_record"][1].split())
+    # (the span's stats are declared in SERVE_DISPATCH_SPAN_KEYS' comment)
+    assert "how = call" in " ".join(
+        inspect.getsource(schema).replace("#", " ").split())
+
+
 @pytest.mark.parametrize("name", PHASES)
 def test_cpu_us_lies_within_wall_us_within_the_spans_duration(
         traced, pipelined, name):

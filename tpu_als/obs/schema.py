@@ -393,14 +393,21 @@ SERVE_BATCH_SPAN_KEYS = (
 # more time for them); not keys of the batch record, which carries their
 # seconds as ``upload`` and ``launch``
 SERVE_DISPATCH_SPAN_KEYS = (
-    "serve.batch.dispatch.upload",  # E: device_put of the staged batch
-    #                                 (seq, bytes: the staged array's,
-    #                                 which every shard of a mesh takes
-    #                                 whole)
-    "serve.batch.dispatch.launch",  # E: the scorer chosen and called,
-    #                                 until the call returns (seq,
-    #                                 program: the jitted function as the
-    #                                 trace's XLA Modules line names it,
+    "serve.batch.dispatch.upload",  # E: what the host does for the
+    #                                 staged batch's upload apart from
+    #                                 the scoring call (seq, bytes: the
+    #                                 staged array's, which every shard of
+    #                                 a mesh takes whole; how = call: the
+    #                                 array rides the program's call as
+    #                                 its host argument, the transfer is
+    #                                 inside launch, and this span holds
+    #                                 the scorer chosen and its arguments
+    #                                 built | put: a separate device_put,
+    #                                 which no path makes since PR 41)
+    "serve.batch.dispatch.launch",  # E: the scorer called, until the call
+    #                                 returns (seq, program: the jitted
+    #                                 function as the trace's XLA Modules
+    #                                 line names it,
     #                                 ``jit__serve_int8_packed``, ...;
     #                                 pinned 0|1: the AOT executable took
     #                                 it)
@@ -654,7 +661,10 @@ EVENTS = {
         "complete, completion_idle = seconds the completion thread had "
         "waited for a batch when this one was handed over, upload / "
         "launch = seconds of the two child spans of dispatch "
-        "(SERVE_DISPATCH_SPAN_KEYS; upload + launch <= dispatch), cpu = "
+        "(SERVE_DISPATCH_SPAN_KEYS; upload + launch <= dispatch), "
+        "upload_how = call|put as the upload span's how (call: the "
+        "staged batch rode the scoring call as its host argument, its "
+        "transfer is inside launch), cpu = "
         "{stage, dispatch, readback, complete}: seconds of the thread's "
         "own CPU time in each phase, beside the phase's wall seconds in "
         "spans — wall - CPU is time the thread held no processor; each "

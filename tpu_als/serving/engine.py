@@ -153,7 +153,9 @@ stack plugs into:
   one ``[B, rank+2]`` int32 array (query rows' f32 bits | ids |
   row-mask; a new one every batch, since the batch before may still be
   in flight from its own) and uploads it as ONE transfer — no per-batch
-  id/row/mask re-uploads (the payload is the only host→device traffic).
+  id/row/mask re-uploads (the payload is the only host→device traffic)
+  — which rides the scoring program's call as its host argument: ONE
+  trip into the runtime a batch, no ``jax.device_put`` on its path.
   Responses come back packed ``[B, 2k]`` (scores' f32 bits | indices) in
   one bulk transfer, and tickets complete with numpy VIEWS sliced from
   that buffer — zero per-ticket copies; the buffer snapshots an
@@ -324,7 +326,8 @@ class _Flown:
     head_wait: float
     in_flight: int
     handoff_wait: float
-    t_launch: float         # the upload had returned
+    upload_how: str         # ``call`` | ``put``, as the upload span's how
+    t_launch: float         # the upload span had closed
     t_launched: float       # the scoring call had returned
     cpu_stage: float | None     # the engine thread's CPU seconds in it
     cpu_dispatch: float | None  # (None: no profiler was recording)
@@ -697,6 +700,7 @@ class ServingEngine:
         # for the programs that exclude
         self._pinned = {}
         self._no_history = None         # _without_history's memo
+        self._last_id = None            # _last_item's: (n_items, handle)
         self._plans = {}                # _mesh_plan's memo
 
     def _place_catalog(self, Vh, validh):
@@ -1323,7 +1327,8 @@ class ServingEngine:
 
     def _proto(self, B, rank, wide=False):
         """An empty staged batch of bucket ``B`` (``wide``: of a batch
-        that excludes), placed as :meth:`_dispatch` places a real one."""
+        that excludes), placed where the pinned program's call places a
+        real one: what ``lower()`` reads the input sharding from."""
         return jax.device_put(self._staged((), B, rank, wide),
                               self._replicated)
 
@@ -1372,7 +1377,9 @@ class ServingEngine:
 
     def _exact_call(self, m, packed, seen=None, pad=None):
         """The same of the exact fallback, against the engine's own
-        catalog handle: per shard with a mesh, nothing uploaded."""
+        catalog handle: per shard with a mesh, nothing uploaded but the
+        staged batch (the last catalog id is on the device already:
+        :meth:`_last_item`)."""
         if self.mesh is None:
             statics = dict(k=self.k, item_chunk=min(
                 self.item_chunk, max(int(m.V.shape[0]), 1)))
@@ -1385,9 +1392,17 @@ class ServingEngine:
         ni_loc = int(m.V.shape[0]) // int(self.mesh.devices.size)
         return (_build_mesh_exact(self.mesh, self.k, min(self.k, ni_loc),
                                   ni_loc, min(self.item_chunk, ni_loc)),
-                (m.U, packed, m.V, m.valid,
-                 jax.device_put(np.int32(m.n_items - 1), self._replicated)),
-                {})
+                (m.U, packed, m.V, m.valid, self._last_item(m.n_items)), {})
+
+    def _last_item(self, n_items):
+        """The last catalog id, replicated over the mesh (the exact
+        fallback clamps its answers to it): uploaded once per catalog
+        size, as ``ShardedInt8Index._last_id`` is for the int8 path, not
+        once a fallback batch."""
+        if self._last_id is None or self._last_id[0] != n_items:
+            self._last_id = (n_items, jax.device_put(
+                np.int32(n_items - 1), self._replicated))
+        return self._last_id[1]
 
     def _mesh_plan(self, m, idx, bucket):
         """What one batch of ``bucket`` rows costs the mesh, scored by
@@ -1520,7 +1535,12 @@ class ServingEngine:
         """Dispatch through the AOT-pinned executable when one is live
         for ``key``; a pin invalidated by a shape-changing publish is
         dropped and the ordinary jit call (compiled once, cached) takes
-        over until the next :meth:`warmup`."""
+        over until the next :meth:`warmup`.  Either takes the staged
+        batch as the host array it is.  (Without a mesh the jit call
+        finds the entry :meth:`warmup` left at the same shapes; with one
+        it compiles once more, since a host argument carries no
+        sharding where ``warmup``'s prototype carried the replicated
+        one.)"""
         c = self._pinned.get(key)
         if c is not None:
             try:
@@ -1798,7 +1818,7 @@ class ServingEngine:
                 mark = cpu_mark()
                 # each counter has one writer: the difference needs no lock
                 in_flight = self._handed - self._completed
-                (resp_dev, path, fell_back, t_launch,
+                (resp_dev, path, fell_back, upload_how, t_launch,
                  t_launched) = self._dispatch(m, st, B, mode, seq, seen,
                                               pad)
                 if fell_back:
@@ -1821,7 +1841,8 @@ class ServingEngine:
         return _Flown(seq, live, resp_dev, B, n, path, fell_back,
                       t_stage, t_locked, t_dispatch, self.batcher.last_wait,
                       self.batcher.closed_by, self.batcher.head_wait,
-                      in_flight, handoff_wait, t_launch, t_launched,
+                      in_flight, handoff_wait, upload_how, t_launch,
+                      t_launched,
                       cpu_stage, cpu_dispatch, time.perf_counter())
 
     def _finish(self, flown, t_readback, idle_s=0.0):
@@ -1895,6 +1916,7 @@ class ServingEngine:
             in_flight=flown.in_flight, handoff_wait=flown.handoff_wait,
             completion_idle=idle_s,
             upload=flown.t_launch - flown.t_dispatch,
+            upload_how=flown.upload_how,
             launch=flown.t_launched - flown.t_launch,
             cpu={"stage": flown.cpu_stage, "dispatch": flown.cpu_dispatch,
                  "readback": cpu_readback, "complete": cpu_complete})
@@ -1950,7 +1972,7 @@ class ServingEngine:
         carries the rows' f32 bits, ids and the row-mask — the payload
         is the only host→device transfer a batch makes.  A NEW array
         every batch, zeroed (pad slots score user 0, unread): the upload
-        may read the host's buffer after ``device_put`` has returned (on
+        may read the host's buffer after the call has returned (on
         the CPU the device array IS that buffer), and the next batch of
         this bucket is staged while this one is still in flight.
         ``wide``: a batch that excludes carries ``MAX_EXCLUDE`` more
@@ -1974,42 +1996,48 @@ class ServingEngine:
         return st
 
     def _dispatch(self, m, st, B, mode, seq, seen=None, pad=None):
-        """Upload the staged batch (to every shard, with a mesh) and
-        call the scorer the live model selects, each in a child span of
-        the caller's ``serve.batch.dispatch``; returns ``(packed
-        response on the device, path, fell back to exact, when the
-        upload had returned, when the call had)`` as soon as the call
-        returns.  ``seen``, ``pad``: the batch excludes — the program of
-        its history pad; on the exact fallback the longest pad's, the
-        one :meth:`warmup` pins."""
-        index = m.index
+        """Call the scorer the live model selects on the staged batch,
+        which rides the call as the host array it is: the program's own
+        argument handling places it (on the default device; replicated
+        over the mesh, as the pinned executable's input sharding says),
+        ONE trip into the runtime a batch, no upload call of its own.
+        Two child spans of the caller's ``serve.batch.dispatch``:
+        ``upload`` around what the host still does for the upload apart
+        from the call — the scorer chosen and its argument tuple built
+        around ``st`` (``how`` = ``call``) — and ``launch`` around the
+        call, the transfer inside it.  Returns ``(packed response on the
+        device, path, fell back to exact, how the batch was uploaded,
+        when the upload span had closed, when the call had returned)``
+        as soon as the call returns.  ``seen``, ``pad``: the batch
+        excludes — the program of its history pad; on the exact fallback
+        the longest pad's, the one :meth:`warmup` pins."""
+        index, how = m.index, "call"
         with TraceAnnotation("serve.batch.dispatch.upload", seq=seq,
-                             bytes=st.nbytes):
-            packed = jax.device_put(st, self._replicated)
-        t_launch = time.perf_counter()
-        with TraceAnnotation("serve.batch.dispatch.launch",
-                             seq=seq) as span:
+                             bytes=st.nbytes, how=how):
             use_index = (index is not None and index.seq == m.seq
                          and mode != "corrupt")
             fell_back = index is not None and not use_index
+            if not use_index:
+                path, pin = "exact", "exact"
+                if seen is not None:
+                    pad = seen.pads[-1]
+                fn, args, statics = self._exact_call(m, st, seen, pad)
+            else:
+                path = "int8" if self.mesh is None else "int8_sharded"
+                pin = self._int8_pin(index)
+                fn, args, statics = self._int8_call(m, index, st, seen,
+                                                    pad)
+            key = (B, pin) if seen is None else (B, pin, pad)
+        t_launch = time.perf_counter()
+        with TraceAnnotation("serve.batch.dispatch.launch",
+                             seq=seq) as span:
             if self.mesh is not None:
                 obs.counter("serving.mesh_exchange_bytes",
                             self._mesh_plan(m, index if use_index else None,
                                             B)["exchange_bytes"],
                             **self._labels)
-            if not use_index:
-                path, pin = "exact", "exact"
-                if seen is not None:
-                    pad = seen.pads[-1]
-                fn, args, statics = self._exact_call(m, packed, seen, pad)
-            else:
-                path = "int8" if self.mesh is None else "int8_sharded"
-                pin = self._int8_pin(index)
-                fn, args, statics = self._int8_call(m, index, packed,
-                                                    seen, pad)
-            key = (B, pin) if seen is None else (B, pin, pad)
             resp_dev = self._run_pinned(key, fn, args, statics)
             # a pin that failed was dropped inside the call
             span.set_metadata(program="jit_" + fn.__name__,
                               pinned=int(key in self._pinned))
-        return resp_dev, path, fell_back, t_launch, time.perf_counter()
+        return resp_dev, path, fell_back, how, t_launch, time.perf_counter()

@@ -469,3 +469,143 @@ def test_the_program_that_excludes_at_the_unseen_cells_size(one_chip, bucket):
         assert not re.search(r"= u32\[368,32,\d+,128\]", entry), pad
         assert 'op_name="jit(_serve_int8_seen_packed)/jit(_int8_topk)/' \
             "serve.exclude/" in text
+
+
+# -- histories that grow (PR 42) -----------------------------------------------
+
+# sha256 of the lowered text of the programs a generation WITH histories
+# runs when nobody called ``warmup_live`` (the ``serve-unseen`` cell), at
+# that cell's shapes, taken on the parent commit (906f260): the histories
+# as published keep their layout, their programs and their text; only a
+# table laid out to grow runs other programs (``runs`` a pair of arrays)
+PARENT_LOWERED_SEEN = {
+    ("exact", 8, 4096): "dd710ba22e7cf9ef",
+    ("exact", 32, 4096): "70e882ac8237161a",
+    ("exact", 128, 4096): "2d9f85f31e88b6a6",
+    ("int8", 8, 64): "13f574549f0fee50", ("int8", 8, 512): "e29857978345e68b",
+    ("int8", 8, 4096): "10f68d3c0bc4bb3d",
+    ("int8", 32, 64): "2e12f18bc9e90166",
+    ("int8", 32, 512): "f0385d3c50b3d0d9",
+    ("int8", 32, 4096): "ef08812d83074275",
+    ("int8", 128, 64): "5044add96388252e",
+    ("int8", 128, 512): "1fad941d79f1434b",
+    ("int8", 128, 4096): "04410b6144928bb0",
+}
+
+
+def _seen_shapes(bucket, grown=False):
+    """(catalog tables, where the runs lie, the ids, the staged batch) of
+    the unseen cell; ``grown``: of the live-unseen cell after
+    ``warmup_live`` (row capacity, room behind the runs, the 8,192 rung)."""
+    from tpu_als.core.ratings import row_capacity
+    from tpu_als.ops.topk import shortlist_columns
+    from tpu_als.serving.engine import MAX_EXCLUDE
+
+    cols = shortlist_columns(LIVE_ITEMS, 64)
+    users = row_capacity(LIVE_USERS) if grown else LIVE_USERS
+    tables = [((users, LIVE_RANK), jnp.float32),
+              ((cols, LIVE_RANK), jnp.int8), ((cols,), jnp.float32),
+              ((LIVE_ITEMS, LIVE_RANK), jnp.float32), ((cols,), jnp.bool_)]
+    runs = (((users,), jnp.int32),) * 2 if grown \
+        else ((LIVE_USERS + 1,), jnp.int32)
+    ids = ((36_000_000 + 8192 if grown
+            else UNSEEN_RATINGS + HISTORY_PADS[-1],), jnp.int32)
+    packed = ((bucket, LIVE_RANK + 2 + MAX_EXCLUDE), jnp.int32)
+    return tables, runs, ids, packed
+
+
+@pytest.mark.parametrize("path,bucket,pad", sorted(PARENT_LOWERED_SEEN))
+def test_histories_as_published_lower_to_the_parents_text(
+        one_chip, path, bucket, pad):
+    import hashlib
+
+    from tpu_als.serving.engine import (
+        _serve_exact_seen_packed,
+        _serve_int8_seen_packed,
+    )
+
+    tables, runs, ids, packed = _seen_shapes(bucket)
+    if path == "int8":
+        low = _lower(one_chip, _serve_int8_seen_packed, *tables, runs, ids,
+                     packed, k=10, shortlist_k=64, pad=pad)
+    else:
+        low = _lower(one_chip, _serve_exact_seen_packed, tables[0],
+                     tables[3], ((LIVE_ITEMS,), jnp.bool_), runs, ids,
+                     packed, k=10, item_chunk=8192, pad=pad)
+    assert hashlib.sha256(low.as_text().encode()).hexdigest()[:16] \
+        == PARENT_LOWERED_SEEN[path, bucket, pad]
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_the_program_that_excludes_from_histories_that_grow(one_chip, bucket):
+    """``_serve_int8_seen_packed`` over a table laid out to grow
+    (``(start, count)`` in place of ``indptr``) at the live-unseen cell's
+    size and its top rung, 8,192: it compiles, with the one sort of its
+    own, and holds no more than the program at 4,096 over the histories
+    as published plus the wider lists."""
+    from tpu_als.serving.engine import _serve_int8_seen_packed
+
+    def compiled(grown, pad):
+        tables, runs, ids, packed = _seen_shapes(bucket, grown)
+        sh = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+              for s, d in tables]
+        one = lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip)
+        runs = tuple(map(one, runs)) if grown else one(runs)
+        return _serve_int8_seen_packed.lower(
+            *sh, runs, one(ids), one(packed), k=10, shortlist_k=64,
+            pad=pad).compile()
+
+    frozen, grown = compiled(False, 4096), compiled(True, 8192)
+    assert grown.as_text().count(" sort(") \
+        == frozen.as_text().count(" sort(")
+    extra = (grown.memory_analysis().temp_size_in_bytes
+             - frozen.memory_analysis().temp_size_in_bytes)
+    # twice the keys to sort and scatter, the same mask
+    assert extra < 16 * bucket * 8192 + (8 << 20), extra
+
+
+@pytest.mark.parametrize("rows,width", [(8, 4096), (8, 8192), (64, 8192)])
+def test_fold_in_over_whole_histories_at_rank_256(one_chip, rows, width):
+    """The fold-in program at the widths a resident base history brings, up
+    to the most a call gathers (``FOLD_ELEMENTS``): it compiles, in true
+    float32, within 1.5 GB of temporaries."""
+    from tpu_als.core.foldin import _fold_in_jit
+    from tpu_als.core.ratings import row_capacity
+    from tpu_als.ops.solve import DEFAULT_JITTER
+    from tpu_als.stream.microbatch import FOLD_ELEMENTS
+
+    assert rows * width <= max(FOLD_ELEMENTS, 8 * width)
+    c = _compiled(
+        one_chip, _fold_in_jit,
+        ((row_capacity(LIVE_ITEMS), LIVE_RANK), jnp.float32),
+        ((rows, width), jnp.int32), ((rows, width), jnp.float32),
+        ((rows, width), jnp.float32), ((), jnp.float32),
+        implicit_prefs=False, alpha=1.0, nonnegative=False, nnls_sweeps=32,
+        jitter=DEFAULT_JITTER, backend="xla")
+    assert c.memory_analysis().temp_size_in_bytes < 1.5 * (1 << 30)
+    assert 'op_name="jit(_fold_in_jit)/live.foldin.gram/' in c.as_text()
+
+
+def test_the_history_writes_are_in_place_at_the_cells_size(one_chip):
+    """``_append_runs`` and ``_move_run`` donate what they write into:
+    aliased to the result in the compiled program, no copy of the 144 MB
+    table; their operations carry the scope ``live.publish.history``."""
+    from tpu_als.core.ratings import row_capacity
+    from tpu_als.serving.engine import _append_runs, _move_run
+
+    cap, size = row_capacity(LIVE_USERS), 36_000_000 + 8192
+    tables = (f"s32[{cap}]", f"s32[{size}]")
+    for fn, shapes, statics in (
+            (_append_runs, (((cap,), jnp.int32), ((cap,), jnp.int32),
+                            ((size,), jnp.int32), ((5, 8), jnp.int32)),
+             {}),
+            (_move_run, (((size,), jnp.int32), ((), jnp.int32),
+                         ((), jnp.int32)), {"width": 8192})):
+        c = _compiled(one_chip, fn, *shapes, **statics)
+        text = c.as_text()
+        assert "input_output_alias" in text
+        assert not [ln for ln in text.splitlines()
+                    if (" copy(" in ln or " copy-start(" in ln)
+                    and any(t in ln for t in tables)], fn
+        assert "live.publish.history" in text
+        assert c.memory_analysis().temp_size_in_bytes < (1 << 20)

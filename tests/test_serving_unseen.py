@@ -370,12 +370,15 @@ def test_what_cannot_take_histories_yet_says_so(factors):
     mesh.publish(U, V[:4096])
     with pytest.raises(NotImplementedError, match="mesh"):
         mesh.submit(0, exclude=[1])
+    # a generation with histories takes publish_update since PR 42; what
+    # it refuses is a catalog that moves (tests/test_live_unseen.py)
     eng = ServingEngine(k=K, buckets=(8,), shortlist_k=SK)
     eng.publish(U, V, user_seen=hist)
     with pytest.raises(NotImplementedError, match="_int8_topk_delta"):
-        eng.warmup_live()
-    with pytest.raises(NotImplementedError, match="history"):
-        eng.publish_update(U, V, touched_users=[0])
+        eng.publish_update(U, V, touched_items=[0])
+    eng.warmup_live()
+    assert eng.published_index.delta_slots == 0     # no segment for it
+    assert eng.publish_update(U, V, touched_users=[0]) == (2, "retag")
     live = ServingEngine(k=K, buckets=(8,), shortlist_k=SK)
     live.publish(U, V)
     live.warmup_live()

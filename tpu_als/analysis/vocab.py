@@ -112,6 +112,7 @@ PROFILER_SPAN_TUPLES = (
     ("PIPE_SPAN_KEYS", ("serving",)),
     ("LIVE_BATCH_SPAN_KEYS", ("live",)),
     ("LIVE_ITEM_SPAN_KEYS", ("live", "serving")),
+    ("LIVE_HISTORY_SPAN_KEYS", ("serving",)),
     ("LIVE_FOLDIN_SPAN_KEYS", ("stream",)),
 )
 
@@ -349,7 +350,7 @@ def check_tenant_vocabulary(repo=REPO):
         | {"tenant", "trace_id", "trace_ids"}
     for attr in ("SERVE_SPAN_KEYS", "SERVE_BATCH_SPAN_KEYS",
                  "LIVE_SPAN_KEYS", "LIVE_BATCH_SPAN_KEYS",
-                 "LIVE_ITEM_SPAN_KEYS"):
+                 "LIVE_ITEM_SPAN_KEYS", "LIVE_HISTORY_SPAN_KEYS"):
         overlap = sorted(set(getattr(schema, attr, ())) & reserved)
         if overlap:
             errors.append(

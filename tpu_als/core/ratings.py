@@ -197,6 +197,30 @@ def pad_for(n):
     return pads_up_to(n)[-1]
 
 
+def growth_room(n):
+    """How many more a list of ``n`` has room for where lists GROW between
+    refits (a resident rating history, the run of it on the device): an
+    eighth of it, 8 at least (``n``: a count, or an array of them)."""
+    return np.maximum(8, np.asarray(n) >> 3)
+
+
+def growth_pads(longest):
+    """:func:`pads_up_to` for lists that grow from at most ``longest``:
+    where the longest with its :func:`growth_room` no longer fits the top
+    rung, ONE rung more, the power of two that holds it (8,192 above a
+    longest of 4,096 — the ladder's own next rung, 32,768, would have
+    every program that rides it pay for four times the padding)."""
+    pads = pads_up_to(longest)
+    most = int(longest) + int(growth_room(int(longest)))
+    return pads if most <= pads[-1] else pads + (_next_pow2(most),)
+
+
+def rung_for(n, pads):
+    """The least of ``pads`` that holds ``n``; past them all the plain
+    ladder's (:func:`pad_for`: a size nothing was warmed for)."""
+    return next((p for p in pads if p >= n), None) or pad_for(n)
+
+
 # what the live path warms by default: up to 512 touched entities a
 # micro-batch (the live updater's max_batch is 256), of up to 512 ratings
 LIVE_PADS = pads_up_to(512)

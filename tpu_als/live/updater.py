@@ -27,7 +27,10 @@ single measurable quantity:
    (``stream/microbatch.py``); no admitted rating is dropped from a
    history.
 5. **Publish.**  ``ServingEngine.publish_update`` swaps the new
-   generation in atomically — retag for user-only batches, an
+   generation in atomically (on an engine that holds its users'
+   histories with the ids the batch's ratings add to them,
+   ``FoldInServer.last_appended``: what was just rated leaves that
+   user's answers with the publish that folds it in) — retag for user-only batches, an
    O(touched) delta re-quantization for item batches — never a full
    O(catalog) rebuild while the live index is healthy.  The touched
    user rows are written into the device's table in place (the table
@@ -47,6 +50,10 @@ also (``obs.schema.LIVE_ITEM_SPAN_KEYS``) ``live.batch.foldin.users``
 and ``live.batch.foldin.items`` inside the fold,
 ``live.batch.publish.compact`` (the engine's) inside a publish that
 compacts, and the stats ``items``, ``new_items``, ``segment_rows``.
+On an engine that holds its users' histories also
+(``obs.schema.LIVE_HISTORY_SPAN_KEYS``) ``live.batch.publish.history``
+(the engine's: the appended ids planned and uploaded; stats ``ids``,
+``users``, ``relocated``) inside every publish.
 Every fold writes ``live.batch.foldin.readback`` around its program's
 call and the blocking read of its rows
 (``obs.schema.LIVE_FOLDIN_SPAN_KEYS``, stream/microbatch.py), and
@@ -167,17 +174,31 @@ class LiveUpdater:
         """Have the engine run the row writes this updater's publishes
         will make (up to ``max_batch`` users a publish; with
         ``fold_items`` as many items, into a catalog with spare rows and
-        a segment of fixed size: ``ServingEngine.warmup_live``), so that
-        none compiles or loads under traffic, then start the loop."""
+        a segment of fixed size; on an engine that holds its users'
+        histories as many ids appended to them, into a table laid out to
+        grow: ``ServingEngine.warmup_live``), so that none compiles or
+        loads under traffic, then start the loop."""
         if self._thread is not None:
             raise RuntimeError("updater already started")
+        if self.fold_items and self._histories:
+            raise NotImplementedError(
+                "fold_items on an engine whose generation holds users' "
+                "histories: its catalog cannot move yet (the scoring "
+                "program with a delta segment takes no per-row exclusion)")
         self.engine.warmup_publish(self.max_batch)
-        if self.fold_items:
+        if self.fold_items or self._histories:
             self.engine.warmup_live(max_rows=self.max_batch)
         self._thread = threading.Thread(
             target=self._run, name="tpu-als-live", daemon=True)
         self._thread.start()
         return self
+
+    @property
+    def _histories(self):
+        """Whether the engine answers without what its users have rated
+        (``publish(user_seen=...)``): every publish then hands it the
+        ids its ratings add to their users' histories."""
+        return getattr(self.engine, "holds_histories", False)
 
     def stop(self, drain_timeout_s=10.0):
         """Close admission, drain the queue, join the loop."""
@@ -314,11 +335,20 @@ class LiveUpdater:
         tp = time.perf_counter()
         with TraceAnnotation("live.batch.publish") as span:
             mark = cpu_mark()
-            # the rows the fold moved, and nothing else of either table
+            # the rows the fold moved, and nothing else of either table;
+            # with them, where the engine keeps the users' histories, the
+            # ids these ratings add to them (one publish, one generation)
+            grown = {}
+            if self._histories:
+                who, what = self.foldin.last_appended
+                who, what = (m._user_map.to_dense(who),
+                             m._item_map.to_dense(what))
+                ok = (who >= 0) & (what >= 0)
+                grown["seen_appended"] = (who[ok], what[ok])
             seq, mode = self.engine.publish_update(
                 m._U, m._V, touched_items=touched_item_rows,
                 touched_users=m._user_map.to_dense(touched_users),
-                trace=ctxs)
+                trace=ctxs, **grown)
             publish_cpu = stamp_cpu(span, mark)
         publish_s = time.perf_counter() - tp
         sizes = {}

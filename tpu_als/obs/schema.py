@@ -228,6 +228,33 @@ METRICS = {
         "histogram", "rows",
         "entities solved per FoldInServer micro-batch (the padded "
         "bucket is the next pow2 above this)"),
+    "foldin.history_width": (
+        "histogram", "ratings",
+        "padded width of each run of the fold-in program (the rung that "
+        "holds the longest rating history among the entities it solves: "
+        "8 / 64 / 512 / 4096 ...; a server with a resident base history "
+        "folds over ALL of a user's ratings, so its widths follow the "
+        "histories, not the batch), labeled side=user|item"),
+    "live.history_appended_ids": (
+        "counter", "ids",
+        "catalog ids ServingEngine.publish_update appended to its users' "
+        "resident histories (seen_appended: one per folded rating of an "
+        "item its user had not rated), swapped in with the rows of the "
+        "same publish"),
+    "live.history_h2d_bytes": (
+        "counter", "bytes",
+        "bytes ServingEngine.publish_update sent host -> device for the "
+        "HISTORIES: the appended ids, their positions and their users' "
+        "starts and counts (padded to 8 / 64 / 512 entries) and two "
+        "scalars a relocated run — O(ids appended), never a history; the "
+        "8 bytes an id of a table laid out anew are not in it (a warning "
+        "says when that happens under traffic)"),
+    "live.history_relocations": (
+        "counter", "runs",
+        "histories whose run on the device was full when a publish "
+        "appended to it and was moved to the table's free room first (a "
+        "copy of the run on the device, O(history): the slow path of "
+        "the grown layout, serving.engine._Seen)"),
     "train.stage_seconds": (
         "histogram", "seconds",
         "fence-timed seconds of one attributed ALS stage (obs.trace."
@@ -282,6 +309,7 @@ LABELS = {
     "serve.request_seconds": ("strategy",),
     "foldin.update_seconds": ("side",),
     "foldin.batch_rows": ("side",),
+    "foldin.history_width": ("side",),
     "serving.enqueue_seconds": ("tenant",),
     "serving.score_seconds": ("path", "tenant"),
     "serving.e2e_seconds": ("tenant",),
@@ -307,6 +335,9 @@ LABELS = {
     "live.catalog_h2d_bytes": ("tenant",),
     "live.items_appended": ("tenant",),
     "live.events_waiting": ("tenant",),
+    "live.history_appended_ids": ("tenant",),
+    "live.history_h2d_bytes": ("tenant",),
+    "live.history_relocations": ("tenant",),
     "serving.catalog_writes": ("how", "tenant"),
     "tenancy.served_rows": ("tenant",),
     "tenancy.batch_errors": ("tenant",),
@@ -471,6 +502,18 @@ LIVE_ITEM_SPAN_KEYS = (
     "live.batch.foldin.items",    # FoldInServer.update_items
     "live.batch.publish.compact",  # the segment folded into the base
 )
+# what a publish on a generation that holds users' histories writes
+# besides, inside ``live.batch.publish`` (serving/engine.py
+# ``_append_history``; none of it on an engine without histories): the
+# ids the publish adds to its users' histories planned and uploaded — the
+# write itself is dispatched with the row write, under the table lock —
+# with the stats ``ids``, ``users``, ``relocated`` and, under a profiler,
+# ``cpu_us`` / ``wall_us``.  In the device programs that write them
+# (``_append_runs``, ``_move_run``) the scope LIVE_HISTORY_SCOPE
+LIVE_HISTORY_SPAN_KEYS = (
+    "live.batch.publish.history",
+)
+LIVE_HISTORY_SCOPE = "live.publish.history"
 # what every fold writes, items or none, inside ``live.batch.foldin``
 # (with ``fold_items`` inside ``.foldin.users`` / ``.foldin.items``):
 # stream/microbatch.py, which the updater drives.  ``live.batch``,

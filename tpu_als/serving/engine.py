@@ -96,7 +96,12 @@ stack plugs into:
   is folded back into the base arrays, IN PLACE (they are donated:
   :meth:`ServingEngine._compact_live`), when it crosses the
   planner-resolved compaction threshold: ONE generation of the catalog
-  on the device, whatever is published.  Every
+  on the device, whatever is published.  On a generation that holds
+  its users' HISTORIES (``publish(user_seen=...)``) a publish also
+  appends the ids its ratings add to them (``seen_appended``), behind
+  their users' runs on the device, in place, and the rows and the ids
+  become servable as ONE generation (:class:`_Seen`,
+  :func:`_append_runs`); such a generation's catalog does not move.  Every
   mode lands in the ``serving.publish_seconds`` histogram so the
   O(touched)-vs-O(catalog) publish cost claim is measured, not assumed.
 - **Fault points.**  ``serving.publish`` fires inside publish (corrupt
@@ -175,6 +180,7 @@ import functools
 import queue
 import threading
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -185,12 +191,16 @@ from tpu_als import obs
 from tpu_als.core.foldin import place_rows
 from tpu_als.core.ratings import (
     LIVE_PADS,
+    growth_pads,
+    growth_room,
     pad_for,
     pads_up_to,
     row_capacity,
+    rung_for,
 )
 from tpu_als.obs import tracing
 from tpu_als.obs.schema import (
+    LIVE_HISTORY_SCOPE,
     SERVE_BATCH_SPAN_KEYS,
     SERVE_EXCLUDE_SCOPE,
     SERVE_MESH_SCOPES,
@@ -237,35 +247,91 @@ MAX_EXCLUDE = 64
 
 
 class _Seen:
-    """The users' histories of one generation: CSR over catalog ids on
-    the device (``indptr int32[n_users + 1]``, ``indices int32[nnz]``; a
-    row's ids ascending, none twice), and on the host what staging needs
-    of them: each user's count (``lengths``) and the ladder of history
-    pads the scoring programs are compiled for (``pads``: 64, 512, ...
-    up to the longest history) — a batch rides the least that holds its
-    longest."""
+    """The users' histories of one generation: catalog ids on the device,
+    each user's a contiguous run of ``indices``, and on the host what
+    staging needs of them: each user's count (``lengths``) and the ladder
+    of history pads the scoring programs are compiled for (``pads``: 64,
+    512, ... up to the longest history) — a batch rides the least that
+    holds its longest.
 
-    __slots__ = ("indptr", "indices", "lengths", "pads")
+    ``runs`` says where the runs lie, in one of two layouts.  AS
+    PUBLISHED: the CSR's ``indptr int32[n_users + 1]``, run after run
+    with no room between them (a row's ids ascending, none twice):
+    nothing can be appended.  GROWN (:meth:`ServingEngine._lay_out`, which
+    ``warmup_live`` calls): ``(start, count)``, ``int32`` of the user
+    table's row capacity each, every run with room behind it for an
+    eighth more ids (``core.ratings.growth_room``) and free room behind
+    the last run, and ``room`` the host's account of it
+    (:class:`_Room`).  A publish then writes the ids its ratings add
+    behind their users' runs IN PLACE (:func:`_append_runs`; appended
+    ids stand in arrival order) and a run that is full moves to the free
+    room first (:func:`_move_run`); ``lengths`` is shared by the
+    generations of one layout and written under ``_table_lock``."""
 
-    def __init__(self, indptr, indices, lengths, pads):
-        self.indptr, self.indices = indptr, indices
-        self.lengths, self.pads = lengths, pads
+    __slots__ = ("runs", "indices", "lengths", "pads", "room")
 
-    def pad_for(self, live):
-        """The history pad of a batch of tickets: its by-id requests'
-        longest history, up the ladder."""
-        ids = [t.payload for t in live
-               if isinstance(t.payload, (int, np.integer))]
-        if self.lengths is None or not ids:
-            return self.pads[0]
-        longest = int(self.lengths[ids].max())
+    def __init__(self, runs, indices, lengths, pads, room=None):
+        self.runs, self.indices = runs, indices
+        self.lengths, self.pads, self.room = lengths, pads, room
+
+    def lengths_of(self, live):
+        """How many ids each ticket of a batch loses to its user's
+        history (a request by vector none), or ``None`` for a table that
+        holds no history."""
+        if self.lengths is None:
+            return None
+        return [int(self.lengths[t.payload])
+                if isinstance(t.payload, (int, np.integer)) else 0
+                for t in live]
+
+    def pad_for(self, lengths):
+        """The history pad of a batch whose tickets' histories are
+        ``lengths`` long (:meth:`lengths_of`): its longest, up the
+        ladder."""
+        longest = max(lengths or (0,))
         return next(p for p in self.pads if p >= longest)
 
 
-def history_pads(longest):
+class _Append(NamedTuple):
+    """What one publish adds to a grown table of histories
+    (:meth:`ServingEngine._plan_append`): :func:`_append_runs`' ``plan``
+    on the device, the full runs to move first as ``(old, new,
+    width)``, the touched users with their runs' starts, room and
+    lengths afterwards, where the free room then begins, the bytes
+    sent."""
+
+    args: object
+    moves: list
+    users: np.ndarray
+    start: np.ndarray
+    cap: np.ndarray
+    lengths: np.ndarray
+    free: int
+    sent: int
+
+
+class _Room:
+    """The host's account of a grown table of histories (:class:`_Seen`):
+    where each user's run starts and how many ids it has room for
+    (``start``, ``cap``: one entry a row of the user table, spare rows
+    included — a user appended to the table has an empty history until
+    a publish gives it a run), where the free room begins (``free``) and
+    where it ends (``size``; behind it the table has its longest pad of
+    spare ids, so that no slice of a run is clamped)."""
+
+    __slots__ = ("start", "cap", "free", "size")
+
+    def __init__(self, start, cap, free, size):
+        self.start, self.cap, self.free, self.size = start, cap, free, size
+
+
+def history_pads(longest, grows=False):
     """The ladder of history pads for histories of up to ``longest``
-    ids: 64, 512, 4096, ... (``core.ratings.pads_up_to`` from 64)."""
-    return tuple(p for p in pads_up_to(max(int(longest), MAX_EXCLUDE))
+    ids: 64, 512, 4096, ... (``core.ratings.pads_up_to`` from 64);
+    ``grows``: with the rung above them that histories growing from
+    there need (``core.ratings.growth_pads``: 8,192 above 4,096)."""
+    longest = max(int(longest), MAX_EXCLUDE)
+    return tuple(p for p in (growth_pads if grows else pads_up_to)(longest)
                  if p >= MAX_EXCLUDE)
 
 
@@ -430,58 +496,108 @@ def _serve_int8_delta_packed(U, Vq, sv, V, valid, drows, dVq, dsv, dV,
     return _pack_response(s, ix)
 
 
-def _select_seen(indptr, indices, packed, rank, pad):
+def _select_seen(runs, indices, packed, rank, pad):
     """``(int32[B, pad], int32[B, MAX_EXCLUDE])``: what each slot of a
     staged batch is not to be answered with — the first ``pad`` ids of
     the published history of a by-id request's user (a batch rides a pad
     that holds its longest; a request by vector has none), and the
     request's own list as it rode the staging layout's last
     ``MAX_EXCLUDE`` columns; each padded with ``NOT_AN_ID``: the two
-    lists ``ops.topk.excluded_mask`` takes.
+    lists ``ops.topk.excluded_mask`` takes.  ``runs``: where the
+    histories lie in ``indices`` (:class:`_Seen`) — the CSR's ``indptr``
+    as published, or ``(start, count)`` of histories that grow: what
+    differs is where a run's first id and its count are read.
     Selected on the device, as :func:`_select_packed` selects the user
     rows: the histories never cross host→device after their publish."""
     with jax.named_scope(SERVE_EXCLUDE_SCOPE):
-        ids = jnp.clip(packed[:, rank], 0, indptr.shape[0] - 2)
-        first = jnp.take(indptr, ids)
-        count = jnp.where(packed[:, rank + 1] != 0, 0,
-                          jnp.take(indptr, ids + 1) - first)
-        # a history is a contiguous run of the CSR: one slice of ``pad``
+        grown = isinstance(runs, tuple)
+        users = runs[0].shape[0] if grown else runs.shape[0] - 1
+        ids = jnp.clip(packed[:, rank], 0, users - 1)
+        first = jnp.take(runs[0] if grown else runs, ids)
+        count = jnp.where(
+            packed[:, rank + 1] != 0, 0,
+            jnp.take(runs[1], ids) if grown
+            else jnp.take(runs, ids + 1) - first)
+        # a history is a contiguous run of the table: one slice of ``pad``
         # ids a row (the table ends in ``pad`` spare ids, so none is
         # clamped), not ``pad`` scalar gathers (0.23 ms a batch of 8 rows
         # of 4,096 on the v5e, and the whole 71 MB table fetched before
         # them: PERF.md section 6, PR 39)
-        runs = jax.vmap(lambda at: jax.lax.dynamic_slice(
+        rows = jax.vmap(lambda at: jax.lax.dynamic_slice(
             indices, (at,), (pad,)))(first)
         j = jnp.arange(pad, dtype=jnp.int32)[None, :]
-        history = jnp.where(j < count[:, None], runs, NOT_AN_ID)
+        history = jnp.where(j < count[:, None], rows, NOT_AN_ID)
         return history, packed[:, rank + 2:]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "shortlist_k", "pad"))
-def _serve_int8_seen_packed(U, Vq, sv, V, valid, indptr, indices, packed,
+def _serve_int8_seen_packed(U, Vq, sv, V, valid, runs, indices, packed,
                             *, k, shortlist_k, pad):
     """:func:`_serve_int8_packed` for a batch that excludes: the staging
     layout is ``MAX_EXCLUDE`` columns wider (the requests' own lists),
-    the users' histories are taken from the published CSR
-    (:func:`_select_seen`), and the scoring takes them out
-    (``ops.topk.excluded_mask``'s rule).  One program a bucket and
-    history pad."""
+    the users' histories are taken from the published table
+    (:func:`_select_seen`; ``runs``: in either layout), and the scoring
+    takes them out (``ops.topk.excluded_mask``'s rule).  One program a
+    bucket and history pad."""
     Ub = _select_packed(U, packed)
-    seen = _select_seen(indptr, indices, packed, U.shape[1], pad)
+    seen = _select_seen(runs, indices, packed, U.shape[1], pad)
     s, ix = _int8_topk(Ub, Vq, sv, V, valid, k=k, shortlist_k=shortlist_k,
                        seen=seen)
     return _pack_response(s, ix)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "item_chunk", "pad"))
-def _serve_exact_seen_packed(U, V, valid, indptr, indices, packed, *, k,
+def _serve_exact_seen_packed(U, V, valid, runs, indices, packed, *, k,
                              item_chunk, pad):
     """The exact fallback of a batch that excludes."""
     Ub = _select_packed(U, packed)
-    seen = _select_seen(indptr, indices, packed, U.shape[1], pad)
+    seen = _select_seen(runs, indices, packed, U.shape[1], pad)
     s, ix = chunked_topk_scores(Ub, V, valid, k, item_chunk=item_chunk,
                                 seen=seen)
     return _pack_response(s, ix)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+def _append_runs(start, count, indices, plan):
+    """The ids a publish adds to its users' histories, written IN PLACE
+    into a grown table (:class:`_Seen`; all three arrays are donated, as
+    the user table is to :func:`_scatter_users`, and in the same order
+    on the device).  ``plan``, ``int32[5, pad]``, ONE upload (as five
+    arrays it cost the publish 3.1 ms on the chip's host, as one 1.0:
+    PERF.md section 5, PR 42): the touched
+    ``users`` with their ``starts`` and ``counts``, and the ``ids`` with
+    the positions ``at`` they go to — behind their users' runs, in room
+    no count reaches yet.  Everything is padded up ``pad_for``'s ladder
+    with entries outside the arrays (``mode='drop'``): few programs, and
+    a publish sends O(ids appended)."""
+    users, starts, counts, at, ids = plan
+    with jax.named_scope(LIVE_HISTORY_SCOPE):
+        return (start.at[users].set(starts, mode="drop"),
+                count.at[users].set(counts, mode="drop"),
+                indices.at[at].set(ids, mode="drop"))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("width",))
+def _move_run(indices, old, new, *, width):
+    """A run that is full moved to the free room of a grown table, IN
+    PLACE: the ``width`` ids from ``old`` (a history pad that holds the
+    run; what it copies beyond the run lands in room nothing counts yet)
+    written from ``new`` on.  One program a pad, O(run) on the device,
+    two scalars from the host."""
+    with jax.named_scope(LIVE_HISTORY_SCOPE):
+        return jax.lax.dynamic_update_slice(
+            indices, jax.lax.dynamic_slice(indices, (old,), (width,)),
+            (new,))
+
+
+@functools.partial(jax.jit, static_argnames=("size",))
+def _spread_runs(indices, src, dst, *, size):
+    """A table of ``size`` ids (``NOT_AN_ID`` where no run lies) with
+    ``indices[src]`` at ``dst``: the histories laid out anew, each run
+    with room behind it (:meth:`ServingEngine._lay_out`)."""
+    return jnp.full((size,), NOT_AN_ID, jnp.int32).at[dst].set(
+        jnp.take(indices, src), unique_indices=True,
+        indices_are_sorted=True)       # the runs lie in the users' order
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -788,7 +904,7 @@ class ServingEngine:
         return ("replaced",) + self._place_users(prev, U)
 
     def _swap(self, how, users, seq, n_users, V, valid, index, n_items,
-              host=None, items=None, seen=None):
+              host=None, items=None, seen=None, appended=None):
         """Install the next generation, the one place that assigns
         ``_model`` (but for :meth:`_compact_live`, which installs the
         same generation compacted); returns ``how`` it got its user
@@ -796,7 +912,13 @@ class ServingEngine:
         that table, or with ``how == "inplace"`` the ``(rows, vals)`` to
         write into the live one first.  ``items``: the ``(rows, vals,
         ok)`` to write into the live generation's OWN catalog first
-        (:func:`_scatter_items`; ``V``/``valid`` are then ignored).  The
+        (:func:`_scatter_items`; ``V``/``valid`` are then ignored).
+        ``appended``: what :meth:`_plan_append` made of the ids this
+        generation adds to the live one's histories, written behind
+        their users' runs first (:meth:`_write_history`; ``seen`` is then
+        ignored) — the rows and the ids of one publish go in under one
+        hold of the lock and are one ``_Published``: no batch is scored
+        from a row that knows a rating and a history that lacks it.  The
         donating calls and the swap
         are one step under ``_table_lock``: the engine thread reads the
         live generation and scores against its tables under the same
@@ -833,6 +955,8 @@ class ServingEngine:
             if items is not None:
                 V, valid = _scatter_items(self._model.V, self._model.valid,
                                           *items)
+            if appended is not None:
+                seen = self._write_history(self._model.seen, appended)
             self._model = _Published(seq, users, n_users, V, valid, index,
                                      n_items, seen)
         return how
@@ -931,6 +1055,171 @@ class ServingEngine:
                             np.full(pads[-1], NOT_AN_ID, np.int32)])))
         return _Seen(*dev, lengths.astype(np.int32), pads)
 
+    def _lay_out(self, seen, rows, more=0):
+        """``seen`` laid out anew as histories that GROW (:class:`_Seen`),
+        for a user table of ``rows`` rows: every run with room behind it
+        for an eighth more ids, 8 at least (``core.ratings.growth_room``),
+        free room behind the last run (an eighth of the table, 65,536 ids
+        at least, and ``more``), the ladder of pads with the rung above
+        the longest history that its growth needs, and as many spare ids
+        at the end.  The ids move on the device (:func:`_spread_runs`;
+        the host sends where from and where to, 8 bytes an id, once);
+        O(all the histories), so it is ``warmup_live``'s to call before
+        the traffic — under it only where the room laid out here is used
+        up, with a warning."""
+        n = len(seen.lengths)
+        lengths = np.zeros(rows, np.int32)
+        lengths[:n] = seen.lengths
+        cap = lengths + growth_room(lengths)
+        cap[n:] = 0         # a spare row's user gets a run with its first id
+        start = np.zeros(rows, np.int64)
+        np.cumsum(cap[:-1], out=start[1:])
+        held = int(start[-1] + cap[-1])
+        size = held + max(1 << 16, held >> 3) + int(more)
+        pads = history_pads(lengths.max(initial=0), grows=True)
+        if size + pads[-1] >= NOT_AN_ID:
+            raise ValueError(f"{held} ids of history with room to grow: "
+                             "more than int32 positions hold")
+        old = (np.asarray(seen.runs)[:-1] if seen.room is None
+               else seen.room.start[:n])
+        user = np.repeat(np.arange(n), seen.lengths)
+        within = (np.arange(len(user))
+                  - np.repeat(np.cumsum(seen.lengths) - seen.lengths,
+                              seen.lengths))
+        src, dst = ((a[user] + within).astype(np.int32)
+                    for a in (old, start))
+        dev = jax.device_put((start.astype(np.int32), lengths.copy()))
+        indices = _spread_runs(seen.indices, *jax.device_put((src, dst)),
+                               size=size + pads[-1])
+        return _Seen(dev, indices, lengths, pads,
+                     _Room(start, cap, held, size))
+
+    def _append_history(self, n_users, appended):
+        """The history half of a ``publish_update`` on a generation that
+        holds histories, up to the write (which is :meth:`_swap`'s): the
+        plan of :meth:`_plan_append` for ``appended`` (``None``: no id,
+        the table carried as it is), its uploads made, inside the span
+        ``live.batch.publish.history`` and counted.  Where the table has
+        no room for the plan — nobody laid it out to grow, or the room
+        is used up — it is laid out anew first (:meth:`_lay_out`: O(all
+        the histories), shapes change and the pinned programs go stale;
+        warned)."""
+        with TraceAnnotation("live.batch.publish.history") as span:
+            mark = cpu_mark()
+            m = self._model
+            if appended is None:
+                appended = ((), ())
+            plan = (None if m.seen.room is None else self._plan_append(
+                m.seen, n_users, m.n_items, appended))
+            if plan is None:
+                obs.emit("warning", what="serving.publish_update",
+                         reason="the histories have no room for this "
+                                "publish as they are laid out (warmup_live "
+                                "lays them out to grow): laid out anew")
+                longest = int(m.seen.lengths.max(initial=0))
+                with self._table_lock:
+                    m = self._model
+                    self._model = _Published(
+                        m.seq, m.U, m.n_users, m.V, m.valid, m.index,
+                        m.n_items, self._lay_out(
+                            m.seen, max(n_users, int(m.U.shape[0])),
+                            more=8 * (longest + len(appended[0]))))
+                plan = self._plan_append(self._model.seen, n_users,
+                                         m.n_items, appended)
+                if plan is None:
+                    raise ValueError(
+                        "seen_appended: more ids for one user than a "
+                        "table laid out anew has room for")
+            ids, moves = len(np.ravel(appended[0])), len(plan.moves)
+            span.set_metadata(ids=ids, users=len(plan.users),
+                              relocated=moves)
+            stamp_cpu(span, mark)
+        obs.counter("live.history_appended_ids", ids, **self._labels)
+        obs.counter("live.history_relocations", moves, **self._labels)
+        obs.counter("live.history_h2d_bytes", plan.sent, **self._labels)
+        return plan
+
+    def _plan_append(self, seen, n_users, n_items, appended):
+        """What appending ``appended`` — ``(users, items)``: rows of the
+        user table and the catalog ids their ratings add, any order, a
+        user any number of times — to the grown table ``seen`` takes
+        (:class:`_Append`, for :meth:`_write_history`), or ``None``
+        where the table has no room
+        for it as it is laid out (too few rows, the free room used up,
+        a history past the longest pad).  O(ids appended) on the host
+        and on the link; writes nothing."""
+        users = np.asarray(appended[0], dtype=np.int64).ravel()
+        items = np.asarray(appended[1], dtype=np.int64).ravel()
+        if users.shape != items.shape or (users.size and not (
+                0 <= users.min() and users.max() < n_users
+                and 0 <= items.min() and items.max() < n_items)):
+            raise ValueError(
+                f"seen_appended: {users.size} users and {items.size} items, "
+                f"to lie in [0, {n_users}) and [0, {n_items})")
+        room = seen.room
+        if n_users > len(room.start):
+            return None
+        order = np.argsort(users, kind="stable")
+        users, items = users[order], items[order]
+        uniq, first, added = np.unique(users, return_index=True,
+                                       return_counts=True)
+        had = seen.lengths[uniq].astype(np.int64)
+        start, cap, free = room.start[uniq], room.cap[uniq], room.free
+        moves = []
+        for j in np.flatnonzero(had + added > cap):
+            # the run is full (a new user's: not there yet): to the free
+            # room, with room to grow again
+            need = int(had[j] + added[j])
+            cap[j] = need + int(growth_room(need))
+            if had[j]:
+                moves.append((int(start[j]), free,
+                              rung_for(int(had[j]), seen.pads)))
+            start[j], free = free, free + int(cap[j])
+        if free > room.size or (had + added).max(initial=0) > seen.pads[-1]:
+            return None
+        n = len(users)
+        plan = self._no_append(seen, pad_for(n))
+        plan[0, :len(uniq)], plan[1, :len(uniq)] = uniq, start
+        plan[2, :len(uniq)] = had + added
+        plan[3, :n] = (np.repeat(start + had, added) + np.arange(n)
+                       - np.repeat(first, added))
+        plan[4, :n] = items
+        return _Append(jax.device_put(plan), moves, uniq, start, cap,
+                       had + added, free,
+                       sent=plan.nbytes + 8 * len(moves))
+
+    @staticmethod
+    def _no_append(seen, pad):
+        """:func:`_append_runs`' ``plan`` of ``pad`` entries that writes
+        nothing: every user and every position outside its array."""
+        plan = np.zeros((5, pad), np.int32)
+        plan[0] = len(seen.room.start)
+        plan[3] = seen.room.size + seen.pads[-1]
+        return plan
+
+    def _write_history(self, seen, plan):
+        """The next generation's histories: ``plan``
+        (:meth:`_plan_append`) written into the live one's IN PLACE —
+        the full runs moved, then the ids and their users' starts and
+        counts, all donated and in dispatch order — and the host's
+        account brought up to it.  Under ``_table_lock``
+        (:meth:`_swap`); the calls are asynchronous."""
+        indices, room = seen.indices, seen.room
+        try:
+            for old, new, width in plan.moves:
+                indices = _move_run(indices, old, new, width=width)
+            runs = _append_runs(*seen.runs, indices, plan.args)
+        except Exception as e:
+            obs.emit("warning", what="serving.publish_update",
+                     reason="history write failed "
+                            f"({type(e).__name__}: {e}): NO histories "
+                            "until the next publish, every batch fails")
+            raise
+        room.start[plan.users], room.cap[plan.users] = plan.start, plan.cap
+        room.free = plan.free
+        seen.lengths[plan.users] = plan.lengths
+        return _Seen(runs[:2], runs[2], seen.lengths, seen.pads, room)
+
     def _without_history(self):
         """The empty table of histories: what a batch of an engine that
         published none rides when a request brings a list of its own."""
@@ -962,9 +1251,12 @@ class ServingEngine:
         the exact fallback alike.  ``None`` publishes none: the engine
         then compiles and runs what it did before it knew of histories.
         Not yet with a mesh (``serving.index._shard_score`` takes no
-        per-row mask: each shard would mask its own ids) — refused here,
-        as :meth:`warmup_live` and :meth:`publish_update` refuse a
-        generation that holds histories.
+        per-row mask: each shard would mask its own ids) — refused here.
+        The histories published here lie run after run with no room
+        between them; :meth:`warmup_live` lays them out to GROW, after
+        which :meth:`publish_update` appends to them
+        (``seen_appended``).  What such a generation still refuses is a
+        catalog that moves (``publish_update(touched_items=...)``).
         """
         t0 = time.perf_counter()
         mode = faults.check("serving.publish")
@@ -1061,7 +1353,8 @@ class ServingEngine:
         return index, items, sent, compacted
 
     def publish_update(self, U, V, *, touched_items=None,
-                       touched_users=None, item_valid=None, trace=None):
+                       touched_users=None, item_valid=None, trace=None,
+                       seen_appended=None):
         """Incremental publish after a fold-in: O(touched rows), not
         O(catalog).  Returns ``(seq, mode)``.
 
@@ -1109,15 +1402,47 @@ class ServingEngine:
         ``serving.catalog_writes{how=carried|delta|compact|replaced}``
         and ``catalog=`` on the ``serving_publish`` event say what
         became of the catalog.
+
+        ``seen_appended``: on a generation that holds users' histories
+        (``publish(user_seen=...)``), ``(users, items)`` — rows of ``U``
+        and the catalog ids that the ratings folded into this publish
+        add to those users' histories (a rating of an item its user had
+        rated before adds none; the engine checks no pair against the
+        history, and an id that stands twice is excluded once).  They
+        are written behind their users' runs on the device, in place,
+        and swapped in WITH the rows (:meth:`_swap`): a request
+        dequeued after this publish is answered from the new row and
+        without the new id, one before it from the old row with the old
+        history, none from one of each.  O(ids appended) on the host and
+        on the link (``live.history_h2d_bytes``,
+        ``live.history_appended_ids``; the span
+        ``live.batch.publish.history``), no array changes shape; a user
+        whose run is full is moved to free room first
+        (``live.history_relocations``).  A user appended to the table
+        starts with an empty history.  The catalog of such a generation
+        does not move: ``touched_items``, a catalog of another size or
+        ``item_valid`` raise ``NotImplementedError`` before anything is
+        written (the program with a delta segment,
+        ``serving.index._int8_topk_delta``, takes no per-row
+        exclusion), and so does ``seen_appended`` on a generation that
+        holds no histories.  The histories are laid out to grow by
+        :meth:`warmup_live`; on an engine nobody warmed up the first
+        such publish does it, under the traffic, with a warning.
         """
-        if self._model is not None and self._model.seen is not None:
+        prev = self._model
+        if prev is not None and prev.seen is not None and (
+                touched_items is not None or item_valid is not None
+                or int(np.shape(V)[0]) != prev.n_items):
             raise NotImplementedError(
-                "publish_update on a generation that holds users' "
-                "histories: a folded rating has to join its user's "
-                "history with the publish that makes it servable, and "
-                "neither the delta segment's program "
-                "(serving.index._int8_topk_delta) nor the row write "
-                "takes histories yet; publish(..., user_seen=...) whole")
+                "publish_update of a catalog that moves on a generation "
+                "that holds users' histories: the scoring program with a "
+                "delta segment (serving.index._int8_topk_delta) takes no "
+                "per-row exclusion yet; publish(..., user_seen=...) whole")
+        if seen_appended is not None and (prev is None
+                                          or prev.seen is None):
+            raise NotImplementedError(
+                "seen_appended on a generation that holds no histories: "
+                "publish(..., user_seen=...) first")
         t0 = time.perf_counter()
         # keep a host handle: the delta path gathers only the touched
         # rows, and doing that in numpy costs O(touched) with no
@@ -1137,6 +1462,8 @@ class ServingEngine:
             prev = self._model
             how, users, n_users, h2d = self._update_users(
                 prev, U, touched_users)
+            appended = (None if prev is None or prev.seen is None
+                        else self._append_history(n_users, seen_appended))
             cur = prev.index if prev is not None else None
             fresh = (cur is not None and cur.seq == prev.seq
                      and cur.n_items <= Ni)
@@ -1184,7 +1511,7 @@ class ServingEngine:
             # last: every step above may raise or take long (an index
             # build), and from the row write on the old table is gone
             how = self._swap(how, users, seq, n_users, V, valid, index, Ni,
-                             host=U, items=items)
+                             host=U, items=items, appended=appended)
             self._seq = seq
             if (mode == "delta"
                     and index.delta_count >= self._compact_rows(index)):
@@ -1349,7 +1676,7 @@ class ServingEngine:
                     "a batch that excludes, scored by the program with a "
                     "delta segment: it takes no per-row exclusion yet")
             return (_serve_int8_seen_packed,
-                    (m.U, idx.Vq, idx.sv, idx.V, idx.valid, seen.indptr,
+                    (m.U, idx.Vq, idx.sv, idx.V, idx.valid, seen.runs,
                      seen.indices, packed),
                     dict(k=self.k, shortlist_k=idx.shortlist_k, pad=pad))
         if self.mesh is not None:
@@ -1385,7 +1712,7 @@ class ServingEngine:
                 self.item_chunk, max(int(m.V.shape[0]), 1)))
             if seen is not None:
                 return (_serve_exact_seen_packed,
-                        (m.U, m.V, m.valid, seen.indptr, seen.indices,
+                        (m.U, m.V, m.valid, seen.runs, seen.indices,
                          packed), dict(statics, pad=pad))
             return (_serve_exact_packed, (m.U, m.V, m.valid, packed),
                     statics)
@@ -1442,7 +1769,8 @@ class ServingEngine:
                         (np.full(pad, m.U.shape[0], np.int32),
                          np.zeros((pad, m.rank), np.float32)),
                         self._replicated),
-                    m.seq, m.n_users, m.V, m.valid, m.index, m.n_items)
+                    m.seq, m.n_users, m.V, m.valid, m.index, m.n_items,
+                    seen=m.seen)
             self._model.U.block_until_ready()
 
     def warmup_live(self, max_delta_rows=None, max_rows=LIVE_PADS[-1]):
@@ -1470,20 +1798,30 @@ class ServingEngine:
 
         ``LiveUpdater.start`` calls it when ``fold_items`` is on.  Cheap
         no-op when the model serves exact.  ``seq`` does not move.
+
+        A generation that holds users' histories
+        (``publish(user_seen=...)``) is made ready for HISTORIES that
+        grow instead (``publish_update(seen_appended=...)``; its catalog
+        does not move, so it gets neither spare rows nor a segment, and
+        ``LiveUpdater.start`` calls this for it whatever ``fold_items``
+        says): the histories are laid out with room behind every run
+        and free room at the end (:meth:`_lay_out`, once), the programs
+        that exclude are pinned AND run for every bucket and history pad
+        of the grown ladder, in place of whatever :meth:`warmup` had
+        pinned (:meth:`_warm_exclusion`), and the write programs are run
+        on the live table, writing nothing: :func:`_append_runs` at
+        every padded size up to ``max_rows`` ids a publish,
+        :func:`_move_run` at every history pad.
         """
         with self._publish_lock, self._table_lock:
             m = self._model
             if m is None:
                 raise NoModelPublished("publish(U, V) before warmup")
+            if m.seen is not None:
+                return self._warm_histories(m, max_rows)
             idx = m.index
             if idx is None or idx.seq != m.seq:
                 return
-            if m.seen is not None:
-                raise NotImplementedError(
-                    "warmup_live on a generation that holds users' "
-                    "histories: the scoring program with a delta segment "
-                    "(serving.index._int8_topk_delta) takes no per-row "
-                    "exclusion yet")
             rows = (idx.n_base if idx.n_base > idx.n_items
                     else row_capacity(idx.n_items))
             idx = idx.reserve(rows, self._segment_slots(idx,
@@ -1519,6 +1857,32 @@ class ServingEngine:
                 fn, args, statics = self._exact_call(m, proto)
                 self._pinned[(B, "exact")] = fn.lower(
                     *args, **statics).compile()
+
+    def _warm_histories(self, m, max_rows):
+        """:meth:`warmup_live` for a generation that holds histories,
+        under its locks."""
+        seen = m.seen
+        if seen.room is None:
+            seen = self._lay_out(seen, int(m.U.shape[0]))
+        runs, indices, room = seen.runs, seen.indices, seen.room
+        for pad in pads_up_to(max_rows):
+            *runs, indices = _append_runs(*runs, indices, jax.device_put(
+                self._no_append(seen, pad)))
+        for width in seen.pads:
+            indices = _move_run(indices, 0, 0, width=width)  # onto itself
+        m = self._model = _Published(
+            m.seq, m.U, m.n_users, m.V, m.valid, m.index, m.n_items,
+            _Seen(tuple(runs), indices, seen.lengths, seen.pads, room))
+        self._pinned.clear()
+        for B in self.batcher.buckets:
+            self._warm_exclusion(m, B)
+
+    @property
+    def holds_histories(self):
+        """Whether the live generation was published with its users'
+        histories (``publish(user_seen=...)``)."""
+        m = self._model
+        return m is not None and m.seen is not None
 
     @staticmethod
     def _emit_shortlist(bucket, index, **extra):
@@ -1804,12 +2168,14 @@ class ServingEngine:
                 # a batch excludes where its generation holds histories
                 # or one of its requests brings a list: the wide layout,
                 # and the history pad that holds its longest
-                seen, pad = m.seen, None
+                seen, pad, histories = m.seen, None, None
                 if seen is None and any(t.exclude is not None
                                         for t in live):
                     seen = self._without_history()
                 if seen is not None:
-                    pad = seen.pad_for(live)
+                    # read under the lock: a publish writes the lengths
+                    histories = seen.lengths_of(live)
+                    pad = seen.pad_for(histories)
                     span.set_metadata(excluded=pad)
                 st = self._staged(live, B, m.rank, wide=seen is not None)
                 cpu_stage = stamp_cpu(span, mark)
@@ -1835,7 +2201,7 @@ class ServingEngine:
         obs.counter("serving.batch_overlap", in_flight=in_flight,
                     **self._labels)
         if seen is not None:
-            self._count_excluded(live, seen, B)
+            self._count_excluded(live, histories, B)
         # the batcher's account of this dequeue is taken now: by the
         # time the batch completes the engine thread has dequeued again
         return _Flown(seq, live, resp_dev, B, n, path, fell_back,
@@ -1949,16 +2315,14 @@ class ServingEngine:
                 f"({now - t.t_submit:.4f}s since submit)"))
         return live
 
-    def _count_excluded(self, live, seen, B):
+    def _count_excluded(self, live, histories, B):
         """The counters of one batch that excludes: ids taken out a
-        request, by where they came from, and what the requests' own
-        lists added to the batch's one upload."""
-        if seen.lengths is not None:
-            obs.histogram_many(
-                "serving.excluded_ids",
-                [int(seen.lengths[t.payload])
-                 if isinstance(t.payload, (int, np.integer)) else 0
-                 for t in live], source="history", **self._labels)
+        request, by where they came from (``histories``: the lengths of
+        its by-id requests' histories, ``_Seen.lengths_of``), and what
+        the requests' own lists added to the batch's one upload."""
+        if histories is not None:
+            obs.histogram_many("serving.excluded_ids", histories,
+                               source="history", **self._labels)
         obs.histogram_many(
             "serving.excluded_ids",
             [0 if t.exclude is None else len(t.exclude) for t in live],

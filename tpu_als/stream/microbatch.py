@@ -37,6 +37,26 @@ first fold after the other side got one.  An entity none of whose
 ratings can be used gets no factor (it is not appended) and its ratings
 wait; ``events_waiting`` counts them.  (Without ``keep_history`` there
 is nowhere to keep one: it is dropped, as before.)
+
+**A server that knows its users' histories** (``base_history``: the
+ratings behind the factors, resident beside them) folds a user over ALL of
+that user's ratings — the resident ones and, behind them in arrival order,
+the run's — which is the fold-in contract (Spark's, ``implicit``'s
+``recalculate_user``): without it a returning customer with 2,000 ratings
+who rates one more item gets a factor fitted to that one rating.  A fold
+then is as wide as its longest history, so the widths' ladder reaches the
+rung above the longest resident one (``core.ratings.growth_pads``), a call
+gathers at most ``FOLD_ELEMENTS`` ratings (a batch of many long histories
+goes in several), and a batch costs the host and the link what its touched
+users' histories hold, never all the histories.  Such a server also keeps
+ONE rating a user and item (Amazon keeps one review a customer and
+product): an event on an item its user has rated already — in the
+resident history or earlier in the run — replaces that rating's stars and
+adds no id; ``last_appended`` names the pairs of the last ``update`` that
+did add one, for whoever keeps the users' histories elsewhere
+(``ServingEngine.publish_update(seen_appended=...)``).  A server without a
+base history cannot know what was rated before the run and keeps every
+event as a rating of its own, as it always has.
 """
 
 from __future__ import annotations
@@ -53,20 +73,52 @@ from tpu_als import obs
 from tpu_als.core.foldin import fold_in, place_rows, solve_path, write_rows
 from tpu_als.core.ratings import (
     LIVE_PADS,
+    growth_pads,
     pad_for,
     pads_up_to,
     row_capacity,
+    rung_for,
 )
 from tpu_als.ops.solve import compute_yty
 from tpu_als.utils.frame import as_frame
 
 
-class FoldInServer:
-    """Incremental user-factor updates against a fitted model."""
+# ratings one call of the fold-in program gathers at most, at the widths
+# only a resident base history reaches (above LIVE_PADS): 0.5 GB of
+# rank-256 float32 rows, and the Gram build holds it more than once
+FOLD_ELEMENTS = 1 << 19
 
-    def __init__(self, model, keep_history=True, stats_window=512):
+
+class FoldInServer:
+    """Incremental user-factor updates against a fitted model.
+
+    ``base_history``: the users' resident rating histories, ``(indptr,
+    indices, stars)`` — CSR over the model's dense user rows (row ``u`` is
+    user ``model._user_map.to_original(u)``; fewer rows than users: the
+    others have none), ``indices`` dense catalog rows, a row's none twice,
+    ``stars`` the ratings.  A user's fold is then over the resident
+    ratings AND the events, and an event on an item the user has already
+    rated replaces that rating (module docstring).  The arrays are read,
+    never written or copied whole: a touched user's run is copied into the
+    server's own history at that user's first event.  Needs
+    ``keep_history``."""
+
+    def __init__(self, model, keep_history=True, stats_window=512,
+                 base_history=None):
         self.model = model
         self.keep_history = keep_history
+        if base_history is not None and not keep_history:
+            raise ValueError("base_history needs keep_history: the events "
+                             "go behind the resident ratings")
+        self._base = base_history
+        # the widths' ladder: up the plain one (8, 64, 512, ...) without a
+        # base history, with the rung above the longest resident one's
+        # rung where histories grow from there
+        self._widths = () if base_history is None else growth_pads(
+            int(np.diff(base_history[0]).max(initial=0)))
+        # (user ids, item ids) of the last ``update``'s events that added
+        # an id to their user's history (all of them without a base)
+        self.last_appended = (np.empty(0, np.int64), np.empty(0, np.int64))
         # original id -> (fixed-side ORIGINAL ids, ratings), in arrival
         # order
         self._history = {}
@@ -131,10 +183,23 @@ class FoldInServer:
         setattr(m, fac_attr, buf[:len(fac)])
         (m._item_map if items_side else m._user_map).reserve(cap)
 
-    def prewarm(self, rows=LIVE_PADS, widths=LIVE_PADS,
+    def _rows_at(self, width):
+        """Entities one call of the program takes at this padded width:
+        any number up to the widths every server has always run
+        (``LIVE_PADS``), beyond them what keeps the gather under
+        ``FOLD_ELEMENTS`` ratings, down the rows' ladder (8 at least)."""
+        if width <= LIVE_PADS[-1]:
+            return None
+        return max(p for p in pads_up_to(max(8, FOLD_ELEMENTS // width))
+                   if p == 8 or p * width <= FOLD_ELEMENTS)
+
+    def prewarm(self, rows=LIVE_PADS, widths=None,
                 sides=("user",), growth=0):
         """Compile AND run the fold-in program of every padded shape up to
-        ``max(rows)`` entities of ``max(widths)`` ratings a batch.
+        ``max(rows)`` entities of ``max(widths)`` ratings a batch
+        (``widths``: by default up to 512, with a base history up to the
+        rung above the longest resident one; the shapes no call takes —
+        more rows than ``_rows_at`` allows a width — are left out).
 
         ``update`` pads a batch up the ladder 8, 64, 512, ..., so the
         programs are few — but a shape's first call still pays its compile
@@ -152,7 +217,9 @@ class FoldInServer:
         between sides (equal capacities) hit the same jit-cache entry.
         """
         m = self.model
-        rows, widths = pads_up_to(max(rows)), pads_up_to(max(widths))
+        rows = pads_up_to(max(rows))
+        widths = (pads_up_to(max(widths)) if widths is not None
+                  else self._widths or LIVE_PADS)
         for side in sides:
             # the id maps sort their ids at first use: now, not mid-stream
             (m._user_map if side == "user" else m._item_map).to_dense([0])
@@ -164,6 +231,8 @@ class FoldInServer:
                     _, path, why = solve_path(F.shape[1], n,
                                               self._nonnegative)
                     for w in widths:
+                        if n > (self._rows_at(w) or n):
+                            continue
                         fold_in(
                             F,
                             jnp.zeros((n, w), jnp.int32),
@@ -242,6 +311,8 @@ class FoldInServer:
             fixed_raw = np.asarray(frame[p["itemCol"]])
             fixed_map, history = m._item_map, self._history
         r = np.asarray(frame[p["ratingCol"]], dtype=np.float32)
+        if not items_side:
+            self.last_appended = (solved_raw[:0], fixed_raw[:0])
         if len(solved_raw) == 0:
             return np.array([], dtype=np.int64)
 
@@ -254,17 +325,31 @@ class FoldInServer:
                        np.split(r[by_entity], bounds)))
         side = "item" if items_side else "user"
         used = self._used[side] if self.keep_history else {}
+        # with a base history a user has ONE rating an item: an event on
+        # an item already rated replaces it (``adds``: which events add
+        # an id to their user's history; ``again``: the items re-rated)
+        one_rating = self._base is not None and not items_side
+        adds, again = np.ones(len(r), bool), []
         if self.keep_history:
             # a rating whose other side has no factor yet waits for it
             held = self._waiting["user" if items_side else "item"]
             for e in fixed_raw[fixed_map.to_dense(fixed_raw) < 0].tolist():
                 held[e] = held.get(e, 0) + 1
+            events = np.split(by_entity, bounds)
             for j, e in enumerate(touched.tolist()):
                 hist = history.get(e)
-                if hist is not None:
+                if hist is None and one_rating:
+                    hist = self._resident(e, used)
+                if one_rating:
+                    per[j], new = _one_rating_each(hist, *per[j])
+                    adds[events[j][~new]] = False
+                    again.append(fixed_raw[events[j][~new]])
+                elif hist is not None:
                     per[j] = (np.concatenate([hist[0], per[j][0]]),
                               np.concatenate([hist[1], per[j][1]]))
                 history[e] = per[j]
+        if not items_side:
+            self.last_appended = (solved_raw[adds], fixed_raw[adds])
 
         # a fold regresses on the ratings whose other side has a factor
         # NOW (fixed-side entities never seen cannot contribute: no
@@ -275,8 +360,10 @@ class FoldInServer:
         known = dense >= 0
         usable = np.add.reduceat(known.astype(np.int64),
                                  np.cumsum(lens) - lens)
-        # ratings that enter a fold of this side for the first time
-        entered = 0
+        # ratings that enter a fold of this side for the first time (a
+        # rating that replaces one enters in its place)
+        entered = (int((fixed_map.to_dense(np.concatenate(again)) >= 0).sum())
+                   if again else 0)
         for e, n_ok in zip(touched.tolist(), usable.tolist()):
             entered += n_ok - used.get(e, 0)
             used[e] = n_ok
@@ -287,44 +374,83 @@ class FoldInServer:
         dense, vals_all = dense[known], vals_all[known]
         lens = usable[fold]
 
-        # pad rows and width up the ladder -> the programs prewarm ran
-        n = len(touched)
-        n_pad, w = pad_for(n), pad_for(int(lens.max()))
-        row = np.repeat(np.arange(n), lens)
-        slot = np.arange(lens.sum()) - np.repeat(np.cumsum(lens) - lens, lens)
-        cols = np.zeros((n_pad, w), dtype=np.int32)
-        vals = np.zeros((n_pad, w), dtype=np.float32)
-        mask = np.zeros((n_pad, w), dtype=np.float32)
-        cols[row, slot] = dense
-        vals[row, slot] = vals_all
-        mask[row, slot] = 1.0
-
         F = self._fixed(items_side)
         if items_side:
             # O(table) a batch on the implicit path: ROADMAP R2
             YtY = compute_yty(F) if self._implicit else None
         else:
             YtY = self._YtY
-        # the fold's one wait for the device, on the profiler's timeline
-        # (obs.schema.LIVE_FOLDIN_SPAN_KEYS): three uploads, the call,
-        # the rows read back
-        with TraceAnnotation("live.batch.foldin.readback",
-                             side=side + "s"):
-            x = np.asarray(fold_in(
-                F, jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(mask),
-                self._reg, implicit_prefs=self._implicit,
-                alpha=self._alpha, nonnegative=self._nonnegative, YtY=YtY,
-            ))[:n]
+        # pad rows and width up the ladder -> the programs prewarm ran;
+        # one call, or where its gather would pass FOLD_ELEMENTS several
+        n, first = len(touched), np.cumsum(lens) - lens
+        x, widest = np.empty((n, F.shape[1]), np.float32), 0
+        for sel in self._calls(lens):
+            ln = lens[sel]
+            n_pad, w = pad_for(len(sel)), rung_for(int(ln.max()),
+                                                   self._widths)
+            row = np.repeat(np.arange(len(sel)), ln)
+            slot = np.arange(ln.sum()) - np.repeat(np.cumsum(ln) - ln, ln)
+            flat = np.repeat(first[sel], ln) + slot
+            cols = np.zeros((n_pad, w), dtype=np.int32)
+            vals = np.zeros((n_pad, w), dtype=np.float32)
+            mask = np.zeros((n_pad, w), dtype=np.float32)
+            cols[row, slot] = dense[flat]
+            vals[row, slot] = vals_all[flat]
+            mask[row, slot] = 1.0
+            # the fold's one wait for the device, on the profiler's
+            # timeline (obs.schema.LIVE_FOLDIN_SPAN_KEYS): three uploads,
+            # the call, the rows read back
+            with TraceAnnotation("live.batch.foldin.readback",
+                                 side=side + "s"):
+                x[sel] = np.asarray(fold_in(
+                    F, jnp.asarray(cols), jnp.asarray(vals),
+                    jnp.asarray(mask), self._reg,
+                    implicit_prefs=self._implicit, alpha=self._alpha,
+                    nonnegative=self._nonnegative, YtY=YtY,
+                ))[:len(sel)]
+            obs.histogram("foldin.history_width", w, side=side)
+            widest = max(widest, w)
 
         self._write_back(touched, x, items_side)
         if items_side and self._implicit:
             self._YtY = compute_yty(self._V)
         dt = time.perf_counter() - t0
-        self.stats.append((entered, n, dt, w))
+        self.stats.append((entered, n, dt, widest))
         obs.histogram("foldin.update_seconds", dt, side=side)
         obs.histogram("foldin.batch_rows", n, side=side)
         obs.counter("foldin.ratings", entered)
         return touched
+
+    def _resident(self, user, used):
+        """The resident ratings of ``user`` as the start of the server's
+        own history of that user, ``(item ids, stars)``, copied (``None``
+        for a user the base history has no row for); all of them count as
+        folded before (``used``: they are behind the model's factors)."""
+        indptr, indices, stars = self._base
+        row = int(self.model._user_map.to_dense([user])[0])
+        if not 0 <= row < len(indptr) - 1:
+            return None
+        lo, hi = int(indptr[row]), int(indptr[row + 1])
+        used[user] = hi - lo
+        return (self.model._item_map.to_original(indices[lo:hi]),
+                np.array(stars[lo:hi], dtype=np.float32))
+
+    def _calls(self, lens):
+        """The entities of one batch by call of the fold-in program, as
+        index arrays into ``lens`` (their usable ratings): all in one
+        where the padded batch is a shape :meth:`_rows_at` allows, else
+        longest first, each call as many as its own width allows."""
+        n = len(lens)
+        most = self._rows_at(rung_for(int(lens.max()), self._widths))
+        if most is None or pad_for(n) <= most:
+            yield np.arange(n)
+            return
+        order, at = np.argsort(-lens, kind="stable"), 0
+        while at < n:
+            most = self._rows_at(rung_for(int(lens[order[at]]),
+                                          self._widths)) or n
+            yield order[at:at + most]
+            at += most
 
     def _write_back(self, touched_raw_ids, new_rows, items_side=False):
         """New factor rows into the model's table, and into the server's
@@ -368,3 +494,27 @@ class FoldInServer:
 
     def p50_latency(self):
         return self.latency(0.5)
+
+
+def _one_rating_each(hist, items, stars):
+    """``((item ids, stars) of a history with the events merged in,
+    which events added an id)``: the events ``(items, stars)``, in arrival
+    order, put behind ``hist`` (``None``: no history yet), but an event
+    on an item the history holds already — or an earlier event of these
+    does — replaces that rating's stars instead.  ``hist``'s stars are
+    written in place."""
+    if hist is None:
+        hist = (items[:0], stars[:0])
+    h_items, h_stars = hist
+    new = np.ones(len(items), bool)
+    at = {}                 # item -> its place among these events' new ones
+    for k, (item, star) in enumerate(zip(items.tolist(), stars.tolist())):
+        had = np.flatnonzero(h_items == item)
+        if len(had):
+            h_stars[had[0]], new[k] = star, False
+        elif item in at:
+            stars[at[item]], new[k] = star, False
+        else:
+            at[item] = k
+    return (np.concatenate([h_items, items[new]]),
+            np.concatenate([h_stars, stars[new]])), new

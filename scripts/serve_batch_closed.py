@@ -44,7 +44,10 @@ rows, the items appended and the ratings still waiting for a side's
 factor), the ``serving_shortlist`` events (one a scoring program
 ``warmup()`` / ``warmup_live()`` compiled: the selection's stages, blocks
 and ``blocks_layout``, what stage two asks of the compiler for that
-bucket), and for an engine given a mesh its
+bucket; since PR 43 ``blockmax``, how stage one reduces a block —
+``lanes`` on the mesh cell's blocks of 256, ``block`` elsewhere — and
+``tail``, the delta segment's slots that join at stage three: 512 in the
+live-items cell, 0 elsewhere), and for an engine given a mesh its
 ``serving_mesh_plan`` events (one a bucket ``warmup()`` pinned) with
 the process's ``serving.mesh_exchange_bytes``: the mesh path read
 without a profiler.  No CPU mode (``run.py`` has none):

@@ -26,6 +26,24 @@ Three tables, one JSON line a row and then markdown:
 Then, as since PR 26, ``jax.lax.top_k`` against ``shortlist_topk`` on
 ``f32[B, 1505938]``, ragged and at the padded width.
 
+With ``--block-len 256`` and / or ``--tail 512`` (PR 43) it times STAGE
+ONE instead, and nothing else (~2 min): the scoring program whose stage
+one reads the written score matrix a second time, in the parent's form
+beside the tree's, with the operations that take longest in each —
+
+- ``--block-len L`` (a multiple of 128 above 128): ``_int8_topk`` over a
+  catalog whose plan has blocks of ``L`` (256: one shard of the mesh
+  cell, 3,012,096 rows), the block maximum taken over a block at once
+  (parent) and as ``ops.topk.block_maxima`` takes a long block (tree:
+  its 128-lane groups folded into one first);
+- ``--tail d``: ``_int8_topk_delta`` at the live-items cell's shapes
+  (1,529,856 base columns, ``d`` slots), the segment's scores
+  concatenated to the matrix (parent) and joined at stage three (tree).
+
+The parent's form is written out here (:func:`parents_shortlist`) and
+put in the index module's place of ``shortlist_topk`` around its runs: a
+script's device, not a switch of the program.
+
 A time is the device's own: the median duration of the program's runs on
 the trace's ``XLA Modules`` line over 20 runs after a warm one (a host
 clock around a 0.05 ms program reads the launch, 0.2-0.5 ms on this
@@ -37,6 +55,7 @@ Exits 1 without a TPU: a CPU's times are not the chip's.
 
 from __future__ import annotations
 
+import argparse
 import functools
 import glob
 import json
@@ -72,10 +91,10 @@ ROW_MAJOR = Layout(major_to_minor=(0, 1))
 COLUMN_MAJOR = Layout(major_to_minor=(1, 0))
 
 
-def times_ms(fn, *args):
-    """``(device ms, host ms, longest operation, its ms)`` of one call of
-    the jitted ``fn``: medians over ``REPEATS`` runs, the device's from
-    its own record of them; the operation's is its self time a run."""
+def profiled(fn, *args):
+    """``(device ms of each run, host ms of each run, {operation: self
+    ns over all runs})`` of ``REPEATS`` fenced calls of the jitted ``fn``
+    after a warm one, from a profiler trace of them."""
     jax.block_until_ready(fn(*args))
     host = []
     with tempfile.TemporaryDirectory() as d:
@@ -96,9 +115,27 @@ def times_ms(fn, *args):
     assert len(runs) == REPEATS, (len(runs), REPEATS)
     ops = self_times([(short_name(ev.name), int(ev.start_ns),
                        int(ev.duration_ns)) for ev in lines["XLA Ops"]])
+    return runs, host, ops
+
+
+def times_ms(fn, *args):
+    """``(device ms, host ms, longest operation, its ms)`` of one call of
+    the jitted ``fn``: medians over ``REPEATS`` runs, the device's from
+    its own record of them; the operation's is its self time a run."""
+    runs, host, ops = profiled(fn, *args)
     op, ns = max(ops.items(), key=lambda kv: kv[1])
     return (statistics.median(runs), statistics.median(host), op,
             ns * 1e-6 / REPEATS)
+
+
+def longest_ops(fn, *args, top=6):
+    """``(device ms, [(operation, self ms a run)])`` of the jitted
+    ``fn``: the median of its runs and its ``top`` operations by self
+    time, as :func:`times_ms` reads them."""
+    runs, _, ops = profiled(fn, *args)
+    ranked = sorted(ops.items(), key=lambda kv: -kv[1])[:top]
+    return (statistics.median(runs),
+            [(op, ns * 1e-6 / REPEATS) for op, ns in ranked])
 
 
 def topk_operands(fn, *args, **kw):
@@ -235,12 +272,134 @@ def against_single_top_k(dev):
     return rows, padded
 
 
+def parents_shortlist(scores, k, tail=None):
+    """``shortlist_topk`` as the parent of PR 43 had it: a tail
+    concatenated to the matrix before stage one, and a block's maximum
+    taken over the whole block whatever its length."""
+    if tail is not None:
+        scores = jnp.concatenate([scores, tail], axis=1)
+    kept = topk_mod.shortlist_plan
+    topk_mod.shortlist_plan = lambda *a, **kw: kept(*a, **kw)._replace(
+        blockmax="block")
+    try:
+        return shortlist_topk(scores, k)
+    finally:
+        topk_mod.shortlist_plan = kept
+
+
+def in_both_forms(fn, *args, **kw):
+    """``{"parent": ..., "tree": ...}``: :func:`longest_ops` of the
+    jitted scoring program ``fn`` with the index module's
+    ``shortlist_topk`` replaced by :func:`parents_shortlist` and as it
+    stands, and whether the two answered alike."""
+    from tpu_als.serving import index
+
+    out, answers = {}, {}
+    for form, topk in (("parent", parents_shortlist),
+                       ("tree", shortlist_topk)):
+        index.shortlist_topk = topk
+        jax.clear_caches()
+        try:
+            call = lambda *a: fn(*a, **kw)      # noqa: E731
+            answers[form] = jax.block_until_ready(call(*args))
+            out[form] = longest_ops(call, *args)
+        finally:
+            index.shortlist_topk = shortlist_topk
+    jax.clear_caches()
+    out["equal"] = all(bool(jnp.array_equal(a, b)) for a, b in
+                       zip(answers["parent"], answers["tree"]))
+    return out
+
+
+def stage_one(block_len, tail):
+    """The scoring programs whose stage one PR 43 rewrote, in both forms
+    (module docstring): one JSON line a bucket, then markdown."""
+    from tpu_als.core.ratings import row_capacity
+    from tpu_als.serving.index import SLOT_FREE, _int8_topk, _int8_topk_delta
+
+    def catalog(cols, rows):
+        k1, k2 = jax.random.split(jax.random.PRNGKey(cols))
+        return (jax.random.randint(k1, (cols, RANK), -127, 128, jnp.int8),
+                jnp.full((cols,), 1.0 / 127 / 16, jnp.float32),
+                jax.random.normal(k2, (rows, RANK), jnp.float32) / 16,
+                jnp.ones((cols,), jnp.bool_).at[rows:].set(False))
+
+    def queries(b):
+        return jax.random.normal(jax.random.PRNGKey(b), (b, RANK),
+                                 jnp.float32)
+
+    rows = []
+    if block_len:
+        # one shard of the mesh cell where that gives the asked length,
+        # else the catalog whose plan's blocks are that long
+        cols = 3_012_096 if block_len == 256 else K * block_len ** 2
+        cols = shortlist_columns(cols, K)
+        assert shortlist_plan(cols, K).block_len == block_len, cols
+        tables = catalog(cols, cols)
+        for b in BATCHES:
+            rows.append({"table": "stage_one", "program": "_int8_topk",
+                         "B": b, **shortlist_plan(cols, K, b)._asdict(),
+                         **in_both_forms(_int8_topk, queries(b), *tables,
+                                         k=10, shortlist_k=K)})
+            print(json.dumps(rows[-1]), flush=True)
+        del tables
+    if tail:
+        cap = row_capacity(COLUMNS)
+        cols = shortlist_columns(cap, K)
+        tables = catalog(cols, cap)
+        k1, k2 = jax.random.split(jax.random.PRNGKey(tail))
+        used = tail // 2        # half the slots hold rows, some overridden
+        seg = (jnp.full((tail,), SLOT_FREE, jnp.int32).at[:used].set(
+                   jax.random.choice(k1, cap, (used,), replace=False)
+                   .astype(jnp.int32)),
+               jax.random.randint(k2, (tail, RANK), -127, 128, jnp.int8),
+               jnp.full((tail,), 1.0 / 127 / 16, jnp.float32),
+               jax.random.normal(k1, (tail, RANK), jnp.float32) / 16,
+               jnp.zeros((tail,), jnp.bool_).at[:used].set(True))
+        for b in BATCHES:
+            rows.append({"table": "stage_one",
+                         "program": "_int8_topk_delta", "B": b,
+                         **shortlist_plan(cols, K, b, tail)._asdict(),
+                         **in_both_forms(_int8_topk_delta, queries(b),
+                                         *tables, *seg, jnp.int32(cap - 1),
+                                         k=10, shortlist_k=K)})
+            print(json.dumps(rows[-1]), flush=True)
+    print("\nDevice ms a run (median of 20) of the scoring program, stage "
+          "one in the parent's form and in the tree's; below each, its "
+          "longest operations (self ms a run).\n")
+    print("| program | B | columns, L, tail | parent | tree | parent - tree "
+          "| equal |")
+    print("| --- | --- | --- | --- | --- | --- | --- |")
+    for r in rows:
+        print(f"| {r['program']} | {r['B']} | {r['columns']}, "
+              f"{r['block_len']}, {r['tail']} | {r['parent'][0]:.4f} | "
+              f"{r['tree'][0]:.4f} | {r['parent'][0] - r['tree'][0]:.4f} | "
+              f"{r['equal']} |")
+    for r in rows:
+        for form in ("parent", "tree"):
+            print(f"\n{r['program']} B={r['B']} {form}: " + "; ".join(
+                f"`{op}` {ms:.4f}" for op, ms in r[form][1]))
+    return 0 if all(r["equal"] for r in rows) else 1
+
+
 def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--block-len", type=int, default=0,
+                    help="time stage one at blocks of this many columns "
+                    "(a multiple of 128 above 128), parent's form and "
+                    "tree's")
+    ap.add_argument("--tail", type=int, default=0,
+                    help="time stage one with a delta segment of this "
+                    "many slots, concatenated (parent) and joined at "
+                    "stage three (tree)")
+    args = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(f"time_shortlist: needs a TPU, found {dev.platform}",
               file=sys.stderr)
         return 1
+    if args.block_len or args.tail:
+        return stage_one(args.block_len, args.tail)
     stages = stages_alone(dev)
     whole = whole_function()
     program = scoring_program()

@@ -14,6 +14,7 @@ described chip cannot be read back without one).
 """
 
 import functools
+import math
 import re
 
 import jax
@@ -208,7 +209,7 @@ def test_serve_with_a_segment_at_the_live_cells_size(one_chip, bucket):
     from tpu_als.serving.engine import _serve_int8_delta_packed
 
     cap, cols, base, seg = _live_catalog_shapes()
-    plan = shortlist_plan(cols + LIVE_SLOTS, 64)
+    plan = shortlist_plan(cols, 64, tail=LIVE_SLOTS)
     assert plan.stages == 2 and plan.columns % plan.block_len == 0
     c = _compiled(
         one_chip, _serve_int8_delta_packed,
@@ -311,7 +312,8 @@ def test_stage_two_topk_layout_with_a_segment(one_chip, bucket):
         ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32),
         *base, *seg, ((), jnp.int32), ((bucket, LIVE_RANK + 2), jnp.int32),
         k=10, shortlist_k=64)
-    _assert_stage_two_as_planned(c.as_text(), cols + LIVE_SLOTS, bucket)
+    # the base's columns: the segment's scores join at stage three
+    _assert_stage_two_as_planned(c.as_text(), cols, bucket)
 
 
 @pytest.mark.parametrize("bucket", BUCKETS)
@@ -350,16 +352,19 @@ def test_stage_two_topk_layout_on_every_shard_of_the_mesh_cell(
 UNSEEN_RATINGS = 17_860_625
 HISTORY_PADS = (64, 512, 4096)
 # sha256 of the lowered (StableHLO) text of the three programs the cells
-# without histories run, at their cells' shapes, taken on the parent commit
-# (01a67e1): an engine that published no histories and sees no ``exclude``
-# must lower to what it lowered to before the engine knew of either
+# without histories run, at their cells' shapes.  ``steady``: taken on
+# 01a67e1, before the engine knew of histories or ``exclude``, and unmoved
+# since — PR 43's stage one keeps the parent's expression for blocks of 128
+# lanes without a tail.  ``delta`` and ``mesh``: pinned again at PR 43,
+# whose stage one they run (the segment's scores a tail, blocks of 256
+# through the lane maxima)
 PARENT_LOWERED = {
     ("steady", 8): "35ea3052c100ffc5", ("steady", 32): "abfcefa10f9a23f9",
     ("steady", 128): "f61c331f33283e9b",
-    ("delta", 8): "2919b222ef9e0eea", ("delta", 32): "f64fc009c44cba65",
-    ("delta", 128): "6b8540347d23241f",
-    ("mesh", 8): "2b4d0ec9ed8e15d5", ("mesh", 32): "e839ec6eb37f9beb",
-    ("mesh", 128): "6a08a28f0749d53b",
+    ("delta", 8): "099e8ce54c689f77", ("delta", 32): "7f6b3ce1d9918a52",
+    ("delta", 128): "d225a128acc850eb",
+    ("mesh", 8): "93dd3ee999387067", ("mesh", 32): "568ce002adf0855d",
+    ("mesh", 128): "34a54c00de2d742b",
 }
 
 
@@ -422,6 +427,89 @@ def test_programs_without_histories_lower_to_the_parents_text(
     text = _lowered(name, bucket, topo, one_chip).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == PARENT_LOWERED[name, bucket]
+
+
+# -- stage one reads the score matrix once (PR 43) ------------------------------
+
+SCORE_COLUMNS = {"steady": 1_506_048, "delta": 1_529_856,
+                 "mesh": MESH_ITEMS_PER_SHARD}
+INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*?)\)(?:, |$)")
+
+
+def _entry(text):
+    """``[(name, result shapes, opcode, operand names, op_name)]`` of the
+    compiled program's entry computation."""
+    out = []
+    for ln in text[text.index("\nENTRY "):].splitlines():
+        m = INSTRUCTION.match(ln)
+        if m:
+            scope = re.search(r'op_name="([^"]*)"', ln)
+            out.append((m.group(1), m.group(2), m.group(3),
+                        re.findall(r"%([\w.\-]+)", m.group(4)),
+                        scope.group(1) if scope else ""))
+    return out
+
+
+def _over_the_matrix(text, elements):
+    """The entry's instructions that write or read an f32 array of
+    ``elements`` elements (the score matrix under any of its views), the
+    ones that move no byte left out: ``{name: (opcode, op_name)}``."""
+    def holds(shapes):
+        return any(math.prod(map(int, dims.split(","))) == elements
+                   for dims in re.findall(r"f32\[([\d,]+)\]", shapes))
+
+    entry = _entry(text)
+    matrix = {name for name, shapes, *_ in entry if holds(shapes)}
+    return {name: (opcode, scope)
+            for name, shapes, opcode, operands, scope in entry
+            if opcode not in ("get-tuple-element", "bitcast", "tuple",
+                              "parameter")
+            and (name in matrix or matrix & set(operands))}
+
+
+def test_the_mesh_programs_block_maxima_fold_before_they_reduce(
+        topo, one_chip):
+    """Bucket 8 of the mesh cell (97 % of its batches), blocks of 256: the
+    compiler fuses no reduce over 256 lanes into the score fusion, so ONE
+    instruction under ``serve.shortlist.blockmax`` reads the ``f32[8,
+    3012096]`` matrix — a fusion that takes it as ``[.., 11766, 2, 128]``
+    and folds the two 128-lane groups elementwise before it reduces
+    (0.068 ms on the chip; the parent's plain ``reduce`` over ``[.., 11766,
+    256]`` 0.165)."""
+    text = _lowered("mesh", 8, topo, one_chip).compile().as_text()
+    over = _over_the_matrix(text, 8 * MESH_ITEMS_PER_SHARD)
+    (name,) = [name for name, (_, scope) in over.items()
+               if "serve.shortlist.blockmax" in scope]
+    assert over[name][0] == "fusion"
+    called = re.search(rf"%{re.escape(name)} = .*calls=%([\w.\-]+)",
+                       text).group(1)
+    body = text[text.index(f"\n%{called} ("):]
+    body = body[:body.index("\n}")]
+    blocks = MESH_ITEMS_PER_SHARD // 256
+    assert re.search(rf"f32\[(1,)?8,{blocks},2,128\]", body), body[:600]
+    assert " maximum(" in body and " reduce(" in body
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_no_scoring_program_passes_over_the_matrix_more_than_steady(
+        topo, one_chip, bucket):
+    """With a segment the score matrix is written once and read as often
+    as in the steady program of that bucket (at 8: by the gather alone;
+    at 32 and 128, where the compiler writes it to HBM, by the block
+    maxima's reduce and the gather), and the program holds no array of
+    the concatenated width.  The mesh program, blocks of 256, pays the
+    block maxima's pass at bucket 8 too (the test above), and nothing
+    beyond it."""
+    count = {}
+    for name, columns in SCORE_COLUMNS.items():
+        text = _lowered(name, bucket, topo, one_chip).compile().as_text()
+        count[name] = _over_the_matrix(text, bucket * columns)
+        if name == "delta":
+            assert not re.search(r"\b(11956|1530368)\b", text)
+    assert len(count["steady"]) == (2 if bucket == 8 else 3), count
+    assert len(count["delta"]) <= len(count["steady"]), count
+    assert len(count["mesh"]) <= 3, count
 
 
 @pytest.mark.parametrize("bucket", BUCKETS)

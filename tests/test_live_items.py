@@ -604,3 +604,74 @@ def test_a_folds_readback_lies_inside_the_fold_of_its_side(traced_folds,
              and s[3]["side"] != side]
     for _, f0, f1, _ in folds:
         assert all(b1 <= f0 or f1 <= b0 for _, b0, b1, _ in other)
+
+
+# (i) the segment joined at the shortlist's last stage (PR 43)
+
+
+def concatenated_top_k(scores, k, tail=None):
+    """What a scoring program's shortlist returned while the segment's
+    scores were concatenated to the matrix (``shortlist_topk`` of that is
+    ``lax.top_k`` of it, element for element: tests/test_shortlist.py)."""
+    if tail is not None:
+        scores = jnp.concatenate([scores, tail], axis=1)
+    return jax.lax.top_k(scores, k)
+
+
+def segment_states(idx, V, rng, slots, appended=9):
+    """``{name: index}``: ``idx`` with a segment of ``slots`` slots that
+    is empty (every slot free), holds overridden base rows (some marked
+    invalid), holds appended ids beside them, and is full."""
+    n, r = V.shape
+    held = idx.reserve(rows=row_capacity(n), slots=slots)
+
+    def rows_of(ids):
+        return 3.0 * rng.normal(size=(len(ids), r)).astype(np.float32)
+
+    over = np.sort(rng.choice(n, slots // 4, replace=False)).astype(np.int64)
+    grown = np.r_[over[::2], n:n + appended].astype(np.int64)
+    full = np.r_[rng.choice(n, slots - appended, replace=False),
+                 n:n + appended].astype(np.int64)
+    states = {
+        "free_slots": held,
+        "overridden": held.with_updates(
+            over, rows_of(over), valid_rows=rng.random(len(over)) < 0.8),
+        "appended": held.with_updates(over, rows_of(over)).with_updates(
+            grown, rows_of(grown)),
+        "full": held.with_updates(full, rows_of(full)),
+    }
+    assert states["full"].delta_count == states["full"].delta_slots == slots
+    assert all(s.delta_slots == slots for s in states.values())
+    return states
+
+
+@pytest.mark.parametrize("state", ["free_slots", "overridden", "appended",
+                                   "full"])
+@pytest.mark.parametrize("n_items,shortlist_k,stages", [(N_ITEMS, 64, 1),
+                                                        (40_000, 16, 2)])
+def test_a_segment_as_the_shortlists_tail_answers_as_concatenated(
+        monkeypatch, state, n_items, shortlist_k, stages):
+    """``_int8_topk_delta`` hands its shortlist the segment's scores as a
+    ``tail``: scores and ids are, bit for bit, those of the program that
+    concatenated them to the matrix, whatever the segment holds."""
+    from tpu_als.serving import index as index_module
+
+    rng = np.random.default_rng(43 + n_items)
+    V = rng.normal(size=(n_items, RANK)).astype(np.float32)
+    idx = segment_states(build_index(V, shortlist_k=shortlist_k), V, rng,
+                         slots=64)[state]
+    plan = idx.shortlist_plan(rows=9)
+    assert (plan.stages, plan.tail) == (stages, 64)
+    assert plan.columns == int(idx.Vq.shape[0])
+    Q = jnp.asarray(rng.normal(size=(9, RANK)).astype(np.float32))
+    args = (Q, idx.Vq, idx.sv, idx.V, idx.valid, *idx._seg, idx._last_id())
+    got = idx.topk(Q, K)
+    # the parent's program: the shortlist replaced, under a function of
+    # its own (a jit of the same function would answer from its cache)
+    monkeypatch.setattr(index_module, "shortlist_topk", concatenated_top_k)
+    want = jax.jit(lambda *a: index_module._int8_topk_delta.__wrapped__(
+        *a, k=K, shortlist_k=idx.shortlist_k))(*args)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    if state in ("appended", "full"):       # the segment does answer
+        assert np.isin(np.asarray(got[1]), idx.d_rows).any()

@@ -320,3 +320,43 @@ def test_exact_fallback_scores_per_shard_and_uploads_nothing(monkeypatch):
         assert np.abs(gs - ws).max() <= SCORE_ULPS * np.spacing(
             np.abs(ws).max())
     assert eng.batch_flight.records()[-1]["path"] == "exact"
+
+
+# -- (g) a segment joined at the shortlist's last stage (PR 43) ---------------
+
+
+@pytest.mark.parametrize("state", ["free_slots", "overridden", "appended",
+                                   "full"])
+@pytest.mark.parametrize("n_items,shortlist_k,stages", [(N_ITEMS, 128, 1),
+                                                        (36_000, 16, 2)])
+def test_shard_score_with_a_segment_answers_as_concatenated(
+        monkeypatch, state, n_items, shortlist_k, stages):
+    """``_shard_score`` hands its shortlist the (replicated) segment's
+    scores as a ``tail``: on every seeded state of the segment the sharded
+    program's scores and ids are, bit for bit, those of the program that
+    concatenated them to its shard's matrix."""
+    from tests.test_live_items import concatenated_top_k, segment_states
+    from tpu_als.serving import index as index_module
+
+    rng = np.random.default_rng(43 + n_items)
+    V = rng.standard_normal((n_items, RANK)).astype(np.float32)
+    sh = index_module.build_sharded_index(V, make_mesh(S),
+                                          shortlist_k=shortlist_k)
+    # the shards' stride leaves spare ids past the catalog to append to
+    spare = sh.capacity - n_items
+    assert spare >= 1
+    idx = segment_states(sh, V, rng, slots=32, appended=min(spare, 9))[state]
+    plan = idx.shortlist_plan(rows=9)
+    assert (plan.stages, plan.columns, plan.tail) == (stages, idx.ni_loc, 32)
+    Q = jax.numpy.asarray(
+        rng.standard_normal((9, RANK)).astype(np.float32))
+    got = idx.topk(Q, K)
+    monkeypatch.setattr(index_module, "shortlist_topk", concatenated_top_k)
+    k_loc, sk_loc = idx.shard_widths(K)
+    # the builder behind its cache: a program of its own, traced now
+    want = index_module._build_sharded_int8.__wrapped__(
+        idx.mesh, K, k_loc, sk_loc, idx.ni_loc, True)(Q, *idx.score_args())
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    if state in ("appended", "full"):
+        assert np.isin(np.asarray(got[1]), idx.d_rows).any()

@@ -16,6 +16,7 @@ from tpu_als.ops.topk import (
     NEG_INF,
     ROW_MAJOR_BELOW,
     ShortlistPlan,
+    block_maxima,
     shortlist_columns,
     shortlist_plan,
     shortlist_topk,
@@ -65,6 +66,9 @@ SHAPES = [
     (1, 40_064, 16, 2),          # whole blocks: no pad
     (8, 70_001, 64, 2),
     (2, 300_000, 8, 2),          # block length 256
+    (8, 40_960, 16, 2),          # 320 blocks: whole tiles of 8 (the other
+    (32, 40_900, 16, 2),         # gather), whole and ragged last block,
+    (3, 40_960, 16, 2),          # rows that are no whole tile
 ]
 
 
@@ -129,6 +133,105 @@ def test_stage_two_is_lax_top_k_at_every_bucket(kind, n, N):
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
 
 
+def _with_a_tail(rng, kind, n, N, d, k):
+    """``(scores [n, N], tail [n, d])`` for the cases a tail decides."""
+    if kind == "ties_across":   # seven values: ties across the boundary
+        x = rng.integers(-3, 4, (n, N + d)).astype(np.float32)
+        x[:, N - 3:N + 3] = 3.0     # the largest, on both sides of it
+    elif kind == "all_in_tail":
+        x = rng.standard_normal((n, N + d)).astype(np.float32)
+        x[:, N:N + k] += 100.0
+    elif kind == "none_in_tail":
+        x = rng.standard_normal((n, N + d)).astype(np.float32)
+        x[:, N:] = NEG_INF          # an empty segment: every slot free
+    else:
+        assert kind == "sentinels"  # fewer than k finite entries a row
+        x = np.full((n, N + d), NEG_INF, np.float32)
+        x[:, ::3] = -np.inf
+        for row in x:
+            cols = rng.choice(N + d, size=int(rng.integers(0, k)),
+                              replace=False)
+            row[cols] = rng.integers(-2, 3, len(cols))
+            row[N + int(rng.integers(0, d))] = 1.0
+    return x[:, :N], x[:, N:]
+
+
+# (n, N, d, k, stages): whole and ragged last blocks, a one-stage plan, a
+# tail that is no whole block, blocks of 256, the buckets' row counts
+TAIL_SHAPES = [
+    (8, 40_064, 512, 16, 2),
+    (32, 40_064, 512, 16, 2),
+    (128, 40_064, 512, 16, 2),
+    (8, 40_000, 512, 16, 2),     # ragged last block AND a tail
+    (3, 20_259, 5, 16, 2),       # ragged, rows no whole tile, a short tail
+    (8, 4_231, 100, 8, 1),       # one stage: concatenated here
+    (4, 60, 8, 64, 1),           # fewer columns than k without the tail
+    (2, 300_000, 256, 8, 2),     # block length 256
+    (8, 40_960, 512, 16, 2),     # 320 blocks: whole tiles of 8
+    (128, 40_900, 512, 16, 2),   # the same, ragged
+]
+
+
+@pytest.mark.parametrize("kind", ["ties_across", "all_in_tail",
+                                  "none_in_tail", "sentinels"])
+@pytest.mark.parametrize("n,N,d,k,stages", TAIL_SHAPES)
+def test_a_tail_is_top_k_of_the_concatenation(kind, n, N, d, k, stages):
+    """``shortlist_topk(scores, k, tail=)`` against ``lax.top_k`` of the
+    two concatenated, values and indices: the tail's columns are
+    positions ``N ..`` of the answer, and no ``-inf`` pad of a ragged last
+    block is returned or shares one of them."""
+    plan = shortlist_plan(N, k, rows=n, tail=d)
+    assert (plan.stages, plan.columns, plan.tail) == (stages, N, d)
+    rng = np.random.default_rng(n + N + d)
+    x, t = (jnp.asarray(a) for a in _with_a_tail(rng, kind, n, N, d, k))
+    want_s, want_i = jax.lax.top_k(jnp.concatenate([x, t], axis=1), k)
+    got_s, got_i = jax.jit(shortlist_topk, static_argnums=1)(x, k, tail=t)
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    assert np.asarray(got_i).max() < N + d
+    if kind == "all_in_tail":       # as many winners as the tail can hold
+        assert np.asarray(got_i)[:, :min(d, k)].min() >= N
+    if kind == "none_in_tail" and N >= k:
+        assert np.asarray(got_i).max() < N
+
+
+@pytest.mark.parametrize("kind", ["random", "ties", "sentinels"])
+@pytest.mark.parametrize("L", [256, 384])
+@pytest.mark.parametrize("n", [8, 32, 128])
+def test_stage_one_through_the_lane_maxima_is_the_block_maximum(kind, L, n):
+    """Blocks longer than 128 lanes: the maximum over the maxima of a
+    block's 128-lane groups is the block's maximum, bit for bit."""
+    blocks = 37
+    rng = np.random.default_rng(L + n)
+    if kind == "random":
+        x = rng.standard_normal((n, blocks * L)).astype(np.float32)
+    elif kind == "ties":
+        x = rng.integers(-3, 4, (n, blocks * L)).astype(np.float32)
+    else:
+        x = rng.choice(np.array([NEG_INF, -np.inf, 0.5], np.float32),
+                       (n, blocks * L), p=[0.6, 0.399, 0.001])
+        x[:, :L] = -np.inf          # a block of nothing else
+    tiled = jnp.asarray(x).reshape(n // 8, 8, blocks, L)
+    lanes = jax.jit(block_maxima, static_argnums=1)(tiled, "lanes")
+    block = jax.jit(block_maxima, static_argnums=1)(tiled, "block")
+    want = x.reshape(n // 8, 8, blocks, L).max(axis=-1)
+    np.testing.assert_array_equal(np.asarray(lanes), want)
+    np.testing.assert_array_equal(np.asarray(block), want)
+
+
+@pytest.mark.parametrize("N,k,L", [(300_000, 8, 256), (589_824, 4, 384),
+                                   (600_000, 4, 384)])
+def test_long_blocks_plan_the_lane_maxima_and_select_as_top_k(N, k, L):
+    plan = shortlist_plan(N, k, rows=8)
+    assert (plan.stages, plan.block_len, plan.blockmax) == (2, L, "lanes")
+    rng = np.random.default_rng(N)
+    x = jnp.asarray(rng.integers(-3, 4, (8, N)).astype(np.float32))
+    want_s, want_i = jax.lax.top_k(x, k)
+    got_s, got_i = jax.jit(shortlist_topk, static_argnums=1)(x, k)
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+
+
 def test_rows_decide_the_layout_and_nothing_else():
     two = shortlist_plan(1_506_048, 64)
     assert ROW_MAJOR_BELOW == 56
@@ -172,17 +275,21 @@ def test_plan_is_whole_blocks_near_the_square_root(N, k):
 
 def test_the_benchmark_cells_plan():
     assert shortlist_plan(1_505_938, 64) == ShortlistPlan(
-        stages=2, blocks=11_766, block_len=128, columns=1_505_938)
+        stages=2, blocks=11_766, block_len=128, columns=1_505_938,
+        blockmax="block")
     assert shortlist_columns(1_505_938, 64) == 1_506_048
-    # the cells' programs, bucket by bucket: steady and fold-in; with the
-    # live segment's 512 slots; one shard of the mesh cell
-    for columns, blocks, block_len in ((1_506_048, 11_766, 128),
-                                       (1_530_368, 11_956, 128),
-                                       (3_012_096, 11_766, 256)):
+    # the cells' programs, bucket by bucket: steady and fold-in; the live
+    # catalog's base with the segment's 512 slots as its tail; one shard
+    # of the mesh cell, whose blocks of 256 go through the lane maxima
+    for columns, blocks, block_len, blockmax, tail in (
+            (1_506_048, 11_766, 128, "block", 0),
+            (1_529_856, 11_952, 128, "block", 512),
+            (3_012_096, 11_766, 256, "lanes", 0)):
         for bucket, layout in ((8, "row_major"), (32, "row_major"),
                                (128, "compiler")):
-            assert shortlist_plan(columns, 64, rows=bucket) == ShortlistPlan(
-                2, blocks, block_len, columns, layout)
+            assert shortlist_plan(columns, 64, rows=bucket, tail=tail) \
+                == ShortlistPlan(2, blocks, block_len, columns, layout,
+                                 blockmax, tail)
 
 
 @pytest.mark.parametrize("N,k", [(1, 1), (63, 64), (2_000, 64),
@@ -266,8 +373,10 @@ def test_big_delta_and_compact_bitwise_against_rebuild(big):
     valid2 = np.concatenate([valid, np.ones(len(appended), bool)])
     valid2[touched] = True
     upd = idx.with_updates(rows, V2[rows], seq=2)
-    assert upd.shortlist_plan().stages == 2
-    assert upd.shortlist_plan().columns == 40_064 + 256
+    # the base's columns in two stages, the segment's slots their tail
+    assert upd.shortlist_plan() == shortlist_plan(40_064, SHORTLIST,
+                                                  tail=256)
+    assert upd.shortlist_plan()[:4] == idx.shortlist_plan()[:4]
     ref = build_index(V2, item_valid=valid2, shortlist_k=SHORTLIST, seq=2)
     Uq = jnp.asarray(U)
     _assert_bitwise(upd, ref, Uq, 5)
@@ -304,7 +413,7 @@ def test_big_sharded_index_two_stages_per_shard(big):
                                   np.asarray(one.topk(jnp.asarray(U), 5)[1]))
     rows = np.array([5, 9_001, 71_999], dtype=np.int64)
     upd = sh.with_updates(rows, 4.0 * V[rows], seq=2)
-    assert upd.shortlist_plan() == shortlist_plan(cols + 4, SHORTLIST)
+    assert upd.shortlist_plan() == shortlist_plan(cols, SHORTLIST, tail=4)
     V2 = V.copy()
     V2[rows] *= 4.0
     s2, ix2 = upd.topk(jnp.asarray(U), 5)
@@ -333,21 +442,25 @@ def test_warmup_reports_the_plan_the_program_was_traced_with(_fresh, big):
         # stage two asked of the compiler for THIS program
         assert e["blocks_layout"] == "row_major" == \
             eng.published_index.shortlist_plan(rows=e["bucket"]).blocks_layout
+        assert (e["blockmax"], e["tail"]) == ("block", 0)
     eng.warmup_live()
     live = _shortlist_events(_fresh)[2:]
     idx = eng.published_index
     # ONE program a bucket for "with a segment": the catalog's spare
-    # rows and the segment's slots fix its shapes, base + segment in
-    # whole blocks (no batch pays for a ragged last one)
+    # rows and the segment's slots fix its shapes, the base in whole
+    # blocks (no batch pays for a ragged last one) and the segment's
+    # slots joined at stage three
     assert idx.n_base == row_capacity(BIG_ITEMS) and idx.delta_slots == 512
-    columns = int(idx.Vq.shape[0]) + 512
-    assert [(e["bucket"], e["delta_rows"], e["columns"]) for e in live] == [
-        (8, 512, columns), (32, 512, columns)]
+    columns = int(idx.Vq.shape[0])
+    assert [(e["bucket"], e["delta_rows"], e["tail"], e["columns"])
+            for e in live] == [(8, 512, 512, columns),
+                               (32, 512, 512, columns)]
     assert all(e["stages"] == 2 and e["columns"] % e["block_len"] == 0
-               for e in live)
-    assert all(tuple(shortlist_plan(e["columns"], SHORTLIST, e["bucket"]))
+               and e["blockmax"] == "block" for e in live)
+    assert all(tuple(shortlist_plan(e["columns"], SHORTLIST, e["bucket"],
+                                    tail=512))
                == (e["stages"], e["blocks"], e["block_len"], e["columns"],
-                   e["blocks_layout"])
+                   e["blocks_layout"], e["blockmax"], e["tail"])
                for e in live)
 
 
@@ -359,5 +472,6 @@ def test_warmup_reports_one_stage_on_a_small_catalog(_fresh):
     eng.warmup()
     (e,) = _shortlist_events(_fresh)
     assert (e["bucket"], e["path"], e["stages"], e["blocks"],
-            e["block_len"], e["columns"], e["blocks_layout"]) == (
-                8, "int8", 1, 1, 300, 300, "compiler")
+            e["block_len"], e["columns"], e["blocks_layout"],
+            e["blockmax"], e["tail"]) == (
+                8, "int8", 1, 1, 300, 300, "compiler", "none", 0)

@@ -592,7 +592,7 @@ EVENTS = {
         "devices); an engine without a mesh emits none"),
     "serving_shortlist": (
         ("bucket", "path", "stages", "blocks", "block_len", "columns",
-         "blocks_layout"),
+         "blocks_layout", "blockmax", "tail"),
         "one per int8 scoring program ServingEngine.warmup / warmup_live "
         "compiles (per bucket and path; warmup_live adds delta_rows, the "
         "segment's slots): how "
@@ -603,7 +603,16 @@ EVENTS = {
         "(the block maxima constrained to blocks-along-lanes, for a "
         "bucket under ops.topk.ROW_MAJOR_BELOW rows: on the TPU they "
         "leave the score fusion with the batch's rows along the 128 "
-        "lanes, 8 of 128 filled at bucket 8) or compiler (no constraint)"),
+        "lanes, 8 of 128 filled at bucket 8) or compiler (no constraint); "
+        "blockmax is how stage one reduces a block: block (its 128 lanes "
+        "at once), lanes (a block longer than 128 folded to 128 lanes "
+        "first, the elementwise maximum of its 128-lane groups: the v5e "
+        "compiler does not fuse a reduce over 256 lanes into the fusion "
+        "that computes the scores, and the fold halves what that pass of "
+        "its own costs) or none (one stage); tail is the columns joined "
+        "to stage three's winners instead of to the score matrix (a "
+        "delta segment's slots; 0 without one; columns counts the "
+        "matrix's own)"),
     "serving_exclusion": (
         ("bucket", "path", "history_pad", "request_pad", "rows", "ids",
          "columns", "block", "words", "mask_bytes", "keys"),

@@ -166,28 +166,37 @@ ROW_MAJOR_BELOW = 56
 
 class ShortlistPlan(NamedTuple):
     """How :func:`shortlist_topk` selects ``k`` of ``columns`` scores a
-    row: in one ``lax.top_k`` (``stages`` 1, one block of all columns)
-    or over ``blocks`` contiguous blocks of ``block_len`` columns, and
-    what stage two asks of the compiler for its operand
-    (``blocks_layout``: ``"row_major"`` = the block maxima constrained
-    to blocks-along-lanes before ``top_k``, ``"compiler"`` = no
-    constraint, the layout is the compiler's)."""
+    row (and of ``tail`` more behind them): in one ``lax.top_k``
+    (``stages`` 1, one block of all columns) or over ``blocks``
+    contiguous blocks of ``block_len`` columns; how stage one reduces a
+    block (``blockmax``: ``"block"`` = each block of 128 lanes at once,
+    ``"lanes"`` = a longer block folded to 128 lanes first, the
+    elementwise maximum of its 128-lane groups, ``"none"`` with one
+    stage); what stage two asks of the
+    compiler for its operand (``blocks_layout``: ``"row_major"`` = the
+    block maxima constrained to blocks-along-lanes before ``top_k``,
+    ``"compiler"`` = no constraint, the layout is the compiler's); and
+    how many columns stage three joins to its winners (``tail``)."""
 
     stages: int
     blocks: int
     block_len: int
     columns: int
     blocks_layout: str = "compiler"
+    blockmax: str = "none"
+    tail: int = 0
 
 
-def shortlist_plan(columns, k, rows=None):
+def shortlist_plan(columns, k, rows=None, tail=0):
     """The selection :func:`shortlist_topk` compiles for a ``[rows,
-    columns]`` score matrix and ``k`` — a function of those static
-    numbers and nothing else (no argument, environment variable, planner
-    entry or probe), so an event that reports it cannot disagree with
-    the program.  ``rows`` decides ``blocks_layout`` alone (row-major
-    below :data:`ROW_MAJOR_BELOW` rows); a plan asked for without it is
-    the plan of the columns, its layout left at ``"compiler"``.
+    columns]`` score matrix, ``k`` and a ``[rows, tail]`` tail — a
+    function of those static numbers and nothing else (no argument,
+    environment variable, planner entry or probe), so an event that
+    reports it cannot disagree with the program.  ``rows`` decides
+    ``blocks_layout`` alone (row-major below :data:`ROW_MAJOR_BELOW`
+    rows); a plan asked for without it is the plan of the columns, its
+    layout left at ``"compiler"``.  ``tail`` decides nothing: the stages
+    and blocks are those of the ``columns`` alone.
 
     Two stages read ``columns`` scores once for the block maxima and
     then run ``TopK`` over ``blocks + k * block_len`` of them, which is
@@ -197,7 +206,7 @@ def shortlist_plan(columns, k, rows=None):
     fewer than ``k`` blocks to choose from) the single ``lax.top_k``
     stays: every small catalog.
     """
-    columns, k = int(columns), int(k)
+    columns, k, tail = int(columns), int(k), int(tail)
     if k >= 1:
         block_len = 128 * max(1, int(math.sqrt(columns / k) / 128 + 0.5))
         blocks = -(-columns // block_len)
@@ -205,8 +214,9 @@ def shortlist_plan(columns, k, rows=None):
             return ShortlistPlan(
                 2, blocks, block_len, columns,
                 "row_major" if rows is not None and rows < ROW_MAJOR_BELOW
-                else "compiler")
-    return ShortlistPlan(1, 1, columns, columns)
+                else "compiler",
+                "lanes" if block_len > 128 else "block", tail)
+    return ShortlistPlan(1, 1, columns, columns, tail=tail)
 
 
 def shortlist_columns(columns, k):
@@ -221,7 +231,30 @@ def shortlist_columns(columns, k):
         columns = plan.blocks * plan.block_len
 
 
-def shortlist_topk(scores, k):
+def block_maxima(tiled, blockmax):
+    """Stage one of :func:`shortlist_topk`: the maximum of every block
+    of the ``[..., blocks, L]`` view, ``[..., blocks]`` — over the block
+    at once (``blockmax`` = ``"block"``), or, for ``L`` a multiple of 128
+    above it (``"lanes"``), with the block's 128-lane groups folded into
+    one first, elementwise, and that reduced over its lanes: the same
+    values bit for bit, a maximum of maxima.  What is dear in this pass on
+    the TPU is the reduce ACROSS lanes, one for every 8 rows by 128 lanes;
+    the fold halves their number for a block of 256 at the price of an
+    elementwise maximum (PERF.md section 6, PR 43: 0.165 -> 0.068 ms for
+    8 rows of 3.0 M columns; the forms that were timed are there)."""
+    *lead, blocks, L = tiled.shape
+    if blockmax == "lanes":
+        groups = tiled.reshape(*lead, blocks, L // 128, 128)
+        # slice by slice: a ``max`` over the groups' axis and one over the
+        # lanes the compiler merges back into the reduce over the block
+        folded = groups[..., 0, :]
+        for g in range(1, L // 128):
+            folded = jnp.maximum(folded, groups[..., g, :])
+        return jnp.max(folded, axis=-1)
+    return jnp.max(tiled, axis=-1)
+
+
+def shortlist_topk(scores, k, tail=None):
     """``jax.lax.top_k(scores, k)`` for an f32 ``[n, N]`` matrix, element
     for element (values, indices, ties to the lower index, sentinels
     included), in two exact stages where :func:`shortlist_plan` says
@@ -238,8 +271,24 @@ def shortlist_topk(scores, k):
     ``[n, k * L]`` is column order and the second ``top_k`` breaks ties
     as the single one would.
 
+    ``tail`` (f32 ``[n, d]``): scores of ``d`` more columns that follow
+    the matrix's own — positions ``N .. N + d - 1`` of the result — and
+    the answer is ``lax.top_k(concatenate([scores, tail], 1), k)``'s.
+    The matrix alone goes through stages one and two; the tail is joined
+    to stage three's winners before its ``top_k``.  Exact for the reason
+    above (a member of the top ``k`` of matrix and tail that lies in the
+    matrix is in the matrix's own top ``k``, and every tail column is a
+    candidate), and the joined ``[n, k * L + d]`` is in column order.  A
+    caller that concatenated instead (a delta segment's 512 scores behind
+    1.5 M) handed stage one a matrix whose block maximum the v5e compiler
+    does not fuse into the fusion that computes the scores: it read the
+    matrix a second time, 0.11 ms a batch of 8 rows (PERF.md section 6,
+    PR 43).  With one stage the two are concatenated here.
+
     A ragged last block is padded with ``-inf`` — below every score
-    and, being last, behind every real column in a tie.  An index that
+    and, being last, behind every real column in a tie (``k * L - L +
+    1`` or more real columns precede the pad in stage three, so no pad
+    is returned, tail or none).  An index that
     knows its shape ahead pads its catalog to
     :func:`shortlist_columns` instead and skips that copy.  The matrix
     is viewed as ``[n / 8, 8, blocks, L]``: on the TPU, whose f32 tile
@@ -247,6 +296,30 @@ def shortlist_topk(scores, k):
     bytes, and both the block maximum and the gather read it in place
     (the flat ``[n, blocks, L]`` view cost two relayout copies of the
     whole matrix at ``n`` = 128: compiled HLO for the v5e, PR 26).
+
+    Stage one (:func:`block_maxima`) reduces a block of 128 columns at
+    once: the v5e compiler makes that reduce a second result of the
+    fusion that computes the scores, for a batch whose matrix it keeps in
+    fast memory (8 rows).  A reduce over a longer block it does not fuse:
+    a pass of its own over the written matrix, 0.165 ms a batch of the
+    mesh cell (blocks of 256).  There (``blockmax`` of the plan:
+    ``"lanes"``) the block's 128-lane groups are folded into one first,
+    elementwise, which leaves that pass half its reduces across lanes
+    (0.068 ms).  Asked for the groups' maxima first, the compiler does
+    fuse them into the score fusion — and that fusion, with 96 MB of
+    matrix and the maxima's 12 MB (8 of 128 lanes filled) in fast memory,
+    takes smaller windows and 0.09 ms longer: the slower form (PERF.md
+    section 6, PR 43).
+
+    Where the blocks come in whole tiles of 8 (``blocks % 8 == 0``: the
+    live-items cell's 11,952) the v5e compiler gives the ``[n / 8, 8,
+    blocks, L]`` view its default layout before a gather along
+    ``blocks`` — legal there without padding — and COPIES the matrix into
+    it: 0.043 ms a batch of 8 rows, 0.59 of 32, 2.36 of 128 (PR 43).
+    There the winners are taken as rows of ``[n / 8 * blocks * 8, L]``,
+    the view transposed to the order its bytes already have, which
+    compiles to the row gather of the matrix in place that the other
+    block counts get by themselves.
 
     The block maxima leave that reduce (on the chip: the score fusion)
     with the blocks along the sublanes and the batch's ``n`` rows along
@@ -257,8 +330,11 @@ def shortlist_topk(scores, k):
     no value.
     """
     n, N = scores.shape
-    plan = shortlist_plan(N, k, rows=n)
+    plan = shortlist_plan(N, k, rows=n,
+                          tail=0 if tail is None else tail.shape[1])
     if plan.stages == 1:
+        if tail is not None:
+            scores = jnp.concatenate([scores, tail], axis=1)
         return jax.lax.top_k(scores, k)
     L, blocks = plan.block_len, plan.blocks
     if blocks * L != N:
@@ -267,7 +343,7 @@ def shortlist_topk(scores, k):
     sub = 8 if n % 8 == 0 else n
     tiled = scores.reshape(n // sub, sub, blocks, L)
     with jax.named_scope("serve.shortlist.blockmax"):
-        block_max = jnp.max(tiled, axis=-1).reshape(n, blocks)
+        block_max = block_maxima(tiled, plan.blockmax).reshape(n, blocks)
     with jax.named_scope("serve.shortlist.blocks"):
         if plan.blocks_layout == "row_major":
             block_max = with_layout_constraint(
@@ -275,12 +351,26 @@ def shortlist_topk(scores, k):
         _, block_ids = jax.lax.top_k(block_max, k)
         block_ids = jnp.sort(block_ids, axis=1)
     with jax.named_scope("serve.shortlist.gather"):
-        won = jnp.take_along_axis(
-            tiled, block_ids.reshape(n // sub, sub, k, 1), axis=2)
+        if blocks % 8:
+            won = jnp.take_along_axis(
+                tiled, block_ids.reshape(n // sub, sub, k, 1), axis=2)
+        else:
+            # the same rows, taken from the view's own bytes (docstring)
+            rows = jnp.transpose(tiled, (0, 2, 1, 3)).reshape(-1, L)
+            row = jnp.arange(n, dtype=jnp.int32)[:, None]
+            at = ((row // sub) * blocks + block_ids) * sub + row % sub
+            won = rows.at[at.reshape(-1)].get(mode="promise_in_bounds")
     with jax.named_scope("serve.shortlist.select"):
-        values, pos = jax.lax.top_k(won.reshape(n, k * L), k)
-        cols = (jnp.take_along_axis(block_ids, pos // L, axis=1) * L
-                + pos % L)
+        flat = won.reshape(n, k * L)
+        if tail is not None:
+            flat = jnp.concatenate([flat, tail], axis=1)
+        values, pos = jax.lax.top_k(flat, k)
+        # a position past the winners is the tail's
+        inb = pos if tail is None else jnp.minimum(pos, k * L - 1)
+        cols = (jnp.take_along_axis(block_ids, inb // L, axis=1) * L
+                + inb % L)
+        if tail is not None:
+            cols = jnp.where(pos < k * L, cols, N + pos - k * L)
     return values, cols
 
 

@@ -210,7 +210,6 @@ from tpu_als.ops.topk import (
     NOT_AN_ID,
     chunked_topk_scores,
     exclusion_plan,
-    shortlist_plan,
 )
 from tpu_als.resilience import faults
 from tpu_als.serving.batcher import (
@@ -974,18 +973,13 @@ class ServingEngine:
         from: the compaction threshold (:meth:`_compact_rows`) plus one
         ``max_batch`` — the most a segment holds before a publish folds
         it back — or ``at_least`` where a caller asks for more, as a
-        power of two, in whole blocks of the shortlist over base +
-        segment so that no batch pays for a ragged last block.  Resolved
-        here, once: a segment has no other size while the engine serves
-        from it."""
+        power of two (the segment's scores join the shortlist at its
+        last stage, ``ops.topk.shortlist_topk``'s ``tail``: they are in
+        no block, so any number does).  Resolved here, once: a segment
+        has no other size while the engine serves from it."""
         rows = max(self._compact_rows(index)
                    + self._live_cadence()["max_batch"], at_least or 0)
-        slots = _next_pow2(int(np.ceil(rows)))
-        cols = index.shortlist_plan().columns - index.delta_slots
-        plan = shortlist_plan(cols + slots, index.shortlist_k)
-        if plan.stages == 2:
-            slots = -(-slots // plan.block_len) * plan.block_len
-        return max(slots, index.delta_slots)
+        return max(_next_pow2(int(np.ceil(rows))), index.delta_slots)
 
     def _compact_live(self):
         """Fold the live generation's delta segment into its base

@@ -196,7 +196,10 @@ def _int8_topk_delta(U, Vq, sv, V, valid, drows, dVq, dsv, dV, dvalid,
                      last_id, k, shortlist_k):
     """The base kernel with a delta segment: two int8 GEMMs (base +
     segment), overridden base columns masked, one shortlist over the
-    concatenated approx scores, and the SAME-shaped exact rescore as
+    base's approx scores with the segment's as its ``tail`` (what a
+    shortlist over the two concatenated returns, without the second pass
+    over the matrix that the concatenation cost: ``ops.topk.
+    shortlist_topk``), and the SAME-shaped exact rescore as
     the base path (see module docstring for why this stays bitwise).
 
     ``drows`` maps segment slots to logical catalog ids; free slots
@@ -220,8 +223,8 @@ def _int8_topk_delta(U, Vq, sv, V, valid, drows, dVq, dsv, dV, dvalid,
                        preferred_element_type=jnp.int32)
     approx_d = acc_d.astype(jnp.float32) * su[:, None] * dsv[None, :]
     approx_d = jnp.where(dvalid[None, :], approx_d, NEG_INF)
-    approx = jnp.concatenate([approx_b, approx_d], axis=1)
-    _, cand = shortlist_topk(approx, shortlist_k)   # positions in nb+d
+    # positions in nb + d
+    _, cand = shortlist_topk(approx_b, shortlist_k, tail=approx_d)
     flat = cand.reshape(-1)
     in_base = flat < nb
     base_ix = jnp.minimum(flat, nb - 1)
@@ -317,10 +320,11 @@ class Int8CandidateIndex:
 
     def shortlist_plan(self, rows=None):
         """The selection :meth:`topk` compiles for this index as it
-        stands (delta segment included) and a batch of ``rows``
-        queries: the ``ops.topk.shortlist_plan`` of its score matrix."""
-        return shortlist_plan(int(self.Vq.shape[0]) + self.delta_slots,
-                              self.shortlist_k, rows)
+        stands and a batch of ``rows`` queries: the
+        ``ops.topk.shortlist_plan`` of its base's score matrix, the
+        delta segment's slots its ``tail``."""
+        return shortlist_plan(int(self.Vq.shape[0]), self.shortlist_k, rows,
+                              tail=self.delta_slots)
 
     # -- delta segment (incremental re-quantization) -------------------
 
@@ -659,11 +663,12 @@ def _shard_score(U, Vq, sv, V, valid, delta, *, me, k_loc, sk_loc, ni_loc):
         approx_d = (acc_d.astype(jnp.float32)
                     * su[:, None] * dsv[None, :])
         approx_d = jnp.where(dmask[None, :], approx_d, NEG_INF)
-        approx = jnp.concatenate([approx, approx_d], axis=1)
     else:
         base_ok = valid
         approx = jnp.where(base_ok[None, :], approx, NEG_INF)
-    _, cand = shortlist_topk(approx, sk_loc)
+        approx_d = None
+    # with a segment: positions in ni_loc + d
+    _, cand = shortlist_topk(approx, sk_loc, tail=approx_d)
     flat = cand.reshape(-1)
     if delta:
         in_base = flat < ni_loc
@@ -834,8 +839,11 @@ class ShardedInt8Index(Int8CandidateIndex):
             self.mesh, jax.sharding.PartitionSpec()))
 
     def shortlist_plan(self, rows=None):
-        cols = self.ni_loc + self.delta_slots     # what one shard scores
-        return shortlist_plan(cols, min(self.shortlist_k, cols), rows)
+        # what one shard scores: its rows, and the whole segment behind
+        d = self.delta_slots
+        return shortlist_plan(self.ni_loc,
+                              min(self.shortlist_k, self.ni_loc + d), rows,
+                              tail=d)
 
     def _copy_extra(self, new):
         new.mesh = self.mesh

@@ -25,8 +25,10 @@ phases) and that thread's wait for a batch (``completion_idle``), the
 two halves of dispatch (``upload``, ``launch``: median, mean, longest in
 ms) with how the staged batch reached the device (``upload_how_pct``: the
 share of ``call`` — it rode the scoring call as its host argument, the
-transfer is inside ``launch`` — and of ``put``, a separate ``device_put``,
-which no path makes since PR 41) and, for a stream a profiler watched, each
+transfer is inside ``launch`` — of ``put_one``, a mesh engine's since PR
+44: one transfer to the mesh's first device, inside ``upload``, and the
+program spreads it — and of ``put``, a separate ``device_put``, which no
+path makes since PR 41) and, for a stream a profiler watched, each
 phase's CPU share
 (``cpu_pct``: the thread's own CPU time over the phase's wall time, summed
 over the window — what is missing the thread spent without a processor, or
@@ -48,7 +50,9 @@ bucket; since PR 43 ``blockmax``, how stage one reduces a block —
 ``lanes`` on the mesh cell's blocks of 256, ``block`` elsewhere — and
 ``tail``, the delta segment's slots that join at stage three: 512 in the
 live-items cell, 0 elsewhere), and for an engine given a mesh its
-``serving_mesh_plan`` events (one a bucket ``warmup()`` pinned) with
+``serving_mesh_plan`` events (one a bucket ``warmup()`` pinned; since PR
+44 with ``placements``, the transfers a staged batch takes, and
+``spread_bytes``, what the program's first all-reduce moves for it) with
 the process's ``serving.mesh_exchange_bytes``: the mesh path read
 without a profiler.  No CPU mode (``run.py`` has none):
 

@@ -338,7 +338,7 @@ def test_stage_two_topk_layout_on_every_shard_of_the_mesh_cell(
     fn = _build_mesh_serve(mesh, 10, 10, 64, ni, False)
     c = fn.lower(
         shape((shards * MESH_USERS_PER_SHARD, r), jnp.float32, rows),
-        shape((bucket, r + 2), jnp.int32, whole),
+        shape((shards * bucket, r + 2), jnp.int32, rows),   # _place_one's
         shape((shards * ni, r), jnp.int8, rows),
         shape((shards * ni,), jnp.float32, rows),
         shape((shards * ni, r), jnp.float32, rows),
@@ -357,14 +357,17 @@ HISTORY_PADS = (64, 512, 4096)
 # since — PR 43's stage one keeps the parent's expression for blocks of 128
 # lanes without a tail.  ``delta`` and ``mesh``: pinned again at PR 43,
 # whose stage one they run (the segment's scores a tail, blocks of 256
-# through the lane maxima)
+# through the lane maxima); ``mesh`` once more at PR 44, whose entry it
+# takes: the staged batch ``[S * B, rank + 2]`` by rows and one
+# ``all_reduce`` of its block before the lookup, the rest of the text
+# PR 43's but for the numbering
 PARENT_LOWERED = {
     ("steady", 8): "35ea3052c100ffc5", ("steady", 32): "abfcefa10f9a23f9",
     ("steady", 128): "f61c331f33283e9b",
     ("delta", 8): "099e8ce54c689f77", ("delta", 32): "7f6b3ce1d9918a52",
     ("delta", 128): "d225a128acc850eb",
-    ("mesh", 8): "93dd3ee999387067", ("mesh", 32): "568ce002adf0855d",
-    ("mesh", 128): "34a54c00de2d742b",
+    ("mesh", 8): "8906500ff0b43bb7", ("mesh", 32): "4e28d421bd393208",
+    ("mesh", 128): "462781274ca22ec9",
 }
 
 
@@ -411,7 +414,7 @@ def _lowered(name, bucket, topo, one_chip):
 
     return _build_mesh_serve(mesh, 10, 10, 64, ni, False).lower(
         shape((shards * MESH_USERS_PER_SHARD, r), jnp.float32, rows),
-        shape((bucket, r + 2), jnp.int32, whole),
+        shape((shards * bucket, r + 2), jnp.int32, rows),   # _place_one's
         shape((shards * ni, r), jnp.int8, rows),
         shape((shards * ni,), jnp.float32, rows),
         shape((shards * ni, r), jnp.float32, rows),

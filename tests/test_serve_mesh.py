@@ -17,7 +17,11 @@ from tpu_als.parallel.comm_audit import collective_bytes
 from tpu_als.parallel.mesh import AXIS, shard_map
 from tpu_als.serving import engine as engine_module
 from tpu_als.serving.engine import ServingEngine, _mesh_lookup
-from tpu_als.serving.index import SCORE_ULPS, mesh_exchange_bytes
+from tpu_als.serving.index import (
+    SCORE_ULPS,
+    mesh_exchange_bytes,
+    mesh_spread_bytes,
+)
 
 S, N_USERS, N_ITEMS, RANK, K = 4, 5003, 2003, 32, 10
 BUCKETS = (8, 32)
@@ -281,17 +285,21 @@ def test_exchange_bytes_match_the_traced_program(bucket):
     _, U, V = factors(7)
     eng = engine(U, V)
     m = eng._model
-    packed = jax.device_put(np.zeros((bucket, RANK + 2), np.int32),
-                            eng._replicated)
+    packed = eng._proto(bucket, RANK)       # placed as a batch is
+    spread = mesh_spread_bytes(S, bucket, RANK)
     for call, idx in ((eng._int8_call(m, m.index, packed), m.index),
                       (eng._exact_call(m, packed), None)):
         fn, args, _ = call
         traced, breakdown = collective_bytes(fn, *args, axis_size=S)
         plan = eng._mesh_plan(m, idx, bucket)
         assert set(breakdown) == {"psum", "all_gather"}
+        # the batch's spread and the lookup, an all-reduce each
+        assert breakdown["psum"] == spread + 2 * (S - 1) * bucket * RANK
+        assert (plan["placements"], plan["spread_bytes"]) == (1, spread)
         assert traced == plan["exchange_bytes"] == mesh_exchange_bytes(
             S, bucket, RANK, K)
-    assert mesh_exchange_bytes(4, 8, 256, 10) == 12288 + 1920
+    assert mesh_spread_bytes(4, 8, 256) == 12384       # 1.5 x 8,256
+    assert mesh_exchange_bytes(4, 8, 256, 10) == 12384 + 12288 + 1920
 
 
 # -- the exact fallback ----------------------------------------------------------
@@ -360,3 +368,163 @@ def test_shard_score_with_a_segment_answers_as_concatenated(
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
     if state in ("appended", "full"):
         assert np.isin(np.asarray(got[1]), idx.d_rows).any()
+
+
+# -- (h) one placement a batch (PR 44) ----------------------------------------
+
+ALL_BUCKETS = (8, 32, 128)
+
+
+def given_to_every_shard(monkeypatch, eng, path, st):
+    """The packed response of the parent's form of a mesh engine's
+    ``path`` program: the engine's own builder (behind its cache: traced
+    now) with the spread taken out, on the staged batch given to every
+    shard whole — a placement a shard."""
+    monkeypatch.setattr(engine_module, "_mesh_spread",
+                        lambda block, axis: block)
+    m = eng._model
+    whole = jax.device_put(np.tile(st, (S, 1)), eng._by_rows)
+    if path == "int8":
+        _, args, _ = eng._int8_call(m, m.index, whole)
+        fn = engine_module._build_mesh_serve.__wrapped__(
+            eng.mesh, K, *m.index.shard_widths(K), m.index.ni_loc, False)
+    else:
+        _, args, _ = eng._exact_call(m, whole)
+        ni_loc = int(m.V.shape[0]) // S
+        fn = engine_module._build_mesh_exact.__wrapped__(
+            eng.mesh, K, min(K, ni_loc), ni_loc, min(eng.item_chunk, ni_loc))
+    return np.asarray(fn(*args))
+
+
+def staged_batch(rng, U, bucket, rows):
+    """A staged batch as ``_staged`` lays it out: ``rows`` requests, ids
+    of every shard and every fourth by vector, then pad slots."""
+    st = np.zeros((bucket, RANK + 2), np.int32)
+    st[:rows, RANK] = rng.choice(N_USERS, rows, replace=False)
+    st[:4, RANK] = [0, N_USERS - 1, N_USERS // 2, N_USERS // 4]
+    for j in range(3, rows, 4):
+        st[j, :RANK] = (U[st[j, RANK]] + rng.standard_normal(RANK).astype(
+            np.float32) / 8).view(np.int32)
+        st[j, RANK:] = 0, 1
+    return st
+
+
+@pytest.fixture(scope="module")
+def wide_engine():
+    rng, U, V = factors(44)
+    eng = ServingEngine(k=K, buckets=ALL_BUCKETS, shortlist_k=128,
+                        mesh=make_mesh(S))
+    eng.publish(U, V)
+    eng.warmup()
+    return rng, U, eng
+
+
+@pytest.mark.parametrize("path", ["int8", "exact"])
+@pytest.mark.parametrize("bucket", ALL_BUCKETS)
+def test_one_placement_answers_as_a_placement_a_shard_did(
+        monkeypatch, wide_engine, bucket, path):
+    """The program that spreads ONE placed block answers, bit for bit, as
+    the parent's program did on the batch given to every shard whole: the
+    int8 program and the exact fallback, every bucket."""
+    rng, U, eng = wide_engine
+    m = eng._model
+    st = staged_batch(rng, U, bucket, bucket - 3)
+    call = eng._int8_call if path == "int8" else eng._exact_call
+    _, args, _ = call(*((m, m.index) if path == "int8" else (m,)),
+                      eng._place_one(st))
+    got = np.asarray(eng._pinned[(bucket, path)](*args))
+    want = given_to_every_shard(monkeypatch, eng, path, st)
+    assert got.shape == (bucket, 2 * K)
+    assert got.tobytes() == want.tobytes()
+    # by id and by vector, rows of every shard: not the same answer twice
+    assert len({r.tobytes() for r in got[:bucket - 3]}) == bucket - 3
+
+
+def blocks(placed):
+    """A placed batch's shards, in the order of their rows."""
+    return sorted(placed.addressable_shards, key=lambda s: s.index[0].start)
+
+
+def test_place_one_transfers_one_block_and_keeps_the_others(wide_engine):
+    rng, U, eng = wide_engine
+    st = staged_batch(rng, U, 8, 5)
+    first, second = eng._place_one(st), eng._place_one(st.copy())
+    assert first.shape == (S * 8, RANK + 2) and first.dtype == np.int32
+    assert first.sharding.is_equivalent_to(eng._by_rows, 2)
+    assert [s.device for s in blocks(first)] == list(eng.mesh.devices.flat)
+    assert np.asarray(blocks(first)[0].data).tobytes() == st.tobytes()
+    for s in blocks(first)[1:]:
+        assert not np.asarray(s.data).any()
+    # the zeros lie where they lay: the same buffers in every batch's array
+    where = [[s.data.unsafe_buffer_pointer() for s in blocks(a)]
+             for a in (first, second)]
+    assert where[0][1:] == where[1][1:] and where[0][0] != where[1][0]
+
+
+@pytest.mark.parametrize("path", ["int8", "exact"])
+def test_the_pin_takes_the_sharding_dispatch_hands_it_and_never_compiles(
+        path):
+    """``warmup()``'s prototype is placed as a batch is, so the pinned
+    program's input sharding is the batch's, the pin takes all of 50
+    batches and nothing compiles (the jit call after a dropped pin:
+    ``tests/test_serving_dispatch.py``)."""
+    from tests.conftest import CompileCount
+
+    rng, U, V = factors(45)
+    eng = ServingEngine(k=K, buckets=BUCKETS, shortlist_k=128,
+                        mesh=make_mesh(S))
+    eng.publish(U, V, quantize=path == "int8")
+    eng.warmup()
+    for B in BUCKETS:
+        packed_in = eng._pinned[(B, path)].input_shardings[0][1]
+        handed = eng._place_one(eng._staged((), B, RANK)).sharding
+        assert packed_in.is_equivalent_to(handed, 2)
+        assert not packed_in.is_fully_replicated
+    pin = eng._pinned[(8, path)] = CountingCalls(eng._pinned[(8, path)])
+    compiles = CompileCount()
+    for j in range(50):
+        drain(eng, [int(rng.integers(N_USERS)) for _ in range(1 + j % 8)])
+    assert (pin.calls, compiles.n) == (50, 0)
+
+
+def test_upload_span_and_plan_event_say_one_placement(tmp_path):
+    """The words that say the entry engaged: the upload span's ``how`` and
+    the batch record's ``upload_how`` read ``put_one`` (with the staged
+    array's bytes), every ``serving_mesh_plan`` event ``placements`` 1
+    with the spread's bytes, and ``obs/schema.py`` declares them."""
+    import inspect
+
+    from tests.test_serving_spans import _spans_by_line
+    from tpu_als.obs import schema
+
+    reg = obs.reset()
+    try:
+        rng, U, V = factors(46)
+        eng = engine(U, V)
+        drain(eng, [1, 2, 3])                    # the bucket's program ran
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            drain(eng, [5, 2600, N_USERS - 1])
+            drain(eng, list(range(20)))
+        finally:
+            jax.profiler.stop_trace()
+        uploads = [s[3] for s in _spans_by_line(str(tmp_path))
+                   if s[0] == schema.SERVE_DISPATCH_SPAN_KEYS[0]]
+        assert [(u["how"], u["bytes"]) for u in uploads] == [
+            ("put_one", 8 * (RANK + 2) * 4), ("put_one", 32 * (RANK + 2) * 4)]
+        assert [r["upload_how"] for r in eng.batch_flight.records()] \
+            == ["put_one"] * 3
+        plans = [e for e in reg._events if e["type"] == "serving_mesh_plan"]
+        assert [(e["bucket"], e["placements"], e["spread_bytes"])
+                for e in plans] == [
+            (B, 1, mesh_spread_bytes(S, B, RANK)) for B in BUCKETS]
+        assert {"placements", "spread_bytes"} <= set(
+            schema.EVENTS["serving_mesh_plan"][0])
+        assert "upload_how = call|put_one|put" in " ".join(
+            schema.EVENTS["flight_record"][1].split())
+        assert "put_one: a mesh engine's" in " ".join(
+            inspect.getsource(schema).replace("#", " ").split())
+    finally:
+        obs.reset()

@@ -3,14 +3,17 @@ batch rides the pinned program's call as the host array it is, and no
 ``jax.device_put`` is left on a batch's path — for every program
 ``ServingEngine._dispatch`` can choose: the plain int8 program, the one
 with a delta segment, the one that excludes histories, the exact
-fallback, and on four CPU devices the mesh's int8 and exact programs.
+fallback, and on four CPU devices the mesh's int8 and exact programs
+(which since PR 44 take the batch placed by ONE transfer, to the mesh's
+first device, ``ServingEngine._place_one``: a second runtime call, and
+still no ``jax.device_put``).
 
 One engine a program runs one scenario (``flown``) and four tests read
 it: (i) the same answers, bit for bit, as the same pinned executable
 called on a ``device_put`` copy of the same staged array; (ii) not one
 ``device_put`` while a started engine serves 50 batches; (iii) no
-compilation across them, nor on the jit fall-back after a dropped pin
-(the mesh's compiles once: a host argument carries no sharding); (iv) two
+compilation across them, nor on the jit fall-back after a dropped pin;
+(iv) two
 batches of one bucket in flight at once are staged in two arrays and
 both answered right."""
 
@@ -91,12 +94,21 @@ def serve_now(eng, payloads):
     return [t.result(timeout=0) for t in tickets]
 
 
-def on_a_placed_copy(eng, pin, args):
-    """The pinned executable on the same arguments but the staged array,
-    which goes up first by a ``device_put`` of a copy, as every batch's
-    did until PR 41: the packed response."""
-    (at,) = [i for i, a in enumerate(args) if isinstance(a, np.ndarray)]
-    placed = jax.device_put(args[at].copy(), eng._replicated)
+def on_a_placed_copy(eng, pin, args, st):
+    """The pinned executable on the same arguments but the staged array
+    ``st``, which goes up first by a ``device_put`` of a copy, as every
+    batch's did until PR 41 — on a mesh a copy of the whole ``[S * B,
+    width]`` array the program takes since PR 44, ``st`` and its ``S -
+    1`` blocks of zeros, a placement a shard: the packed response."""
+    if eng.mesh is None:
+        (at,) = [i for i, a in enumerate(args) if a is st]
+        placed = jax.device_put(st.copy())
+    else:
+        whole = np.zeros((S * len(st), st.shape[1]), st.dtype)
+        whole[:len(st)] = st
+        (at,) = [i for i, a in enumerate(args)
+                 if (a.shape, a.dtype) == (whole.shape, whole.dtype)]
+        placed = jax.device_put(whole, eng._by_rows)
     return np.asarray(pin.compiled(*args[:at], placed, *args[at + 1:]))
 
 
@@ -125,7 +137,8 @@ def flown(request):
     out = {"program": program, "name": name, "eng": eng}
     # (i) one batch on the caller's thread
     out["answers"] = packed(serve_now(eng, requests(rng, 5)))
-    out["placed"] = on_a_placed_copy(eng, pin, pin.args[-1])[:5]
+    out["placed"] = on_a_placed_copy(eng, pin, pin.args[-1],
+                                     staged[-1])[:5]
     # (iii) the pin dropped: the ordinary jit call takes the host array
     n0, pin.broken = compiles.n, True
     fell = serve_now(eng, requests(rng, 5))
@@ -169,8 +182,8 @@ def flown(request):
             out["pair"] = packed([a.result(timeout=20.0)]), packed(
                 [b.result(timeout=20.0)])
         out["device_puts_in_all"] = list(puts)
-    out["pair_placed"] = [on_a_placed_copy(eng, pin, args)[:1]
-                          for args in pin.args[-2:]]
+    out["pair_placed"] = [on_a_placed_copy(eng, pin, args, st)[:1]
+                          for args, st in zip(pin.args[-2:], staged[-2:])]
     return out
 
 
@@ -183,7 +196,8 @@ def test_the_batch_rides_the_call_bit_for_bit(flown):
     assert flown["answers"].shape == (5, 2 * K)
     assert np.array_equal(flown["answers"], flown["placed"])
     rec = eng.batch_flight.records()[-1]
-    assert rec["upload_how"] == "call" and rec["upload"] > 0
+    assert rec["upload_how"] == ("call" if eng.mesh is None
+                                 else "put_one") and rec["upload"] > 0
 
 
 def test_no_device_put_while_a_started_engine_serves(flown):
@@ -194,15 +208,15 @@ def test_no_device_put_while_a_started_engine_serves(flown):
 
 
 def test_nothing_compiles_on_the_pin_nor_on_the_jit_fall_back(flown):
-    """(iii) the host argument hits the pinned executable; after a
-    dropped pin the ordinary jit call takes it too — without a mesh from
-    the cache entry ``warmup()`` left, with one after ONE compilation
-    (a host argument carries no sharding where the prototype carried
-    the replicated one), and the answers are the pinned program's."""
+    """(iii) the staged batch hits the pinned executable; after a
+    dropped pin the ordinary jit call takes it too, from the cache entry
+    ``warmup()`` left — with a mesh as well since PR 44: the batch
+    arrives placed as ``warmup()``'s prototype was (a host argument
+    carried no sharding, and the call compiled once more) — and the
+    answers are the pinned program's."""
     assert flown["compiles"] == 0
     assert flown["pin_dropped"]
-    mesh = flown["program"].startswith("mesh")
-    assert flown["fallback_compiles"] == (1 if mesh else 0)
+    assert flown["fallback_compiles"] == 0
     assert flown["fallback_compiles_again"] == 0
     by_jit, by_pin = flown["fallback"]
     assert np.array_equal(by_jit, by_pin)

@@ -315,7 +315,7 @@ def test_upload_says_how_the_staged_batch_reached_the_device(either):
     by_batch = {r["batch"]: r for r in eng.batch_flight.records()}
     for stats in uploads:
         assert by_batch[stats["seq"]]["upload_how"] == stats["how"]
-    assert "upload_how = call|put" in " ".join(
+    assert "upload_how = call|put_one|put" in " ".join(
         EVENTS["flight_record"][1].split())
     # (the span's stats are declared in SERVE_DISPATCH_SPAN_KEYS' comment)
     assert "how = call" in " ".join(

@@ -160,7 +160,9 @@ METRICS = {
     "serving.mesh_exchange_bytes": (
         "counter", "bytes",
         "a mesh engine only: what one device moves between the chips "
-        "for the batches it scored, one add a batch — the by-id "
+        "for the batches it scored, one add a batch — the all-reduce "
+        "that spreads the staged [bucket, rank + 2] batch from the one "
+        "shard the host placed it on (since PR 44), the by-id "
         "lookup's all-reduce of the [bucket, rank] queries and the "
         "merge's two all-gathers of the shards' local top-k lists, by "
         "the closed form serving.index.mesh_exchange_bytes (a test pins "
@@ -427,14 +429,18 @@ SERVE_DISPATCH_SPAN_KEYS = (
     "serve.batch.dispatch.upload",  # E: what the host does for the
     #                                 staged batch's upload apart from
     #                                 the scoring call (seq, bytes: the
-    #                                 staged array's, which every shard of
-    #                                 a mesh takes whole; how = call: the
+    #                                 staged array's; how = call: the
     #                                 array rides the program's call as
     #                                 its host argument, the transfer is
     #                                 inside launch, and this span holds
     #                                 the scorer chosen and its arguments
-    #                                 built | put: a separate device_put,
-    #                                 which no path makes since PR 41)
+    #                                 built | put_one: a mesh engine's,
+    #                                 since PR 44 — one transfer to the
+    #                                 mesh's first device inside this
+    #                                 span, the program spreads it and
+    #                                 launch holds no transfer | put: a
+    #                                 separate device_put, which no path
+    #                                 makes since PR 41)
     "serve.batch.dispatch.launch",  # E: the scorer called, until the call
     #                                 returns (seq, program: the jitted
     #                                 function as the trace's XLA Modules
@@ -628,14 +634,19 @@ EVENTS = {
         "keys it sorts before its one scatter-add (rows x ids)"),
     "serving_mesh_plan": (
         ("bucket", "shards", "items_per_shard", "users_per_shard", "k_loc",
-         "exchange_bytes"),
+         "placements", "spread_bytes", "exchange_bytes"),
         "one per sharded int8 scoring program a mesh engine's "
         "ServingEngine.warmup compiles and pins (per bucket): the mesh "
         "size, the catalog and user-table rows one shard holds, the "
-        "answers one shard gives a query, and the bytes one device moves "
-        "between the chips for one batch (the lookup's all-reduce, the "
-        "merge's all-gathers: serving.index.mesh_exchange_bytes), which "
-        "is what serving.mesh_exchange_bytes adds per batch"),
+        "answers one shard gives a query, the host->device transfers the "
+        "staged batch takes (placements: 1 since PR 44, to the mesh's "
+        "first device; one a shard before), the bytes the program's "
+        "first all-reduce moves to spread it (spread_bytes: "
+        "serving.index.mesh_spread_bytes), and the bytes one device moves "
+        "between the chips for one batch, the spread among them (the "
+        "lookup's all-reduce, the merge's all-gathers: "
+        "serving.index.mesh_exchange_bytes), which is what "
+        "serving.mesh_exchange_bytes adds per batch"),
     "foldin_solve_path": (
         ("side", "rank", "rows", "width", "path", "reason"),
         "one per fold-in program FoldInServer.prewarm compiled and ran "
@@ -714,9 +725,11 @@ EVENTS = {
         "waited for a batch when this one was handed over, upload / "
         "launch = seconds of the two child spans of dispatch "
         "(SERVE_DISPATCH_SPAN_KEYS; upload + launch <= dispatch), "
-        "upload_how = call|put as the upload span's how (call: the "
-        "staged batch rode the scoring call as its host argument, its "
-        "transfer is inside launch), cpu = "
+        "upload_how = call|put_one|put as the upload span's how (call: "
+        "the staged batch rode the scoring call as its host argument, its "
+        "transfer is inside launch; put_one: a mesh engine placed it on "
+        "the mesh's first device, inside upload, and the program spread "
+        "it), cpu = "
         "{stage, dispatch, readback, complete}: seconds of the thread's "
         "own CPU time in each phase, beside the phase's wall seconds in "
         "spans — wall - CPU is time the thread held no processor; each "

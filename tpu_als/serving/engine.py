@@ -145,7 +145,10 @@ stack plugs into:
   block: the catalog and its int8 rows (no device ever holds the whole
   of them, and the engine and its index share the one sharded copy) and
   the user table (spare rows on the last shards).  A batch is ONE
-  program (:func:`_build_mesh_serve`): every shard takes the user rows
+  program (:func:`_build_mesh_serve`): the staged batch is placed on
+  the mesh's first device alone and one all-reduce spreads it
+  (:meth:`ServingEngine._place_one`, :func:`_mesh_spread`), every
+  shard takes the user rows
   it owns for the batch's ids and one all-reduce sums them
   (:func:`_mesh_lookup`), every shard scores its slice, two all-gathers
   and one ``top_k`` merge the local lists on every shard, and the
@@ -160,7 +163,8 @@ stack plugs into:
   in flight from its own) and uploads it as ONE transfer — no per-batch
   id/row/mask re-uploads (the payload is the only host→device traffic)
   — which rides the scoring program's call as its host argument: ONE
-  trip into the runtime a batch, no ``jax.device_put`` on its path.
+  trip into the runtime a batch, no ``jax.device_put`` on its path
+  (with a mesh two: the one placement, then the call).
   Responses come back packed ``[B, 2k]`` (scores' f32 bits | indices) in
   one bulk transfer, and tickets complete with numpy VIEWS sliced from
   that buffer — zero per-ticket copies; the buffer snapshots an
@@ -185,6 +189,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src.interpreters.pxla import batched_device_put
 from jax.profiler import TraceAnnotation
 
 from tpu_als import obs
@@ -229,6 +234,7 @@ from tpu_als.serving.index import (
     _shard_score,
     mask_block,
     mesh_exchange_bytes,
+    mesh_spread_bytes,
     place_catalog,
     segment_write_bytes,
 )
@@ -626,6 +632,16 @@ def _scatter_items(V, valid, rows, vals, ok):
                 valid.at[rows].set(ok, mode="drop"))
 
 
+def _mesh_spread(block, *, axis):
+    """The staged batch on every shard, from the one shard that was
+    given it: ``block`` is this shard's ``[B, width]`` rows of the
+    ``[S * B, width]`` array :meth:`ServingEngine._place_one` assembled
+    — the batch on the mesh's first device, zeros on the others — and
+    the blocks' sum is the batch, bit for bit (``int32`` plus zeros):
+    one all-reduce over ICI where the host made one placement a shard."""
+    return jax.lax.psum(block, axis)
+
+
 def _mesh_lookup(U, packed, *, me, axis):
     """:func:`_select_packed` against a user table sharded by rows,
     inside ``shard_map``: this shard holds table rows ``[me * n_loc,
@@ -652,7 +668,10 @@ def _build_mesh_serve(mesh, k, k_loc, sk_loc, ni_loc, has_delta):
     (``serving.index._shard_score``, the body ``ShardedInt8Index.topk``
     runs), the merge of the shards' local top-k lists on every shard,
     the packed ``[B, 2k]`` response, replicated: one device→host
-    transfer answers the batch.  :meth:`ServingEngine.warmup` lowers,
+    transfer answers the batch.  ``packed`` is the ``[S * B, rank + 2]``
+    array of :meth:`ServingEngine._place_one`, sharded by rows, and the
+    program's first operation spreads its one live block
+    (:func:`_mesh_spread`).  :meth:`ServingEngine.warmup` lowers,
     compiles and pins it per bucket, as it does
     :func:`_serve_int8_packed` without a mesh; a device trace names it
     ``jit_serve_mesh_int8`` on its ``XLA Modules`` line, one a batch."""
@@ -663,6 +682,7 @@ def _build_mesh_serve(mesh, k, k_loc, sk_loc, ni_loc, has_delta):
     def serve_mesh_int8(U, packed, Vq, sv, V, valid, last_id, *delta):
         me = jax.lax.axis_index(AXIS)
         with jax.named_scope(SERVE_MESH_SCOPES[0]):
+            packed = _mesh_spread(packed, axis=AXIS)
             Ub = _mesh_lookup(U, packed, me=me, axis=AXIS)
         with jax.named_scope(SERVE_MESH_SCOPES[1]):
             s, gids = _shard_score(Ub, Vq, sv, V, valid, delta, me=me,
@@ -674,7 +694,7 @@ def _build_mesh_serve(mesh, k, k_loc, sk_loc, ni_loc, has_delta):
 
     return jax.jit(shard_map(
         serve_mesh_int8, mesh=mesh,
-        in_specs=(P(AXIS), P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P())
+        in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P())
         + (P(),) * (5 if has_delta else 0),
         out_specs=P(), check_vma=False))
 
@@ -691,6 +711,7 @@ def _build_mesh_exact(mesh, k, k_loc, ni_loc, item_chunk):
     def serve_mesh_exact(U, packed, V, valid, last_id):
         me = jax.lax.axis_index(AXIS)
         with jax.named_scope(SERVE_MESH_SCOPES[0]):
+            packed = _mesh_spread(packed, axis=AXIS)
             Ub = _mesh_lookup(U, packed, me=me, axis=AXIS)
         with jax.named_scope(SERVE_MESH_SCOPES[1]):
             s, ix = chunked_topk_scores(Ub, V, valid, k_loc,
@@ -701,7 +722,8 @@ def _build_mesh_exact(mesh, k, k_loc, ni_loc, item_chunk):
                 axis=AXIS, k=k))
 
     return jax.jit(shard_map(
-        serve_mesh_exact, mesh=mesh, in_specs=(P(AXIS), P(), P(AXIS), P(AXIS), P()),
+        serve_mesh_exact, mesh=mesh,
+        in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P()),
         out_specs=P(), check_vma=False))
 
 
@@ -806,11 +828,20 @@ class ServingEngine:
         self._handed = 0
         self._completed = 0
         self.mesh = mesh
-        # what every shard is given whole: the staged batch, a publish's
-        # touched rows
+        # what every shard is given whole: a publish's touched rows
         self._replicated = (None if mesh is None else
                             jax.sharding.NamedSharding(
                                 mesh, jax.sharding.PartitionSpec()))
+        # what the staged batch is placed as (_place_one): a block of
+        # rows a shard
+        self._by_rows = (None if mesh is None else
+                         jax.sharding.NamedSharding(
+                             mesh, jax.sharding.PartitionSpec(
+                                 mesh.axis_names[0])))
+        self._devices = None if mesh is None else list(mesh.devices.flat)
+        # _place_one's: staged shape -> (the placed array's abstract
+        # value, the blocks of zeros on every device but the first)
+        self._idle_blocks = {}
         # (bucket, path) -> AOT executable; (bucket, path, history pad)
         # for the programs that exclude
         self._pinned = {}
@@ -1648,10 +1679,40 @@ class ServingEngine:
 
     def _proto(self, B, rank, wide=False):
         """An empty staged batch of bucket ``B`` (``wide``: of a batch
-        that excludes), placed where the pinned program's call places a
-        real one: what ``lower()`` reads the input sharding from."""
-        return jax.device_put(self._staged((), B, rank, wide),
-                              self._replicated)
+        that excludes), placed where a real one is: what ``lower()``
+        reads the input sharding from (without a mesh the default
+        device's, where the pinned program's call places the host array;
+        with one, :meth:`_place_one`'s)."""
+        st = self._staged((), B, rank, wide)
+        return jax.device_put(st) if self.mesh is None else \
+            self._place_one(st)
+
+    def _place_one(self, st):
+        """The staged batch as a mesh program's argument, by ONE
+        host→device transfer: the ``[S * B, width]`` array sharded by
+        rows whose first block, on the mesh's first device, is ``st``
+        and whose other blocks are zeros that lie on the other devices
+        since the first batch of this shape (every batch reads them,
+        none writes them) — assembled by one call of the runtime's
+        batched placement, which transfers what is on the host and takes
+        what is on its device already as it is.  The program's first
+        operation sums the blocks (:func:`_mesh_spread`), so every shard
+        sees the ``[B, width]`` bits a replicated argument gave it.  (A
+        host array handed to the sharded program's call is placed by
+        Python's ``shard_args``, a transfer a shard on the calling
+        thread: 0.53 ms of the engine thread a batch on four chips,
+        PERF.md section 5, Since PR 44.)"""
+        held = self._idle_blocks.get(st.shape)
+        if held is None:
+            held = self._idle_blocks[st.shape] = (
+                jax.core.ShapedArray(
+                    (len(self._devices) * st.shape[0], st.shape[1]),
+                    st.dtype),
+                [jax.device_put(np.zeros_like(st), d)
+                 for d in self._devices[1:]])
+        aval, zeros = held
+        return batched_device_put(aval, self._by_rows, [st, *zeros],
+                                  self._devices)
 
     def _int8_call(self, m, idx, packed, seen=None, pad=None):
         """``(jitted function, arguments, static arguments)`` of the
@@ -1740,6 +1801,8 @@ class ServingEngine:
             plan = self._plans[key] = dict(
                 shards=S, items_per_shard=ni_loc,
                 users_per_shard=int(m.U.shape[0]) // S, k_loc=k_loc,
+                placements=1,
+                spread_bytes=mesh_spread_bytes(S, bucket, m.rank),
                 exchange_bytes=mesh_exchange_bytes(S, bucket, m.rank,
                                                    k_loc))
         return plan
@@ -1894,11 +1957,8 @@ class ServingEngine:
         for ``key``; a pin invalidated by a shape-changing publish is
         dropped and the ordinary jit call (compiled once, cached) takes
         over until the next :meth:`warmup`.  Either takes the staged
-        batch as the host array it is.  (Without a mesh the jit call
-        finds the entry :meth:`warmup` left at the same shapes; with one
-        it compiles once more, since a host argument carries no
-        sharding where ``warmup``'s prototype carried the replicated
-        one.)"""
+        batch as :meth:`_dispatch` hands it: the host array without a
+        mesh, the placed one with."""
         c = self._pinned.get(key)
         if c is not None:
             try:
@@ -2354,24 +2414,31 @@ class ServingEngine:
         return st
 
     def _dispatch(self, m, st, B, mode, seq, seen=None, pad=None):
-        """Call the scorer the live model selects on the staged batch,
-        which rides the call as the host array it is: the program's own
-        argument handling places it (on the default device; replicated
-        over the mesh, as the pinned executable's input sharding says),
-        ONE trip into the runtime a batch, no upload call of its own.
+        """Call the scorer the live model selects on the staged batch.
+        Without a mesh the batch rides the call as the host array it is:
+        the program's own argument handling places it on the default
+        device, ONE trip into the runtime a batch, no upload call of its
+        own (``how`` = ``call``).  With a mesh it is placed first, by
+        one transfer to the mesh's first device (:meth:`_place_one`,
+        ``how`` = ``put_one``), and the call takes an array that is on
+        its devices already: a host array would be placed a shard at a
+        time by Python, inside the call.
         Two child spans of the caller's ``serve.batch.dispatch``:
-        ``upload`` around what the host still does for the upload apart
-        from the call — the scorer chosen and its argument tuple built
-        around ``st`` (``how`` = ``call``) — and ``launch`` around the
-        call, the transfer inside it.  Returns ``(packed response on the
-        device, path, fell back to exact, how the batch was uploaded,
-        when the upload span had closed, when the call had returned)``
-        as soon as the call returns.  ``seen``, ``pad``: the batch
+        ``upload`` around what the host does for the upload apart from
+        the call — the mesh's one placement, the scorer chosen and its
+        argument tuple built around the batch — and ``launch`` around
+        the call (without a mesh the transfer inside it).  Returns
+        ``(packed response on the device, path, fell back to exact, how
+        the batch was uploaded, when the upload span had closed, when
+        the call had returned)`` as soon as the call returns.  ``seen``, ``pad``: the batch
         excludes — the program of its history pad; on the exact fallback
         the longest pad's, the one :meth:`warmup` pins."""
-        index, how = m.index, "call"
+        index = m.index
+        how = "call" if self.mesh is None else "put_one"
         with TraceAnnotation("serve.batch.dispatch.upload", seq=seq,
                              bytes=st.nbytes, how=how):
+            if self.mesh is not None:
+                st = self._place_one(st)
             use_index = (index is not None and index.seq == m.seq
                          and mode != "corrupt")
             fell_back = index is not None and not use_index

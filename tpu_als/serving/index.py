@@ -722,14 +722,25 @@ def _shard_merge(s, gids, last_id, *, axis, k):
 def mesh_exchange_bytes(n_shards, rows, rank, k_loc):
     """Bytes one device moves for one batch of the mesh engine's scoring
     program, by ``parallel.comm_audit``'s conventions (a test pins this
-    to the traced program's): the by-id lookup's ``psum`` of the
-    ``[rows, rank]`` f32 queries, a bidirectional-ring all-reduce,
-    ``2 (S-1)/S`` of them, and the merge's two ``all_gather``s of the
-    local ``[rows, k_loc]`` f32 scores and int32 ids, ``(S-1)/S`` of the
-    gathered ``[S, rows, k_loc]`` each."""
+    to the traced program's): the ``psum`` that spreads the staged
+    ``[rows, rank + 2]`` int32 batch from the one shard it was placed on
+    (``serving.engine._mesh_spread``: see :func:`mesh_spread_bytes`),
+    the by-id lookup's ``psum`` of the ``[rows, rank]`` f32 queries, a
+    bidirectional-ring all-reduce, ``2 (S-1)/S`` of them, and the
+    merge's two ``all_gather``s of the local ``[rows, k_loc]`` f32
+    scores and int32 ids, ``(S-1)/S`` of the gathered ``[S, rows,
+    k_loc]`` each."""
     S = int(n_shards)
-    return (2 * (S - 1) * rows * rank * 4 // S
+    return (mesh_spread_bytes(S, rows, rank)
+            + 2 * (S - 1) * rows * rank * 4 // S
             + 2 * (S - 1) * rows * k_loc * 4)
+
+
+def mesh_spread_bytes(n_shards, rows, rank):
+    """Of :func:`mesh_exchange_bytes`, the staged batch's spread alone:
+    what the host's one placement a batch costs the ICI."""
+    S = int(n_shards)
+    return 2 * (S - 1) * rows * (rank + 2) * 4 // S
 
 
 @functools.lru_cache(maxsize=32)

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """What a batch pays for the delta segment's slots, on the chip: the
-with-segment scoring program (``serving.engine._serve_int8_delta_packed``)
+scoring program with a segment (``serving.engine._serve_int8_packed(delta=)``)
 at the live cells' size for several numbers of slots, beside the delta-free
 one, bucket 8 and 128, median of 30 runs each after a warm-up.  The reading
 ``plan.DEFAULT_LIVE_CADENCE["compact_delta_frac"]`` was moved from (PERF.md
@@ -31,10 +31,7 @@ def main():
         print("time_delta_segment: no TPU", file=sys.stderr)
         return 1
     from tpu_als.core.ratings import row_capacity
-    from tpu_als.serving.engine import (
-        _serve_int8_delta_packed,
-        _serve_int8_packed,
-    )
+    from tpu_als.serving.engine import _serve_int8_packed
     from tpu_als.serving.index import build_index
 
     key = jax.random.PRNGKey(0)
@@ -55,13 +52,13 @@ def main():
     for bucket in (8, 128):
         packed = jnp.zeros((bucket, RANK + 2), jnp.int32)
         row = {"bucket": bucket, "no_segment_ms": timed(
-            _serve_int8_packed, U, idx.Vq, idx.sv, idx.V, idx.valid, packed,
-            k=10, shortlist_k=64)}
+            _serve_int8_packed, U, idx.Vq, idx.sv, idx.V, idx.valid, (), (),
+            packed, k=10, shortlist_k=64)}
         for slots in (512, 4096, 32768, 262144):
             seg = idx.reserve(slots=slots)
             row[f"slots_{slots}_ms"] = timed(
-                _serve_int8_delta_packed, U, seg.Vq, seg.sv, seg.V,
-                seg.valid, *seg._seg, seg._last_id(), packed, k=10,
+                _serve_int8_packed, U, seg.Vq, seg.sv, seg.V, seg.valid,
+                (*seg._seg, seg._last_id()), (), packed, k=10,
                 shortlist_k=64)
             del seg
         print(json.dumps(row), flush=True)

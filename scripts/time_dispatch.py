@@ -135,7 +135,7 @@ def build_entry_variant(eng, idx, entry):
 
     from tpu_als.parallel.mesh import AXIS, shard_map
     from tpu_als.serving import engine as E
-    from tpu_als.serving.index import _shard_merge, _shard_score
+    from tpu_als.serving.index import _shard_merge, shortlist_rescore
 
     P = jax.sharding.PartitionSpec
     k_loc, sk_loc = idx.shard_widths(eng.k)
@@ -145,8 +145,9 @@ def build_entry_variant(eng, idx, entry):
         if entry == "rows":
             packed = jax.lax.all_gather(packed, AXIS, tiled=True)
         Ub = E._mesh_lookup(U, packed, me=me, axis=AXIS)
-        s, gids = _shard_score(Ub, Vq, sv, V, valid, (), me=me, k_loc=k_loc,
-                               sk_loc=sk_loc, ni_loc=idx.ni_loc)
+        s, gids = shortlist_rescore(Ub, Vq, sv, V, valid, k=k_loc,
+                                    shortlist_k=sk_loc,
+                                    shard=(me, idx.ni_loc))
         return E._pack_response(
             *_shard_merge(s, gids, last_id, axis=AXIS, k=eng.k))
 

@@ -26,7 +26,7 @@ each returning ``bool[B, columns]``:
 - ``compare``: no scatter — ``any(columns == id)`` over the ids, bucket
   8 and pad 64 alone (it costs ``B * columns * ids`` compares).
 
-Then ``serving.index._int8_topk`` whole: without ``seen`` (pad 0, the
+Then ``serving.index.shortlist_rescore`` whole: without ``seen`` (pad 0, the
 program every other cell runs) and with it at each pad — every row's
 lists full, and one row's full beside rows of 3 ids (what most batches
 look like: the scatter then takes the quarter of the sorted keys that
@@ -183,7 +183,7 @@ def masks_alone(forms, rng):
 
 
 def scoring_program(rng):
-    from tpu_als.serving.index import _int8_topk
+    from tpu_als.serving.index import _topk_jit
 
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
     Vq = jax.random.randint(k1, (COLS, RANK), -127, 128, jnp.int8)
@@ -204,11 +204,11 @@ def scoring_program(rng):
                     jnp.asarray(histories(rng, bucket, OWN, ITEMS,
                                           full_rows)))
             ms, compile_s, top = device_ms(
-                lambda *a, kw=kw: _int8_topk(*a, k=10, shortlist_k=K, **kw),
+                lambda *a, kw=kw: _topk_jit(*a, k=10, shortlist_k=K, **kw),
                 U, Vq, sv, V, valid)
             if pad:     # the rule held: no excluded id among the answers
-                _, ids = _int8_topk(U, Vq, sv, V, valid, k=10,
-                                    shortlist_k=K, **kw)
+                _, ids = _topk_jit(U, Vq, sv, V, valid, k=10,
+                                   shortlist_k=K, **kw)
                 hit = (np.asarray(ids)[:, :, None] == np.concatenate(
                     kw["seen"], axis=1)[:, None, :]).any()
                 assert not hit, (bucket, pad)
@@ -248,7 +248,7 @@ def main(argv=None):
     for r in masks:
         print(f"| {r['B']} | {r['pad']} | " + " | ".join(
             cell(r[f]) if f in r else "-" for f in names) + " |")
-    print("\n`_int8_topk` whole at the cell's shapes: pad 0 is the "
+    print("\n`shortlist_rescore` whole at the cell's shapes: pad 0 is the "
           "program without `seen`.\n")
     print("| B | pad | rows full | device ms | over pad 0 | compile s | "
           "longest operations |")

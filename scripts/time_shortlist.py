@@ -19,7 +19,7 @@ Three tables, one JSON line a row and then markdown:
    blocks * 128]`` under three rules for stage two's layout constraint:
    the tree's (``ops.topk.ROW_MAJOR_BELOW`` rows), never (the function
    as it was before PR 37) and always.
-3. **The scoring program** ``serving.index._int8_topk`` at the benchmark
+3. **The scoring program** ``serving.index.shortlist_rescore`` at the benchmark
    cell's shapes (1,506,048 int8 rows of rank 256) under the same three:
    what a batch pays on the device.
 
@@ -31,12 +31,12 @@ ONE instead, and nothing else (~2 min): the scoring program whose stage
 one reads the written score matrix a second time, in the parent's form
 beside the tree's, with the operations that take longest in each —
 
-- ``--block-len L`` (a multiple of 128 above 128): ``_int8_topk`` over a
+- ``--block-len L`` (a multiple of 128 above 128): ``shortlist_rescore`` over a
   catalog whose plan has blocks of ``L`` (256: one shard of the mesh
   cell, 3,012,096 rows), the block maximum taken over a block at once
   (parent) and as ``ops.topk.block_maxima`` takes a long block (tree:
   its 128-lane groups folded into one first);
-- ``--tail d``: ``_int8_topk_delta`` at the live-items cell's shapes
+- ``--tail d``: ``shortlist_rescore(delta=)`` at the live-items cell's shapes
   (1,529,856 base columns, ``d`` slots), the segment's scores
   concatenated to the matrix (parent) and joined at stage three (tree).
 
@@ -230,7 +230,7 @@ def whole_function():
 
 
 def scoring_program():
-    from tpu_als.serving.index import _int8_topk
+    from tpu_als.serving.index import _topk_jit
 
     cols = shortlist_columns(COLUMNS, K)
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -244,7 +244,7 @@ def scoring_program():
                               jnp.float32)
         row = {"table": "program", "B": b, "columns": cols,
                "blocks": shortlist_plan(cols, K).blocks,
-               **timed_by_rule(_int8_topk, U, Vq, sv, V, valid,
+               **timed_by_rule(_topk_jit, U, Vq, sv, V, valid,
                                k=10, shortlist_k=K)}
         rows.append(row)
         print(json.dumps(row), flush=True)
@@ -315,7 +315,7 @@ def stage_one(block_len, tail):
     """The scoring programs whose stage one PR 43 rewrote, in both forms
     (module docstring): one JSON line a bucket, then markdown."""
     from tpu_als.core.ratings import row_capacity
-    from tpu_als.serving.index import SLOT_FREE, _int8_topk, _int8_topk_delta
+    from tpu_als.serving.index import SLOT_FREE, _topk_jit
 
     def catalog(cols, rows):
         k1, k2 = jax.random.split(jax.random.PRNGKey(cols))
@@ -337,9 +337,9 @@ def stage_one(block_len, tail):
         assert shortlist_plan(cols, K).block_len == block_len, cols
         tables = catalog(cols, cols)
         for b in BATCHES:
-            rows.append({"table": "stage_one", "program": "_int8_topk",
+            rows.append({"table": "stage_one", "program": "base",
                          "B": b, **shortlist_plan(cols, K, b)._asdict(),
-                         **in_both_forms(_int8_topk, queries(b), *tables,
+                         **in_both_forms(_topk_jit, queries(b), *tables,
                                          k=10, shortlist_k=K)})
             print(json.dumps(rows[-1]), flush=True)
         del tables
@@ -358,11 +358,11 @@ def stage_one(block_len, tail):
                jnp.zeros((tail,), jnp.bool_).at[:used].set(True))
         for b in BATCHES:
             rows.append({"table": "stage_one",
-                         "program": "_int8_topk_delta", "B": b,
+                         "program": "with a segment", "B": b,
                          **shortlist_plan(cols, K, b, tail)._asdict(),
-                         **in_both_forms(_int8_topk_delta, queries(b),
-                                         *tables, *seg, jnp.int32(cap - 1),
-                                         k=10, shortlist_k=K)})
+                         **in_both_forms(_topk_jit, queries(b), *tables,
+                                         k=10, shortlist_k=K, delta=seg,
+                                         last_id=jnp.int32(cap - 1))})
             print(json.dumps(rows[-1]), flush=True)
     print("\nDevice ms a run (median of 20) of the scoring program, stage "
           "one in the parent's form and in the tree's; below each, its "
@@ -429,7 +429,7 @@ def main():
           f"(under {topk_mod.ROW_MAJOR_BELOW} rows), never (the program "
           "before PR 37), always.\n")
     for title, table in (("shortlist_topk on f32[B, blocks * 128]", whole),
-                         ("_int8_topk at the cell's shapes", program)):
+                         ("shortlist_rescore at the cell's shapes", program)):
         print(f"| B | blocks | {title}: tree | its longest operation | "
               "never | its longest operation | always | its longest "
               "operation | never - tree | TopK was handed (tree) |")

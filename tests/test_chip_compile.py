@@ -185,6 +185,23 @@ def _compiled(sharding, jitted, *shapes, **statics):
                           for s, d in shapes], **statics).compile()
 
 
+def _serve_int8(sharding, U, base, packed, delta=(), histories=(), pad=None):
+    """The engine's one int8 program lowered for the described chip from
+    ``(shape, dtype)`` pairs: ``delta`` the segment's five arrays and the
+    last id, ``histories`` ``(runs, indices)`` (``runs`` a pair itself
+    where the table is laid out to grow), or neither."""
+    from tpu_als.serving.engine import _serve_int8_packed
+
+    def of(sd):       # a ``(shape, dtype)`` pair, or a tuple of such
+        if len(sd) == 2 and not isinstance(sd[1], tuple):
+            return jax.ShapeDtypeStruct(*sd, sharding=sharding)
+        return tuple(map(of, sd))
+
+    return _serve_int8_packed.lower(
+        of(U), *map(of, base), of(tuple(delta)), of(tuple(histories)),
+        of(packed), k=10, shortlist_k=64, pad=pad)
+
+
 def _live_catalog_shapes():
     from tpu_als.core.ratings import row_capacity
     from tpu_als.ops.topk import shortlist_columns
@@ -206,16 +223,13 @@ def test_serve_with_a_segment_at_the_live_cells_size(one_chip, bucket):
     pad), and the program fits beside the tables."""
     from tpu_als.core.ratings import row_capacity
     from tpu_als.ops.topk import shortlist_plan
-    from tpu_als.serving.engine import _serve_int8_delta_packed
-
     cap, cols, base, seg = _live_catalog_shapes()
     plan = shortlist_plan(cols, 64, tail=LIVE_SLOTS)
     assert plan.stages == 2 and plan.columns % plan.block_len == 0
-    c = _compiled(
-        one_chip, _serve_int8_delta_packed,
-        ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32),
-        *base, *seg, ((), jnp.int32), ((bucket, LIVE_RANK + 2), jnp.int32),
-        k=10, shortlist_k=64)
+    c = _serve_int8(
+        one_chip, ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32),
+        base, ((bucket, LIVE_RANK + 2), jnp.int32),
+        delta=(*seg, ((), jnp.int32))).compile()
     assert c.memory_analysis().temp_size_in_bytes < 4 << 30
 
 
@@ -290,28 +304,24 @@ def _assert_stage_two_as_planned(text, columns, bucket):
 @pytest.mark.parametrize("bucket", BUCKETS)
 def test_stage_two_topk_layout_in_the_steady_cells_program(
         one_chip, bucket):
-    from tpu_als.serving.engine import _serve_int8_packed
-
     _, cols, base, _ = _live_catalog_shapes()
     Vq, sv, _, valid = base
-    c = _compiled(
-        one_chip, _serve_int8_packed, ((LIVE_USERS, LIVE_RANK), jnp.float32),
-        Vq, sv, ((LIVE_ITEMS, LIVE_RANK), jnp.float32), valid,
-        ((bucket, LIVE_RANK + 2), jnp.int32), k=10, shortlist_k=64)
+    c = _serve_int8(
+        one_chip, ((LIVE_USERS, LIVE_RANK), jnp.float32),
+        (Vq, sv, ((LIVE_ITEMS, LIVE_RANK), jnp.float32), valid),
+        ((bucket, LIVE_RANK + 2), jnp.int32)).compile()
     _assert_stage_two_as_planned(c.as_text(), cols, bucket)
 
 
 @pytest.mark.parametrize("bucket", BUCKETS)
 def test_stage_two_topk_layout_with_a_segment(one_chip, bucket):
     from tpu_als.core.ratings import row_capacity
-    from tpu_als.serving.engine import _serve_int8_delta_packed
 
     _, cols, base, seg = _live_catalog_shapes()
-    c = _compiled(
-        one_chip, _serve_int8_delta_packed,
-        ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32),
-        *base, *seg, ((), jnp.int32), ((bucket, LIVE_RANK + 2), jnp.int32),
-        k=10, shortlist_k=64)
+    c = _serve_int8(
+        one_chip, ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32),
+        base, ((bucket, LIVE_RANK + 2), jnp.int32),
+        delta=(*seg, ((), jnp.int32))).compile()
     # the base's columns: the segment's scores join at stage three
     _assert_stage_two_as_planned(c.as_text(), cols, bucket)
 
@@ -319,32 +329,11 @@ def test_stage_two_topk_layout_with_a_segment(one_chip, bucket):
 @pytest.mark.parametrize("bucket", BUCKETS)
 def test_stage_two_topk_layout_on_every_shard_of_the_mesh_cell(
         topo, bucket):
-    """``_shard_score`` inside the mesh engine's one program a bucket, at
-    the mesh cell's 3,012,096 catalog rows a shard, for the four
-    described chips."""
-    from jax.sharding import NamedSharding
-    from jax.sharding import PartitionSpec as P
-
-    from tpu_als.parallel.mesh import AXIS, make_mesh
-    from tpu_als.serving.engine import _build_mesh_serve
-
-    mesh = make_mesh(devices=list(topo.devices))
-    shards, ni, r = len(topo.devices), MESH_ITEMS_PER_SHARD, LIVE_RANK
-    rows, whole = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
-
-    def shape(s, d, sharding):
-        return jax.ShapeDtypeStruct(s, d, sharding=sharding)
-
-    fn = _build_mesh_serve(mesh, 10, 10, 64, ni, False)
-    c = fn.lower(
-        shape((shards * MESH_USERS_PER_SHARD, r), jnp.float32, rows),
-        shape((shards * bucket, r + 2), jnp.int32, rows),   # _place_one's
-        shape((shards * ni, r), jnp.int8, rows),
-        shape((shards * ni,), jnp.float32, rows),
-        shape((shards * ni, r), jnp.float32, rows),
-        shape((shards * ni,), jnp.bool_, rows),
-        shape((), jnp.int32, whole)).compile()
-    _assert_stage_two_as_planned(c.as_text(), ni, bucket)
+    """``shortlist_rescore`` on a shard, inside the mesh engine's one
+    program a bucket, at the mesh cell's 3,012,096 catalog rows a shard,
+    for the four described chips."""
+    c = _lowered("mesh", bucket, topo, None).compile()
+    _assert_stage_two_as_planned(c.as_text(), MESH_ITEMS_PER_SHARD, bucket)
 
 
 # -- per-request exclusion (PR 39) --------------------------------------------
@@ -352,54 +341,58 @@ def test_stage_two_topk_layout_on_every_shard_of_the_mesh_cell(
 UNSEEN_RATINGS = 17_860_625
 HISTORY_PADS = (64, 512, 4096)
 # sha256 of the lowered (StableHLO) text of the three programs the cells
-# without histories run, at their cells' shapes.  ``steady``: taken on
-# 01a67e1, before the engine knew of histories or ``exclude``, and unmoved
-# since — PR 43's stage one keeps the parent's expression for blocks of 128
-# lanes without a tail.  ``delta`` and ``mesh``: pinned again at PR 43,
-# whose stage one they run (the segment's scores a tail, blocks of 256
-# through the lane maxima); ``mesh`` once more at PR 44, whose entry it
-# takes: the staged batch ``[S * B, rank + 2]`` by rows and one
-# ``all_reduce`` of its block before the lookup, the rest of the text
-# PR 43's but for the numbering
+# without histories run, at their cells' shapes.  Pinned again at PR 46,
+# which made the three copies of the scoring pipeline one function
+# (``serving.index.shortlist_rescore``) traced straight into the two
+# engine programs: the nested ``jit(_int8_topk)`` / ``jit(_int8_topk_
+# delta)`` calls, whose names stood in the text, went, and ``delta`` took
+# the name ``_serve_int8_packed``.  What was compared before the hashes
+# moved, here and for ``PARENT_LOWERED_SEEN`` below: the OPTIMISED HLO the
+# chip's compiler (this file's described v5e) makes of parent (13dd465)
+# and change for every program the six cells pin — int8 and exact, at
+# buckets 8 / 32 / 128, steady, with the live cell's spare user rows,
+# with a segment, on the four-chip mesh, and the programs that exclude at
+# every history pad in both layouts of the histories: 51 programs.  With
+# instruction, computation and parameter names, metadata and the tables
+# of file and function names taken out, 39 read the same line for line;
+# in 12 the entry's schedule is the same line for line and the fused
+# computations are listed in another order (in 3 of them one fusion
+# numbers its parameters otherwise); ``memory_analysis()`` is equal in
+# all 51.  The text moved by names alone.  History of the hashes before:
+# ``steady`` stood from 01a67e1 (before the engine knew of histories) to
+# PR 45; ``delta`` and ``mesh`` were pinned at PR 43 (stage one reads the
+# segment's scores as a tail; blocks of 256 through the lane maxima) and
+# ``mesh`` again at PR 44 (the staged batch ``[S * B, rank + 2]`` by
+# rows, one ``all_reduce`` of its block before the lookup)
 PARENT_LOWERED = {
-    ("steady", 8): "35ea3052c100ffc5", ("steady", 32): "abfcefa10f9a23f9",
-    ("steady", 128): "f61c331f33283e9b",
-    ("delta", 8): "099e8ce54c689f77", ("delta", 32): "7f6b3ce1d9918a52",
-    ("delta", 128): "d225a128acc850eb",
-    ("mesh", 8): "8906500ff0b43bb7", ("mesh", 32): "4e28d421bd393208",
-    ("mesh", 128): "462781274ca22ec9",
+    ("steady", 8): "ce5ee2d9c92b0463", ("steady", 32): "cbc45aeebd13a265",
+    ("steady", 128): "5a98f1e78003bc5a",
+    ("delta", 8): "f60c536c4610f20d", ("delta", 32): "dc97ae197b569b91",
+    ("delta", 128): "fd3a95afaeac44d6",
+    ("mesh", 8): "12fa06f50388057e", ("mesh", 32): "1eba0b98255ed5b9",
+    ("mesh", 128): "9bc54298d8f56bf5",
 }
-
-
-def _lower(sharding, jitted, *shapes, **statics):
-    return jitted.lower(*[jax.ShapeDtypeStruct(s, d, sharding=sharding)
-                          for s, d in shapes], **statics)
 
 
 def _lowered(name, bucket, topo, one_chip):
     from tpu_als.core.ratings import row_capacity
-    from tpu_als.serving.engine import (
-        _build_mesh_serve,
-        _serve_int8_delta_packed,
-        _serve_int8_packed,
-    )
-
     from tpu_als.ops.topk import shortlist_columns
+    from tpu_als.serving.engine import _mesh_queries, _pack_response
+    from tpu_als.serving.index import _build_sharded_int8
 
     cap, _, base, seg = _live_catalog_shapes()
     packed = ((bucket, LIVE_RANK + 2), jnp.int32)
     if name == "steady":
         cols = shortlist_columns(LIVE_ITEMS, 64)
-        return _lower(one_chip, _serve_int8_packed,
-                      ((LIVE_USERS, LIVE_RANK), jnp.float32),
-                      ((cols, LIVE_RANK), jnp.int8), ((cols,), jnp.float32),
-                      ((LIVE_ITEMS, LIVE_RANK), jnp.float32),
-                      ((cols,), jnp.bool_), packed, k=10, shortlist_k=64)
+        return _serve_int8(
+            one_chip, ((LIVE_USERS, LIVE_RANK), jnp.float32),
+            (((cols, LIVE_RANK), jnp.int8), ((cols,), jnp.float32),
+             ((LIVE_ITEMS, LIVE_RANK), jnp.float32), ((cols,), jnp.bool_)),
+            packed)
     if name == "delta":
-        return _lower(one_chip, _serve_int8_delta_packed,
-                      ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32),
-                      *base, *seg, ((), jnp.int32), packed,
-                      k=10, shortlist_k=64)
+        return _serve_int8(
+            one_chip, ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32),
+            base, packed, delta=(*seg, ((), jnp.int32)))
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
@@ -412,7 +405,10 @@ def _lowered(name, bucket, topo, one_chip):
     def shape(s, d, sharding):
         return jax.ShapeDtypeStruct(s, d, sharding=sharding)
 
-    return _build_mesh_serve(mesh, 10, 10, 64, ni, False).lower(
+    # as ``ServingEngine._int8_call`` builds it
+    return _build_sharded_int8(
+        mesh, 10, 10, 64, ni, False, _mesh_queries, _pack_response,
+        "serve_mesh_int8").lower(
         shape((shards * MESH_USERS_PER_SHARD, r), jnp.float32, rows),
         shape((shards * bucket, r + 2), jnp.int32, rows),   # _place_one's
         shape((shards * ni, r), jnp.int8, rows),
@@ -517,8 +513,8 @@ def test_no_scoring_program_passes_over_the_matrix_more_than_steady(
 
 @pytest.mark.parametrize("bucket", BUCKETS)
 def test_the_program_that_excludes_at_the_unseen_cells_size(one_chip, bucket):
-    """``_serve_int8_seen_packed`` at every history pad of the cell's
-    ladder: it compiles; beyond the program without histories it holds
+    """``_serve_int8_packed`` with histories at every history pad of the
+    cell's ladder: it compiles; beyond the program without histories it holds
     the one-byte mask and no four-byte matrix of the scores' size (the
     bit-packed words written out broadcast, or a second ``f32[B,
     columns]``); the only sort it adds is its own, unstable one (the
@@ -526,28 +522,23 @@ def test_the_program_that_excludes_at_the_unseen_cells_size(one_chip, bucket):
     not told is sorted), and no mask is copied row by row (the ``while``
     a scatter into ``bool[B, columns]`` ends in)."""
     from tpu_als.ops.topk import shortlist_columns
-    from tpu_als.serving.engine import (
-        MAX_EXCLUDE,
-        _serve_int8_packed,
-        _serve_int8_seen_packed,
-    )
+    from tpu_als.serving.engine import MAX_EXCLUDE
 
     cols = shortlist_columns(LIVE_ITEMS, 64)
     tables = [((LIVE_USERS, LIVE_RANK), jnp.float32),
               ((cols, LIVE_RANK), jnp.int8), ((cols,), jnp.float32),
               ((LIVE_ITEMS, LIVE_RANK), jnp.float32), ((cols,), jnp.bool_)]
-    plain = _compiled(one_chip, _serve_int8_packed, *tables,
-                      ((bucket, LIVE_RANK + 2), jnp.int32),
-                      k=10, shortlist_k=64)
+    plain = _serve_int8(one_chip, tables[0], tables[1:],
+                        ((bucket, LIVE_RANK + 2), jnp.int32)).compile()
     base = plain.memory_analysis().temp_size_in_bytes
     sorts = plain.as_text().count(" sort(")
     for pad in HISTORY_PADS:
-        c = _compiled(
-            one_chip, _serve_int8_seen_packed, *tables,
-            ((LIVE_USERS + 1,), jnp.int32),
-            ((UNSEEN_RATINGS + HISTORY_PADS[-1],), jnp.int32),
+        c = _serve_int8(
+            one_chip, tables[0], tables[1:],
             ((bucket, LIVE_RANK + 2 + MAX_EXCLUDE), jnp.int32),
-            k=10, shortlist_k=64, pad=pad)
+            histories=(((LIVE_USERS + 1,), jnp.int32),
+                       ((UNSEEN_RATINGS + HISTORY_PADS[-1],), jnp.int32)),
+            pad=pad).compile()
         text = c.as_text()
         entry = text[text.index("\nENTRY "):]
         extra = c.memory_analysis().temp_size_in_bytes - base
@@ -558,29 +549,29 @@ def test_the_program_that_excludes_at_the_unseen_cells_size(one_chip, bucket):
         assert not [ln for ln in entry.splitlines()
                     if " while(" in ln and "pred[" in ln], pad
         assert not re.search(r"= u32\[368,32,\d+,128\]", entry), pad
-        assert 'op_name="jit(_serve_int8_seen_packed)/jit(_int8_topk)/' \
-            "serve.exclude/" in text
+        assert 'op_name="jit(_serve_int8_packed)/serve.exclude/' in text
 
 
 # -- histories that grow (PR 42) -----------------------------------------------
 
 # sha256 of the lowered text of the programs a generation WITH histories
 # runs when nobody called ``warmup_live`` (the ``serve-unseen`` cell), at
-# that cell's shapes, taken on the parent commit (906f260): the histories
-# as published keep their layout, their programs and their text; only a
-# table laid out to grow runs other programs (``runs`` a pair of arrays)
+# that cell's shapes: the histories as published keep their layout and
+# their programs; only a table laid out to grow runs other programs
+# (``runs`` a pair of arrays).  Taken at 906f260 (PR 42's parent), pinned
+# again at PR 46 after the comparison above
 PARENT_LOWERED_SEEN = {
-    ("exact", 8, 4096): "dd710ba22e7cf9ef",
-    ("exact", 32, 4096): "70e882ac8237161a",
-    ("exact", 128, 4096): "2d9f85f31e88b6a6",
-    ("int8", 8, 64): "13f574549f0fee50", ("int8", 8, 512): "e29857978345e68b",
-    ("int8", 8, 4096): "10f68d3c0bc4bb3d",
-    ("int8", 32, 64): "2e12f18bc9e90166",
-    ("int8", 32, 512): "f0385d3c50b3d0d9",
-    ("int8", 32, 4096): "ef08812d83074275",
-    ("int8", 128, 64): "5044add96388252e",
-    ("int8", 128, 512): "1fad941d79f1434b",
-    ("int8", 128, 4096): "04410b6144928bb0",
+    ("exact", 8, 4096): "acc254a58ae6577b",
+    ("exact", 32, 4096): "f2439bc227f36498",
+    ("exact", 128, 4096): "06dd0b878a1807c3",
+    ("int8", 8, 64): "2e3edab155129c6c", ("int8", 8, 512): "0ab65b0db0163d0d",
+    ("int8", 8, 4096): "9f0c49e4b5ef46e3",
+    ("int8", 32, 64): "1eebc45e50694806",
+    ("int8", 32, 512): "2a9faa5256dda76b",
+    ("int8", 32, 4096): "c507bbfffef0cc1e",
+    ("int8", 128, 64): "51b116703c9b54fb",
+    ("int8", 128, 512): "eb6e54fb9b925f76",
+    ("int8", 128, 4096): "b1866e399046b623",
 }
 
 
@@ -610,41 +601,33 @@ def test_histories_as_published_lower_to_the_parents_text(
         one_chip, path, bucket, pad):
     import hashlib
 
-    from tpu_als.serving.engine import (
-        _serve_exact_seen_packed,
-        _serve_int8_seen_packed,
-    )
+    from tpu_als.serving.engine import _serve_exact_packed
 
     tables, runs, ids, packed = _seen_shapes(bucket)
     if path == "int8":
-        low = _lower(one_chip, _serve_int8_seen_packed, *tables, runs, ids,
-                     packed, k=10, shortlist_k=64, pad=pad)
+        low = _serve_int8(one_chip, tables[0], tables[1:], packed,
+                          histories=(runs, ids), pad=pad)
     else:
-        low = _lower(one_chip, _serve_exact_seen_packed, tables[0],
-                     tables[3], ((LIVE_ITEMS,), jnp.bool_), runs, ids,
-                     packed, k=10, item_chunk=8192, pad=pad)
+        one = lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip)
+        low = _serve_exact_packed.lower(
+            one(tables[0]), one(tables[3]), one(((LIVE_ITEMS,), jnp.bool_)),
+            (one(runs), one(ids)), one(packed), k=10, item_chunk=8192,
+            pad=pad)
     assert hashlib.sha256(low.as_text().encode()).hexdigest()[:16] \
         == PARENT_LOWERED_SEEN[path, bucket, pad]
 
 
 @pytest.mark.parametrize("bucket", BUCKETS)
 def test_the_program_that_excludes_from_histories_that_grow(one_chip, bucket):
-    """``_serve_int8_seen_packed`` over a table laid out to grow
+    """``_serve_int8_packed`` over a table of histories laid out to grow
     (``(start, count)`` in place of ``indptr``) at the live-unseen cell's
     size and its top rung, 8,192: it compiles, with the one sort of its
     own, and holds no more than the program at 4,096 over the histories
     as published plus the wider lists."""
-    from tpu_als.serving.engine import _serve_int8_seen_packed
-
     def compiled(grown, pad):
         tables, runs, ids, packed = _seen_shapes(bucket, grown)
-        sh = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-              for s, d in tables]
-        one = lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip)
-        runs = tuple(map(one, runs)) if grown else one(runs)
-        return _serve_int8_seen_packed.lower(
-            *sh, runs, one(ids), one(packed), k=10, shortlist_k=64,
-            pad=pad).compile()
+        return _serve_int8(one_chip, tables[0], tables[1:], packed,
+                           histories=(runs, ids), pad=pad).compile()
 
     frozen, grown = compiled(False, 4096), compiled(True, 8192)
     assert grown.as_text().count(" sort(") \

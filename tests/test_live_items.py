@@ -651,7 +651,7 @@ def segment_states(idx, V, rng, slots, appended=9):
                                                         (40_000, 16, 2)])
 def test_a_segment_as_the_shortlists_tail_answers_as_concatenated(
         monkeypatch, state, n_items, shortlist_k, stages):
-    """``_int8_topk_delta`` hands its shortlist the segment's scores as a
+    """``shortlist_rescore`` hands its shortlist the segment's scores as a
     ``tail``: scores and ids are, bit for bit, those of the program that
     concatenated them to the matrix, whatever the segment holds."""
     from tpu_als.serving import index as index_module
@@ -664,13 +664,14 @@ def test_a_segment_as_the_shortlists_tail_answers_as_concatenated(
     assert (plan.stages, plan.tail) == (stages, 64)
     assert plan.columns == int(idx.Vq.shape[0])
     Q = jnp.asarray(rng.normal(size=(9, RANK)).astype(np.float32))
-    args = (Q, idx.Vq, idx.sv, idx.V, idx.valid, *idx._seg, idx._last_id())
     got = idx.topk(Q, K)
     # the parent's program: the shortlist replaced, under a function of
     # its own (a jit of the same function would answer from its cache)
     monkeypatch.setattr(index_module, "shortlist_topk", concatenated_top_k)
-    want = jax.jit(lambda *a: index_module._int8_topk_delta.__wrapped__(
-        *a, k=K, shortlist_k=idx.shortlist_k))(*args)
+    want = jax.jit(lambda *a, **kw: index_module.shortlist_rescore(
+        *a, k=K, shortlist_k=idx.shortlist_k, **kw))(
+            Q, idx.Vq, idx.sv, idx.V, idx.valid, delta=idx._seg,
+            last_id=idx._last_id())
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
     if state in ("appended", "full"):       # the segment does answer

@@ -358,9 +358,9 @@ def test_the_row_and_the_id_are_swapped_in_together():
 def test_what_histories_still_refuse_says_so():
     rng, V, hist, model, eng, srv, upd = make_stack(seed=2)
     Uh = model._U
-    with pytest.raises(NotImplementedError, match="_int8_topk_delta"):
+    with pytest.raises(NotImplementedError, match="delta segment that also excludes"):
         eng.publish_update(Uh, V, touched_items=[0], touched_users=[0])
-    with pytest.raises(NotImplementedError, match="_int8_topk_delta"):
+    with pytest.raises(NotImplementedError, match="delta segment that also excludes"):
         eng.publish_update(Uh, np.concatenate([V, V[:1]]), touched_users=[0])
     items = LiveUpdater(eng, srv, fold_items=True)
     with pytest.raises(NotImplementedError, match="fold_items"):

@@ -16,6 +16,7 @@ from tpu_als.obs.schema import SERVE_MESH_SCOPES
 from tpu_als.parallel.comm_audit import collective_bytes
 from tpu_als.parallel.mesh import AXIS, shard_map
 from tpu_als.serving import engine as engine_module
+from tpu_als.serving import index as index_module
 from tpu_als.serving.engine import ServingEngine, _mesh_lookup
 from tpu_als.serving.index import (
     SCORE_ULPS,
@@ -337,14 +338,13 @@ def test_exact_fallback_scores_per_shard_and_uploads_nothing(monkeypatch):
                                    "full"])
 @pytest.mark.parametrize("n_items,shortlist_k,stages", [(N_ITEMS, 128, 1),
                                                         (36_000, 16, 2)])
-def test_shard_score_with_a_segment_answers_as_concatenated(
+def test_a_shards_score_with_a_segment_answers_as_concatenated(
         monkeypatch, state, n_items, shortlist_k, stages):
-    """``_shard_score`` hands its shortlist the (replicated) segment's
-    scores as a ``tail``: on every seeded state of the segment the sharded
+    """``shortlist_rescore`` on a shard hands its shortlist the
+    (replicated) segment's scores as a ``tail``: on every seeded state of the segment the sharded
     program's scores and ids are, bit for bit, those of the program that
     concatenated them to its shard's matrix."""
     from tests.test_live_items import concatenated_top_k, segment_states
-    from tpu_als.serving import index as index_module
 
     rng = np.random.default_rng(43 + n_items)
     V = rng.standard_normal((n_items, RANK)).astype(np.float32)
@@ -386,8 +386,10 @@ def given_to_every_shard(monkeypatch, eng, path, st):
     whole = jax.device_put(np.tile(st, (S, 1)), eng._by_rows)
     if path == "int8":
         _, args, _ = eng._int8_call(m, m.index, whole)
-        fn = engine_module._build_mesh_serve.__wrapped__(
-            eng.mesh, K, *m.index.shard_widths(K), m.index.ni_loc, False)
+        fn = index_module._build_sharded_int8.__wrapped__(
+            eng.mesh, K, *m.index.shard_widths(K), m.index.ni_loc, False,
+            engine_module._mesh_queries, engine_module._pack_response,
+            "serve_mesh_int8")
     else:
         _, args, _ = eng._exact_call(m, whole)
         ni_loc = int(m.V.shape[0]) // S

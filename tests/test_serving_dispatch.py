@@ -15,30 +15,43 @@ called on a ``device_put`` copy of the same staged array; (ii) not one
 compilation across them, nor on the jit fall-back after a dropped pin;
 (iv) two
 batches of one bucket in flight at once are staged in two arrays and
-both answered right."""
+both answered right.
+
+Every dispatch of a warmed engine rides its pin (PR 46): for an engine of
+each benchmark cell's kind, warmed as its cell warms it, one batch for
+every (bucket, path, history pad) it pinned goes through that pinned
+executable — called once, still pinned afterwards, nothing compiled, the
+launch span saying so and naming the executable's own module.  A pin that
+misses is invisible otherwise: ``_run_pinned`` drops it and calls the
+``jit`` function, whose compile costs milliseconds here and seconds on
+the chip."""
 
 from __future__ import annotations
 
+import re
 import threading
+import time
 
 import jax
 import numpy as np
 import pytest
 
 from tests.conftest import CompileCount, GatedResponses
-from tpu_als import make_mesh
+from tpu_als import ALSModel, FoldInServer, IdMap, LiveUpdater, make_mesh
+from tpu_als.resilience import faults
+from tpu_als.serving import engine as engine_module
 from tpu_als.serving.engine import ServingEngine
 
 S, N_USERS, N_ITEMS, RANK, K, BUCKET = 4, 203, 2003, 16, 5, 8
 BATCHES = 50
-# program: (the jitted function's name, the key warmup() pins it under)
+# program: the key warmup() pins it under
 PROGRAMS = {
-    "int8": ("_serve_int8_packed", (BUCKET, "int8")),
-    "int8_delta": ("_serve_int8_delta_packed", (BUCKET, "int8_delta")),
-    "int8_seen": ("_serve_int8_seen_packed", (BUCKET, "int8", 64)),
-    "exact": ("_serve_exact_packed", (BUCKET, "exact")),
-    "mesh_int8": ("serve_mesh_int8", (BUCKET, "int8")),
-    "mesh_exact": ("serve_mesh_exact", (BUCKET, "exact")),
+    "int8": (BUCKET, "int8"),
+    "int8_delta": (BUCKET, "int8_delta"),
+    "int8_seen": (BUCKET, "int8", 64),
+    "exact": (BUCKET, "exact"),
+    "mesh_int8": (BUCKET, "int8"),
+    "mesh_exact": (BUCKET, "exact"),
 }
 
 
@@ -123,7 +136,7 @@ def flown(request):
     """What one engine of ``program`` did, step by step (the keys of the
     returned dict), with ``jax.device_put`` and the compiler watched."""
     program = request.param
-    name, key = PROGRAMS[program]
+    key = PROGRAMS[program]
     eng, rng = build(program)
     compiles = CompileCount()
     pin = eng._pinned[key] = Calls(eng._pinned[key])
@@ -134,7 +147,7 @@ def flown(request):
         return staged[-1]
 
     eng._staged = keep_staged
-    out = {"program": program, "name": name, "eng": eng}
+    out = {"program": program, "eng": eng}
     # (i) one batch on the caller's thread
     out["answers"] = packed(serve_now(eng, requests(rng, 5)))
     out["placed"] = on_a_placed_copy(eng, pin, pin.args[-1],
@@ -191,7 +204,7 @@ def test_the_batch_rides_the_call_bit_for_bit(flown):
     """(i) the program and its operands are the parent's: the answers of
     a batch served through the engine are those of the same pinned
     executable on a ``device_put`` copy of the same staged array."""
-    eng, (_, key) = flown["eng"], PROGRAMS[flown["program"]]
+    eng, key = flown["eng"], PROGRAMS[flown["program"]]
     assert eng._pinned[key].compiled is not None
     assert flown["answers"].shape == (5, 2 * K)
     assert np.array_equal(flown["answers"], flown["placed"])
@@ -245,3 +258,192 @@ def test_the_fallback_on_a_mesh_uploads_the_last_id_once():
     assert int(handle) == N_ITEMS - 1
     assert handle is not eng._last_item(N_ITEMS + 1)
     assert int(eng._last_item(N_ITEMS + 1)) == N_ITEMS
+
+
+# -- every dispatch of a warmed engine rides its pin (PR 46) ------------------
+
+PIN_BUCKETS = (8, 32)
+# kind of engine -> the history pads it pins (None: it excludes nothing)
+# and the name its int8 program is pinned under
+KINDS = {
+    "plain": (None, "int8"),            # serve-steady, serve-foldin
+    "segment": (None, "int8_delta"),    # serve-foldin-items
+    "histories": ((64, 512), "int8"),   # serve-unseen
+    "grown": ((64, 128), "int8"),       # serve-foldin-unseen
+    "mesh": (None, "int8"),             # serve-steady-mesh
+}
+# resident history lengths: the as-published ladder 64 / 512 from the
+# longest of ``histories``; ``grown``'s longest, 64, with its room (72)
+# asks for one rung more, 128, which only an append reaches
+LENGTHS = {"histories": (0, 3, 40, 64, 65, 300), "grown": (0, 3, 40, 63, 64)}
+
+
+def pin_key(bucket, path, pad):
+    return (bucket, path) if pad is None else (bucket, path, pad)
+
+
+def pins_of(kind):
+    """``[(bucket, path, history pad or None)]`` of what ``kind``'s
+    warm-ups pin: per bucket the int8 program (at every history pad) and
+    the exact fallback (at the longest alone)."""
+    pads, int8 = KINDS[kind]
+    return [(B, path, pad) for B in PIN_BUCKETS for path, pad in (
+        [(int8, None), ("exact", None)] if pads is None else
+        [(int8, p) for p in pads] + [("exact", pads[-1])])]
+
+
+class Spans:
+    """In ``TraceAnnotation``'s place in the engine: every span's name
+    with the metadata it was given, at birth or later."""
+
+    def __init__(self):
+        self.seen = []
+
+    def __call__(self, name, **stats):
+        self.seen.append((name, stats))
+        return _Span(stats)
+
+    @staticmethod
+    def is_enabled():
+        return False                # no profiler records: no CPU stamps
+
+
+class _Span:
+    def __init__(self, stats):
+        self.stats = stats
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **stats):
+        self.stats.update(stats)
+
+
+def warmed(kind):
+    """``(engine, {history length: a user of it})`` of ``kind``, published
+    and warmed as the benchmark cell of that kind does it."""
+    rng = np.random.default_rng(46)
+    V = (rng.standard_normal((N_ITEMS, RANK)) / 4).astype(np.float32)
+    U = rng.standard_normal((N_USERS, RANK)).astype(np.float32)
+    eng = ServingEngine(k=K, buckets=PIN_BUCKETS, shortlist_k=32,
+                        mesh=make_mesh(S) if kind == "mesh" else None)
+    if kind not in LENGTHS:
+        eng.publish(U, V)
+        eng.warmup()
+        if kind == "segment":
+            eng.warmup_live(max_rows=8)
+            # a publish that moves the catalog: a row re-folded, one new
+            V2 = np.concatenate([V, V[:1]])
+            V2[5] *= 0.5
+            assert eng.publish_update(U, V2, touched_items=[5])[1] == "delta"
+        return eng, {}
+    lengths = np.resize(LENGTHS[kind], N_USERS)
+    items = [np.sort(rng.choice(N_ITEMS, n, replace=False)) for n in lengths]
+    indptr = np.concatenate([[0], np.cumsum(lengths)]).astype(np.int64)
+    indices = np.concatenate(items).astype(np.int32)
+    eng.publish(U, V, user_seen=(indptr, indices))
+    users = {int(n): j for j, n in enumerate(LENGTHS[kind])}
+    if kind == "histories":
+        eng.warmup()
+        return eng, users
+    model = ALSModel(
+        RANK, IdMap(ids=np.arange(N_USERS)), IdMap(ids=np.arange(N_ITEMS)),
+        U.copy(), V.copy(),
+        {"userCol": "u", "itemCol": "i", "ratingCol": "r", "regParam": 0.1,
+         "implicitPrefs": False, "alpha": 1.0, "nonnegative": False})
+    srv = FoldInServer(model, base_history=(
+        indptr, indices, np.ones(len(indices), np.float32)))
+    srv.prewarm(rows=(8,))
+    upd = LiveUpdater(eng, srv, max_batch=8, max_wait_ms=2.0).start()
+    try:
+        # appends under the warmed programs: the user at the top resident
+        # rung outgrows it (and its run's room: the run moves), one at 63
+        # crosses 64, one the model never saw gets a first run
+        for user in [users[64]] * 10 + [users[63]] * 2 + [N_USERS + 3]:
+            have = set(items[user].tolist()) if user < N_USERS else set()
+            item = next(i for i in range(N_ITEMS) if i not in have)
+            if user < N_USERS:
+                items[user] = np.append(items[user], item)
+            seq0 = eng.published_seq
+            upd.submit(user, item, 4.0)
+            deadline = time.perf_counter() + 30.0
+            while eng.published_seq == seq0:
+                assert time.perf_counter() < deadline, "no publish"
+                time.sleep(0.002)
+    finally:
+        upd.stop(drain_timeout_s=30.0)
+    # ten and two appended: both ride the rung above the resident ones
+    users[74], users[65] = users.pop(64), users.pop(63)
+    return eng, users
+
+
+@pytest.fixture(scope="module")
+def warm_engines():
+    """Engines by kind, each built when first asked for."""
+    built = {}
+
+    def get(kind):
+        if kind not in built:
+            built[kind] = warmed(kind)
+        return built[kind]
+
+    return get
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_the_warm_ups_pin_what_they_pinned_at_the_parent(warm_engines, kind):
+    eng, _ = warm_engines(kind)
+    assert set(eng._pinned) == {pin_key(*pin) for pin in pins_of(kind)}
+
+
+@pytest.mark.parametrize("kind,bucket,path,pad", [
+    (kind, *pin) for kind in KINDS for pin in pins_of(kind)])
+def test_a_warmed_engines_dispatch_rides_its_pin(warm_engines, monkeypatch,
+                                                 kind, bucket, path, pad):
+    eng, users = warm_engines(kind)
+    key = pin_key(bucket, path, pad)
+    rows = 5 if bucket == 8 else 20
+    if pad is None:
+        payloads = requests(np.random.default_rng(bucket), rows)
+    else:
+        # by id for users whose histories the pad holds, the longest of
+        # them one the pad below would not; the exact fallback rides the
+        # top pad whatever its batch holds
+        fit = sorted(n for n in users if n <= pad)
+        if path == "exact":
+            fit = fit[:2]
+        else:
+            assert fit[-1] > max([p for p in KINDS[kind][0] if p < pad],
+                                 default=-1), (pad, sorted(users))
+        payloads = [users[fit[-1]]] + [users[fit[j % len(fit)]]
+                                       for j in range(rows - 1)]
+    spans = Spans()
+    monkeypatch.setattr(engine_module, "TraceAnnotation", spans)
+    pin = Calls(eng._pinned[key])
+    monkeypatch.setitem(eng._pinned, key, pin)
+    compiles = CompileCount()
+    if path == "exact":
+        faults.install("serving.score=corrupt@nth=1")
+    try:
+        answers = serve_now(eng, payloads)
+    finally:
+        faults.clear()
+    assert len(answers) == rows
+    assert len(pin.args) == 1, "the batch did not ride the pin under its key"
+    assert eng._pinned[key] is pin, "the pin was dropped"
+    assert compiles.n == 0
+    (launch,) = [stats for name, stats in spans.seen
+                 if name == "serve.batch.dispatch.launch"]
+    assert launch["pinned"] == 1
+    # the span names the executable's own module: what a device trace
+    # calls its runs, and never the other path's
+    module = re.match(r"HloModule (\S+?),", pin.compiled.as_text()).group(1)
+    assert launch["program"] == module
+    pads, int8 = KINDS[kind]
+    other = pin_key(bucket, int8 if path == "exact" else "exact",
+                    pads and pads[-1])
+    assert module != re.match(
+        r"HloModule (\S+?),", eng._pinned[other].as_text()).group(1)

@@ -31,7 +31,11 @@ from tpu_als.ops.topk import (  # noqa: E402
 from tpu_als.resilience import faults  # noqa: E402
 from tpu_als.serving import engine as engine_mod  # noqa: E402
 from tpu_als.serving.engine import MAX_EXCLUDE, ServingEngine  # noqa: E402
-from tpu_als.serving.index import Int8CandidateIndex, _int8_topk  # noqa: E402
+from tpu_als.serving.index import (  # noqa: E402
+    SCORE_ULPS,
+    Int8CandidateIndex,
+    _topk_jit,
+)
 
 K, SK = 10, 64
 N_USERS, N_ITEMS, RANK = 160, 36_864, 16   # 288 blocks of 128: two stages
@@ -365,7 +369,7 @@ def test_what_cannot_take_histories_yet_says_so(factors):
     U, V, scores = factors
     hist = csr(top_histories(scores, [5] * N_USERS))
     mesh = ServingEngine(k=K, buckets=(8,), mesh=make_mesh(4))
-    with pytest.raises(NotImplementedError, match="_shard_score"):
+    with pytest.raises(NotImplementedError, match="shortlist_rescore"):
         mesh.publish(U, V[:4096], user_seen=hist)
     mesh.publish(U, V[:4096])
     with pytest.raises(NotImplementedError, match="mesh"):
@@ -374,7 +378,7 @@ def test_what_cannot_take_histories_yet_says_so(factors):
     # it refuses is a catalog that moves (tests/test_live_unseen.py)
     eng = ServingEngine(k=K, buckets=(8,), shortlist_k=SK)
     eng.publish(U, V, user_seen=hist)
-    with pytest.raises(NotImplementedError, match="_int8_topk_delta"):
+    with pytest.raises(NotImplementedError, match="delta segment that also excludes"):
         eng.publish_update(U, V, touched_items=[0])
     eng.warmup_live()
     assert eng.published_index.delta_slots == 0     # no segment for it
@@ -411,7 +415,7 @@ def test_excluded_mask_is_the_brute_force_mask(shape):
 def test_the_rule_is_valid_false_for_that_row_alone(factors):
     """``chunked_topk_scores(seen=...)`` row by row against the same
     function with ``item_valid`` cleared at that row's ids, and
-    ``_int8_topk`` against itself with the ids cleared for every row:
+    ``shortlist_rescore`` against itself with the ids cleared for every row:
     bit for bit."""
     U, V, scores = factors
     rng = np.random.default_rng(8)
@@ -432,11 +436,55 @@ def test_the_rule_is_valid_false_for_that_row_alone(factors):
         assert (np.asarray(i[b]) == np.asarray(ri[b])).all()
     idx = Int8CandidateIndex(V, item_valid=np.asarray(valid), shortlist_k=SK)
     same = np.tile(seen[2], (n, 1))
-    s, i = _int8_topk(jnp.asarray(U[:n]), idx.Vq, idx.sv, idx.V, idx.valid,
-                      k=K, shortlist_k=SK, seen=(jnp.asarray(same),))
+    s, i = _topk_jit(jnp.asarray(U[:n]), idx.Vq, idx.sv, idx.V, idx.valid,
+                     k=K, shortlist_k=SK, seen=(jnp.asarray(same),))
     cleared = np.asarray(idx.valid).copy()
     cleared[seen[2][seen[2] < N_ITEMS]] = False
-    rs, ri = _int8_topk(jnp.asarray(U[:n]), idx.Vq, idx.sv, idx.V,
-                        jnp.asarray(cleared), k=K, shortlist_k=SK)
+    rs, ri = _topk_jit(jnp.asarray(U[:n]), idx.Vq, idx.sv, idx.V,
+                       jnp.asarray(cleared), k=K, shortlist_k=SK)
     assert (np.asarray(s) == np.asarray(rs)).all()
     assert (np.asarray(i) == np.asarray(ri)).all()
+
+
+@pytest.mark.parametrize("state", ["free_slots", "overridden", "appended",
+                                   "full"])
+@pytest.mark.parametrize("n_items,stages", [(2048, 1), (N_ITEMS, 2)])
+def test_seen_and_a_segment_together_against_the_exact_scan(state, n_items,
+                                                            stages):
+    """``shortlist_rescore`` with ``seen`` AND ``delta`` (the engine
+    refuses the combination still; the one pipeline traces it): against
+    ``chunked_topk_scores(seen=...)`` over the catalog as the index holds
+    it, for every seeded state of the segment, histories that name
+    overridden and appended ids among each row's best."""
+    from tests.test_live_items import segment_states
+    from tpu_als.serving.index import build_index
+
+    rng = np.random.default_rng(46 + n_items)
+    V = rng.normal(size=(n_items, RANK)).astype(np.float32)
+    idx = segment_states(build_index(V, shortlist_k=SK), V, rng,
+                         slots=64)[state]
+    assert idx.shortlist_plan(rows=9).stages == stages
+    now, ok = idx.rows(np.arange(idx.n_items))      # the catalog it serves
+    Q = rng.normal(size=(9, RANK)).astype(np.float32)
+    best = np.argsort(-np.where(ok, Q @ now.T, -np.inf), axis=1)
+    hist = np.full((9, 128), NOT_AN_ID, np.int32)
+    own = np.full((9, 8), NOT_AN_ID, np.int32)
+    for b, m in enumerate([0, 1, 5, K, 40, 100, 128, 3, 77]):
+        hist[b, :m] = best[b, :2 * m:2]             # every other of its best
+        own[b, :b % 8] = best[b, 1:2 * (b % 8):2]
+    if idx.delta_count:     # and ids the segment holds, best or not
+        hist[:, -4:] = idx.d_rows[-4:]
+    seen = (jnp.asarray(hist), jnp.asarray(own))
+    s, i = _topk_jit(jnp.asarray(Q), idx.Vq, idx.sv, idx.V, idx.valid, k=K,
+                     shortlist_k=SK, delta=idx._seg, last_id=idx._last_id(),
+                     seen=seen)
+    rs, ri = chunked_topk_scores(jnp.asarray(Q), jnp.asarray(now),
+                                 jnp.asarray(ok), K, seen=seen)
+    s, i, rs, ri = (np.asarray(a) for a in (s, i, rs, ri))
+    assert np.array_equal(i, ri)
+    tol = SCORE_ULPS * np.spacing(np.abs(rs).max(axis=1))[:, None]
+    assert (np.abs(s - rs) <= tol).all()
+    for b in range(9):
+        assert not set(i[b]) & (set(hist[b]) | set(own[b]))
+    if state in ("appended", "full"):       # the segment does answer
+        assert np.isin(i, idx.d_rows).any()

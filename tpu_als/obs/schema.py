@@ -445,7 +445,10 @@ SERVE_DISPATCH_SPAN_KEYS = (
     #                                 returns (seq, program: the jitted
     #                                 function as the trace's XLA Modules
     #                                 line names it,
-    #                                 ``jit__serve_int8_packed``, ...;
+    #                                 ``jit__serve_int8_packed``,
+    #                                 ``jit__serve_exact_packed``,
+    #                                 ``jit_serve_mesh_int8``,
+    #                                 ``jit_serve_mesh_exact``;
     #                                 pinned 0|1: the AOT executable took
     #                                 it)
 )
@@ -460,7 +463,8 @@ PIPE_SPAN_KEYS = (
     "pipe.slot_wait",
 )
 # inside a mesh engine's ONE scoring program a bucket (serving/engine.py
-# ``_build_mesh_serve`` / ``_build_mesh_exact``) the three steps that
+# ``_build_mesh_exact``; serving/index.py ``_build_sharded_int8``, which
+# the engine gives its lookup and pack) the three steps that
 # exist only across chips are ``jax.named_scope``s, in every operation's
 # ``op_name`` beside ``serve.shortlist.*`` (ops/topk.py); the engine
 # thread's spans above lie around that program unchanged,

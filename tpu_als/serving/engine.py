@@ -145,7 +145,9 @@ stack plugs into:
   block: the catalog and its int8 rows (no device ever holds the whole
   of them, and the engine and its index share the one sharded copy) and
   the user table (spare rows on the last shards).  A batch is ONE
-  program (:func:`_build_mesh_serve`): the staged batch is placed on
+  program (``serving.index._build_sharded_int8`` behind
+  :func:`_mesh_queries`, :meth:`ServingEngine._int8_call`; a device
+  trace names it ``jit_serve_mesh_int8``): the staged batch is placed on
   the mesh's first device alone and one all-reduce spreads it
   (:meth:`ServingEngine._place_one`, :func:`_mesh_spread`), every
   shard takes the user rows
@@ -216,6 +218,7 @@ from tpu_als.ops.topk import (
     chunked_topk_scores,
     exclusion_plan,
 )
+from tpu_als.parallel.mesh import AXIS, shard_map
 from tpu_als.resilience import faults
 from tpu_als.serving.batcher import (
     DEFAULT_BUCKETS,
@@ -227,16 +230,15 @@ from tpu_als.serving.batcher import (
 from tpu_als.serving.index import (
     Int8CandidateIndex,
     ShardedInt8Index,
-    _int8_topk,
-    _int8_topk_delta,
+    _build_sharded_int8,
     _next_pow2,
     _shard_merge,
-    _shard_score,
     mask_block,
     mesh_exchange_bytes,
     mesh_spread_bytes,
     place_catalog,
     segment_write_bytes,
+    shortlist_rescore,
 )
 
 
@@ -471,36 +473,6 @@ def _pack_response(s, ix):
          ix.astype(jnp.int32)], axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "item_chunk"))
-def _serve_exact_packed(U, V, valid, packed, *, k, item_chunk):
-    """Whole exact request path — select → chunked top-k → pack — as one
-    executable, so :meth:`ServingEngine.warmup` can AOT-pin it."""
-    Ub = _select_packed(U, packed)
-    s, ix = chunked_topk_scores(Ub, V, valid, k, item_chunk=item_chunk)
-    return _pack_response(s, ix)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "shortlist_k"))
-def _serve_int8_packed(U, Vq, sv, V, valid, packed, *, k, shortlist_k):
-    """Whole delta-free int8 request path as one pinnable executable."""
-    Ub = _select_packed(U, packed)
-    s, ix = _int8_topk(Ub, Vq, sv, V, valid, k=k, shortlist_k=shortlist_k)
-    return _pack_response(s, ix)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "shortlist_k"))
-def _serve_int8_delta_packed(U, Vq, sv, V, valid, drows, dVq, dsv, dV,
-                             dvalid, last_id, packed, *, k, shortlist_k):
-    """The same with a delta segment beside the base arrays
-    (``serving.index._int8_topk_delta``): the one program a bucket an
-    engine whose catalog moves runs, whatever the segment holds — its
-    slots are fixed (:meth:`ServingEngine.warmup_live` pins it)."""
-    Ub = _select_packed(U, packed)
-    s, ix = _int8_topk_delta(Ub, Vq, sv, V, valid, drows, dVq, dsv, dV,
-                             dvalid, last_id, k=k, shortlist_k=shortlist_k)
-    return _pack_response(s, ix)
-
-
 def _select_seen(runs, indices, packed, rank, pad):
     """``(int32[B, pad], int32[B, MAX_EXCLUDE])``: what each slot of a
     staged batch is not to be answered with — the first ``pad`` ids of
@@ -536,27 +508,40 @@ def _select_seen(runs, indices, packed, rank, pad):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "shortlist_k", "pad"))
-def _serve_int8_seen_packed(U, Vq, sv, V, valid, runs, indices, packed,
-                            *, k, shortlist_k, pad):
-    """:func:`_serve_int8_packed` for a batch that excludes: the staging
-    layout is ``MAX_EXCLUDE`` columns wider (the requests' own lists),
-    the users' histories are taken from the published table
-    (:func:`_select_seen`; ``runs``: in either layout), and the scoring
-    takes them out (``ops.topk.excluded_mask``'s rule).  One program a
-    bucket and history pad."""
+def _serve_int8_packed(U, Vq, sv, V, valid, delta, histories, packed, *, k,
+                       shortlist_k, pad=None):
+    """Whole int8 request path — select → shortlist and rescore
+    (``serving.index.shortlist_rescore``) → pack — as one executable, so
+    that :meth:`ServingEngine.warmup` can AOT-pin it: one program a
+    bucket and shape of its arguments.  ``delta``: ``()``, or the
+    index's delta segment and last catalog id ``(drows, dVq, dsv, dV,
+    dvalid, last_id)`` — the one program a bucket an engine whose
+    catalog moves runs, whatever the segment holds: its slots are fixed
+    (:meth:`ServingEngine.warmup_live` pins it).  ``histories``: ``()``,
+    or for a batch that excludes ``(runs, indices)`` of the published
+    table, in either layout: the staging layout is then ``MAX_EXCLUDE``
+    columns wider (the requests' own lists), the users' histories are
+    taken from the table at history pad ``pad`` (:func:`_select_seen`),
+    and the scoring takes both out (``ops.topk.excluded_mask``'s rule):
+    one program a bucket and history pad."""
     Ub = _select_packed(U, packed)
-    seen = _select_seen(runs, indices, packed, U.shape[1], pad)
-    s, ix = _int8_topk(Ub, Vq, sv, V, valid, k=k, shortlist_k=shortlist_k,
-                       seen=seen)
+    seen = (_select_seen(*histories, packed, U.shape[1], pad)
+            if histories else None)
+    s, ix = shortlist_rescore(
+        Ub, Vq, sv, V, valid, k=k, shortlist_k=shortlist_k,
+        delta=delta[:5], last_id=delta[5] if delta else None, seen=seen)
     return _pack_response(s, ix)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "item_chunk", "pad"))
-def _serve_exact_seen_packed(U, V, valid, runs, indices, packed, *, k,
-                             item_chunk, pad):
-    """The exact fallback of a batch that excludes."""
+def _serve_exact_packed(U, V, valid, histories, packed, *, k, item_chunk,
+                        pad=None):
+    """Whole exact request path — select → chunked top-k → pack — the
+    fallback's one executable a bucket; ``histories`` and ``pad`` as
+    :func:`_serve_int8_packed` takes them."""
     Ub = _select_packed(U, packed)
-    seen = _select_seen(runs, indices, packed, U.shape[1], pad)
+    seen = (_select_seen(*histories, packed, U.shape[1], pad)
+            if histories else None)
     s, ix = chunked_topk_scores(Ub, V, valid, k, item_chunk=item_chunk,
                                 seen=seen)
     return _pack_response(s, ix)
@@ -660,43 +645,12 @@ def _mesh_lookup(U, packed, *, me, axis):
     return jnp.where(rowmask[:, None], rows, jax.lax.psum(mine, axis))
 
 
-@functools.lru_cache(maxsize=32)
-def _build_mesh_serve(mesh, k, k_loc, sk_loc, ni_loc, has_delta):
-    """A mesh engine's whole int8 request path as ONE program: the
-    by-id lookup in the sharded user table, each shard's int8 shortlist
-    and f32 rescore over its slice of the catalog
-    (``serving.index._shard_score``, the body ``ShardedInt8Index.topk``
-    runs), the merge of the shards' local top-k lists on every shard,
-    the packed ``[B, 2k]`` response, replicated: one device→host
-    transfer answers the batch.  ``packed`` is the ``[S * B, rank + 2]``
-    array of :meth:`ServingEngine._place_one`, sharded by rows, and the
-    program's first operation spreads its one live block
-    (:func:`_mesh_spread`).  :meth:`ServingEngine.warmup` lowers,
-    compiles and pins it per bucket, as it does
-    :func:`_serve_int8_packed` without a mesh; a device trace names it
-    ``jit_serve_mesh_int8`` on its ``XLA Modules`` line, one a batch."""
-    from tpu_als.parallel.mesh import AXIS, shard_map
-
-    P = jax.sharding.PartitionSpec
-
-    def serve_mesh_int8(U, packed, Vq, sv, V, valid, last_id, *delta):
-        me = jax.lax.axis_index(AXIS)
-        with jax.named_scope(SERVE_MESH_SCOPES[0]):
-            packed = _mesh_spread(packed, axis=AXIS)
-            Ub = _mesh_lookup(U, packed, me=me, axis=AXIS)
-        with jax.named_scope(SERVE_MESH_SCOPES[1]):
-            s, gids = _shard_score(Ub, Vq, sv, V, valid, delta, me=me,
-                                   k_loc=k_loc, sk_loc=sk_loc,
-                                   ni_loc=ni_loc)
-        with jax.named_scope(SERVE_MESH_SCOPES[2]):
-            return _pack_response(
-                *_shard_merge(s, gids, last_id, axis=AXIS, k=k))
-
-    return jax.jit(shard_map(
-        serve_mesh_int8, mesh=mesh,
-        in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P())
-        + (P(),) * (5 if has_delta else 0),
-        out_specs=P(), check_vma=False))
+def _mesh_queries(U, packed, *, me, axis):
+    """What stands before the scoring in both of a mesh engine's
+    programs: the staged batch spread from the one shard that was given
+    it, then the by-id lookup — every shard ends with the ``[B, rank]``
+    queries."""
+    return _mesh_lookup(U, _mesh_spread(packed, axis=axis), me=me, axis=axis)
 
 
 @functools.lru_cache(maxsize=32)
@@ -704,15 +658,12 @@ def _build_mesh_exact(mesh, k, k_loc, ni_loc, item_chunk):
     """A mesh engine's exact fallback, per shard: the lookup, the exact
     chunked scan of this shard's slice of the engine's own sharded
     catalog, the same merge.  Nothing of the catalog moves."""
-    from tpu_als.parallel.mesh import AXIS, shard_map
-
     P = jax.sharding.PartitionSpec
 
     def serve_mesh_exact(U, packed, V, valid, last_id):
         me = jax.lax.axis_index(AXIS)
         with jax.named_scope(SERVE_MESH_SCOPES[0]):
-            packed = _mesh_spread(packed, axis=AXIS)
-            Ub = _mesh_lookup(U, packed, me=me, axis=AXIS)
+            Ub = _mesh_queries(U, packed, me=me, axis=AXIS)
         with jax.named_scope(SERVE_MESH_SCOPES[1]):
             s, ix = chunked_topk_scores(Ub, V, valid, k_loc,
                                         item_chunk=item_chunk)
@@ -733,8 +684,6 @@ def _build_mesh_scatter(mesh):
     is given the same ``(rows, vals)`` and writes the rows it owns into
     its own part, IN PLACE (the table is donated; the others fall on the
     out-of-range sentinel and are dropped).  No collective, no copy."""
-    from tpu_als.parallel.mesh import AXIS, shard_map
-
     P = jax.sharding.PartitionSpec
 
     def scatter_users_mesh(U, rows, vals):
@@ -1275,8 +1224,9 @@ class ServingEngine:
         rule ``ops.topk.excluded_mask`` states, on the int8 path and on
         the exact fallback alike.  ``None`` publishes none: the engine
         then compiles and runs what it did before it knew of histories.
-        Not yet with a mesh (``serving.index._shard_score`` takes no
-        per-row mask: each shard would mask its own ids) — refused here.
+        Not yet with a mesh (``serving.index.shortlist_rescore`` takes
+        no per-row mask on a shard: each would mask the ids it owns) —
+        refused here.
         The histories published here lie run after run with no room
         between them; :meth:`warmup_live` lays them out to GROW, after
         which :meth:`publish_update` appends to them
@@ -1287,9 +1237,10 @@ class ServingEngine:
         mode = faults.check("serving.publish")
         if user_seen is not None and self.mesh is not None:
             raise NotImplementedError(
-                "publish(user_seen=...) on a mesh engine: the sharded "
-                "scoring program (serving.index._shard_score) takes no "
-                "per-row exclusion; each shard would mask the ids it owns")
+                "publish(user_seen=...) on a mesh engine: the scoring "
+                "pipeline (serving.index.shortlist_rescore) takes no "
+                "per-row exclusion on a shard; each would mask the ids it "
+                "owns")
         Vh = np.asarray(V, dtype=np.float32)
         Ni = int(Vh.shape[0])
         seen = (None if user_seen is None
@@ -1447,9 +1398,9 @@ class ServingEngine:
         starts with an empty history.  The catalog of such a generation
         does not move: ``touched_items``, a catalog of another size or
         ``item_valid`` raise ``NotImplementedError`` before anything is
-        written (the program with a delta segment,
-        ``serving.index._int8_topk_delta``, takes no per-row
-        exclusion), and so does ``seen_appended`` on a generation that
+        written (no warm-up pins the program that excludes AND scores a
+        delta segment, and no cell measures it), and so does
+        ``seen_appended`` on a generation that
         holds no histories.  The histories are laid out to grow by
         :meth:`warmup_live`; on an engine nobody warmed up the first
         such publish does it, under the traffic, with a warning.
@@ -1461,8 +1412,8 @@ class ServingEngine:
             raise NotImplementedError(
                 "publish_update of a catalog that moves on a generation "
                 "that holds users' histories: the scoring program with a "
-                "delta segment (serving.index._int8_topk_delta) takes no "
-                "per-row exclusion yet; publish(..., user_seen=...) whole")
+                "delta segment that also excludes is pinned by no warm-up "
+                "yet; publish(..., user_seen=...) whole")
         if seen_appended is not None and (prev is None
                                           or prev.seen is None):
             raise NotImplementedError(
@@ -1722,40 +1673,29 @@ class ServingEngine:
         :meth:`warmup_live` pins (under :meth:`_int8_pin`) and
         :meth:`_dispatch` runs.  ``seen`` (with its history ``pad``):
         the batch excludes, and ``packed`` is the wide layout."""
-        if seen is not None:
-            # (a mesh engine never gets here: ``publish`` and ``submit``
-            # refuse it histories and lists; a segment can arrive between
-            # a request's ``submit`` and its batch)
-            if idx.delta_slots:
-                raise NotImplementedError(
-                    "a batch that excludes, scored by the program with a "
-                    "delta segment: it takes no per-row exclusion yet")
-            return (_serve_int8_seen_packed,
-                    (m.U, idx.Vq, idx.sv, idx.V, idx.valid, seen.runs,
-                     seen.indices, packed),
-                    dict(k=self.k, shortlist_k=idx.shortlist_k, pad=pad))
+        # (a mesh engine never sees histories or lists: ``publish`` and
+        # ``submit`` refuse it them; a segment can arrive between a
+        # request's ``submit`` and its batch)
+        if seen is not None and idx.delta_slots:
+            raise NotImplementedError(
+                "a batch that excludes, scored by the program with a "
+                "delta segment: no warm-up pins that program yet")
         if self.mesh is not None:
-            return (*self._mesh_serve_call(m, idx, packed), {})
-        statics = dict(k=self.k, shortlist_k=idx.shortlist_k)
-        if idx.delta_slots:
-            return (_serve_int8_delta_packed,
-                    (m.U, idx.Vq, idx.sv, idx.V, idx.valid, *idx._seg,
-                     idx._last_id(), packed), statics)
+            k_loc, sk_loc = idx.shard_widths(self.k)
+            return (_build_sharded_int8(
+                self.mesh, self.k, k_loc, sk_loc, idx.ni_loc,
+                bool(idx.delta_slots), _mesh_queries, _pack_response,
+                "serve_mesh_int8"), (m.U, packed, *idx.score_args()), {})
         return (_serve_int8_packed,
-                (m.U, idx.Vq, idx.sv, idx.V, idx.valid, packed), statics)
+                (m.U, idx.Vq, idx.sv, idx.V, idx.valid,
+                 (*idx._seg, idx._last_id()) if idx.delta_slots else (),
+                 () if seen is None else (seen.runs, seen.indices), packed),
+                dict(k=self.k, shortlist_k=idx.shortlist_k, pad=pad))
 
     @staticmethod
     def _int8_pin(idx):
         """The name :meth:`_int8_call`'s program is pinned under."""
         return "int8_delta" if idx.delta_slots else "int8"
-
-    def _mesh_serve_call(self, m, idx, packed):
-        """``(jitted function, arguments)`` of a mesh engine's one int8
-        program for this index as it stands, delta segment or none."""
-        k_loc, sk_loc = idx.shard_widths(self.k)
-        return (_build_mesh_serve(self.mesh, self.k, k_loc, sk_loc,
-                                  idx.ni_loc, bool(idx.delta_slots)),
-                (m.U, packed, *idx.score_args()))
 
     def _exact_call(self, m, packed, seen=None, pad=None):
         """The same of the exact fallback, against the engine's own
@@ -1763,14 +1703,12 @@ class ServingEngine:
         staged batch (the last catalog id is on the device already:
         :meth:`_last_item`)."""
         if self.mesh is None:
-            statics = dict(k=self.k, item_chunk=min(
-                self.item_chunk, max(int(m.V.shape[0]), 1)))
-            if seen is not None:
-                return (_serve_exact_seen_packed,
-                        (m.U, m.V, m.valid, seen.runs, seen.indices,
-                         packed), dict(statics, pad=pad))
-            return (_serve_exact_packed, (m.U, m.V, m.valid, packed),
-                    statics)
+            return (_serve_exact_packed,
+                    (m.U, m.V, m.valid,
+                     () if seen is None else (seen.runs, seen.indices),
+                     packed),
+                    dict(k=self.k, pad=pad, item_chunk=min(
+                        self.item_chunk, max(int(m.V.shape[0]), 1))))
         ni_loc = int(m.V.shape[0]) // int(self.mesh.devices.size)
         return (_build_mesh_exact(self.mesh, self.k, min(self.k, ni_loc),
                                   ni_loc, min(self.item_chunk, ni_loc)),
@@ -2047,7 +1985,7 @@ class ServingEngine:
                 "exclude on "
                 + ("a mesh engine" if self.mesh is not None
                    else "an index with a delta segment")
-                + ": its scoring program takes no per-row exclusion yet")
+                + ": no pinned program of it excludes yet")
         return ids.astype(np.int32)
 
     def recommend(self, payload, k=None, deadline_s=None, timeout=None,

@@ -36,9 +36,10 @@ The shortlist itself is ``ops.topk.shortlist_topk``: what
 element, found in two exact stages (block maxima, then ``top_k`` over
 the winning blocks) wherever the static shapes ``(columns,
 shortlist_k)`` say that pays, and by the single ``top_k`` on every
-small catalog.  All three kernels here (base, delta, per-shard) call
-that one function.  A base index whose shape engages two stages pads
-``Vq`` / ``sv`` / ``valid`` — never ``V`` — with invalid columns to
+small catalog.  The one pipeline here (:func:`shortlist_rescore`: base,
+with a delta segment, per shard) calls it once.  A base index whose
+shape engages two stages pads ``Vq`` / ``sv`` / ``valid`` — never
+``V`` — with invalid columns to
 whole blocks once, at build time (``ops.topk.shortlist_columns``):
 they sort behind every real column, so no answer changes, and no batch
 pays for a ragged last block.
@@ -99,6 +100,7 @@ row write, ``serving/engine.py``).
 
 from __future__ import annotations
 
+import copy
 import functools
 
 import jax
@@ -106,6 +108,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_als.core.ratings import _next_pow2, pad_for, pads_up_to
+from tpu_als.obs.schema import SERVE_MESH_SCOPES
 from tpu_als.ops.topk import (
     NEG_INF,
     NOT_AN_ID,
@@ -114,6 +117,7 @@ from tpu_als.ops.topk import (
     shortlist_plan,
     shortlist_topk,
 )
+from tpu_als.parallel.mesh import AXIS, shard_leading, shard_map
 
 # how far a rescored score may sit from the chunked kernel's, in units in
 # the last place of the row's largest score (module docstring)
@@ -142,7 +146,7 @@ def _quantize_rows(X, pad=0):
 
 
 def mask_block(columns):
-    """The block :func:`_int8_topk` asks ``ops.topk.excluded_mask`` for
+    """The block :func:`shortlist_rescore` asks ``ops.topk.excluded_mask`` for
     over ``columns`` scores: the TPU's 128 lanes where the columns are
     whole blocks of them (every catalog the shortlist takes in two
     stages, which an index pads to whole blocks), else one block of all
@@ -150,99 +154,134 @@ def mask_block(columns):
     return 128 if columns % 128 == 0 else columns
 
 
-@functools.partial(jax.jit, static_argnames=("k", "shortlist_k"))
-def _int8_topk(U, Vq, sv, V, valid, k, shortlist_k, seen=None):
-    """``seen`` (lists of ``int32[n, h]`` ids, padded with
-    ``NOT_AN_ID``): the ids each row is not to be answered with
-    (``ops.topk.excluded_mask`` takes them and states the rule):
-    out of the approximate scores before the shortlist, and out of
-    the rescored candidates before the last ``top_k`` (a candidate list
-    of a row with fewer than ``shortlist_k`` columns left holds some).
-    ``None`` traces what the function traced before it took the
-    argument."""
-    n = U.shape[0]
+def shortlist_rescore(U, Vq, sv, V, valid, *, k, shortlist_k, delta=None,
+                      last_id=None, seen=None, shard=None):
+    """THE scoring pipeline, traced into its caller's program: ``U``
+    quantized per row, the int8 GEMM against ``Vq`` (int8 x int8 ->
+    int32 on the MXU) rescaled to approximate f32 scores, what may not
+    be answered masked to ``NEG_INF``, ``ops.topk.shortlist_topk``, the
+    candidates' f32 rows gathered and rescored exactly with the chunked
+    kernel's own contraction shape (full ``U`` batch x gathered catalog
+    columns: module docstring), the last ``top_k``.  Returns the top
+    ``k`` ``(scores [n, k], logical catalog ids [n, k])``.  What is
+    passed beside the base arrays decides, at trace time, what else it
+    does; ``None`` traces nothing for it:
+
+    ``delta`` — the segment's five arrays ``(drows, dVq, dsv, dV,
+    dvalid)``: a second int8 GEMM over its slots, whose scores join the
+    shortlist as its ``tail`` (what a shortlist over the two
+    concatenated returns, without a second pass over the matrix), base
+    columns the segment overrides masked (whatever ``dvalid`` says: a
+    slot may mark an item invalid), and the SAME-shaped rescore (why this
+    stays bitwise: module docstring).  ``drows`` maps slots to logical
+    ids; free slots carry :data:`SLOT_FREE` (out of every scatter's
+    range, ``dvalid`` False).  An appended id may fall on a spare or
+    block-padding column of ``Vq``: invalid already, so marking it
+    overridden changes nothing.  ``last_id`` clamps the ids into the
+    logical catalog (a sharded caller clamps after its merge instead).
+
+    ``seen`` — lists of ``int32[n, h]`` ids padded with ``NOT_AN_ID``,
+    the ids each row is not to be answered with
+    (``ops.topk.excluded_mask`` takes them and states the rule): out of
+    the approximate scores before the shortlist, and out of the rescored
+    candidates before the last ``top_k`` (a candidate list of a row with
+    fewer than ``shortlist_k`` columns left holds some).
+
+    ``shard`` — ``(me, ni_loc)`` inside ``shard_map``: the base arrays
+    are this shard's slice, catalog ids ``[me * ni_loc, (me + 1) *
+    ni_loc)``, which the returned ids are offset by; the (replicated)
+    segment is scored by every shard but masked to the slots it OWNS, so
+    each is scored exactly once mesh-wide.  No shard sees another's
+    rows."""
+    if seen is not None and shard is not None:
+        raise NotImplementedError(
+            "seen on a shard: the lists hold catalog ids, the mask a "
+            "shard's own columns")
+    n, nb = U.shape[0], Vq.shape[0]
+    # the catalog id of this shard's first row (without a shard nothing
+    # is traced for it, no ``+ 0``)
+    first = None if shard is None else shard[0] * shard[1]
     Uq, su = _quantize_rows(U)
-    # int8 x int8 -> int32 on the MXU; rescale to approximate f32 scores
     acc = jnp.einsum("nr,cr->nc", Uq, Vq,
                      preferred_element_type=jnp.int32)
     approx = acc.astype(jnp.float32) * su[:, None] * sv[None, :]
-    ok = valid[None, :]
+    base_ok, approx_d = valid, None
+    if delta:
+        drows, dVq, dsv, dV, dvalid = delta
+        d = dVq.shape[0]
+        at, dmask = drows, dvalid       # the slots' columns of the base
+        if shard is not None:
+            at = drows - first
+            owned = (at >= 0) & (at < nb)
+            at, dmask = jnp.where(owned, at, nb), dvalid & owned
+        # a base row the segment overrides (or an id out of this base's
+        # range, dropped) must never shortlist from its stale value
+        over = jnp.zeros((nb,), jnp.bool_).at[at].set(True, mode="drop")
+        base_ok = valid & ~over
+    ok = base_ok[None, :]
     if seen is not None:
         # block-major: transposed it is the row-major mask's own bytes
-        cols = Vq.shape[0]
-        excluded = excluded_mask(seen, cols, mask_block(cols)).transpose(
-            1, 0, 2).reshape(n, cols)
+        excluded = excluded_mask(seen, nb, mask_block(nb)).transpose(
+            1, 0, 2).reshape(n, nb)
         ok = ok & ~excluded
     approx = jnp.where(ok, approx, NEG_INF)
-    _, cand = shortlist_topk(approx, shortlist_k)      # [n, sk]
-    # exact f32 rescore with the chunked kernel's own contraction shape:
-    # full U batch x gathered catalog columns (see module docstring)
-    Vc = jnp.take(V, cand.reshape(-1), axis=0)         # [n*sk, r]
+    if delta:
+        acc_d = jnp.einsum("nr,cr->nc", Uq, dVq,
+                           preferred_element_type=jnp.int32)
+        approx_d = acc_d.astype(jnp.float32) * su[:, None] * dsv[None, :]
+        ok_d = dmask[None, :]
+        if seen is not None:
+            # a slot's logical id against the lists themselves: it may
+            # lie past the base's columns, where the mask ends
+            excluded_d = (jnp.concatenate(seen, axis=1)[:, :, None]
+                          == drows[None, None, :]).any(axis=1)
+            ok_d = ok_d & ~excluded_d
+        approx_d = jnp.where(ok_d, approx_d, NEG_INF)
+    # with a segment: positions in nb + d
+    _, cand = shortlist_topk(approx, shortlist_k, tail=approx_d)
+    flat = cand.reshape(-1)
+    if delta:
+        in_base = flat < nb
+        base_ix = jnp.minimum(flat, nb - 1)
+        delta_ix = jnp.clip(flat - nb, 0, d - 1)
+        Vc = jnp.where(in_base[:, None], jnp.take(V, base_ix, axis=0),
+                       jnp.take(dV, delta_ix, axis=0))  # [n*sk, r]
+    else:
+        Vc = jnp.take(V, flat, axis=0)
     exact_all = jnp.einsum("nr,cr->nc", U, Vc,
                            preferred_element_type=jnp.float32)
     rows = (jnp.arange(n, dtype=jnp.int32)[:, None] * shortlist_k
             + jnp.arange(shortlist_k, dtype=jnp.int32)[None, :])
     exact = jnp.take_along_axis(exact_all, rows, axis=1)
-    cand_ok = jnp.take(valid, cand)
+    logical = flat if shard is None else flat + first
+    if delta:
+        cand_ok = jnp.where(in_base, jnp.take(base_ok, base_ix),
+                            jnp.take(dmask, delta_ix))
+        logical = jnp.where(in_base, logical, jnp.take(drows, delta_ix))
+        cand_ok = cand_ok.reshape(n, shortlist_k)
+    else:
+        # (a shard gathers over the flat list, a whole index over ``[n,
+        # shortlist_k]``: the forms the two compiled from before they
+        # were one function, kept so that both compile to what the chip
+        # was measured running)
+        cand_ok = (jnp.take(base_ok, cand) if shard is None
+                   else jnp.take(base_ok, flat).reshape(n, shortlist_k))
     if seen is not None:
-        cand_ok &= ~jnp.take_along_axis(excluded, cand, axis=1)
+        gone = jnp.take_along_axis(excluded, cand, axis=1)
+        if delta:
+            gone = jnp.where(cand < nb, gone, jnp.take_along_axis(
+                excluded_d, jnp.clip(cand - nb, 0, d - 1), axis=1))
+        cand_ok &= ~gone
     exact = jnp.where(cand_ok, exact, NEG_INF)
     s, sel = jax.lax.top_k(exact, k)
-    return s, jnp.take_along_axis(cand, sel, axis=1)
+    if last_id is not None:
+        logical = jnp.minimum(logical, last_id)
+    return s, jnp.take_along_axis(logical.reshape(n, shortlist_k), sel,
+                                  axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "shortlist_k"))
-def _int8_topk_delta(U, Vq, sv, V, valid, drows, dVq, dsv, dV, dvalid,
-                     last_id, k, shortlist_k):
-    """The base kernel with a delta segment: two int8 GEMMs (base +
-    segment), overridden base columns masked, one shortlist over the
-    base's approx scores with the segment's as its ``tail`` (what a
-    shortlist over the two concatenated returns, without the second pass
-    over the matrix that the concatenation cost: ``ops.topk.
-    shortlist_topk``), and the SAME-shaped exact rescore as
-    the base path (see module docstring for why this stays bitwise).
-
-    ``drows`` maps segment slots to logical catalog ids; free slots
-    carry :data:`SLOT_FREE` (out of every scatter's range, ``dvalid``
-    False).  An appended id may fall on a spare or block-padding column
-    of ``Vq``: invalid already, so marking it overridden changes nothing.
-    ``last_id`` clamps returned ids into the logical catalog.
-    """
-    n = U.shape[0]
-    nb = Vq.shape[0]
-    d = dVq.shape[0]
-    Uq, su = _quantize_rows(U)
-    acc = jnp.einsum("nr,cr->nc", Uq, Vq,
-                     preferred_element_type=jnp.int32)
-    approx_b = acc.astype(jnp.float32) * su[:, None] * sv[None, :]
-    # a base row the segment overrides (or an appended id, out of base
-    # range and dropped) must never shortlist from its stale value
-    over = jnp.zeros((nb,), jnp.bool_).at[drows].set(True, mode="drop")
-    approx_b = jnp.where((valid & ~over)[None, :], approx_b, NEG_INF)
-    acc_d = jnp.einsum("nr,cr->nc", Uq, dVq,
-                       preferred_element_type=jnp.int32)
-    approx_d = acc_d.astype(jnp.float32) * su[:, None] * dsv[None, :]
-    approx_d = jnp.where(dvalid[None, :], approx_d, NEG_INF)
-    # positions in nb + d
-    _, cand = shortlist_topk(approx_b, shortlist_k, tail=approx_d)
-    flat = cand.reshape(-1)
-    in_base = flat < nb
-    base_ix = jnp.minimum(flat, nb - 1)
-    delta_ix = jnp.clip(flat - nb, 0, d - 1)
-    Vc = jnp.where(in_base[:, None], jnp.take(V, base_ix, axis=0),
-                   jnp.take(dV, delta_ix, axis=0))  # [n*sk, r]
-    exact_all = jnp.einsum("nr,cr->nc", U, Vc,
-                           preferred_element_type=jnp.float32)
-    rows = (jnp.arange(n, dtype=jnp.int32)[:, None] * shortlist_k
-            + jnp.arange(shortlist_k, dtype=jnp.int32)[None, :])
-    exact = jnp.take_along_axis(exact_all, rows, axis=1)
-    cand_ok = jnp.where(in_base, jnp.take(valid & ~over, base_ix),
-                        jnp.take(dvalid, delta_ix))
-    exact = jnp.where(cand_ok.reshape(n, shortlist_k), exact, NEG_INF)
-    s, sel = jax.lax.top_k(exact, k)
-    logical = jnp.where(in_base, flat, jnp.take(drows, delta_ix))
-    logical = jnp.minimum(logical, last_id).reshape(n, shortlist_k)
-    return s, jnp.take_along_axis(logical, sel, axis=1)
+# what the indexes' own ``topk`` run: the pipeline as a program by itself
+_topk_jit = jax.jit(shortlist_rescore, static_argnames=("k", "shortlist_k"))
 
 
 @jax.jit
@@ -361,20 +400,10 @@ class Int8CandidateIndex:
         return 0 if self._seg is None else int(self._seg[0].shape[0])
 
     def _copy_shell(self, seq):
-        new = object.__new__(type(self))
-        new.V, new.valid = self.V, self.valid
-        new.Vq, new.sv = self.Vq, self.sv
-        new.n_items = self.n_items
-        new.shortlist_k = self.shortlist_k
+        new = copy.copy(self)       # shallow: every array shared
         new.seq = self.seq if seq is None else int(seq)
-        new.d_rows, new._seg, new._last = self.d_rows, self._seg, self._last
         new.written = None
-        self._copy_extra(new)
         return new
-
-    def _copy_extra(self, new):
-        """Subclass hook: carry extra attributes through shell copies
-        (the sharded index's mesh placement state)."""
 
     def retag(self, seq):
         """A shallow copy sharing every array, tagged for a new publish.
@@ -441,6 +470,30 @@ class Int8CandidateIndex:
         rows = np.unique(np.asarray(rows, dtype=np.int64).ravel())
         return int(rows.size - self._held(rows)[0].sum())
 
+    def _checked_update(self, rows, V_rows, valid_rows):
+        """``(ids ascending, none twice; their rows; their valid bits;
+        the catalog's size afterwards)`` of an update to ``rows`` (at
+        least one), or ``ValueError``: a negative id, or appended ids
+        that leave a hole above the catalog."""
+        V_rows = np.asarray(V_rows, dtype=np.float32).reshape(
+            len(rows), int(self.V.shape[1]))
+        valid_rows = (np.ones(len(rows), dtype=bool) if valid_rows is None
+                      else np.asarray(valid_rows, dtype=bool).ravel())
+        if rows.min() < 0:
+            raise ValueError("negative catalog row id in delta update")
+        # newest-wins dedup inside the call: keep each id's LAST row
+        uniq, first_rev = np.unique(rows[::-1], return_index=True)
+        last = len(rows) - 1 - first_rev
+        n_new = int(max(self.n_items, int(uniq[-1]) + 1))
+        appended = uniq[uniq >= self.n_items]
+        if len(appended) != n_new - self.n_items:
+            gap = sorted(set(range(self.n_items, n_new))
+                         - set(appended.tolist()))
+            raise ValueError(
+                f"append gap: ids {gap} missing — appended rows must "
+                "be contiguous above the current catalog")
+        return uniq, V_rows[last], valid_rows[last], n_new
+
     def with_updates(self, rows, V_rows, valid_rows=None, seq=None):
         """A new index with ``rows`` of the catalog re-quantized into
         the delta segment — O(len(rows)) upload and quantization work,
@@ -458,26 +511,11 @@ class Int8CandidateIndex:
         before the segment overflows, as the engine does).
         """
         rows = np.asarray(rows, dtype=np.int64).ravel()
-        r = int(self.V.shape[1])
-        V_rows = np.asarray(V_rows, dtype=np.float32).reshape(len(rows), r)
-        valid_rows = (np.ones(len(rows), dtype=bool) if valid_rows is None
-                      else np.asarray(valid_rows, dtype=bool).ravel())
         if len(rows) == 0:
             return self._copy_shell(seq)
-        if rows.min() < 0:
-            raise ValueError("negative catalog row id in delta update")
-        # newest-wins dedup inside the call: keep each id's LAST row
-        uniq, first_rev = np.unique(rows[::-1], return_index=True)
-        last = len(rows) - 1 - first_rev
-        rows, V_rows, valid_rows = uniq, V_rows[last], valid_rows[last]
-        n_new = int(max(self.n_items, int(rows.max()) + 1))
-        appended = rows[rows >= self.n_items]
-        if len(appended) != n_new - self.n_items:
-            gap = sorted(set(range(self.n_items, n_new))
-                         - set(appended.tolist()))
-            raise ValueError(
-                f"append gap: ids {gap} missing — appended rows must "
-                "be contiguous above the current catalog")
+        rows, V_rows, valid_rows, n_new = self._checked_update(
+            rows, V_rows, valid_rows)
+        r = int(self.V.shape[1])
         # a row the segment holds keeps its slot, the others take the
         # next free ones
         held, slots = self._held(rows)
@@ -612,13 +650,12 @@ class Int8CandidateIndex:
             raise ValueError(
                 f"k={k} exceeds shortlist_k={sk}; the shortlist must "
                 "contain at least k candidates")
-        U = jnp.asarray(U, dtype=jnp.float32)
-        if not self.delta_slots:
-            return _int8_topk(U, self.Vq, self.sv, self.V, self.valid,
-                              k=int(k), shortlist_k=sk)
-        return _int8_topk_delta(
-            U, self.Vq, self.sv, self.V, self.valid, *self._seg,
-            self._last_id(), k=int(k), shortlist_k=sk)
+        return self._topk(jnp.asarray(U, dtype=jnp.float32), int(k), sk)
+
+    def _topk(self, U, k, sk):
+        return _topk_jit(
+            U, self.Vq, self.sv, self.V, self.valid, k=k, shortlist_k=sk,
+            delta=self._seg, last_id=self._last_id() if self._seg else None)
 
 
 def build_index(V, item_valid=None, shortlist_k=64, seq=0):
@@ -630,72 +667,6 @@ def build_index(V, item_valid=None, shortlist_k=64, seq=0):
     """
     return Int8CandidateIndex(V, item_valid=item_valid,
                               shortlist_k=shortlist_k, seq=seq)
-
-
-def _shard_score(U, Vq, sv, V, valid, delta, *, me, k_loc, sk_loc, ni_loc):
-    """One shard's part of a sharded query, inside ``shard_map``: the
-    SAME shortlist→rescore pipeline as :func:`_int8_topk` /
-    :func:`_int8_topk_delta` over this shard's catalog slice only — no
-    shard ever sees another's rows.  The (tiny, replicated) ``delta``
-    segment — ``()`` without one — is scored by every shard but masked
-    to the rows it OWNS (``row // ni_loc == me``), so each delta row is
-    scored exactly once mesh-wide.  Returns the local top-``k_loc``
-    ``(scores [n, k_loc], catalog ids [n, k_loc])``."""
-    n = U.shape[0]
-    Uq, su = _quantize_rows(U)
-    acc = jnp.einsum("nr,cr->nc", Uq, Vq,
-                     preferred_element_type=jnp.int32)
-    approx = acc.astype(jnp.float32) * su[:, None] * sv[None, :]
-    if delta:
-        drows, dVq, dsv, dV, dvalid = delta
-        d = dVq.shape[0]
-        idx = drows - me * ni_loc          # local slot, if owned
-        owned = (idx >= 0) & (idx < ni_loc)
-        # overridden base rows mask regardless of dvalid (a delta
-        # row may mark an item invalid); ni_loc is the OOB sentinel
-        over = jnp.zeros((ni_loc,), jnp.bool_).at[
-            jnp.where(owned, idx, ni_loc)].set(True, mode="drop")
-        base_ok = valid & ~over
-        approx = jnp.where(base_ok[None, :], approx, NEG_INF)
-        dmask = dvalid & owned
-        acc_d = jnp.einsum("nr,cr->nc", Uq, dVq,
-                           preferred_element_type=jnp.int32)
-        approx_d = (acc_d.astype(jnp.float32)
-                    * su[:, None] * dsv[None, :])
-        approx_d = jnp.where(dmask[None, :], approx_d, NEG_INF)
-    else:
-        base_ok = valid
-        approx = jnp.where(base_ok[None, :], approx, NEG_INF)
-        approx_d = None
-    # with a segment: positions in ni_loc + d
-    _, cand = shortlist_topk(approx, sk_loc, tail=approx_d)
-    flat = cand.reshape(-1)
-    if delta:
-        in_base = flat < ni_loc
-        base_ix = jnp.minimum(flat, ni_loc - 1)
-        delta_ix = jnp.clip(flat - ni_loc, 0, d - 1)
-        Vc = jnp.where(in_base[:, None],
-                       jnp.take(V, base_ix, axis=0),
-                       jnp.take(dV, delta_ix, axis=0))
-    else:
-        Vc = jnp.take(V, flat, axis=0)
-    exact_all = jnp.einsum("nr,cr->nc", U, Vc,
-                           preferred_element_type=jnp.float32)
-    pos = (jnp.arange(n, dtype=jnp.int32)[:, None] * sk_loc
-           + jnp.arange(sk_loc, dtype=jnp.int32)[None, :])
-    exact = jnp.take_along_axis(exact_all, pos, axis=1)
-    if delta:
-        cand_ok = jnp.where(in_base, jnp.take(base_ok, base_ix),
-                            jnp.take(dmask, delta_ix))
-        gid = jnp.where(in_base, flat + me * ni_loc,
-                        jnp.take(drows, delta_ix))
-    else:
-        cand_ok = jnp.take(base_ok, flat)
-        gid = flat + me * ni_loc
-    exact = jnp.where(cand_ok.reshape(n, sk_loc), exact, NEG_INF)
-    s, sel = jax.lax.top_k(exact, k_loc)
-    gids = jnp.take_along_axis(gid.reshape(n, sk_loc), sel, axis=1)
-    return s, gids.astype(jnp.int32)
 
 
 def _shard_merge(s, gids, last_id, *, axis, k):
@@ -744,28 +715,42 @@ def mesh_spread_bytes(n_shards, rows, rank):
 
 
 @functools.lru_cache(maxsize=32)
-def _build_sharded_int8(mesh, k, k_loc, sk_loc, ni_loc, has_delta):
-    """shard_map'd int8 shortlist + exact rescore for a batch of query
-    VECTORS: :func:`_shard_score` per shard, :func:`_shard_merge` on
-    every shard, one program.  (The engine's whole request path, from
-    the staged batch to the packed response, is the same two functions
-    behind a by-id lookup: ``serving.engine._build_mesh_serve``.)"""
-    from tpu_als.parallel.mesh import AXIS, shard_map
-
+def _build_sharded_int8(mesh, k, k_loc, sk_loc, ni_loc, has_delta,
+                        lookup=None, pack=None, name="sharded_int8_topk"):
+    """THE sharded scoring program, ``shard_map``'d and jitted under
+    ``name``: per shard :func:`shortlist_rescore` over its slice of the
+    catalog, :func:`_shard_merge` on every shard.  As
+    :meth:`ShardedInt8Index.topk` builds it, it takes a batch of query
+    VECTORS, replicated, and returns ``(scores, ids)``.  A serving
+    engine's whole request path is the same program with ``lookup(U,
+    packed, me=, axis=)`` in front — the queries from ITS first two
+    arguments, a user table and a staged batch, both sharded by rows —
+    and ``pack(scores, ids)`` behind, one replicated result.  The three
+    steps lie in the scopes ``obs.schema.SERVE_MESH_SCOPES``."""
     P = jax.sharding.PartitionSpec
+    head = (P(),) if lookup is None else (P(AXIS), P(AXIS))
 
-    def sharded_int8_topk(U, Vq, sv, V, valid, last_id, *delta):
-        s, gids = _shard_score(
-            U, Vq, sv, V, valid, delta, me=jax.lax.axis_index(AXIS),
-            k_loc=k_loc, sk_loc=sk_loc, ni_loc=ni_loc)
-        return _shard_merge(s, gids, last_id, axis=AXIS, k=k)
+    def program(*args):
+        queries, (Vq, sv, V, valid, last_id, *delta) = (
+            args[:len(head)], args[len(head):])
+        me = jax.lax.axis_index(AXIS)
+        with jax.named_scope(SERVE_MESH_SCOPES[0]):
+            U = (queries[0] if lookup is None
+                 else lookup(*queries, me=me, axis=AXIS))
+        with jax.named_scope(SERVE_MESH_SCOPES[1]):
+            s, gids = shortlist_rescore(
+                U, Vq, sv, V, valid, k=k_loc, shortlist_k=sk_loc,
+                delta=delta, shard=(me, ni_loc))
+        with jax.named_scope(SERVE_MESH_SCOPES[2]):
+            out = _shard_merge(s, gids, last_id, axis=AXIS, k=k)
+            return out if pack is None else pack(*out)
 
-    delta_specs = (P(),) * 5 if has_delta else ()
+    program.__name__ = name     # the program's name on a device trace
     return jax.jit(shard_map(
-        sharded_int8_topk, mesh=mesh,
-        in_specs=(P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P())
-        + delta_specs,
-        out_specs=(P(), P()), check_vma=False))
+        program, mesh=mesh,
+        in_specs=head + (P(AXIS), P(AXIS), P(AXIS), P(AXIS), P())
+        + (P(),) * (5 if has_delta else 0),
+        out_specs=(P(), P()) if pack is None else P(), check_vma=False))
 
 
 def place_catalog(V, item_valid, mesh, shortlist_k=64):
@@ -779,8 +764,6 @@ def place_catalog(V, item_valid, mesh, shortlist_k=64):
     of the catalog and no device holds more than its shard and one
     chunk."""
     from tpu_als.core.foldin import place_rows
-    from tpu_als.parallel.mesh import shard_leading
-
     V = np.asarray(V, dtype=np.float32)
     Ni, D = int(V.shape[0]), int(mesh.devices.size)
     if Ni == 0:
@@ -856,11 +839,6 @@ class ShardedInt8Index(Int8CandidateIndex):
                               min(self.shortlist_k, self.ni_loc + d), rows,
                               tail=d)
 
-    def _copy_extra(self, new):
-        new.mesh = self.mesh
-        new.n_shards = self.n_shards
-        new.ni_loc = self.ni_loc
-
     @property
     def capacity(self):
         """Catalog ids the sharded base can hold without re-striding."""
@@ -882,26 +860,13 @@ class ShardedInt8Index(Int8CandidateIndex):
         so there is no incremental path — rebuild the sharded base at
         the grown size (O(catalog), the rare capacity-crossing publish;
         within capacity :meth:`with_updates` stays O(touched))."""
-        if rows.min() < 0:
-            raise ValueError("negative catalog row id in delta update")
-        r = int(self.V.shape[1])
-        V_rows = np.asarray(V_rows, dtype=np.float32).reshape(len(rows), r)
-        valid_rows = (np.ones(len(rows), dtype=bool) if valid_rows is None
-                      else np.asarray(valid_rows, dtype=bool).ravel())
+        rows, V_rows, valid_rows, n_new = self._checked_update(
+            rows, V_rows, valid_rows)
         base = self.compact() if self.d_rows.size else self
-        n_new = int(max(self.n_items, int(rows.max()) + 1))
-        missing = sorted(set(range(self.n_items, n_new))
-                         - set(rows[rows >= self.n_items].tolist()))
-        if missing:
-            raise ValueError(
-                f"append gap: ids {missing} missing — appended rows "
-                "must be contiguous above the current catalog")
-        V_full = np.zeros((n_new, r), dtype=np.float32)
+        V_full = np.zeros((n_new, int(self.V.shape[1])), dtype=np.float32)
         V_full[:self.n_items] = np.asarray(base.V)[:self.n_items]
         valid_full = np.zeros(n_new, dtype=bool)
         valid_full[:self.n_items] = np.asarray(base.valid)[:self.n_items]
-        # numpy fancy assignment keeps the LAST duplicate: newest wins,
-        # matching the base class's in-call dedup
         V_full[rows] = V_rows
         valid_full[rows] = valid_rows
         return type(self)(V_full, self.mesh, item_valid=valid_full,
@@ -912,27 +877,18 @@ class ShardedInt8Index(Int8CandidateIndex):
         """The base class's scatter into a COPY of the sharded base
         arrays (nothing donated: the index stays whole), re-placed
         shard-leading so residency survives the scatter."""
-        from tpu_als.parallel.mesh import shard_leading
-
         spec = shard_leading(self.mesh)
         out = _fold_segment_copied(self.V, self.Vq, self.sv, self.valid,
                                    drows, *self._seg[1:])
         return (*(jax.device_put(a, spec) for a in out[:4]), *out[4:])
 
-    def topk(self, U, k, shortlist_k=None):
-        """Top-k of ``U @ V.T`` scored shard-resident (see class
-        docstring); per-query device traffic is ``S * k_loc`` merged
-        candidates, never a per-shard list."""
-        sk = self.shortlist_k if shortlist_k is None else \
-            min(int(shortlist_k), self.n_items)
-        if k > sk:
-            raise ValueError(
-                f"k={k} exceeds shortlist_k={sk}; the shortlist must "
-                "contain at least k candidates")
-        U = jnp.asarray(U, dtype=jnp.float32)
+    def _topk(self, U, k, sk):
+        """Scored shard-resident (see class docstring); per-query device
+        traffic is ``S * k_loc`` merged candidates, never a per-shard
+        list."""
         k_loc, sk_loc = self.shard_widths(k, sk)
-        fn = _build_sharded_int8(self.mesh, int(k), k_loc, sk_loc,
-                                 self.ni_loc, bool(self.delta_slots))
+        fn = _build_sharded_int8(self.mesh, k, k_loc, sk_loc, self.ni_loc,
+                                 bool(self.delta_slots))
         return fn(U, *self.score_args())
 
     def shard_widths(self, k, shortlist_k=None):

@@ -638,6 +638,44 @@ def test_the_program_that_excludes_from_histories_that_grow(one_chip, bucket):
     assert extra < 16 * bucket * 8192 + (8 << 20), extra
 
 
+@pytest.mark.parametrize("pad", (64, 512, 4096, 8192))
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_the_program_that_excludes_and_scores_a_segment(one_chip, bucket,
+                                                        pad):
+    """``_serve_int8_packed`` given the segment AND histories that grow,
+    at the ``serve-foldin-all`` cell's shapes (spare catalog rows, 512
+    slots, the grown ladder's four pads): what ``warmup_live`` pins under
+    ``(bucket, "int8_delta", pad)`` since PR 47.  It compiles; beyond the
+    program with the segment alone it holds the one-byte mask and no
+    ``[B, pad + 64, 512]`` compare of the lists against the slots (541 MB
+    at bucket 128 and pad 8,192, were it written out); its one sort more
+    is the mask's own.  Temporaries on the described v5e (PERF.md section
+    5): 1.2-2.6 MB at bucket 8, 197 MB at 32, 983 MB at 128, whatever the
+    pad."""
+    from tpu_als.core.ratings import row_capacity
+    from tpu_als.serving.engine import MAX_EXCLUDE
+
+    cap, cols, base, seg = _live_catalog_shapes()
+    users = ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32)
+    delta = (*seg, ((), jnp.int32))
+    plain = _serve_int8(one_chip, users, base,
+                        ((bucket, LIVE_RANK + 2), jnp.int32),
+                        delta=delta).compile()
+    _, runs, ids, packed = _seen_shapes(bucket, grown=True)
+    assert packed == ((bucket, LIVE_RANK + 2 + MAX_EXCLUDE), jnp.int32)
+    c = _serve_int8(one_chip, users, base, packed, delta=delta,
+                    histories=(runs, ids), pad=pad).compile()
+    text = c.as_text()
+    extra = (c.memory_analysis().temp_size_in_bytes
+             - plain.memory_analysis().temp_size_in_bytes)
+    assert extra < 1.05 * bucket * cols + (8 << 20), extra
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert text.count(" sort(") == plain.as_text().count(" sort(") + 1
+    assert not re.search(rf"pred\[{bucket},{pad + MAX_EXCLUDE},{LIVE_SLOTS}\]",
+                         text[text.index("\nENTRY "):])
+    assert 'op_name="jit(_serve_int8_packed)/serve.exclude/' in text
+
+
 @pytest.mark.parametrize("rows,width", [(8, 4096), (8, 8192), (64, 8192)])
 def test_fold_in_over_whole_histories_at_rank_256(one_chip, rows, width):
     """The fold-in program at the widths a resident base history brings, up

@@ -44,18 +44,29 @@ def fold(F, ids, ratings):
     return np.linalg.solve(A, Fe.T @ np.asarray(ratings, np.float64))
 
 
-def replay(U0, V0, batches, fold_items=True):
+def replay(U0, V0, batches, fold_items=True, published=None):
     """The rule, plainly: ``(U, V)`` as ``{id: float64 row}`` after the
     batches, and the ratings that entered a fold for the first time.  In a
     batch the users fold first, against the catalog as the batch before
     left it, then the items, against the user factors as this batch's
     user fold left them; a fold of an entity is over ALL its ratings so
     far whose other side has a factor now; an entity with none gets no
-    factor and its ratings wait."""
+    factor and its ratings wait.
+
+    ``published``: per batch ``({user: row}, {item: row})``, what the
+    program published.  Each fold is then made from the rows the PROGRAM
+    had, its row held to it, and the state goes on from the program's row
+    (``benchmark/reference/foldin_replay.py`` says why: folds chain, and
+    where an item's raters rated nothing else their rows lie along the
+    item's own, so its next fold carries a float32 rounding of theirs a
+    thousandfold).  Returns besides: the relative error of every fold,
+    and the (batch, side, entity) the rule folds without a published row
+    or the other way round."""
     U = {u: np.asarray(x, np.float64) for u, x in enumerate(U0)}
     V = {i: np.asarray(x, np.float64) for i, x in enumerate(V0)}
     hist_u, hist_i, used, entered = {}, {}, {}, 0
-    for batch in batches:
+    errs, off = [], []
+    for b, batch in enumerate(batches):
         sides = [(U, V, hist_u, 0, 1)]
         if fold_items:
             sides.append((V, U, hist_i, 1, 0))
@@ -69,8 +80,17 @@ def replay(U0, V0, batches, fold_items=True):
                 used[(me, e)] = len(ok)
                 if ok:
                     moved[e] = fold(fixed, *zip(*ok))
+            if published is not None:
+                theirs = published[b][me]
+                off += [(b, me, e) for e in set(moved) ^ set(theirs)]
+                for e in set(moved) & set(theirs):
+                    x = np.asarray(theirs[e], np.float64)
+                    errs.append(float(np.linalg.norm(x - moved[e])
+                                      / np.linalg.norm(moved[e])))
+                    moved[e] = x
             solved.update(moved)
-    return U, V, entered
+    return (U, V, entered) if published is None else (U, V, entered, errs,
+                                                      off)
 
 
 def exact_topk(q, V, n_items, k=K):
@@ -136,6 +156,23 @@ def run_stream(seed, fold_items, n=400):
     rng, U, V, model, eng, srv, upd = make_stack(seed, fold_items)
     events = seeded_events(rng, n)
     compiles = CompileCount()
+    # the rows every publish carried, by original id: what the replay
+    # follows, fold by fold
+    published, publish = [], eng.publish_update
+
+    def tapped(U, V, *, touched_items=None, touched_users=None, **kw):
+        out = publish(U, V, touched_items=touched_items,
+                      touched_users=touched_users, **kw)
+        published.append(tuple(
+            dict(zip(ids.to_original(np.asarray(rows, np.int64)).tolist(),
+                     np.array(table[np.asarray(rows, np.int64)])))
+            for ids, rows, table in (
+                (model._user_map, touched_users, U),
+                (model._item_map, () if touched_items is None
+                 else touched_items, V))))
+        return out
+
+    eng.publish_update = tapped
     eng.start()
     upd.start()             # warmup_publish, and warmup_live for items
     warm = compiles.n
@@ -147,7 +184,8 @@ def run_stream(seed, fold_items, n=400):
     finally:
         upd.stop(drain_timeout_s=30.0)
     return dict(reg=reg, rng=rng, U0=U, V0=V, model=model, eng=eng, srv=srv,
-                upd=upd, events=events, compiled=compiles.n - warm)
+                upd=upd, events=events, compiled=compiles.n - warm,
+                published=published)
 
 
 @pytest.fixture(scope="module", params=[True, False],
@@ -155,9 +193,9 @@ def run_stream(seed, fold_items, n=400):
 def streamed(request):
     out = run_stream(seed=0, fold_items=request.param)
     out["fold_items"] = request.param
-    out["replay"] = replay(out["U0"], out["V0"],
-                           batches_of(out["upd"], out["events"]),
-                           fold_items=request.param)
+    *out["replay"], out["fold_errs"], out["off"] = replay(
+        out["U0"], out["V0"], batches_of(out["upd"], out["events"]),
+        fold_items=request.param, published=out["published"])
     yield out
     out["eng"].stop()
 
@@ -168,16 +206,25 @@ def streamed(request):
 def test_the_models_factors_are_the_replays(streamed):
     m, (U, V, _) = streamed["model"], streamed["replay"]
     assert len(m._user_map) == len(U) and len(m._item_map) == len(V)
-    # folds chain (an item's row feeds its users' next folds, and back),
-    # each in float32: looser than one fold's 2e-4
+    # every fold the rule asks for was published and none besides, each
+    # within one float32 fold of the float64 fold of the SAME inputs (the
+    # replay goes on from the program's row: a trajectory replayed from
+    # the seeded factors alone is held to what the chain of folds carries,
+    # which depends on where the clock cut the batches — one run in three
+    # of a loaded machine left one element of 15,008 at 1.7 times the
+    # tolerance that form of this test had: PR 46's known flake)
+    assert streamed["off"] == []
+    assert len(streamed["fold_errs"]) > 300
+    assert max(streamed["fold_errs"]) < 2e-4
+    # ... so the tables are the last published rows, bit for bit
     users = sorted(U)
-    np.testing.assert_allclose(
-        m._U[m._user_map.to_dense(users)], [U[u] for u in users],
-        rtol=2e-3, atol=5e-4)
+    np.testing.assert_array_equal(
+        m._U[m._user_map.to_dense(users)],
+        np.array([U[u] for u in users], np.float32))
     items = sorted(V)
-    np.testing.assert_allclose(
-        m._V[m._item_map.to_dense(items)], [V[i] for i in items],
-        rtol=2e-3, atol=5e-4)
+    np.testing.assert_array_equal(
+        m._V[m._item_map.to_dense(items)],
+        np.array([V[i] for i in items], np.float32))
     if not streamed["fold_items"]:
         # today's user-only behaviour: the catalog is not touched
         assert len(V) == N_ITEMS

@@ -356,16 +356,29 @@ def test_the_row_and_the_id_are_swapped_in_together():
 # -- what is still refused, and the timeline ------------------------------------
 
 def test_what_histories_still_refuse_says_so():
+    """Since PR 47 a generation that holds histories takes a catalog that
+    moves — a row named, a row appended, an updater that folds items
+    (``tests/test_live_items_unseen.py`` holds the answers to the
+    reference) — and what is left refused is an id for a generation that
+    holds no history, and an id outside the catalog."""
     rng, V, hist, model, eng, srv, upd = make_stack(seed=2)
     Uh = model._U
-    with pytest.raises(NotImplementedError, match="delta segment that also excludes"):
-        eng.publish_update(Uh, V, touched_items=[0], touched_users=[0])
-    with pytest.raises(NotImplementedError, match="delta segment that also excludes"):
-        eng.publish_update(Uh, np.concatenate([V, V[:1]]), touched_users=[0])
-    items = LiveUpdater(eng, srv, fold_items=True)
-    with pytest.raises(NotImplementedError, match="fold_items"):
-        items.start()
-    assert eng.published_seq == 1           # nothing was written
+    assert eng.publish_update(Uh, V, touched_items=[0],
+                              touched_users=[0]) == (2, "delta")
+    longer = np.concatenate([V, V[:1]])
+    assert eng.publish_update(Uh, longer, touched_users=[0]) == (3, "delta")
+    assert eng._model.n_items == N_ITEMS + 1
+    # an id may name the row this very publish appends, and no further
+    eng.publish_update(Uh, np.concatenate([longer, V[:1]]), touched_users=[0],
+                       seen_appended=([0], [N_ITEMS + 1]))
+    with pytest.raises(ValueError, match="seen_appended"):
+        eng.publish_update(Uh, np.concatenate([longer, V[:1]]),
+                           touched_users=[0],
+                           seen_appended=([0], [N_ITEMS + 2]))
+    items = LiveUpdater(eng, srv, fold_items=True).start()
+    items.stop()
+    assert eng.published_index.delta_slots > 0
+    assert eng.published_seq == 4           # the warm-ups published nothing
     plain = ServingEngine(k=K, buckets=(8,), shortlist_k=256)
     plain.publish(Uh, V)
     with pytest.raises(NotImplementedError, match="user_seen"):

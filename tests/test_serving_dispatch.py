@@ -18,7 +18,8 @@ batches of one bucket in flight at once are staged in two arrays and
 both answered right.
 
 Every dispatch of a warmed engine rides its pin (PR 46): for an engine of
-each benchmark cell's kind, warmed as its cell warms it, one batch for
+each benchmark cell's kind (since PR 47 also the one whose catalog moves
+under histories that grow), warmed as its cell warms it, one batch for
 every (bucket, path, history pad) it pinned goes through that pinned
 executable — called once, still pinned afterwards, nothing compiled, the
 launch span saying so and naming the executable's own module.  A pin that
@@ -271,11 +272,14 @@ KINDS = {
     "histories": ((64, 512), "int8"),   # serve-unseen
     "grown": ((64, 128), "int8"),       # serve-foldin-unseen
     "mesh": (None, "int8"),             # serve-steady-mesh
+    # PR 47, no parent: the catalog moves under histories that grow
+    "segment_grown": ((64, 128), "int8_delta"),     # serve-foldin-all
 }
 # resident history lengths: the as-published ladder 64 / 512 from the
 # longest of ``histories``; ``grown``'s longest, 64, with its room (72)
 # asks for one rung more, 128, which only an append reaches
-LENGTHS = {"histories": (0, 3, 40, 64, 65, 300), "grown": (0, 3, 40, 63, 64)}
+LENGTHS = {"histories": (0, 3, 40, 64, 65, 300), "grown": (0, 3, 40, 63, 64),
+           "segment_grown": (0, 3, 40, 63, 64)}
 
 
 def pin_key(bucket, path, pad):
@@ -356,15 +360,22 @@ def warmed(kind):
          "implicitPrefs": False, "alpha": 1.0, "nonnegative": False})
     srv = FoldInServer(model, base_history=(
         indptr, indices, np.ones(len(indices), np.float32)))
-    srv.prewarm(rows=(8,))
-    upd = LiveUpdater(eng, srv, max_batch=8, max_wait_ms=2.0).start()
+    both = kind == "segment_grown"
+    srv.prewarm(rows=(8,), sides=("user", "item") if both else ("user",))
+    upd = LiveUpdater(eng, srv, max_batch=8, max_wait_ms=2.0,
+                      fold_items=both).start()
     try:
         # appends under the warmed programs: the user at the top resident
         # rung outgrows it (and its run's room: the run moves), one at 63
-        # crosses 64, one the model never saw gets a first run
+        # crosses 64, one the model never saw gets a first run; where the
+        # catalog moves too, the first of them are of items it does not
+        # hold yet (a catalog publish each, the id naming a slot)
+        fresh = iter(range(N_ITEMS, N_ITEMS + 3 * both))
         for user in [users[64]] * 10 + [users[63]] * 2 + [N_USERS + 3]:
             have = set(items[user].tolist()) if user < N_USERS else set()
-            item = next(i for i in range(N_ITEMS) if i not in have)
+            item = next(fresh, None) if user < N_USERS else None
+            if item is None:
+                item = next(i for i in range(N_ITEMS) if i not in have)
             if user < N_USERS:
                 items[user] = np.append(items[user], item)
             seq0 = eng.published_seq
@@ -377,6 +388,9 @@ def warmed(kind):
         upd.stop(drain_timeout_s=30.0)
     # ten and two appended: both ride the rung above the resident ones
     users[74], users[65] = users.pop(64), users.pop(63)
+    if both:
+        index = eng.published_index
+        assert index.n_items == N_ITEMS + 3 and index.delta_count >= 3
     return eng, users
 
 

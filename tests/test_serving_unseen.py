@@ -374,20 +374,39 @@ def test_what_cannot_take_histories_yet_says_so(factors):
     mesh.publish(U, V[:4096])
     with pytest.raises(NotImplementedError, match="mesh"):
         mesh.submit(0, exclude=[1])
-    # a generation with histories takes publish_update since PR 42; what
-    # it refuses is a catalog that moves (tests/test_live_unseen.py)
+    # a generation with histories takes publish_update since PR 42 and a
+    # catalog that moves since PR 47 (tests/test_live_items_unseen.py);
+    # made ready for its histories alone it gets no segment ...
+    top = top_histories(scores, [5] * N_USERS)
     eng = ServingEngine(k=K, buckets=(8,), shortlist_k=SK)
     eng.publish(U, V, user_seen=hist)
-    with pytest.raises(NotImplementedError, match="delta segment that also excludes"):
-        eng.publish_update(U, V, touched_items=[0])
-    eng.warmup_live()
-    assert eng.published_index.delta_slots == 0     # no segment for it
+    eng.warmup_histories()
+    assert eng.published_index.delta_slots == 0
     assert eng.publish_update(U, V, touched_users=[0]) == (2, "retag")
+    # ... and takes one at the first row that moves: user 0's best item,
+    # twice as long, lives in a slot now and is still not returned
+    V2 = V.copy()
+    V2[top[0][0]] *= 2
+    assert eng.publish_update(U, V2, touched_items=[top[0][0]]) == (3, "delta")
+    assert eng.published_index.delta_count == 1
+    held_to_reference(ask(eng, [(0, None)]), U[:1], V2, [top[0]])
+    # made ready for both, it has its segment from the start
+    both = ServingEngine(k=K, buckets=(8,), shortlist_k=SK)
+    both.publish(U, V, user_seen=hist)
+    both.warmup_live()
+    assert both.published_index.delta_slots > 0
+    # (histories of 5 ids with room to grow ride the pads 64 and 128)
+    assert set(both._pinned) == {(8, "int8_delta", 64),
+                                 (8, "int8_delta", 128), (8, "exact", 128)}
+    assert both.publish_update(U, V2, touched_items=[top[0][0]])[1] == "delta"
+    held_to_reference(ask(both, [(0, None)]), U[:1], V2, [top[0]])
+    # a request's own list on an index with a segment: the id in a slot
+    # and the id in the base are both gone
     live = ServingEngine(k=K, buckets=(8,), shortlist_k=SK)
     live.publish(U, V)
     live.warmup_live()
-    with pytest.raises(NotImplementedError, match="delta segment"):
-        live.submit(0, exclude=[1])
+    assert live.publish_update(U, V2, touched_items=[top[0][0]])[1] == "delta"
+    held_to_reference(ask(live, [(0, top[0][:2])]), U[:1], V2, [top[0][:2]])
 
 
 @pytest.mark.parametrize("shape", [(8, 4096, 128, 100), (5, 300, 300, 40),

@@ -30,7 +30,10 @@ single measurable quantity:
    generation in atomically (on an engine that holds its users'
    histories with the ids the batch's ratings add to them,
    ``FoldInServer.last_appended``: what was just rated leaves that
-   user's answers with the publish that folds it in) — retag for user-only batches, an
+   user's answers with the publish that folds it in; with
+   ``fold_items`` the catalog rows go in the same publish, and an id
+   whose item has no row yet joins with the publish that gives it one:
+   ``_joining``) — retag for user-only batches, an
    O(touched) delta re-quantization for item batches — never a full
    O(catalog) rebuild while the live index is healthy.  The touched
    user rows are written into the device's table in place (the table
@@ -135,6 +138,9 @@ class LiveUpdater:
                                      span_keys=LIVE_SPAN_KEYS,
                                      labels=self._labels)
         self._queue = []
+        # (user ids, item ids) rated but not yet in the engine's
+        # histories: a side of the pair has no row yet (_joining)
+        self._not_joined = (np.empty(0, np.int64), np.empty(0, np.int64))
         self._batch_seq = 0
         self._cond = threading.Condition()
         self._closed = False
@@ -176,18 +182,17 @@ class LiveUpdater:
         ``fold_items`` as many items, into a catalog with spare rows and
         a segment of fixed size; on an engine that holds its users'
         histories as many ids appended to them, into a table laid out to
-        grow: ``ServingEngine.warmup_live``), so that none compiles or
-        loads under traffic, then start the loop."""
+        grow: ``ServingEngine.warmup_live`` / ``warmup_histories``), so
+        that none compiles or loads under traffic, then start the
+        loop."""
         if self._thread is not None:
             raise RuntimeError("updater already started")
-        if self.fold_items and self._histories:
-            raise NotImplementedError(
-                "fold_items on an engine whose generation holds users' "
-                "histories: its catalog cannot move yet (the scoring "
-                "program with a delta segment takes no per-row exclusion)")
         self.engine.warmup_publish(self.max_batch)
-        if self.fold_items or self._histories:
+        if self.fold_items:
+            # (the histories too, where the engine holds them)
             self.engine.warmup_live(max_rows=self.max_batch)
+        elif self._histories:
+            self.engine.warmup_histories(max_rows=self.max_batch)
         self._thread = threading.Thread(
             target=self._run, name="tpu-als-live", daemon=True)
         self._thread.start()
@@ -199,6 +204,24 @@ class LiveUpdater:
         (``publish(user_seen=...)``): every publish then hands it the
         ids its ratings add to their users' histories."""
         return getattr(self.engine, "holds_histories", False)
+
+    def _joining(self):
+        """``(user rows, catalog ids)`` this publish adds to its users'
+        histories on the engine: the pairs the last user fold added
+        (``FoldInServer.last_appended``) and those kept from earlier
+        batches, both sides of which have a row NOW, after the batch's
+        folds — so an id joins its user's history in the publish that
+        first makes its item servable (a new item folded in this batch:
+        this one), whether or not the user's row could use the rating
+        yet.  A pair one side of which still has no row (a rating with
+        both sides unknown) is kept for the publish that gives it one."""
+        m = self.foldin.model
+        who, what = (np.concatenate([kept, new]) for kept, new in zip(
+            self._not_joined, self.foldin.last_appended))
+        rows, ids = m._user_map.to_dense(who), m._item_map.to_dense(what)
+        ok = (rows >= 0) & (ids >= 0)
+        self._not_joined = (who[~ok], what[~ok])
+        return rows[ok], ids[ok]
 
     def stop(self, drain_timeout_s=10.0):
         """Close admission, drain the queue, join the loop."""
@@ -338,13 +361,14 @@ class LiveUpdater:
             # the rows the fold moved, and nothing else of either table;
             # with them, where the engine keeps the users' histories, the
             # ids these ratings add to them (one publish, one generation)
-            grown = {}
+            grown, parts = {}, {}
             if self._histories:
-                who, what = self.foldin.last_appended
-                who, what = (m._user_map.to_dense(who),
-                             m._item_map.to_dense(what))
-                ok = (who >= 0) & (what >= 0)
-                grown["seen_appended"] = (who[ok], what[ok])
+                grown["seen_appended"] = self._joining()
+                parts["history_ids"] = len(grown["seen_appended"][0])
+            if self.fold_items:
+                parts["items"] = len(touched_item_rows)
+            if parts:
+                span.set_metadata(**parts)
             seq, mode = self.engine.publish_update(
                 m._U, m._V, touched_items=touched_item_rows,
                 touched_users=m._user_map.to_dense(touched_users),
@@ -359,6 +383,13 @@ class LiveUpdater:
                      "segment_rows": (index.delta_count
                                       if index is not None else 0)}
             obs.counter("live.items_appended", sizes["new_items"],
+                        **self._labels)
+            did = self.foldin.last_items
+            obs.counter("live.items_folded", did["first"], kind="first",
+                        **self._labels)
+            obs.counter("live.items_folded", did["again"], kind="again",
+                        **self._labels)
+            obs.counter("live.items_left_to_refit", did["left_to_refit"],
                         **self._labels)
             obs.gauge("live.events_waiting", self.foldin.events_waiting,
                       **self._labels)

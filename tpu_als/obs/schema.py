@@ -221,6 +221,25 @@ METRICS = {
         "catalog items the live updater's item fold appended (unknown "
         "before their first foldable rating, servable by id after its "
         "publish)"),
+    "live.items_folded": (
+        "counter", "items",
+        "item folds the live updater published, labeled kind=first (the "
+        "item had no factor: appended to the catalog) | again (a row "
+        "re-folded over all the item's ratings so far)"),
+    "live.items_left_to_refit": (
+        "counter", "events",
+        "rating events whose ITEM the fold-in server left its factor: a "
+        "server with a resident base history folds an item only where "
+        "the run's events are ALL its ratings (none resident), so an "
+        "item a resident rating names waits for the refit; the events "
+        "still enter their users' folds and histories "
+        "(stream/microbatch.py)"),
+    "live.history_segment_ids": (
+        "counter", "ids",
+        "of the ids ServingEngine.publish_update appended to its users' "
+        "histories (live.history_appended_ids), those that name an item "
+        "the published index holds in its delta segment at that publish: "
+        "the scoring program masks them by slot, not by base column"),
     "live.events_waiting": (
         "gauge", "events",
         "ratings the fold-in server holds in a history for a side whose "
@@ -337,6 +356,9 @@ LABELS = {
     "live.catalog_h2d_bytes": ("tenant",),
     "live.items_appended": ("tenant",),
     "live.events_waiting": ("tenant",),
+    "live.items_folded": ("kind", "tenant"),
+    "live.items_left_to_refit": ("tenant",),
+    "live.history_segment_ids": ("tenant",),
     "live.history_appended_ids": ("tenant",),
     "live.history_h2d_bytes": ("tenant",),
     "live.history_relocations": ("tenant",),
@@ -449,8 +471,13 @@ SERVE_DISPATCH_SPAN_KEYS = (
     #                                 ``jit__serve_exact_packed``,
     #                                 ``jit_serve_mesh_int8``,
     #                                 ``jit_serve_mesh_exact``;
-    #                                 pinned 0|1: the AOT executable took
-    #                                 it)
+    #                                 pin: the path name of the key it
+    #                                 was pinned under, ``int8`` |
+    #                                 ``int8_delta`` (given a segment) |
+    #                                 ``exact`` — with the stage span's
+    #                                 ``excluded`` (the history pad) the
+    #                                 whole key; pinned 0|1: the AOT
+    #                                 executable took it)
 )
 # the engine thread's blocking wait for one of MAX_IN_FLIGHT slots,
 # written only when it blocks (seq: the batch that will take the slot;
@@ -499,7 +526,11 @@ LIVE_BATCH_SPAN_KEYS = (
     #                           new_users, width, mode; with fold_items
     #                           also items, new_items, segment_rows)
     "live.batch.foldin",      # FoldInServer.update (+ update_items)
-    "live.batch.publish",     # ServingEngine.publish_update
+    "live.batch.publish",     # ServingEngine.publish_update (with
+    #                           fold_items also ``items``: the catalog
+    #                           rows it names; on an engine that holds
+    #                           histories ``history_ids``: the ids it
+    #                           appends — one publish's three parts)
 )
 # what an updater with ``fold_items`` writes besides, inside the two
 # phases above (none of it without: a user-only updater's timeline is

@@ -1068,11 +1068,12 @@ class ServingEngine:
         return _Seen(dev, indices, lengths, pads,
                      _Room(start, cap, held, size))
 
-    def _append_history(self, n_users, appended):
+    def _append_history(self, n_users, n_items, appended):
         """The history half of a ``publish_update`` on a generation that
         holds histories, up to the write (which is :meth:`_swap`'s): the
         plan of :meth:`_plan_append` for ``appended`` (``None``: no id,
-        the table carried as it is), its uploads made, inside the span
+        the table carried as it is; ids below ``n_items``, the catalog's
+        size as this publish leaves it), its uploads made, inside the span
         ``live.batch.publish.history`` and counted.  Where the table has
         no room for the plan — nobody laid it out to grow, or the room
         is used up — it is laid out anew first (:meth:`_lay_out`: O(all
@@ -1084,7 +1085,7 @@ class ServingEngine:
             if appended is None:
                 appended = ((), ())
             plan = (None if m.seen.room is None else self._plan_append(
-                m.seen, n_users, m.n_items, appended))
+                m.seen, n_users, n_items, appended))
             if plan is None:
                 obs.emit("warning", what="serving.publish_update",
                          reason="the histories have no room for this "
@@ -1099,7 +1100,7 @@ class ServingEngine:
                             m.seen, max(n_users, int(m.U.shape[0])),
                             more=8 * (longest + len(appended[0]))))
                 plan = self._plan_append(self._model.seen, n_users,
-                                         m.n_items, appended)
+                                         n_items, appended)
                 if plan is None:
                     raise ValueError(
                         "seen_appended: more ids for one user than a "
@@ -1230,8 +1231,8 @@ class ServingEngine:
         The histories published here lie run after run with no room
         between them; :meth:`warmup_live` lays them out to GROW, after
         which :meth:`publish_update` appends to them
-        (``seen_appended``).  What such a generation still refuses is a
-        catalog that moves (``publish_update(touched_items=...)``).
+        (``seen_appended``), also while its catalog moves
+        (``touched_items``).
         """
         t0 = time.perf_counter()
         mode = faults.check("serving.publish")
@@ -1396,24 +1397,18 @@ class ServingEngine:
         whose run is full is moved to free room first
         (``live.history_relocations``).  A user appended to the table
         starts with an empty history.  The catalog of such a generation
-        does not move: ``touched_items``, a catalog of another size or
-        ``item_valid`` raise ``NotImplementedError`` before anything is
-        written (no warm-up pins the program that excludes AND scores a
-        delta segment, and no cell measures it), and so does
-        ``seen_appended`` on a generation that
-        holds no histories.  The histories are laid out to grow by
-        :meth:`warmup_live`; on an engine nobody warmed up the first
+        moves like any other (``touched_items``, appended rows): the
+        user rows, the catalog rows and the ids of ONE publish are one
+        generation, and an id may name an item the same publish appends
+        (ids are checked against the catalog as this publish leaves it;
+        ``live.history_segment_ids`` counts those that name an item the
+        index holds in its segment).  ``seen_appended`` on a generation
+        that holds no histories raises ``NotImplementedError``.  The
+        histories are laid out to grow by :meth:`warmup_live` /
+        :meth:`warmup_histories`; on an engine nobody warmed up the first
         such publish does it, under the traffic, with a warning.
         """
         prev = self._model
-        if prev is not None and prev.seen is not None and (
-                touched_items is not None or item_valid is not None
-                or int(np.shape(V)[0]) != prev.n_items):
-            raise NotImplementedError(
-                "publish_update of a catalog that moves on a generation "
-                "that holds users' histories: the scoring program with a "
-                "delta segment that also excludes is pinned by no warm-up "
-                "yet; publish(..., user_seen=...) whole")
         if seen_appended is not None and (prev is None
                                           or prev.seen is None):
             raise NotImplementedError(
@@ -1438,8 +1433,11 @@ class ServingEngine:
             prev = self._model
             how, users, n_users, h2d = self._update_users(
                 prev, U, touched_users)
+            # planned against the catalog as THIS publish leaves it: an
+            # id it appends may name an item it appends
             appended = (None if prev is None or prev.seen is None
-                        else self._append_history(n_users, seen_appended))
+                        else self._append_history(n_users, Ni,
+                                                  seen_appended))
             cur = prev.index if prev is not None else None
             fresh = (cur is not None and cur.seq == prev.seq
                      and cur.n_items <= Ni)
@@ -1486,6 +1484,9 @@ class ServingEngine:
                     mode = "none"
             # last: every step above may raise or take long (an index
             # build), and from the row write on the old table is gone
+            in_segment = (0 if seen_appended is None or index is None
+                          else int(np.isin(seen_appended[1],
+                                           index.d_rows).sum()))
             how = self._swap(how, users, seq, n_users, V, valid, index, Ni,
                              host=U, items=items, appended=appended)
             self._seq = seq
@@ -1502,6 +1503,9 @@ class ServingEngine:
         obs.counter("serving.catalog_writes", how=catalog, **self._labels)
         obs.counter("live.publish_h2d_bytes", h2d, **self._labels)
         obs.counter("live.catalog_h2d_bytes", sent, **self._labels)
+        if prev is not None and prev.seen is not None:
+            obs.counter("live.history_segment_ids", in_segment,
+                        **self._labels)
         obs.histogram("serving.publish_seconds",
                       time.perf_counter() - t0, mode=mode,
                       **self._labels)
@@ -1546,7 +1550,7 @@ class ServingEngine:
         that changes array shapes invalidates a pin (the serve path
         falls back to the jit call and drops it) — re-run warmup to
         restore.  With a mesh the pinned programs are the sharded ones
-        (:func:`_build_mesh_serve`, :func:`_build_mesh_exact`), one a
+        (``serving.index._build_sharded_int8``, :func:`_build_mesh_exact`), one a
         bucket and path like the others, and each int8 one is announced
         by a ``serving_mesh_plan`` event.  For a generation that holds
         users' histories (``publish(user_seen=...)``) the pinned
@@ -1595,7 +1599,9 @@ class ServingEngine:
         """Pin what a generation with histories runs for bucket ``B``,
         and run each once (a program's first execution takes up to
         seconds, and there are several a bucket: none is left to the
-        traffic): the int8 program at every history pad of its ladder,
+        traffic): the int8 program at every history pad of its ladder
+        (given the index's segment where it has one, and pinned under
+        :meth:`_int8_pin`'s name then: ``(B, "int8_delta", pad)``),
         the exact fallback at the longest alone (a fallback batch rides
         that one whatever its histories: :meth:`_dispatch`); one
         ``serving_exclusion`` event each, from the plan the program's
@@ -1606,10 +1612,12 @@ class ServingEngine:
             for pad in m.seen.pads:
                 fn, args, statics = self._int8_call(m, idx, wide,
                                                     m.seen, pad)
-                c = self._pinned[(B, "int8", pad)] = fn.lower(
+                c = self._pinned[(B, self._int8_pin(idx), pad)] = fn.lower(
                     *args, **statics).compile()
                 c(*args).block_until_ready()
-                self._emit_shortlist(B, idx, history_pad=pad)
+                self._emit_shortlist(B, idx, history_pad=pad, **(
+                    {"delta_rows": idx.delta_slots} if idx.delta_slots
+                    else {}))
                 cols = int(idx.Vq.shape[0])
                 self._emit_exclusion(B, "int8", pad, cols,
                                      mask_block(cols))
@@ -1674,12 +1682,7 @@ class ServingEngine:
         :meth:`_dispatch` runs.  ``seen`` (with its history ``pad``):
         the batch excludes, and ``packed`` is the wide layout."""
         # (a mesh engine never sees histories or lists: ``publish`` and
-        # ``submit`` refuse it them; a segment can arrive between a
-        # request's ``submit`` and its batch)
-        if seen is not None and idx.delta_slots:
-            raise NotImplementedError(
-                "a batch that excludes, scored by the program with a "
-                "delta segment: no warm-up pins that program yet")
+        # ``submit`` refuse it them)
         if self.mesh is not None:
             k_loc, sk_loc = idx.shard_widths(self.k)
             return (_build_sharded_int8(
@@ -1795,27 +1798,23 @@ class ServingEngine:
         no-op when the model serves exact.  ``seq`` does not move.
 
         A generation that holds users' histories
-        (``publish(user_seen=...)``) is made ready for HISTORIES that
-        grow instead (``publish_update(seen_appended=...)``; its catalog
-        does not move, so it gets neither spare rows nor a segment, and
-        ``LiveUpdater.start`` calls this for it whatever ``fold_items``
-        says): the histories are laid out with room behind every run
-        and free room at the end (:meth:`_lay_out`, once), the programs
-        that exclude are pinned AND run for every bucket and history pad
-        of the grown ladder, in place of whatever :meth:`warmup` had
-        pinned (:meth:`_warm_exclusion`), and the write programs are run
-        on the live table, writing nothing: :func:`_append_runs` at
-        every padded size up to ``max_rows`` ids a publish,
-        :func:`_move_run` at every history pad.
+        (``publish(user_seen=...)``) is made ready for its HISTORIES to
+        grow as well (:meth:`warmup_histories`, which an updater that
+        folds no items calls alone), after the catalog: the programs
+        pinned and run are then the ones that exclude AND score the
+        segment, one a bucket and history pad of the grown ladder under
+        ``(bucket, "int8_delta", pad)``, and the programs without
+        histories are not compiled (no batch of such a generation runs
+        them).
         """
         with self._publish_lock, self._table_lock:
             m = self._model
             if m is None:
                 raise NoModelPublished("publish(U, V) before warmup")
-            if m.seen is not None:
-                return self._warm_histories(m, max_rows)
             idx = m.index
             if idx is None or idx.seq != m.seq:
+                if m.seen is not None:
+                    self._warm_histories(m, max_rows)
                 return
             rows = (idx.n_base if idx.n_base > idx.n_items
                     else row_capacity(idx.n_items))
@@ -1825,7 +1824,7 @@ class ServingEngine:
             # engine's own table is copied larger (one table's worth of
             # room at a time)
             m = self._model = _Published(m.seq, m.U, m.n_users, m.V,
-                                         m.valid, idx, m.n_items)
+                                         m.valid, idx, m.n_items, m.seen)
             V, valid = m.V, m.valid
             if self.mesh is None and int(V.shape[0]) < idx.n_base:
                 more = idx.n_base - int(V.shape[0])
@@ -1840,7 +1839,13 @@ class ServingEngine:
                         np.zeros(pad, bool))))
             idx = idx.prewarm(max_rows)
             m = self._model = _Published(m.seq, m.U, m.n_users, V, valid,
-                                         idx, m.n_items)
+                                         idx, m.n_items, m.seen)
+            if m.seen is not None:
+                # every batch of this generation excludes: what is pinned
+                # is the programs that do, given the segment.  (Room for
+                # more ids than rows: a publish appends its batch's ids
+                # AND those that waited for their item's row)
+                return self._warm_histories(m, max_rows + 1)
             for B in self.batcher.buckets:
                 proto = self._proto(B, m.rank)
                 self._pinned.pop((B, "int8"), None)
@@ -1853,9 +1858,30 @@ class ServingEngine:
                 self._pinned[(B, "exact")] = fn.lower(
                     *args, **statics).compile()
 
+    def warmup_histories(self, max_rows=LIVE_PADS[-1]):
+        """Make a generation that holds users' histories
+        (``publish(user_seen=...)``) ready for HISTORIES that grow
+        (``publish_update(seen_appended=...)``) and for nothing else —
+        its catalog gets neither spare rows nor a segment; what
+        ``LiveUpdater.start`` calls where ``fold_items`` is off — before
+        any live traffic: the histories are laid out with room behind
+        every run and free room at the end (:meth:`_lay_out`, once), the
+        programs that exclude are pinned AND run for every bucket and
+        history pad of the grown ladder, in place of whatever
+        :meth:`warmup` had pinned (:meth:`_warm_exclusion`), and the
+        write programs are run on the live table, writing nothing:
+        :func:`_append_runs` at every padded size up to ``max_rows`` ids
+        a publish, :func:`_move_run` at every history pad.  A generation
+        without histories: nothing to do."""
+        with self._publish_lock, self._table_lock:
+            m = self._model
+            if m is None:
+                raise NoModelPublished("publish(U, V) before warmup")
+            if m.seen is not None:
+                self._warm_histories(m, max_rows)
+
     def _warm_histories(self, m, max_rows):
-        """:meth:`warmup_live` for a generation that holds histories,
-        under its locks."""
+        """:meth:`warmup_histories`, under its locks."""
         seen = m.seen
         if seen.room is None:
             seen = self._lay_out(seen, int(m.U.shape[0]))
@@ -1919,8 +1945,7 @@ class ServingEngine:
         ``Overloaded`` when shedding, ``NoModelPublished`` before the
         first publish, ``ValueError`` on a malformed payload or list (a
         longer one is refused, not cut), ``NotImplementedError`` for a
-        list where the scoring program cannot take one yet (a mesh, an
-        index with a delta segment).
+        list where the scoring program cannot take one yet (a mesh).
         """
         t_enter = time.perf_counter()
         m = self._model
@@ -1979,13 +2004,10 @@ class ServingEngine:
                 f"exclude holds {ids.size} ids, a request may bring "
                 f"{MAX_EXCLUDE}: a longer list belongs to the user's "
                 "history (publish(user_seen=...))")
-        if self.mesh is not None or (m.index is not None
-                                     and m.index.delta_slots):
+        if self.mesh is not None:
             raise NotImplementedError(
-                "exclude on "
-                + ("a mesh engine" if self.mesh is not None
-                   else "an index with a delta segment")
-                + ": no pinned program of it excludes yet")
+                "exclude on a mesh engine: no pinned program of it "
+                "excludes yet")
         return ids.astype(np.int32)
 
     def recommend(self, payload, k=None, deadline_s=None, timeout=None,
@@ -2401,6 +2423,6 @@ class ServingEngine:
                             **self._labels)
             resp_dev = self._run_pinned(key, fn, args, statics)
             # a pin that failed was dropped inside the call
-            span.set_metadata(program="jit_" + fn.__name__,
+            span.set_metadata(program="jit_" + fn.__name__, pin=pin,
                               pinned=int(key in self._pinned))
         return resp_dev, path, fell_back, how, t_launch, time.perf_counter()

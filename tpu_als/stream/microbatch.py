@@ -57,6 +57,20 @@ did add one, for whoever keeps the users' histories elsewhere
 (``ServingEngine.publish_update(seen_appended=...)``).  A server without a
 base history cannot know what was rated before the run and keeps every
 event as a rating of its own, as it always has.
+
+**The item side of such a server** obeys the same contract: a fold is over
+ALL of the entity's ratings, or it does not happen.  The resident ratings
+lie by user, and a hot item's are hundreds of thousands: folding it over
+the run's handful of events would replace a factor fitted to all of them by
+one fitted to five, and folding it over all of them is a gather of hundreds
+of megabytes an event.  So ``update_items`` folds an item none of whose
+ratings is resident — a new id, or a catalog row no resident rating names:
+the run's events are all its ratings (``implicit``'s ``partial_fit_items``
+for items new to the model) — and leaves an item with resident ratings its
+factor until the refit (``live.items_left_to_refit`` counts its events,
+which still enter their users' folds and histories).  Which is which the
+server reads from its base history, once, when the item side is first
+asked for.
 """
 
 from __future__ import annotations
@@ -119,6 +133,10 @@ class FoldInServer:
         # (user ids, item ids) of the last ``update``'s events that added
         # an id to their user's history (all of them without a base)
         self.last_appended = (np.empty(0, np.int64), np.empty(0, np.int64))
+        # of the last ``update_items``: items folded for the first time
+        # and again, events of items it left to the refit (module
+        # docstring: the item side of a server with a base history)
+        self.last_items = {"first": 0, "again": 0, "left_to_refit": 0}
         # original id -> (fixed-side ORIGINAL ids, ratings), in arrival
         # order
         self._history = {}
@@ -133,6 +151,9 @@ class FoldInServer:
         self._implicit = bool(p.get("implicitPrefs", False))
         self._alpha = float(p.get("alpha", 1.0))
         self._nonnegative = bool(p.get("nonnegative", False))
+        # with a base history, by dense catalog row: whether a resident
+        # rating names it (made when the item side is first asked for)
+        self._rated_before = None
         self._bufs = {}     # "_U" / "_V" -> the buffer the model's is a view of
         self._reserve(items_side=False)
         # each fold direction's fixed side on the device, placed once:
@@ -291,7 +312,19 @@ class FoldInServer:
             # host too (one copy, here and not under the first append)
             self._reserve(items_side=True)
             self._Ud = self._place("_U")
+            if self._base is not None:
+                self._rated_before = np.bincount(
+                    self._base[1], minlength=len(self.model._V)) > 0
         return self._Ud
+
+    def _to_refit(self, items):
+        """Which of the events' ``items`` (original ids) the item side
+        of a server with a base history leaves alone: those a resident
+        rating names (module docstring)."""
+        self._fixed(items_side=True)
+        row = self.model._item_map.to_dense(items)
+        known = (row >= 0) & (row < len(self._rated_before))
+        return known & self._rated_before[np.where(known, row, 0)]
 
     def _fold_batch(self, batch, items_side):
         """ONE shared mechanics path for both directions — history
@@ -313,6 +346,15 @@ class FoldInServer:
         r = np.asarray(frame[p["ratingCol"]], dtype=np.float32)
         if not items_side:
             self.last_appended = (solved_raw[:0], fixed_raw[:0])
+        else:
+            self.last_items = dict.fromkeys(self.last_items, 0)
+            if self._base is not None and len(solved_raw):
+                # an item with resident ratings keeps its factor until
+                # the refit: a fold here could not be over all of them
+                left = self._to_refit(solved_raw)
+                self.last_items["left_to_refit"] = int(left.sum())
+                solved_raw, fixed_raw, r = (
+                    a[~left] for a in (solved_raw, fixed_raw, r))
         if len(solved_raw) == 0:
             return np.array([], dtype=np.int64)
 
@@ -411,6 +453,9 @@ class FoldInServer:
             obs.histogram("foldin.history_width", w, side=side)
             widest = max(widest, w)
 
+        if items_side:
+            first = int((m._item_map.to_dense(touched) < 0).sum())
+            self.last_items.update(first=first, again=n - first)
         self._write_back(touched, x, items_side)
         if items_side and self._implicit:
             self._YtY = compute_yty(self._V)

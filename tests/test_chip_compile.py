@@ -113,7 +113,7 @@ def test_fold_in_solve_rank128(one_chip, monkeypatch):
                              reg_param=0.01, implicit_prefs=True,
                              alpha=40.0)
     compile_for(one_chip, body, ((ML25M_ITEMS, 128), F32),
-                ((64, 256), I32), ((64, 256), F32), ((64, 256), F32))
+                ((3, 64, 256), I32))
 
 
 GATHER = [((60_000, 128), None), ((2048, 256), I32), ((2048, 256), None),
@@ -690,8 +690,7 @@ def test_fold_in_over_whole_histories_at_rank_256(one_chip, rows, width):
     c = _compiled(
         one_chip, _fold_in_jit,
         ((row_capacity(LIVE_ITEMS), LIVE_RANK), jnp.float32),
-        ((rows, width), jnp.int32), ((rows, width), jnp.float32),
-        ((rows, width), jnp.float32), ((), jnp.float32),
+        ((3, rows, width), jnp.int32), ((), jnp.float32),
         implicit_prefs=False, alpha=1.0, nonnegative=False, nnls_sweeps=32,
         jitter=DEFAULT_JITTER, backend="xla")
     assert c.memory_analysis().temp_size_in_bytes < 1.5 * (1 << 30)

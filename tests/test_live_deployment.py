@@ -625,8 +625,8 @@ def test_the_programs_name_their_halves_in_every_op_name():
     V = jnp.zeros((64, 8), jnp.float32)
     z = jnp.zeros((8, 8), jnp.float32)
     text = foldin._fold_in_jit.lower(
-        V, z.astype(jnp.int32), z, z, 0.1, backend="xla").as_text(
-            debug_info=True)
+        V, foldin.pack_rows(z.astype(jnp.int32), z, z), 0.1,
+        backend="xla").as_text(debug_info=True)
     assert "live.foldin.gram" in text and "live.foldin.solve" in text
     from tpu_als.serving.engine import _scatter_users
 

@@ -505,10 +505,11 @@ def test_an_item_publish_uploads_the_touched_rows_not_the_catalog():
                            touched_users=np.array([], int))
         sent.append(reg.counter_value("live.catalog_h2d_bytes")
                     - sum(sent))
-    # slot, id, row, bit, padded to 8, 8, 64 rows: uploaded once, for the
-    # segment and for the engine's own table alike
+    # slot, id, bit and the last id as one int32 array, and the row, padded
+    # to 8, 8, 64 rows: uploaded once, for the segment and for the engine's
+    # own table alike
     assert sent == [segment_write_bytes(n, RANK) for n in (3, 8, 40)]
-    assert sent == [pad * (4 + 4 + 4 * RANK + 1) for pad in (8, 8, 64)]
+    assert sent == [pad * 4 * (4 + RANK) for pad in (8, 8, 64)]
     assert sent[2] < 4 * N_ITEMS * RANK // 4
     assert reg.counter_value("live.publish_h2d_bytes") == 0
     new = eng._model
@@ -547,8 +548,13 @@ def test_the_catalog_writes_hold_no_catalog_shaped_copy(pad):
         assert not [ln for ln in text.splitlines() if " copy(" in ln
                     and any(s in ln for s in shaped)], fn
     # the segment's own write touches nothing of the catalog's size
-    text = _write_segment.lower(*seg, rows, rows, vals, ok).compile().as_text()
-    assert not any(s in text for s in shaped)
+    # (what it takes from the host alone, and as rows 1-4 of a publish's
+    # one array)
+    for sent, at in ((jnp.zeros((4, pad), jnp.int32), 0),
+                     (jnp.zeros((10, 512), jnp.int32), 1)):
+        text = _write_segment.lower(*seg, sent, vals,
+                                    at=at).compile().as_text()
+        assert not any(s in text for s in shaped)
 
 
 def test_the_fold_in_server_uploads_no_table_per_batch(monkeypatch):

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,25 @@ def solve_path(rank, rows, nonnegative=False):
 # rows a :func:`place_rows` upload carries: 32 MiB at rank 256
 PLACE_CHUNK = 1 << 15
 
+# host→device placement calls of the live write path, by the thread that
+# made them (:func:`put`, :func:`placements`)
+_placed = threading.local()
+
+
+def put(arrays, device=None):
+    """``jax.device_put``, counted for the calling thread: every placement
+    the live write path makes beside a program's call goes through here
+    (a placement is ≈ 0.35 ms of Python around 0.08 ms of transfer on
+    the chip's host: PERF.md section 5, PR 41), so that
+    ``live.host_placements`` counts them where they are made."""
+    _placed.n = placements() + 1
+    return jax.device_put(arrays, device)
+
+
+def placements():
+    """Placements the calling thread has made through :func:`put`."""
+    return getattr(_placed, "n", 0)
+
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _write_rows(table, rows, at):
@@ -86,10 +106,27 @@ def write_rows(table, rows, vals, pad=None):
     programs, O(touched rows) on the host, on the link and on the
     device."""
     n, pad = len(rows), pad or pad_for(len(rows))
-    rp = np.full(pad, table.shape[0], dtype=np.int32)
     vp = np.zeros((pad, table.shape[1]), dtype=np.float32)
-    rp[:n], vp[:n] = rows, vals
-    return _scatter_rows(table, *jax.device_put((rp, vp)))
+    vp[:n] = vals
+    return _scatter_rows(table, *put((padded_rows(rows, pad, table), vp)))
+
+
+def padded_rows(rows, pad, table):
+    """``rows`` as ``int32[pad]``, padded with a row outside ``table``
+    (dropped by every row write)."""
+    rp = np.full(pad, table.shape[0], dtype=np.int32)
+    rp[:len(rows)] = rows
+    return rp
+
+
+def write_placed_rows(table, rows, vals):
+    """:func:`write_rows` for ``vals`` that lie on the device already —
+    the padded ``[pad, rank]`` result of a fold, ``rows`` the table rows
+    of its first ``len(rows)`` — the same program at the same shapes:
+    only the row numbers come from the host, and they ride the call as
+    its host argument (no placement)."""
+    return _scatter_rows(table, padded_rows(rows, vals.shape[0], table),
+                         vals)
 
 
 def place_rows(F, *, capacity, mesh=None):
@@ -171,16 +208,51 @@ def fold_in(
     cols/vals/mask: [n, w] padded CSR rows (same convention as
     tpu_als.core.ratings).  Returns new factors [n, rank].
 
-    Eager wrapper: settles the solve's backend before tracing
+    The three arrays go to the program as ONE (:func:`pack_rows`: planes
+    of one packed array cost nothing to pack, and host arrays ride the
+    call).  Eager wrapper: settles the solve's backend before tracing
     (:func:`solve_path`; a probe inside the jit trace cannot run and
     would pin the fallback path into the jit cache), then dispatches to the
     jitted body, one program per (rows, width, backend).
     """
     backend = solve_path(V.shape[-1], cols.shape[0], nonnegative)[0]
-    return _fold_in_jit(V, cols, vals, mask, reg_param,
+    return _fold_in_jit(V, pack_rows(cols, vals, mask), reg_param,
                         implicit_prefs=implicit_prefs, alpha=alpha,
                         nonnegative=nonnegative, nnls_sweeps=nnls_sweeps,
                         YtY=YtY, jitter=jitter, backend=backend)
+
+
+def planes(packed):
+    """``(cols, vals, mask)`` of rows packed as :func:`pack_rows` packs
+    them, as views of the host array ``packed`` (``int32[3, n, w]``):
+    what a caller fills in place and hands to :func:`fold_in`, which
+    then sends ``packed`` itself."""
+    return packed[0], packed[1].view(np.float32), packed[2].view(np.float32)
+
+
+def pack_rows(cols, vals, mask):
+    """The padded rows of a fold as the ONE array its program takes,
+    ``int32[3, n, w]``: the ids, the ratings' float32 bits and the mask's
+    float32 bits (same-itemsize views: nothing is converted, and floats
+    ride as integer bits, never the other way — the TPU flushes the
+    subnormals that small ids read as).  The :func:`planes` of one host
+    array ARE that array: no copy.  Other host arrays are stacked on the
+    host — either way the array rides the program's call as its host
+    argument, no placement of its own; arrays on the device are packed
+    there."""
+    if not all(isinstance(a, np.ndarray) for a in (cols, vals, mask)):
+        return jnp.stack([jnp.asarray(cols, jnp.int32)] + [
+            jax.lax.bitcast_convert_type(jnp.asarray(a, jnp.float32),
+                                         jnp.int32) for a in (vals, mask)])
+    whole = cols.base
+    if (isinstance(whole, np.ndarray) and whole.dtype == np.int32
+            and whole.shape == (3, *cols.shape)
+            and all(a.base is whole and a.ctypes.data == whole[i].ctypes.data
+                    for i, a in enumerate((cols, vals, mask)))):
+        return whole
+    return np.stack([cols.astype(np.int32, copy=False),
+                     vals.astype(np.float32, copy=False).view(np.int32),
+                     mask.astype(np.float32, copy=False).view(np.int32)])
 
 
 @functools.partial(
@@ -190,9 +262,7 @@ def fold_in(
 )
 def _fold_in_jit(
     V,
-    cols,
-    vals,
-    mask,
+    packed,
     reg_param,
     implicit_prefs=False,
     alpha=1.0,
@@ -202,6 +272,11 @@ def _fold_in_jit(
     jitter=DEFAULT_JITTER,
     backend="auto",
 ):
+    # the rows as ``pack_rows`` packed them: one argument, one transfer
+    # where it comes from the host
+    cols = packed[0]
+    vals, mask = (jax.lax.bitcast_convert_type(packed[i], jnp.float32)
+                  for i in (1, 2))
     # A handful of systems builds its normal equations in true float32:
     # six bf16 passes of matrices this small cost nothing beside the
     # program's launch, and one pass costs the published rows 6e-3 to

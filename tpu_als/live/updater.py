@@ -47,7 +47,7 @@ The thread's cycle is on the profiler's timeline as ``TraceAnnotation``
 spans, always on, the write path's counterpart of the engine thread's
 (``obs.schema.LIVE_BATCH_SPAN_KEYS``): ``live.idle``,
 ``live.batch.coalesce``, and ``live.batch`` (stats ``seq``, ``events``,
-``users``, ``new_users``, ``width``, ``mode``) around
+``users``, ``new_users``, ``width``, ``mode``, ``placements``) around
 ``live.batch.foldin`` and ``live.batch.publish``.  With ``fold_items``
 also (``obs.schema.LIVE_ITEM_SPAN_KEYS``) ``live.batch.foldin.users``
 and ``live.batch.foldin.items`` inside the fold,
@@ -85,6 +85,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from tpu_als import obs
+from tpu_als.core.foldin import placements
 from tpu_als.core.ratings import invalid_rating_mask
 from tpu_als.obs import tracing
 from tpu_als.obs.trace import FlightRecorder
@@ -292,7 +293,7 @@ class LiveUpdater:
         """Fold one popped batch in and publish it; ``whole`` is the
         ``live.batch`` span around the call, which takes the batch's
         sizes as its stats."""
-        t0 = time.perf_counter()
+        t0, placed_before = time.perf_counter(), placements()
         users, items, ratings, arrivals, ctxs = map(list, zip(*batch))
         users, items = np.asarray(users), np.asarray(items)
         ratings = np.asarray(ratings, dtype=np.float32)
@@ -369,6 +370,15 @@ class LiveUpdater:
                 parts["items"] = len(touched_item_rows)
             if parts:
                 span.set_metadata(**parts)
+            # and the same rows where the folds left them on the device:
+            # the engine writes its tables from there, not from the
+            # host's copies (no keyword where no fold left any: an
+            # engine that knows none is asked nothing new)
+            held = {side: self.foldin.last_rows[side]
+                    for side in ("users", "items")[:1 + self.fold_items]
+                    if self.foldin.last_rows[side] is not None}
+            if held:
+                grown["device_rows"] = held
             seq, mode = self.engine.publish_update(
                 m._U, m._V, touched_items=touched_item_rows,
                 touched_users=m._user_map.to_dense(touched_users),
@@ -393,10 +403,14 @@ class LiveUpdater:
                         **self._labels)
             obs.gauge("live.events_waiting", self.foldin.events_waiting,
                       **self._labels)
+        # host→device placements this thread made for the batch, beside
+        # its programs' calls (``core.foldin.put``)
+        made = placements() - placed_before
+        obs.counter("live.host_placements", made, **self._labels)
         whole.set_metadata(
             events=len(ratings), users=len(touched_users),
             new_users=len(m._user_map) - users_before,
-            width=width, mode=mode, **sizes)
+            width=width, mode=mode, placements=made, **sizes)
         ctxs = [tracing.record_span(c, "live.publish",
                                     seconds=publish_s, seq=seq,
                                     mode=mode)

@@ -207,15 +207,28 @@ METRICS = {
         "counter", "bytes",
         "bytes ServingEngine.publish_update sent host -> device of the "
         "USER table: the touched user rows and their indices on the "
-        "incremental path, the whole table where it had to re-place it "
-        "(the catalog's: live.catalog_h2d_bytes)"),
+        "incremental path (the indices alone — and what is unread of "
+        "the publish's one int32[10, pad] — where the caller held the "
+        "rows on the device, device_rows), the whole table where it had "
+        "to re-place it (the catalog's: live.catalog_h2d_bytes)"),
     "live.catalog_h2d_bytes": (
         "counter", "bytes",
         "bytes ServingEngine.publish_update sent host -> device of the "
         "CATALOG: the touched and appended item rows with their ids, slots "
-        "and valid bits (padded to 8 / 64 / 512 rows; once for the "
-        "index's segment, once for the engine's own table), the whole "
-        "catalog where it had to re-place it"),
+        "and valid bits (padded to 8 / 64 / 512 rows; once, for the "
+        "index's segment and the engine's own table; ids, slots and "
+        "valid bits alone where the caller held the rows on the device, "
+        "device_rows), the whole catalog where it had to re-place it"),
+    "live.host_placements": (
+        "counter", "placements",
+        "host -> device placement calls (core.foldin.put: jax.device_put "
+        "beside a program's call, ~0.35 ms of Python each on the chip's "
+        "host) the live updater's thread made for its micro-batches, one "
+        "add a batch: folds, the fold-in server's row writes, the "
+        "publish's rows, segment and history plan; 1 a batch where every "
+        "table is written from the fold's rows on the device (the "
+        "publish's one int32 array), 0 for a batch that moved no row; "
+        "the same number as the live.batch span's ``placements``"),
     "live.items_appended": (
         "counter", "items",
         "catalog items the live updater's item fold appended (unknown "
@@ -353,6 +366,7 @@ LABELS = {
     "live.shed": ("tenant",),
     "live.queue_depth": ("tenant",),
     "live.publish_h2d_bytes": ("tenant",),
+    "live.host_placements": ("tenant",),
     "live.catalog_h2d_bytes": ("tenant",),
     "live.items_appended": ("tenant",),
     "live.events_waiting": ("tenant",),
@@ -523,7 +537,10 @@ LIVE_BATCH_SPAN_KEYS = (
     "live.idle",              # blocked on an empty admission queue
     "live.batch.coalesce",    # first event seen -> batch popped
     "live.batch",             # all of _process (seq, events, users,
-    #                           new_users, width, mode; with fold_items
+    #                           new_users, width, mode, placements: the
+    #                           host -> device placements the thread
+    #                           made for the batch, counter
+    #                           live.host_placements; with fold_items
     #                           also items, new_items, segment_rows)
     "live.batch.foldin",      # FoldInServer.update (+ update_items)
     "live.batch.publish",     # ServingEngine.publish_update (with
@@ -561,9 +578,13 @@ LIVE_HISTORY_SCOPE = "live.publish.history"
 # ``.foldin`` and ``.publish`` also carry ``cpu_us`` and ``wall_us`` (as
 # the engine's phases above, under a profiler)
 LIVE_FOLDIN_SPAN_KEYS = (
-    "live.batch.foldin.readback",  # the fold-in program called and its
-    #                                rows read back: blocks on the
-    #                                device (side: users | items)
+    "live.batch.foldin.readback",  # the fold-in program called — its
+    #                                ids, stars and mask ride the call
+    #                                as ONE host array: no upload of
+    #                                their own since PR 49 (three
+    #                                before) — and its rows read back:
+    #                                blocks on the device (side: users
+    #                                | items)
 )
 
 # field names every flight record (and its flight_record event) claims

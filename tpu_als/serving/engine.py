@@ -195,7 +195,7 @@ from jax._src.interpreters.pxla import batched_device_put
 from jax.profiler import TraceAnnotation
 
 from tpu_als import obs
-from tpu_als.core.foldin import place_rows
+from tpu_als.core.foldin import padded_rows, place_rows, put
 from tpu_als.core.ratings import (
     LIVE_PADS,
     growth_pads,
@@ -228,6 +228,7 @@ from tpu_als.serving.batcher import (
     bucket_for,
 )
 from tpu_als.serving.index import (
+    SEGMENT_SENT,
     Int8CandidateIndex,
     ShardedInt8Index,
     _build_sharded_int8,
@@ -246,6 +247,16 @@ from tpu_als.serving.index import (
 # read back and one dispatched behind it.  The engine thread waits for
 # the older to complete before it dequeues a third
 MAX_IN_FLIGHT = 2
+
+
+# A publish's ONE host array (:class:`_Ride`), ``int32[PUBLISH_SENT,
+# pad]``: its first row the user-table rows, from ``SENT_SEGMENT`` on what
+# the segment's write takes (``serving.index._write_segment``), its last
+# ``SENT_PLAN`` rows :func:`_append_runs`' plan.  Always all of them — a
+# publish without a part leaves its rows unread — so that a write program
+# meets few shapes
+SENT_SEGMENT, SENT_PLAN = 1, 5
+PUBLISH_SENT = SENT_SEGMENT + SEGMENT_SENT + SENT_PLAN
 
 
 # catalog ids a request may bring of its own (``submit(exclude=...)``):
@@ -302,10 +313,11 @@ class _Seen:
 class _Append(NamedTuple):
     """What one publish adds to a grown table of histories
     (:meth:`ServingEngine._plan_append`): :func:`_append_runs`' ``plan``
-    on the device, the full runs to move first as ``(old, new,
-    width)``, the touched users with their runs' starts, room and
-    lengths afterwards, where the free room then begins, the bytes
-    sent."""
+    (on the host as planned; on the device — by itself or as the last
+    rows of the publish's one array, :class:`_Ride` — when it is
+    written), the full runs to move first as ``(old, new, width)``, the
+    touched users with their runs' starts, room and lengths afterwards,
+    where the free room then begins, the bytes sent."""
 
     args: object
     moves: list
@@ -315,6 +327,26 @@ class _Append(NamedTuple):
     lengths: np.ndarray
     free: int
     sent: int
+
+
+class _Ride:
+    """What of one ``publish_update`` rides its ONE host array
+    (``PUBLISH_SENT``), where the caller holds the fold's rows on the
+    device and only integers are left to send: the user-table ``rows``
+    (in the order of the device's rows, padded as they are), the
+    segment's ``SegmentUpdate.sent`` and the history's ``plan``, each
+    ``None`` where the publish has no such part — and ``sent``, the
+    array on the device once :meth:`ServingEngine._send` has placed it:
+    one placement a publish, made before ``_table_lock`` is taken."""
+
+    rows = segment = plan = sent = None
+
+    @property
+    def wanted(self):
+        """Whether a part that can only ride waits to be sent (the
+        plan alone goes up by itself, as it did)."""
+        return self.sent is None and (self.rows is not None
+                                      or self.segment is not None)
 
 
 class _Room:
@@ -559,8 +591,10 @@ def _append_runs(start, count, indices, plan):
     the positions ``at`` they go to — behind their users' runs, in room
     no count reaches yet.  Everything is padded up ``pad_for``'s ladder
     with entries outside the arrays (``mode='drop'``): few programs, and
-    a publish sends O(ids appended)."""
-    users, starts, counts, at, ids = plan
+    a publish sends O(ids appended).  The plan is the LAST five rows of
+    what it is given: a publish that sends more in the same array
+    (:class:`_Ride`) hands over all of it."""
+    users, starts, counts, at, ids = plan[-SENT_PLAN:]
     with jax.named_scope(LIVE_HISTORY_SCOPE):
         return (start.at[users].set(starts, mode="drop"),
                 count.at[users].set(counts, mode="drop"),
@@ -600,9 +634,37 @@ def _scatter_users(U, rows, vals):
     away from the engine thread (``ServingEngine._table_lock``).
     ``rows`` are padded up ``pad_for``'s ladder with an out-of-range
     sentinel (``mode='drop'``), so the programs are few and only the
-    touched payload crosses host→device."""
+    touched payload crosses host→device.  ``rows`` may be a publish's
+    whole host array (:class:`_Ride`, whose FIRST row they are) where
+    ``vals`` lay on the device already: its first ``len(vals)`` entries
+    are read."""
     with jax.named_scope("live.publish.scatter"):
-        return U.at[rows].set(vals, mode="drop")
+        return U.at[rows.reshape(-1)[:vals.shape[0]]].set(vals, mode="drop")
+
+
+def _ride_shapes(max_rows, mesh=None):
+    """``(pad of the publish's one array, pad of a side's rows on the
+    device)`` for every shape a write program meets in publishes of up to
+    ``max_rows`` rows a side (:class:`_Ride`): the array is as wide as
+    its widest part, and the history's plan may carry one id more than
+    the batch has rows (those that waited for a row).  None on a mesh,
+    where every side goes up from the host."""
+    return [] if mesh is not None else [
+        (pad, rows) for pad in pads_up_to(max_rows + 1)
+        for rows in pads_up_to(max_rows) if rows <= pad]
+
+
+def _same_rows(placed, rows, rank):
+    """Whether ``placed`` — a caller's ``(rows, their values on the
+    device)``, or ``None`` — holds exactly the table rows ``rows``
+    (ascending, none twice), padded up ``pad_for``'s ladder as the
+    programs that were run ahead expect."""
+    if placed is None:
+        return False
+    mine, vals = placed
+    return (vals.shape == (pad_for(len(rows)), rank)
+            and len(mine) == len(rows)
+            and np.array_equal(np.sort(mine), rows))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -844,14 +906,19 @@ class ServingEngine:
                            mesh=self.mesh).block_until_ready(),
                 n, 4 * n * rank)
 
-    def _update_users(self, prev, U, touched_users):
+    def _update_users(self, prev, U, touched_users, placed, ride):
         """What a ``publish_update`` does to the user table: ``(how,
         users, live rows, bytes sent)``.  ``inplace``: the
         ``touched_users`` rows of ``U`` (and the rows appended since)
         are uploaded alone — O(touched) host work and traffic, no shape
         change — and ``users`` is the ``(rows, vals)`` that
         :func:`_scatter_users` writes into the live table, which the
-        caller does under ``_table_lock``.  ``carried``: no row to
+        caller does under ``_table_lock``.  With ``placed``, the
+        caller's ``(rows, their values on the device, padded)``, and
+        where those are the rows this publish writes (and no mesh),
+        nothing is uploaded here: the row numbers ride ``ride`` and
+        ``users`` is ``(None, the device's rows)`` until
+        :meth:`_send` has placed them.  ``carried``: no row to
         write, ``users`` is the live table.  ``replaced``: ``users`` is a
         new table from :meth:`_place_users`, where a row write cannot be
         (no row list, no live generation, another rank, a shrunken
@@ -866,14 +933,16 @@ class ServingEngine:
             if not rows.size:
                 return "carried", prev.U, n, 0
             if 0 <= int(rows[0]) and int(rows[-1]) < n:
+                if self.mesh is None and _same_rows(placed, rows, rank):
+                    ride.rows = padded_rows(placed[0], placed[1].shape[0],
+                                            prev.U)
+                    return "inplace", (None, placed[1]), n, 0
                 pad = pad_for(len(rows))
                 # the sentinel lies outside the table: dropped
-                rp = np.full(pad, prev.U.shape[0], dtype=np.int32)
-                rp[:len(rows)] = rows
+                rp = padded_rows(rows, pad, prev.U)
                 vals = np.zeros((pad, rank), dtype=np.float32)
                 vals[:len(rows)] = U[rows]
-                return ("inplace",
-                        jax.device_put((rp, vals), self._replicated),
+                return ("inplace", put((rp, vals), self._replicated),
                         n, rp.nbytes + vals.nbytes)
         if touched_users is not None and prev is not None:
             obs.emit("warning", what="serving.publish_update",
@@ -1073,8 +1142,10 @@ class ServingEngine:
         holds histories, up to the write (which is :meth:`_swap`'s): the
         plan of :meth:`_plan_append` for ``appended`` (``None``: no id,
         the table carried as it is; ids below ``n_items``, the catalog's
-        size as this publish leaves it), its uploads made, inside the span
-        ``live.batch.publish.history`` and counted.  Where the table has
+        size as this publish leaves it), inside the span
+        ``live.batch.publish.history`` and counted; the plan is still
+        on the host (:meth:`_place_plan` sends it, alone or riding the
+        publish's one array).  Where the table has
         no room for the plan — nobody laid it out to grow, or the room
         is used up — it is laid out anew first (:meth:`_lay_out`: O(all
         the histories), shapes change and the pinned programs go stale;
@@ -1111,7 +1182,6 @@ class ServingEngine:
             stamp_cpu(span, mark)
         obs.counter("live.history_appended_ids", ids, **self._labels)
         obs.counter("live.history_relocations", moves, **self._labels)
-        obs.counter("live.history_h2d_bytes", plan.sent, **self._labels)
         return plan
 
     def _plan_append(self, seen, n_users, n_items, appended):
@@ -1159,9 +1229,42 @@ class ServingEngine:
         plan[3, :n] = (np.repeat(start + had, added) + np.arange(n)
                        - np.repeat(first, added))
         plan[4, :n] = items
-        return _Append(jax.device_put(plan), moves, uniq, start, cap,
-                       had + added, free,
+        return _Append(plan, moves, uniq, start, cap, had + added, free,
                        sent=plan.nbytes + 8 * len(moves))
+
+    def _send(self, ride, seen, pad=0):
+        """``ride``'s parts as ONE ``int32[PUBLISH_SENT, pad]`` on the
+        device (``pad``: the widest part's, or wider for whoever runs
+        the programs ahead; a part's padding entries lie outside its
+        arrays — ``seen``'s, for the plan — and a part the publish lacks
+        is never read): the one placement of a publish whose rows lie on
+        the device."""
+        parts = [a for a in (ride.rows, ride.segment, ride.plan)
+                 if a is not None]
+        sent = np.zeros(
+            (PUBLISH_SENT, max(pad, *(a.shape[-1] for a in parts))),
+            np.int32)
+        if ride.rows is not None:
+            sent[0, :len(ride.rows)] = ride.rows
+        if ride.segment is not None:
+            sent[SENT_SEGMENT:SENT_SEGMENT + SEGMENT_SENT,
+                 :ride.segment.shape[1]] = ride.segment
+        if ride.plan is not None:
+            sent[-SENT_PLAN:] = self._no_append(seen, sent.shape[1])
+            sent[-SENT_PLAN:, :ride.plan.shape[1]] = ride.plan
+        ride.sent = put(sent)
+        return ride.sent
+
+    def _place_plan(self, appended, ride):
+        """``appended`` (:meth:`_append_history`) with its plan on the
+        device: the publish's one array where there is one, else
+        uploaded by itself; ``sent`` is what it added to the link."""
+        if ride.sent is None:
+            return appended._replace(args=put(appended.args))
+        return appended._replace(
+            args=ride.sent,
+            sent=appended.sent + 4 * SENT_PLAN * (
+                ride.sent.shape[1] - appended.args.shape[1]))
 
     @staticmethod
     def _no_append(seen, pad):
@@ -1287,12 +1390,17 @@ class ServingEngine:
                  **self._labels)
         return seq
 
-    def _write_catalog(self, Vh, rows, item_valid, seq):
+    def _write_catalog(self, Vh, rows, item_valid, seq, placed, ride):
         """The item side of a ``publish_update`` with a live index:
         ``rows`` of the host's catalog ``Vh`` (touched and appended, in
         order) uploaded alone and written into the index's delta segment
         — folded into the base first where it has no room for them —
-        and handed on, on the device, for the engine's own table.
+        and handed on, on the device, for the engine's own table.  With
+        ``placed``, the caller's ``(rows, their values on the device,
+        padded)``, and where those are the rows this publish writes, no
+        row is uploaded: the segment is written from the device's rows,
+        and what its write takes from the host rides ``ride``, which is
+        sent here (:meth:`_send`).
         Returns ``(index, the (rows, vals, ok) that _swap writes in
         place or None where that table cannot take them, bytes sent,
         whether a compaction ran)``; a row outside the catalog raises
@@ -1302,7 +1410,6 @@ class ServingEngine:
         if int(rows[-1]) >= Ni:
             raise ValueError(f"touched row {int(rows[-1])} outside "
                              f"the catalog [0, {Ni})")
-        vrs = np.ascontiguousarray(Vh[rows], dtype=np.float32)
         vls = (np.ones(len(rows), dtype=bool)
                if item_valid is None else item_valid[rows])
         slots, compacted = self._segment_slots(cur), False
@@ -1315,8 +1422,20 @@ class ServingEngine:
                 raise ValueError("the index was lost in its compaction")
         if cur.delta_slots < slots:     # an engine nobody warmed up
             cur = cur.reserve(slots=slots)
-        index = cur.with_updates(rows, vrs, valid_rows=vls, seq=seq)
-        sent, items = segment_write_bytes(len(rows), prev.rank), None
+        if self.mesh is None and _same_rows(placed, rows, prev.rank):
+            update, _ = cur.plan_update(rows, vls)
+            ride.segment = update.sent(
+                placed[1].shape[0], np.searchsorted(update.ids, placed[0]))
+            index = cur.write_update(
+                update, self._send(ride, prev.seen), placed[1],
+                at=SENT_SEGMENT, seq=seq)
+            sent = 4 * SEGMENT_SENT * ride.sent.shape[1]
+        else:
+            index = cur.with_updates(
+                rows, np.ascontiguousarray(Vh[rows], dtype=np.float32),
+                valid_rows=vls, seq=seq)
+            sent = segment_write_bytes(len(rows), prev.rank)
+        items = None
         cap = int(prev.V.shape[0])
         if self.mesh is None and prev.n_items <= Ni <= cap:
             # the engine's own catalog takes the same rows, as they
@@ -1331,7 +1450,7 @@ class ServingEngine:
 
     def publish_update(self, U, V, *, touched_items=None,
                        touched_users=None, item_valid=None, trace=None,
-                       seen_appended=None):
+                       seen_appended=None, device_rows=None):
         """Incremental publish after a fold-in: O(touched rows), not
         O(catalog).  Returns ``(seq, mode)``.
 
@@ -1407,6 +1526,24 @@ class ServingEngine:
         histories are laid out to grow by :meth:`warmup_live` /
         :meth:`warmup_histories`; on an engine nobody warmed up the first
         such publish does it, under the traffic, with a warning.
+
+        ``device_rows``: for a caller that still holds the rows it
+        names ON THE DEVICE (``FoldInServer.last_rows``: a fold's own
+        result), ``{"users": (rows, vals), "items": (rows, vals)}``,
+        either or both — ``vals`` a device array ``[pad, rank]`` up
+        ``pad_for``'s ladder, its first ``len(rows)`` rows the new
+        values of table rows ``rows`` (any order, none twice), the same
+        bits as ``U[rows]`` / ``V[rows]``.  Where a side's ``rows`` are
+        exactly the rows this publish writes on that side, they are
+        written from the device — into the user table, the segment
+        (quantized there) and the engine's catalog — and nothing of
+        them crosses host→device again: all that goes up is ONE
+        ``int32[PUBLISH_SENT, pad]`` (row numbers, slots and valid
+        bits, the history's plan; :class:`_Ride`), placed before
+        ``_table_lock`` is taken.  A side without them, or whose rows
+        differ (users appended in between, a fold that took several
+        calls), and every publish on a mesh, goes up from the host as
+        above.  Same generation, same bits either way.
         """
         prev = self._model
         if seen_appended is not None and (prev is None
@@ -1428,16 +1565,19 @@ class ServingEngine:
         touched = (np.empty(0, dtype=np.int64) if touched_items is None
                    else np.unique(np.asarray(touched_items,
                                              dtype=np.int64).ravel()))
+        placed, ride = device_rows or {}, _Ride()
         with self._publish_lock:
             seq = self._seq + 1
             prev = self._model
             how, users, n_users, h2d = self._update_users(
-                prev, U, touched_users)
+                prev, U, touched_users, placed.get("users"), ride)
             # planned against the catalog as THIS publish leaves it: an
             # id it appends may name an item it appends
             appended = (None if prev is None or prev.seen is None
                         else self._append_history(n_users, Ni,
                                                   seen_appended))
+            if appended is not None:
+                ride.plan = appended.args
             cur = prev.index if prev is not None else None
             fresh = (cur is not None and cur.seq == prev.seq
                      and cur.n_items <= Ni)
@@ -1463,7 +1603,8 @@ class ServingEngine:
             elif fresh:
                 try:
                     index, items, sent, compacted = self._write_catalog(
-                        Vh, rows, item_valid, seq)
+                        Vh, rows, item_valid, seq, placed.get("items"),
+                        ride)
                     mode, catalog, prev = "delta", "delta", self._model
                     V, valid = prev.V, prev.valid
                 except ValueError as e:
@@ -1487,6 +1628,19 @@ class ServingEngine:
             in_segment = (0 if seen_appended is None or index is None
                           else int(np.isin(seen_appended[1],
                                            index.d_rows).sum()))
+            if ride.wanted:
+                self._send(ride, self._model.seen)
+            if ride.sent is not None:
+                # the user table's counter takes what no other part
+                # accounts for: the rows' own row and the unread ones
+                h2d += 4 * ride.sent.shape[1] * (
+                    PUBLISH_SENT
+                    - (SEGMENT_SENT if ride.segment is not None else 0)
+                    - (SENT_PLAN if ride.plan is not None else 0))
+            if ride.rows is not None:
+                users = (ride.sent, users[1])
+            if appended is not None:
+                appended = self._place_plan(appended, ride)
             how = self._swap(how, users, seq, n_users, V, valid, index, Ni,
                              host=U, items=items, appended=appended)
             self._seq = seq
@@ -1503,8 +1657,10 @@ class ServingEngine:
         obs.counter("serving.catalog_writes", how=catalog, **self._labels)
         obs.counter("live.publish_h2d_bytes", h2d, **self._labels)
         obs.counter("live.catalog_h2d_bytes", sent, **self._labels)
-        if prev is not None and prev.seen is not None:
+        if appended is not None:
             obs.counter("live.history_segment_ids", in_segment,
+                        **self._labels)
+            obs.counter("live.history_h2d_bytes", appended.sent,
                         **self._labels)
         obs.histogram("serving.publish_seconds",
                       time.perf_counter() - t0, mode=mode,
@@ -1751,7 +1907,8 @@ class ServingEngine:
     def warmup_publish(self, max_rows=LIVE_PADS[-1]):
         """Compile AND run the user-row writes ``publish_update(
         touched_users=...)`` makes, for up to ``max_rows`` rows a publish
-        (padded 8 / 64 / 512 ...), on the published table itself: each
+        (padded 8 / 64 / 512 ...; from the host's rows and from rows on
+        the device, ``device_rows``), on the published table itself: each
         run writes nothing (every row the out-of-range sentinel), and its
         result, the same buffer with the same values, is installed as
         the live generation's table, since the write deleted the handle
@@ -1761,12 +1918,22 @@ class ServingEngine:
             if m is None:
                 raise NoModelPublished("publish(U, V) before warmup")
             for pad in pads_up_to(max_rows):
+                nothing = padded_rows((), pad, m.U)
                 self._swap(
                     "inplace",
-                    jax.device_put(
-                        (np.full(pad, m.U.shape[0], np.int32),
-                         np.zeros((pad, m.rank), np.float32)),
+                    put((nothing, np.zeros((pad, m.rank), np.float32)),
                         self._replicated),
+                    m.seq, m.n_users, m.V, m.valid, m.index, m.n_items,
+                    seen=m.seen)
+            # and from rows on the device, their numbers riding a
+            # publish's one array, as wide or wider
+            for pad, rows in _ride_shapes(max_rows, self.mesh):
+                ride = _Ride()
+                ride.rows = padded_rows((), rows, m.U)
+                self._swap(
+                    "inplace",
+                    (self._send(ride, None, pad),
+                     jnp.zeros((rows, m.rank), jnp.float32)),
                     m.seq, m.n_users, m.V, m.valid, m.index, m.n_items,
                     seen=m.seen)
             self._model.U.block_until_ready()
@@ -1833,11 +2000,20 @@ class ServingEngine:
             if self.mesh is None:
                 for pad in pads_up_to(max_rows):
                     # every row the out-of-range sentinel: nothing written
-                    V, valid = _scatter_items(V, valid, *jax.device_put((
-                        np.full(pad, V.shape[0], np.int32),
+                    V, valid = _scatter_items(V, valid, *put((
+                        padded_rows((), pad, V),
                         np.zeros((pad, m.rank), np.float32),
                         np.zeros(pad, bool))))
             idx = idx.prewarm(max_rows)
+            # the segment's write from rows on the device, what it takes
+            # from the host riding a publish's one array
+            nothing = idx.plan_update(())[0]
+            for pad, rows in _ride_shapes(max_rows, self.mesh):
+                ride = _Ride()
+                ride.segment = nothing.sent(rows)
+                idx = idx.write_update(
+                    nothing, self._send(ride, None, pad),
+                    jnp.zeros((rows, m.rank), jnp.float32), at=SENT_SEGMENT)
             m = self._model = _Published(m.seq, m.U, m.n_users, V, valid,
                                          idx, m.n_items, m.seen)
             if m.seen is not None:
@@ -1887,8 +2063,12 @@ class ServingEngine:
             seen = self._lay_out(seen, int(m.U.shape[0]))
         runs, indices, room = seen.runs, seen.indices, seen.room
         for pad in pads_up_to(max_rows):
-            *runs, indices = _append_runs(*runs, indices, jax.device_put(
-                self._no_append(seen, pad)))
+            # the plan by itself, and as the last rows of a publish's
+            # one array
+            ride = _Ride()
+            ride.plan = self._no_append(seen, pad)
+            for plan in (put(ride.plan), self._send(ride, seen)):
+                *runs, indices = _append_runs(*runs, indices, plan)
         for width in seen.pads:
             indices = _move_run(indices, 0, 0, width=width)  # onto itself
         m = self._model = _Published(

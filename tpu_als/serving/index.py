@@ -102,11 +102,13 @@ from __future__ import annotations
 
 import copy
 import functools
+import typing
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_als.core.foldin import put
 from tpu_als.core.ratings import _next_pow2, pad_for, pads_up_to
 from tpu_als.obs.schema import SERVE_MESH_SCOPES
 from tpu_als.ops.topk import (
@@ -284,22 +286,61 @@ def shortlist_rescore(U, Vq, sv, V, valid, *, k, shortlist_k, delta=None,
 _topk_jit = jax.jit(shortlist_rescore, static_argnames=("k", "shortlist_k"))
 
 
-@jax.jit
-def _write_segment(drows, dVq, dsv, dV, dvalid, slots, ids, rows, valid):
-    """``rows`` (f32, with their catalog ``ids`` and ``valid`` bits)
-    into ``slots`` of the delta segment, quantized here — per row, so
-    bit for bit what a rebuild of the whole catalog would hold.  The
-    update is padded up ``pad_for``'s ladder with slots outside the
-    segment, which are dropped: few programs, and only the touched
-    payload crosses host→device.  Nothing is donated: the generation
-    before scores against its own segment, and a segment is a few MB."""
+# rows of the host array a segment write takes: each row's slot, its
+# catalog id, its valid bit, and (all along the fourth) the catalog's last
+# id afterwards
+SEGMENT_SENT = 4
+
+
+@functools.partial(jax.jit, static_argnames=("at",))
+def _write_segment(drows, dVq, dsv, dV, dvalid, sent, rows, *, at=0):
+    """``rows`` (f32 ``[n, rank]``, from the host or, a fold's own
+    result, on the device already) into their slots of the delta segment,
+    quantized here — per row, so bit for bit what a rebuild of the whole
+    catalog would hold.  What the write needs from the host is ONE
+    array, ``sent``: ``int32``, its rows ``at`` to ``at + SEGMENT_SENT``
+    the slots, the catalog ids, the valid bits and the catalog's last id
+    (``at``: whoever sends more in the same array says where these lie),
+    of which the first ``n`` columns are read.  The update is padded up
+    ``pad_for``'s ladder with slots outside the segment, which are
+    dropped: few programs, and only the touched payload crosses
+    host→device.  Nothing is donated: the generation before scores
+    against its own segment, and a segment is a few MB.  Returns the
+    segment and, for whoever writes the same rows elsewhere or clamps
+    answers to the catalog, ``(ids, valid bits, last id)`` on the
+    device."""
+    slots, ids, ok = (sent[at + i, :rows.shape[0]] for i in range(3))
+    valid = ok != 0
     with jax.named_scope("live.publish.scatter_items"):
         q, s = _quantize_rows(rows)
         return (drows.at[slots].set(ids, mode="drop"),
                 dVq.at[slots].set(q, mode="drop"),
                 dsv.at[slots].set(s, mode="drop"),
                 dV.at[slots].set(rows, mode="drop"),
-                dvalid.at[slots].set(valid, mode="drop"))
+                dvalid.at[slots].set(valid, mode="drop"),
+                ids, valid, sent[at + 3, 0])
+
+
+class SegmentUpdate(typing.NamedTuple):
+    """The host's side of one update of the delta segment
+    (:meth:`Int8CandidateIndex.plan_update`), before anything is sent."""
+
+    ids: np.ndarray     # the catalog ids, ascending, none twice
+    slots: np.ndarray   # the slot each one takes (its own, or a free one)
+    ok: np.ndarray      # the valid bit of each
+    n_items: int        # the catalog's size afterwards
+    d_rows: np.ndarray  # the segment's ids by slot afterwards
+
+    def sent(self, pad, order=None):
+        """:func:`_write_segment`'s ``sent`` for rows that lie in
+        ``order`` (indices into ``ids``; as ``ids`` without), padded to
+        ``pad`` with slots and ids outside every array."""
+        o, n = slice(None) if order is None else order, len(self.ids)
+        sent = np.full((SEGMENT_SENT, pad), SLOT_FREE, dtype=np.int32)
+        sent[0, :n], sent[1, :n] = self.slots[o], self.ids[o]
+        sent[2, :n], sent[2, n:] = self.ok[o], 0
+        sent[3] = self.n_items - 1
+        return sent
 
 
 def _fold_segment(V, Vq, sv, valid, drows, dVq, dsv, dV, dvalid):
@@ -324,10 +365,10 @@ _fold_segment_copied = jax.jit(_fold_segment)
 
 def segment_write_bytes(n_rows, rank):
     """Bytes :meth:`Int8CandidateIndex.with_updates` uploads for
-    ``n_rows`` touched rows: slot, id, f32 row and valid bit of each,
-    padded up the ladder (the engine's own table takes the same arrays
-    on the device: no second upload)."""
-    return pad_for(n_rows) * (4 + 4 + 4 * int(rank) + 1)
+    ``n_rows`` touched rows: :func:`_write_segment`'s ``sent`` and the
+    f32 rows, padded up the ladder (the engine's own table takes the
+    same arrays on the device: no second upload)."""
+    return pad_for(n_rows) * 4 * (SEGMENT_SENT + int(rank))
 
 
 class Int8CandidateIndex:
@@ -380,7 +421,7 @@ class Int8CandidateIndex:
     @staticmethod
     def _put(arrays):
         """Host arrays onto the device(s) the segment lives on."""
-        return jax.device_put(arrays)
+        return put(arrays)
 
     @property
     def n_base(self):
@@ -470,13 +511,12 @@ class Int8CandidateIndex:
         rows = np.unique(np.asarray(rows, dtype=np.int64).ravel())
         return int(rows.size - self._held(rows)[0].sum())
 
-    def _checked_update(self, rows, V_rows, valid_rows):
-        """``(ids ascending, none twice; their rows; their valid bits;
-        the catalog's size afterwards)`` of an update to ``rows`` (at
-        least one), or ``ValueError``: a negative id, or appended ids
-        that leave a hole above the catalog."""
-        V_rows = np.asarray(V_rows, dtype=np.float32).reshape(
-            len(rows), int(self.V.shape[1]))
+    def _checked_update(self, rows, valid_rows):
+        """``(ids ascending, none twice; where in ``rows`` each one's LAST
+        mention stands; their valid bits; the catalog's size afterwards)``
+        of an update to ``rows`` (at least one), or ``ValueError``: a
+        negative id, or appended ids that leave a hole above the
+        catalog."""
         valid_rows = (np.ones(len(rows), dtype=bool) if valid_rows is None
                       else np.asarray(valid_rows, dtype=bool).ravel())
         if rows.min() < 0:
@@ -492,7 +532,54 @@ class Int8CandidateIndex:
             raise ValueError(
                 f"append gap: ids {gap} missing — appended rows must "
                 "be contiguous above the current catalog")
-        return uniq, V_rows[last], valid_rows[last], n_new
+        return uniq, last, valid_rows[last], n_new
+
+    def _host_rows(self, V_rows, n):
+        """``V_rows`` as the ``n`` f32 rows of an update."""
+        return np.asarray(V_rows, dtype=np.float32).reshape(
+            n, int(self.V.shape[1]))
+
+    def plan_update(self, rows, valid_rows=None):
+        """``(SegmentUpdate, where in ``rows`` each of its ids' LAST
+        mention stands)`` of an update to catalog ``rows`` (see
+        :meth:`with_updates`; none: an update that writes nothing): the
+        host's side alone, nothing sent, nothing written.  A row the
+        segment holds keeps its slot, the others take the next free
+        ones."""
+        rows = np.asarray(rows, dtype=np.int64).ravel()
+        if len(rows) == 0:
+            none = np.empty(0, dtype=np.int64)
+            return SegmentUpdate(none, none, none.astype(bool),
+                                 self.n_items, self.d_rows), none
+        ids, last, ok, n_new = self._checked_update(rows, valid_rows)
+        held, slots = self._held(ids)
+        slots[~held] = self.delta_count + np.arange(int((~held).sum()))
+        return SegmentUpdate(ids, slots, ok, n_new, np.concatenate(
+            [self.d_rows, ids[~held]])), last
+
+    def write_update(self, update, sent, rows, at=0, seq=None):
+        """A new index with ``update`` (:meth:`plan_update`) written:
+        ``sent`` is its :meth:`SegmentUpdate.sent` on the device — rows
+        ``at`` to ``at + SEGMENT_SENT`` of a larger array, where its
+        sender had more to send — and ``rows`` the f32 rows in the same
+        order, on the device, padded (uploaded with it, or a fold's own
+        result: nothing of them crosses host→device then).  A segment
+        without room for the new rows is enlarged to the next power of
+        two."""
+        new = self._copy_shell(seq)
+        new.n_items, new.d_rows = update.n_items, update.d_rows
+        seg = self._seg
+        if len(new.d_rows) > self.delta_slots:
+            seg = self._with_slots(_next_pow2(len(new.d_rows)))
+        *new._seg, ids, ok, last = _write_segment(*seg, sent, rows, at=at)
+        new._seg = tuple(new._seg)
+        # the rows as they were written — ``(ids, rows, valid bits)``,
+        # padded with ids outside any table — for whoever writes them
+        # elsewhere too (the engine's own catalog): nothing is uploaded
+        # twice; and the last catalog id, which came up with them
+        new.written = (ids, rows, ok)
+        new._last = (new.n_items, last)
+        return new
 
     def with_updates(self, rows, V_rows, valid_rows=None, seq=None):
         """A new index with ``rows`` of the catalog re-quantized into
@@ -508,40 +595,20 @@ class Int8CandidateIndex:
         contract).  A segment without room for the new rows is enlarged
         to the next power of two (new shapes: whoever must not compile
         under traffic calls :meth:`reserve` ahead and :meth:`compact`
-        before the segment overflows, as the engine does).
+        before the segment overflows, as the engine does).  ONE
+        placement: the rows with :func:`_write_segment`'s ``sent``
+        (:meth:`plan_update` + :meth:`write_update`, which whoever holds
+        the rows on the device calls itself).
         """
         rows = np.asarray(rows, dtype=np.int64).ravel()
         if len(rows) == 0:
             return self._copy_shell(seq)
-        rows, V_rows, valid_rows, n_new = self._checked_update(
-            rows, V_rows, valid_rows)
-        r = int(self.V.shape[1])
-        # a row the segment holds keeps its slot, the others take the
-        # next free ones
-        held, slots = self._held(rows)
-        slots = slots.astype(np.int32)
-        slots[~held] = self.delta_count + np.arange(int((~held).sum()))
-        new = self._copy_shell(seq)
-        new.n_items = n_new
-        new.d_rows = np.concatenate([self.d_rows, rows[~held]])
-        seg = self._seg
-        if len(new.d_rows) > self.delta_slots:
-            seg = self._with_slots(_next_pow2(len(new.d_rows)))
-        pad = pad_for(len(rows))
-        sl = np.full(pad, SLOT_FREE, dtype=np.int32)
-        ids = np.full(pad, SLOT_FREE, dtype=np.int32)
-        vals = np.zeros((pad, r), dtype=np.float32)
-        ok = np.zeros(pad, dtype=bool)
-        sl[:len(rows)], ids[:len(rows)] = slots, rows
-        vals[:len(rows)], ok[:len(rows)] = V_rows, valid_rows
-        sl, *written = self._put((sl, ids, vals, ok))
-        new._seg = _write_segment(*seg, sl, *written)
-        # the rows as they went up — ``(ids, rows, valid bits)``, padded
-        # with ids outside any table — for whoever writes them elsewhere
-        # too (the engine's own catalog): nothing is uploaded twice
-        new.written = tuple(written)
-        new._last_id()      # here, not on the request path
-        return new
+        update, last = self.plan_update(rows, valid_rows)
+        pad = pad_for(len(update.ids))
+        vals = np.zeros((pad, int(self.V.shape[1])), dtype=np.float32)
+        vals[:len(last)] = self._host_rows(V_rows, len(rows))[last]
+        return self.write_update(
+            update, *self._put((update.sent(pad), vals)), seq=seq)
 
     def compact(self, seq=None):
         """Fold the delta segment back into the base arrays, IN PLACE.
@@ -586,11 +653,10 @@ class Int8CandidateIndex:
         given, the same buffers with the same values come back."""
         new = self._copy_shell(None)
         r = int(self.V.shape[1])
+        nothing = self.plan_update(())[0]
         for pad in pads_up_to(max_rows):
-            free = np.full(pad, SLOT_FREE, dtype=np.int32)
-            new._seg = _write_segment(*new._seg, *self._put((
-                free, free, np.zeros((pad, r), np.float32),
-                np.zeros(pad, bool))))
+            new = new.write_update(nothing, *self._put((
+                nothing.sent(pad), np.zeros((pad, r), np.float32))))
         new.V, new.Vq, new.sv, new.valid, _, _ = new._fold(
             jnp.full_like(new._seg[0], SLOT_FREE))
         return new
@@ -829,7 +895,7 @@ class ShardedInt8Index(Int8CandidateIndex):
         self._clear_delta()
 
     def _put(self, arrays):
-        return jax.device_put(arrays, jax.sharding.NamedSharding(
+        return put(arrays, jax.sharding.NamedSharding(
             self.mesh, jax.sharding.PartitionSpec()))
 
     def shortlist_plan(self, rows=None):
@@ -860,8 +926,10 @@ class ShardedInt8Index(Int8CandidateIndex):
         so there is no incremental path — rebuild the sharded base at
         the grown size (O(catalog), the rare capacity-crossing publish;
         within capacity :meth:`with_updates` stays O(touched))."""
-        rows, V_rows, valid_rows, n_new = self._checked_update(
-            rows, V_rows, valid_rows)
+        V_rows = self._host_rows(V_rows, len(rows))
+        rows, last, valid_rows, n_new = self._checked_update(rows,
+                                                             valid_rows)
+        V_rows = V_rows[last]
         base = self.compact() if self.d_rows.size else self
         V_full = np.zeros((n_new, int(self.V.shape[1])), dtype=np.float32)
         V_full[:self.n_items] = np.asarray(base.V)[:self.n_items]

@@ -84,7 +84,14 @@ import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
 from tpu_als import obs
-from tpu_als.core.foldin import fold_in, place_rows, solve_path, write_rows
+from tpu_als.core.foldin import (
+    fold_in,
+    place_rows,
+    planes,
+    solve_path,
+    write_placed_rows,
+    write_rows,
+)
 from tpu_als.core.ratings import (
     LIVE_PADS,
     growth_pads,
@@ -137,6 +144,14 @@ class FoldInServer:
         # and again, events of items it left to the refit (module
         # docstring: the item side of a server with a base history)
         self.last_items = {"first": 0, "again": 0, "left_to_refit": 0}
+        # of the last ``update`` ("users") and ``update_items`` ("items"):
+        # ``(table rows, their new factors ON THE DEVICE)`` — the fold
+        # program's own result, padded up the rows' ladder, its first
+        # ``len(rows)`` rows those of ``rows`` — for whoever writes the
+        # same rows into another table on the device
+        # (``ServingEngine.publish_update(device_rows=...)``); ``None``
+        # where nothing was folded or the fold took several calls
+        self.last_rows = {"users": None, "items": None}
         # original id -> (fixed-side ORIGINAL ids, ratings), in arrival
         # order
         self._history = {}
@@ -254,26 +269,24 @@ class FoldInServer:
                     for w in widths:
                         if n > (self._rows_at(w) or n):
                             continue
-                        fold_in(
-                            F,
-                            jnp.zeros((n, w), jnp.int32),
-                            jnp.zeros((n, w), jnp.float32),
-                            jnp.zeros((n, w), jnp.float32),
-                            self._reg, implicit_prefs=self._implicit,
-                            alpha=self._alpha,
-                            nonnegative=self._nonnegative,
-                            YtY=YtY,
-                        ).block_until_ready()
+                        # host arrays, as ``_fold_batch`` hands them over
+                        self._fold(F, planes(np.zeros((3, n, w), np.int32)),
+                                   YtY).block_until_ready()
                         obs.emit("foldin_solve_path", side=side,
                                  rank=int(F.shape[1]), rows=n, width=w,
                                  path=path, reason=why)
         if self._Ud is not None:
             # both directions fold: each one's write-back also writes
             # its rows into the other's fixed table; those programs now
+            # (from the host's rows and from the fold's on the device:
+            # one program, both forms of its call)
             none = np.empty((0, m._U.shape[1]), np.float32)
             for n in rows:
+                placed = jnp.zeros((n, m._U.shape[1]), jnp.float32)
                 self._V = write_rows(self._V, [], none, pad=n)
                 self._Ud = write_rows(self._Ud, [], none, pad=n)
+                self._V = write_placed_rows(self._V, [], placed)
+                self._Ud = write_placed_rows(self._Ud, [], placed)
 
     def update(self, batch):
         """Process one micro-batch frame (userCol/itemCol/ratingCol of the
@@ -335,6 +348,8 @@ class FoldInServer:
         frame = as_frame(batch)
         m = self.model
         p = m._params
+        side = "item" if items_side else "user"
+        self.last_rows[side + "s"] = None
         if items_side:
             solved_raw = np.asarray(frame[p["itemCol"]])
             fixed_raw = np.asarray(frame[p["userCol"]])
@@ -365,7 +380,6 @@ class FoldInServer:
         bounds = np.cumsum(np.bincount(entity, minlength=len(touched)))[:-1]
         per = list(zip(np.split(fixed_raw[by_entity], bounds),
                        np.split(r[by_entity], bounds)))
-        side = "item" if items_side else "user"
         used = self._used[side] if self.keep_history else {}
         # with a base history a user has ONE rating an item: an event on
         # an item already rated replaces it (``adds``: which events add
@@ -425,7 +439,7 @@ class FoldInServer:
         # pad rows and width up the ladder -> the programs prewarm ran;
         # one call, or where its gather would pass FOLD_ELEMENTS several
         n, first = len(touched), np.cumsum(lens) - lens
-        x, widest = np.empty((n, F.shape[1]), np.float32), 0
+        x, widest, solved = np.empty((n, F.shape[1]), np.float32), 0, []
         for sel in self._calls(lens):
             ln = lens[sel]
             n_pad, w = pad_for(len(sel)), rung_for(int(ln.max()),
@@ -433,30 +447,33 @@ class FoldInServer:
             row = np.repeat(np.arange(len(sel)), ln)
             slot = np.arange(ln.sum()) - np.repeat(np.cumsum(ln) - ln, ln)
             flat = np.repeat(first[sel], ln) + slot
-            cols = np.zeros((n_pad, w), dtype=np.int32)
-            vals = np.zeros((n_pad, w), dtype=np.float32)
-            mask = np.zeros((n_pad, w), dtype=np.float32)
+            # ids, stars and mask as planes of the ONE array the program
+            # takes (``core.foldin.pack_rows``), a host argument of its
+            # call: nothing is placed ahead of it
+            rows = cols, vals, mask = planes(
+                np.zeros((3, n_pad, w), dtype=np.int32))
             cols[row, slot] = dense[flat]
             vals[row, slot] = vals_all[flat]
             mask[row, slot] = 1.0
             # the fold's one wait for the device, on the profiler's
-            # timeline (obs.schema.LIVE_FOLDIN_SPAN_KEYS): three uploads,
-            # the call, the rows read back
+            # timeline (obs.schema.LIVE_FOLDIN_SPAN_KEYS): the call, which
+            # carries the one array up, and the rows read back
             with TraceAnnotation("live.batch.foldin.readback",
                                  side=side + "s"):
-                x[sel] = np.asarray(fold_in(
-                    F, jnp.asarray(cols), jnp.asarray(vals),
-                    jnp.asarray(mask), self._reg,
-                    implicit_prefs=self._implicit, alpha=self._alpha,
-                    nonnegative=self._nonnegative, YtY=YtY,
-                ))[:len(sel)]
+                solved.append(self._fold(F, rows, YtY))
+                x[sel] = np.asarray(solved[-1])[:len(sel)]
             obs.histogram("foldin.history_width", w, side=side)
             widest = max(widest, w)
 
         if items_side:
             first = int((m._item_map.to_dense(touched) < 0).sum())
             self.last_items.update(first=first, again=n - first)
-        self._write_back(touched, x, items_side)
+        # one call: its result holds the batch's rows in ``touched``'s
+        # order, and is what every table on the device is written from
+        placed = solved[0] if len(solved) == 1 else None
+        at = self._write_back(touched, x, items_side, placed)
+        if placed is not None:
+            self.last_rows[side + "s"] = (at, placed)
         if items_side and self._implicit:
             self._YtY = compute_yty(self._V)
         dt = time.perf_counter() - t0
@@ -465,6 +482,14 @@ class FoldInServer:
         obs.histogram("foldin.batch_rows", n, side=side)
         obs.counter("foldin.ratings", entered)
         return touched
+
+    def _fold(self, F, rows, YtY):
+        """The fold-in program on ``rows`` (``cols, vals, mask``) against
+        ``F``, by this model's parameters: the one call ``_fold_batch``
+        makes and ``prewarm`` runs ahead."""
+        return fold_in(F, *rows, self._reg,
+                       implicit_prefs=self._implicit, alpha=self._alpha,
+                       nonnegative=self._nonnegative, YtY=YtY)
 
     def _resident(self, user, used):
         """The resident ratings of ``user`` as the start of the server's
@@ -497,12 +522,16 @@ class FoldInServer:
             yield order[at:at + most]
             at += most
 
-    def _write_back(self, touched_raw_ids, new_rows, items_side=False):
+    def _write_back(self, touched_raw_ids, new_rows, items_side=False,
+                    placed=None):
         """New factor rows into the model's table, and into the server's
         own table of that side on the device where it holds one (the
         other direction's fixed side); entities the id map does not know
         take the next spare rows, in the order given, and what was held
-        for them waits no longer."""
+        for them waits no longer.  ``placed``: the same rows where they
+        still lie on the device, padded (the fold's own result) — the
+        device's table is then written from them, the row numbers alone
+        coming from the host.  Returns the table rows written."""
         m = self.model
         fac_attr = "_V" if items_side else "_U"
         emap = m._item_map if items_side else m._user_map
@@ -519,12 +548,15 @@ class FoldInServer:
         dev_attr = "_V" if items_side else "_Ud"
         table = getattr(self, dev_attr)
         if table is None:
-            return
+            return dense
         if int(table.shape[0]) != len(self._bufs[fac_attr]):
             # spare rows used up: the table of the new capacity, whole
             setattr(self, dev_attr, self._place(fac_attr))
+        elif placed is not None:
+            setattr(self, dev_attr, write_placed_rows(table, dense, placed))
         else:
             setattr(self, dev_attr, write_rows(table, dense, new_rows))
+        return dense
 
     def latency(self, q=0.5, skip_warmup=False):
         """Latency quantile over processed batches.  ``skip_warmup`` drops

@@ -49,7 +49,10 @@ and ``blocks_layout``, what stage two asks of the compiler for that
 bucket; since PR 43 ``blockmax``, how stage one reduces a block —
 ``lanes`` on the mesh cell's blocks of 256, ``block`` elsewhere — and
 ``tail``, the delta segment's slots that join at stage three: 512 in the
-live-items cell, 0 elsewhere), and for an engine given a mesh its
+live-items cell, 0 elsewhere), since PR 51 where its pins came from
+(``serving.pins`` by ``source`` and the ``serving_pin`` events, one a pin
+with its seconds and its file's bytes: a start with a warm compile cache
+counts ``loaded`` alone), and for an engine given a mesh its
 ``serving_mesh_plan`` events (one a bucket ``warmup()`` pinned; since PR
 44 with ``placements``, the transfers a staged batch takes, and
 ``spread_bytes``, what the program's first all-reduce moves for it) with
@@ -192,6 +195,11 @@ def main(argv):
 
     print(json.dumps({"serving_shortlist": bare("serving_shortlist")}),
           flush=True)
+    print(json.dumps({
+        "serving.pins": {source: obs.counter_value("serving.pins",
+                                                   source=source)
+                         for source in ("loaded", "compiled", "unreadable")},
+        "serving_pin": bare("serving_pin")}), flush=True)
     plans = bare("serving_mesh_plan")
     if plans:
         print(json.dumps({"serving_mesh_plan": plans,

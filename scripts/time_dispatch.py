@@ -17,7 +17,11 @@ On one chip (PR 41):
     argument handling places it — ``_dispatch`` since PR 41;
 (c) ``put``: the ``device_put`` alone;
 (d) ``placed_call``: the call on an argument that is on the device
-    already (what a call costs with nothing to upload).
+    already (what a call costs with nothing to upload);
+(i) ``loaded_call``: (b) on the same executable LOADED from its
+    serialized bytes (``serving.pins.dumps`` / ``loads``), as a warm
+    start pins it since PR 51 — it should cost what (b) costs (with
+    ``--mesh``: ``loaded_one_call``, beside (e)).
 
 (a) − (b) is what a batch saves; (c) against (b) − (d) says how much of
 a ``device_put`` is its Python path and how much the transfer.
@@ -178,23 +182,31 @@ def one_chip_forms(eng, m, idx, B, rank):
     mesh-less engine."""
     import jax
 
+    from tpu_als.serving import pins
+
     proto = eng._proto(B, rank)
     fn, call_args, _ = eng._int8_call(m, idx, proto)
     c = eng._pinned[(B, eng._int8_pin(idx))]
+    loaded = pins.loads(pins.dumps(c), call_args)
     at = next(i for i, a in enumerate(call_args) if a is proto)
     head, tail = call_args[:at], call_args[at + 1:]
 
     def call(packed):
         return c(*head, packed, *tail)
 
+    def loaded_call(packed):
+        return loaded(*head, packed, *tail)
+
     x = np.zeros((B, rank + 2), np.int32)
     x[:, rank] = np.arange(B)
-    same = bool(np.array_equal(np.asarray(call(jax.device_put(x))),
-                               np.asarray(call(x))))
+    want = np.asarray(call(x))
+    same = all(np.array_equal(want, np.asarray(got)) for got in (
+        call(jax.device_put(x)), loaded_call(x)))
     forms = {"put_call": halves(jax.device_put, call),
              "host_call": whole(call),
              "put": whole(jax.device_put),
-             "placed_call": whole(lambda _, p=jax.device_put(x): call(p))}
+             "placed_call": whole(lambda _, p=jax.device_put(x): call(p)),
+             "loaded_call": whole(loaded_call)}
     return forms, {"jit_" + fn.__name__: (call, jax.device_put(x))}, same
 
 
@@ -204,11 +216,14 @@ def mesh_forms(eng, m, idx, B, rank):
     import jax
     from jax._src.interpreters.pxla import batched_device_put
 
+    from tpu_als.serving import pins
+
     devices = list(eng.mesh.devices.flat)
     S = len(devices)
     proto = eng._proto(B, rank)
     fn, call_args, _ = eng._int8_call(m, idx, proto)
     c = eng._pinned[(B, eng._int8_pin(idx))]
+    loaded = pins.loads(pins.dumps(c), call_args)
     at = next(i for i, a in enumerate(call_args) if a is proto)
     head, tail = call_args[:at], call_args[at + 1:]
     rep_proto = jax.device_put(np.zeros((B, rank + 2), np.int32),
@@ -222,6 +237,9 @@ def mesh_forms(eng, m, idx, B, rank):
 
     def one(packed):
         return c(*head, packed, *tail)
+
+    def loaded_one(packed):
+        return loaded(*head, packed, *tail)
 
     def rep(packed):
         return replicated(*head, packed, *tail)
@@ -250,7 +268,7 @@ def mesh_forms(eng, m, idx, B, rank):
     want = np.asarray(rep(x))
     same = all(np.array_equal(want, np.asarray(got)) for got in (
         one(eng._place_one(x)), one(place_public(x)), rows(x),
-        rep(place_batched(x)), rep(put(x))))
+        rep(place_batched(x)), rep(put(x)), loaded_one(eng._place_one(x))))
     forms = {"put_call": halves(put, rep),
              "host_call": whole(rep),
              "put": whole(put),
@@ -258,7 +276,8 @@ def mesh_forms(eng, m, idx, B, rank):
              "one_call": halves(eng._place_one, one),
              "one_public_call": halves(place_public, one),
              "rows_call": whole(rows),
-             "batched_call": halves(place_batched, rep)}
+             "batched_call": halves(place_batched, rep),
+             "loaded_one_call": halves(eng._place_one, loaded_one)}
     programs = {"jit_serve_mesh_int8_replicated": (rep, put(x)),
                 "jit_" + fn.__name__: (one, eng._place_one(x)),
                 "jit_serve_mesh_int8_rows": (
@@ -360,6 +379,11 @@ def main(argv=None):
                 "put_alone_us (c)": med["put"],
                 "upload_inside_call_us (b)-(d)":
                     med["host_call"] - med["placed_call"]}
+        ours, as_loaded = (("one_call", "loaded_one_call") if args.mesh
+                           else ("host_call", "loaded_call"))
+        line["loaded_minus_compiled_us"] = med[as_loaded] - med[ours]
+        line["compiled_quartiles_us"] = (
+            row(took[ours][0])["spread"] * med[ours])
         if args.mesh:
             line.update({
                 f"saving_us (b)-{name}": med["host_call"] - med[name]

@@ -167,6 +167,17 @@ METRICS = {
         "merge's two all-gathers of the shards' local top-k lists, by "
         "the closed form serving.index.mesh_exchange_bytes (a test pins "
         "it to the traced program's collectives)"),
+    "serving.pins": (
+        "counter", "programs",
+        "scoring programs a ServingEngine warm-up pinned (warmup, "
+        "warmup_live, warmup_histories; one a bucket, path and history "
+        "pad), labeled by where the executable came from: source=loaded "
+        "(deserialized from the pin store in the compile cache's "
+        "directory, serving.pins.pin: nothing traced or lowered) | "
+        "compiled (not there, or no compile cache configured: lowered "
+        "and compiled, and written there) | unreadable (its file did "
+        "not load: compiled, and the file written over); a start with "
+        "a warm cache counts loaded only"),
     "scenario.freshness_seconds": (
         "histogram", "seconds",
         "cold-start scenario: rating-arrival -> servable latency (fold-"
@@ -358,6 +369,7 @@ LABELS = {
     "serving.publishes": ("tenant",),
     "serving.user_table_writes": ("how", "tenant"),
     "serving.mesh_exchange_bytes": ("tenant",),
+    "serving.pins": ("source", "tenant"),
     "serving.excluded_ids": ("source", "tenant"),
     "serving.exclusion_upload_bytes": ("tenant",),
     "serving.publish_seconds": ("mode", "tenant"),
@@ -703,6 +715,16 @@ EVENTS = {
         "lookup's all-reduce, the merge's all-gathers: "
         "serving.index.mesh_exchange_bytes), which is what "
         "serving.mesh_exchange_bytes adds per batch"),
+    "serving_pin": (
+        ("bucket", "path", "pad", "source", "seconds", "bytes"),
+        "one per scoring program a ServingEngine warm-up pinned: its "
+        "bucket, path (int8 | int8_delta | exact) and history pad (null "
+        "for a program that takes no histories), where the executable "
+        "came from (source, as serving.pins labels it: loaded | compiled "
+        "| unreadable), the seconds the pin took (the load, or lower + "
+        "compile + the store's write; not the program's first run) and "
+        "the bytes of its file in the store, read or written (0 where "
+        "no compile cache is configured)"),
     "foldin_solve_path": (
         ("side", "rank", "rows", "width", "path", "reason"),
         "one per fold-in program FoldInServer.prewarm compiled and ran "

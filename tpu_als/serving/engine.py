@@ -172,9 +172,10 @@ stack plugs into:
   that buffer — zero per-ticket copies; the buffer snapshots an
   immutable device array, so the views stay valid indefinitely.
   :meth:`ServingEngine.warmup` additionally PINS the steady-state
-  scoring executables ahead of time (``jit(...).lower().compile()`` per
-  bucket, with a mesh or without), taking jit-cache dispatch off the
-  hot path;
+  scoring executables ahead of time (one ``stages.Compiled`` per
+  bucket, with a mesh or without: loaded from the compile cache's
+  directory where it lies there, lowered and compiled where not —
+  ``serving.pins``), taking jit-cache dispatch off the hot path;
   a shape-changing publish invalidates a pin and falls back to the
   ordinary jit call until the next warmup.
 """
@@ -220,6 +221,7 @@ from tpu_als.ops.topk import (
 )
 from tpu_als.parallel.mesh import AXIS, shard_map
 from tpu_als.resilience import faults
+from tpu_als.serving import pins
 from tpu_als.serving.batcher import (
     DEFAULT_BUCKETS,
     DeadlineExceeded,
@@ -734,10 +736,11 @@ def _build_mesh_exact(mesh, k, k_loc, ni_loc, item_chunk):
                 s, ix.astype(jnp.int32) + me * ni_loc, last_id,
                 axis=AXIS, k=k))
 
-    return jax.jit(shard_map(
+    return pins.built(jax.jit(shard_map(
         serve_mesh_exact, mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P()),
-        out_specs=P(), check_vma=False))
+        out_specs=P(), check_vma=False)),
+        _build_mesh_exact, mesh, k, k_loc, ni_loc, item_chunk)
 
 
 @functools.lru_cache(maxsize=8)
@@ -1700,8 +1703,13 @@ class ServingEngine:
         compile.  Records no metrics (a warmup sample in the latency
         histograms would poison the SLO tail serve-bench reports).
 
-        This PINS the steady-state packed executables per bucket (AOT
-        ``lower().compile()``), so the hot path calls a compiled program
+        This PINS the steady-state packed executables per bucket (a
+        ``stages.Compiled`` each, by :meth:`_pin`: LOADED where the
+        compile cache's directory holds the executable under the pin's
+        key, which a start with a warm cache does without tracing or
+        lowering anything; lowered, compiled and written there where it
+        does not; plain AOT ``lower().compile()`` where no compile cache
+        is configured), so the hot path calls a compiled program
         directly instead of going through jit-cache dispatch; a publish
         that changes array shapes invalidates a pin (the serve path
         falls back to the jit call and drops it) — re-run warmup to
@@ -1722,7 +1730,8 @@ class ServingEngine:
         before the traffic.  (The lock is taken HERE and not by a
         wrapper around the method: one more Python frame between the
         caller and ``lower()`` cost the six lowerings 0.33 s on the
-        chip's host; PERF.md section 6, PR 31.)
+        chip's host; PERF.md section 6, PR 31.  Since PR 51 that is a
+        cold start's cost alone.)
         """
         with self._table_lock:
             m = self._model
@@ -1739,17 +1748,36 @@ class ServingEngine:
                 idx = m.index
                 if idx is not None and idx.seq == m.seq:
                     self._emit_shortlist(B, idx)
-                    fn, args, statics = self._int8_call(m, idx, proto)
-                    self._pinned[(B, self._int8_pin(idx))] = fn.lower(
-                        *args, **statics).compile()
+                    self._pin((B, self._int8_pin(idx)),
+                              self._int8_call(m, idx, proto))
                     if self.mesh is not None:
                         obs.emit("serving_mesh_plan", bucket=B,
                                  **self._mesh_plan(m, idx, B),
                                  **self._labels)
                 # the exact path backs every fallback: always warm
-                fn, args, statics = self._exact_call(m, proto)
-                self._pinned[(B, "exact")] = fn.lower(
-                    *args, **statics).compile()
+                self._pin((B, "exact"), self._exact_call(m, proto))
+
+    def _pin(self, key, call, run=False):
+        """Pin ``call`` — ``(function, arguments, static arguments)`` as
+        :meth:`_int8_call` / :meth:`_exact_call` give them — under
+        ``key`` = ``(bucket, path[, history pad])``: the one place a
+        scoring program is pinned (:func:`serving.pins.pin` loads it or
+        compiles it), counted by ``serving.pins{source}`` and announced
+        by a ``serving_pin`` event.  ``run``: the pinned program is run
+        once on ``call``'s arguments, loaded or compiled (a program's
+        first execution takes up to seconds: none is left to the
+        traffic); the event's ``seconds`` are the pin's alone."""
+        fn, args, statics = call
+        t0 = time.perf_counter()
+        c, source, nbytes = pins.pin(fn, args, statics)
+        seconds = time.perf_counter() - t0
+        self._pinned[key] = c
+        obs.counter("serving.pins", source=source, **self._labels)
+        obs.emit("serving_pin", bucket=key[0], path=key[1],
+                 pad=key[2] if len(key) > 2 else None, source=source,
+                 seconds=seconds, bytes=nbytes, **self._labels)
+        if run:
+            c(*args).block_until_ready()
 
     def _warm_exclusion(self, m, B):
         """Pin what a generation with histories runs for bucket ``B``,
@@ -1766,11 +1794,9 @@ class ServingEngine:
         idx = m.index
         if idx is not None and idx.seq == m.seq:
             for pad in m.seen.pads:
-                fn, args, statics = self._int8_call(m, idx, wide,
-                                                    m.seen, pad)
-                c = self._pinned[(B, self._int8_pin(idx), pad)] = fn.lower(
-                    *args, **statics).compile()
-                c(*args).block_until_ready()
+                self._pin((B, self._int8_pin(idx), pad),
+                          self._int8_call(m, idx, wide, m.seen, pad),
+                          run=True)
                 self._emit_shortlist(B, idx, history_pad=pad, **(
                     {"delta_rows": idx.delta_slots} if idx.delta_slots
                     else {}))
@@ -1778,11 +1804,9 @@ class ServingEngine:
                 self._emit_exclusion(B, "int8", pad, cols,
                                      mask_block(cols))
         pad = m.seen.pads[-1]
-        fn, args, statics = self._exact_call(m, wide, m.seen, pad)
-        c = self._pinned[(B, "exact", pad)] = fn.lower(
-            *args, **statics).compile()
-        c(*args).block_until_ready()
-        chunk = statics["item_chunk"]
+        call = self._exact_call(m, wide, m.seen, pad)
+        self._pin((B, "exact", pad), call, run=True)
+        chunk = call[2]["item_chunk"]
         self._emit_exclusion(B, "exact", pad,
                              -(-int(m.V.shape[0]) // chunk) * chunk, chunk)
 
@@ -2025,14 +2049,10 @@ class ServingEngine:
             for B in self.batcher.buckets:
                 proto = self._proto(B, m.rank)
                 self._pinned.pop((B, "int8"), None)
-                fn, args, statics = self._int8_call(m, idx, proto)
-                c = self._pinned[(B, self._int8_pin(idx))] = fn.lower(
-                    *args, **statics).compile()
-                c(*args).block_until_ready()
+                self._pin((B, self._int8_pin(idx)),
+                          self._int8_call(m, idx, proto), run=True)
                 self._emit_shortlist(B, idx, delta_rows=idx.delta_slots)
-                fn, args, statics = self._exact_call(m, proto)
-                self._pinned[(B, "exact")] = fn.lower(
-                    *args, **statics).compile()
+                self._pin((B, "exact"), self._exact_call(m, proto))
 
     def warmup_histories(self, max_rows=LIVE_PADS[-1]):
         """Make a generation that holds users' histories
@@ -2097,12 +2117,13 @@ class ServingEngine:
                  **index.shortlist_plan(rows=bucket)._asdict(), **extra)
 
     def _run_pinned(self, key, fn, args, statics):
-        """Dispatch through the AOT-pinned executable when one is live
+        """Dispatch through the pinned executable when one is live
         for ``key``; a pin invalidated by a shape-changing publish is
-        dropped and the ordinary jit call (compiled once, cached) takes
-        over until the next :meth:`warmup`.  Either takes the staged
-        batch as :meth:`_dispatch` hands it: the host array without a
-        mesh, the placed one with."""
+        dropped and the ordinary jit call (compiled once, cached; after
+        a LOADED pin also traced and lowered once, which no warm-up of
+        this process did) takes over until the next :meth:`warmup`.
+        Either takes the staged batch as :meth:`_dispatch` hands it: the
+        host array without a mesh, the placed one with."""
         c = self._pinned.get(key)
         if c is not None:
             try:

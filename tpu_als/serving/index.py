@@ -120,6 +120,7 @@ from tpu_als.ops.topk import (
     shortlist_topk,
 )
 from tpu_als.parallel.mesh import AXIS, shard_leading, shard_map
+from tpu_als.serving import pins
 
 # how far a rescored score may sit from the chunked kernel's, in units in
 # the last place of the row's largest score (module docstring)
@@ -812,11 +813,13 @@ def _build_sharded_int8(mesh, k, k_loc, sk_loc, ni_loc, has_delta,
             return out if pack is None else pack(*out)
 
     program.__name__ = name     # the program's name on a device trace
-    return jax.jit(shard_map(
+    return pins.built(jax.jit(shard_map(
         program, mesh=mesh,
         in_specs=head + (P(AXIS), P(AXIS), P(AXIS), P(AXIS), P())
         + (P(),) * (5 if has_delta else 0),
-        out_specs=(P(), P()) if pack is None else P(), check_vma=False))
+        out_specs=(P(), P()) if pack is None else P(), check_vma=False)),
+        _build_sharded_int8, mesh, k, k_loc, sk_loc, ni_loc, has_delta,
+        lookup, pack, name)
 
 
 def place_catalog(V, item_valid, mesh, shortlist_k=64):

@@ -364,16 +364,15 @@ def test_histories_that_are_refused(eng, factors, what):
 
 
 def test_what_cannot_take_histories_yet_says_so(factors):
-    from tpu_als import make_mesh
-
     U, V, scores = factors
     hist = csr(top_histories(scores, [5] * N_USERS))
-    mesh = ServingEngine(k=K, buckets=(8,), mesh=make_mesh(4))
-    with pytest.raises(NotImplementedError, match="shortlist_rescore"):
-        mesh.publish(U, V[:4096], user_seen=hist)
-    mesh.publish(U, V[:4096])
-    with pytest.raises(NotImplementedError, match="mesh"):
-        mesh.submit(0, exclude=[1])
+    # (a mesh engine takes histories and lists since PR 52:
+    # tests/test_serve_mesh_unseen.py; what it still refuses is there)
+    none = ServingEngine(k=K, buckets=(8,), shortlist_k=SK)
+    none.publish(U, V)
+    with pytest.raises(NotImplementedError, match="holds no histories"):
+        none.publish_update(U, V, touched_users=[0],
+                            seen_appended=([0], [1]))
     # a generation with histories takes publish_update since PR 42 and a
     # catalog that moves since PR 47 (tests/test_live_items_unseen.py);
     # made ready for its histories alone it gets no segment ...

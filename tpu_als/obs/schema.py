@@ -161,12 +161,24 @@ METRICS = {
         "counter", "bytes",
         "a mesh engine only: what one device moves between the chips "
         "for the batches it scored, one add a batch — the all-reduce "
-        "that spreads the staged [bucket, rank + 2] batch from the one "
-        "shard the host placed it on (since PR 44), the by-id "
+        "that spreads the staged [bucket, rank + 2] batch (64 columns "
+        "wider where the batch excludes: the requests' own lists) from "
+        "the one shard the host placed it on (since PR 44), the by-id "
         "lookup's all-reduce of the [bucket, rank] queries and the "
         "merge's two all-gathers of the shards' local top-k lists, by "
         "the closed form serving.index.mesh_exchange_bytes (a test pins "
         "it to the traced program's collectives)"),
+    "serving.mesh_history_bytes": (
+        "counter", "bytes",
+        "a mesh engine that excludes only: what one device moves between "
+        "the chips for the users' histories of the batches it scored, one "
+        "add a batch that excludes — the all-reduce of the [bucket, "
+        "history pad] int32 lists, the owning shard's ids and zeros from "
+        "the others (serving.engine._mesh_history), by the closed form "
+        "serving.index.mesh_history_bytes (a test pins it to the traced "
+        "program's collectives); nothing else of a history crosses a "
+        "link after its publish, and serving.mesh_exchange_bytes counts "
+        "the requests' own lists, which ride the staged batch's spread"),
     "serving.pins": (
         "counter", "programs",
         "scoring programs a ServingEngine warm-up pinned (warmup, "
@@ -369,6 +381,7 @@ LABELS = {
     "serving.publishes": ("tenant",),
     "serving.user_table_writes": ("how", "tenant"),
     "serving.mesh_exchange_bytes": ("tenant",),
+    "serving.mesh_history_bytes": ("tenant",),
     "serving.pins": ("source", "tenant"),
     "serving.excluded_ids": ("source", "tenant"),
     "serving.exclusion_upload_bytes": ("tenant",),
@@ -538,6 +551,14 @@ SERVE_MESH_SCOPES = (
 # ``excluded`` (the batch's history pad) on ``serve.batch.stage`` of a
 # batch that excludes
 SERVE_EXCLUDE_SCOPE = "serve.exclude"
+# on a mesh, beside ``serve.mesh.lookup``: the shard that owns a by-id
+# slot's user takes that history's first ids from ITS part of the table
+# (``_select_seen`` on the shard's own runs, so ``serve.exclude`` lies
+# inside it) and one all-reduce of the ``int32[bucket, history pad]``
+# lists hands them to every shard (serving/engine.py ``_mesh_history``);
+# each shard's mask over its own columns is ``serve.exclude`` inside
+# ``serve.mesh.score`` (serving/index.py ``shard_lists``, ops/topk.py)
+SERVE_MESH_HISTORY_SCOPE = "serve.mesh.history"
 LIVE_SPAN_KEYS = ("queue_wait", "quarantine", "foldin", "publish")
 # the updater thread's batch cycle as profiler spans, the write path's
 # counterpart of SERVE_BATCH_SPAN_KEYS (``TraceAnnotation`` in
@@ -702,9 +723,15 @@ EVENTS = {
         "keys it sorts before its one scatter-add (rows x ids)"),
     "serving_mesh_plan": (
         ("bucket", "shards", "items_per_shard", "users_per_shard", "k_loc",
-         "placements", "spread_bytes", "exchange_bytes"),
+         "placements", "spread_bytes", "exchange_bytes", "history_pad",
+         "history_bytes"),
         "one per sharded int8 scoring program a mesh engine's "
-        "ServingEngine.warmup compiles and pins (per bucket): the mesh "
+        "ServingEngine.warmup compiles and pins (per bucket, and per "
+        "history pad where the generation holds histories: history_pad, "
+        "null otherwise, and history_bytes, what one device moves for "
+        "the batch's [bucket, history pad] lists, "
+        "serving.index.mesh_history_bytes; spread_bytes and "
+        "exchange_bytes then count the 64 wider columns): the mesh "
         "size, the catalog and user-table rows one shard holds, the "
         "answers one shard gives a query, the host->device transfers the "
         "staged batch takes (placements: 1 since PR 44, to the mesh's "

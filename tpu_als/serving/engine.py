@@ -158,7 +158,14 @@ stack plugs into:
   publish goes into the owning shard in place
   (:func:`_build_mesh_scatter`), and the exact fallback scores per
   shard too (:func:`_build_mesh_exact`): nothing but the staged batch
-  and a publish's touched rows is ever uploaded.
+  and a publish's touched rows is ever uploaded.  Users' HISTORIES
+  (``publish(user_seen=...)``) are sharded with the user table — a
+  history lies once on the mesh, on the chip that holds its user's row
+  (:meth:`ServingEngine._shard_seen`) — the owning shard hands a batch
+  that excludes its lists inside the program, one more all-reduce
+  (:func:`_mesh_history`), and every shard masks the ids it owns among
+  its own columns (``serving.index.shard_lists``): one program a bucket
+  and history pad, on the int8 path and the exact fallback alike.
 - **Host throughput.**  The request path stages each micro-batch into
   one ``[B, rank+2]`` int32 array (query rows' f32 bits | ids |
   row-mask; a new one every batch, since the batch before may still be
@@ -211,6 +218,7 @@ from tpu_als.obs.schema import (
     LIVE_HISTORY_SCOPE,
     SERVE_BATCH_SPAN_KEYS,
     SERVE_EXCLUDE_SCOPE,
+    SERVE_MESH_HISTORY_SCOPE,
     SERVE_MESH_SCOPES,
 )
 from tpu_als.obs.trace import FlightRecorder
@@ -238,9 +246,11 @@ from tpu_als.serving.index import (
     _shard_merge,
     mask_block,
     mesh_exchange_bytes,
+    mesh_history_bytes,
     mesh_spread_bytes,
     place_catalog,
     segment_write_bytes,
+    shard_lists,
     shortlist_rescore,
 )
 
@@ -286,7 +296,17 @@ class _Seen:
     behind their users' runs IN PLACE (:func:`_append_runs`; appended
     ids stand in arrival order) and a run that is full moves to the free
     room first (:func:`_move_run`); ``lengths`` is shared by the
-    generations of one layout and written under ``_table_lock``."""
+    generations of one layout and written under ``_table_lock``.
+
+    ON A MESH the table is sharded WITH the user table, as published
+    (:meth:`ServingEngine._place_seen`): shard ``s`` holds the runs and
+    the ids of table rows ``[s * n_loc, (s + 1) * n_loc)`` — ``runs``
+    ``int32[S * (n_loc + 1)]``, every shard's own ``indptr`` from 0
+    (spare rows: empty runs), ``indices`` every shard's ids padded to
+    one common length plus the longest pad of spare ids, both sharded by
+    rows — so a history is held ONCE on the mesh, by the shard that
+    holds its user's row (:func:`_mesh_history`), and ``lengths`` has one
+    entry a table row.  Nothing grows there yet."""
 
     __slots__ = ("runs", "indices", "lengths", "pads", "room")
 
@@ -709,28 +729,70 @@ def _mesh_lookup(U, packed, *, me, axis):
     return jnp.where(rowmask[:, None], rows, jax.lax.psum(mine, axis))
 
 
-def _mesh_queries(U, packed, *, me, axis):
+def _mesh_history(runs, indices, packed, *, me, axis, rank, pad):
+    """:func:`_select_seen` against histories sharded WITH the user
+    table, inside ``shard_map``: this shard holds the runs and the ids of
+    table rows ``[me * n_loc, (me + 1) * n_loc)`` (``runs``: its own
+    ``indptr``, ``int32[n_loc + 1]`` from 0; :meth:`ServingEngine.
+    _place_seen`), takes the first ``pad`` ids of the histories it owns
+    for the batch's ids — a slot another shard owns is selected as a
+    request by vector is, no id — and the shards' lists are summed: one
+    list and ``S - 1`` empty ones a slot.  What is summed is ``id + 1``
+    with 0 for no id (``NOT_AN_ID`` is the largest ``int32``: summed as
+    it is it would overflow), so the sum is bit-exact, as
+    :func:`_mesh_spread`'s is, and every shard ends with the same
+    ``int32[B, pad]`` of LOGICAL ids padded with ``NOT_AN_ID``, beside
+    the requests' own lists, which rode the staged batch."""
+    n_loc = runs.shape[0] - 1
+    loc = packed[:, rank] - me * n_loc
+    owned = (loc >= 0) & (loc < n_loc)
+    local = packed.at[:, rank].set(loc).at[:, rank + 1].set(
+        jnp.where(owned, packed[:, rank + 1], 1))
+    history, own = _select_seen(runs, indices, local, rank, pad)
+    total = jax.lax.psum(
+        jnp.where(history == NOT_AN_ID, 0, history + 1), axis)
+    return jnp.where(total == 0, NOT_AN_ID, total - 1), own
+
+
+def _mesh_queries(U, packed, *histories, me, axis, pad=None):
     """What stands before the scoring in both of a mesh engine's
     programs: the staged batch spread from the one shard that was given
     it, then the by-id lookup — every shard ends with the ``[B, rank]``
-    queries."""
-    return _mesh_lookup(U, _mesh_spread(packed, axis=axis), me=me, axis=axis)
+    queries — in the scope ``SERVE_MESH_SCOPES[0]``; and for a batch that
+    excludes (``histories``: the sharded ``(runs, indices)``, ``pad``
+    its history pad) the lists of ids its slots are not to be answered
+    with (:func:`_mesh_history`), in ``SERVE_MESH_HISTORY_SCOPE`` beside
+    it.  Returns ``(queries, lists or None)``."""
+    with jax.named_scope(SERVE_MESH_SCOPES[0]):
+        packed = _mesh_spread(packed, axis=axis)
+        Ub = _mesh_lookup(U, packed, me=me, axis=axis)
+    if not histories:
+        return Ub, None
+    with jax.named_scope(SERVE_MESH_HISTORY_SCOPE):
+        return Ub, _mesh_history(*histories, packed, me=me, axis=axis,
+                                 rank=U.shape[1], pad=pad)
 
 
 @functools.lru_cache(maxsize=32)
-def _build_mesh_exact(mesh, k, k_loc, ni_loc, item_chunk):
+def _build_mesh_exact(mesh, k, k_loc, ni_loc, item_chunk, pad=None):
     """A mesh engine's exact fallback, per shard: the lookup, the exact
     chunked scan of this shard's slice of the engine's own sharded
-    catalog, the same merge.  Nothing of the catalog moves."""
+    catalog, the same merge.  Nothing of the catalog moves.  ``pad``: a
+    batch that excludes — the sharded histories follow the staged batch,
+    and the scan takes each shard's own columns out
+    (``serving.index.shard_lists``), as the int8 program does."""
     P = jax.sharding.PartitionSpec
+    head = 2 if pad is None else 4
 
-    def serve_mesh_exact(U, packed, V, valid, last_id):
+    def serve_mesh_exact(*args):
+        V, valid, last_id = args[head:]
         me = jax.lax.axis_index(AXIS)
-        with jax.named_scope(SERVE_MESH_SCOPES[0]):
-            Ub = _mesh_queries(U, packed, me=me, axis=AXIS)
+        Ub, seen = _mesh_queries(*args[:head], me=me, axis=AXIS, pad=pad)
         with jax.named_scope(SERVE_MESH_SCOPES[1]):
-            s, ix = chunked_topk_scores(Ub, V, valid, k_loc,
-                                        item_chunk=item_chunk)
+            s, ix = chunked_topk_scores(
+                Ub, V, valid, k_loc, item_chunk=item_chunk,
+                seen=(None if pad is None
+                      else shard_lists(seen, me * ni_loc, ni_loc)))
         with jax.named_scope(SERVE_MESH_SCOPES[2]):
             return _pack_response(*_shard_merge(
                 s, ix.astype(jnp.int32) + me * ni_loc, last_id,
@@ -738,9 +800,9 @@ def _build_mesh_exact(mesh, k, k_loc, ni_loc, item_chunk):
 
     return pins.built(jax.jit(shard_map(
         serve_mesh_exact, mesh=mesh,
-        in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P()),
+        in_specs=(P(AXIS),) * (head + 2) + (P(),),
         out_specs=P(), check_vma=False)),
-        _build_mesh_exact, mesh, k, k_loc, ni_loc, item_chunk)
+        _build_mesh_exact, mesh, k, k_loc, ni_loc, item_chunk, pad)
 
 
 @functools.lru_cache(maxsize=8)
@@ -1065,11 +1127,12 @@ class ServingEngine:
         obs.emit("serving_compaction", seq=m.seq, rows=rows,
                  **self._labels)
 
-    def _place_seen(self, user_seen, n_users, n_items):
+    def _place_seen(self, user_seen, n_users, n_items, rows=None):
         """The users' histories of a publish on the device
         (:class:`_Seen`), checked first: CSR ``(indptr, indices)`` over
         catalog ids, one row a user, a row's ids ascending and none
-        twice."""
+        twice.  On a mesh (``rows``: the placed user table's) sharded
+        with that table (:meth:`_shard_seen`)."""
         indptr, indices = (np.asarray(a) for a in user_seen)
         if indptr.shape != (n_users + 1,) or indptr[0] != 0 \
                 or indptr[-1] != len(indices) \
@@ -1093,6 +1156,8 @@ class ServingEngine:
                 "user_seen: every row holds catalog ids in "
                 f"[0, {n_items}), ascending, none twice")
         pads = history_pads(lengths.max(initial=0))
+        if self.mesh is not None:
+            return self._shard_seen(indptr, indices, lengths, pads, rows)
         # spare ids at the end: a slice of the longest pad from the last
         # user's first id stays inside the table (``_select_seen``)
         dev = jax.device_put((
@@ -1100,6 +1165,48 @@ class ServingEngine:
             np.concatenate([indices.astype(np.int32),
                             np.full(pads[-1], NOT_AN_ID, np.int32)])))
         return _Seen(*dev, lengths.astype(np.int32), pads)
+
+    def _shard_seen(self, indptr, indices, lengths, pads, rows):
+        """Checked histories sharded with a user table of ``rows`` rows
+        (:class:`_Seen`, ON A MESH): each shard's runs and ids go to its
+        own device alone — a history is never whole on another chip, nor
+        all of them on the host a second time."""
+        S, n_users = len(self._devices), len(lengths)
+        n_loc = rows // S
+        bounds = np.minimum(np.arange(S + 1) * n_loc, n_users)
+        cuts = indptr[bounds]
+        # the common length in whole granules of about a 32nd of a
+        # shard's even share (65,536 ids at least), as a table's rows are
+        # (``row_capacity``): WHO holds which history moves the fullest
+        # shard by a fraction of a per cent, and a shape that moved with
+        # it would compile every scoring program anew for every such
+        # publish (47 s of the four-chip cell's start, a seed: PERF.md
+        # section 6, PR 52)
+        granule = max(1 << 16, _next_pow2(-(-len(indices) // S)) >> 5)
+        width = (-(-int(np.diff(cuts).max()) // granule) * granule
+                 + pads[-1])
+
+        def runs_of(s):
+            own = np.full(n_loc + 1, cuts[s + 1] - cuts[s], np.int32)
+            mine = indptr[bounds[s]:bounds[s + 1] + 1] - cuts[s]
+            own[:len(mine)] = mine
+            return own
+
+        def ids_of(s):
+            own = np.full(width, NOT_AN_ID, np.int32)
+            own[:cuts[s + 1] - cuts[s]] = indices[cuts[s]:cuts[s + 1]]
+            return own
+
+        def placed(part, length):
+            return jax.make_array_from_single_device_arrays(
+                (S * length,), self._by_rows,
+                [jax.device_put(part(s), d)
+                 for s, d in enumerate(self._devices)])
+
+        held = np.zeros(rows, np.int32)
+        held[:n_users] = lengths
+        return _Seen(placed(runs_of, n_loc + 1), placed(ids_of, width),
+                     held, pads)
 
     def _lay_out(self, seen, rows, more=0):
         """``seen`` laid out anew as histories that GROW (:class:`_Seen`),
@@ -1301,16 +1408,27 @@ class ServingEngine:
         seen.lengths[plan.users] = plan.lengths
         return _Seen(runs[:2], runs[2], seen.lengths, seen.pads, room)
 
-    def _without_history(self):
-        """The empty table of histories: what a batch of an engine that
-        published none rides when a request brings a list of its own."""
-        if self._no_history is None:
+    def _without_history(self, m):
+        """The empty table of histories: what a batch of a generation
+        ``m`` that published none rides when a request brings a list of
+        its own (on a mesh sharded like a real one, for ``m``'s user
+        table)."""
+        rows = None if self.mesh is None else int(m.U.shape[0])
+        if self._no_history is None or self._no_history[0] != rows:
             pads = history_pads(0)
-            self._no_history = _Seen(
-                *jax.device_put((np.zeros(2, np.int32),
-                                 np.full(pads[-1], NOT_AN_ID, np.int32))),
-                None, pads)
-        return self._no_history
+            if self.mesh is None:
+                seen = _Seen(
+                    *jax.device_put((np.zeros(2, np.int32),
+                                     np.full(pads[-1], NOT_AN_ID,
+                                             np.int32))),
+                    None, pads)
+            else:
+                seen = self._shard_seen(
+                    np.zeros(1, np.int64), np.empty(0, np.int32),
+                    np.empty(0, np.int32), pads, rows)
+                seen.lengths = None
+            self._no_history = (rows, seen)
+        return self._no_history[1]
 
     def publish(self, U, V, item_valid=None, quantize=True, user_seen=None):
         """Swap in a new model generation atomically.
@@ -1331,9 +1449,12 @@ class ServingEngine:
         rule ``ops.topk.excluded_mask`` states, on the int8 path and on
         the exact fallback alike.  ``None`` publishes none: the engine
         then compiles and runs what it did before it knew of histories.
-        Not yet with a mesh (``serving.index.shortlist_rescore`` takes
-        no per-row mask on a shard: each would mask the ids it owns) —
-        refused here.
+        With a mesh the histories are sharded with the user table — a
+        history lies once on the mesh, on the chip that holds its user's
+        row (:class:`_Seen`) — the owning shard hands a batch its lists
+        inside the scoring program (:func:`_mesh_history`) and every
+        shard masks the ids it owns among its own columns
+        (``serving.index.shard_lists``).
         The histories published here lie run after run with no room
         between them; :meth:`warmup_live` lays them out to GROW, after
         which :meth:`publish_update` appends to them
@@ -1342,17 +1463,12 @@ class ServingEngine:
         """
         t0 = time.perf_counter()
         mode = faults.check("serving.publish")
-        if user_seen is not None and self.mesh is not None:
-            raise NotImplementedError(
-                "publish(user_seen=...) on a mesh engine: the scoring "
-                "pipeline (serving.index.shortlist_rescore) takes no "
-                "per-row exclusion on a shard; each would mask the ids it "
-                "owns")
         Vh = np.asarray(V, dtype=np.float32)
         Ni = int(Vh.shape[0])
-        seen = (None if user_seen is None
-                else self._place_seen(user_seen, int(U.shape[0]), Ni))
         U, n_users, _ = self._place_users(self._model, U)
+        # behind the table they are sharded with, on a mesh
+        seen = (None if user_seen is None else self._place_seen(
+            user_seen, n_users, Ni, rows=int(U.shape[0])))
         validh = (np.ones(Ni, dtype=bool) if item_valid is None
                   else np.asarray(item_valid, dtype=bool).ravel())
         self._announce_mesh()
@@ -1525,7 +1641,10 @@ class ServingEngine:
         (ids are checked against the catalog as this publish leaves it;
         ``live.history_segment_ids`` counts those that name an item the
         index holds in its segment).  ``seen_appended`` on a generation
-        that holds no histories raises ``NotImplementedError``.  The
+        that holds no histories raises ``NotImplementedError``; so it
+        does on a mesh, where histories are held as published and a
+        publish carries them as they are (a user appended to the table
+        has none).  The
         histories are laid out to grow by :meth:`warmup_live` /
         :meth:`warmup_histories`; on an engine nobody warmed up the first
         such publish does it, under the traffic, with a warning.
@@ -1554,6 +1673,13 @@ class ServingEngine:
             raise NotImplementedError(
                 "seen_appended on a generation that holds no histories: "
                 "publish(..., user_seen=...) first")
+        if seen_appended is not None and self.mesh is not None:
+            raise NotImplementedError(
+                "seen_appended on a mesh engine: its histories lie as "
+                "published, sharded with the user table; what is missing "
+                "is the grown layout's shard-local write (each shard "
+                "appending behind the runs it owns, as the row write of "
+                "_build_mesh_scatter goes to the owning shard)")
         t0 = time.perf_counter()
         # keep a host handle: the delta path gathers only the touched
         # rows, and doing that in numpy costs O(touched) with no
@@ -1576,7 +1702,18 @@ class ServingEngine:
                 prev, U, touched_users, placed.get("users"), ride)
             # planned against the catalog as THIS publish leaves it: an
             # id it appends may name an item it appends
+            # (a mesh's histories do not grow: carried as they are, and
+            # only with the table they are sharded with)
+            carried = (prev.seen if prev is not None
+                       and self.mesh is not None else None)
+            if carried is not None and how == "replaced" \
+                    and users.shape[0] != prev.U.shape[0]:
+                raise NotImplementedError(
+                    "a user table re-placed at another size under "
+                    "histories on a mesh (they are sharded by its rows): "
+                    "publish(..., user_seen=...) anew")
             appended = (None if prev is None or prev.seen is None
+                        or carried is not None
                         else self._append_history(n_users, Ni,
                                                   seen_appended))
             if appended is not None:
@@ -1645,7 +1782,8 @@ class ServingEngine:
             if appended is not None:
                 appended = self._place_plan(appended, ride)
             how = self._swap(how, users, seq, n_users, V, valid, index, Ni,
-                             host=U, items=items, appended=appended)
+                             host=U, items=items, seen=carried,
+                             appended=appended)
             self._seq = seq
             if (mode == "delta"
                     and index.delta_count >= self._compact_rows(index)):
@@ -1715,8 +1853,8 @@ class ServingEngine:
         falls back to the jit call and drops it) — re-run warmup to
         restore.  With a mesh the pinned programs are the sharded ones
         (``serving.index._build_sharded_int8``, :func:`_build_mesh_exact`), one a
-        bucket and path like the others, and each int8 one is announced
-        by a ``serving_mesh_plan`` event.  For a generation that holds
+        bucket and path (and history pad) like the others, and each int8
+        one is announced by a ``serving_mesh_plan`` event.  For a generation that holds
         users' histories (``publish(user_seen=...)``) the pinned
         programs are the ones that exclude (:meth:`_warm_exclusion`: the
         int8 program at every history pad, the exact one at the
@@ -1792,6 +1930,7 @@ class ServingEngine:
         mask was traced with."""
         wide = self._proto(B, m.rank, wide=True)
         idx = m.index
+        shards = 1 if self.mesh is None else len(self._devices)
         if idx is not None and idx.seq == m.seq:
             for pad in m.seen.pads:
                 self._pin((B, self._int8_pin(idx), pad),
@@ -1800,15 +1939,20 @@ class ServingEngine:
                 self._emit_shortlist(B, idx, history_pad=pad, **(
                     {"delta_rows": idx.delta_slots} if idx.delta_slots
                     else {}))
-                cols = int(idx.Vq.shape[0])
+                # (a shard masks its own columns)
+                cols = int(idx.Vq.shape[0]) // shards
                 self._emit_exclusion(B, "int8", pad, cols,
                                      mask_block(cols))
+                if self.mesh is not None:
+                    obs.emit("serving_mesh_plan", bucket=B,
+                             **self._mesh_plan(m, idx, B, pad),
+                             **self._labels)
         pad = m.seen.pads[-1]
-        call = self._exact_call(m, wide, m.seen, pad)
-        self._pin((B, "exact", pad), call, run=True)
-        chunk = call[2]["item_chunk"]
-        self._emit_exclusion(B, "exact", pad,
-                             -(-int(m.V.shape[0]) // chunk) * chunk, chunk)
+        self._pin((B, "exact", pad), self._exact_call(m, wide, m.seen, pad),
+                  run=True)
+        cols, chunk = self._scan_columns(m)
+        self._emit_exclusion(B, "exact", pad, -(-cols // chunk) * chunk,
+                             chunk)
 
     def _emit_exclusion(self, bucket, path, pad, columns, block):
         obs.emit("serving_exclusion", bucket=bucket, path=path,
@@ -1861,14 +2005,15 @@ class ServingEngine:
         :meth:`warmup_live` pins (under :meth:`_int8_pin`) and
         :meth:`_dispatch` runs.  ``seen`` (with its history ``pad``):
         the batch excludes, and ``packed`` is the wide layout."""
-        # (a mesh engine never sees histories or lists: ``publish`` and
-        # ``submit`` refuse it them)
         if self.mesh is not None:
             k_loc, sk_loc = idx.shard_widths(self.k)
             return (_build_sharded_int8(
                 self.mesh, self.k, k_loc, sk_loc, idx.ni_loc,
                 bool(idx.delta_slots), _mesh_queries, _pack_response,
-                "serve_mesh_int8"), (m.U, packed, *idx.score_args()), {})
+                "serve_mesh_int8", pad),
+                (m.U, packed,
+                 *(() if seen is None else (seen.runs, seen.indices)),
+                 *idx.score_args()), {})
         return (_serve_int8_packed,
                 (m.U, idx.Vq, idx.sv, idx.V, idx.valid,
                  (*idx._seg, idx._last_id()) if idx.delta_slots else (),
@@ -1885,17 +2030,25 @@ class ServingEngine:
         catalog handle: per shard with a mesh, nothing uploaded but the
         staged batch (the last catalog id is on the device already:
         :meth:`_last_item`)."""
+        ni_loc, chunk = self._scan_columns(m)
         if self.mesh is None:
             return (_serve_exact_packed,
                     (m.U, m.V, m.valid,
                      () if seen is None else (seen.runs, seen.indices),
                      packed),
-                    dict(k=self.k, pad=pad, item_chunk=min(
-                        self.item_chunk, max(int(m.V.shape[0]), 1))))
-        ni_loc = int(m.V.shape[0]) // int(self.mesh.devices.size)
+                    dict(k=self.k, pad=pad, item_chunk=chunk))
         return (_build_mesh_exact(self.mesh, self.k, min(self.k, ni_loc),
-                                  ni_loc, min(self.item_chunk, ni_loc)),
-                (m.U, packed, m.V, m.valid, self._last_item(m.n_items)), {})
+                                  ni_loc, chunk, pad),
+                (m.U, packed,
+                 *(() if seen is None else (seen.runs, seen.indices)),
+                 m.V, m.valid, self._last_item(m.n_items)), {})
+
+    def _scan_columns(self, m):
+        """``(catalog rows the exact scan walks on one device, its
+        chunk)``: the whole table, or with a mesh one shard's slice."""
+        cols = int(m.V.shape[0]) // (1 if self.mesh is None
+                                     else len(self._devices))
+        return cols, min(self.item_chunk, max(cols, 1))
 
     def _last_item(self, n_items):
         """The last catalog id, replicated over the mesh (the exact
@@ -1907,25 +2060,31 @@ class ServingEngine:
                 np.int32(n_items - 1), self._replicated))
         return self._last_id[1]
 
-    def _mesh_plan(self, m, idx, bucket):
+    def _mesh_plan(self, m, idx, bucket, pad=None):
         """What one batch of ``bucket`` rows costs the mesh, scored by
-        ``idx`` (``None``: by the exact fallback): the fields of a
+        ``idx`` (``None``: by the exact fallback; ``pad``: a batch that
+        excludes, at that history pad): the fields of a
         ``serving_mesh_plan`` event, kept per (bucket, shapes) — the
-        engine thread looks ``exchange_bytes`` up for every batch."""
+        engine thread looks ``exchange_bytes`` and ``history_bytes`` up
+        for every batch."""
         S = int(self.mesh.devices.size)
         ni_loc = int(m.V.shape[0]) // S if idx is None else idx.ni_loc
         k_loc = (min(self.k, ni_loc) if idx is None
                  else idx.shard_widths(self.k)[0])
-        key = (bucket, ni_loc, k_loc, m.U.shape)
+        key = (bucket, ni_loc, k_loc, m.U.shape, pad)
         plan = self._plans.get(key)
         if plan is None:
+            wide = 0 if pad is None else MAX_EXCLUDE
             plan = self._plans[key] = dict(
                 shards=S, items_per_shard=ni_loc,
                 users_per_shard=int(m.U.shape[0]) // S, k_loc=k_loc,
                 placements=1,
-                spread_bytes=mesh_spread_bytes(S, bucket, m.rank),
+                spread_bytes=mesh_spread_bytes(S, bucket, m.rank, wide),
                 exchange_bytes=mesh_exchange_bytes(S, bucket, m.rank,
-                                                   k_loc))
+                                                   k_loc, wide),
+                history_pad=pad,
+                history_bytes=(0 if pad is None
+                               else mesh_history_bytes(S, bucket, pad)))
         return plan
 
     def warmup_publish(self, max_rows=LIVE_PADS[-1]):
@@ -2077,23 +2236,28 @@ class ServingEngine:
                 self._warm_histories(m, max_rows)
 
     def _warm_histories(self, m, max_rows):
-        """:meth:`warmup_histories`, under its locks."""
-        seen = m.seen
-        if seen.room is None:
-            seen = self._lay_out(seen, int(m.U.shape[0]))
-        runs, indices, room = seen.runs, seen.indices, seen.room
-        for pad in pads_up_to(max_rows):
-            # the plan by itself, and as the last rows of a publish's
-            # one array
-            ride = _Ride()
-            ride.plan = self._no_append(seen, pad)
-            for plan in (put(ride.plan), self._send(ride, seen)):
-                *runs, indices = _append_runs(*runs, indices, plan)
-        for width in seen.pads:
-            indices = _move_run(indices, 0, 0, width=width)  # onto itself
-        m = self._model = _Published(
-            m.seq, m.U, m.n_users, m.V, m.valid, m.index, m.n_items,
-            _Seen(tuple(runs), indices, seen.lengths, seen.pads, room))
+        """:meth:`warmup_histories`, under its locks.  On a mesh the
+        histories stay as published (nothing grows there yet:
+        ``publish_update`` refuses ``seen_appended``), and this pins and
+        runs the programs that exclude — given the segment, where the
+        index has one — and nothing else."""
+        if self.mesh is None:
+            seen = m.seen
+            if seen.room is None:
+                seen = self._lay_out(seen, int(m.U.shape[0]))
+            runs, indices, room = seen.runs, seen.indices, seen.room
+            for pad in pads_up_to(max_rows):
+                # the plan by itself, and as the last rows of a publish's
+                # one array
+                ride = _Ride()
+                ride.plan = self._no_append(seen, pad)
+                for plan in (put(ride.plan), self._send(ride, seen)):
+                    *runs, indices = _append_runs(*runs, indices, plan)
+            for width in seen.pads:
+                indices = _move_run(indices, 0, 0, width=width)  # onto itself
+            m = self._model = _Published(
+                m.seq, m.U, m.n_users, m.V, m.valid, m.index, m.n_items,
+                _Seen(tuple(runs), indices, seen.lengths, seen.pads, room))
         self._pinned.clear()
         for B in self.batcher.buckets:
             self._warm_exclusion(m, B)
@@ -2145,8 +2309,7 @@ class ServingEngine:
         (``ops.topk.excluded_mask`` states the rule).  Raises
         ``Overloaded`` when shedding, ``NoModelPublished`` before the
         first publish, ``ValueError`` on a malformed payload or list (a
-        longer one is refused, not cut), ``NotImplementedError`` for a
-        list where the scoring program cannot take one yet (a mesh).
+        longer one is refused, not cut).
         """
         t_enter = time.perf_counter()
         m = self._model
@@ -2205,10 +2368,6 @@ class ServingEngine:
                 f"exclude holds {ids.size} ids, a request may bring "
                 f"{MAX_EXCLUDE}: a longer list belongs to the user's "
                 "history (publish(user_seen=...))")
-        if self.mesh is not None:
-            raise NotImplementedError(
-                "exclude on a mesh engine: no pinned program of it "
-                "excludes yet")
         return ids.astype(np.int32)
 
     def recommend(self, payload, k=None, deadline_s=None, timeout=None,
@@ -2386,7 +2545,7 @@ class ServingEngine:
                 seen, pad, histories = m.seen, None, None
                 if seen is None and any(t.exclude is not None
                                         for t in live):
-                    seen = self._without_history()
+                    seen = self._without_history(m)
                 if seen is not None:
                     # read under the lock: a publish writes the lengths
                     histories = seen.lengths_of(live)
@@ -2618,10 +2777,13 @@ class ServingEngine:
         with TraceAnnotation("serve.batch.dispatch.launch",
                              seq=seq) as span:
             if self.mesh is not None:
+                plan = self._mesh_plan(m, index if use_index else None, B,
+                                       pad)
                 obs.counter("serving.mesh_exchange_bytes",
-                            self._mesh_plan(m, index if use_index else None,
-                                            B)["exchange_bytes"],
-                            **self._labels)
+                            plan["exchange_bytes"], **self._labels)
+                if seen is not None:
+                    obs.counter("serving.mesh_history_bytes",
+                                plan["history_bytes"], **self._labels)
             resp_dev = self._run_pinned(key, fn, args, statics)
             # a pin that failed was dropped inside the call
             span.set_metadata(program="jit_" + fn.__name__, pin=pin,

@@ -110,7 +110,7 @@ import numpy as np
 
 from tpu_als.core.foldin import put
 from tpu_als.core.ratings import _next_pow2, pad_for, pads_up_to
-from tpu_als.obs.schema import SERVE_MESH_SCOPES
+from tpu_als.obs.schema import SERVE_EXCLUDE_SCOPE, SERVE_MESH_SCOPES
 from tpu_als.ops.topk import (
     NEG_INF,
     NOT_AN_ID,
@@ -157,6 +157,18 @@ def mask_block(columns):
     return 128 if columns % 128 == 0 else columns
 
 
+def shard_lists(seen, first, columns):
+    """Lists of LOGICAL excluded ids (``ops.topk.excluded_mask``'s
+    ``seen``) as the lists of one shard's own columns: the shard holds
+    catalog ids ``[first, first + columns)``, an id inside them becomes
+    its column ``id - first``, every other ``NOT_AN_ID`` (another
+    shard's to mask; the padding stays what it is)."""
+    with jax.named_scope(SERVE_EXCLUDE_SCOPE):
+        return tuple(
+            jnp.where((ids >= first) & (ids - first < columns), ids - first,
+                      NOT_AN_ID) for ids in seen)
+
+
 def shortlist_rescore(U, Vq, sv, V, valid, *, k, shortlist_k, delta=None,
                       last_id=None, seen=None, shard=None):
     """THE scoring pipeline, traced into its caller's program: ``U``
@@ -195,11 +207,11 @@ def shortlist_rescore(U, Vq, sv, V, valid, *, k, shortlist_k, delta=None,
     ni_loc)``, which the returned ids are offset by; the (replicated)
     segment is scored by every shard but masked to the slots it OWNS, so
     each is scored exactly once mesh-wide.  No shard sees another's
-    rows."""
-    if seen is not None and shard is not None:
-        raise NotImplementedError(
-            "seen on a shard: the lists hold catalog ids, the mask a "
-            "shard's own columns")
+    rows.  ``seen`` holds LOGICAL ids, the same lists on every shard:
+    each masks the ids it owns among its own columns
+    (:func:`shard_lists`) — every excluded id is masked by exactly one
+    shard — and compares the segment's slots with the lists as they
+    are."""
     n, nb = U.shape[0], Vq.shape[0]
     # the catalog id of this shard's first row (without a shard nothing
     # is traced for it, no ``+ 0``)
@@ -224,8 +236,9 @@ def shortlist_rescore(U, Vq, sv, V, valid, *, k, shortlist_k, delta=None,
     ok = base_ok[None, :]
     if seen is not None:
         # block-major: transposed it is the row-major mask's own bytes
-        excluded = excluded_mask(seen, nb, mask_block(nb)).transpose(
-            1, 0, 2).reshape(n, nb)
+        excluded = excluded_mask(
+            seen if shard is None else shard_lists(seen, first, nb), nb,
+            mask_block(nb)).transpose(1, 0, 2).reshape(n, nb)
         ok = ok & ~excluded
     approx = jnp.where(ok, approx, NEG_INF)
     if delta:
@@ -757,57 +770,77 @@ def _shard_merge(s, gids, last_id, *, axis, k):
     return bs, jnp.minimum(bi, last_id)
 
 
-def mesh_exchange_bytes(n_shards, rows, rank, k_loc):
+def mesh_exchange_bytes(n_shards, rows, rank, k_loc, wide=0):
     """Bytes one device moves for one batch of the mesh engine's scoring
     program, by ``parallel.comm_audit``'s conventions (a test pins this
     to the traced program's): the ``psum`` that spreads the staged
-    ``[rows, rank + 2]`` int32 batch from the one shard it was placed on
-    (``serving.engine._mesh_spread``: see :func:`mesh_spread_bytes`),
+    ``[rows, rank + 2 + wide]`` int32 batch from the one shard it was
+    placed on (``serving.engine._mesh_spread``: see
+    :func:`mesh_spread_bytes`; ``wide``: the columns of the requests'
+    own lists of excluded ids, where the batch excludes),
     the by-id lookup's ``psum`` of the ``[rows, rank]`` f32 queries, a
     bidirectional-ring all-reduce, ``2 (S-1)/S`` of them, and the
     merge's two ``all_gather``s of the local ``[rows, k_loc]`` f32
     scores and int32 ids, ``(S-1)/S`` of the gathered ``[S, rows,
-    k_loc]`` each."""
+    k_loc]`` each.  What the users' histories add to a batch that
+    excludes is :func:`mesh_history_bytes`, counted beside this."""
     S = int(n_shards)
-    return (mesh_spread_bytes(S, rows, rank)
+    return (mesh_spread_bytes(S, rows, rank, wide)
             + 2 * (S - 1) * rows * rank * 4 // S
             + 2 * (S - 1) * rows * k_loc * 4)
 
 
-def mesh_spread_bytes(n_shards, rows, rank):
+def mesh_spread_bytes(n_shards, rows, rank, wide=0):
     """Of :func:`mesh_exchange_bytes`, the staged batch's spread alone:
     what the host's one placement a batch costs the ICI."""
     S = int(n_shards)
-    return 2 * (S - 1) * rows * (rank + 2) * 4 // S
+    return 2 * (S - 1) * rows * (rank + 2 + wide) * 4 // S
 
 
-@functools.lru_cache(maxsize=32)
+def mesh_history_bytes(n_shards, rows, pad):
+    """Bytes one device moves for the histories of one batch that
+    excludes, at history pad ``pad``: the ``psum`` of the ``[rows, pad]``
+    int32 lists (``serving.engine._mesh_history``: the owning shard's
+    ids, zeros from the others), the same all-reduce convention as
+    :func:`mesh_exchange_bytes` — all of the users' histories that ever
+    crosses a link after their publish."""
+    S = int(n_shards)
+    return 2 * (S - 1) * rows * pad * 4 // S
+
+
+@functools.lru_cache(maxsize=64)
 def _build_sharded_int8(mesh, k, k_loc, sk_loc, ni_loc, has_delta,
-                        lookup=None, pack=None, name="sharded_int8_topk"):
+                        lookup=None, pack=None, name="sharded_int8_topk",
+                        pad=None):
     """THE sharded scoring program, ``shard_map``'d and jitted under
     ``name``: per shard :func:`shortlist_rescore` over its slice of the
     catalog, :func:`_shard_merge` on every shard.  As
     :meth:`ShardedInt8Index.topk` builds it, it takes a batch of query
     VECTORS, replicated, and returns ``(scores, ids)``.  A serving
     engine's whole request path is the same program with ``lookup(U,
-    packed, me=, axis=)`` in front — the queries from ITS first two
+    packed, me=, axis=, pad=)`` in front — ``(the queries, the lists of
+    ids they are not to be answered with or None)`` from ITS first two
     arguments, a user table and a staged batch, both sharded by rows —
-    and ``pack(scores, ids)`` behind, one replicated result.  The three
-    steps lie in the scopes ``obs.schema.SERVE_MESH_SCOPES``."""
+    and ``pack(scores, ids)`` behind, one replicated result.  ``pad``: a
+    batch that excludes; the lookup then takes the users' histories too,
+    ``lookup(U, packed, runs, indices, ...)``, both sharded by rows, and
+    gives the lists at that history pad — one program a bucket and pad.
+    The steps lie in the scopes ``obs.schema.SERVE_MESH_SCOPES`` (the
+    lookup opens its own)."""
     P = jax.sharding.PartitionSpec
-    head = (P(),) if lookup is None else (P(AXIS), P(AXIS))
+    head = ((P(),) if lookup is None
+            else (P(AXIS),) * (2 if pad is None else 4))
 
     def program(*args):
         queries, (Vq, sv, V, valid, last_id, *delta) = (
             args[:len(head)], args[len(head):])
         me = jax.lax.axis_index(AXIS)
-        with jax.named_scope(SERVE_MESH_SCOPES[0]):
-            U = (queries[0] if lookup is None
-                 else lookup(*queries, me=me, axis=AXIS))
+        U, seen = ((queries[0], None) if lookup is None
+                   else lookup(*queries, me=me, axis=AXIS, pad=pad))
         with jax.named_scope(SERVE_MESH_SCOPES[1]):
             s, gids = shortlist_rescore(
                 U, Vq, sv, V, valid, k=k_loc, shortlist_k=sk_loc,
-                delta=delta, shard=(me, ni_loc))
+                delta=delta, seen=seen, shard=(me, ni_loc))
         with jax.named_scope(SERVE_MESH_SCOPES[2]):
             out = _shard_merge(s, gids, last_id, axis=AXIS, k=k)
             return out if pack is None else pack(*out)
@@ -819,7 +852,7 @@ def _build_sharded_int8(mesh, k, k_loc, sk_loc, ni_loc, has_delta,
         + (P(),) * (5 if has_delta else 0),
         out_specs=(P(), P()) if pack is None else P(), check_vma=False)),
         _build_sharded_int8, mesh, k, k_loc, sk_loc, ni_loc, has_delta,
-        lookup, pack, name)
+        lookup, pack, name, pad)
 
 
 def place_catalog(V, item_valid, mesh, shortlist_k=64):

@@ -27,7 +27,11 @@ from tpu_als.core.ratings import (
     pads_up_to,
     row_capacity,
 )
-from tpu_als.obs.schema import LIVE_BATCH_SPAN_KEYS, LIVE_FOLDIN_SPAN_KEYS
+from tpu_als.obs.schema import (
+    LIVE_BATCH_SPAN_KEYS,
+    LIVE_FOLDIN_SPAN_KEYS,
+    LIVE_PHASE_SPAN_KEYS,
+)
 from tpu_als.serving import ServingEngine
 from tpu_als.serving.engine import _scatter_users
 
@@ -668,7 +672,9 @@ def test_the_updaters_cycle_is_on_the_profilers_timeline(tmp_path):
         jax.profiler.stop_trace()
     spans = _live_spans(str(tmp_path))
     names = [s[0] for s in spans]
-    assert set(names) == set(LIVE_BATCH_SPAN_KEYS + LIVE_FOLDIN_SPAN_KEYS)
+    # (with the batch's phases, ISSUE 54: tests/test_live_phase_spans.py)
+    assert set(names) == set(LIVE_BATCH_SPAN_KEYS + LIVE_FOLDIN_SPAN_KEYS
+                             + LIVE_PHASE_SPAN_KEYS)
     for phase in ("live.batch", "live.batch.coalesce", "live.batch.foldin",
                   "live.batch.publish", "live.batch.foldin.readback"):
         assert names.count(phase) == 2

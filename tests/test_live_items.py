@@ -22,6 +22,7 @@ from tpu_als.obs.schema import (
     LIVE_BATCH_SPAN_KEYS,
     LIVE_FOLDIN_SPAN_KEYS,
     LIVE_ITEM_SPAN_KEYS,
+    LIVE_PHASE_SPAN_KEYS,
 )
 from tpu_als.serving import ServingEngine, build_index
 from tpu_als.serving.engine import _scatter_items
@@ -600,7 +601,8 @@ def test_an_items_updaters_timeline_holds_the_item_spans(tmp_path):
              if ev.name.startswith("live.")]
     names = [n for n, _ in spans]
     assert set(names) == set(LIVE_BATCH_SPAN_KEYS + LIVE_ITEM_SPAN_KEYS
-                             + LIVE_FOLDIN_SPAN_KEYS)
+                             + LIVE_FOLDIN_SPAN_KEYS
+                             + LIVE_PHASE_SPAN_KEYS)
     assert names.count("live.batch.foldin.users") == names.count(
         "live.batch.foldin.items") == names.count("live.batch") == 70
     assert names.count("live.batch.publish.compact") == 1   # at 64 rows

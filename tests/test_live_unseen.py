@@ -37,6 +37,7 @@ from tpu_als.obs.schema import (  # noqa: E402
     LIVE_BATCH_SPAN_KEYS,
     LIVE_FOLDIN_SPAN_KEYS,
     LIVE_HISTORY_SPAN_KEYS,
+    LIVE_PHASE_SPAN_KEYS,
 )
 from tpu_als.serving import ServingEngine  # noqa: E402
 from tpu_als.serving.engine import history_pads  # noqa: E402
@@ -446,7 +447,7 @@ def test_the_publish_writes_its_history_span(tmp_path):
     spans = program_spans.read(path, prefix="live.")
     names = {s[0] for s in spans}
     assert names == set(LIVE_BATCH_SPAN_KEYS + LIVE_FOLDIN_SPAN_KEYS
-                        + LIVE_HISTORY_SPAN_KEYS)
+                        + LIVE_HISTORY_SPAN_KEYS + LIVE_PHASE_SPAN_KEYS)
     history = [s for s in spans if s[0] == LIVE_HISTORY_SPAN_KEYS[0]]
     assert len(history) == 3
     for s in history:

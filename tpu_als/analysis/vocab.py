@@ -102,8 +102,10 @@ TRACE_RECORD_RE = re.compile(
 # thread's ``serve.`` spans by name), so it is vocabulary like the rest:
 # declared in schema.SERVE_BATCH_SPAN_KEYS (the updater thread's ``live.``
 # spans: schema.LIVE_BATCH_SPAN_KEYS)
+# (``Stamped``: serving/engine.py's TraceAnnotation with a CPU account)
 ANNOTATION_RE = re.compile(
-    r"\bTraceAnnotation\(\s*(?P<q>['\"])(?P<name>[^'\"]+)(?P=q)")
+    r"\b(?:TraceAnnotation|Stamped)\(\s*"
+    r"(?P<q>['\"])(?P<name>[^'\"]+)(?P=q)")
 # the schema's tuples of profiler span names, each with the packages
 # under tpu_als/ whose TraceAnnotations open them
 PROFILER_SPAN_TUPLES = (
@@ -114,6 +116,7 @@ PROFILER_SPAN_TUPLES = (
     ("LIVE_ITEM_SPAN_KEYS", ("live", "serving")),
     ("LIVE_HISTORY_SPAN_KEYS", ("serving",)),
     ("LIVE_FOLDIN_SPAN_KEYS", ("stream",)),
+    ("LIVE_PHASE_SPAN_KEYS", ("live", "stream", "serving")),
 )
 
 # inline event dicts: a line carrying both a "ts" key and a literal
@@ -350,7 +353,8 @@ def check_tenant_vocabulary(repo=REPO):
         | {"tenant", "trace_id", "trace_ids"}
     for attr in ("SERVE_SPAN_KEYS", "SERVE_BATCH_SPAN_KEYS",
                  "LIVE_SPAN_KEYS", "LIVE_BATCH_SPAN_KEYS",
-                 "LIVE_ITEM_SPAN_KEYS", "LIVE_HISTORY_SPAN_KEYS"):
+                 "LIVE_ITEM_SPAN_KEYS", "LIVE_HISTORY_SPAN_KEYS",
+                 "LIVE_PHASE_SPAN_KEYS"):
         overlap = sorted(set(getattr(schema, attr, ())) & reserved)
         if overlap:
             errors.append(

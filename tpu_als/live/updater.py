@@ -63,8 +63,14 @@ call and the blocking read of its rows
 ``live.batch``, ``.foldin`` and ``.publish`` carry ``cpu_us`` beside
 ``wall_us`` while a profiler session records: the thread's own CPU time
 inside the span (``serving.engine.cpu_mark`` / ``stamp_cpu``; the batch
-record's ``foldin_cpu``, ``publish_cpu``).  A trace reader keys on those
-names.
+record's ``foldin_cpu``, ``publish_cpu``).  Since ISSUE 54 the batch is
+tiled by phase (``obs.schema.LIVE_PHASE_SPAN_KEYS``, each a
+``serving.engine.Stamped`` span with the same two stats, opened a batch
+and never an event): ``live.batch.prepare`` and ``live.batch.record``
+around the two phases here, ``live.batch.publish.join`` ahead of the
+engine's call, the fold's phases in stream/microbatch.py and the
+publish's in serving/engine.py.  A trace reader keys on those names
+(benchmark/live_phase_spans.py).
 
 Freshness (``live.freshness_seconds``) is per EVENT, arrival →
 publish-visible, so the histogram's p99 is exactly the SLO quantity:
@@ -91,7 +97,7 @@ from tpu_als.obs import tracing
 from tpu_als.obs.trace import FlightRecorder
 from tpu_als.resilience import faults
 from tpu_als.serving.batcher import Overloaded
-from tpu_als.serving.engine import cpu_mark, stamp_cpu
+from tpu_als.serving.engine import Stamped, cpu_mark, stamp_cpu
 
 # the per-batch span breakdown the updater's flight ring carries
 # (source of truth in the stdlib-only schema module, where the jax-free
@@ -294,44 +300,45 @@ class LiveUpdater:
         ``live.batch`` span around the call, which takes the batch's
         sizes as its stats."""
         t0, placed_before = time.perf_counter(), placements()
-        users, items, ratings, arrivals, ctxs = map(list, zip(*batch))
-        users, items = np.asarray(users), np.asarray(items)
-        ratings = np.asarray(ratings, dtype=np.float32)
-        arrivals = np.asarray(arrivals)
-        # chain the queue hop per event (its own wait, not the batch's)
-        ctxs = [tracing.record_span(c, "live.queue", seconds=t0 - a)
-                if c is not None else None
-                for c, a in zip(ctxs, arrivals)]
-        queue_wait = t0 - float(arrivals.min())
+        with Stamped("live.batch.prepare"):
+            users, items, ratings, arrivals, ctxs = map(list, zip(*batch))
+            users, items = np.asarray(users), np.asarray(items)
+            ratings = np.asarray(ratings, dtype=np.float32)
+            arrivals = np.asarray(arrivals)
+            # chain the queue hop per event (its own wait, not the batch's)
+            ctxs = [tracing.record_span(c, "live.queue", seconds=t0 - a)
+                    if c is not None else None
+                    for c, a in zip(ctxs, arrivals)]
+            queue_wait = t0 - float(arrivals.min())
 
-        # quarantine BEFORE the factors can see a poisoned value — the
-        # streaming-ingest contract, same event + counter vocabulary
-        bad = invalid_rating_mask(ratings)
-        n_bad = int(bad.sum())
-        if n_bad:
-            nonfinite = int((~np.isfinite(ratings)).sum())
-            obs.counter("ingest.quarantined_rows", n_bad)
-            obs.emit("ingest_quarantined", path="live", rows=n_bad,
-                     reasons={"nonfinite": nonfinite,
-                              "out_of_range": n_bad - nonfinite},
-                     **self._labels)
-            keep = ~bad
-            for c, dropped in zip(ctxs, bad):
-                # a poisoned event's trail ENDS at quarantine — status
-                # says so; the trace is complete, not dropped
-                if dropped and c is not None:
-                    tracing.record_span(c, "live.quarantine",
-                                        status="quarantined")
-            users, items = users[keep], items[keep]
-            ratings, arrivals = ratings[keep], arrivals[keep]
-            ctxs = [c for c, k in zip(ctxs, keep) if k]
-        quarantine_s = time.perf_counter() - t0
-        obs.histogram("live.batch_rows", len(ratings), **self._labels)
-        if len(ratings) == 0:
-            self.flight.record(
-                "quarantined",
-                {"queue_wait": queue_wait, "quarantine": quarantine_s})
-            return
+            # quarantine BEFORE the factors can see a poisoned value — the
+            # streaming-ingest contract, same event + counter vocabulary
+            bad = invalid_rating_mask(ratings)
+            n_bad = int(bad.sum())
+            if n_bad:
+                nonfinite = int((~np.isfinite(ratings)).sum())
+                obs.counter("ingest.quarantined_rows", n_bad)
+                obs.emit("ingest_quarantined", path="live", rows=n_bad,
+                         reasons={"nonfinite": nonfinite,
+                                  "out_of_range": n_bad - nonfinite},
+                         **self._labels)
+                keep = ~bad
+                for c, dropped in zip(ctxs, bad):
+                    # a poisoned event's trail ENDS at quarantine — status
+                    # says so; the trace is complete, not dropped
+                    if dropped and c is not None:
+                        tracing.record_span(c, "live.quarantine",
+                                            status="quarantined")
+                users, items = users[keep], items[keep]
+                ratings, arrivals = ratings[keep], arrivals[keep]
+                ctxs = [c for c, k in zip(ctxs, keep) if k]
+            quarantine_s = time.perf_counter() - t0
+            obs.histogram("live.batch_rows", len(ratings), **self._labels)
+            if len(ratings) == 0:
+                self.flight.record(
+                    "quarantined",
+                    {"queue_wait": queue_wait, "quarantine": quarantine_s})
+                return
 
         p = self.foldin.model._params
         frame = {p["userCol"]: users, p["itemCol"]: items,
@@ -359,99 +366,104 @@ class LiveUpdater:
         tp = time.perf_counter()
         with TraceAnnotation("live.batch.publish") as span:
             mark = cpu_mark()
-            # the rows the fold moved, and nothing else of either table;
-            # with them, where the engine keeps the users' histories, the
-            # ids these ratings add to them (one publish, one generation)
-            grown, parts = {}, {}
-            if self._histories:
-                grown["seen_appended"] = self._joining()
-                parts["history_ids"] = len(grown["seen_appended"][0])
-            if self.fold_items:
-                parts["items"] = len(touched_item_rows)
-            if parts:
-                span.set_metadata(**parts)
-            # and the same rows where the folds left them on the device:
-            # the engine writes its tables from there, not from the
-            # host's copies (no keyword where no fold left any: an
-            # engine that knows none is asked nothing new)
-            held = {side: self.foldin.last_rows[side]
-                    for side in ("users", "items")[:1 + self.fold_items]
-                    if self.foldin.last_rows[side] is not None}
-            if held:
-                grown["device_rows"] = held
+            with Stamped("live.batch.publish.join"):
+                # the rows the fold moved, and nothing else of either
+                # table; with them, where the engine keeps the users'
+                # histories, the ids these ratings add to them (one
+                # publish, one generation)
+                grown, parts = {}, {}
+                if self._histories:
+                    grown["seen_appended"] = self._joining()
+                    parts["history_ids"] = len(grown["seen_appended"][0])
+                if self.fold_items:
+                    parts["items"] = len(touched_item_rows)
+                if parts:
+                    span.set_metadata(**parts)
+                # and the same rows where the folds left them on the
+                # device: the engine writes its tables from there, not
+                # from the host's copies (no keyword where no fold left
+                # any: an engine that knows none is asked nothing new)
+                held = {side: self.foldin.last_rows[side]
+                        for side in ("users", "items")[:1 + self.fold_items]
+                        if self.foldin.last_rows[side] is not None}
+                if held:
+                    grown["device_rows"] = held
+                touched_user_rows = m._user_map.to_dense(touched_users)
             seq, mode = self.engine.publish_update(
                 m._U, m._V, touched_items=touched_item_rows,
-                touched_users=m._user_map.to_dense(touched_users),
-                trace=ctxs, **grown)
+                touched_users=touched_user_rows, trace=ctxs, **grown)
             publish_cpu = stamp_cpu(span, mark)
         publish_s = time.perf_counter() - tp
-        sizes = {}
-        if self.fold_items:
-            index = self.engine.published_index
-            sizes = {"items": len(touched_item_rows),
-                     "new_items": len(m._item_map) - items_before,
-                     "segment_rows": (index.delta_count
-                                      if index is not None else 0)}
-            obs.counter("live.items_appended", sizes["new_items"],
-                        **self._labels)
-            did = self.foldin.last_items
-            obs.counter("live.items_folded", did["first"], kind="first",
-                        **self._labels)
-            obs.counter("live.items_folded", did["again"], kind="again",
-                        **self._labels)
-            obs.counter("live.items_left_to_refit", did["left_to_refit"],
-                        **self._labels)
-            obs.gauge("live.events_waiting", self.foldin.events_waiting,
-                      **self._labels)
-        # host→device placements this thread made for the batch, beside
-        # its programs' calls (``core.foldin.put``)
-        made = placements() - placed_before
-        obs.counter("live.host_placements", made, **self._labels)
-        whole.set_metadata(
-            events=len(ratings), users=len(touched_users),
-            new_users=len(m._user_map) - users_before,
-            width=width, mode=mode, placements=made, **sizes)
-        ctxs = [tracing.record_span(c, "live.publish",
-                                    seconds=publish_s, seq=seq,
-                                    mode=mode)
-                if c is not None else None for c in ctxs]
+        # the batch's bookkeeping: counters, the span's stats, the
+        # freshness samples, the flight record
+        with Stamped("live.batch.record"):
+            sizes = {}
+            if self.fold_items:
+                index = self.engine.published_index
+                sizes = {"items": len(touched_item_rows),
+                         "new_items": len(m._item_map) - items_before,
+                         "segment_rows": (index.delta_count
+                                          if index is not None else 0)}
+                obs.counter("live.items_appended", sizes["new_items"],
+                            **self._labels)
+                did = self.foldin.last_items
+                obs.counter("live.items_folded", did["first"], kind="first",
+                            **self._labels)
+                obs.counter("live.items_folded", did["again"], kind="again",
+                            **self._labels)
+                obs.counter("live.items_left_to_refit", did["left_to_refit"],
+                            **self._labels)
+                obs.gauge("live.events_waiting", self.foldin.events_waiting,
+                          **self._labels)
+            # host→device placements this thread made for the batch, beside
+            # its programs' calls (``core.foldin.put``)
+            made = placements() - placed_before
+            obs.counter("live.host_placements", made, **self._labels)
+            whole.set_metadata(
+                events=len(ratings), users=len(touched_users),
+                new_users=len(m._user_map) - users_before,
+                width=width, mode=mode, placements=made, **sizes)
+            ctxs = [tracing.record_span(c, "live.publish",
+                                        seconds=publish_s, seq=seq,
+                                        mode=mode)
+                    if c is not None else None for c in ctxs]
 
-        done = time.perf_counter()
-        fresh = done - arrivals
-        obs.histogram_many("live.freshness_seconds", fresh.tolist(),
-                           **self._labels)
-        for fr, c in zip(fresh, ctxs):
-            # the terminal hop: this event's publish seq is now visible
-            # to the score path; its seconds ARE the freshness sample
-            if c is not None:
-                tracing.record_span(c, "live.visible", seconds=float(fr),
-                                    seq=seq)
-        worst, worst_ctx = float(fresh.max()), ctxs[int(fresh.argmax())]
-        touched = len(touched_users) + (
-            len(touched_item_rows) if touched_item_rows is not None
-            else 0)
-        obs.emit("live_update", seq=seq, events=len(ratings),
-                 touched=touched, mode=mode, **self._labels)
-        self.flight.record(
-            "ok",
-            {"queue_wait": queue_wait, "quarantine": quarantine_s,
-             "foldin": foldin_s, "publish": publish_s},
-            e2e_seconds=worst, seq=seq, mode=mode,
-            # for whoever runs no profiler: how many events became
-            # visible, and when on perf_counter's clock (the engine's
-            # batch records carry their ``t0`` the same way)
-            events=len(ratings), t_done=done, **sizes,
-            # the thread's own CPU seconds in the two phases, beside
-            # their wall seconds above (None: no profiler recorded):
-            # what is missing it spent waiting, for the interpreter or
-            # for the device
-            foldin_cpu=foldin_cpu, publish_cpu=publish_cpu,
-            trace_ids=sorted({c.trace_id for c in ctxs
-                              if c is not None}) or None)
-        if self.slo_s is not None and worst > self.slo_s:
-            obs.emit("live_freshness_breach", seq=seq,
-                     freshness_seconds=worst, slo_s=self.slo_s,
-                     trace_id=(worst_ctx.trace_id
-                               if worst_ctx is not None else None),
-                     **self._labels)
-            self.flight.dump("freshness_breach")
+            done = time.perf_counter()
+            fresh = done - arrivals
+            obs.histogram_many("live.freshness_seconds", fresh.tolist(),
+                               **self._labels)
+            for fr, c in zip(fresh, ctxs):
+                # the terminal hop: this event's publish seq is now visible
+                # to the score path; its seconds ARE the freshness sample
+                if c is not None:
+                    tracing.record_span(c, "live.visible", seconds=float(fr),
+                                        seq=seq)
+            worst, worst_ctx = float(fresh.max()), ctxs[int(fresh.argmax())]
+            touched = len(touched_users) + (
+                len(touched_item_rows) if touched_item_rows is not None
+                else 0)
+            obs.emit("live_update", seq=seq, events=len(ratings),
+                     touched=touched, mode=mode, **self._labels)
+            self.flight.record(
+                "ok",
+                {"queue_wait": queue_wait, "quarantine": quarantine_s,
+                 "foldin": foldin_s, "publish": publish_s},
+                e2e_seconds=worst, seq=seq, mode=mode,
+                # for whoever runs no profiler: how many events became
+                # visible, and when on perf_counter's clock (the engine's
+                # batch records carry their ``t0`` the same way)
+                events=len(ratings), t_done=done, **sizes,
+                # the thread's own CPU seconds in the two phases, beside
+                # their wall seconds above (None: no profiler recorded):
+                # what is missing it spent waiting, for the interpreter or
+                # for the device
+                foldin_cpu=foldin_cpu, publish_cpu=publish_cpu,
+                trace_ids=sorted({c.trace_id for c in ctxs
+                                  if c is not None}) or None)
+            if self.slo_s is not None and worst > self.slo_s:
+                obs.emit("live_freshness_breach", seq=seq,
+                         freshness_seconds=worst, slo_s=self.slo_s,
+                         trace_id=(worst_ctx.trace_id
+                                   if worst_ctx is not None else None),
+                         **self._labels)
+                self.flight.dump("freshness_breach")

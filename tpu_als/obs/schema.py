@@ -467,7 +467,10 @@ SERVE_BATCH_SPAN_KEYS = (
     #                           The RECORD's is the batch's whole life,
     #                           stage to its last ticket's bookkeeping
     "serve.batch.stage",      # E: the wait for the user table's lock, then
-    #                           expiry check + staging into the upload array
+    #                           expiry check + staging into the upload
+    #                           array (lock_wait_us: that wait alone, the
+    #                           record's ``lock_wait`` — what a publish's
+    #                           ``live.batch.publish.writes`` kept it out)
     "serve.batch.dispatch",   # E: upload + the scoring call, until it
     #                           returns
     "serve.batch.readback",   # C: the one bulk device->host transfer (seq)
@@ -618,6 +621,87 @@ LIVE_FOLDIN_SPAN_KEYS = (
     #                                before) — and its rows read back:
     #                                blocks on the device (side: users
     #                                | items)
+)
+# one ``live.batch`` tiled by phase (ISSUE 54): every millisecond of the
+# updater thread's batch lies under one of these or under one of the
+# spans above (``live.batch.foldin.readback``, ``.publish.history``,
+# ``.publish.compact``), each written a BATCH of events and never an
+# event, each with ``cpu_us`` / ``wall_us`` under a profiler
+# (serving/engine.py ``Stamped``).  The tree of one batch, a child
+# indented under its parent (docs/observability.md has it whole):
+#
+#   live.batch
+#     .prepare
+#     .foldin [.users | .items with fold_items]
+#       .group .history .map .pack .readback{.call} .write_back
+#     .publish
+#       .join .users .history .catalog{.ride .compact} .send{.ride}
+#       .lock_wait .writes .after
+#     .record
+#
+# benchmark/live_phase_spans.py keys on the names: what a container
+# (``live.batch``, ``.foldin``, ``.foldin.users`` / ``.items``,
+# ``.publish``) holds outside its children is the batch's UNSPLIT time
+LIVE_PHASE_SPAN_KEYS = (
+    "live.batch.prepare",            # updater: _process up to the fold —
+    #                                  the events' arrays, their queue
+    #                                  hops, the quarantine mask
+    "live.batch.foldin.group",       # microbatch (side on each of the
+    #                                  fold's): the frame's columns, the
+    #                                  items left to the refit, events
+    #                                  grouped by entity
+    "live.batch.foldin.history",     # events put behind each entity's
+    #                                  history (_resident, _one_rating_each,
+    #                                  ratings held for a side without a
+    #                                  factor)
+    "live.batch.foldin.map",         # the merged histories' ids mapped to
+    #                                  table rows (ratings: how many), the
+    #                                  usable counted, who is folded
+    "live.batch.foldin.pack",        # one call's ids, stars and mask
+    #                                  into the ONE host array it rides
+    "live.batch.foldin.call",        # the fold-in program called, until
+    #                                  the call returns (rows, width: the
+    #                                  padded shape; calls: which of the
+    #                                  fold's calls) — INSIDE
+    #                                  ``.foldin.readback``, whose rest is
+    #                                  the blocking read of the rows
+    "live.batch.foldin.write_back",  # the rows into the host's table and
+    #                                  the other direction's fixed table
+    "live.batch.publish.join",       # updater: what the publish is
+    #                                  handed — the ids that join their
+    #                                  users' histories (_joining), the
+    #                                  folds' rows on the device, the
+    #                                  touched users' table rows
+    "live.batch.publish.users",      # engine: _update_users — which rows
+    #                                  the user table takes, and how
+    "live.batch.publish.catalog",    # engine: what becomes of the catalog
+    #                                  — carried and the index re-tagged,
+    #                                  or (_write_catalog) the segment's
+    #                                  update planned and written, around
+    #                                  a ``.ride`` and, where the segment
+    #                                  is full, a ``.compact``
+    "live.batch.publish.send",       # engine: the last before the lock —
+    #                                  the ids that name a segment's item
+    #                                  counted, the ``.ride`` where the
+    #                                  catalog sent none, the history's
+    #                                  plan placed
+    "live.batch.publish.ride",       # engine: _send — the publish's ONE
+    #                                  int32[PUBLISH_SENT, pad] built and
+    #                                  placed (bytes); inside ``.catalog``
+    #                                  or ``.send``
+    "live.batch.publish.lock_wait",  # engine: _swap until _table_lock is
+    #                                  held
+    "live.batch.publish.writes",     # engine: _swap under the lock — the
+    #                                  donating row writes dispatched
+    #                                  (programs: how many), the
+    #                                  generation swapped; how long a
+    #                                  batch's stage can be kept out
+    "live.batch.publish.after",      # engine: both locks given back —
+    #                                  the counters, the serving_publish
+    #                                  event
+    "live.batch.record",             # updater: counters, the batch
+    #                                  span's stats, the freshness
+    #                                  samples, the flight record
 )
 
 # field names every flight record (and its flight_record event) claims

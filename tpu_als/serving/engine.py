@@ -49,7 +49,12 @@ stack plugs into:
   a batch carries its ``seq``, and — while a profiler session records —
   each phase ``cpu_us`` and ``wall_us``, the thread's own CPU time
   inside it beside the wall time of the same interval
-  (:func:`cpu_mark`, :func:`stamp_cpu`).  The engine thread's wait
+  (:func:`cpu_mark`, :func:`stamp_cpu`), and the stage span
+  ``lock_wait_us``, its wait for ``_table_lock``.  A publisher's thread
+  writes the publish's phases (:class:`Stamped`: ``live.batch.publish.
+  users``, ``.catalog``, ``.send``, ``.ride``, ``.lock_wait``,
+  ``.writes``, ``.after``; ``LIVE_PHASE_SPAN_KEYS``).  The engine
+  thread's wait
   for a slot is ``pipe.slot_wait`` (``PIPE_SPAN_KEYS``), outside the
   ``serve.`` prefix: a trace reader counts every ``serve.`` span as a
   phase of a batch.  What the completion thread waits for when it has
@@ -424,6 +429,25 @@ def stamp_cpu(span, mark):
     cpu_ns = time.thread_time_ns() - mark[0]
     span.set_metadata(cpu_us=cpu_ns // 1000, wall_us=wall_ns // 1000)
     return 1e-9 * cpu_ns
+
+
+class Stamped(TraceAnnotation):
+    """A ``TraceAnnotation`` that carries its own CPU account
+    (:func:`cpu_mark`, :func:`stamp_cpu`): one phase of the live write
+    path (``obs.schema.LIVE_PHASE_SPAN_KEYS``), opened a BATCH of events
+    and never an event — a microsecond with no profiler, and two
+    readings of each clock while one records.  A phase that raises
+    closes unstamped."""
+
+    def __enter__(self):
+        super().__enter__()
+        self._mark = cpu_mark()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if exc_type is None:
+            stamp_cpu(self, self._mark)
+        return super().__exit__(exc_type, exc, tb)
 
 
 class NoModelPublished(RuntimeError):
@@ -1045,33 +1069,46 @@ class ServingEngine:
         under the lock (``"replaced"``, with a warning); without it the
         warning names the state — every batch fails until a ``publish``
         — and the error is raised."""
-        with self._table_lock:
-            if how == "inplace":
-                table = self._model.U
-                try:
-                    users = (_scatter_users if self.mesh is None else
-                             _build_mesh_scatter(self.mesh))(table, *users)
-                except Exception as e:
-                    if not self._model.U.is_deleted():
-                        raise           # nothing was donated: all whole
-                    fate = ("re-placed whole" if host is not None else
-                            "NO user table until the next publish")
-                    obs.emit("warning", what="serving.publish_update",
-                             reason="row write failed after donating the "
-                                    f"user table ({type(e).__name__}: {e}): "
-                                    f"{fate}")
-                    if host is None:
-                        raise
-                    # a deleted table still reads its shape: same capacity
-                    how, users = "replaced", self._place_users(
-                        self._model, host)[0]
-            if items is not None:
-                V, valid = _scatter_items(self._model.V, self._model.valid,
-                                          *items)
-            if appended is not None:
-                seen = self._write_history(self._model.seen, appended)
-            self._model = _Published(seq, users, n_users, V, valid, index,
-                                     n_items, seen)
+        # the wait for the lock and what is done under it are two phases
+        # of a publish on the profiler's timeline (the second is how long
+        # a batch's stage can be kept out): ``programs``, the donating
+        # calls dispatched under this hold
+        programs = (int(how == "inplace") + int(items is not None)
+                    + (0 if appended is None else 1 + len(appended.moves)))
+        with Stamped("live.batch.publish.lock_wait"):
+            self._table_lock.acquire()
+        try:
+            with Stamped("live.batch.publish.writes", programs=programs):
+                if how == "inplace":
+                    table = self._model.U
+                    try:
+                        users = (_scatter_users if self.mesh is None else
+                                 _build_mesh_scatter(self.mesh))(table,
+                                                                 *users)
+                    except Exception as e:
+                        if not self._model.U.is_deleted():
+                            raise       # nothing was donated: all whole
+                        fate = ("re-placed whole" if host is not None else
+                                "NO user table until the next publish")
+                        obs.emit("warning", what="serving.publish_update",
+                                 reason="row write failed after donating "
+                                        "the user table "
+                                        f"({type(e).__name__}: {e}): {fate}")
+                        if host is None:
+                            raise
+                        # a deleted table still reads its shape: same
+                        # capacity
+                        how, users = "replaced", self._place_users(
+                            self._model, host)[0]
+                if items is not None:
+                    V, valid = _scatter_items(self._model.V,
+                                              self._model.valid, *items)
+                if appended is not None:
+                    seen = self._write_history(self._model.seen, appended)
+                self._model = _Published(seq, users, n_users, V, valid,
+                                         index, n_items, seen)
+        finally:
+            self._table_lock.release()
         return how
 
     def _compact_rows(self, index):
@@ -1351,18 +1388,20 @@ class ServingEngine:
         the device."""
         parts = [a for a in (ride.rows, ride.segment, ride.plan)
                  if a is not None]
-        sent = np.zeros(
-            (PUBLISH_SENT, max(pad, *(a.shape[-1] for a in parts))),
-            np.int32)
-        if ride.rows is not None:
-            sent[0, :len(ride.rows)] = ride.rows
-        if ride.segment is not None:
-            sent[SENT_SEGMENT:SENT_SEGMENT + SEGMENT_SENT,
-                 :ride.segment.shape[1]] = ride.segment
-        if ride.plan is not None:
-            sent[-SENT_PLAN:] = self._no_append(seen, sent.shape[1])
-            sent[-SENT_PLAN:, :ride.plan.shape[1]] = ride.plan
-        ride.sent = put(sent)
+        with Stamped("live.batch.publish.ride") as span:
+            sent = np.zeros(
+                (PUBLISH_SENT, max(pad, *(a.shape[-1] for a in parts))),
+                np.int32)
+            if ride.rows is not None:
+                sent[0, :len(ride.rows)] = ride.rows
+            if ride.segment is not None:
+                sent[SENT_SEGMENT:SENT_SEGMENT + SEGMENT_SENT,
+                     :ride.segment.shape[1]] = ride.segment
+            if ride.plan is not None:
+                sent[-SENT_PLAN:] = self._no_append(seen, sent.shape[1])
+                sent[-SENT_PLAN:, :ride.plan.shape[1]] = ride.plan
+            ride.sent = put(sent)
+            span.set_metadata(bytes=sent.nbytes)
         return ride.sent
 
     def _place_plan(self, appended, ride):
@@ -1698,8 +1737,9 @@ class ServingEngine:
         with self._publish_lock:
             seq = self._seq + 1
             prev = self._model
-            how, users, n_users, h2d = self._update_users(
-                prev, U, touched_users, placed.get("users"), ride)
+            with Stamped("live.batch.publish.users"):
+                how, users, n_users, h2d = self._update_users(
+                    prev, U, touched_users, placed.get("users"), ride)
             # planned against the catalog as THIS publish leaves it: an
             # id it appends may name an item it appends
             # (a mesh's histories do not grow: carried as they are, and
@@ -1718,69 +1758,71 @@ class ServingEngine:
                                                   seen_appended))
             if appended is not None:
                 ride.plan = appended.args
-            cur = prev.index if prev is not None else None
-            fresh = (cur is not None and cur.seq == prev.seq
-                     and cur.n_items <= Ni)
-            rows = (np.union1d(touched, np.arange(cur.n_items, Ni))
-                    if fresh else touched)
-            # what becomes of the catalog: ``carried`` as it is, or the
-            # touched rows written (``delta``), or uploaded whole
-            # (``replaced``); ``items``: the rows for the engine's own
-            # table, written in place by ``_swap``
-            catalog, items, index, mode, sent = "replaced", None, None, \
-                "full", 0
-            compacted = False
-            if (prev is not None and not touched.size
-                    and item_valid is None and prev.n_items == Ni):
-                # nothing of the catalog changed: the device's copy stays
-                V, valid, catalog = prev.V, prev.valid, "carried"
-                if fresh:
+            with Stamped("live.batch.publish.catalog"):
+                cur = prev.index if prev is not None else None
+                fresh = (cur is not None and cur.seq == prev.seq
+                         and cur.n_items <= Ni)
+                rows = (np.union1d(touched, np.arange(cur.n_items, Ni))
+                        if fresh else touched)
+                # what becomes of the catalog: ``carried`` as it is, or the
+                # touched rows written (``delta``), or uploaded whole
+                # (``replaced``); ``items``: the rows for the engine's own
+                # table, written in place by ``_swap``
+                catalog, items, index, mode, sent = "replaced", None, None, \
+                    "full", 0
+                compacted = False
+                if (prev is not None and not touched.size
+                        and item_valid is None and prev.n_items == Ni):
+                    # nothing of the catalog changed: the device's copy stays
+                    V, valid, catalog = prev.V, prev.valid, "carried"
+                    if fresh:
+                        index, mode = cur.retag(seq), "retag"
+                elif fresh and not rows.size:
+                    # a validity mask alone, no row named: the index is
+                    # carried as it is (the caller's guarantee)
                     index, mode = cur.retag(seq), "retag"
-            elif fresh and not rows.size:
-                # a validity mask alone, no row named: the index is
-                # carried as it is (the caller's guarantee)
-                index, mode = cur.retag(seq), "retag"
-            elif fresh:
-                try:
-                    index, items, sent, compacted = self._write_catalog(
-                        Vh, rows, item_valid, seq, placed.get("items"),
-                        ride)
-                    mode, catalog, prev = "delta", "delta", self._model
-                    V, valid = prev.V, prev.valid
-                except ValueError as e:
-                    obs.emit("warning", what="serving.publish_update",
-                             reason=f"delta rejected, full rebuild: {e}")
-            if catalog != "carried" and items is None:
-                # no row write to be had (no live index to take the
-                # rows, a mesh, spare rows used up): the whole catalog
-                valid_h = (np.ones(Ni, dtype=bool) if item_valid is None
-                           else item_valid)
-                V, valid = self._place_catalog(Vh, valid_h)
-                sent += Vh.nbytes + valid_h.nbytes
-            if index is None:
-                sk = min(max(self.shortlist_k, self.k), Ni)
-                if sk >= self.k and Ni > 0:
-                    index = self._build_index(V, valid, Ni, sk, seq)
-                else:
-                    mode = "none"
+                elif fresh:
+                    try:
+                        index, items, sent, compacted = self._write_catalog(
+                            Vh, rows, item_valid, seq, placed.get("items"),
+                            ride)
+                        mode, catalog, prev = "delta", "delta", self._model
+                        V, valid = prev.V, prev.valid
+                    except ValueError as e:
+                        obs.emit("warning", what="serving.publish_update",
+                                 reason=f"delta rejected, full rebuild: {e}")
+                if catalog != "carried" and items is None:
+                    # no row write to be had (no live index to take the
+                    # rows, a mesh, spare rows used up): the whole catalog
+                    valid_h = (np.ones(Ni, dtype=bool) if item_valid is None
+                               else item_valid)
+                    V, valid = self._place_catalog(Vh, valid_h)
+                    sent += Vh.nbytes + valid_h.nbytes
+                if index is None:
+                    sk = min(max(self.shortlist_k, self.k), Ni)
+                    if sk >= self.k and Ni > 0:
+                        index = self._build_index(V, valid, Ni, sk, seq)
+                    else:
+                        mode = "none"
             # last: every step above may raise or take long (an index
             # build), and from the row write on the old table is gone
-            in_segment = (0 if seen_appended is None or index is None
-                          else int(np.isin(seen_appended[1],
-                                           index.d_rows).sum()))
-            if ride.wanted:
-                self._send(ride, self._model.seen)
-            if ride.sent is not None:
-                # the user table's counter takes what no other part
-                # accounts for: the rows' own row and the unread ones
-                h2d += 4 * ride.sent.shape[1] * (
-                    PUBLISH_SENT
-                    - (SEGMENT_SENT if ride.segment is not None else 0)
-                    - (SENT_PLAN if ride.plan is not None else 0))
-            if ride.rows is not None:
-                users = (ride.sent, users[1])
-            if appended is not None:
-                appended = self._place_plan(appended, ride)
+            with Stamped("live.batch.publish.send"):
+                in_segment = (0 if seen_appended is None or index is None
+                              else int(np.isin(seen_appended[1],
+                                               index.d_rows).sum()))
+                if ride.wanted:
+                    self._send(ride, self._model.seen)
+                if ride.sent is not None:
+                    # the user table's counter takes what no other part
+                    # accounts for: the rows' own row and the unread ones
+                    h2d += 4 * ride.sent.shape[1] * (
+                        PUBLISH_SENT
+                        - (SEGMENT_SENT if ride.segment is not None else 0)
+                        - (SENT_PLAN if ride.plan is not None else 0))
+                if ride.rows is not None:
+                    users = (ride.sent, users[1])
+                if appended is not None:
+                    appended = self._place_plan(appended, ride)
             how = self._swap(how, users, seq, n_users, V, valid, index, Ni,
                              host=U, items=items, seen=carried,
                              appended=appended)
@@ -1793,27 +1835,30 @@ class ServingEngine:
                 index, compacted = self._model.index, True
             if compacted:
                 mode = catalog = "compact"
-        obs.counter("serving.publishes", **self._labels)
-        obs.counter("serving.user_table_writes", how=how, **self._labels)
-        obs.counter("serving.catalog_writes", how=catalog, **self._labels)
-        obs.counter("live.publish_h2d_bytes", h2d, **self._labels)
-        obs.counter("live.catalog_h2d_bytes", sent, **self._labels)
-        if appended is not None:
-            obs.counter("live.history_segment_ids", in_segment,
+        with Stamped("live.batch.publish.after"):
+            obs.counter("serving.publishes", **self._labels)
+            obs.counter("serving.user_table_writes", how=how,
                         **self._labels)
-            obs.counter("live.history_h2d_bytes", appended.sent,
+            obs.counter("serving.catalog_writes", how=catalog,
                         **self._labels)
-        obs.histogram("serving.publish_seconds",
-                      time.perf_counter() - t0, mode=mode,
-                      **self._labels)
-        linked = ({"trace_ids": sorted({c.trace_id for c in trace
-                                        if c is not None})}
-                  if trace else {})
-        obs.emit("serving_publish", seq=seq, items=Ni,
-                 quantized=bool(index is not None), mode=mode,
-                 delta_rows=(index.delta_count
-                             if index is not None else 0),
-                 users=how, catalog=catalog, **linked, **self._labels)
+            obs.counter("live.publish_h2d_bytes", h2d, **self._labels)
+            obs.counter("live.catalog_h2d_bytes", sent, **self._labels)
+            if appended is not None:
+                obs.counter("live.history_segment_ids", in_segment,
+                            **self._labels)
+                obs.counter("live.history_h2d_bytes", appended.sent,
+                            **self._labels)
+            obs.histogram("serving.publish_seconds",
+                          time.perf_counter() - t0, mode=mode,
+                          **self._labels)
+            linked = ({"trace_ids": sorted({c.trace_id for c in trace
+                                            if c is not None})}
+                      if trace else {})
+            obs.emit("serving_publish", seq=seq, items=Ni,
+                     quantized=bool(index is not None), mode=mode,
+                     delta_rows=(index.delta_count
+                                 if index is not None else 0),
+                     users=how, catalog=catalog, **linked, **self._labels)
         return seq, mode
 
     def _live_cadence(self):
@@ -2529,6 +2574,9 @@ class ServingEngine:
                 mark = cpu_mark()
                 held = self._table_lock.acquire()
                 t_locked = time.perf_counter()
+                # what the record keeps as ``lock_wait``, on the span
+                span.set_metadata(
+                    lock_wait_us=int(1e6 * (t_locked - t_stage)))
                 live = self._expire(batch, t_locked)
                 if not live:
                     return None

@@ -71,6 +71,12 @@ factor until the refit (``live.items_left_to_refit`` counts its events,
 which still enter their users' folds and histories).  Which is which the
 server reads from its base history, once, when the item side is first
 asked for.
+
+**A fold on the profiler's timeline** (``obs.schema.
+LIVE_PHASE_SPAN_KEYS``, ``LIVE_FOLDIN_SPAN_KEYS``; each with its
+``side``): ``live.batch.foldin.group``, ``.history``, ``.map``, ``.pack``
+(one a call), ``.readback`` around ``.call``, ``.write_back`` — spans a
+FOLD, never an event; outside a profiler session a microsecond each.
 """
 
 from __future__ import annotations
@@ -101,6 +107,7 @@ from tpu_als.core.ratings import (
     rung_for,
 )
 from tpu_als.ops.solve import compute_yty
+from tpu_als.serving.engine import Stamped
 from tpu_als.utils.frame import as_frame
 
 
@@ -345,142 +352,160 @@ class FoldInServer:
         solve, write-back — parameterized by which side is being solved,
         so a fix to any of it cannot apply to one direction only."""
         t0 = time.perf_counter()
-        frame = as_frame(batch)
-        m = self.model
-        p = m._params
         side = "item" if items_side else "user"
-        self.last_rows[side + "s"] = None
-        if items_side:
-            solved_raw = np.asarray(frame[p["itemCol"]])
-            fixed_raw = np.asarray(frame[p["userCol"]])
-            fixed_map, history = m._user_map, self._item_history
-        else:
-            solved_raw = np.asarray(frame[p["userCol"]])
-            fixed_raw = np.asarray(frame[p["itemCol"]])
-            fixed_map, history = m._item_map, self._history
-        r = np.asarray(frame[p["ratingCol"]], dtype=np.float32)
-        if not items_side:
-            self.last_appended = (solved_raw[:0], fixed_raw[:0])
-        else:
-            self.last_items = dict.fromkeys(self.last_items, 0)
-            if self._base is not None and len(solved_raw):
-                # an item with resident ratings keeps its factor until
-                # the refit: a fold here could not be over all of them
-                left = self._to_refit(solved_raw)
-                self.last_items["left_to_refit"] = int(left.sum())
-                solved_raw, fixed_raw, r = (
-                    a[~left] for a in (solved_raw, fixed_raw, r))
-        if len(solved_raw) == 0:
-            return np.array([], dtype=np.int64)
+        # the fold's host work by phase on the profiler's timeline
+        # (obs.schema.LIVE_PHASE_SPAN_KEYS), each with its ``side``
+        sides = side + "s"
+        with Stamped("live.batch.foldin.group", side=sides):
+            frame = as_frame(batch)
+            m = self.model
+            p = m._params
+            self.last_rows[sides] = None
+            if items_side:
+                solved_raw = np.asarray(frame[p["itemCol"]])
+                fixed_raw = np.asarray(frame[p["userCol"]])
+                fixed_map, history = m._user_map, self._item_history
+            else:
+                solved_raw = np.asarray(frame[p["userCol"]])
+                fixed_raw = np.asarray(frame[p["itemCol"]])
+                fixed_map, history = m._item_map, self._history
+            r = np.asarray(frame[p["ratingCol"]], dtype=np.float32)
+            if not items_side:
+                self.last_appended = (solved_raw[:0], fixed_raw[:0])
+            else:
+                self.last_items = dict.fromkeys(self.last_items, 0)
+                if self._base is not None and len(solved_raw):
+                    # an item with resident ratings keeps its factor until
+                    # the refit: a fold here could not be over all of them
+                    left = self._to_refit(solved_raw)
+                    self.last_items["left_to_refit"] = int(left.sum())
+                    solved_raw, fixed_raw, r = (
+                        a[~left] for a in (solved_raw, fixed_raw, r))
+            if len(solved_raw) == 0:
+                return np.array([], dtype=np.int64)
 
-        # group the events by entity, each entity's in arrival order, and
-        # put them behind the entity's history
-        touched, entity = np.unique(solved_raw, return_inverse=True)
-        by_entity = np.argsort(entity, kind="stable")
-        bounds = np.cumsum(np.bincount(entity, minlength=len(touched)))[:-1]
-        per = list(zip(np.split(fixed_raw[by_entity], bounds),
-                       np.split(r[by_entity], bounds)))
-        used = self._used[side] if self.keep_history else {}
-        # with a base history a user has ONE rating an item: an event on
-        # an item already rated replaces it (``adds``: which events add
-        # an id to their user's history; ``again``: the items re-rated)
-        one_rating = self._base is not None and not items_side
-        adds, again = np.ones(len(r), bool), []
-        if self.keep_history:
-            # a rating whose other side has no factor yet waits for it
-            held = self._waiting["user" if items_side else "item"]
-            for e in fixed_raw[fixed_map.to_dense(fixed_raw) < 0].tolist():
-                held[e] = held.get(e, 0) + 1
-            events = np.split(by_entity, bounds)
-            for j, e in enumerate(touched.tolist()):
-                hist = history.get(e)
-                if hist is None and one_rating:
-                    hist = self._resident(e, used)
-                if one_rating:
-                    per[j], new = _one_rating_each(hist, *per[j])
-                    adds[events[j][~new]] = False
-                    again.append(fixed_raw[events[j][~new]])
-                elif hist is not None:
-                    per[j] = (np.concatenate([hist[0], per[j][0]]),
-                              np.concatenate([hist[1], per[j][1]]))
-                history[e] = per[j]
-        if not items_side:
-            self.last_appended = (solved_raw[adds], fixed_raw[adds])
+            # group the events by entity, each entity's in arrival order,
+            # and put them behind the entity's history
+            touched, entity = np.unique(solved_raw, return_inverse=True)
+            by_entity = np.argsort(entity, kind="stable")
+            bounds = np.cumsum(
+                np.bincount(entity, minlength=len(touched)))[:-1]
+            per = list(zip(np.split(fixed_raw[by_entity], bounds),
+                           np.split(r[by_entity], bounds)))
+        with Stamped("live.batch.foldin.history", side=sides):
+            used = self._used[side] if self.keep_history else {}
+            # with a base history a user has ONE rating an item: an event
+            # on an item already rated replaces it (``adds``: which events
+            # add an id to their user's history; ``again``: the items
+            # re-rated)
+            one_rating = self._base is not None and not items_side
+            adds, again = np.ones(len(r), bool), []
+            if self.keep_history:
+                # a rating whose other side has no factor yet waits for it
+                held = self._waiting["user" if items_side else "item"]
+                for e in fixed_raw[
+                        fixed_map.to_dense(fixed_raw) < 0].tolist():
+                    held[e] = held.get(e, 0) + 1
+                events = np.split(by_entity, bounds)
+                for j, e in enumerate(touched.tolist()):
+                    hist = history.get(e)
+                    if hist is None and one_rating:
+                        hist = self._resident(e, used)
+                    if one_rating:
+                        per[j], new = _one_rating_each(hist, *per[j])
+                        adds[events[j][~new]] = False
+                        again.append(fixed_raw[events[j][~new]])
+                    elif hist is not None:
+                        per[j] = (np.concatenate([hist[0], per[j][0]]),
+                                  np.concatenate([hist[1], per[j][1]]))
+                    history[e] = per[j]
+            if not items_side:
+                self.last_appended = (solved_raw[adds], fixed_raw[adds])
 
         # a fold regresses on the ratings whose other side has a factor
         # NOW (fixed-side entities never seen cannot contribute: no
         # factors to regress on); an entity with none is not folded
-        lens = np.array([len(f) for f, _ in per])
-        dense = fixed_map.to_dense(np.concatenate([f for f, _ in per]))
-        vals_all = np.concatenate([v for _, v in per])
-        known = dense >= 0
-        usable = np.add.reduceat(known.astype(np.int64),
-                                 np.cumsum(lens) - lens)
-        # ratings that enter a fold of this side for the first time (a
-        # rating that replaces one enters in its place)
-        entered = (int((fixed_map.to_dense(np.concatenate(again)) >= 0).sum())
-                   if again else 0)
-        for e, n_ok in zip(touched.tolist(), usable.tolist()):
-            entered += n_ok - used.get(e, 0)
-            used[e] = n_ok
-        fold = usable > 0
-        if not fold.any():
-            return np.array([], dtype=np.int64)
-        touched = touched[fold]
-        dense, vals_all = dense[known], vals_all[known]
-        lens = usable[fold]
+        with Stamped("live.batch.foldin.map", side=sides) as span:
+            lens = np.array([len(f) for f, _ in per])
+            dense = fixed_map.to_dense(np.concatenate([f for f, _ in per]))
+            vals_all = np.concatenate([v for _, v in per])
+            span.set_metadata(ratings=len(dense))
+            known = dense >= 0
+            usable = np.add.reduceat(known.astype(np.int64),
+                                     np.cumsum(lens) - lens)
+            # ratings that enter a fold of this side for the first time (a
+            # rating that replaces one enters in its place)
+            entered = (
+                int((fixed_map.to_dense(np.concatenate(again)) >= 0).sum())
+                if again else 0)
+            for e, n_ok in zip(touched.tolist(), usable.tolist()):
+                entered += n_ok - used.get(e, 0)
+                used[e] = n_ok
+            fold = usable > 0
+            if not fold.any():
+                return np.array([], dtype=np.int64)
+            touched = touched[fold]
+            dense, vals_all = dense[known], vals_all[known]
+            lens = usable[fold]
 
-        F = self._fixed(items_side)
-        if items_side:
-            # O(table) a batch on the implicit path: ROADMAP R2
-            YtY = compute_yty(F) if self._implicit else None
-        else:
-            YtY = self._YtY
-        # pad rows and width up the ladder -> the programs prewarm ran;
-        # one call, or where its gather would pass FOLD_ELEMENTS several
-        n, first = len(touched), np.cumsum(lens) - lens
-        x, widest, solved = np.empty((n, F.shape[1]), np.float32), 0, []
+            F = self._fixed(items_side)
+            if items_side:
+                # O(table) a batch on the implicit path: ROADMAP R2
+                YtY = compute_yty(F) if self._implicit else None
+            else:
+                YtY = self._YtY
+            # pad rows and width up the ladder -> the programs prewarm
+            # ran; one call, or where its gather would pass FOLD_ELEMENTS
+            # several
+            n, first = len(touched), np.cumsum(lens) - lens
+            x, widest, solved = (np.empty((n, F.shape[1]), np.float32), 0,
+                                 [])
         for sel in self._calls(lens):
-            ln = lens[sel]
-            n_pad, w = pad_for(len(sel)), rung_for(int(ln.max()),
-                                                   self._widths)
-            row = np.repeat(np.arange(len(sel)), ln)
-            slot = np.arange(ln.sum()) - np.repeat(np.cumsum(ln) - ln, ln)
-            flat = np.repeat(first[sel], ln) + slot
-            # ids, stars and mask as planes of the ONE array the program
-            # takes (``core.foldin.pack_rows``), a host argument of its
-            # call: nothing is placed ahead of it
-            rows = cols, vals, mask = planes(
-                np.zeros((3, n_pad, w), dtype=np.int32))
-            cols[row, slot] = dense[flat]
-            vals[row, slot] = vals_all[flat]
-            mask[row, slot] = 1.0
+            with Stamped("live.batch.foldin.pack", side=sides):
+                ln = lens[sel]
+                n_pad, w = pad_for(len(sel)), rung_for(int(ln.max()),
+                                                       self._widths)
+                obs.histogram("foldin.history_width", w, side=side)
+                widest = max(widest, w)
+                row = np.repeat(np.arange(len(sel)), ln)
+                slot = (np.arange(ln.sum())
+                        - np.repeat(np.cumsum(ln) - ln, ln))
+                flat = np.repeat(first[sel], ln) + slot
+                # ids, stars and mask as planes of the ONE array the
+                # program takes (``core.foldin.pack_rows``), a host
+                # argument of its call: nothing is placed ahead of it
+                rows = cols, vals, mask = planes(
+                    np.zeros((3, n_pad, w), dtype=np.int32))
+                cols[row, slot] = dense[flat]
+                vals[row, slot] = vals_all[flat]
+                mask[row, slot] = 1.0
             # the fold's one wait for the device, on the profiler's
             # timeline (obs.schema.LIVE_FOLDIN_SPAN_KEYS): the call, which
-            # carries the one array up, and the rows read back
-            with TraceAnnotation("live.batch.foldin.readback",
-                                 side=side + "s"):
-                solved.append(self._fold(F, rows, YtY))
+            # carries the one array up (a phase of its own inside, until
+            # the call returns), and the rows read back
+            with TraceAnnotation("live.batch.foldin.readback", side=sides):
+                with Stamped("live.batch.foldin.call", side=sides,
+                             rows=n_pad, width=w, calls=len(solved) + 1):
+                    solved.append(self._fold(F, rows, YtY))
                 x[sel] = np.asarray(solved[-1])[:len(sel)]
-            obs.histogram("foldin.history_width", w, side=side)
-            widest = max(widest, w)
 
-        if items_side:
-            first = int((m._item_map.to_dense(touched) < 0).sum())
-            self.last_items.update(first=first, again=n - first)
-        # one call: its result holds the batch's rows in ``touched``'s
-        # order, and is what every table on the device is written from
-        placed = solved[0] if len(solved) == 1 else None
-        at = self._write_back(touched, x, items_side, placed)
-        if placed is not None:
-            self.last_rows[side + "s"] = (at, placed)
-        if items_side and self._implicit:
-            self._YtY = compute_yty(self._V)
-        dt = time.perf_counter() - t0
-        self.stats.append((entered, n, dt, widest))
-        obs.histogram("foldin.update_seconds", dt, side=side)
-        obs.histogram("foldin.batch_rows", n, side=side)
-        obs.counter("foldin.ratings", entered)
+        with Stamped("live.batch.foldin.write_back", side=sides):
+            if items_side:
+                first = int((m._item_map.to_dense(touched) < 0).sum())
+                self.last_items.update(first=first, again=n - first)
+            # one call: its result holds the batch's rows in ``touched``'s
+            # order, and is what every table on the device is written from
+            placed = solved[0] if len(solved) == 1 else None
+            at = self._write_back(touched, x, items_side, placed)
+            if placed is not None:
+                self.last_rows[sides] = (at, placed)
+            if items_side and self._implicit:
+                self._YtY = compute_yty(self._V)
+            dt = time.perf_counter() - t0
+            self.stats.append((entered, n, dt, widest))
+            obs.histogram("foldin.update_seconds", dt, side=side)
+            obs.histogram("foldin.batch_rows", n, side=side)
+            obs.counter("foldin.ratings", entered)
         return touched
 
     def _fold(self, F, rows, YtY):

@@ -132,36 +132,6 @@ class PallasCallLog:
                 for (p, kn, i), n in counts.items()]
 
 
-class CompileClock:
-    """Sums JAX's own lowering and backend-compile durations
-    (``jax.monitoring``), so compile seconds are the compiler's and not a
-    difference of walls.  ``compile_s`` is small when the persistent
-    compilation cache is warm; tracing is not summed (its events nest)."""
-
-    EVENTS = {"/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
-              "/jax/core/compile/backend_compile_duration": "compile_s"}
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.totals = dict.fromkeys(self.EVENTS.values(), 0.0)
-        self.compilations = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        name = self.EVENTS.get(event)
-        if name is not None:
-            self.totals[name] += duration
-            self.compilations += name == "compile_s"
-
-    def since(self, mark=None):
-        """Totals now (``mark=None``) or the rounded growth since."""
-        now = dict(self.totals, compilations=self.compilations)
-        if mark is None:
-            return now
-        return {k: round(now[k] - mark[k], 2) for k in now}
-
-
 def device_phase(chips):
     """Look at the device first; anything but a TPU ends the run."""
     import jax
@@ -337,14 +307,14 @@ def train_phase(frame, *, rank, max_iter, seed, n_check_users, clock,
         fence((U, V))
         marks.append(time.perf_counter())
 
-    mark = clock.since()
+    mark = clock.now()
     t_fit = time.perf_counter()
     with (contextlib.nullcontext() if precision is None
           else jax.default_matmul_precision(precision)):
         model = tpu_als.ALS(fitCallback=on_iteration, mesh=mesh,
                             **est_kwargs).fit(frame)
     fit_s = time.perf_counter() - t_fit
-    compiled = clock.since(mark)
+    compiled = {k: round(v, 2) for k, v in clock.since(mark).items()}
     iter_s = np.diff([t_fit] + marks)
     require(len(iter_s) == max_iter, "fitCallback did not fire per iteration")
 
@@ -732,11 +702,12 @@ def main(argv=None):
     result = {"ok": True}
     try:
         result["device"] = device_phase(args.chips)
+        from tpu_als.obs import compiles
         from tpu_als.utils.platform import enable_persistent_compile_cache
 
         emit("setup", plan_cache="disarmed (TPU_ALS_PLAN_CACHE=off)",
              compile_cache_dir=enable_persistent_compile_cache())
-        clock = CompileClock()
+        clock = compiles.install()
         with PallasCallLog() as log:
             log.phase = "data"
             frame = data_phase(seed=args.seed, **ML25M)

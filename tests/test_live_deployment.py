@@ -430,7 +430,8 @@ def test_a_row_write_that_fails_after_the_donation_replaces_the_table(
     assert (seq, mode) == (old.seq + 1, "retag") and old.U.is_deleted()
     assert m.U.shape == old.U.shape and not m.U.is_deleted()
     np.testing.assert_array_equal(np.asarray(m.U[:N_USERS]), U2)
-    warn = [e for e in reg._events if e["type"] == "warning"]
+    warn = [e for e in reg._events if e["type"] == "warning"
+            and e["what"] == "serving.publish_update"]
     assert len(warn) == 1 and "after donating" in warn[0]["reason"] \
         and "re-placed whole" in warn[0]["reason"]
     assert reg.counter_value("serving.user_table_writes",

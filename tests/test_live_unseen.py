@@ -402,7 +402,8 @@ def test_an_engine_nobody_warmed_lays_the_histories_out_at_the_first_publish():
                        seen_appended=([user], [item]))
     hist.publish(2, [user], [item], [5.0])
     assert eng._model.seen.room is not None
-    warn, = [e for e in reg._events if e["type"] == "warning"]
+    warn, = [e for e in reg._events if e["type"] == "warning"
+             and e["what"] == "serving.publish_update"]
     assert "laid out anew" in warn["reason"]
     scores, ids = _serve_one(eng, user)
     assert item not in ids

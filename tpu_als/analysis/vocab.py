@@ -119,6 +119,13 @@ PROFILER_SPAN_TUPLES = (
     ("LIVE_PHASE_SPAN_KEYS", ("live", "stream", "serving")),
 )
 
+# start-phase literals: ``phase("start....")`` (obs/phases.py) opens one
+# phase of a serving start, whose name the benchmark's ``start_*``
+# readers and ``scripts/time_start.py`` key on — declared in
+# schema.START_PHASES, like the rest
+START_PHASE_RE = re.compile(
+    r"(?<![\w.])phase\(\s*(?P<q>['\"])(?P<name>start\.[^'\"]+)(?P=q)")
+
 # inline event dicts: a line carrying both a "ts" key and a literal
 # "type" value (the hand-built shape allowed where importing tpu_als is
 # off-limits)
@@ -597,6 +604,15 @@ def check_file(path, repo=REPO):
                     "declared in tpu_als.obs.schema ("
                     + ", ".join(attr for attr, _ in PROFILER_SPAN_TUPLES)
                     + ") — trace readers key on declared span names only")
+        start_phases = getattr(schema, "START_PHASES", ())
+        for m in START_PHASE_RE.finditer(text):
+            name = m.group("name")
+            if name not in start_phases:
+                lineno = line_of(m.start())
+                add(lineno,
+                    f"{rel}:{lineno}: start phase {name!r} is not "
+                    "declared in tpu_als.obs.schema.START_PHASES — the "
+                    "start's readers key on declared phase names only")
         trace_spans = getattr(schema, "TRACE_SPANS", ())
         for regex in (TRACE_START_RE, TRACE_RECORD_RE):
             for m in regex.finditer(text):

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_als.core.ratings import pad_for
+from tpu_als.obs.phases import count_placed
 from tpu_als.ops.solve import (
     DEFAULT_JITTER,
     SOLVE_PATH_NAMES,
@@ -129,7 +130,7 @@ def write_placed_rows(table, rows, vals):
                          vals)
 
 
-def place_rows(F, *, capacity, mesh=None):
+def place_rows(F, *, capacity, mesh=None, table=None):
     """``F`` on the device with zero rows up to ``capacity``: the table a
     live path appends to without a change of shape (a gather or a lookup
     never addresses the spare rows; ``F^T F`` is unchanged by them).
@@ -146,8 +147,14 @@ def place_rows(F, *, capacity, mesh=None):
     shard is placed as above on its own device, the shards' chunks in
     turn so that the devices' uploads overlap, and the shards are joined
     without a copy: no device ever holds another's rows, or its own
-    twice."""
+    twice.
+
+    ``table`` names what goes up for ``device.placed_bytes`` (``users`` |
+    ``catalog`` | ``fold_fixed``: ``obs.phases.count_placed``); ``None``
+    counts nothing."""
     F = np.asarray(F, dtype=np.float32)
+    if table is not None:
+        count_placed(table, F.nbytes)
     if mesh is None:
         return _place_shards(F, [(None, 0, len(F))], capacity)[0]
     devices = list(mesh.devices.flat)

@@ -94,6 +94,7 @@ from tpu_als import obs
 from tpu_als.core.foldin import placements
 from tpu_als.core.ratings import invalid_rating_mask
 from tpu_als.obs import tracing
+from tpu_als.obs.phases import phase
 from tpu_als.obs.trace import FlightRecorder
 from tpu_als.resilience import faults
 from tpu_als.serving.batcher import Overloaded
@@ -194,12 +195,13 @@ class LiveUpdater:
         loop."""
         if self._thread is not None:
             raise RuntimeError("updater already started")
-        self.engine.warmup_publish(self.max_batch)
-        if self.fold_items:
-            # (the histories too, where the engine holds them)
-            self.engine.warmup_live(max_rows=self.max_batch)
-        elif self._histories:
-            self.engine.warmup_histories(max_rows=self.max_batch)
+        with phase("start.updater"):
+            self.engine.warmup_publish(self.max_batch)
+            if self.fold_items:
+                # (the histories too, where the engine holds them)
+                self.engine.warmup_live(max_rows=self.max_batch)
+            elif self._histories:
+                self.engine.warmup_histories(max_rows=self.max_batch)
         self._thread = threading.Thread(
             target=self._run, name="tpu-als-live", daemon=True)
         self._thread.start()
@@ -333,7 +335,6 @@ class LiveUpdater:
                 ratings, arrivals = ratings[keep], arrivals[keep]
                 ctxs = [c for c, k in zip(ctxs, keep) if k]
             quarantine_s = time.perf_counter() - t0
-            obs.histogram("live.batch_rows", len(ratings), **self._labels)
             if len(ratings) == 0:
                 self.flight.record(
                     "quarantined",

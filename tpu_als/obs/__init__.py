@@ -70,12 +70,24 @@ def counter_value(name, **labels):
     return _default.counter_value(name, **labels)
 
 
+def counter_series(name):
+    return _default.counter_series(name)
+
+
 def emit(etype, **fields):
     return _default.emit(etype, **fields)
 
 
 def span(name, **labels):
     return _default.span(name, **labels)
+
+
+def unscoped_span(name, **labels):
+    return _default.unscoped_span(name, **labels)
+
+
+def open_spans():
+    return _default.open_spans()
 
 
 def configure(run_dir, config=None, argv=None):

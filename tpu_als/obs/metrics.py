@@ -19,7 +19,9 @@ Prometheus ``le`` contract.
 stack gives each event its ``path``) and applies ``jax.named_scope``
 when jax is already imported, so host spans and device-trace scopes
 share names (docs/observability.md's Perfetto walkthrough relies on
-this).  Metric/event NAMES are validated against tpu_als.obs.schema at
+this); ``unscoped_span`` is the same without the scope, for spans that
+lie around ``lower()`` (obs/phases.py: the phases of a serving start).
+Metric/event NAMES are validated against tpu_als.obs.schema at
 call time; ``scripts/check_obs_schema.py`` validates call sites
 statically.
 """
@@ -226,6 +228,13 @@ class MetricsRegistry:
         with self._lock:
             return self._counters.get(key, 0)
 
+    def counter_series(self, name):
+        """``[(labels, value)]`` of every series of counter ``name``:
+        what an in-process reader sums without a trail."""
+        with self._lock:
+            return [(dict(lk), v) for (n, lk), v in self._counters.items()
+                    if n == name]
+
     def emit(self, etype, **fields):
         """Append one event; returns the event dict (with its ts)."""
         schema.check_event(etype, fields)
@@ -248,6 +257,28 @@ class MetricsRegistry:
         profiler's timeline, on the device trace's clock) — but obs
         never imports jax itself (it must stay importable in processes
         that keep jax out, e.g. bench.py's probe)."""
+        with self._span(name, labels, scoped=True):
+            yield
+
+    @contextlib.contextmanager
+    def unscoped_span(self, name, **labels):
+        """:meth:`span` WITHOUT the ``jax.named_scope``: for a span
+        around ``lower()``, where a scope would put the span's name into
+        the ``op_name`` of every operation traced inside it — and so
+        into the programs' texts and their cache keys.  Yields the dict
+        of the event's further fields: what the body puts there is
+        written with the ``span`` event as the span closes (``seconds``
+        there stands in place of the span's own)."""
+        with self._span(name, labels, scoped=False) as fields:
+            yield fields
+
+    def open_spans(self):
+        """The names of the calling thread's open spans, outermost
+        first."""
+        return tuple(getattr(self._local, "stack", ()))
+
+    @contextlib.contextmanager
+    def _span(self, name, labels, scoped):
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
@@ -257,19 +288,22 @@ class MetricsRegistry:
         jax = sys.modules.get("jax")
         if jax is not None:
             try:
-                scope = jax.named_scope(name)
+                if scoped:
+                    scope = jax.named_scope(name)
                 annotation = jax.profiler.TraceAnnotation(name)
             except Exception:
                 pass
+        fields = {}
         t0 = time.perf_counter()
         try:
             with scope, annotation:
-                yield
+                yield fields
         finally:
             dt = time.perf_counter() - t0
             stack.pop()
-            self.emit("span", name=name, path=path, t0=round(t0, 6),
-                      seconds=round(dt, 6), **labels)
+            self.emit("span", **{"name": name, "path": path,
+                                 "t0": round(t0, 6), "seconds": round(dt, 6),
+                                 **labels, **fields})
 
     # -- run lifecycle -------------------------------------------------
     def configure(self, run_dir, config=None, argv=None):

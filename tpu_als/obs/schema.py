@@ -50,9 +50,6 @@ METRICS = {
         "counter", "seconds",
         "time stream_ingest spent blocked in file reads (I/O stall, "
         "as opposed to parse/intern time)"),
-    "foldin.update_seconds": (
-        "histogram", "seconds",
-        "FoldInServer micro-batch latency, labeled side=user|item"),
     "foldin.ratings": (
         "counter", "rows",
         "ratings that entered a FoldInServer fold for the first time, one "
@@ -214,10 +211,6 @@ METRICS = {
         "rating-arrival -> servable: from the event entering the live "
         "updater's admission queue to its fold-in's publish seq being "
         "visible to the score path (tpu_als.live.updater)"),
-    "live.batch_rows": (
-        "histogram", "rows",
-        "rating events per live-updater micro-batch (accumulation "
-        "bounded by the planner's max_batch/max_wait_ms cadence)"),
     "live.shed": (
         "counter", "events",
         "rating events refused at the live updater's admission queue "
@@ -281,10 +274,6 @@ METRICS = {
         "ratings the fold-in server holds in a history for a side whose "
         "other entity has no factor yet (one per rating and side), "
         "sampled after each micro-batch's folds"),
-    "foldin.batch_rows": (
-        "histogram", "rows",
-        "entities solved per FoldInServer micro-batch (the padded "
-        "bucket is the next pow2 above this)"),
     "foldin.history_width": (
         "histogram", "ratings",
         "padded width of each run of the fold-in program (the rung that "
@@ -353,6 +342,45 @@ METRICS = {
         "histogram", "seconds",
         "wall-clock duration of one soak window (traffic replay + "
         "chaos actions + joins; the schedule's window_s is the floor)"),
+    "jax.programs": (
+        "counter", "programs",
+        "compile-path events of this process by JAX's own listeners "
+        "(obs.compiles, installed by the first ServingEngine or "
+        "FoldInServer): stage=trace|lower|compile, and for a backend-"
+        "compile call cache=hit (the persistent compilation cache held "
+        "the executable) | miss (it did not: compiled) | off (the call "
+        "went by no persistent cache: none configured, or an entry "
+        "under its thresholds); when=traffic (no start phase open on "
+        "the compiling thread and an engine started) | before.  No "
+        "program name in a label: the jax_program event names each"),
+    "jax.program_seconds": (
+        "counter", "seconds",
+        "seconds of the same events by stage=trace|lower|compile and "
+        "when=traffic|before (a function traced inside another counted "
+        "once; compile: the backend's compile call, a cache's fetch "
+        "included)"),
+    "device.placed_bytes": (
+        "counter", "bytes",
+        "bytes of whole tables handed host -> device, where they go up "
+        "(core.foldin.place_rows and the engine's placements), at a "
+        "start AND under traffic: table=users (ServingEngine's user "
+        "table) | catalog (its catalog) | index (the candidate index's "
+        "own copy of the catalog: one chip, no mesh) | histories (the "
+        "users' histories and, where they are laid out to grow, the "
+        "moves' sources and targets) | fold_fixed (a FoldInServer's "
+        "fixed side: the catalog, and the user table where items fold). "
+        "A table re-placed whole under traffic (spare rows used up) "
+        "shows here and in no per-batch counter"),
+    "start.seconds": (
+        "counter", "seconds",
+        "wall seconds of the start phases (obs.phases.phase; "
+        "schema.START_PHASES), exact sums by path = the '/'-joined "
+        "start.* phases open on the thread: a path with no '/' is a "
+        "start's top level, one that prefixes no other a leaf"),
+    "start.placed_bytes": (
+        "counter", "bytes",
+        "device.placed_bytes' growth inside each start phase, by the "
+        "same path"),
 }
 
 # metric name -> label keys its writers may attach.  Any key outside
@@ -364,8 +392,6 @@ LABELS = {
     "train.gather_block_rows": ("n_blocks", "side"),
     "train.stage_seconds": ("stage",),
     "serve.request_seconds": ("strategy",),
-    "foldin.update_seconds": ("side",),
-    "foldin.batch_rows": ("side",),
     "foldin.history_width": ("side",),
     "serving.enqueue_seconds": ("tenant",),
     "serving.score_seconds": ("path", "tenant"),
@@ -387,7 +413,6 @@ LABELS = {
     "serving.exclusion_upload_bytes": ("tenant",),
     "serving.publish_seconds": ("mode", "tenant"),
     "live.freshness_seconds": ("tenant",),
-    "live.batch_rows": ("tenant",),
     "live.shed": ("tenant",),
     "live.queue_depth": ("tenant",),
     "live.publish_h2d_bytes": ("tenant",),
@@ -404,6 +429,11 @@ LABELS = {
     "serving.catalog_writes": ("how", "tenant"),
     "tenancy.served_rows": ("tenant",),
     "tenancy.batch_errors": ("tenant",),
+    "jax.programs": ("stage", "cache", "when"),
+    "jax.program_seconds": ("stage", "when"),
+    "device.placed_bytes": ("table",),
+    "start.seconds": ("path",),
+    "start.placed_bytes": ("path",),
 }
 
 # every metric allowed to carry the multi-tenant attribution label —
@@ -704,6 +734,65 @@ LIVE_PHASE_SPAN_KEYS = (
     #                                  samples, the flight record
 )
 
+# the phases of a serving start (obs/phases.py::phase: one ``span`` event
+# each with seconds, CPU seconds, bytes placed, device bytes in use and
+# the compile ledger's difference; a TraceAnnotation, never a named
+# scope; exact sums in start.seconds / start.placed_bytes by path).
+# Children tile their parent; ``start.pin`` and ``start.first_run`` are
+# children of whichever warm-up pins and runs.  benchmark/start_phases.py
+# keys on the names and on the path's shape
+START_PHASES = (
+    "start.publish",                  # ServingEngine.publish, whole
+    "start.publish.users",            # _place_users: the user table up,
+    #                                   in chunks, waited for
+    "start.publish.histories",        # _place_seen
+    "start.publish.histories.check",  #   the CSR validated on the host
+    "start.publish.histories.place",  #   runs and ids up (on a mesh a
+    #                                   shard at a time: _shard_seen)
+    "start.publish.catalog",          # _place_catalog: V (and valid) up
+    "start.publish.index",            # _build_index
+    "start.publish.index.place",      #   one chip: V up a SECOND time,
+    #                                   the index's own copy
+    "start.publish.index.quantize",   #   the int8 rows and scales made
+    #                                   on the device (_quantize_rows)
+    "start.warmup",                   # ServingEngine.warmup
+    "start.warmup_publish",           # .warmup_publish: the user-row
+    #                                   writes run (start.first_run)
+    "start.warmup_live",              # .warmup_live
+    "start.warmup_live.reserve",      #   spare rows for the catalog's
+    #                                   arrays, the segment made
+    "start.warmup_histories",         # _warm_histories (alone, or inside
+    #                                   start.warmup_live)
+    "start.warmup_histories.plan",    #   _lay_out on the host: where
+    #                                   every id moves to
+    "start.warmup_histories.place",   #   sources and targets up (8 bytes
+    #                                   an id, once)
+    "start.pin",                      # _pin: one pinned program loaded,
+    #                                   or lowered + compiled + written
+    "start.first_run",                # a program RUN for the first time
+    #                                   by a warm-up, waited for
+    "start.foldin_server",            # FoldInServer.__init__
+    "start.foldin_server.reserve",    #   the host's table copied into a
+    #                                   buffer with spare rows
+    "start.foldin_server.place",      #   _place("_V"): the catalog up a
+    #                                   THIRD time, the folds' fixed side
+    "start.foldin_server.history",    #   the resident ratings' widths
+    "start.foldin_server.yty",        #   implicit: the Gram of the table
+    "start.prewarm",                  # FoldInServer.prewarm (sides=)
+    "start.prewarm.reserve",          #   host work before a side folds:
+    #                                   the id map sorted; on the item
+    #                                   side (_fixed) the catalog copied
+    #                                   into a buffer with spare rows,
+    #                                   which items a resident rating
+    #                                   names
+    "start.prewarm.place",            #   _fixed, the item side: the user
+    #                                   table up a SECOND time
+    "start.prewarm.programs",         #   the ladder: every fold-in
+    #                                   program compiled or fetched, run
+    "start.prewarm.writes",           #   the four row-write forms a pad
+    "start.updater",                  # LiveUpdater.start, whole
+)
+
 # field names every flight record (and its flight_record event) claims
 # structurally — span keys and label keys must stay disjoint from these
 FLIGHT_RESERVED = ("seq", "status", "spans", "e2e_seconds", "path",
@@ -721,7 +810,22 @@ EVENTS = {
         "one per closed span(): its start (t0, perf_counter seconds) and "
         "wall-clock duration; path is the '/'-joined stack of enclosing "
         "span names (the tree structure).  The same name is a "
-        "TraceAnnotation on the profiler's timeline while one records"),
+        "TraceAnnotation on the profiler's timeline while one records.  "
+        "A start phase's span (obs.phases.phase; name in START_PHASES) "
+        "also carries cpu_seconds (the calling thread's), placed_bytes "
+        "(device.placed_bytes' growth inside it), device_bytes_in_use "
+        "(as it closes, the fullest local device) and the compile "
+        "ledger's difference over it: programs (backend-compile calls), "
+        "cache_hits, cache_misses, trace_s, lower_s, compile_s"),
+    "jax_program": (
+        ("fun_name", "trace_s", "lower_s", "compile_s", "cache", "phase"),
+        "one per backend-compile call of this process (obs.compiles): "
+        "the program's name as JAX gives it (jit(...) stripped), the "
+        "seconds it was traced and lowered on that thread before the "
+        "call and the call's own (a cache's fetch included), cache = "
+        "hit | miss | off, and phase = the innermost start.* phase open "
+        "on the compiling thread, 'traffic' where none is and an engine "
+        "is started, null before"),
     "metric": (
         ("kind", "name", "value"),
         "a gauge set (gauges are point-in-time, so each set is an "
@@ -835,7 +939,10 @@ EVENTS = {
         "| unreadable), the seconds the pin took (the load, or lower + "
         "compile + the store's write; not the program's first run) and "
         "the bytes of its file in the store, read or written (0 where "
-        "no compile cache is configured)"),
+        "no compile cache is configured); split = those seconds by step "
+        "as serving.pins.pin took them: key_s and load_s (the file read "
+        "and deserialized), or key_s, lower_s, compile_s and write_s "
+        "(serialized, deflated and written)"),
     "foldin_solve_path": (
         ("side", "rank", "rows", "width", "path", "reason"),
         "one per fold-in program FoldInServer.prewarm compiled and ran "
@@ -874,7 +981,9 @@ EVENTS = {
     "warning": (
         ("what", "reason"),
         "a degraded-but-continuing condition (e.g. profiler trace "
-        "skipped because one is already active)"),
+        "skipped because one is already active; what='jax.compile': a "
+        "program reached the backend's compile call under traffic — "
+        "fun_name, seconds, cache and phase='traffic' name it)"),
     "snapshot": (
         ("counters", "gauges", "histograms"),
         "final registry state, appended once by finalize() so the JSONL "
@@ -1075,6 +1184,15 @@ def check_labels(name, labels):
             f"metric {name!r} does not declare label key(s) {unknown} "
             f"(declared: {list(allowed)}) — add them to "
             "tpu_als.obs.schema.LABELS before writing the series")
+
+
+def check_start_phase(name):
+    """Raise if ``name`` is no declared phase of a start."""
+    if name not in START_PHASES:
+        raise KeyError(
+            f"start phase {name!r} is not declared in tpu_als.obs."
+            "schema.START_PHASES — declare it there (and in "
+            "docs/observability.md) before opening it")
 
 
 def check_trace_span(name, status="ok"):

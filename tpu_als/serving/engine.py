@@ -139,6 +139,22 @@ stack plugs into:
   halves of dispatch (``upload``, ``launch``) and each phase's CPU
   seconds (``cpu``; ``None`` for a batch no profiler watched: the CPU
   clock costs too much on the chip's host to be read for nobody).
+- **A start read from inside.**  ``publish`` and every warm-up are
+  tiled by START PHASES (``obs.phases.phase``, names in
+  ``obs.schema.START_PHASES``): ``start.publish`` around the user
+  table's, the histories', the catalog's and the index's own placement
+  and the quantization; ``start.warmup`` / ``.warmup_publish`` /
+  ``.warmup_live`` / ``.warmup_histories`` around one ``start.pin`` a
+  pinned program and one ``start.first_run`` wherever a warm-up RUNS
+  what it pinned or a write program.  A phase closes with its seconds,
+  CPU seconds, bytes handed to the device
+  (``device.placed_bytes{table}``, counted where a table goes up, under
+  traffic too), device bytes in use and the programs JAX made inside it
+  (``obs.compiles``: the process's compile ledger, installed by the
+  first engine; ``start()`` / ``stop()`` tell it when traffic runs, and a
+  program that compiles then is named in a ``warning``).  A phase is a
+  ``TraceAnnotation`` and never a ``jax.named_scope``, and a ``with``
+  block, never a wrapper: it lies around ``lower()``.
 - **One algorithm on any number of chips.**  The engine serves the
   int8 shortlist + exact f32 rescore from a candidate index: an
   :class:`~tpu_als.serving.index.Int8CandidateIndex` when ``mesh`` is
@@ -218,7 +234,8 @@ from tpu_als.core.ratings import (
     row_capacity,
     rung_for,
 )
-from tpu_als.obs import tracing
+from tpu_als.obs import compiles, tracing
+from tpu_als.obs.phases import count_placed, phase
 from tpu_als.obs.schema import (
     LIVE_HISTORY_SCOPE,
     SERVE_BATCH_SPAN_KEYS,
@@ -946,6 +963,7 @@ class ServingEngine:
         # for the programs that exclude
         self._pinned = {}
         self._no_history = None         # _without_history's memo
+        compiles.install()
         self._last_id = None            # _last_item's: (n_items, handle)
         self._plans = {}                # _mesh_plan's memo
 
@@ -954,6 +972,7 @@ class ServingEngine:
         over the mesh (``serving.index.place_catalog``: a chunk at a
         time into each shard, never whole on one device)."""
         if self.mesh is None:
+            count_placed("catalog", Vh.nbytes + validh.nbytes)
             return jnp.asarray(Vh), jnp.asarray(validh)
         return place_catalog(Vh, validh, self.mesh,
                              max(self.shortlist_k, self.k))[:2]
@@ -963,9 +982,20 @@ class ServingEngine:
         :meth:`_place_catalog` placed it, sharded over the mesh when the
         engine has one: the one place that chooses."""
         if self.mesh is None:
-            return Int8CandidateIndex(V, valid, shortlist_k=sk, seq=seq)
-        return ShardedInt8Index(V, self.mesh, item_valid=valid,
-                                shortlist_k=sk, seq=seq, n_items=n_items)
+            with phase("start.publish.index.place"):
+                # the index's own copy (a host catalog; a device array
+                # passes through), waited for: a placement returns with
+                # the transfer in flight, and its seconds would be the
+                # next program's
+                if isinstance(V, np.ndarray):
+                    count_placed("index", V.nbytes)
+                V = jnp.asarray(V, dtype=jnp.float32).block_until_ready()
+        with phase("start.publish.index.quantize"):
+            if self.mesh is None:
+                return Int8CandidateIndex(V, valid, shortlist_k=sk, seq=seq)
+            return ShardedInt8Index(V, self.mesh, item_valid=valid,
+                                    shortlist_k=sk, seq=seq,
+                                    n_items=n_items)
 
     def _announce_mesh(self):
         """One ``serving_backend`` event per mesh engine, at its first
@@ -991,8 +1021,8 @@ class ServingEngine:
             cap = int(prev.U.shape[0])
         # wait for it: what a publish allocates next (the catalog, its
         # index) is then allocated after the last chunk's buffer is freed
-        return (place_rows(U, capacity=cap,
-                           mesh=self.mesh).block_until_ready(),
+        return (place_rows(U, capacity=cap, mesh=self.mesh,
+                           table="users").block_until_ready(),
                 n, 4 * n * rank)
 
     def _update_users(self, prev, U, touched_users, placed, ride):
@@ -1170,6 +1200,29 @@ class ServingEngine:
         catalog ids, one row a user, a row's ids ascending and none
         twice.  On a mesh (``rows``: the placed user table's) sharded
         with that table (:meth:`_shard_seen`)."""
+        with phase("start.publish.histories.check"):
+            indptr, indices, lengths = self._checked_seen(
+                user_seen, n_users, n_items)
+        pads = history_pads(lengths.max(initial=0))
+        with phase("start.publish.histories.place"):
+            if self.mesh is not None:
+                return self._shard_seen(indptr, indices, lengths, pads,
+                                        rows)
+            # spare ids at the end: a slice of the longest pad from the
+            # last user's first id stays inside the table
+            # (``_select_seen``)
+            host = (indptr.astype(np.int32),
+                    np.concatenate([indices.astype(np.int32),
+                                    np.full(pads[-1], NOT_AN_ID, np.int32)]))
+            count_placed("histories", sum(a.nbytes for a in host))
+            return _Seen(*jax.device_put(host), lengths.astype(np.int32),
+                         pads)
+
+    @staticmethod
+    def _checked_seen(user_seen, n_users, n_items):
+        """``(indptr, indices, lengths)`` of a publish's histories, or
+        ``ValueError``: CSR over catalog ids, one row a user, a row's ids
+        ascending and none twice."""
         indptr, indices = (np.asarray(a) for a in user_seen)
         if indptr.shape != (n_users + 1,) or indptr[0] != 0 \
                 or indptr[-1] != len(indices) \
@@ -1192,16 +1245,7 @@ class ServingEngine:
             raise ValueError(
                 "user_seen: every row holds catalog ids in "
                 f"[0, {n_items}), ascending, none twice")
-        pads = history_pads(lengths.max(initial=0))
-        if self.mesh is not None:
-            return self._shard_seen(indptr, indices, lengths, pads, rows)
-        # spare ids at the end: a slice of the longest pad from the last
-        # user's first id stays inside the table (``_select_seen``)
-        dev = jax.device_put((
-            indptr.astype(np.int32),
-            np.concatenate([indices.astype(np.int32),
-                            np.full(pads[-1], NOT_AN_ID, np.int32)])))
-        return _Seen(*dev, lengths.astype(np.int32), pads)
+        return indptr, indices, lengths
 
     def _shard_seen(self, indptr, indices, lengths, pads, rows):
         """Checked histories sharded with a user table of ``rows`` rows
@@ -1234,7 +1278,8 @@ class ServingEngine:
             own[:cuts[s + 1] - cuts[s]] = indices[cuts[s]:cuts[s + 1]]
             return own
 
-        def placed(part, length):
+        def sharded(part, length):
+            count_placed("histories", 4 * S * length)
             return jax.make_array_from_single_device_arrays(
                 (S * length,), self._by_rows,
                 [jax.device_put(part(s), d)
@@ -1242,7 +1287,7 @@ class ServingEngine:
 
         held = np.zeros(rows, np.int32)
         held[:n_users] = lengths
-        return _Seen(placed(runs_of, n_loc + 1), placed(ids_of, width),
+        return _Seen(sharded(runs_of, n_loc + 1), sharded(ids_of, width),
                      held, pads)
 
     def _lay_out(self, seen, rows, more=0):
@@ -1257,30 +1302,37 @@ class ServingEngine:
         O(all the histories), so it is ``warmup_live``'s to call before
         the traffic — under it only where the room laid out here is used
         up, with a warning."""
-        n = len(seen.lengths)
-        lengths = np.zeros(rows, np.int32)
-        lengths[:n] = seen.lengths
-        cap = lengths + growth_room(lengths)
-        cap[n:] = 0         # a spare row's user gets a run with its first id
-        start = np.zeros(rows, np.int64)
-        np.cumsum(cap[:-1], out=start[1:])
-        held = int(start[-1] + cap[-1])
-        size = held + max(1 << 16, held >> 3) + int(more)
-        pads = history_pads(lengths.max(initial=0), grows=True)
-        if size + pads[-1] >= NOT_AN_ID:
-            raise ValueError(f"{held} ids of history with room to grow: "
-                             "more than int32 positions hold")
-        old = (np.asarray(seen.runs)[:-1] if seen.room is None
-               else seen.room.start[:n])
-        user = np.repeat(np.arange(n), seen.lengths)
-        within = (np.arange(len(user))
-                  - np.repeat(np.cumsum(seen.lengths) - seen.lengths,
-                              seen.lengths))
-        src, dst = ((a[user] + within).astype(np.int32)
-                    for a in (old, start))
-        dev = jax.device_put((start.astype(np.int32), lengths.copy()))
-        indices = _spread_runs(seen.indices, *jax.device_put((src, dst)),
-                               size=size + pads[-1])
+        with phase("start.warmup_histories.plan"):
+            n = len(seen.lengths)
+            lengths = np.zeros(rows, np.int32)
+            lengths[:n] = seen.lengths
+            cap = lengths + growth_room(lengths)
+            cap[n:] = 0     # a spare row's user gets a run with its first id
+            start = np.zeros(rows, np.int64)
+            np.cumsum(cap[:-1], out=start[1:])
+            held = int(start[-1] + cap[-1])
+            size = held + max(1 << 16, held >> 3) + int(more)
+            pads = history_pads(lengths.max(initial=0), grows=True)
+            if size + pads[-1] >= NOT_AN_ID:
+                raise ValueError(f"{held} ids of history with room to "
+                                 "grow: more than int32 positions hold")
+            old = (np.asarray(seen.runs)[:-1] if seen.room is None
+                   else seen.room.start[:n])
+            user = np.repeat(np.arange(n), seen.lengths)
+            within = (np.arange(len(user))
+                      - np.repeat(np.cumsum(seen.lengths) - seen.lengths,
+                                  seen.lengths))
+            src, dst = ((a[user] + within).astype(np.int32)
+                        for a in (old, start))
+            runs = (start.astype(np.int32), lengths.copy())
+        with phase("start.warmup_histories.place"):
+            count_placed("histories",
+                         sum(a.nbytes for a in runs + (src, dst)))
+            dev, moves = jax.block_until_ready(
+                (jax.device_put(runs), jax.device_put((src, dst))))
+        with phase("start.first_run"):
+            indices = _spread_runs(seen.indices, *moves,
+                                   size=size + pads[-1]).block_until_ready()
         return _Seen(dev, indices, lengths, pads,
                      _Room(start, cap, held, size))
 
@@ -1500,53 +1552,61 @@ class ServingEngine:
         (``seen_appended``), also while its catalog moves
         (``touched_items``).
         """
-        t0 = time.perf_counter()
-        mode = faults.check("serving.publish")
-        Vh = np.asarray(V, dtype=np.float32)
-        Ni = int(Vh.shape[0])
-        U, n_users, _ = self._place_users(self._model, U)
-        # behind the table they are sharded with, on a mesh
-        seen = (None if user_seen is None else self._place_seen(
-            user_seen, n_users, Ni, rows=int(U.shape[0])))
-        validh = (np.ones(Ni, dtype=bool) if item_valid is None
-                  else np.asarray(item_valid, dtype=bool).ravel())
-        self._announce_mesh()
-        V, valid = self._place_catalog(Vh, validh)
-        with self._publish_lock:
-            seq = self._seq + 1
-            sk = min(max(self.shortlist_k, self.k), Ni)
-            index = None
-            if quantize and sk >= self.k and Ni > 0:
-                # without a mesh the index uploads a copy of its own from
-                # the host's catalog, as it always has (the device then
-                # holds V twice: PERF.md section 7); with one it shares
-                # the engine's sharded table
-                index = self._build_index(
-                    *((Vh, validh) if self.mesh is None else (V, valid)),
-                    Ni, sk, seq)
-                if mode == "corrupt":
-                    # injected torn publish: quantization died mid-swap,
-                    # so the fresh index is never published.  The
-                    # previous generation's index is carried (stale by
-                    # seq, detected on the score path) or the publish
-                    # goes out index-less — no in-place seq mutation
-                    # either way.
-                    index = (self._model.index
-                             if self._model is not None else None)
-            elif self._model is not None:
-                index = self._model.index      # carried, now stale
-            self._swap("replaced", U, seq, n_users, V, valid, index, Ni,
-                       seen=seen)
-            self._seq = seq
-        fresh = index is not None and index.seq == seq
-        obs.counter("serving.publishes", **self._labels)
-        obs.histogram("serving.publish_seconds",
-                      time.perf_counter() - t0,
-                      mode="full" if fresh else "none", **self._labels)
-        obs.emit("serving_publish", seq=seq, items=Ni, quantized=fresh,
-                 mode="full" if fresh else "none", delta_rows=0,
-                 **self._labels)
-        return seq
+        with phase("start.publish"):
+            t0 = time.perf_counter()
+            mode = faults.check("serving.publish")
+            Vh = np.asarray(V, dtype=np.float32)
+            Ni = int(Vh.shape[0])
+            with phase("start.publish.users"):
+                U, n_users, _ = self._place_users(self._model, U)
+            seen = None
+            if user_seen is not None:
+                # behind the table they are sharded with, on a mesh
+                with phase("start.publish.histories"):
+                    seen = self._place_seen(user_seen, n_users, Ni,
+                                            rows=int(U.shape[0]))
+            validh = (np.ones(Ni, dtype=bool) if item_valid is None
+                      else np.asarray(item_valid, dtype=bool).ravel())
+            self._announce_mesh()
+            with phase("start.publish.catalog"):
+                V, valid = jax.block_until_ready(
+                    self._place_catalog(Vh, validh))
+            with self._publish_lock:
+                seq = self._seq + 1
+                sk = min(max(self.shortlist_k, self.k), Ni)
+                index = None
+                if quantize and sk >= self.k and Ni > 0:
+                    # without a mesh the index uploads a copy of its own from
+                    # the host's catalog, as it always has (the device then
+                    # holds V twice: PERF.md section 7); with one it shares
+                    # the engine's sharded table
+                    with phase("start.publish.index"):
+                        index = self._build_index(
+                            *((Vh, validh) if self.mesh is None
+                              else (V, valid)), Ni, sk, seq)
+                    if mode == "corrupt":
+                        # injected torn publish: quantization died mid-swap,
+                        # so the fresh index is never published.  The
+                        # previous generation's index is carried (stale by
+                        # seq, detected on the score path) or the publish
+                        # goes out index-less — no in-place seq mutation
+                        # either way.
+                        index = (self._model.index
+                                 if self._model is not None else None)
+                elif self._model is not None:
+                    index = self._model.index      # carried, now stale
+                self._swap("replaced", U, seq, n_users, V, valid, index, Ni,
+                           seen=seen)
+                self._seq = seq
+            fresh = index is not None and index.seq == seq
+            obs.counter("serving.publishes", **self._labels)
+            obs.histogram("serving.publish_seconds",
+                          time.perf_counter() - t0,
+                          mode="full" if fresh else "none", **self._labels)
+            obs.emit("serving_publish", seq=seq, items=Ni, quantized=fresh,
+                     mode="full" if fresh else "none", delta_rows=0,
+                     **self._labels)
+            return seq
 
     def _write_catalog(self, Vh, rows, item_valid, seq, placed, ride):
         """The item side of a ``publish_update`` with a live index:
@@ -1916,7 +1976,7 @@ class ServingEngine:
         chip's host; PERF.md section 6, PR 31.  Since PR 51 that is a
         cold start's cost alone.)
         """
-        with self._table_lock:
+        with phase("start.warmup"), self._table_lock:
             m = self._model
             if m is None:
                 raise NoModelPublished("publish(U, V) before warmup")
@@ -1951,16 +2011,19 @@ class ServingEngine:
         first execution takes up to seconds: none is left to the
         traffic); the event's ``seconds`` are the pin's alone."""
         fn, args, statics = call
-        t0 = time.perf_counter()
-        c, source, nbytes = pins.pin(fn, args, statics)
-        seconds = time.perf_counter() - t0
-        self._pinned[key] = c
-        obs.counter("serving.pins", source=source, **self._labels)
-        obs.emit("serving_pin", bucket=key[0], path=key[1],
-                 pad=key[2] if len(key) > 2 else None, source=source,
-                 seconds=seconds, bytes=nbytes, **self._labels)
+        with phase("start.pin"):
+            t0 = time.perf_counter()
+            c, source, nbytes, split = pins.pin(fn, args, statics)
+            seconds = time.perf_counter() - t0
+            self._pinned[key] = c
+            obs.counter("serving.pins", source=source, **self._labels)
+            obs.emit("serving_pin", bucket=key[0], path=key[1],
+                     pad=key[2] if len(key) > 2 else None, source=source,
+                     seconds=seconds, bytes=nbytes, split=split,
+                     **self._labels)
         if run:
-            c(*args).block_until_ready()
+            with phase("start.first_run"):
+                c(*args).block_until_ready()
 
     def _warm_exclusion(self, m, B):
         """Pin what a generation with histories runs for bucket ``B``,
@@ -2141,7 +2204,8 @@ class ServingEngine:
         result, the same buffer with the same values, is installed as
         the live generation's table, since the write deleted the handle
         it was given.  ``LiveUpdater.start`` calls it."""
-        with self._publish_lock:
+        with phase("start.warmup_publish"), self._publish_lock, \
+                phase("start.first_run"):
             m = self._model
             if m is None:
                 raise NoModelPublished("publish(U, V) before warmup")
@@ -2202,7 +2266,8 @@ class ServingEngine:
         histories are not compiled (no batch of such a generation runs
         them).
         """
-        with self._publish_lock, self._table_lock:
+        with phase("start.warmup_live"), self._publish_lock, \
+                self._table_lock:
             m = self._model
             if m is None:
                 raise NoModelPublished("publish(U, V) before warmup")
@@ -2211,37 +2276,46 @@ class ServingEngine:
                 if m.seen is not None:
                     self._warm_histories(m, max_rows)
                 return
-            rows = (idx.n_base if idx.n_base > idx.n_items
-                    else row_capacity(idx.n_items))
-            idx = idx.reserve(rows, self._segment_slots(idx,
-                                                        max_delta_rows))
-            # installed at once: the smaller base arrays go before the
-            # engine's own table is copied larger (one table's worth of
-            # room at a time)
-            m = self._model = _Published(m.seq, m.U, m.n_users, m.V,
-                                         m.valid, idx, m.n_items, m.seen)
-            V, valid = m.V, m.valid
-            if self.mesh is None and int(V.shape[0]) < idx.n_base:
-                more = idx.n_base - int(V.shape[0])
-                V = jnp.pad(V, ((0, more), (0, 0)))
-                valid = jnp.pad(valid, (0, more))
-            if self.mesh is None:
-                for pad in pads_up_to(max_rows):
-                    # every row the out-of-range sentinel: nothing written
-                    V, valid = _scatter_items(V, valid, *put((
-                        padded_rows((), pad, V),
-                        np.zeros((pad, m.rank), np.float32),
-                        np.zeros(pad, bool))))
-            idx = idx.prewarm(max_rows)
-            # the segment's write from rows on the device, what it takes
-            # from the host riding a publish's one array
-            nothing = idx.plan_update(())[0]
-            for pad, rows in _ride_shapes(max_rows, self.mesh):
-                ride = _Ride()
-                ride.segment = nothing.sent(rows)
-                idx = idx.write_update(
-                    nothing, self._send(ride, None, pad),
-                    jnp.zeros((rows, m.rank), jnp.float32), at=SENT_SEGMENT)
+            with phase("start.warmup_live.reserve"):
+                rows = (idx.n_base if idx.n_base > idx.n_items
+                        else row_capacity(idx.n_items))
+                idx = idx.reserve(rows, self._segment_slots(idx,
+                                                            max_delta_rows))
+                # installed at once: the smaller base arrays go before the
+                # engine's own table is copied larger (one table's worth
+                # of room at a time)
+                m = self._model = _Published(m.seq, m.U, m.n_users, m.V,
+                                             m.valid, idx, m.n_items,
+                                             m.seen)
+                V, valid = m.V, m.valid
+                if self.mesh is None and int(V.shape[0]) < idx.n_base:
+                    more = idx.n_base - int(V.shape[0])
+                    V = jnp.pad(V, ((0, more), (0, 0)))
+                    valid = jnp.pad(valid, (0, more))
+                jax.block_until_ready((V, valid))
+                idx.block_until_ready()
+            with phase("start.first_run"):
+                if self.mesh is None:
+                    for pad in pads_up_to(max_rows):
+                        # every row the out-of-range sentinel: nothing
+                        # written
+                        V, valid = _scatter_items(V, valid, *put((
+                            padded_rows((), pad, V),
+                            np.zeros((pad, m.rank), np.float32),
+                            np.zeros(pad, bool))))
+                idx = idx.prewarm(max_rows)
+                # the segment's write from rows on the device, what it
+                # takes from the host riding a publish's one array
+                nothing = idx.plan_update(())[0]
+                for pad, rows in _ride_shapes(max_rows, self.mesh):
+                    ride = _Ride()
+                    ride.segment = nothing.sent(rows)
+                    idx = idx.write_update(
+                        nothing, self._send(ride, None, pad),
+                        jnp.zeros((rows, m.rank), jnp.float32),
+                        at=SENT_SEGMENT)
+                jax.block_until_ready((V, valid))
+                idx.block_until_ready()
             m = self._model = _Published(m.seq, m.U, m.n_users, V, valid,
                                          idx, m.n_items, m.seen)
             if m.seen is not None:
@@ -2286,26 +2360,32 @@ class ServingEngine:
         ``publish_update`` refuses ``seen_appended``), and this pins and
         runs the programs that exclude — given the segment, where the
         index has one — and nothing else."""
-        if self.mesh is None:
-            seen = m.seen
-            if seen.room is None:
-                seen = self._lay_out(seen, int(m.U.shape[0]))
-            runs, indices, room = seen.runs, seen.indices, seen.room
-            for pad in pads_up_to(max_rows):
-                # the plan by itself, and as the last rows of a publish's
-                # one array
-                ride = _Ride()
-                ride.plan = self._no_append(seen, pad)
-                for plan in (put(ride.plan), self._send(ride, seen)):
-                    *runs, indices = _append_runs(*runs, indices, plan)
-            for width in seen.pads:
-                indices = _move_run(indices, 0, 0, width=width)  # onto itself
-            m = self._model = _Published(
-                m.seq, m.U, m.n_users, m.V, m.valid, m.index, m.n_items,
-                _Seen(tuple(runs), indices, seen.lengths, seen.pads, room))
-        self._pinned.clear()
-        for B in self.batcher.buckets:
-            self._warm_exclusion(m, B)
+        with phase("start.warmup_histories"):
+            if self.mesh is None:
+                seen = m.seen
+                if seen.room is None:
+                    seen = self._lay_out(seen, int(m.U.shape[0]))
+                runs, indices, room = seen.runs, seen.indices, seen.room
+                with phase("start.first_run"):
+                    for pad in pads_up_to(max_rows):
+                        # the plan by itself, and as the last rows of a
+                        # publish's one array
+                        ride = _Ride()
+                        ride.plan = self._no_append(seen, pad)
+                        for plan in (put(ride.plan),
+                                     self._send(ride, seen)):
+                            *runs, indices = _append_runs(*runs, indices,
+                                                          plan)
+                    for width in seen.pads:     # each run onto itself
+                        indices = _move_run(indices, 0, 0, width=width)
+                    indices.block_until_ready()
+                m = self._model = _Published(
+                    m.seq, m.U, m.n_users, m.V, m.valid, m.index,
+                    m.n_items, _Seen(tuple(runs), indices, seen.lengths,
+                                     seen.pads, room))
+            self._pinned.clear()
+            for B in self.batcher.buckets:
+                self._warm_exclusion(m, B)
 
     @property
     def holds_histories(self):
@@ -2433,6 +2513,7 @@ class ServingEngine:
             daemon=True)
         self._completer.start()
         self._thread.start()
+        compiles.traffic(True)
         return self
 
     def stop(self, drain_timeout_s=5.0):
@@ -2444,6 +2525,8 @@ class ServingEngine:
         for thread in (self._thread, self._completer):
             if thread is not None:
                 thread.join(max(0.0, deadline - time.monotonic()))
+        if self._thread is not None:
+            compiles.traffic(False)
         self._thread = self._completer = None
 
     def __enter__(self):

@@ -874,7 +874,7 @@ def place_catalog(V, item_valid, mesh, shortlist_k=64):
     cap = D * shortlist_columns(ni_loc, min(int(shortlist_k), ni_loc))
     valid = (np.ones(Ni, dtype=bool) if item_valid is None
              else np.asarray(item_valid, dtype=bool).ravel())
-    return (place_rows(V, capacity=cap, mesh=mesh),
+    return (place_rows(V, capacity=cap, mesh=mesh, table="catalog"),
             jax.device_put(np.pad(valid, (0, cap - Ni)),
                            shard_leading(mesh)), Ni)
 

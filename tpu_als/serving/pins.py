@@ -32,6 +32,7 @@ import logging
 import os
 import pickle
 import threading
+import time
 import zlib
 
 import jax
@@ -166,29 +167,51 @@ def loads(blob, args):
 
 
 def pin(fn, args, statics):
-    """``(compiled, source, bytes)`` of the ``stages.Compiled`` that
-    ``fn.lower(*args, **statics).compile()`` gives.  ``source``:
+    """``(compiled, source, bytes, split)`` of the ``stages.Compiled``
+    that ``fn.lower(*args, **statics).compile()`` gives.  ``source``:
     ``loaded`` — the executable lay in the store under :func:`key` and
     was deserialized and loaded, nothing traced or lowered; ``compiled``
     — it did not (or there is no store): lowered and compiled, and
     written there; ``unreadable`` — its file did not load (truncated,
     another runtime's): compiled, and the file written over.  ``bytes``:
-    the file's, read or written (0 without a store)."""
+    the file's, read or written (0 without a store).  ``split``: the
+    seconds by step — ``key_s`` and ``load_s``, or ``key_s``,
+    ``lower_s``, ``compile_s`` and ``write_s`` (no ``key_s`` / ``write_s``
+    without a store)."""
+    split, last = {}, time.perf_counter()
+
+    def lap(step):
+        nonlocal last
+        now = time.perf_counter()
+        split[step], last = now - last, now
+
     root = store_dir()
-    if root is None:
-        return fn.lower(*args, **statics).compile(), "compiled", 0
-    path = os.path.join(root, key(fn, args, statics))
-    try:
-        with open(path, "rb") as f:
-            blob = f.read()
-        return loads(blob, args), "loaded", len(blob)
-    except FileNotFoundError:
-        source = "compiled"
-    except Exception:   # whatever a damaged file raises: a miss
-        log.warning("pin %s did not load: compiling", path, exc_info=True)
-        source = "unreadable"
-    compiled = fn.lower(*args, **statics).compile()
-    return compiled, source, _write(path, compiled)
+    source, path = "compiled", None
+    if root is not None:
+        path = os.path.join(root, key(fn, args, statics))
+        lap("key_s")
+        try:
+            with open(path, "rb") as f:
+                blob = f.read()
+            loaded = loads(blob, args)
+            lap("load_s")
+            return loaded, "loaded", len(blob), split
+        except FileNotFoundError:
+            pass
+        except Exception:   # whatever a damaged file raises: a miss
+            log.warning("pin %s did not load: compiling", path,
+                        exc_info=True)
+            source = "unreadable"
+        last = time.perf_counter()      # a load that failed is no step
+    lowered = fn.lower(*args, **statics)
+    lap("lower_s")
+    compiled = lowered.compile()
+    lap("compile_s")
+    if path is None:
+        return compiled, source, 0, split
+    nbytes = _write(path, compiled)
+    lap("write_s")
+    return compiled, source, nbytes, split
 
 
 def _write(path, compiled):

@@ -86,6 +86,7 @@ import time
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
@@ -106,6 +107,7 @@ from tpu_als.core.ratings import (
     row_capacity,
     rung_for,
 )
+from tpu_als.obs.phases import phase
 from tpu_als.ops.solve import compute_yty
 from tpu_als.serving.engine import Stamped
 from tpu_als.utils.frame import as_frame
@@ -139,11 +141,6 @@ class FoldInServer:
             raise ValueError("base_history needs keep_history: the events "
                              "go behind the resident ratings")
         self._base = base_history
-        # the widths' ladder: up the plain one (8, 64, 512, ...) without a
-        # base history, with the rung above the longest resident one's
-        # rung where histories grow from there
-        self._widths = () if base_history is None else growth_pads(
-            int(np.diff(base_history[0]).max(initial=0)))
         # (user ids, item ids) of the last ``update``'s events that added
         # an id to their user's history (all of them without a base)
         self.last_appended = (np.empty(0, np.int64), np.empty(0, np.int64))
@@ -177,13 +174,26 @@ class FoldInServer:
         # rating names it (made when the item side is first asked for)
         self._rated_before = None
         self._bufs = {}     # "_U" / "_V" -> the buffer the model's is a view of
-        self._reserve(items_side=False)
-        # each fold direction's fixed side on the device, placed once:
-        # the catalog now, the user table when an item fold first needs
-        # it (a user-only server never holds one)
-        self._V = self._place("_V")
-        self._Ud = None
-        self._YtY = compute_yty(self._V) if self._implicit else None
+        self._Ud = self._YtY = None
+        # what of a start takes time, phase by phase
+        # (``obs.schema.START_PHASES``)
+        with phase("start.foldin_server"):
+            # the widths' ladder: up the plain one (8, 64, 512, ...)
+            # without a base history, with the rung above the longest
+            # resident one's rung where histories grow from there
+            with phase("start.foldin_server.history"):
+                self._widths = () if base_history is None else growth_pads(
+                    int(np.diff(base_history[0]).max(initial=0)))
+            with phase("start.foldin_server.reserve"):
+                self._reserve(items_side=False)
+            # each fold direction's fixed side on the device, placed
+            # once: the catalog now, the user table when an item fold
+            # first needs it (a user-only server never holds one)
+            with phase("start.foldin_server.place"):
+                self._V = self._place("_V")
+            if self._implicit:
+                with phase("start.foldin_server.yty"):
+                    self._YtY = compute_yty(self._V).block_until_ready()
         # (batch_size, touched_users, latency_seconds, padded width) —
         # bounded: a
         # long-lived live pipeline folds in forever, and the durable
@@ -208,7 +218,8 @@ class FoldInServer:
         appended to it later change no shape and compile nothing."""
         return place_rows(
             getattr(self.model, fac_attr),
-            capacity=self._capacity(fac_attr) << growth).block_until_ready()
+            capacity=self._capacity(fac_attr) << growth,
+            table="fold_fixed").block_until_ready()
 
     def _reserve(self, items_side, rows=0):
         """Make the model's factor table of this side a writable view of a
@@ -264,36 +275,50 @@ class FoldInServer:
         widths = (pads_up_to(max(widths)) if widths is not None
                   else self._widths or LIVE_PADS)
         for side in sides:
-            # the id maps sort their ids at first use: now, not mid-stream
-            (m._user_map if side == "user" else m._item_map).to_dense([0])
-            for g in range(int(growth) + 1):
-                F = (self._fixed(items_side=side == "item") if g == 0
-                     else self._place("_V" if side == "user" else "_U", g))
-                YtY = compute_yty(F) if self._implicit else None
-                for n in rows:
-                    _, path, why = solve_path(F.shape[1], n,
-                                              self._nonnegative)
-                    for w in widths:
-                        if n > (self._rows_at(w) or n):
-                            continue
-                        # host arrays, as ``_fold_batch`` hands them over
-                        self._fold(F, planes(np.zeros((3, n, w), np.int32)),
-                                   YtY).block_until_ready()
-                        obs.emit("foldin_solve_path", side=side,
-                                 rank=int(F.shape[1]), rows=n, width=w,
-                                 path=path, reason=why)
+            with phase("start.prewarm", side=side):
+                with phase("start.prewarm.reserve", side=side):
+                    # the id maps sort their ids at first use: now, not
+                    # mid-stream
+                    (m._user_map if side == "user"
+                     else m._item_map).to_dense([0])
+                fixed = self._fixed(items_side=side == "item")
+                with phase("start.prewarm.programs", side=side):
+                    self._prewarm_folds(side, fixed, rows, widths, growth)
         if self._Ud is not None:
             # both directions fold: each one's write-back also writes
             # its rows into the other's fixed table; those programs now
             # (from the host's rows and from the fold's on the device:
             # one program, both forms of its call)
             none = np.empty((0, m._U.shape[1]), np.float32)
+            with phase("start.prewarm", side="both"), \
+                    phase("start.prewarm.writes"):
+                for n in rows:
+                    placed = jnp.zeros((n, m._U.shape[1]), jnp.float32)
+                    self._V = write_rows(self._V, [], none, pad=n)
+                    self._Ud = write_rows(self._Ud, [], none, pad=n)
+                    self._V = write_placed_rows(self._V, [], placed)
+                    self._Ud = write_placed_rows(self._Ud, [], placed)
+                jax.block_until_ready((self._V, self._Ud))
+
+    def _prewarm_folds(self, side, fixed, rows, widths, growth):
+        """:meth:`prewarm`'s ladder for one side: the fold-in program of
+        every padded shape compiled (or fetched) and run against
+        ``fixed``, and against a table ``growth`` doublings larger."""
+        for g in range(int(growth) + 1):
+            F = (fixed if g == 0
+                 else self._place("_V" if side == "user" else "_U", g))
+            YtY = compute_yty(F) if self._implicit else None
             for n in rows:
-                placed = jnp.zeros((n, m._U.shape[1]), jnp.float32)
-                self._V = write_rows(self._V, [], none, pad=n)
-                self._Ud = write_rows(self._Ud, [], none, pad=n)
-                self._V = write_placed_rows(self._V, [], placed)
-                self._Ud = write_placed_rows(self._Ud, [], placed)
+                _, path, why = solve_path(F.shape[1], n, self._nonnegative)
+                for w in widths:
+                    if n > (self._rows_at(w) or n):
+                        continue
+                    # host arrays, as ``_fold_batch`` hands them over
+                    self._fold(F, planes(np.zeros((3, n, w), np.int32)),
+                               YtY).block_until_ready()
+                    obs.emit("foldin_solve_path", side=side,
+                             rank=int(F.shape[1]), rows=n, width=w,
+                             path=path, reason=why)
 
     def update(self, batch):
         """Process one micro-batch frame (userCol/itemCol/ratingCol of the
@@ -330,11 +355,13 @@ class FoldInServer:
         if self._Ud is None:
             # item folds begin: the catalog gets its spare rows on the
             # host too (one copy, here and not under the first append)
-            self._reserve(items_side=True)
-            self._Ud = self._place("_U")
-            if self._base is not None:
-                self._rated_before = np.bincount(
-                    self._base[1], minlength=len(self.model._V)) > 0
+            with phase("start.prewarm.reserve", side="item"):
+                self._reserve(items_side=True)
+                if self._base is not None:
+                    self._rated_before = np.bincount(
+                        self._base[1], minlength=len(self.model._V)) > 0
+            with phase("start.prewarm.place", side="item"):
+                self._Ud = self._place("_U")
         return self._Ud
 
     def _to_refit(self, items):
@@ -503,8 +530,6 @@ class FoldInServer:
                 self._YtY = compute_yty(self._V)
             dt = time.perf_counter() - t0
             self.stats.append((entered, n, dt, widest))
-            obs.histogram("foldin.update_seconds", dt, side=side)
-            obs.histogram("foldin.batch_rows", n, side=side)
             obs.counter("foldin.ratings", entered)
         return touched
 

@@ -148,9 +148,8 @@ def test_untouched_users_keep_their_rows(drained):
 
 
 def test_a_users_events_are_kept_in_arrival_order(drained):
-    hist = drained["srv"]._history
     for user, evs in drained["by_user"].items():
-        items, stars = hist[user]
+        items, stars = drained["srv"].history_of(user)
         assert items.tolist() == [i for i, _ in evs]
         assert stars.tolist() == [r for _, r in evs]
 
@@ -160,7 +159,8 @@ def test_no_event_is_lost_or_folded_twice(drained):
     assert reg.histogram_count("live.freshness_seconds") == N_EVENTS
     assert reg.counter_value("foldin.ratings") == N_EVENTS
     assert reg.counter_value("live.shed") == 0
-    assert sum(len(h[0]) for h in drained["srv"]._history.values()) \
+    srv = drained["srv"]
+    assert sum(len(srv.history_of(user)[0]) for user in srv._history) \
         == N_EVENTS
 
 

@@ -355,7 +355,7 @@ def test_a_rating_of_a_new_item_enters_its_users_next_fold():
     np.testing.assert_allclose(model._U[3], want, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(model._U[3], Ur[3], rtol=2e-4, atol=2e-5)
     assert not np.allclose(model._U[3], fold(then, [12], [1.0]), rtol=1e-2)
-    assert srv._history[3][0].tolist() == [N_ITEMS, 12]
+    assert srv.history_of(3)[0].tolist() == [N_ITEMS, 12]
     assert reg.counter_value("foldin.ratings") == entered == 6
     assert srv.events_waiting == 0
 
@@ -391,7 +391,7 @@ def test_a_rating_with_both_sides_unknown_waits_and_is_folded():
         Vr[Y], fold({X: Ur[X], 5: U[5].astype(np.float64)} | {
             X: fold(dict(enumerate(V.astype(np.float64))), [11], [3.0])},
             [X, 5], [5.0, 4.0]), rtol=1e-9)
-    assert srv._history[X][0].tolist() == [Y, 11, 12]
+    assert srv.history_of(X)[0].tolist() == [Y, 11, 12]
     # 4 ratings x 2 sides, less user 5's of Y (5 has not been folded since)
     assert reg.counter_value("foldin.ratings") == entered == 7
     s, ix = eng.recommend(8.0 * model._V[y], timeout=10.0) \

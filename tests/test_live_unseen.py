@@ -210,7 +210,7 @@ def test_rating_an_item_again_replaces_the_rating_and_adds_no_id():
     *_, plain, _ = make_stack(seed=5, base=False)
     plain.update(frame([user] * 2, [new, new], [5.0, 2.0]))
     assert len(plain.last_appended[0]) == 2
-    assert len(plain._history[user][0]) == 2
+    assert len(plain.history_of(user)[0]) == 2
 
 
 # -- (b) (d) the deployment, event by event -------------------------------------

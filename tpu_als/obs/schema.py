@@ -56,6 +56,14 @@ METRICS = {
         "per rating and side folded: a rating folded into its user AND "
         "into its item (update and update_items) counts twice; a rating "
         "held for a side without a factor counts when it enters"),
+    "foldin.ids_mapped": (
+        "counter", "ids",
+        "ids of a fold's fixed side sent through IdMap.to_dense, by side "
+        "folded (user | item): the events' own, once, and what a touched "
+        "entity's history still held WITHOUT a table row where the map "
+        "has grown since it last looked — never a history's known ids "
+        "(stream.microbatch._Ratings keeps those as rows); the "
+        "live.batch.foldin.map span's ``mapped`` beside its ``ratings``"),
     "checkpoint.save_seconds": (
         "histogram", "seconds", "save_factors wall-clock duration"),
     "checkpoint.save_bytes": (
@@ -393,6 +401,7 @@ LABELS = {
     "train.stage_seconds": ("stage",),
     "serve.request_seconds": ("strategy",),
     "foldin.history_width": ("side",),
+    "foldin.ids_mapped": ("side",),
     "serving.enqueue_seconds": ("tenant",),
     "serving.score_seconds": ("path", "tenant"),
     "serving.e2e_seconds": ("tenant",),
@@ -680,15 +689,23 @@ LIVE_PHASE_SPAN_KEYS = (
     #                                  fold's): the frame's columns, the
     #                                  items left to the refit, events
     #                                  grouped by entity
-    "live.batch.foldin.history",     # events put behind each entity's
-    #                                  history (_resident, _one_rating_each,
-    #                                  ratings held for a side without a
-    #                                  factor)
-    "live.batch.foldin.map",         # the merged histories' ids mapped to
-    #                                  table rows (ratings: how many), the
-    #                                  usable counted, who is folded
+    "live.batch.foldin.history",     # the EVENTS' ids mapped to table
+    #                                  rows, once, and put behind each
+    #                                  entity's history, which is kept as
+    #                                  rows (_Ratings: a first touch copies
+    #                                  the resident run as it lies;
+    #                                  ``rate``: one rating an id; ratings
+    #                                  held for a side without a factor)
+    "live.batch.foldin.map",         # what the touched histories hold
+    #                                  WITHOUT a row looked up again, where
+    #                                  the map has grown (mapped: ids sent
+    #                                  through to_dense this fold, the
+    #                                  events' included; ratings: ids in
+    #                                  the folds), the usable counted, who
+    #                                  is folded
     "live.batch.foldin.pack",        # one call's ids, stars and mask
-    #                                  into the ONE host array it rides
+    #                                  into the ONE host array it rides:
+    #                                  slice copies, an entity a row
     "live.batch.foldin.call",        # the fold-in program called, until
     #                                  the call returns (rows, width: the
     #                                  padded shape; calls: which of the

@@ -5,8 +5,11 @@ for new ratings — SURVEY.md §3.5), promised by the north-star (BASELINE.json
 configs[3]: "hourly micro-batches of new ratings → incremental user-factor
 jit update").  The server wraps a fitted ALSModel; each ``update`` call:
 
-1. groups the batch by touched user and merges each with the rating history
-   the server keeps of that user (optional),
+1. groups the batch by touched user and puts each user's events behind the
+   rating history the server keeps of that user (optional) — kept in the
+   form the fold program takes it, the fixed side's TABLE ROWS and the
+   stars, so a fold maps its events' ids and never a history's
+   (``_Ratings``),
 2. pads touched-user rows and widths up a short ladder (8, 64, 512, ...:
    ``core.ratings.pad_for``) so that the set of compiled programs is small
    and ``prewarm`` can run all of them before the stream starts,
@@ -48,7 +51,9 @@ then is as wide as its longest history, so the widths' ladder reaches the
 rung above the longest resident one (``core.ratings.growth_pads``), a call
 gathers at most ``FOLD_ELEMENTS`` ratings (a batch of many long histories
 goes in several), and a batch costs the host and the link what its touched
-users' histories hold, never all the histories.  Such a server also keeps
+users' histories hold, never all the histories — and of that only slice
+copies: a touched user's resident run IS table rows and is copied as it
+lies, once, at that user's first event.  Such a server also keeps
 ONE rating a user and item (Amazon keeps one review a customer and
 product): an event on an item its user has rated already — in the
 resident history or earlier in the run — replaces that rating's stars and
@@ -74,9 +79,13 @@ asked for.
 
 **A fold on the profiler's timeline** (``obs.schema.
 LIVE_PHASE_SPAN_KEYS``, ``LIVE_FOLDIN_SPAN_KEYS``; each with its
-``side``): ``live.batch.foldin.group``, ``.history``, ``.map``, ``.pack``
-(one a call), ``.readback`` around ``.call``, ``.write_back`` — spans a
-FOLD, never an event; outside a profiler session a microsecond each.
+``side``): ``live.batch.foldin.group``, ``.history`` (the events' ids
+mapped once, the events behind their entities' rows), ``.map`` (only the
+ids a history still holds WITHOUT a row, and only once the map has grown:
+``mapped`` beside ``ratings``, the counter ``foldin.ids_mapped``),
+``.pack`` (slice copies, one a call), ``.readback`` around ``.call``,
+``.write_back`` — spans a FOLD, never an event; outside a profiler
+session a microsecond each.
 """
 
 from __future__ import annotations
@@ -102,6 +111,7 @@ from tpu_als.core.foldin import (
 from tpu_als.core.ratings import (
     LIVE_PADS,
     growth_pads,
+    growth_room,
     pad_for,
     pads_up_to,
     row_capacity,
@@ -130,7 +140,8 @@ class FoldInServer:
     ratings AND the events, and an event on an item the user has already
     rated replaces that rating (module docstring).  The arrays are read,
     never written or copied whole: a touched user's run is copied into the
-    server's own history at that user's first event.  Needs
+    server's own history at that user's first event, as the table rows it
+    is (:meth:`history_of` reads a history back as original ids).  Needs
     ``keep_history``."""
 
     def __init__(self, model, keep_history=True, stats_window=512,
@@ -156,8 +167,9 @@ class FoldInServer:
         # (``ServingEngine.publish_update(device_rows=...)``); ``None``
         # where nothing was folded or the fold took several calls
         self.last_rows = {"users": None, "items": None}
-        # original id -> (fixed-side ORIGINAL ids, ratings), in arrival
-        # order
+        # original id -> its ratings as the fold program takes them
+        # (``_Ratings``: the fixed side's TABLE ROWS and the stars, in
+        # arrival order; ``history_of`` gives the original ids)
         self._history = {}
         self._item_history = {}
         # side -> {original id: how many of its ratings its last fold
@@ -415,37 +427,63 @@ class FoldInServer:
             # and put them behind the entity's history
             touched, entity = np.unique(solved_raw, return_inverse=True)
             by_entity = np.argsort(entity, kind="stable")
-            bounds = np.cumsum(
-                np.bincount(entity, minlength=len(touched)))[:-1]
-            per = list(zip(np.split(fixed_raw[by_entity], bounds),
-                           np.split(r[by_entity], bounds)))
+            # entity j's events: ``by_entity[cut[j]:cut[j + 1]]``
+            cut = np.append(0, np.cumsum(
+                np.bincount(entity, minlength=len(touched))))
+            ids_by, stars_by = fixed_raw[by_entity], r[by_entity]
         with Stamped("live.batch.foldin.history", side=sides):
             used = self._used[side] if self.keep_history else {}
+            # the events' own ids are ALL a fold maps (a history is kept
+            # in table rows: ``_Ratings``); -1: no factor yet
+            event_rows = fixed_map.to_dense(fixed_raw).astype(np.int32)
+            grown, mapped = len(fixed_map), len(fixed_raw)
+            no_row = event_rows < 0
+            lacking = bool(no_row.any())
             # with a base history a user has ONE rating an item: an event
             # on an item already rated replaces it (``adds``: which events
-            # add an id to their user's history; ``again``: the items
-            # re-rated)
+            # add an id to their user's history)
             one_rating = self._base is not None and not items_side
-            adds, again = np.ones(len(r), bool), []
-            if self.keep_history:
+            adds = np.ones(len(r), bool)
+            if self.keep_history and lacking:
                 # a rating whose other side has no factor yet waits for it
                 held = self._waiting["user" if items_side else "item"]
-                for e in fixed_raw[
-                        fixed_map.to_dense(fixed_raw) < 0].tolist():
+                for e in fixed_raw[no_row].tolist():
                     held[e] = held.get(e, 0) + 1
-                events = np.split(by_entity, bounds)
-                for j, e in enumerate(touched.tolist()):
-                    hist = history.get(e)
-                    if hist is None and one_rating:
-                        hist = self._resident(e, used)
-                    if one_rating:
-                        per[j], new = _one_rating_each(hist, *per[j])
-                        adds[events[j][~new]] = False
-                        again.append(fixed_raw[events[j][~new]])
-                    elif hist is not None:
-                        per[j] = (np.concatenate([hist[0], per[j][0]]),
-                                  np.concatenate([hist[1], per[j][1]]))
-                    history[e] = per[j]
+            rows_by = event_rows[by_entity]
+            # which entities' events hold an id without a row
+            lacks = (np.logical_or.reduceat(no_row[by_entity],
+                                            cut[:-1]).tolist()
+                     if lacking else [False] * len(touched))
+            hists, cut, entities = [], cut.tolist(), touched.tolist()
+            # the users no event has touched yet start from their resident
+            # runs: their table rows in ONE lookup (a lookup is a dozen
+            # numpy calls whatever it looks up, and on a serving host the
+            # calls are what a batch of five events pays for)
+            fresh = ([e for e in entities if e not in history]
+                     if one_rating else [])
+            home = dict(zip(fresh, m._user_map.to_dense(fresh).tolist())
+                        if fresh else ())
+            for j, e in enumerate(entities):
+                mine = slice(cut[j], cut[j + 1])
+                hist = history.get(e) if self.keep_history else None
+                if hist is None:
+                    hist = self._resident(home[e]) if one_rating else None
+                    if hist is None:
+                        hist = _Ratings()
+                    else:
+                        # behind the model's factors: folded before
+                        used[e] = hist.n
+                    if self.keep_history:
+                        history[e] = hist
+                if one_rating:
+                    old = hist.rate(rows_by[mine], ids_by[mine],
+                                    stars_by[mine], grown, lacks[j])
+                    if old:
+                        adds[by_entity[mine][old]] = False
+                else:
+                    hist.extend(rows_by[mine], ids_by[mine], stars_by[mine],
+                                grown, lacks[j])
+                hists.append(hist)
             if not items_side:
                 self.last_appended = (solved_raw[adds], fixed_raw[adds])
 
@@ -453,18 +491,19 @@ class FoldInServer:
         # NOW (fixed-side entities never seen cannot contribute: no
         # factors to regress on); an entity with none is not folded
         with Stamped("live.batch.foldin.map", side=sides) as span:
-            lens = np.array([len(f) for f, _ in per])
-            dense = fixed_map.to_dense(np.concatenate([f for f, _ in per]))
-            vals_all = np.concatenate([v for _, v in per])
-            span.set_metadata(ratings=len(dense))
-            known = dense >= 0
-            usable = np.add.reduceat(known.astype(np.int64),
-                                     np.cumsum(lens) - lens)
+            # no lookup of an id that has its row: only what a history
+            # holds without one, and only where the map has grown since
+            # that history last looked
+            mapped += _look_again(
+                [h for h in hists if h.unknown and h.looked < grown],
+                fixed_map)
+            span.set_metadata(ratings=sum(h.n for h in hists),
+                              mapped=mapped)
+            obs.counter("foldin.ids_mapped", mapped, side=side)
+            usable = np.array([h.n - len(h.unknown) for h in hists])
             # ratings that enter a fold of this side for the first time (a
             # rating that replaces one enters in its place)
-            entered = (
-                int((fixed_map.to_dense(np.concatenate(again)) >= 0).sum())
-                if again else 0)
+            entered = int((~no_row[~adds]).sum())
             for e, n_ok in zip(touched.tolist(), usable.tolist()):
                 entered += n_ok - used.get(e, 0)
                 used[e] = n_ok
@@ -472,7 +511,7 @@ class FoldInServer:
             if not fold.any():
                 return np.array([], dtype=np.int64)
             touched = touched[fold]
-            dense, vals_all = dense[known], vals_all[known]
+            hists = [h for h, f in zip(hists, fold.tolist()) if f]
             lens = usable[fold]
 
             F = self._fixed(items_side)
@@ -484,28 +523,26 @@ class FoldInServer:
             # pad rows and width up the ladder -> the programs prewarm
             # ran; one call, or where its gather would pass FOLD_ELEMENTS
             # several
-            n, first = len(touched), np.cumsum(lens) - lens
+            n = len(touched)
             x, widest, solved = (np.empty((n, F.shape[1]), np.float32), 0,
                                  [])
         for sel in self._calls(lens):
             with Stamped("live.batch.foldin.pack", side=sides):
-                ln = lens[sel]
-                n_pad, w = pad_for(len(sel)), rung_for(int(ln.max()),
+                n_pad, w = pad_for(len(sel)), rung_for(int(lens[sel].max()),
                                                        self._widths)
                 obs.histogram("foldin.history_width", w, side=side)
                 widest = max(widest, w)
-                row = np.repeat(np.arange(len(sel)), ln)
-                slot = (np.arange(ln.sum())
-                        - np.repeat(np.cumsum(ln) - ln, ln))
-                flat = np.repeat(first[sel], ln) + slot
                 # ids, stars and mask as planes of the ONE array the
                 # program takes (``core.foldin.pack_rows``), a host
-                # argument of its call: nothing is placed ahead of it
+                # argument of its call: nothing is placed ahead of it;
+                # each entity's usable ratings as slice copies
                 rows = cols, vals, mask = planes(
                     np.zeros((3, n_pad, w), dtype=np.int32))
-                cols[row, slot] = dense[flat]
-                vals[row, slot] = vals_all[flat]
-                mask[row, slot] = 1.0
+                for k, i in enumerate(sel.tolist()):
+                    ids, stars = hists[i].usable()
+                    cols[k, :len(ids)] = ids
+                    vals[k, :len(ids)] = stars
+                    mask[k, :len(ids)] = 1.0
             # the fold's one wait for the device, on the profiler's
             # timeline (obs.schema.LIVE_FOLDIN_SPAN_KEYS): the call, which
             # carries the one array up (a phase of its own inside, until
@@ -541,19 +578,37 @@ class FoldInServer:
                        implicit_prefs=self._implicit, alpha=self._alpha,
                        nonnegative=self._nonnegative, YtY=YtY)
 
-    def _resident(self, user, used):
-        """The resident ratings of ``user`` as the start of the server's
-        own history of that user, ``(item ids, stars)``, copied (``None``
-        for a user the base history has no row for); all of them count as
-        folded before (``used``: they are behind the model's factors)."""
+    def _resident(self, row):
+        """The resident ratings of the user at table row ``row`` as the
+        start of the server's own history of that user, copied as they
+        lie — the run IS table rows — into buffers with room (``None``
+        for a user the base history has no row for)."""
         indptr, indices, stars = self._base
-        row = int(self.model._user_map.to_dense([user])[0])
         if not 0 <= row < len(indptr) - 1:
             return None
         lo, hi = int(indptr[row]), int(indptr[row + 1])
-        used[user] = hi - lo
-        return (self.model._item_map.to_original(indices[lo:hi]),
-                np.array(stars[lo:hi], dtype=np.float32))
+        n = hi - lo
+        size = n + int(growth_room(n))
+        return _Ratings(_resized(indices[lo:hi], n, size, np.int32),
+                        _resized(stars[lo:hi], n, size, np.float32), n)
+
+    def history_of(self, entity, items_side=False):
+        """``(original ids of the other side, stars)`` of the ratings the
+        server keeps of ``entity`` (an original id: a user's, with
+        ``items_side`` an item's), in arrival order — copies; empty for
+        an entity no event has touched."""
+        m = self.model
+        hist = (self._item_history if items_side
+                else self._history).get(entity)
+        ids = (m._user_map if items_side else m._item_map).ids
+        if hist is None:
+            return ids[:0].copy(), np.empty(0, np.float32)
+        rows = hist.rows[:hist.n]
+        out, known = np.empty(hist.n, ids.dtype), rows >= 0
+        out[known] = ids[rows[known]]
+        for at, original in hist.unknown.items():
+            out[at] = original
+        return out, hist.stars[:hist.n].copy()
 
     def _calls(self, lens):
         """The entities of one batch by call of the fold-in program, as
@@ -623,25 +678,110 @@ class FoldInServer:
         return self.latency(0.5)
 
 
-def _one_rating_each(hist, items, stars):
-    """``((item ids, stars) of a history with the events merged in,
-    which events added an id)``: the events ``(items, stars)``, in arrival
-    order, put behind ``hist`` (``None``: no history yet), but an event
-    on an item the history holds already — or an earlier event of these
-    does — replaces that rating's stars instead.  ``hist``'s stars are
-    written in place."""
-    if hist is None:
-        hist = (items[:0], stars[:0])
-    h_items, h_stars = hist
-    new = np.ones(len(items), bool)
-    at = {}                 # item -> its place among these events' new ones
-    for k, (item, star) in enumerate(zip(items.tolist(), stars.tolist())):
-        had = np.flatnonzero(h_items == item)
-        if len(had):
-            h_stars[had[0]], new[k] = star, False
-        elif item in at:
-            stars[at[item]], new[k] = star, False
+# a history nothing has been put in yet: no buffer to write into
+_NO_ROWS, _NO_STARS = np.empty(0, np.int32), np.empty(0, np.float32)
+
+
+class _Ratings:
+    """One entity's ratings in the form the fold program takes them: the
+    other side's TABLE ROWS (int32; -1 where the id map does not hold the
+    id yet) and the stars, contiguous, in arrival order, in buffers with
+    room to grow (``core.ratings.growth_room``).  An id keeps its row for
+    good (``IdMap.append`` only adds rows at the end), so a row is looked
+    up once, when its event arrives; the original id is kept only where
+    the row is -1 (``unknown``: place -> id), and looked up again only
+    once the map has grown (``looked``: its length when these last
+    looked; :func:`_look_again`)."""
+
+    __slots__ = ("rows", "stars", "n", "unknown", "looked")
+
+    def __init__(self, rows=_NO_ROWS, stars=_NO_STARS, n=0):
+        # buffers, and how many of their entries are ratings
+        self.rows, self.stars, self.n = rows, stars, n
+        self.unknown, self.looked = {}, 0
+
+    def extend(self, rows, ids, stars, grown, lacking=True):
+        """The events ``(rows, ids, stars)`` — their table rows as a map
+        of ``grown`` ids gave them (``lacking``: some may have none),
+        their original ids — behind these.  An entity's first events'
+        arrays ARE its history until it is rated again: only then is
+        there something to copy, into buffers with room."""
+        n, most = self.n, self.n + len(rows)
+        if not len(self.rows):
+            self.rows, self.stars = rows, stars
         else:
-            at[item] = k
-    return (np.concatenate([h_items, items[new]]),
-            np.concatenate([h_stars, stars[new]])), new
+            if most > len(self.rows):
+                size = most + int(growth_room(most))
+                self.rows, self.stars = (
+                    _resized(a, n, size) for a in (self.rows, self.stars))
+            self.rows[n:most], self.stars[n:most] = rows, stars
+        self.n = most
+        if lacking:
+            if not self.unknown:
+                self.looked = grown
+            for k in np.flatnonzero(rows < 0).tolist():
+                self.unknown[n + k] = ids[k]
+
+    def rate(self, rows, ids, stars, grown, lacking=True):
+        """:meth:`extend` under the rule ONE rating an id: an event on an
+        id these hold already — or an earlier event of the same ones does
+        — replaces that rating's stars and adds nothing.  Rows are
+        compared with rows, original ids only among the entries without
+        one.  Returns which of the events added nothing (their places:
+        mostly none)."""
+        n, old, at = self.n, [], {}
+        for k, (row, original, star) in enumerate(
+                zip(rows.tolist(), ids.tolist(), stars.tolist())):
+            had = next((p for p, i in self.unknown.items() if i == original),
+                       None) if self.unknown else None
+            if had is None and row >= 0:
+                had = next(iter(
+                    (self.rows[:n] == row).nonzero()[0].tolist()), None)
+            if had is not None:
+                self.stars[had] = star
+                old.append(k)
+            elif original in at:
+                stars[at[original]] = star
+                old.append(k)
+            else:
+                at[original] = k
+        if old:
+            new = np.ones(len(rows), bool)
+            new[old] = False
+            rows, ids, stars = rows[new], ids[new], stars[new]
+        self.extend(rows, ids, stars, grown, lacking)
+        return old
+
+    def usable(self):
+        """``(rows, stars)`` of the ratings whose other side has a row."""
+        rows, stars = self.rows[:self.n], self.stars[:self.n]
+        if not self.unknown:
+            return rows, stars
+        known = rows >= 0
+        return rows[known], stars[known]
+
+
+def _resized(a, n, size, dtype=None):
+    """``a[:n]`` at the start of a new array of ``size`` (of ``a``'s
+    type, or of ``dtype``)."""
+    out = np.empty(size, dtype or a.dtype)
+    out[:n] = a[:n]
+    return out
+
+
+def _look_again(hists, id_map):
+    """The entries of ``hists`` without a row looked up in ``id_map``
+    again, ONE lookup for all of them; those it now holds take their rows
+    for good.  Returns how many ids were looked up."""
+    if not hists:
+        return 0
+    ids = [i for h in hists for i in h.unknown.values()]
+    rows = iter(id_map.to_dense(ids).tolist())
+    for h in hists:
+        for at in list(h.unknown):
+            row = next(rows)
+            if row >= 0:
+                h.rows[at] = row
+                del h.unknown[at]
+        h.looked = len(id_map)
+    return len(ids)

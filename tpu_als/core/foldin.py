@@ -24,6 +24,7 @@ import numpy as np
 
 from tpu_als.core.ratings import pad_for
 from tpu_als.obs.phases import count_placed
+from tpu_als.obs.schema import LIVE_FOLDIN_YTY_SCOPE
 from tpu_als.ops.solve import (
     DEFAULT_JITTER,
     SOLVE_PATH_NAMES,
@@ -97,7 +98,57 @@ def _scatter_rows(table, rows, vals):
         return table.at[rows].set(vals, mode="drop")
 
 
-def write_rows(table, rows, vals, pad=None):
+# rows one step of :func:`whole_yty` multiplies (8 MiB at rank 256).  On a
+# v5e the sum inside ONE product loses with its length, all one way: over
+# 1.73 M x 256 rows of N(0, 1) a step of 65,536 rows (and the one einsum
+# over the table) read 1.1e-5 to 1.9e-5 under the float64 diagonal, every
+# entry alike, a step of 8,192 rows 2e-7 with no lean, steps of 1,024 and
+# fewer 6e-7 to 1.2e-6 again (more sums of sums) — 8.2 to 17 ms each
+# (PERF.md section 6, PR 57)
+YTY_CHUNK = 1 << 13
+
+
+@jax.jit
+def whole_yty(table):
+    """``table^T table`` of a WHOLE factor table in true float32
+    (``Precision.HIGHEST``: the default is one bfloat16 pass of float32
+    operands on the chip), ``YTY_CHUNK`` rows a step: short sums, and no
+    table-sized operand split beside the table.  O(table): what a
+    live server pays where it places a table whole, never a batch
+    (:func:`_scatter_rows_yty` moves the result from then on)."""
+    n, r = table.shape
+    step = min(n, YTY_CHUNK)
+    with jax.named_scope(LIVE_FOLDIN_YTY_SCOPE), \
+            jax.default_matmul_precision("highest"):
+        G = jax.lax.fori_loop(
+            0, n // step,
+            lambda i, G: G + compute_yty(jax.lax.dynamic_slice_in_dim(
+                table, i * step, step)),
+            jnp.zeros((r, r), jnp.float32))
+        if n % step:
+            G = G + compute_yty(table[n - n % step:])
+    return G
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_rows_yty(table, yty, rows, vals):
+    """:func:`_scatter_rows` that also moves ``yty``, the table's Gram
+    matrix ``table^T table``, by the rows it writes: ``yty + new^T new -
+    old^T old`` in true float32, ``old`` the rows as they lie at ``rows``
+    BEFORE the set (``rows`` names none twice).  A row outside the table
+    (the padding) is dropped by the set and counts as zero on both
+    sides.  O(touched rows * rank^2), the table read at ``rows`` alone."""
+    with jax.named_scope(LIVE_FOLDIN_YTY_SCOPE), \
+            jax.default_matmul_precision("highest"):
+        inside = (rows < table.shape[0])[:, None]
+        old = table.at[rows].get(mode="fill", fill_value=0.0)
+        new = jnp.where(inside, vals, 0.0)
+        yty = yty + compute_yty(new) - compute_yty(old)
+    with jax.named_scope("live.foldin.scatter"):
+        return table.at[rows].set(vals, mode="drop"), yty
+
+
+def write_rows(table, rows, vals, pad=None, yty=None):
     """``table`` (on the device, as :func:`place_rows` made it) with the
     host's ``vals`` at ``rows``, written IN PLACE: the table is donated,
     so the caller's handle is deleted and the result is the same buffer.
@@ -105,11 +156,24 @@ def write_rows(table, rows, vals, pad=None):
     64, 512, ...; ``pad``: to that many, for whoever runs the programs
     ahead) with a row outside the table, which is dropped: few
     programs, O(touched rows) on the host, on the link and on the
-    device."""
+    device.
+
+    ``yty``: the table's Gram matrix on the device (:func:`whole_yty`),
+    for whoever keeps one — the implicit rule reads it at every fold.
+    The same write then also moves it by the rows written
+    (:func:`_scatter_rows_yty`) and ``(table, yty)`` comes back."""
     n, pad = len(rows), pad or pad_for(len(rows))
     vp = np.zeros((pad, table.shape[1]), dtype=np.float32)
     vp[:n] = vals
-    return _scatter_rows(table, *put((padded_rows(rows, pad, table), vp)))
+    return _scatter(table, yty, *put((padded_rows(rows, pad, table), vp)))
+
+
+def _scatter(table, yty, rows, vals):
+    """The row write's program on its arguments: the plain one, or with
+    ``yty`` (the table's Gram matrix) the one that also moves it."""
+    write, more = ((_scatter_rows, ()) if yty is None
+                   else (_scatter_rows_yty, (yty,)))
+    return write(table, *more, rows, vals)
 
 
 def padded_rows(rows, pad, table):
@@ -120,14 +184,14 @@ def padded_rows(rows, pad, table):
     return rp
 
 
-def write_placed_rows(table, rows, vals):
+def write_placed_rows(table, rows, vals, yty=None):
     """:func:`write_rows` for ``vals`` that lie on the device already —
     the padded ``[pad, rank]`` result of a fold, ``rows`` the table rows
     of its first ``len(rows)`` — the same program at the same shapes:
     only the row numbers come from the host, and they ride the call as
     its host argument (no placement)."""
-    return _scatter_rows(table, padded_rows(rows, vals.shape[0], table),
-                         vals)
+    return _scatter(table, yty, padded_rows(rows, vals.shape[0], table),
+                    vals)
 
 
 def place_rows(F, *, capacity, mesh=None, table=None):

@@ -64,6 +64,21 @@ METRICS = {
         "has grown since it last looked — never a history's known ids "
         "(stream.microbatch._Ratings keeps those as rows); the "
         "live.batch.foldin.map span's ``mapped`` beside its ``ratings``"),
+    "foldin.yty_rows": (
+        "counter", "rows",
+        "table rows that entered an update of the Gram matrix an implicit "
+        "FoldInServer keeps of a fixed table (G + new^T new - old^T old "
+        "inside the row write, core.foldin._scatter_rows_yty), by the "
+        "table's side (user: U^T U, moved by a user fold's write-back | "
+        "item: V^T V, by an item fold's): O(touched rows) a batch"),
+    "foldin.yty_full": (
+        "counter", "programs",
+        "whole-table Gram programs (core.foldin.whole_yty, O(table)) an "
+        "implicit FoldInServer ran, by the table's side (user | item) and "
+        "when: start (the table's first placement, at construction or "
+        "when the item side is first asked for; a prewarm's grown table) "
+        "| placed (the table re-placed whole after its spare rows ran "
+        "out) — never a batch that only writes rows"),
     "checkpoint.save_seconds": (
         "histogram", "seconds", "save_factors wall-clock duration"),
     "checkpoint.save_bytes": (
@@ -402,6 +417,8 @@ LABELS = {
     "serve.request_seconds": ("strategy",),
     "foldin.history_width": ("side",),
     "foldin.ids_mapped": ("side",),
+    "foldin.yty_rows": ("side",),
+    "foldin.yty_full": ("side", "when"),
     "serving.enqueue_seconds": ("tenant",),
     "serving.score_seconds": ("path", "tenant"),
     "serving.e2e_seconds": ("tenant",),
@@ -647,6 +664,13 @@ LIVE_HISTORY_SPAN_KEYS = (
     "live.batch.publish.history",
 )
 LIVE_HISTORY_SCOPE = "live.publish.history"
+# the Gram matrix an implicit fold-in server keeps of each fixed table
+# (core/foldin.py): the update by the rows a write moved, inside the row
+# write's program beside ``live.foldin.scatter``
+# (``_scatter_rows_yty``), and the whole-table pass where a table is
+# placed whole (``whole_yty``).  (``live.foldin.gram`` is the normal
+# equations' build inside the fold program.)
+LIVE_FOLDIN_YTY_SCOPE = "live.foldin.yty"
 # what every fold writes, items or none, inside ``live.batch.foldin``
 # (with ``fold_items`` inside ``.foldin.users`` / ``.foldin.items``):
 # stream/microbatch.py, which the updater drives.  ``live.batch``,
@@ -794,7 +818,11 @@ START_PHASES = (
     "start.foldin_server.place",      #   _place("_V"): the catalog up a
     #                                   THIRD time, the folds' fixed side
     "start.foldin_server.history",    #   the resident ratings' widths
-    "start.foldin_server.yty",        #   implicit: the Gram of the table
+    "start.foldin_server.yty",        #   implicit: the Gram of a fixed
+    #                                   table, whole (``side``: item at
+    #                                   construction, user inside
+    #                                   start.prewarm where the item side
+    #                                   is first asked for)
     "start.prewarm",                  # FoldInServer.prewarm (sides=)
     "start.prewarm.reserve",          #   host work before a side folds:
     #                                   the id map sorted; on the item

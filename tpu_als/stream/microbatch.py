@@ -32,6 +32,19 @@ for it): a fold's write-back also writes the rows it moved into that
 table IN PLACE (``core.foldin.write_rows``), so the other direction's
 next fold reads them, and no batch uploads a table.
 
+**Under implicit feedback** (``implicitPrefs``: Hu, Koren and Volinsky's
+rule, ``ops.solve.normal_eq_implicit``) a fold also reads ``F^T F`` of its
+WHOLE fixed table.  The server keeps that Gram matrix on the device
+beside each fixed table — ``V^T V`` from construction, ``U^T U`` once the
+item side is placed — computed whole only where the table itself is
+placed whole (``core.foldin.whole_yty``, true float32: at start, and
+after the spare rows ran out) and from then on MOVED by the rows each
+write-back writes, ``G + new^T new - old^T old`` inside the row write's
+own program (``core.foldin.write_rows(yty=)``): a user fold moves
+``U^T U``, an item fold ``V^T V``, O(touched rows) a batch and never the
+table.  ``foldin.yty_rows`` / ``foldin.yty_full`` count both; an explicit
+server keeps no Gram matrix and runs the plain row writes.
+
 **A rating whose other side has no factor yet** (a new item at its
 user's fold, a new user at its item's fold) is kept in the history, not
 dropped: a fold regresses on those of the entity's ratings whose other
@@ -91,6 +104,7 @@ session a microsecond each.
 from __future__ import annotations
 
 import collections
+import contextlib
 import time
 
 import numpy as np
@@ -105,6 +119,7 @@ from tpu_als.core.foldin import (
     place_rows,
     planes,
     solve_path,
+    whole_yty,
     write_placed_rows,
     write_rows,
 )
@@ -118,7 +133,6 @@ from tpu_als.core.ratings import (
     rung_for,
 )
 from tpu_als.obs.phases import phase
-from tpu_als.ops.solve import compute_yty
 from tpu_als.serving.engine import Stamped
 from tpu_als.utils.frame import as_frame
 
@@ -127,6 +141,10 @@ from tpu_als.utils.frame import as_frame
 # only a resident base history reaches (above LIVE_PADS): 0.5 GB of
 # rank-256 float32 rows, and the Gram build holds it more than once
 FOLD_ELEMENTS = 1 << 19
+
+# the server's table on the device -> the ``side`` its Gram matrix's
+# counters and start phase carry (the TABLE's: ``_Ud`` is the user table)
+_YTY_SIDE = {"_V": "item", "_Ud": "user"}
 
 
 class FoldInServer:
@@ -186,7 +204,10 @@ class FoldInServer:
         # rating names it (made when the item side is first asked for)
         self._rated_before = None
         self._bufs = {}     # "_U" / "_V" -> the buffer the model's is a view of
-        self._Ud = self._YtY = None
+        self._Ud = None
+        # implicit: "_V" / "_Ud" -> that table's Gram matrix on the device,
+        # moved by every write of the table (module docstring)
+        self._yty = {}
         # what of a start takes time, phase by phase
         # (``obs.schema.START_PHASES``)
         with phase("start.foldin_server"):
@@ -203,9 +224,7 @@ class FoldInServer:
             # first needs it (a user-only server never holds one)
             with phase("start.foldin_server.place"):
                 self._V = self._place("_V")
-            if self._implicit:
-                with phase("start.foldin_server.yty"):
-                    self._YtY = compute_yty(self._V).block_until_ready()
+            self._whole_yty("_V", "start")
         # (batch_size, touched_users, latency_seconds, padded width) —
         # bounded: a
         # long-lived live pipeline folds in forever, and the durable
@@ -226,12 +245,36 @@ class FoldInServer:
         """The fixed side of a fold on the device, with spare zero rows
         (``growth``: that many doublings more).  The kernel only GATHERS
         its rows (by dense ids below the live count) and, on the implicit
-        path, reads ``F^T F`` — zero rows change neither — so entities
-        appended to it later change no shape and compile nothing."""
+        path, reads ``F^T F`` — the Gram matrix the server keeps beside
+        the table (:meth:`_whole_yty` wherever this places one, moved by
+        the row writes in between); zero rows change neither — so
+        entities appended to it later change no shape and compile
+        nothing."""
         return place_rows(
             getattr(self.model, fac_attr),
             capacity=self._capacity(fac_attr) << growth,
             table="fold_fixed").block_until_ready()
+
+    def _whole_yty(self, dev_attr, when):
+        """Implicit feedback: the Gram matrix of the server's table
+        ``dev_attr`` (``"_V"`` | ``"_Ud"``) computed WHOLE, O(table) —
+        only where that table was just placed whole (``when``: ``start``
+        | ``placed``, module docstring)."""
+        if not self._implicit:
+            return
+        side = _YTY_SIDE[dev_attr]
+        with (phase("start.foldin_server.yty", side=side)
+              if when == "start" else contextlib.nullcontext()):
+            self._yty[dev_attr] = whole_yty(
+                getattr(self, dev_attr)).block_until_ready()
+        obs.counter("foldin.yty_full", side=side, when=when)
+
+    def yty(self, items_side=False):
+        """The Gram matrix ``F^T F`` a fold of this direction reads, as
+        the server keeps it on the device (``items_side``: ``U^T U``, else
+        ``V^T V``); ``None`` under explicit feedback, and for a table not
+        placed yet."""
+        return self._yty.get("_Ud" if items_side else "_V")
 
     def _reserve(self, items_side, rows=0):
         """Make the model's factor table of this side a writable view of a
@@ -306,20 +349,25 @@ class FoldInServer:
                     phase("start.prewarm.writes"):
                 for n in rows:
                     placed = jnp.zeros((n, m._U.shape[1]), jnp.float32)
-                    self._V = write_rows(self._V, [], none, pad=n)
-                    self._Ud = write_rows(self._Ud, [], none, pad=n)
-                    self._V = write_placed_rows(self._V, [], placed)
-                    self._Ud = write_placed_rows(self._Ud, [], placed)
-                jax.block_until_ready((self._V, self._Ud))
+                    for dev_attr in ("_V", "_Ud"):
+                        self._write(dev_attr, [], none, pad=n)
+                        self._write(dev_attr, [], none, placed=placed)
+                jax.block_until_ready((self._V, self._Ud, self._yty))
 
     def _prewarm_folds(self, side, fixed, rows, widths, growth):
         """:meth:`prewarm`'s ladder for one side: the fold-in program of
         every padded shape compiled (or fetched) and run against
-        ``fixed``, and against a table ``growth`` doublings larger."""
+        ``fixed``, and against a table ``growth`` doublings larger (its
+        whole-table Gram program too, which a re-placement at that size
+        would run)."""
+        YtY = self.yty(items_side=side == "item")
         for g in range(int(growth) + 1):
             F = (fixed if g == 0
                  else self._place("_V" if side == "user" else "_U", g))
-            YtY = compute_yty(F) if self._implicit else None
+            if g and self._implicit:
+                whole_yty(F).block_until_ready()
+                obs.counter("foldin.yty_full", when="start", side=_YTY_SIDE[
+                    "_V" if side == "user" else "_Ud"])
             for n in rows:
                 _, path, why = solve_path(F.shape[1], n, self._nonnegative)
                 for w in widths:
@@ -374,6 +422,7 @@ class FoldInServer:
                         self._base[1], minlength=len(self.model._V)) > 0
             with phase("start.prewarm.place", side="item"):
                 self._Ud = self._place("_U")
+            self._whole_yty("_Ud", "start")
         return self._Ud
 
     def _to_refit(self, items):
@@ -514,12 +563,7 @@ class FoldInServer:
             hists = [h for h, f in zip(hists, fold.tolist()) if f]
             lens = usable[fold]
 
-            F = self._fixed(items_side)
-            if items_side:
-                # O(table) a batch on the implicit path: ROADMAP R2
-                YtY = compute_yty(F) if self._implicit else None
-            else:
-                YtY = self._YtY
+            F, YtY = self._fixed(items_side), self.yty(items_side)
             # pad rows and width up the ladder -> the programs prewarm
             # ran; one call, or where its gather would pass FOLD_ELEMENTS
             # several
@@ -563,8 +607,6 @@ class FoldInServer:
             at = self._write_back(touched, x, items_side, placed)
             if placed is not None:
                 self.last_rows[sides] = (at, placed)
-            if items_side and self._implicit:
-                self._YtY = compute_yty(self._V)
             dt = time.perf_counter() - t0
             self.stats.append((entered, n, dt, widest))
             obs.counter("foldin.ratings", entered)
@@ -656,12 +698,27 @@ class FoldInServer:
             return dense
         if int(table.shape[0]) != len(self._bufs[fac_attr]):
             # spare rows used up: the table of the new capacity, whole
+            # (and with it, implicit, its Gram matrix)
             setattr(self, dev_attr, self._place(fac_attr))
-        elif placed is not None:
-            setattr(self, dev_attr, write_placed_rows(table, dense, placed))
+            self._whole_yty(dev_attr, "placed")
         else:
-            setattr(self, dev_attr, write_rows(table, dense, new_rows))
+            self._write(dev_attr, dense, new_rows, placed=placed)
         return dense
+
+    def _write(self, dev_attr, rows, vals, placed=None, pad=None):
+        """``vals`` at ``rows`` of the server's table ``dev_attr`` on the
+        device, in place (``placed``: from the same rows where they lie
+        there already) — and, implicit, that table's Gram matrix moved by
+        them in the same program."""
+        table, yty = getattr(self, dev_attr), self._yty.get(dev_attr)
+        out = (write_placed_rows(table, rows, placed, yty=yty)
+               if placed is not None
+               else write_rows(table, rows, vals, pad=pad, yty=yty))
+        if yty is not None:
+            out, self._yty[dev_attr] = out
+            obs.counter("foldin.yty_rows", len(rows),
+                        side=_YTY_SIDE[dev_attr])
+        setattr(self, dev_attr, out)
 
     def latency(self, q=0.5, skip_warmup=False):
         """Latency quantile over processed batches.  ``skip_warmup`` drops

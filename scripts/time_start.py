@@ -14,7 +14,9 @@ inside (``tpu_als/obs/phases.py``, ``tpu_als/obs/compiles.py``):
   handed to the device inside it, device GB in use as it last closed,
   programs that reached the backend's compile call inside it, of which
   the persistent cache answered (``hits``), and the seconds traced,
-  lowered and in that call; ``top_level_s`` / ``unsplit_pct`` as the
+  lowered and in that call, and what the phase says of its own input
+  (``side``; the two passes over the histories: ``ids``, the check also
+  ``parts``); ``top_level_s`` / ``unsplit_pct`` as the
   benchmark's ``start_program_s`` / ``start_unsplit_pct`` read them;
 - ``placed_gb``: ``device.placed_bytes`` by table; ``pins``:
   ``serving.pins`` by source;
@@ -56,6 +58,8 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIELDS = ("seconds", "cpu_seconds", "placed_bytes", "programs",
           "cache_hits", "trace_s", "lower_s", "compile_s")
+# what a phase says of its own input (known before it opens)
+LABELS = ("side", "ids", "parts")
 
 
 def phase_table(events):
@@ -74,8 +78,7 @@ def phase_table(events):
         for f in FIELDS:
             row[f] += e[f]
         row["device_bytes_in_use"] = e["device_bytes_in_use"]
-        if "side" in e:
-            row["side"] = e["side"]
+        row.update({k: e[k] for k in LABELS if k in e})
     return sorted(rows.values(), key=lambda row: row["t0"])
 
 
@@ -142,7 +145,7 @@ def main(argv):
             trace_s=round(row["trace_s"], 3),
             lower_s=round(row["lower_s"], 3),
             compile_s=round(row["compile_s"], 3),
-            **({"side": row["side"]} if "side" in row else {}))
+            **{k: row[k] for k in LABELS if k in row})
     say("start_phases", top_level_s=start_phases.seconds(start_phases.top),
         unsplit_pct=start_phases.unsplit_pct())
     say("placed_gb", **{labels["table"]: round(1e-9 * v, 4) for labels, v

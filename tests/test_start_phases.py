@@ -39,7 +39,17 @@ def start_path(event):
 def started():
     """``(span events, start.seconds by path, start.placed_bytes by path)``
     of one whole start: publish with histories, the fold-in server, both
-    sides prewarmed, ``LiveUpdater.start`` with ``fold_items``."""
+    sides prewarmed, ``LiveUpdater.start`` with ``fold_items``.
+
+    JAX's in-memory caches are emptied first (as
+    ``tests.test_serving_pins.warm_start`` does): the worker that runs
+    this file may have warmed an engine of the same kind for another file
+    (``tests/test_serving_dispatch.py``, ``test_serving_pins.py``), and a
+    lowering those caches answer reaches neither the backend's compile
+    call nor the ledger — ``start.pin`` then closes with ``programs`` 0 and
+    ``lower_s`` 0, and the milliseconds it is left with fall under the
+    tiling's absolute slack."""
+    jax.clear_caches()
     obs.reset()
     warmed("segment_grown")
     spans = [e for e in obs.default_registry()._events

@@ -788,6 +788,7 @@ START_PHASES = (
     #                                   in chunks, waited for
     "start.publish.histories",        # _place_seen
     "start.publish.histories.check",  #   the CSR validated on the host
+    #                                   (labels ids, parts)
     "start.publish.histories.place",  #   runs and ids up (on a mesh a
     #                                   shard at a time: _shard_seen)
     "start.publish.catalog",          # _place_catalog: V (and valid) up
@@ -805,7 +806,7 @@ START_PHASES = (
     "start.warmup_histories",         # _warm_histories (alone, or inside
     #                                   start.warmup_live)
     "start.warmup_histories.plan",    #   _lay_out on the host: where
-    #                                   every id moves to
+    #                                   every id moves to (label ids)
     "start.warmup_histories.place",   #   sources and targets up (8 bytes
     #                                   an id, once)
     "start.pin",                      # _pin: one pinned program loaded,
@@ -861,7 +862,9 @@ EVENTS = {
         "(device.placed_bytes' growth inside it), device_bytes_in_use "
         "(as it closes, the fullest local device) and the compile "
         "ledger's difference over it: programs (backend-compile calls), "
-        "cache_hits, cache_misses, trace_s, lower_s, compile_s"),
+        "cache_hits, cache_misses, trace_s, lower_s, compile_s; the two "
+        "passes over the histories also say what they walked: ids, and "
+        "the check its parts"),
     "jax_program": (
         ("fun_name", "trace_s", "lower_s", "compile_s", "cache", "phase"),
         "one per backend-compile call of this process (obs.compiles): "

@@ -298,6 +298,12 @@ PUBLISH_SENT = SENT_SEGMENT + SEGMENT_SENT + SENT_PLAN
 MAX_EXCLUDE = 64
 
 
+# ids a part of the histories' check holds (``_checked_seen``): 8 MB of
+# int32, so that a part and the comparisons made of it stay in a core's
+# cache between the passes over it
+CHECK_PART = 1 << 21
+
+
 class _Seen:
     """The users' histories of one generation: catalog ids on the device,
     each user's a contiguous run of ``indices``, and on the host what
@@ -416,6 +422,22 @@ def history_pads(longest, grows=False):
     longest = max(int(longest), MAX_EXCLUDE)
     return tuple(p for p in (growth_pads if grows else pads_up_to)(longest)
                  if p >= MAX_EXCLUDE)
+
+
+def _part_rises(indptr, indices, n_items, lo, hi):
+    """Whether ids ``[lo, hi)`` of a CSR (``indptr`` non-decreasing) are
+    catalog ids and each exceeds the id before it in ``indices`` — ``lo -
+    1``'s too: one id of overlap carries the comparison across parts —
+    unless it is a row's first.  Compared in the ids' own integer type;
+    the part's row starts are a slice of ``indptr``."""
+    first = max(lo - 1, 0)
+    part = indices[first:hi]
+    if int(part.min()) < 0 or int(part.max()) >= n_items:
+        return False
+    rises = part[1:] > part[:-1]
+    a, b = np.searchsorted(indptr, (first + 1, hi))
+    rises[indptr[a:b] - (first + 1)] = True
+    return bool(rises.all())
 
 
 def cpu_mark():
@@ -1200,7 +1222,9 @@ class ServingEngine:
         catalog ids, one row a user, a row's ids ascending and none
         twice.  On a mesh (``rows``: the placed user table's) sharded
         with that table (:meth:`_shard_seen`)."""
-        with phase("start.publish.histories.check"):
+        ids = len(user_seen[1])
+        with phase("start.publish.histories.check", ids=ids,
+                   parts=-(-ids // CHECK_PART)):
             indptr, indices, lengths = self._checked_seen(
                 user_seen, n_users, n_items)
         pads = history_pads(lengths.max(initial=0))
@@ -1222,7 +1246,17 @@ class ServingEngine:
     def _checked_seen(user_seen, n_users, n_items):
         """``(indptr, indices, lengths)`` of a publish's histories, or
         ``ValueError``: CSR over catalog ids, one row a user, a row's ids
-        ascending and none twice."""
+        ascending and none twice.
+
+        The ids are walked ONCE, in parts of ``CHECK_PART`` ids
+        (:func:`_part_rises`) and as they are: no ``int64`` copy of them,
+        no ``diff``, no array an id wide beyond a part's own comparisons
+        (ids of no integer type — an empty list's ``float64`` — are cast
+        once, and refused where the cast moved one).  The first fault
+        ends the walk.  On ONE thread: on the chip's host a pool of eight
+        took the mesh cell's 143 M ids in 0.18 s where one thread takes
+        0.24 (the form before: 3.5; PERF.md section 6, PR 58) — nothing a
+        start would notice, so there is none."""
         indptr, indices = (np.asarray(a) for a in user_seen)
         if indptr.shape != (n_users + 1,) or indptr[0] != 0 \
                 or indptr[-1] != len(indices) \
@@ -1233,14 +1267,13 @@ class ServingEngine:
                 f"users and {len(indices)} ids")
         lengths = np.diff(indptr)
         ok = bool((lengths >= 0).all())
-        if ok and len(indices):
-            # a row's ids rise; its first need not exceed the last of the
-            # row before
-            rises = np.diff(indices.astype(np.int64)) > 0
-            starts = indptr[1:-1]
-            rises[starts[(starts > 0) & (starts < len(indices))] - 1] = True
-            ok = bool(indices.min() >= 0 and indices.max() < n_items
-                      and rises.all())
+        if ok and indices.dtype.kind not in "iu":
+            given, indices = indices, indices.astype(np.int64)
+            ok = bool((indices == given).all())
+        if ok:
+            ok = all(_part_rises(indptr, indices, n_items, lo,
+                                 min(lo + CHECK_PART, len(indices)))
+                     for lo in range(0, len(indices), CHECK_PART))
         if not ok:
             raise ValueError(
                 "user_seen: every row holds catalog ids in "
@@ -1301,8 +1334,14 @@ class ServingEngine:
         the host sends where from and where to, 8 bytes an id, once);
         O(all the histories), so it is ``warmup_live``'s to call before
         the traffic — under it only where the room laid out here is used
-        up, with a warning."""
-        with phase("start.warmup_histories.plan"):
+        up, with a warning.
+
+        The plan is made BY RUN: what the host builds an id wide is the
+        two ``int32`` arrays it sends and one ``arange`` they share — no
+        id's user, no place within its run, no gather (five ``int64``
+        arrays an id wide until PR 58)."""
+        held_ids = int(seen.lengths.sum())
+        with phase("start.warmup_histories.plan", ids=held_ids):
             n = len(seen.lengths)
             lengths = np.zeros(rows, np.int32)
             lengths[:n] = seen.lengths
@@ -1316,14 +1355,21 @@ class ServingEngine:
             if size + pads[-1] >= NOT_AN_ID:
                 raise ValueError(f"{held} ids of history with room to "
                                  "grow: more than int32 positions hold")
-            old = (np.asarray(seen.runs)[:-1] if seen.room is None
-                   else seen.room.start[:n])
-            user = np.repeat(np.arange(n), seen.lengths)
-            within = (np.arange(len(user))
-                      - np.repeat(np.cumsum(seen.lengths) - seen.lengths,
-                                  seen.lengths))
-            src, dst = ((a[user] + within).astype(np.int32)
-                        for a in (old, start))
+            # an id's place is its place among the ids packed run after
+            # run plus what its RUN lies off its packed start: one
+            # ``repeat`` of a per-run offset a side (none where the table
+            # is as published: its runs lie packed), nothing else an id
+            # wide, int32 throughout (every place lies below ``size``)
+            packed = np.cumsum(seen.lengths) - seen.lengths
+            at = np.arange(held_ids, dtype=np.int32)
+
+            def places(run_starts):
+                off = np.repeat((run_starts - packed).astype(np.int32),
+                                seen.lengths)
+                return np.add(at, off, out=off)
+
+            src = at if seen.room is None else places(seen.room.start[:n])
+            dst = places(start[:n])
             runs = (start.astype(np.int32), lengths.copy())
         with phase("start.warmup_histories.place"):
             count_placed("histories",

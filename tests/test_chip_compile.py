@@ -256,6 +256,30 @@ def test_the_catalog_writes_are_in_place_at_the_live_cells_size(one_chip):
                     and any(t in ln for t in tables)], fn
 
 
+def test_a_landings_programs_at_the_live_cells_size(one_chip):
+    """What a refit's landing runs that no start does (``ServingEngine.
+    warmup_landing``): a table copied on the device — a real second buffer,
+    nothing aliased — and the whole catalog TABLE quantized, spare rows and
+    block padding in one program that holds no copy of the table beside
+    its result (a landing stands two engine generations side by side:
+    13.4 of 16 GB)."""
+    from tpu_als.core.ratings import row_capacity
+    from tpu_als.serving.engine import _copy_table
+    from tpu_als.serving.index import _quantize_rows
+
+    cap, cols, (Vq, sv, V, valid), _ = _live_catalog_shapes()
+    for table in (V, ((row_capacity(LIVE_USERS), LIVE_RANK), jnp.float32)):
+        c = _compiled(one_chip, _copy_table, table)
+        mem = c.memory_analysis()
+        assert "input_output_alias" not in c.as_text()
+        assert mem.output_size_in_bytes == mem.argument_size_in_bytes
+        assert mem.temp_size_in_bytes == 0
+    mem = _compiled(one_chip, _quantize_rows, V,
+                    pad=cols - cap).memory_analysis()
+    assert 0 <= mem.output_size_in_bytes - cols * (LIVE_RANK + 4) < 4096
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
 # -- stage two of the shortlist: the layout its TopK is handed (PR 37) -------
 
 MESH_ITEMS_PER_SHARD, MESH_USERS_PER_SHARD = 3_012_096, 3_460_224

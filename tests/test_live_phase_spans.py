@@ -240,7 +240,10 @@ def test_the_names_stand_once_in_the_schema_and_once_in_the_code():
         for path in glob.glob(os.path.join(ROOT, "tpu_als", sub, "*.py")):
             with open(path, encoding="utf-8") as f:
                 opened += re.findall(r'\bStamped\(\s*"([^"]+)"', f.read())
-    assert sorted(opened) == sorted(PHASES)
+    # (a landing's steps are stamped too: their own tuple, ISSUE 59)
+    landing = [name for name in opened if name.startswith("live.landing")]
+    assert set(landing) <= set(schema.LIVE_LANDING_SPAN_KEYS)
+    assert sorted(n for n in opened if n not in landing) == sorted(PHASES)
     # and the static check of the vocabulary knows the tuple
     from tpu_als.analysis import vocab
 

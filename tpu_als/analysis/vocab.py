@@ -117,6 +117,7 @@ PROFILER_SPAN_TUPLES = (
     ("LIVE_HISTORY_SPAN_KEYS", ("serving",)),
     ("LIVE_FOLDIN_SPAN_KEYS", ("stream",)),
     ("LIVE_PHASE_SPAN_KEYS", ("live", "stream", "serving")),
+    ("LIVE_LANDING_SPAN_KEYS", ("live", "stream", "serving")),
 )
 
 # start-phase literals: ``phase("start....")`` (obs/phases.py) opens one
@@ -361,7 +362,7 @@ def check_tenant_vocabulary(repo=REPO):
     for attr in ("SERVE_SPAN_KEYS", "SERVE_BATCH_SPAN_KEYS",
                  "LIVE_SPAN_KEYS", "LIVE_BATCH_SPAN_KEYS",
                  "LIVE_ITEM_SPAN_KEYS", "LIVE_HISTORY_SPAN_KEYS",
-                 "LIVE_PHASE_SPAN_KEYS"):
+                 "LIVE_PHASE_SPAN_KEYS", "LIVE_LANDING_SPAN_KEYS"):
         overlap = sorted(set(getattr(schema, attr, ())) & reserved)
         if overlap:
             errors.append(

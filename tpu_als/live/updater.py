@@ -72,6 +72,30 @@ engine's call, the fold's phases in stream/microbatch.py and the
 publish's in serving/engine.py.  A trace reader keys on those names
 (benchmark/live_phase_spans.py).
 
+**A refit lands** (:meth:`LiveUpdater.land`).  A deployment refits —
+``ALS.fit`` on a schedule — and hands the new model to the RUNNING
+updater: ``snapshot = updater.mark()`` when the refit's data is cut (an
+admission count: the point in the event stream the data ends at), the
+fit, ``updater.land(model, snapshot)`` when it is done.  The landing
+runs on the caller's thread; the loop stops BETWEEN two batches for it
+(events are admitted meanwhile and wait in the queue), the fold-in
+server takes the refit as its base and folds the catch-up — every
+entity with an event admitted after the snapshot, users first, then
+items, over all its kept ratings (``FoldInServer.land``) — and the
+engine installs refit rows, catch-up rows and a fresh index as ONE
+generation, at the live generation's capacities, and releases the one
+before (``ServingEngine.publish(placed=, release=True)``).  On the
+profiler's timeline ``live.landing`` (stats ``seq``, ``users``,
+``items``, ``catchup_events``, ``catchup_users``, ``catchup_items``)
+(opened once the loop stands: a ``live.`` span never overlaps a
+``live.batch``) around ``.pause`` (stat ``waited_us``: the wait for the
+loop), the server's ``.tables`` / ``.place`` / ``.catchup``
+(``.catchup.call`` a fold), the engine's ``.users`` / ``.catalog`` /
+``.index`` / ``.lock_wait`` / ``.swap`` / ``.release`` and ``.record``
+(``obs.schema.LIVE_LANDING_SPAN_KEYS``); the same seconds, the bytes
+and the programs compiled (0 after ``start`` with ``refits=True``) in
+``landings``, one record a landing.
+
 Freshness (``live.freshness_seconds``) is per EVENT, arrival →
 publish-visible, so the histogram's p99 is exactly the SLO quantity:
 how stale can a rating be before it influences recommendations.  A
@@ -93,8 +117,13 @@ from jax.profiler import TraceAnnotation
 from tpu_als import obs
 from tpu_als.core.foldin import placements
 from tpu_als.core.ratings import invalid_rating_mask
-from tpu_als.obs import tracing
-from tpu_als.obs.phases import phase
+from tpu_als.obs import compiles, tracing
+from tpu_als.obs.phases import (
+    device_bytes_in_use,
+    device_peak_bytes,
+    phase,
+    placed_bytes,
+)
 from tpu_als.obs.trace import FlightRecorder
 from tpu_als.resilience import faults
 from tpu_als.serving.batcher import Overloaded
@@ -121,11 +150,18 @@ class LiveUpdater:
     metric this loop writes and tags its events/flight records, so a
     freshness breach in a multi-tenant process names its tenant from
     the obs trail alone (docs/tenancy.md).
+
+    ``refits``: refits will LAND on this updater (:meth:`land`):
+    ``start`` then also runs the programs a landing runs
+    (``ServingEngine.warmup_landing``), so that none compiles under
+    traffic.  A landing on an updater started without it works and
+    compiles there, once (the compile ledger warns).
     """
 
     def __init__(self, engine, foldin, *, max_queue=4096,
                  max_batch=None, max_wait_ms=None, slo_s=None,
-                 fold_items=False, flight_capacity=64, tenant=None):
+                 fold_items=False, flight_capacity=64, tenant=None,
+                 refits=False):
         from tpu_als import plan as _plan
 
         cad = _plan.resolve_live_cadence()
@@ -142,6 +178,7 @@ class LiveUpdater:
                                 else cad["max_wait_ms"]) / 1e3
         self.slo_s = float(slo_s) if slo_s is not None else None
         self.fold_items = bool(fold_items)
+        self.refits = bool(refits)
         self.flight = FlightRecorder(flight_capacity,
                                      span_keys=LIVE_SPAN_KEYS,
                                      labels=self._labels)
@@ -153,6 +190,20 @@ class LiveUpdater:
         self._cond = threading.Condition()
         self._closed = False
         self._thread = None
+        # events admitted (the next one's place in the stream) and events
+        # the loop has popped: a batch's events are [_taken, _taken + n)
+        self._admitted = 0
+        self._taken = 0
+        # the snapshots handed out and not landed yet (:meth:`mark`), and
+        # while there is one, a folded batch's (places in the stream,
+        # users, items)
+        self._marks = []
+        self._log = []
+        # None | "wanted" (a landing waits for the loop to stand between
+        # two batches) | "granted" (it stands) | "refused" (it ended)
+        self._lander = None
+        # one record a landing (:meth:`land`), oldest first
+        self.landings = []
 
     # -- producer side ------------------------------------------------
     def submit(self, user, item, rating):
@@ -176,6 +227,7 @@ class LiveUpdater:
             ctx = tracing.start_trace("live.admit", tenant=self.tenant)
             self._queue.append((user, item, float(rating), t_arrival,
                                 ctx))
+            self._admitted += 1
             self._cond.notify()
 
     @property
@@ -202,6 +254,8 @@ class LiveUpdater:
                 self.engine.warmup_live(max_rows=self.max_batch)
             elif self._histories:
                 self.engine.warmup_histories(max_rows=self.max_batch)
+            if self.refits:
+                self.engine.warmup_landing()
         self._thread = threading.Thread(
             target=self._run, name="tpu-als-live", daemon=True)
         self._thread.start()
@@ -247,6 +301,160 @@ class LiveUpdater:
     def __exit__(self, *exc):
         self.stop()
 
+    # -- a refit lands ------------------------------------------------
+    def mark(self):
+        """The point in the event stream a refit's data ends at: how
+        many events have been admitted so far.  Take it when the refit's
+        data is cut — every event admitted before it is the refit's to
+        know, every one after it is folded onto the refit again when it
+        lands — and hand it to :meth:`land` with the fitted model.  From
+        now until that landing the loop keeps who each batch touched
+        (O(events), no factors)."""
+        with self._cond:
+            self._marks.append(self._admitted)
+            return self._admitted
+
+    def land(self, refit, snapshot):
+        """A refit LANDS on the running updater: ``refit`` (an
+        ``ALSModel``: a whole new fit's ``(U', V')`` with their ids)
+        becomes the model that serves and that the folds regress on,
+        with the events admitted since ``snapshot`` (:meth:`mark`)
+        folded onto it again — ONE generation, swapped in while the
+        engine answers.
+
+        On the caller's thread, never the engine's.  The loop stops
+        between two batches (events are admitted meanwhile; none is
+        shed unless ``max_queue`` of them arrive during the landing) and
+        goes on afterwards, folding against the landed tables and
+        nothing else:
+
+        1. the fold-in server takes the refit's rows into both host
+           tables, releases its two tables on the device and places them
+           anew, and folds the catch-up — every entity a batch touched
+           at or after ``snapshot``, and every entity new since (the
+           refit has no row for it), users first, then items, each over
+           ALL its kept ratings (``FoldInServer.land``).  An entity with
+           no event after the snapshot keeps the refit's row, bit for
+           bit;
+        2. the engine builds the generation beside the live one — user
+           table and catalog as copies, made on the device, of the
+           server's tables as the catch-up left them, a fresh index with
+           an empty segment — at the live capacities, installs it by one
+           swap and deletes the generation before
+           (``ServingEngine.publish(placed=, release=True)``).
+
+        At 1.7 M users and 1.5 M items of rank 256 the device holds
+        8.1 GB between landings and 13.4 GB at the swap.  Not yet: an
+        engine that holds its users' histories, implicit feedback, a
+        mesh (``NotImplementedError``; ROADMAP R11, R12).  Returns the
+        landing's record (also appended to ``landings``): ``seq``, the
+        sizes, ``seconds`` by step, ``placed_bytes`` / ``copied_bytes``,
+        ``peak_bytes``, ``programs`` (compiled meanwhile: 0 after a
+        ``start`` with ``refits``) and ``catchup`` — ``{"users": (ids,
+        rows), "items": (ids, rows)}``, the rows the catch-up folded."""
+        if self._histories or getattr(self.engine, "mesh", None) is not None:
+            raise NotImplementedError(
+                "a landing on an engine that holds its users' histories "
+                "(the grown layout laid out anew) or on a mesh: ROADMAP "
+                "R11, R12")
+        # the wait for the loop lies OUTSIDE the span: the batch the loop
+        # is finishing is still on the timeline, and a ``live.`` span that
+        # overlapped it would read as its child; ``.pause`` carries the
+        # wait as a stat
+        t0 = time.perf_counter()
+        self._pause()
+        try:
+            with TraceAnnotation("live.landing") as whole:
+                with Stamped("live.landing.pause", waited_us=int(
+                        1e6 * (time.perf_counter() - t0))):
+                    pass
+                record = self._land(refit, snapshot, whole, t0)
+        finally:
+            with self._cond:
+                self._lander = None
+                self._cond.notify_all()
+        return record
+
+    def _pause(self):
+        """Return once the loop stands between two batches, held there
+        until ``_lander`` is cleared."""
+        with self._cond:
+            if self._thread is None or self._closed:
+                raise RuntimeError("LiveUpdater is not running")
+            if self._lander is not None:
+                raise RuntimeError("a landing is under way")
+            self._lander = "wanted"
+            self._cond.notify_all()
+            while self._lander == "wanted":
+                self._cond.wait()
+            if self._lander != "granted":
+                self._lander = None
+                raise RuntimeError("LiveUpdater stopped before the landing")
+
+    def _land(self, refit, snapshot, whole, t0):
+        """:meth:`land` with the loop held."""
+        if snapshot not in self._marks:
+            raise ValueError(f"{snapshot} is no snapshot this updater "
+                             "handed out (LiveUpdater.mark) and has not "
+                             "landed yet")
+        t_paused = time.perf_counter()
+        ledger, m = compiles.install(), self.foldin.model
+        compiled, sent0 = ledger.now(), placed_bytes()
+        # who the batches folded at or after the snapshot: (users, items)
+        since = [(users[at >= snapshot], items[at >= snapshot])
+                 for at, users, items in self._log]
+        users, items = (np.unique(np.concatenate(
+            [batch[side] for batch in since] or [np.empty(0, np.int64)]))
+            for side in (0, 1))
+        events = sum(len(batch[0]) for batch in since)
+        t = time.perf_counter()
+        did = self.foldin.land(refit, users,
+                               items if self.fold_items else ())
+        took = {"pause": t_paused - t0, "server": time.perf_counter() - t}
+        took.update(did.pop("seconds"))
+        seq = self.engine.publish(
+            m._U, m._V, placed=self.foldin.device_tables(), release=True)
+        made = self.engine.last_landing or {}
+        took.update(made.get("seconds", {}))
+        with Stamped("live.landing.record"):
+            self._marks.remove(snapshot)
+            oldest = min(self._marks, default=None)
+            self._log = [] if oldest is None else [
+                e for e in self._log if e[0][-1] >= oldest]
+            caught = {side: did.pop(side) for side in ("users", "items")}
+            took["whole"] = time.perf_counter() - t0
+            record = {
+                "seq": seq, "snapshot": snapshot,
+                "users": len(m._user_map), "items": len(m._item_map),
+                "catchup_events": events,
+                "catchup_users": len(caught["users"][0]),
+                "catchup_items": len(caught["items"][0]),
+                "rounds": did["rounds"], "calls": did["calls"],
+                "programs": ledger.since(compiled)["programs"],
+                "placed_bytes": placed_bytes() - sent0,
+                "copied_bytes": made.get("copied_bytes", 0),
+                "bytes_in_use": device_bytes_in_use(),
+                "peak_bytes": device_peak_bytes(),
+                "seconds": took, "t_start": t0,
+                "t_done": time.perf_counter(), "catchup": caught}
+            whole.set_metadata(**{k: record[k] for k in (
+                "seq", "users", "items", "catchup_events", "catchup_users",
+                "catchup_items")})
+            obs.counter("live.landings", **self._labels)
+            obs.counter("live.landing.catchup_events", events,
+                        **self._labels)
+            obs.counter("live.landing.bytes_placed", record["placed_bytes"],
+                        **self._labels)
+            obs.gauge("live.landing.peak_bytes", record["peak_bytes"],
+                      **self._labels)
+            obs.emit("live_landing", **{k: record[k] for k in (
+                "seq", "snapshot", "users", "items", "catchup_events",
+                "catchup_users", "catchup_items", "programs",
+                "placed_bytes", "peak_bytes")},
+                seconds=round(took["whole"], 6), **self._labels)
+            self.landings.append(record)
+        return record
+
     # -- update loop --------------------------------------------------
     def _next_batch(self):
         """Block for the first event, then accumulate until ``max_batch``
@@ -265,7 +473,7 @@ class LiveUpdater:
             with TraceAnnotation("live.batch.coalesce",
                                  waiting=len(self._queue)):
                 while (len(self._queue) < self.max_batch
-                       and not self._closed):
+                       and not self._closed and self._lander is None):
                     left = self.max_wait_s - (time.perf_counter()
                                               - t_oldest)
                     if left <= 0:
@@ -279,34 +487,47 @@ class LiveUpdater:
 
     def _run(self):
         while True:
+            with self._cond:
+                if self._lander == "wanted":
+                    # between two batches: the landing's, until it is done
+                    self._lander = "granted"
+                    self._cond.notify_all()
+                    while self._lander == "granted":
+                        self._cond.wait()
             batch = self._next_batch()
             if batch is None:
                 with self._cond:
                     if self._closed and not self._queue:
+                        if self._lander == "wanted":
+                            self._lander = "refused"
+                            self._cond.notify_all()
                         return
                 continue
             self._batch_seq += 1
+            first, self._taken = self._taken, self._taken + len(batch)
             try:
                 with TraceAnnotation("live.batch",
                                      seq=self._batch_seq) as whole:
                     mark = cpu_mark()
-                    self._process(batch, whole)
+                    self._process(batch, whole, first)
                     stamp_cpu(whole, mark)
             except BaseException as e:  # noqa: BLE001 — loop must survive
                 if not isinstance(e, faults.InjectedFault):
                     obs.emit("warning", what="live.update",
                              reason=f"{type(e).__name__}: {e}")
 
-    def _process(self, batch, whole):
+    def _process(self, batch, whole, first=0):
         """Fold one popped batch in and publish it; ``whole`` is the
         ``live.batch`` span around the call, which takes the batch's
-        sizes as its stats."""
+        sizes as its stats; ``first``: the place of its first event in
+        the stream of admitted events."""
         t0, placed_before = time.perf_counter(), placements()
         with Stamped("live.batch.prepare"):
             users, items, ratings, arrivals, ctxs = map(list, zip(*batch))
             users, items = np.asarray(users), np.asarray(items)
             ratings = np.asarray(ratings, dtype=np.float32)
             arrivals = np.asarray(arrivals)
+            places = first + np.arange(len(batch))
             # chain the queue hop per event (its own wait, not the batch's)
             ctxs = [tracing.record_span(c, "live.queue", seconds=t0 - a)
                     if c is not None else None
@@ -333,6 +554,7 @@ class LiveUpdater:
                                             status="quarantined")
                 users, items = users[keep], items[keep]
                 ratings, arrivals = ratings[keep], arrivals[keep]
+                places = places[keep]
                 ctxs = [c for c, k in zip(ctxs, keep) if k]
             quarantine_s = time.perf_counter() - t0
             if len(ratings) == 0:
@@ -340,6 +562,10 @@ class LiveUpdater:
                     "quarantined",
                     {"queue_wait": queue_wait, "quarantine": quarantine_s})
                 return
+            if self._marks:
+                # a refit is being made: who was touched after its
+                # snapshot is what its landing folds again
+                self._log.append((places, users, items))
 
         p = self.foldin.model._params
         frame = {p["userCol"]: users, p["itemCol"]: items,

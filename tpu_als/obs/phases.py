@@ -67,6 +67,17 @@ def device_bytes_in_use():
                 for d in jax.local_devices()), default=0)
 
 
+def device_peak_bytes():
+    """``peak_bytes_in_use`` of the fullest local device since the
+    process began (what a landing reads as it ends); 0 where
+    :func:`device_bytes_in_use` is."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return 0
+    return max((int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                for d in jax.local_devices()), default=0)
+
+
 @contextlib.contextmanager
 def phase(name, **labels):
     """One phase of a start (module docstring); ``name`` from

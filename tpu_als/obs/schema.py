@@ -318,6 +318,28 @@ METRICS = {
         "scalars a relocated run — O(ids appended), never a history; the "
         "8 bytes an id of a table laid out anew are not in it (a warning "
         "says when that happens under traffic)"),
+    "live.landings": (
+        "counter", "landings",
+        "whole model generations (a refit's factors with the catch-up "
+        "folded onto them) a running live updater installed "
+        "(LiveUpdater.land)"),
+    "live.landing.catchup_events": (
+        "counter", "events",
+        "rating events admitted after a landed refit's snapshot and "
+        "folded by then: their users and items are what the landing's "
+        "catch-up folded again, over all their kept ratings, against "
+        "the refit's tables"),
+    "live.landing.bytes_placed": (
+        "counter", "bytes",
+        "bytes of whole tables a landing handed host -> device (the "
+        "growth of device.placed_bytes across it: the fold-in server's "
+        "two tables; the engine's generation is copied from them on the "
+        "device)"),
+    "live.landing.peak_bytes": (
+        "gauge", "bytes",
+        "peak_bytes_in_use of the fullest local device, read as a "
+        "landing ends: the most the device has held since the process "
+        "began (a landing holds two engine generations until its swap)"),
     "live.history_relocations": (
         "counter", "runs",
         "histories whose run on the device was full when a publish "
@@ -452,6 +474,10 @@ LABELS = {
     "live.history_appended_ids": ("tenant",),
     "live.history_h2d_bytes": ("tenant",),
     "live.history_relocations": ("tenant",),
+    "live.landings": ("tenant",),
+    "live.landing.catchup_events": ("tenant",),
+    "live.landing.bytes_placed": ("tenant",),
+    "live.landing.peak_bytes": ("tenant",),
     "serving.catalog_writes": ("how", "tenant"),
     "tenancy.served_rows": ("tenant",),
     "tenancy.batch_errors": ("tenant",),
@@ -775,6 +801,46 @@ LIVE_PHASE_SPAN_KEYS = (
     #                                  samples, the flight record
 )
 
+# a refit LANDS on a running updater (live/updater.py ``LiveUpdater.land``,
+# on its caller's thread): one ``live.landing`` (stats ``seq``, ``users``,
+# ``items``, ``catchup_events``, ``catchup_users``, ``catchup_items``)
+# around its steps, each a ``Stamped`` span with ``cpu_us`` / ``wall_us``
+# under a profiler.  In the device program that copies a table
+# (serving/engine.py ``_copy_table``) the scope ``live.landing.copy``.
+# benchmark/layer_metrics/live_landing_*.py read the same seconds from the
+# updater's ``landings`` records; serve_life_beside_landing_ms joins the
+# serving batches' lives with the ``live.landing`` spans
+LIVE_LANDING_SPAN_KEYS = (
+    "live.landing",               # updater: a landing, from the moment the
+    #                               loop stands between two batches (no
+    #                               ``live.`` span overlaps a live.batch)
+    "live.landing.pause",         # updater: the wait for that moment, as
+    #                               the stat ``waited_us`` of an empty span
+    "live.landing.tables",        # microbatch: the refit's rows into the
+    #                               host's two tables, who is folded again
+    "live.landing.place",         # microbatch: one of the server's tables
+    #                               released and placed anew (side, bytes)
+    "live.landing.catchup",       # microbatch: the catch-up's folds and
+    #                               row writes (users, items, rounds,
+    #                               calls)
+    "live.landing.catchup.call",  # microbatch: one call of the fold-in
+    #                               program and its rows read back (side,
+    #                               rows, width)
+    "live.landing.users",         # engine: the generation's user table
+    #                               (a copy on the device, or placed)
+    "live.landing.catalog",       # engine: its catalog, likewise
+    "live.landing.index",         # engine: the index's own catalog and
+    #                               the whole table quantized, an empty
+    #                               segment
+    "live.landing.lock_wait",     # engine: _swap until _table_lock is
+    #                               held
+    "live.landing.swap",          # engine: the generation installed under
+    #                               the lock: how long a batch's stage can
+    #                               be kept out
+    "live.landing.release",       # engine: the generation before deleted
+    "live.landing.record",        # updater: the record, counters, event
+)
+
 # the phases of a serving start (obs/phases.py::phase: one ``span`` event
 # each with seconds, CPU seconds, bytes placed, device bytes in use and
 # the compile ledger's difference; a TraceAnnotation, never a named
@@ -800,6 +866,10 @@ START_PHASES = (
     "start.warmup",                   # ServingEngine.warmup
     "start.warmup_publish",           # .warmup_publish: the user-row
     #                                   writes run (start.first_run)
+    "start.warmup_landing",           # .warmup_landing: the programs a
+    #                                   landing runs (a table copied on
+    #                                   the device, the whole catalog
+    #                                   table quantized), run once
     "start.warmup_live",              # .warmup_live
     "start.warmup_live.reserve",      #   spare rows for the catalog's
     #                                   arrays, the segment made
@@ -1112,6 +1182,18 @@ EVENTS = {
         "publish seq, rating events folded, catalog rows touched, and "
         "the publish mode (retag|delta|compact|full|none) "
         "(tpu_als.live.updater)"),
+    "live_landing": (
+        ("seq", "snapshot", "users", "items", "catchup_events",
+         "catchup_users", "catchup_items", "programs", "placed_bytes",
+         "peak_bytes", "seconds"),
+        "one per refit landed on a running live updater "
+        "(LiveUpdater.land): the publish seq of the landed generation, "
+        "the snapshot (admission count) the refit's data ended at, the "
+        "tables' live rows, the events admitted since the snapshot and "
+        "the users and items the catch-up folded again, the programs "
+        "compiled meanwhile (0 after a start with refits=True), the "
+        "bytes of whole tables handed to the device, the device's peak "
+        "bytes so far and the landing's wall seconds"),
     "live_freshness_breach": (
         ("seq", "freshness_seconds", "slo_s"),
         "a live update's arrival->servable freshness exceeded the SLO; "

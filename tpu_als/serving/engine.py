@@ -68,7 +68,13 @@ stack plugs into:
   state of periodic retraining — reuses every compiled program, and the
   dropped reference releases the old device buffers (the donation
   pattern: the engine owns its buffers, callers hand factors over and
-  must not mutate them afterwards).
+  must not mutate them afterwards).  On a STARTED engine that is a
+  refit LANDING (``LiveUpdater.land``): the new generation is built
+  beside the live one at the live capacities — spare rows, the
+  segment's slots: same shapes, same pins — optionally from tables the
+  caller already holds on the device (``placed=``), and ``release=``
+  deletes the generation it replaced at once (:meth:`ServingEngine.
+  publish`, :meth:`ServingEngine.warmup_landing`).
 - **Stale-index fallback.**  Each publish carries a sequence number;
   an index whose ``seq`` doesn't match the live model (a publish with
   ``quantize=False`` after a quantized one, or a ``serving.publish``
@@ -210,6 +216,7 @@ stack plugs into:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import queue
@@ -235,7 +242,7 @@ from tpu_als.core.ratings import (
     rung_for,
 )
 from tpu_als.obs import compiles, tracing
-from tpu_als.obs.phases import count_placed, phase
+from tpu_als.obs.phases import count_placed, phase, placed_bytes
 from tpu_als.obs.schema import (
     LIVE_HISTORY_SCOPE,
     SERVE_BATCH_SPAN_KEYS,
@@ -727,6 +734,16 @@ def _scatter_users(U, rows, vals):
         return U.at[rows.reshape(-1)[:vals.shape[0]]].set(vals, mode="drop")
 
 
+@jax.jit
+def _copy_table(table):
+    """A second buffer with ``table``'s values, made on the device: how a
+    landing's generation takes a table that already lies there (the
+    fold-in server's, which goes on being written) without a second trip
+    from the host.  Nothing is donated."""
+    with jax.named_scope("live.landing.copy"):
+        return jnp.array(table, copy=True)
+
+
 def _ride_shapes(max_rows, mesh=None):
     """``(pad of the publish's one array, pad of a side's rows on the
     device)`` for every shape a write program meets in publishes of up to
@@ -889,6 +906,42 @@ def _build_mesh_scatter(mesh):
                    donate_argnums=(0,))
 
 
+@contextlib.contextmanager
+def _lap(took, key):
+    """The block's wall seconds added to ``took[key]``."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        took[key] = took.get(key, 0.0) + time.perf_counter() - t0
+
+
+def _arrays_of(m):
+    """Every device array generation ``m`` (:class:`_Published`) holds."""
+    idx, seen = m.index, m.seen
+    return [a for a in (
+        m.U, m.V, m.valid,
+        *((idx.V, idx.Vq, idx.sv, idx.valid, *(idx._seg or ()))
+          if idx is not None else ()),
+        *((*(seen.runs if isinstance(seen.runs, tuple) else (seen.runs,)),
+           seen.indices) if seen is not None else ()))
+        if isinstance(a, jax.Array)]
+
+
+def _release(old, new):
+    """Delete every array of generation ``old`` that ``new`` does not
+    share, now and not when the last reference goes: what a landing
+    leaves of the generation before it is nothing.  The device runs
+    programs in dispatch order and keeps a deleted buffer until those
+    dispatched against it have run, so a batch in flight reads it
+    whole; no later batch can name it (the swap was made under
+    ``ServingEngine._table_lock``)."""
+    kept = {id(a) for a in _arrays_of(new)}
+    for a in _arrays_of(old):
+        if id(a) not in kept and not a.is_deleted():
+            a.delete()
+
+
 class ServingEngine:
     """Request-path serving over published ALS factors.
 
@@ -985,19 +1038,35 @@ class ServingEngine:
         # for the programs that exclude
         self._pinned = {}
         self._no_history = None         # _without_history's memo
+        # of the last publish on a STARTED engine (a landing): its seq,
+        # sizes, seconds by step and bytes placed / copied on the device
+        self.last_landing = None
         compiles.install()
         self._last_id = None            # _last_item's: (n_items, handle)
         self._plans = {}                # _mesh_plan's memo
 
-    def _place_catalog(self, Vh, validh):
-        """The host's catalog on the device: whole, or sharded by rows
-        over the mesh (``serving.index.place_catalog``: a chunk at a
-        time into each shard, never whole on one device)."""
-        if self.mesh is None:
-            count_placed("catalog", Vh.nbytes + validh.nbytes)
-            return jnp.asarray(Vh), jnp.asarray(validh)
-        return place_catalog(Vh, validh, self.mesh,
-                             max(self.shortlist_k, self.k))[:2]
+    def _place_catalog(self, Vh, validh, placed=None):
+        """The host's catalog on the device, with zero rows up to
+        ``len(validh)`` (the valid bits of the whole TABLE: false on the
+        spare rows a live generation's catalog has): a chunk at a time
+        into a zero table (``core.foldin.place_rows``, as the user table
+        goes up: no 1.5 GB transfer in one piece ahead of a started
+        engine's 8 KB request batches, and the table never twice on the
+        device), or sharded by rows over the mesh
+        (``serving.index.place_catalog``: a chunk at a time into each
+        shard, never whole on one device).  ``placed``: the same table
+        where the caller holds it on the device already, at that size —
+        copied there (:func:`_copy_table`).  On a STARTED engine the
+        table lies beside the live generation's until the swap."""
+        if self.mesh is not None:
+            return place_catalog(Vh, validh, self.mesh,
+                                 max(self.shortlist_k, self.k))[:2]
+        count_placed("catalog", validh.nbytes)
+        if placed is not None and placed.shape == (len(validh),
+                                                   Vh.shape[1]):
+            return _copy_table(placed), jnp.asarray(validh)
+        return (place_rows(Vh, capacity=len(validh), table="catalog"),
+                jnp.asarray(validh))
 
     def _build_index(self, V, valid, n_items, sk, seq):
         """The candidate index of one generation over the catalog as
@@ -1027,7 +1096,7 @@ class ServingEngine:
                      n_shards=int(self.mesh.devices.size), **self._labels)
 
     # -- model lifecycle ----------------------------------------------
-    def _place_users(self, prev, U):
+    def _place_users(self, prev, U, placed=None):
         """``(U on the device with spare rows, live rows, bytes sent)``:
         the whole table uploaded into a new one, a chunk at a time
         (``core.foldin.place_rows``: never twice on the device; with a
@@ -1035,12 +1104,17 @@ class ServingEngine:
         + 1) * n_loc)``, so the spare rows, which follow the live ones,
         lie on the last shards).  The capacity is the live generation's
         while the table fits it (same shapes, same programs),
-        ``row_capacity`` of the table otherwise."""
+        ``row_capacity`` of the table otherwise.  ``placed``: the same
+        table where the caller holds it on the device already, at that
+        capacity — copied there (:func:`_copy_table`), nothing sent."""
         n, rank = int(U.shape[0]), int(U.shape[1])
         cap = row_capacity(n)
         if prev is not None and prev.rank == rank \
                 and n <= int(prev.U.shape[0]):
             cap = int(prev.U.shape[0])
+        if placed is not None and self.mesh is None \
+                and placed.shape == (cap, rank):
+            return _copy_table(placed).block_until_ready(), n, 0
         # wait for it: what a publish allocates next (the catalog, its
         # index) is then allocated after the last chunk's buffer is freed
         return (place_rows(U, capacity=cap, mesh=self.mesh,
@@ -1093,7 +1167,8 @@ class ServingEngine:
         return ("replaced",) + self._place_users(prev, U)
 
     def _swap(self, how, users, seq, n_users, V, valid, index, n_items,
-              host=None, items=None, seen=None, appended=None):
+              host=None, items=None, seen=None, appended=None,
+              landing=False, took=None):
         """Install the next generation, the one place that assigns
         ``_model`` (but for :meth:`_compact_live`, which installs the
         same generation compacted); returns ``how`` it got its user
@@ -1120,17 +1195,26 @@ class ServingEngine:
         from, the next generation is then placed anew from it, still
         under the lock (``"replaced"``, with a warning); without it the
         warning names the state — every batch fails until a ``publish``
-        — and the error is raised."""
+        — and the error is raised.  ``landing``: a whole generation
+        installed on a started engine (:meth:`publish`): the same two
+        spans under the landing's names, their seconds added to
+        ``took``."""
         # the wait for the lock and what is done under it are two phases
         # of a publish on the profiler's timeline (the second is how long
         # a batch's stage can be kept out): ``programs``, the donating
         # calls dispatched under this hold
         programs = (int(how == "inplace") + int(items is not None)
                     + (0 if appended is None else 1 + len(appended.moves)))
-        with Stamped("live.batch.publish.lock_wait"):
+        took = {} if took is None else took
+        with _lap(took, "lock_wait"), (
+                Stamped("live.landing.lock_wait") if landing
+                else Stamped("live.batch.publish.lock_wait")):
             self._table_lock.acquire()
         try:
-            with Stamped("live.batch.publish.writes", programs=programs):
+            with _lap(took, "swap"), (
+                    Stamped("live.landing.swap") if landing else
+                    Stamped("live.batch.publish.writes",
+                            programs=programs)):
                 if how == "inplace":
                     table = self._model.U
                     try:
@@ -1567,7 +1651,8 @@ class ServingEngine:
             self._no_history = (rows, seen)
         return self._no_history[1]
 
-    def publish(self, U, V, item_valid=None, quantize=True, user_seen=None):
+    def publish(self, U, V, item_valid=None, quantize=True, user_seen=None,
+                *, placed=None, release=False):
         """Swap in a new model generation atomically.
 
         ``quantize=True`` builds the int8 candidate index for the new
@@ -1597,39 +1682,108 @@ class ServingEngine:
         which :meth:`publish_update` appends to them
         (``seen_appended``), also while its catalog moves
         (``touched_items``).
+
+        **On a STARTED engine** (a refit LANDS: ``LiveUpdater.land``) the
+        new generation is built whole BESIDE the live one — user table,
+        catalog, the index's own float32 catalog and its int8 rows: 5.2
+        GB more at 1.7 M users and 1.5 M items of rank 256, held from the
+        first placement to the swap, while every request is answered
+        from the live generation — at the LIVE generation's capacities:
+        the user table's spare rows, and where the live catalog has spare
+        rows and its index a segment (:meth:`warmup_live`) the same rows
+        and a fresh, EMPTY segment of the same slots
+        (``Int8CandidateIndex.over``), so every pinned program takes the
+        new tables as they are and nothing compiles
+        (:meth:`warmup_landing` runs the programs this path runs ahead of
+        the traffic).  It is installed by one :meth:`_swap`; a request
+        dequeued before it is answered wholly from the old generation,
+        one after it wholly from the new.  ``release`` then deletes what
+        the swap replaced (every array of the old generation that the new
+        one does not share): nothing of it is left on the device once the
+        batches in flight have run.  ``placed``: ``(user table | None,
+        catalog | None)`` where the caller holds the same tables on the
+        device already, at those capacities (``FoldInServer.
+        device_tables``): the generation's tables are then COPIES made on
+        the device (the caller goes on writing its own) and nothing of
+        them crosses host→device.  The steps are on the profiler's
+        timeline as ``live.landing.users`` / ``.catalog`` / ``.index`` /
+        ``.lock_wait`` / ``.swap`` / ``.release``
+        (``obs.schema.LIVE_LANDING_SPAN_KEYS``; a start's are start
+        phases) and their seconds in ``last_landing``.
         """
-        with phase("start.publish"):
+        started = self._thread is not None
+        took = {}
+        with (contextlib.nullcontext() if started
+              else phase("start.publish")):
             t0 = time.perf_counter()
             mode = faults.check("serving.publish")
             Vh = np.asarray(V, dtype=np.float32)
-            Ni = int(Vh.shape[0])
-            with phase("start.publish.users"):
-                U, n_users, _ = self._place_users(self._model, U)
+            Ni, rank = int(Vh.shape[0]), int(Vh.shape[1])
+            prev, sent0 = self._model, placed_bytes()
+            Ud, Vd = placed if placed is not None else (None, None)
+            with _lap(took, "users"), (
+                    Stamped("live.landing.users") if started
+                    else phase("start.publish.users")):
+                U, n_users, _ = self._place_users(prev, U, Ud)
+            # bytes of whole tables this generation took from tables on
+            # the device (``placed``; the index's own catalog)
+            copied = (int(U.nbytes) if self.mesh is None and Ud is not None
+                      and Ud.shape == U.shape else 0)
             seen = None
             if user_seen is not None:
                 # behind the table they are sharded with, on a mesh
                 with phase("start.publish.histories"):
                     seen = self._place_seen(user_seen, n_users, Ni,
                                             rows=int(U.shape[0]))
-            validh = (np.ones(Ni, dtype=bool) if item_valid is None
-                      else np.asarray(item_valid, dtype=bool).ravel())
+            # the LIVE catalog's rows where it has spare ones and this
+            # one fits them: same shapes, same programs
+            rows = Ni
+            if (self.mesh is None and prev is not None
+                    and prev.rank == rank
+                    and prev.n_items < int(prev.V.shape[0])
+                    and Ni <= int(prev.V.shape[0])):
+                rows = int(prev.V.shape[0])
+            validh = np.zeros(rows, dtype=bool)
+            validh[:Ni] = (True if item_valid is None
+                           else np.asarray(item_valid, dtype=bool).ravel())
             self._announce_mesh()
-            with phase("start.publish.catalog"):
+            with _lap(took, "catalog"), (
+                    Stamped("live.landing.catalog") if started
+                    else phase("start.publish.catalog")):
                 V, valid = jax.block_until_ready(
-                    self._place_catalog(Vh, validh))
+                    self._place_catalog(Vh, validh, Vd))
+            if (self.mesh is None and Vd is not None
+                    and Vd.shape == V.shape):
+                copied += int(V.nbytes)
             with self._publish_lock:
                 seq = self._seq + 1
                 sk = min(max(self.shortlist_k, self.k), Ni)
                 index = None
+                live = prev.index if prev is not None else None
+                slots = (live.delta_slots if live is not None
+                         and live.seq == prev.seq else 0)
                 if quantize and sk >= self.k and Ni > 0:
-                    # without a mesh the index uploads a copy of its own from
-                    # the host's catalog, as it always has (the device then
-                    # holds V twice: PERF.md section 7); with one it shares
-                    # the engine's sharded table
-                    with phase("start.publish.index"):
-                        index = self._build_index(
-                            *((Vh, validh) if self.mesh is None
-                              else (V, valid)), Ni, sk, seq)
+                    with _lap(took, "index"), (
+                            Stamped("live.landing.index") if started
+                            else phase("start.publish.index")):
+                        if self.mesh is None and (rows > Ni or slots):
+                            # at the live index's shapes: a copy of the
+                            # table for the index's own (its compaction
+                            # donates it), quantized whole, an empty
+                            # segment
+                            index = Int8CandidateIndex.over(
+                                _copy_table(V), validh, Ni, sk, seq,
+                                slots).block_until_ready()
+                            copied += int(V.nbytes)
+                        else:
+                            # without a mesh the index uploads a copy of
+                            # its own from the host's catalog, as it
+                            # always has (the device then holds V twice:
+                            # PERF.md section 7); with one it shares the
+                            # engine's sharded table
+                            index = self._build_index(
+                                *((Vh, validh) if self.mesh is None
+                                  else (V, valid)), Ni, sk, seq)
                     if mode == "corrupt":
                         # injected torn publish: quantization died mid-swap,
                         # so the fresh index is never published.  The
@@ -1642,8 +1796,18 @@ class ServingEngine:
                 elif self._model is not None:
                     index = self._model.index      # carried, now stale
                 self._swap("replaced", U, seq, n_users, V, valid, index, Ni,
-                           seen=seen)
+                           seen=seen, landing=started, took=took)
                 self._seq = seq
+                if release and prev is not None:
+                    with _lap(took, "release"), \
+                            Stamped("live.landing.release"):
+                        _release(prev, self._model)
+            if started:
+                self.last_landing = {
+                    "seq": seq, "users": n_users, "items": Ni,
+                    "seconds": took,
+                    "placed_bytes": placed_bytes() - sent0,
+                    "copied_bytes": copied}
             fresh = index is not None and index.seq == seq
             obs.counter("serving.publishes", **self._labels)
             obs.histogram("serving.publish_seconds",
@@ -1986,6 +2150,21 @@ class ServingEngine:
         m = self._model
         return m.index if m is not None else None
 
+    def user_rows(self, rows):
+        """The float32 rows the live generation serves for user table
+        ``rows``, read back to the host — O(len(rows)) off the device,
+        under ``_table_lock`` (the table may be donated to a row write at
+        any other time): for whoever checks a publish or a landing
+        against what it meant to install, as ``published_index.rows``
+        does for the catalog.  Compiles a gather per count: not for a
+        request's path."""
+        rows = np.asarray(rows, dtype=np.int32).ravel()
+        with self._table_lock:
+            m = self._model
+            if m is None:
+                raise NoModelPublished("publish(U, V) first")
+            return np.asarray(jnp.take(m.U, rows, axis=0))
+
     def warmup(self):
         """Compile every (bucket, path) scoring executable now, against
         the published model — first-request latency must not carry a
@@ -2275,6 +2454,30 @@ class ServingEngine:
                     m.seq, m.n_users, m.V, m.valid, m.index, m.n_items,
                     seen=m.seen)
             self._model.U.block_until_ready()
+
+    def warmup_landing(self):
+        """Run, on the published generation and ahead of the traffic,
+        the programs a LANDING runs (:meth:`publish` on a started
+        engine) that no start has run: the copy of a table on the device
+        (:func:`_copy_table`, at the user table's shape and the
+        catalog's) and the quantization of the whole catalog TABLE, spare
+        rows and all (``Int8CandidateIndex.over``; at a start the live
+        rows alone are quantized and the spare ones padded on).  Each
+        result is dropped: one table's worth of room, a program at a
+        time.  ``LiveUpdater.start`` calls it, after the other warm-ups,
+        where the updater was told refits will land (``refits=True``).
+        Nothing on a mesh engine (a landing there is ROADMAP R12)."""
+        with phase("start.warmup_landing"), self._publish_lock, \
+                phase("start.first_run"):
+            m = self._model
+            if m is None:
+                raise NoModelPublished("publish(U, V) before warmup")
+            if self.mesh is not None:
+                return
+            for table in (m.U, m.V):
+                _copy_table(table).block_until_ready()
+            if m.index is not None and m.index.seq == m.seq:
+                m.index.prewarm_over()
 
     def warmup_live(self, max_delta_rows=None, max_rows=LIVE_PADS[-1]):
         """Make the published generation ready for a catalog that moves

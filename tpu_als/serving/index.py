@@ -412,6 +412,41 @@ class Int8CandidateIndex:
         self.seq = seq
         self._clear_delta()
 
+    @classmethod
+    def over(cls, V, item_valid, n_items, shortlist_k=64, seq=0, slots=0):
+        """The index over a catalog TABLE as it lies on the device: ``V``
+        ``[rows, rank]`` float32 with ``n_items`` live rows and spare
+        (zero) rows after them, ``item_valid`` the host's ``bool[rows]``
+        (false from ``n_items`` on), an empty segment of ``slots`` slots
+        — array for array the shapes of ``build_index`` of the live rows
+        + :meth:`reserve` ``(rows, slots)``, and the same bits (a zero
+        row quantizes to zeros at scale 1, which is how ``reserve`` pads),
+        with no smaller index in between: what replaces a live generation
+        whose programs are compiled for those shapes.  ``V`` becomes the
+        index's own (:meth:`compact` donates it): hand over a buffer
+        nothing else writes."""
+        new = cls.__new__(cls)
+        rows = int(V.shape[0])
+        new.V, new.n_items, new.seq = V, int(n_items), seq
+        new.shortlist_k = min(int(shortlist_k), new.n_items)
+        cols = shortlist_columns(rows, new.shortlist_k)
+        valid = np.zeros(cols, dtype=bool)
+        valid[:rows] = np.asarray(item_valid, dtype=bool)
+        new.valid = jnp.asarray(valid)
+        new.Vq, new.sv = _quantize_rows(V, pad=cols - rows)
+        new._clear_delta()
+        if slots:
+            new._seg = new._with_slots(int(slots))
+        return new
+
+    def prewarm_over(self):
+        """Run what :meth:`over` runs at this index's shapes — the whole
+        base table quantized, block padding and all — and drop the
+        result (0.4 GB at 1.5 M x 256, for the length of the call)."""
+        rows = self.n_base
+        jax.block_until_ready(_quantize_rows(
+            self.V, pad=shortlist_columns(rows, self.shortlist_k) - rows))
+
     def shortlist_plan(self, rows=None):
         """The selection :meth:`topk` compiles for this index as it
         stands and a batch of ``rows`` queries: the

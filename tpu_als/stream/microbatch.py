@@ -25,7 +25,11 @@ capacity, so an append changes no array's shape.
 Item factors stay fixed during USER fold-ins (the standard fold-in
 contract); the symmetric ``update_items`` folds new/updated ITEMS against
 the fixed user factors, so both directions of catalog growth are served
-between refits.  Each direction's fixed side is a table of the server's
+between refits — and when a refit is done, :meth:`FoldInServer.land`
+takes it as the new base (both tables replaced, the kept ratings kept)
+and folds the events since its snapshot onto it again
+(``LiveUpdater.land`` is what a deployment calls).  Each direction's
+fixed side is a table of the server's
 own on the device, placed once (the catalog at construction, the user
 table when the first item fold — or ``prewarm`` of the item side — asks
 for it): a fold's write-back also writes the rows it moved into that
@@ -141,6 +145,11 @@ from tpu_als.utils.frame import as_frame
 # only a resident base history reaches (above LIVE_PADS): 0.5 GB of
 # rank-256 float32 rows, and the Gram build holds it more than once
 FOLD_ELEMENTS = 1 << 19
+
+# entities one call of the fold-in program takes in a landing's catch-up
+# (``FoldInServer.land``): the widest shape ``prewarm`` runs.  A call is a
+# program no scoring batch can overtake on the device
+CATCHUP_ROWS = LIVE_PADS[-1]
 
 # the server's table on the device -> the ``side`` its Gram matrix's
 # counters and start phase carry (the TABLE's: ``_Ud`` is the user table)
@@ -580,13 +589,8 @@ class FoldInServer:
                 # program takes (``core.foldin.pack_rows``), a host
                 # argument of its call: nothing is placed ahead of it;
                 # each entity's usable ratings as slice copies
-                rows = cols, vals, mask = planes(
-                    np.zeros((3, n_pad, w), dtype=np.int32))
-                for k, i in enumerate(sel.tolist()):
-                    ids, stars = hists[i].usable()
-                    cols[k, :len(ids)] = ids
-                    vals[k, :len(ids)] = stars
-                    mask[k, :len(ids)] = 1.0
+                rows = _packed((hists[i].usable() for i in sel.tolist()),
+                               n_pad, w)
             # the fold's one wait for the device, on the profiler's
             # timeline (obs.schema.LIVE_FOLDIN_SPAN_KEYS): the call, which
             # carries the one array up (a phase of its own inside, until
@@ -720,6 +724,225 @@ class FoldInServer:
                         side=_YTY_SIDE[dev_attr])
         setattr(self, dev_attr, out)
 
+    # -- a refit lands ---------------------------------------------------
+    def device_tables(self):
+        """``(user table | None, catalog)`` as the server holds them on
+        the device (each the fixed side of one fold direction, spare rows
+        and all; the user table ``None`` until item folds began): for
+        whoever takes a copy of them there
+        (``ServingEngine.publish(placed=...)``)."""
+        return self._Ud, self._V
+
+    def land(self, refit, users=(), items=()):
+        """A REFIT lands: ``refit`` (an ``ALSModel``: the factors of a
+        whole new fit, with their ids) becomes the base the server folds
+        against, and the CATCH-UP is folded onto it.
+
+        Both host tables take the refit's rows at the rows the live id
+        maps give those ids (a refit whose ids are the live maps' first
+        ids in their order is copied as it lies; an id the live maps do
+        not hold is a ``ValueError``), the server's tables on the device
+        are released and placed anew from them (a chunk at a time, at
+        the capacities they had: same shapes, same programs), and the
+        kept ratings (``_Ratings``: table rows, which an id keeps for
+        good) stay as they are.  Then the catch-up, by the rule every
+        batch follows — users first, then items: every entity of
+        ``users`` / ``items`` (original ids: those with an event admitted
+        after the refit's snapshot, which the caller knows) and every
+        entity the live maps hold that the refit does not (new since the
+        snapshot: it keeps its row NUMBER and is given the row again) is
+        folded over ALL its kept ratings whose other side has a row in
+        the landed tables when the fold runs — the refit's catalog for
+        the users, the user table as the users' catch-up left it for the
+        items.  Some of these ratings name an entity new since the
+        snapshot, which has no row until its own side's catch-up gave it
+        one: so the rounds go on, users then items, each folding again
+        whoever can now use MORE of its ratings than its last fold here
+        could, until a round folds nobody — every row of the landed
+        generation then includes every kept rating it can, as the rows
+        it replaces did.  An entity in neither set keeps the refit's row
+        untouched.  Nothing counts into ``foldin.ratings`` (no rating
+        enters a fold for the first time); what a later fold counts as
+        entering is measured against what the catch-up could use.
+
+        The caller (``LiveUpdater.land``) makes sure no fold runs
+        meanwhile.  Returns ``{"users": (ids, rows), "items": (ids,
+        rows), "rounds", "calls", "seconds"}``: the entities the catch-up
+        folded with their new rows (copies), in the order they were
+        folded, and the seconds of the three steps (``tables``,
+        ``place``, ``catchup``)."""
+        m, r = self.model, refit
+        if not self.keep_history:
+            raise ValueError("a landing needs keep_history: the catch-up "
+                             "folds over the kept ratings")
+        if self._base is not None or self._implicit:
+            raise NotImplementedError(
+                "a landing under resident histories or implicit feedback: "
+                "the grown layout laid out anew / both Gram matrices "
+                "recomputed (ROADMAP R11)")
+        if int(r._U.shape[1]) != int(m._U.shape[1]):
+            raise ValueError(f"the refit's rank {r._U.shape[1]} is not "
+                             f"the live model's {m._U.shape[1]}")
+        both = self._Ud is not None
+        sides = (("user", "_U", m._user_map, r._user_map, r._U,
+                  self._history),
+                 ("item", "_V", m._item_map, r._item_map, r._V,
+                  self._item_history))
+        # side -> which table rows the refit gives; the rows its ids take
+        # (None: the first ones, in order); who is folded again
+        has, at, todo = {}, {}, {}
+        took, t = {}, time.perf_counter()
+        with Stamped("live.landing.tables"):
+            for (side, fac_attr, emap, theirs, F, history), asked in zip(
+                    sides, (users, items)):
+                n_live, n = len(emap), len(theirs)
+                rows = None
+                if n > n_live or not np.array_equal(theirs.ids,
+                                                    emap.ids[:n]):
+                    rows = emap.to_dense(theirs.ids)
+                    if (rows < 0).any():
+                        raise ValueError(
+                            f"the refit holds {int((rows < 0).sum())} "
+                            f"{side} ids the live model does not: fold "
+                            "their events in before the landing")
+                at[side] = rows
+                has[side] = np.zeros(n_live, bool)
+                has[side][slice(n) if rows is None else rows] = True
+                new = emap.ids[~has[side]]
+                lost = [e for e in new.tolist() if e not in history]
+                if lost:
+                    raise ValueError(
+                        f"{len(lost)} {side} ids of the live model have "
+                        "neither a row in the refit nor a kept rating to "
+                        f"fold one from (first: {lost[0]})")
+                todo[side] = np.union1d(
+                    np.asarray(asked, dtype=emap.ids.dtype), new)
+            for side, fac_attr, emap, theirs, F, _ in sides:
+                # (a user-only server's catalog gets its buffer here)
+                self._reserve(items_side=side == "item")
+                buf, n_live = self._bufs[fac_attr], len(emap)
+                if at[side] is None:
+                    buf[:len(theirs)] = F
+                    buf[len(theirs):n_live] = 0.0
+                else:
+                    buf[:n_live] = 0.0
+                    buf[at[side]] = F
+        took["tables"], t = time.perf_counter() - t, time.perf_counter()
+        for dev_attr in ("_V", "_Ud"):
+            # both released before either is placed anew
+            old = getattr(self, dev_attr)
+            if old is not None:
+                old.delete()
+        for dev_attr, fac_attr, side in (("_V", "_V", "item"),
+                                         ("_Ud", "_U", "user")):
+            if dev_attr == "_Ud" and not both:
+                continue
+            nbytes = int(getattr(m, fac_attr).nbytes)
+            with Stamped("live.landing.place", side=side, bytes=nbytes):
+                setattr(self, dev_attr, self._place(fac_attr))
+        took["place"], t = time.perf_counter() - t, time.perf_counter()
+        out = {"user": ([], []), "item": ([], [])}
+        rounds = calls = 0
+        could = {"user": {}, "item": {}}    # ratings its last fold here used
+        with Stamped("live.landing.catchup") as span:
+            while True:
+                folded = 0
+                for side, fac_attr, emap, _, _, _ in (
+                        sides if both else sides[:1]):
+                    if not len(todo[side]):
+                        continue
+                    done, x, made = self._catch_up(
+                        todo[side], side == "item", has, could[side])
+                    if len(done):
+                        out[side][0].append(done)
+                        out[side][1].append(x)
+                        has[side][emap.to_dense(done)] = True
+                    folded, calls = folded + len(done), calls + made
+                if not folded:
+                    break
+                rounds += 1
+            left = sum(int((~has[side]).sum()) for side in has)
+            span.set_metadata(users=sum(map(len, out["user"][0])),
+                              items=sum(map(len, out["item"][0])),
+                              rounds=rounds, calls=calls)
+        if left:
+            # (unreachable where every entity was appended by a fold: its
+            # usable rating named an older entity)
+            obs.emit("warning", what="live.landing",
+                     reason=f"{left} entities new since the snapshot have "
+                            "no usable rating: their rows are zero until "
+                            "their next event")
+        self.last_rows = {"users": None, "items": None}
+        self.last_appended = (self.last_appended[0][:0],
+                              self.last_appended[1][:0])
+        took["catchup"] = time.perf_counter() - t
+        width = int(m._U.shape[1])
+        return {"rounds": rounds, "calls": calls, "seconds": took, **{
+            side + "s": ((np.concatenate(ids), np.concatenate(x))
+                         if ids else (emap.ids[:0].copy(),
+                                      np.empty((0, width), np.float32)))
+            for (side, _, emap, _, _, _), (ids, x) in zip(
+                sides, (out["user"], out["item"]))}}
+
+    def _catch_up(self, entities, items_side, has, could):
+        """One side's part of one round of a landing's catch-up
+        (:meth:`land`): those of ``entities`` (original ids, each with a
+        kept history) that can use more of their kept ratings — the ones
+        whose other side ``has`` a row in the landed tables — than
+        ``could`` says their last fold of this landing did (none: 0) are
+        folded over them, in calls of at most ``CATCHUP_ROWS`` entities
+        (a shape ``prewarm`` ran), the rows written into the host's
+        table and the server's own on the device.  Returns ``(the
+        entities folded, their rows, the calls made)``."""
+        m = self.model
+        side = "item" if items_side else "user"
+        emap, fixed_map = ((m._item_map, m._user_map) if items_side
+                           else (m._user_map, m._item_map))
+        history = self._item_history if items_side else self._history
+        usable_rows = has["user" if items_side else "item"]
+        hists = [history[e] for e in entities.tolist()]
+        _look_again([h for h in hists
+                     if h.unknown and h.looked < len(fixed_map)], fixed_map)
+        packs = []
+        for h in hists:
+            rows, stars = h.usable()
+            ok = usable_rows[rows]
+            packs.append((rows[ok], stars[ok]))
+        lens = np.array([len(rows) for rows, _ in packs])
+        used = self._used[side]
+        more = np.zeros(len(lens), bool)
+        for k, (e, n_ok) in enumerate(zip(entities.tolist(),
+                                          lens.tolist())):
+            used[e] = n_ok
+            more[k] = n_ok > could.get(e, 0)
+            could[e] = n_ok
+        fold = np.flatnonzero(more)
+        if not len(fold):
+            return entities[:0], np.empty((0, m._U.shape[1]),
+                                          np.float32), 0
+        F, YtY = self._fixed(items_side), self.yty(items_side)
+        dev_attr = "_V" if items_side else "_Ud"
+        table = getattr(m, "_V" if items_side else "_U")
+        x, calls = np.empty((len(fold), F.shape[1]), np.float32), 0
+        dense = emap.to_dense(entities[fold])
+        for lo in range(0, len(fold), CATCHUP_ROWS):
+            part = np.arange(lo, min(lo + CATCHUP_ROWS, len(fold)))
+            for sel in self._calls(lens[fold[part]]):
+                sel = part[sel]
+                n_pad = pad_for(len(sel))
+                w = rung_for(int(lens[fold[sel]].max()), self._widths)
+                rows = _packed((packs[i] for i in fold[sel].tolist()),
+                               n_pad, w)
+                with Stamped("live.landing.catchup.call", side=side + "s",
+                             rows=n_pad, width=w):
+                    solved = self._fold(F, rows, YtY)
+                    x[sel] = np.asarray(solved)[:len(sel)]
+                calls += 1
+                table[dense[sel]] = x[sel]
+                if getattr(self, dev_attr) is not None:
+                    self._write(dev_attr, dense[sel], x[sel], placed=solved)
+        return entities[fold], x, calls
+
     def latency(self, q=0.5, skip_warmup=False):
         """Latency quantile over processed batches.  ``skip_warmup`` drops
         the first batch (jit compile) — what latency benchmarks want."""
@@ -816,6 +1039,20 @@ class _Ratings:
             return rows, stars
         known = rows >= 0
         return rows[known], stars[known]
+
+
+def _packed(ratings, n_pad, w):
+    """``(cols, vals, mask)``, the planes of the ONE ``int32[3, n_pad, w]``
+    host array a call of the fold-in program takes
+    (``core.foldin.pack_rows``), holding ``ratings`` — one ``(table rows,
+    stars)`` an entity, each at most ``w`` long — as slice copies, an
+    entity a row."""
+    rows = cols, vals, mask = planes(np.zeros((3, n_pad, w), dtype=np.int32))
+    for k, (ids, stars) in enumerate(ratings):
+        cols[k, :len(ids)] = ids
+        vals[k, :len(ids)] = stars
+        mask[k, :len(ids)] = 1.0
+    return rows
 
 
 def _resized(a, n, size, dtype=None):
